@@ -228,7 +228,13 @@ def main():
 
         apply_pass(main_prog, "fuse_elewise_add_act")
 
-    place = fluid.CPUPlace() if args.device == "CPU" else fluid.TPUPlace()
+    if args.device == "CPU":
+        place = fluid.CPUPlace()
+    else:
+        # --device TPU means the chip: TPUPlace() alone would resolve to
+        # a CPU device on a host without one and print a samples/sec
+        fluid.require_accelerator(max(1, args.num_devices or 1))
+        place = fluid.TPUPlace()
 
     if args.update_method in ("spmd", "multiproc"):
         build_strategy = fluid.BuildStrategy()

@@ -17,10 +17,12 @@ from paddle_tpu.core.types import (  # noqa: F401
     CPUPlace,
     CUDAPinnedPlace,
     CUDAPlace,
+    NoAcceleratorError,
     TPUPlace,
     Place,
     VarType,
     core_version,
+    require_accelerator,
 )
 from paddle_tpu import framework  # noqa: F401
 from paddle_tpu import ops as _ops  # noqa: F401  (registers all operators)

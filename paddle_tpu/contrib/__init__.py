@@ -6,7 +6,7 @@ op_freq_statis - unigram + adjacent-pair op frequency statistics
                  (contrib/op_frequence.py role).
 QuantizeTranspiler is re-exported from transpiler (the contrib/quantize
 package's home in the reference); the contrib beam-search decoder's
-capability lives in ops/beam_search_ops.py + layers (COVERAGE.md).
+capability lives in ops/beam_search_ops.py + layers.
 """
 
 from collections import OrderedDict

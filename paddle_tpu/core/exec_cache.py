@@ -1,17 +1,23 @@
 """Persistent cross-process executable cache + compile-tax telemetry.
 
 Every process start (serving replica, bench run, CI shard) used to pay the
-full XLA compile from scratch. This module kills that tax in two layers,
-both keyed off the structural fingerprints in ``core/fingerprint.py`` and
-switched by ``FLAGS_exec_cache_dir`` (empty = disabled, zero overhead):
+full XLA compile from scratch. This module kills that tax in two layers:
 
-1. **XLA compile cache** (``<dir>/xla``): JAX's persistent compilation
-   cache, enabled process-wide. A warm process still re-traces the program
-   to HLO, but the backend compile is replaced by a disk load (content
-   hash of the HLO module, so it also dedups across Executor instances
-   and structurally identical programs).
-2. **AOT executable images** (``<dir>/aot``): serialized
-   ``lower()``/``compile()`` output of the whole step function, keyed by
+1. **XLA compile cache**: JAX's persistent compilation cache. A warm
+   process still re-traces the program to HLO, but the backend compile is
+   replaced by a disk load (content hash of the HLO module, so it also
+   dedups across Executor instances and structurally identical
+   programs). It is PLACED FROM OUTSIDE: where the environment sets
+   ``JAX_COMPILATION_CACHE_DIR`` the cache stays there and this module
+   sets no other directory; otherwise it goes to one fixed path inside
+   the checkout (``xla_cache_dir()``). The directory is part of JAX's
+   cache key, so it never derives from a temp name, a pid or a time —
+   a path that moves never hits. Nothing here ever switches it off.
+   ``enable_xla_cache()`` turns it on (``chip_smoke.py`` and
+   ``bench.py`` do at start; ``FLAGS_exec_cache_dir`` implies it).
+2. **AOT executable images** (``<FLAGS_exec_cache_dir>/aot``; empty flag
+   = layer off, zero overhead): serialized ``lower()``/``compile()``
+   output of the whole step function, keyed by
    ``fingerprint.executable_key`` x argument avals x jax/jaxlib versions.
    A warm process skips even the trace: the executable deserializes
    straight into a callable.
@@ -46,6 +52,7 @@ from paddle_tpu.observability import lock_witness
 import time
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache
 
 _lock = lock_witness.make_lock("core.exec_cache")
 _tls = threading.local()
@@ -142,13 +149,18 @@ def record_trace_miss():
 def stats():
     """Snapshot of the cache counters. ``fresh_compiles`` is the number of
     XLA compiles no cache layer could serve — the warm-start smoke stage
-    asserts it is zero in a second process sharing the cache dir."""
+    asserts it is zero in a second process sharing the cache.
+    ``enabled``/``cache_dir`` describe the AOT image layer (the flag);
+    ``xla_cache_dir`` is where JAX's persistent cache is live, or None."""
     with _lock:
         snap = dict(_stats)
     snap["enabled"] = _configured["dir"] is not None
     snap["cache_dir"] = _configured["dir"]
+    snap["xla_cache_dir"] = (
+        jax.config.jax_compilation_cache_dir
+        if jax.config.jax_enable_compilation_cache else None)
     snap["fresh_compiles"] = (
-        snap["persistent_misses"] if snap["enabled"]
+        snap["persistent_misses"] if snap["xla_cache_dir"]
         else snap["backend_compiles"]
     )
     return snap
@@ -161,11 +173,48 @@ def reset_stats():
 
 
 # -- configuration -----------------------------------------------------------
+# the checkout root (this file is paddle_tpu/core/exec_cache.py); listed
+# in .gitignore
+_DEFAULT_XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def xla_cache_dir():
+    """Where JAX's persistent compile cache belongs: where the
+    environment put it, else the one fixed path inside the checkout."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or _DEFAULT_XLA_CACHE_DIR)
+
+
+def enable_xla_cache():
+    """Turn JAX's persistent compile cache on at ``xla_cache_dir()``.
+    Idempotent. With ``JAX_COMPILATION_CACHE_DIR`` set JAX already
+    points there and no directory is set here."""
+    target = xla_cache_dir()
+    if jax.config.jax_compilation_cache_dir != target:
+        os.makedirs(target, mode=0o700, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", target)
+        # jax binds its file cache at the first compile; a dir set
+        # after that only lands once the handle is dropped
+        compilation_cache.reset_cache()
+    # the defaults skip "too fast / too small" entries; this cache
+    # exists to make every process start warm, so persist everything
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # a corrupt entry must degrade to a fresh compile, never a crash
+    jax.config.update("jax_raise_persistent_cache_errors", False)
+    _apply_max_bytes()
+    return target
+
+
 def configure(cache_dir=None):
-    """Point both cache layers at ``cache_dir`` (default: the
-    ``exec_cache_dir`` flag). Idempotent; safe to call per compile. An
-    empty dir disables persistence (and re-disables it if a previous test
-    or run had enabled it with a since-deleted temp dir)."""
+    """Point the AOT image layer at ``cache_dir`` (default: the
+    ``exec_cache_dir`` flag) and, when that is non-empty, make sure
+    JAX's compile cache is on (``enable_xla_cache``). Idempotent; safe
+    to call per compile. An empty dir turns the AOT layer off and
+    leaves JAX's cache exactly as the environment or an earlier
+    ``enable_xla_cache()`` set it."""
     if cache_dir is None:
         from paddle_tpu import flags
 
@@ -175,36 +224,22 @@ def configure(cache_dir=None):
         if cache_dir is not None:
             _apply_max_bytes()  # a flag change must land without a dir change
         return cache_dir
-    if cache_dir is None:
-        jax.config.update("jax_enable_compilation_cache", False)
-        _reset_jax_cache()
-        _configured["dir"] = None
-        return None
-    # 0o700: AOT images load via pickle, so the dir is code-execution
-    # trusted — keep it private to this user (see module docstring)
-    os.makedirs(cache_dir, mode=0o700, exist_ok=True)
-    os.makedirs(os.path.join(cache_dir, "aot"), mode=0o700, exist_ok=True)
-    xla_dir = os.path.join(cache_dir, "xla")
-    os.makedirs(xla_dir, mode=0o700, exist_ok=True)
-    jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_compilation_cache_dir", xla_dir)
-    # the defaults skip "too fast / too small" entries; an executor cache
-    # exists to make every process start warm, so persist everything
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    # a corrupt entry must degrade to a fresh compile, never a crash
-    jax.config.update("jax_raise_persistent_cache_errors", False)
-    _apply_max_bytes()
-    _reset_jax_cache()
+    if cache_dir is not None:
+        # 0o700: AOT images load via pickle, so the dir is code-execution
+        # trusted — keep it private to this user (see module docstring)
+        os.makedirs(cache_dir, mode=0o700, exist_ok=True)
+        os.makedirs(os.path.join(cache_dir, "aot"), mode=0o700,
+                    exist_ok=True)
+        enable_xla_cache()
     _configured["dir"] = cache_dir
     return cache_dir
 
 
 def _apply_max_bytes():
-    """The flag is the TOTAL budget for the cache dir: half to the XLA
-    layer (jax's LRU), half to the AOT image layer (_trim_aot_dir).
-    Always written — including back to -1/unbounded — so a stale cap from
-    an earlier configuration can't linger."""
+    """The flag is the TOTAL budget: half to the XLA layer (jax's LRU),
+    half to the AOT image layer (_trim_aot_dir). Always written —
+    including back to -1/unbounded — so a stale cap from an earlier
+    configuration can't linger."""
     max_bytes = _max_bytes()
     jax.config.update(
         "jax_compilation_cache_max_size",
@@ -219,17 +254,6 @@ def _max_bytes():
         return int(flags.get("exec_cache_max_bytes"))
     except (KeyError, TypeError, ValueError):
         return -1
-
-
-def _reset_jax_cache():
-    """Drop jax's in-memory handle on the file cache so a dir change (or
-    disable) takes effect mid-process; internal API, so best-effort."""
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:
-        pass
 
 
 def enabled():

@@ -28,7 +28,8 @@ import hashlib
 # time; build-time flags (fused_ce) already show up in the program
 # structure and need no entry here.
 TRACE_FLAGS = ("use_pallas_lstm", "use_pallas_gru", "remat_gradients",
-               "conv_nhwc", "attention_impl", "flash_backward")
+               "conv_nhwc", "attention_impl", "flash_backward",
+               "paged_attention", "tree_attention")
 
 
 def trace_flags_key():

@@ -6,38 +6,69 @@ device runtime is owned by JAX/PJRT, so a Place resolves to a ``jax.Device``
 instead of carrying CUDA stream state.
 """
 
+import os
+
 import numpy as np
 
 
-class Place(object):
-    """Base device tag. Resolves lazily to a jax.Device."""
+class NoAcceleratorError(RuntimeError):
+    """The caller asked for the accelerator and JAX found none."""
 
-    _kind = None  # platform preference, e.g. "tpu" / "cpu"
+
+def require_accelerator(count=1):
+    """The accelerator or fail: the first ``count`` non-CPU devices this
+    process owns, as JAX reports them. The entry points that MEASURE
+    (``chip_smoke.py``, ``bench.py``'s chip worker, ``fluid_benchmark.py
+    --device TPU``, ``tools/kernel_bench.py``) call this first, so a
+    host with no chip is an error that names the missing device — never
+    a CPU number under a device metric's name."""
+    import jax
+
+    devices = jax.local_devices()
+    chips = [d for d in devices if d.platform != "cpu"]
+    if len(chips) < count:
+        raise NoAcceleratorError(
+            "need %d accelerator device(s) but JAX found %d: "
+            "jax.local_devices() is %s (default backend %r, "
+            "JAX_PLATFORMS=%r)"
+            % (count, len(chips),
+               ["%s:%d" % (d.platform, d.id) for d in devices],
+               jax.default_backend(),
+               os.environ.get("JAX_PLATFORMS")))
+    return chips[:count]
+
+
+class Place(object):
+    """Base device tag. Resolves lazily to a jax.Device: the
+    ``device_id``-th device of the Place's platform that this process
+    owns. An id beyond that pool is an error — ``TPUPlace(3)`` on a
+    one-chip host is not chip 0."""
+
+    _kind = None  # platform the pool is drawn from; None = default backend
 
     def __init__(self, device_id=0):
         self.device_id = int(device_id)
 
-    def jax_device(self):
+    def _pool(self):
         import jax
 
-        if self._kind is not None:
-            # Ask the backend for this platform directly: jax.devices()
-            # only lists the DEFAULT platform, so with an accelerator
-            # plugin loaded a CPUPlace would otherwise silently resolve to
-            # the accelerator.
-            try:
-                devs = jax.devices(self._kind)
-                # Under jax.distributed, jax.devices() is the GLOBAL list;
-                # an Executor place must be a device this process owns.
-                local = [
-                    d for d in devs if d.process_index == jax.process_index()
-                ]
-                devs = local or devs
-                return devs[self.device_id % len(devs)]
-            except RuntimeError:
-                pass  # platform not present; fall through to default
-        devices = jax.devices()
-        return devices[self.device_id % len(devices)]
+        # Ask the backend for this platform directly: jax.devices() only
+        # lists the DEFAULT platform, so with an accelerator present a
+        # CPUPlace would otherwise resolve to the accelerator. Under
+        # jax.distributed, jax.devices() is the GLOBAL list; an Executor
+        # place must be a device this process owns.
+        devs = jax.devices(self._kind) if self._kind else jax.devices()
+        return [d for d in devs if d.process_index == jax.process_index()]
+
+    def jax_device(self):
+        pool = self._pool()
+        if not 0 <= self.device_id < len(pool):
+            raise ValueError(
+                "%r is out of range: this process owns %d such "
+                "device(s) (%s)" % (
+                    self, len(pool),
+                    ", ".join("%s:%d" % (d.platform, d.id) for d in pool)))
+        return pool[self.device_id]
 
     def __eq__(self, other):
         return type(self) is type(other) and self.device_id == other.device_id
@@ -50,19 +81,24 @@ class Place(object):
 
 
 class TPUPlace(Place):
-    """The TPU device tag — the ``CUDAPlace`` analog (place.h:36). Falls back
-    to the default JAX backend when no TPU platform is present (e.g. unit
-    tests on the virtual CPU mesh)."""
+    """The accelerator device tag — the ``CUDAPlace`` analog
+    (place.h:36): the ``device_id``-th non-CPU device this process owns.
+
+    On a host where JAX has NO accelerator (the forced-CPU test
+    backend, ``JAX_PLATFORMS=cpu``) it resolves to the CPU devices
+    instead, so that programs written for the chip run unchanged in
+    tests. That makes ``TPUPlace()`` a statement of preference, not of
+    fact: code that reports a device number must establish the device
+    with ``require_accelerator()`` (or read ``jax_device().platform``),
+    not infer it from the Place's name."""
 
     _kind = "tpu"
 
-    def jax_device(self):
+    def _pool(self):
         import jax
 
         devices = jax.local_devices()
-        non_cpu = [d for d in devices if d.platform.lower() != "cpu"]
-        pool = non_cpu if non_cpu else devices
-        return pool[self.device_id % len(pool)]
+        return [d for d in devices if d.platform != "cpu"] or devices
 
 
 class CPUPlace(Place):

@@ -226,7 +226,7 @@ class FetchHandle(object):
                 pause = min(pause * 2, 0.05)
         if self._numpy is None:
             # a fetch that never materializes is the canonical silent
-            # hang (wedged tunnel, dead peer): the guard arms the
+            # hang (wedged device, dead peer): the guard arms the
             # watchdog so a stall here is named in the black box
             with _blackbox.guard("FetchHandle.result"):
                 if self._nan_check is not None:
@@ -360,6 +360,15 @@ class Executor(object):
                 exec_cache.record_trace_hit()
         self._cache[key] = cp
         return cp
+
+    def compiled_text(self, program):
+        """Optimized-HLO text of every executable this executor has run
+        for ``program`` (one per feed-shape/fetch-list combination;
+        multi-step scans included) — see
+        ``CompiledProgram.compiled_text``."""
+        fp = program_fingerprint(program)
+        return [cp.compiled_text() for key, cp in self._cache.items()
+                if fp in key[:2]]
 
     def run(
         self,

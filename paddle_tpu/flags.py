@@ -69,9 +69,11 @@ _DEFS = {
     # dkv/dq kernels, O(block) memory) or "reference" (recompute through
     # the XLA-composed path — materializes the [T, S] score matrix)
     "flash_backward": ("pallas", str),
-    # persistent executable cache root (core/exec_cache.py): XLA compile
-    # cache + AOT executable images live under it, shared across
-    # processes; empty disables persistence (in-memory caching stays on)
+    # AOT executable image dir (core/exec_cache.py), shared across
+    # processes; empty turns that layer off. Setting it also turns on
+    # JAX's persistent compile cache, which is placed from OUTSIDE:
+    # JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache —
+    # never under this flag's directory
     "exec_cache_dir": ("", str),
     # TOTAL byte budget for the persistent cache dir (-1 = unbounded),
     # split evenly: LRU eviction on the XLA layer, oldest-first trim on
@@ -85,9 +87,10 @@ _DEFS = {
     # <path> gets the text-format metrics, <path>.steps.jsonl the per-step
     # records; empty disables the files (in-memory registry stays live)
     "metrics_path": ("", str),
-    # MFU accounting override, TFLOP/s: 0 = auto from device_kind (the
-    # chip table); set explicitly on hardware the table doesn't know
-    # (or to make CPU-proxy MFU numbers comparable run-to-run)
+    # MFU accounting override, TFLOP/s: 0 = from device_kind (the one
+    # chip table, observability/telemetry.CHIP_PEAKS); set explicitly on
+    # hardware the table doesn't know — without it such a device
+    # reports NO MFU rather than another chip's
     "peak_tflops": (0.0, float),
     # run the structural program verifier (analysis/verify.py) before
     # every fresh compile in Executor.run/run_multi_step, at Predictor
@@ -169,8 +172,8 @@ _DEFS = {
     # route the transformer's label-smoothed CE head through the fused
     # single-pass op (ops/loss_ops.py fused_label_smooth_ce): bf16
     # logits with f32-accumulated reductions, hand-written one-pass
-    # backward. MFU lever #1 from docs/MFU_PLAN.md (the composed head
-    # moves ~10 GB/step of f32 logits-shaped traffic at bench shapes);
+    # backward. MFU lever #1 (the composed head moves ~10 GB/step of
+    # f32 logits-shaped traffic at bench shapes, per the cost model);
     # opt-in until the chip A/B (watcher leg transformer-ce-fused) lands
     "fused_ce": (False, bool),
     # request-scoped distributed tracing across the serving plane

@@ -32,6 +32,11 @@ _NEG_INF = -1e30
 # forward zeroes them, backward skips them (must stay > _NEG_INF and
 # below any reachable finite score)
 _MASKED_ROW_LSE = -1e29
+# pallas_call names: what a Mosaic error, a profiler trace and the
+# compiled text show for these kernels
+FWD_KERNEL_NAME = "flash_attention_fwd"
+BWD_DKV_KERNEL_NAME = "flash_attention_bwd_dkv"
+BWD_DQ_KERNEL_NAME = "flash_attention_bwd_dq"
 
 
 def _mosaic_params(interpret, dimension_semantics):
@@ -273,6 +278,7 @@ def _flash_forward(q, k, v, kv_mask, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=FWD_KERNEL_NAME,
         # (b, h, qi) tiles are independent — only the kj reduction is
         # order-dependent. Declaring that lets Mosaic pipeline/reorder
         # the independent tiles instead of running the grid serially.
@@ -518,6 +524,7 @@ def _flash_backward(q, k, v, kv_mask, out, lse, dout, causal, sm_scale,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name=BWD_DKV_KERNEL_NAME,
         # dk/dv accumulate over the (gi, qi) inner dims; (b, hk, kj)
         # tiles are independent
         **_mosaic_params(interpret,
@@ -548,6 +555,7 @@ def _flash_backward(q, k, v, kv_mask, out, lse, dout, causal, sm_scale,
         out_shape=jax.ShapeDtypeStruct((B, H, Tp, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=BWD_DQ_KERNEL_NAME,
         # dq accumulates over kj only; (b, h, qi) tiles independent
         **_mosaic_params(interpret, ("parallel",) * 3 + ("arbitrary",)),
     )(qp, kp, vp, dop, lse, delta, kvm)
@@ -613,6 +621,26 @@ def _backward_impl():
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _key_mask(mask):
+    """The [B, S] key-validity view of ``mask`` when it has that form
+    ([B, S], or [B, 1, 1, S] as the sdpa op normalizes it), else None."""
+    if mask is None:
+        return None
+    if mask.ndim == 2:
+        return mask
+    if mask.ndim == 4 and mask.shape[1] == 1 and mask.shape[2] == 1:
+        return mask[:, 0, 0, :]
+    return None
+
+
+def uses_kernel(mask=None, force_reference=False, force_pallas=False):
+    """Whether ``flash_attention`` with these arguments runs the Pallas
+    kernel (else the XLA reference): key-validity masks go through the
+    kernel, full [B, H, T, S] masks never do."""
+    return (mask is None or _key_mask(mask) is not None) and (
+        force_pallas or (not force_reference and _is_tpu_target()))
+
+
 def flash_attention(
     q,
     k,
@@ -647,18 +675,8 @@ def flash_attention(
         raise ValueError(
             "flash_attention: window must be >= 0 (0 disables the "
             "sliding window); got %d" % window)
-    kv_mask = None
-    if mask is not None:
-        if mask.ndim == 2:
-            kv_mask = mask
-        elif mask.ndim == 4 and mask.shape[1] == 1 and mask.shape[2] == 1:
-            kv_mask = mask[:, 0, 0, :]
-    use_pallas = force_pallas or (
-        not force_reference
-        and (mask is None or kv_mask is not None)
-        and _is_tpu_target()
-    )
-    if not use_pallas or (mask is not None and kv_mask is None):
+    kv_mask = _key_mask(mask)
+    if not uses_kernel(mask, force_reference, force_pallas):
         # normalize a [B, S] key mask to [B, 1, 1, S] for the reference
         # einsum path (raw 2-D would broadcast B against the T axis)
         ref_mask = (kv_mask[:, None, None, :] if kv_mask is not None

@@ -28,8 +28,8 @@ per-slot page-index table ``[S, pages_per_slot]`` and a length vector
 
 ``interpret=True`` runs the same kernel on CPU for tests; the composed
 XLA reference (gather pages through the table, masked softmax) is the
-fallback behind ``FLAGS_paged_attention=reference`` and the default on
-CPU targets, mirroring ``flash_attention``'s routing.
+explicit oracle behind ``FLAGS_paged_attention=reference`` and the
+default on CPU targets, mirroring ``flash_attention``'s routing.
 """
 
 import functools
@@ -41,101 +41,27 @@ import jax.numpy as jnp
 # two kernels' impl routing can never diverge.
 from paddle_tpu.kernels.flash_attention import _is_tpu_target
 
-# graceful kernel degradation: a Pallas compile/trace failure trips a
-# ONCE-per-process fallback to the composed reference path instead of
-# killing the request — a serving fleet on a rig with a broken Pallas
-# toolchain degrades to slower attention, not to an outage. The trip is
-# loud (warning log + counter + black-box note) so operators see the
-# perf cliff for what it is.
-_FALLBACK = {"tripped": False}
+
+class KernelCompileError(RuntimeError):
+    """A Pallas kernel was refused at trace/lowering time. Carries the
+    kernel's name and operand shapes: a kernel the compiler refuses is
+    an error to fix, never a reason to serve the reference in its place
+    (``FLAGS_paged_attention=reference`` / ``FLAGS_tree_attention=
+    reference`` remain the explicit oracle)."""
+
+    def __init__(self, kernel, operands, cause):
+        self.kernel = kernel
+        self.shapes = tuple(
+            (tuple(x.shape), str(x.dtype)) for x in operands)
+        super(KernelCompileError, self).__init__(
+            "Pallas kernel %r failed to compile for operands %s: %s: %s"
+            % (kernel, list(self.shapes), type(cause).__name__, cause))
 
 
-def kernel_fallback_tripped():
-    """True once this process abandoned the Pallas paged kernel."""
-    return _FALLBACK["tripped"]
-
-
-def reset_kernel_fallback():
-    """Re-arm the Pallas path (tests; a production process stays
-    degraded until restart — the failure is deterministic per build)."""
-    _FALLBACK["tripped"] = False
-
-
-def _trip_kernel_fallback(exc):
-    if _FALLBACK["tripped"]:
-        return
-    _FALLBACK["tripped"] = True
-    import logging
-
-    logging.getLogger("paddle_tpu.kernels.paged_attention").warning(
-        "Pallas paged_attention kernel failed (%s: %s); falling back to "
-        "the FLAGS_paged_attention=reference path for the rest of this "
-        "process — decode keeps serving, slower",
-        type(exc).__name__, exc)
-    try:
-        from paddle_tpu.observability.metrics_registry import REGISTRY
-
-        REGISTRY.counter(
-            "paddle_tpu_kernel_fallbacks_total",
-            "Pallas kernels abandoned for their reference path this "
-            "process (once per kernel)", labels=("kernel",)
-        ).inc(kernel="paged_attention")
-        from paddle_tpu.observability import blackbox
-
-        if blackbox.ENABLED:
-            blackbox.record(
-                "kernel_fallback", kernel="paged_attention",
-                exc_type=type(exc).__name__,
-                exc_message=str(exc)[:500])
-    except Exception:
-        pass  # degradation bookkeeping must never mask the serve path
-
-
-# the tree-attention verify kernel (speculative decoding) degrades
-# independently of the decode kernel: a broken tree lowering must not
-# take the plain decode path down with it, and vice versa.
-_TREE_FALLBACK = {"tripped": False}
-
-
-def tree_kernel_fallback_tripped():
-    """True once this process abandoned the Pallas tree kernel."""
-    return _TREE_FALLBACK["tripped"]
-
-
-def reset_tree_kernel_fallback():
-    """Re-arm the Pallas tree-attention path (tests)."""
-    _TREE_FALLBACK["tripped"] = False
-
-
-def _trip_tree_fallback(exc):
-    if _TREE_FALLBACK["tripped"]:
-        return
-    _TREE_FALLBACK["tripped"] = True
-    import logging
-
-    logging.getLogger("paddle_tpu.kernels.paged_attention").warning(
-        "Pallas paged_tree_attention kernel failed (%s: %s); falling "
-        "back to the FLAGS_tree_attention=reference path for the rest "
-        "of this process — speculative verify keeps serving, slower",
-        type(exc).__name__, exc)
-    try:
-        from paddle_tpu.observability.metrics_registry import REGISTRY
-
-        REGISTRY.counter(
-            "paddle_tpu_kernel_fallbacks_total",
-            "Pallas kernels abandoned for their reference path this "
-            "process (once per kernel)", labels=("kernel",)
-        ).inc(kernel="paged_tree_attention")
-        from paddle_tpu.observability import blackbox
-
-        if blackbox.ENABLED:
-            blackbox.record(
-                "kernel_fallback", kernel="paged_tree_attention",
-                exc_type=type(exc).__name__,
-                exc_message=str(exc)[:500])
-    except Exception:
-        pass  # degradation bookkeeping must never mask the serve path
-
+# pallas_call names: what a KernelCompileError, a Mosaic error, a
+# profiler trace and the compiled text (op_name) call these kernels
+PAGED_KERNEL_NAME = "paged_decode_attention"
+TREE_KERNEL_NAME = "paged_tree_attention"
 
 _NEG_INF = -1e30
 # a slot whose running max never rose above this saw no visible key
@@ -207,8 +133,8 @@ def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0].astype(jnp.float32) * sm_scale      # [H, dh]
         k = k_ref[0].astype(jnp.float32)                 # [H, ps, dh]
         v = v_ref[0].astype(jnp.float32)
-        sc = jnp.einsum("hd,htd->ht", q, k,
-                        preferred_element_type=jnp.float32)  # [H, ps]
+        sc = jnp.einsum("hnd,htd->hnt", q[:, None, :], k,
+                        preferred_element_type=jnp.float32)[:, 0, :]
         pos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, sc.shape, 1)
         sc = jnp.where(pos < length, sc, _NEG_INF)
@@ -220,7 +146,8 @@ def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = l_prev * alpha + jnp.sum(pexp, axis=-1,
                                               keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-            "ht,htd->hd", pexp, v, preferred_element_type=jnp.float32)
+            "hnt,htd->hnd", pexp[:, None, :], v,
+            preferred_element_type=jnp.float32)[:, 0, :]
         m_ref[...] = m_new
 
     # the ragged bound: a page past the slot's resident length runs NO
@@ -270,6 +197,7 @@ def _paged_pallas(q, k_pool, v_pool, page_table, lengths, sm_scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, dh), q.dtype),
         interpret=interpret,
+        name=PAGED_KERNEL_NAME,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       q, k_pool, v_pool)
 
@@ -286,25 +214,22 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, sm_scale=None,
     Routing mirrors ``flash_attention``: the Pallas kernel on TPU
     targets (``interpret=True`` when forced on CPU), the composed
     gather+softmax reference elsewhere or under
-    ``FLAGS_paged_attention=reference``.
+    ``FLAGS_paged_attention=reference``. A kernel the compiler refuses
+    raises ``KernelCompileError``; nothing stands in for it.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     use_pallas = force_pallas or (not force_reference and _is_tpu_target())
-    if not use_pallas or _FALLBACK["tripped"]:
+    if not use_pallas:
         return paged_attention_reference(
             q, k_pool, v_pool, page_table, lengths, sm_scale=sm_scale)
     try:
         return _paged_pallas(q, k_pool, v_pool, page_table, lengths,
                              sm_scale, interpret=not _is_tpu_target())
-    except Exception as exc:  # noqa: BLE001 - degraded, not dead
-        # Pallas failed at trace/compile time (broken toolchain, an
-        # unsupported shape on this backend): degrade ONCE for the
-        # whole process and serve the request through the composed
-        # reference path — same bits, more HBM traffic
-        _trip_kernel_fallback(exc)
-        return paged_attention_reference(
-            q, k_pool, v_pool, page_table, lengths, sm_scale=sm_scale)
+    except Exception as exc:
+        raise KernelCompileError(
+            PAGED_KERNEL_NAME, (q, k_pool, v_pool, page_table, lengths),
+            exc) from exc
 
 
 def paged_kv_write(k_pool, v_pool, k_new, v_new, page_table, positions):
@@ -484,6 +409,7 @@ def _tree_pallas(q, k_pool, v_pool, page_table, base_lens, anc,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, N, dh), q.dtype),
         interpret=interpret,
+        name=TREE_KERNEL_NAME,
     )(page_table.astype(jnp.int32), base_lens.astype(jnp.int32),
       q, k_pool, v_pool, anc.astype(jnp.int32))
 
@@ -496,14 +422,14 @@ def paged_tree_attention(q, k_pool, v_pool, page_table, base_lens, anc,
     node's own root path (see ``paged_tree_attention_reference`` for
     the full layout contract). Routing mirrors ``paged_attention``:
     Pallas on TPU targets, composed reference on CPU or under
-    ``FLAGS_tree_attention=reference``, with a once-per-process
-    fallback trip on Pallas failure."""
+    ``FLAGS_tree_attention=reference``; a kernel the compiler refuses
+    raises ``KernelCompileError``."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if max_length is None:
         max_length = page_table.shape[1] * k_pool.shape[2]
     use_pallas = force_pallas or (not force_reference and _is_tpu_target())
-    if not use_pallas or _TREE_FALLBACK["tripped"]:
+    if not use_pallas:
         return paged_tree_attention_reference(
             q, k_pool, v_pool, page_table, base_lens, anc,
             sm_scale=sm_scale, max_length=max_length)
@@ -511,11 +437,10 @@ def paged_tree_attention(q, k_pool, v_pool, page_table, base_lens, anc,
         return _tree_pallas(q, k_pool, v_pool, page_table, base_lens,
                             anc, sm_scale, max_length,
                             interpret=not _is_tpu_target())
-    except Exception as exc:  # noqa: BLE001 - degraded, not dead
-        _trip_tree_fallback(exc)
-        return paged_tree_attention_reference(
-            q, k_pool, v_pool, page_table, base_lens, anc,
-            sm_scale=sm_scale, max_length=max_length)
+    except Exception as exc:
+        raise KernelCompileError(
+            TREE_KERNEL_NAME,
+            (q, k_pool, v_pool, page_table, base_lens, anc), exc) from exc
 
 
 def paged_kv_write_block(k_pool, v_pool, k_new, v_new, page_table,

@@ -62,8 +62,8 @@ def fused_label_smooth_ce(logits, label, epsilon=0.0, name=None):
     soft-label tensor, no second log-softmax pass — with the logits kept
     in their network dtype (bf16 under AMP) and f32-accumulated
     reductions. Returns f32 [N, 1] loss. The MFU lever-#1 form of the
-    composed softmax_with_cross_entropy + log_softmax head
-    (docs/MFU_PLAN.md); enable in the bundled transformer with
+    composed softmax_with_cross_entropy + log_softmax head; enable in
+    the bundled transformer with
     FLAGS_fused_ce=1."""
     helper = LayerHelper("fused_label_smooth_ce", name=name)
     loss = helper.create_variable_for_type_inference("float32")

@@ -155,7 +155,7 @@ def build(
     flat_label = fluid.layers.reshape(label, shape=[-1, 1])
     from paddle_tpu import flags as _flags
     if _flags.get("fused_ce"):
-        # MFU lever #1 (docs/MFU_PLAN.md): one fused pass, bf16 logits,
+        # MFU lever #1: one fused pass, bf16 logits,
         # f32-accumulated reductions, hand-written one-pass backward —
         # algebraically identical to the composed head below
         cost = fluid.layers.fused_label_smooth_ce(

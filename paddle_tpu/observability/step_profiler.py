@@ -290,22 +290,28 @@ def _join_cost(sp, steps):
         flops = float(sum(g.flops for g in groups))
         hbm = float(sum(g.bytes_total() for g in groups))
         k = float(max(1, steps))
-        # roofline-predicted step time at nameplate peaks: each fused
-        # group pays max(compute, HBM) — the cost model's pricing rule
-        roof = sum(max(g.flops / mod.PEAK_FLOPS,
-                       g.bytes_total() / mod.HBM_BW) for g in groups)
-        roof_obs = sum(max(g.flops / mod.OBSERVED_PEAK_FLOPS,
-                           g.bytes_total() / mod.HBM_BW) for g in groups)
         entry = {
             "flops": flops / k,
             "hbm_bytes": hbm / k,
-            "roofline_s": roof / k,
-            "roofline_observed_s": roof_obs / k,
             "groups": len(groups),
-            "bound": ("hbm" if hbm / mod.HBM_BW > flops / mod.PEAK_FLOPS
-                      else "mxu"),
-            "nameplate_peak_flops": float(mod.PEAK_FLOPS),
+            # the roofline needs THIS device's published peaks; a
+            # device the chip table does not know gets none
+            "roofline_s": None,
+            "bound": None,
         }
+        from paddle_tpu.observability import telemetry as _telemetry
+
+        peaks = _telemetry.chip_peaks()
+        if peaks and peaks.hbm_bytes_per_sec:
+            # roofline-predicted step time: each fused group pays
+            # max(compute, HBM) — the cost model's pricing rule
+            entry["roofline_s"] = sum(
+                max(g.flops / peaks.bf16_flops,
+                    g.bytes_total() / peaks.hbm_bytes_per_sec)
+                for g in groups) / k
+            entry["bound"] = (
+                "hbm" if hbm / peaks.hbm_bytes_per_sec
+                > flops / peaks.bf16_flops else "mxu")
     except Exception:
         entry = None
     with _lock:
@@ -436,17 +442,18 @@ def finish(sp, steps=1, feeds=None, fetches=None, dispatch_only=False):
         from paddle_tpu.observability import telemetry as _telemetry
 
         achieved = cost["flops"] / step_s
-        # peak: flag override, then the chip table; on hardware the
-        # table misses (CPU proxy runs) fall back to the cost model's
-        # nameplate so MFU stays finite and comparable run-to-run
-        peak = _telemetry.peak_flops() or cost["nameplate_peak_flops"]
         rec["flops_per_step"] = cost["flops"]
         rec["hbm_bytes_per_step"] = cost["hbm_bytes"]
         rec["achieved_flops_per_sec"] = achieved
-        rec["achieved_mfu"] = achieved_mfu = achieved / peak
+        # peak: an explicit FLAGS_peak_tflops, then the chip table. A
+        # device the table does not know (the CPU test backend) has no
+        # MFU and no roofline: not measured, never another chip's
+        peak = _telemetry.peak_flops()
+        rec["achieved_mfu"] = achieved_mfu = (
+            achieved / peak if peak else None)
         rec["roofline_s"] = cost["roofline_s"]
         rec["predicted_ratio"] = (step_s / cost["roofline_s"]
-                                  if cost["roofline_s"] > 0 else None)
+                                  if cost["roofline_s"] else None)
     rec["bound"] = _classify(phases, sp.input_wait, cost)
 
     verdict = None
@@ -489,7 +496,7 @@ def _classify(phases, input_wait, cost):
     """The step's boundedness verdict: ``input`` when starvation
     dominates, ``host`` when host-side phases outweigh device time,
     else the cost model's compute/bandwidth call (``device`` when the
-    executable was never priced)."""
+    executable was never priced or the device has no published peaks)."""
     device_s = phases.get("device", 0.0)
     host_s = sum(v for p, v in phases.items()
                  if p not in ("device", "input_wait"))
@@ -497,7 +504,7 @@ def _classify(phases, input_wait, cost):
         return "input"
     if host_s > device_s:
         return "host"
-    if cost:
+    if cost and cost["bound"]:
         return "compute" if cost["bound"] == "mxu" else "bandwidth"
     return "device"
 

@@ -34,6 +34,7 @@ __all__ = [
     "step_stats", "step_records", "add_step_callback",
     "remove_step_callback", "StepTimer", "record_fetch_materialize",
     "flush", "estimate_flops", "device_memory_bytes", "peak_flops",
+    "chip_peaks", "CHIP_PEAKS",
     "executable_fingerprint", "capture_step_avals",
     "register_flops_from_avals", "record_device_steps",
     "record_device_transfer", "record_pipeline_occupancy",
@@ -49,9 +50,21 @@ _records = collections.deque(maxlen=_RING_CAP)
 _flops = {}              # fingerprint -> flops per step
 _callbacks = []
 
-# bf16 peak TFLOP/s per chip for MFU accounting (bench.py's table).
-_PEAK_TFLOPS = {"tpu v5 lite": 197.0, "tpu v5e": 197.0, "tpu v4": 275.0,
-                "tpu v6 lite": 918.0, "tpu v6e": 918.0}
+# THE peak table — the only one: published per-chip peaks keyed by the
+# ``device_kind`` JAX reports (lowercased). bench.py, peak_flops() below,
+# the step profiler's roofline join and tools/hlo_cost_model.py all read
+# it. Source: Google Cloud TPU documentation, system-architecture pages
+# ("TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM; "TPU v4": 275 TFLOP/s bf16;
+# "TPU v6e": 918 TFLOP/s bf16). A device that is not here HAS NO PEAK:
+# its MFU and roofline share are "not measured", never another chip's
+# nameplate.
+ChipPeaks = collections.namedtuple(
+    "ChipPeaks", ["bf16_flops", "hbm_bytes_per_sec"])
+CHIP_PEAKS = {
+    "tpu v5 lite": ChipPeaks(197e12, 819e9),
+    "tpu v4": ChipPeaks(275e12, None),
+    "tpu v6 lite": ChipPeaks(918e12, None),
+}
 
 # step-time buckets: 100us .. 100s (training steps span ms..minutes)
 _STEP_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
@@ -401,28 +414,27 @@ def _percentile(sorted_vals, q):
     return sorted_vals[k]
 
 
-def peak_flops(device=None):
-    """Peak FLOP/s for MFU accounting: FLAGS_peak_tflops override first,
-    then the chip table keyed on device_kind; None when unknown (CPU)."""
-    from paddle_tpu import flags
-
-    try:
-        override = float(flags.get("peak_tflops"))
-    except (KeyError, TypeError, ValueError):
-        override = 0.0
-    if override > 0:
-        return override * 1e12
-    try:
+def chip_peaks(device=None):
+    """The table's entry for ``device`` (default: this process's first
+    device), or None for a device that is not in it (CPU included)."""
+    if device is None:
         import jax
 
-        device = device or jax.local_devices()[0]
-        kind = (getattr(device, "device_kind", "") or "").lower()
-        for k, v in _PEAK_TFLOPS.items():
-            if k in kind:
-                return v * 1e12
-    except Exception:
-        pass
-    return None
+        device = jax.local_devices()[0]
+    return CHIP_PEAKS.get((device.device_kind or "").lower())
+
+
+def peak_flops(device=None):
+    """Peak FLOP/s for MFU accounting: an explicit FLAGS_peak_tflops
+    first, then the chip table; None for a device the table does not
+    know — the caller then reports no MFU."""
+    from paddle_tpu import flags
+
+    override = float(flags.get("peak_tflops"))
+    if override > 0:
+        return override * 1e12
+    peaks = chip_peaks(device)
+    return peaks.bf16_flops if peaks else None
 
 
 def step_stats(peak=None):

@@ -6,13 +6,17 @@ first-class op lowered to the Pallas flash kernel on TPU / fused XLA math
 elsewhere, because it sets the long-context performance ceiling.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from paddle_tpu.core.op_registry import register_op
 from paddle_tpu.kernels.flash_attention import (
     flash_attention,
     flash_attention_reference,
+    uses_kernel,
 )
 
 
@@ -80,13 +84,50 @@ def _lower_sdpa(ctx, ins, attrs):
         impl = flags.get("attention_impl")
     # impl == "reference" routes through the same entry with
     # force_reference so the grouped-K/V handling lives in ONE place
-    return flash_attention(
-        q, k, v, causal=causal, sm_scale=sm_scale, mask=mask,
-        force_reference=(impl == "reference"),
-        force_pallas=(impl == "pallas"),
+    routing = dict(force_reference=(impl == "reference"),
+                   force_pallas=(impl == "pallas"))
+    attend = functools.partial(
+        flash_attention, causal=causal, sm_scale=sm_scale,
         kv_group=int(attrs.get("kv_group", 1)),
-        window=int(attrs.get("window", 0)),
-    )
+        window=int(attrs.get("window", 0)), **routing)
+    from paddle_tpu.core.lowering import ambient_mesh
+
+    mesh = ambient_mesh()
+    if (mesh is not None and mesh.size > 1
+            and uses_kernel(mask, **routing)):
+        return _kernel_per_device(mesh, attend, q, k, v, mask)
+    return attend(q, k, v, mask=mask)
+
+
+def _kernel_per_device(mesh, attend, q, k, v, mask):
+    """GSPMD cannot partition a Mosaic kernel (the lowering refuses:
+    "wrap the call in a shard_map"), so under a ParallelExecutor mesh
+    the kernel runs per device on that device's own batch x heads block:
+    batch over the mesh's batch axes, heads over its tensor axis, each
+    only where it divides evenly (else that dim stays whole on every
+    device). Attention has no cross-batch or cross-head term, so the
+    body needs no collective and its backward shards the same way."""
+    from jax.sharding import PartitionSpec as P
+
+    sizes = dict(mesh.shape)
+    batch = tuple(a for a in ("data", "fsdp") if sizes.get(a, 1) > 1)
+    if q.shape[0] % int(np.prod([sizes[a] for a in batch] or [1])):
+        batch = ()
+    heads = next(
+        (a for a in ("tp", "model") if sizes.get(a, 1) > 1
+         and q.shape[1] % sizes[a] == 0 and k.shape[1] % sizes[a] == 0),
+        None)
+    qkv = P(batch or None, heads, None, None)
+    args, specs = [q, k, v], [qkv, qkv, qkv]
+    if mask is not None:
+        # a key-validity mask ([B, 1, 1, S] — full masks never reach
+        # the kernel) follows the batch
+        args.append(mask)
+        specs.append(P(batch or None, None, None, None))
+    return jax.shard_map(
+        lambda q_, k_, v_, *m: attend(q_, k_, v_, mask=m[0] if m else None),
+        mesh=mesh, in_specs=tuple(specs), out_specs=qkv,
+        check_vma=False)(*args)
 
 
 register_op(

@@ -50,8 +50,8 @@ def _lower_fused_label_smooth_ce(ctx, ins, attrs):
     The composed head (softmax_with_cross_entropy + log_softmax +
     scale/add, models/transformer.py) makes ~5 logits-shaped passes and
     — because those ops are AMP-blacklisted — materializes them in f32:
-    ~10 GB/step of HBM traffic at bench shapes (docs/MFU_PLAN.md lever
-    #1, from the committed cost-model artifacts). This op keeps the
+    ~10 GB/step of HBM traffic at bench shapes (predicted by
+    tools/hlo_cost_model.py; not measured on the chip). This op keeps the
     logits in their network dtype (bf16 under AMP) and uses the
     factored identity
 

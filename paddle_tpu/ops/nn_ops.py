@@ -38,8 +38,8 @@ def _lower_conv2d(ctx, ins, attrs):
         # transpose sandwich. Between consecutive convs the out-transpose
         # and the next in-transpose cancel in XLA, so a conv-dominated
         # block effectively runs NHWC end to end while the Program stays
-        # NCHW at every op boundary. Numerics unchanged; per-hardware win
-        # measured by the bench (BENCH_NOTES round-3 section).
+        # NCHW at every op boundary. Numerics unchanged; the per-hardware
+        # win is for the bench to measure.
         out = jax.lax.conv_general_dilated(
             jnp.transpose(x, (0, 2, 3, 1)),
             w,

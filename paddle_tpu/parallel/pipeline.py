@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.parallel import _compat
-
 
 def stack_stage_params(stage_params_list):
     """[pytree per stage] -> one pytree with a leading stage dim (what
@@ -56,7 +54,8 @@ def _gpipe_shard(params, x, stage_fn, axis_name):
 
     # varying-marked zero activation: used for carries and as the cond
     # bubble branch, whose output type must match stage_fn's (varying)
-    zero_act = _compat.vary(jnp.zeros_like(x[0]), axis_name)
+    zero_act = jax.lax.pcast(jnp.zeros_like(x[0]), (axis_name,),
+                             to="varying")
 
     def tick(carry, t):
         prev_out, outbuf = carry
@@ -87,7 +86,7 @@ def _gpipe_shard(params, x, stage_fn, axis_name):
         outbuf = jax.lax.dynamic_update_index_in_dim(outbuf, banked, slot, 0)
         return (y, outbuf), None
 
-    outbuf0 = _compat.vary(jnp.zeros_like(x), axis_name)
+    outbuf0 = jax.lax.pcast(jnp.zeros_like(x), (axis_name,), to="varying")
     (_, outbuf), _ = jax.lax.scan(
         tick, (zero_act, outbuf0), jnp.arange(ticks)
     )
@@ -130,7 +129,6 @@ def gpipe(stage_fn, stage_params, x, mesh, axis_name="pipe",
                 "equal to the pipe axis size %d, got shape %s (one stage "
                 "per device; stack with stack_stage_params, fold deeper "
                 "networks into stage_fn)" % (n, l.shape))
-    shard_map = _compat.shard_map()
     if param_specs is None:
         param_specs = jax.tree_util.tree_map(
             lambda _: P(axis_name), stage_params
@@ -157,7 +155,7 @@ def gpipe(stage_fn, stage_params, x, mesh, axis_name="pipe",
     else:
         x_spec = P()
         out_spec = P(axis_name)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _gpipe_shard, stage_fn=stage_fn, axis_name=axis_name
         ),

@@ -59,7 +59,6 @@ from paddle_tpu.core import op_registry
 from paddle_tpu.core.lowering import BlockLowerer
 from paddle_tpu.core.op_registry import LowerContext, normalize_outputs
 from paddle_tpu.framework import OP_ROLE_ATTR_NAME, OpRole
-from paddle_tpu.parallel import _compat
 
 _NON_SEMANTIC_ATTRS = (OP_ROLE_ATTR_NAME, "op_role_var", "__rng_id__")
 
@@ -445,8 +444,8 @@ class PipelinedProgram(object):
         batch_axis = self.batch_axis
 
         def _vary(x):
-            x = _compat.vary(x, axis)
-            return _compat.vary(x, batch_axis) if batch_axis else x
+            axes = (axis, batch_axis) if batch_axis else (axis,)
+            return jax.lax.pcast(x, axes, to="varying")
 
         def shard_body(vec, feeds, key):
             # vec [1, L]; feeds [M, micro_local, ...]
@@ -494,9 +493,8 @@ class PipelinedProgram(object):
                 total = jax.lax.pmean(total, batch_axis)
             return total
 
-        shard_map = _compat.shard_map()
         feed_spec = (P(None, batch_axis) if batch_axis else P())
-        pipeline_loss = shard_map(
+        pipeline_loss = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P(axis), {k: feed_spec for k in feed_specs}, P()),
             out_specs=P(),

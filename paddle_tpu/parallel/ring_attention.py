@@ -25,10 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.parallel import _compat
-
 _NEG_INF = -1e30
-_shard_map = _compat.shard_map
 
 
 def _ring_attention_shard(q, k, v, axis_name, causal, sm_scale):
@@ -43,7 +40,7 @@ def _ring_attention_shard(q, k, v, axis_name, causal, sm_scale):
     def _vary(x):
         # Mark device-uniform initial carries as varying over the ring axis
         # (shard_map's varying-axis type system requires carry in/out match).
-        return _compat.vary(x, axis_name)
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     acc0 = _vary(jnp.zeros(q.shape[:3] + (d,), jnp.float32))
     m0 = _vary(jnp.full(q.shape[:3] + (1,), _NEG_INF, jnp.float32))
@@ -191,8 +188,6 @@ def ring_attention(q, k, v, mesh, axis_name="data", causal=False,
     """
     from paddle_tpu.kernels.flash_attention import _is_tpu_target
 
-    shard_map = _shard_map()
-
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if impl not in ("auto", "flash", "reference"):
@@ -210,18 +205,11 @@ def ring_attention(q, k, v, mesh, axis_name="data", causal=False,
                                      sm_scale)
 
         # pallas_call out_shapes carry no varying-axis (vma) annotation,
-        # which newer shard_map's type checker rejects; the check is a
-        # static lint, not a semantic change — disable it for this body
-        # (check_rep is its pre-rename twin on older jax)
-        try:
-            fn = shard_map(body, check_vma=False, **sm_kwargs)
-        except TypeError:  # older jax: the kwarg is named check_rep
-            try:
-                fn = shard_map(body, check_rep=False, **sm_kwargs)
-            except TypeError:
-                fn = shard_map(body, **sm_kwargs)
+        # which shard_map's type checker rejects; the check is a static
+        # lint, not a semantic change — disable it for this body
+        fn = jax.shard_map(body, check_vma=False, **sm_kwargs)
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(
                 _ring_attention_shard, axis_name=axis_name, causal=causal,
                 sm_scale=sm_scale),
@@ -256,8 +244,6 @@ def _ulysses_shard(q, k, v, axis_name, causal, sm_scale):
 def ulysses_attention(q, k, v, mesh, axis_name="data", causal=False,
                       sm_scale=None):
     """All-to-all (DeepSpeed-Ulysses style) sequence-parallel attention."""
-    shard_map = _shard_map()
-
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     n = mesh.shape[axis_name]
@@ -267,7 +253,7 @@ def ulysses_attention(q, k, v, mesh, axis_name="data", causal=False,
             % (q.shape[1], n)
         )
     spec = P(None, None, axis_name, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ulysses_shard,
             axis_name=axis_name,

@@ -483,6 +483,12 @@ class ParallelExecutor(object):
         self._cache[key] = cp
         return cp
 
+    def compiled_text(self):
+        """Optimized-HLO text of every sharded executable this executor
+        has run (per-device shapes, collectives and kernels as the
+        compiler left them) — see ``CompiledProgram.compiled_text``."""
+        return [cp.compiled_text() for cp in self._cache.values()]
+
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
         # forensics shell (same contract as Executor.run): armed for the
         # watchdog — a multichip step that never returns is THE hang this

@@ -261,8 +261,8 @@ def _emit_exec_cache_report(print_report):
         % (
             st["fresh_compiles"], st["persistent_hits"], st["aot_hits"],
             st["compile_seconds_cold"], st["compile_seconds_warm"],
-            " (dir: %s)" % st["cache_dir"] if st["enabled"] else
-            " (persistence off: FLAGS_exec_cache_dir unset)",
+            " (xla cache: %s; aot images: %s)"
+            % (st["xla_cache_dir"] or "off", st["cache_dir"] or "off"),
         )
     )
     logger.info("%s", msg)

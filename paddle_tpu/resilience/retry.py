@@ -1,7 +1,7 @@
 """Classified retry policy: transient failures backed off and retried,
 user errors surfaced immediately, every retry counted and filed.
 
-The failure taxonomy production TPU fleets actually produce splits
+The failure classes production TPU fleets actually produce split
 cleanly in two. *Transient*: a flaky NFS read under the persistent exec
 cache, an RPC reset while the elastic master restarts, a preempted
 backend compile — retrying after a backoff is the correct (and only)

@@ -507,6 +507,13 @@ class SlotDecodeSession(object):
             self._after_dispatch()
 
     @property
+    def step_program(self):
+        """The decode-step Program every ``step()`` dispatches — the
+        handle for ``Executor.compiled_text`` (which attention kernel
+        the compiled step really holds)."""
+        return self._step_prog
+
+    @property
     def in_dispatch(self):
         """True while a public op (admit/step) is mutating state — the
         window a preemption snapshot must NOT land inside."""

@@ -11,10 +11,15 @@ here the same disk layers are exercised in-process by purging the
 in-memory registries between runs.
 """
 
+import json
 import os
+import subprocess
+import sys
 
+import jax
 import numpy as np
 import pytest
+from jax.experimental.compilation_cache import compilation_cache
 
 import paddle_tpu as fluid
 from paddle_tpu import flags, unique_name
@@ -121,7 +126,9 @@ def test_each_trace_flag_toggle_recompiles():
     for name in TRACE_FLAGS:
         old = flags.get(name)
         flip = {"attention_impl": "reference",
-                "flash_backward": "reference"}.get(name, True)
+                "flash_backward": "reference",
+                "paged_attention": "reference",
+                "tree_attention": "reference"}.get(name, True)
         assert flip != old, "flag %s: test flip value equals default" % name
         misses = _trace_misses()
         flags.set_flag(name, flip)
@@ -255,13 +262,17 @@ def _purge_in_memory():
     """Simulate a fresh process: drop every in-memory executable handle so
     the next run can only be served by the on-disk layers."""
     executor_mod._shared_executables.clear()
-    exec_cache._reset_jax_cache()
+    compilation_cache.reset_cache()
 
 
 @pytest.fixture
-def cache_dir(tmp_path):
+def cache_dir(tmp_path, monkeypatch):
+    """Both layers under one tmp dir: the flag places the AOT images,
+    the environment places JAX's cache (the only way to place it)."""
     d = str(tmp_path / "exec_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", os.path.join(d, "xla"))
     old = flags.get("exec_cache_dir")
+    old_xla = jax.config.jax_compilation_cache_dir
     flags.set_flag("exec_cache_dir", d)
     exec_cache.configure()
     # executables compiled by EARLIER tests (while persistence was off)
@@ -272,7 +283,11 @@ def cache_dir(tmp_path):
         yield d
     finally:
         flags.set_flag("exec_cache_dir", old)
-        exec_cache.configure()  # re-disable persistence for later tests
+        exec_cache.configure()
+        # the library never moves or disables JAX's cache once on;
+        # later tests want it as they found it
+        jax.config.update("jax_compilation_cache_dir", old_xla)
+        compilation_cache.reset_cache()
 
 
 def test_warm_start_loads_aot_image(cache_dir):
@@ -343,3 +358,61 @@ def test_cache_stats_exported_through_profiler(cache_dir):
               "compile_seconds_cold", "compile_seconds_warm"):
         assert k in st
     assert st["compile_seconds_cold"] + st["compile_seconds_warm"] >= 0
+
+
+# -- cache placement ---------------------------------------------------------
+
+_PLACEMENT_PROBE = """
+import json, sys
+import jax, jax.numpy as jnp
+from paddle_tpu.core import exec_cache
+
+exec_cache.configure(sys.argv[1] or None)
+if not sys.argv[1]:
+    exec_cache.enable_xla_cache()  # what chip_smoke.py and bench.py do
+jax.jit(lambda x: x * 2 + 1)(jnp.arange(8.0)).block_until_ready()
+print(json.dumps({
+    "dir": jax.config.jax_compilation_cache_dir,
+    "enabled": bool(jax.config.jax_enable_compilation_cache),
+    "stats": exec_cache.stats(),
+}))
+"""
+
+
+def _placement_probe(tmp_path, aot_dir="", **env_overrides):
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "FLAGS_exec_cache_dir")}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo, **env_overrides)
+    out = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_PROBE, aot_dir], env=env,
+        cwd=str(tmp_path), capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_xla_cache_stays_where_the_environment_put_it(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the effective directory equals it
+    — even with the AOT flag naming another directory — the cache is
+    enabled after a compile, and the compile landed there."""
+    outside = str(tmp_path / "placed_from_outside")
+    got = _placement_probe(tmp_path, aot_dir=str(tmp_path / "aot_flag"),
+                           JAX_COMPILATION_CACHE_DIR=outside)
+    assert got["dir"] == outside and got["enabled"]
+    assert got["stats"]["xla_cache_dir"] == outside
+    assert got["stats"]["persistent_misses"] >= 1
+    assert os.listdir(outside), "the compile was not cached there"
+    assert os.listdir(str(tmp_path / "aot_flag")) == ["aot"]
+
+
+def test_xla_cache_defaults_to_one_fixed_path_in_the_checkout(tmp_path):
+    """Unset: two separate process starts (run from an unrelated cwd)
+    resolve the same fixed in-checkout path, and the second start is
+    served from what the first wrote."""
+    first = _placement_probe(tmp_path)
+    second = _placement_probe(tmp_path)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert first["dir"] == second["dir"] == os.path.join(repo, ".jax_cache")
+    assert first["enabled"] and second["enabled"]
+    assert second["stats"]["persistent_hits"] >= 1
+    assert second["stats"]["fresh_compiles"] == 0

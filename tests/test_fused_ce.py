@@ -1,4 +1,4 @@
-"""fused_label_smooth_ce: the MFU lever-#1 op (docs/MFU_PLAN.md) must be
+"""fused_label_smooth_ce: the MFU lever-#1 op must be
 algebraically identical to the composed head it replaces
 (softmax_with_cross_entropy + log_softmax smoothing term,
 models/transformer.py), in loss AND in gradients."""

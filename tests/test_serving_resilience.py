@@ -537,9 +537,12 @@ def test_pool_acquire_is_a_chaos_site():
     assert pool.acquire() in (1, 2, 3)
 
 
-# -- kernel degradation ------------------------------------------------------
+# -- kernel failure is an error, not a degradation ------------------------------------------------------
 
-def test_paged_attention_falls_back_once_per_process(monkeypatch):
+def test_paged_attention_kernel_failure_propagates_typed(monkeypatch):
+    """No trip to the reference any more: a kernel the toolchain
+    refuses propagates, typed, naming the kernel and its operand
+    shapes — on every call, and the fallback counter never appears."""
     from paddle_tpu.kernels import paged_attention as pa
 
     rng = np.random.RandomState(5)
@@ -548,8 +551,6 @@ def test_paged_attention_falls_back_once_per_process(monkeypatch):
     vp = rng.randn(3, 2, 4, 8).astype("float32")
     table = np.asarray([[1, 1], [2, 2]], "int32")
     lengths = np.asarray([3, 4], "int32")
-    want = np.asarray(pa.paged_attention_reference(
-        q, kp, vp, table, lengths))
 
     calls = {"n": 0}
 
@@ -557,26 +558,22 @@ def test_paged_attention_falls_back_once_per_process(monkeypatch):
         calls["n"] += 1
         raise RuntimeError("pallas toolchain exploded")
 
-    pa.reset_kernel_fallback()
     monkeypatch.setattr(pa, "_paged_pallas", boom)
-    try:
-        got = np.asarray(pa.paged_attention(
-            q, kp, vp, table, lengths, force_pallas=True))
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-        assert pa.kernel_fallback_tripped()
-        # second call: the tripped flag routes straight to reference —
-        # the broken kernel is attempted ONCE per process
-        np.asarray(pa.paged_attention(q, kp, vp, table, lengths,
-                                      force_pallas=True))
-        assert calls["n"] == 1
-        count = REGISTRY.counter(
-            "paddle_tpu_kernel_fallbacks_total",
-            "Pallas kernels abandoned for their reference path this "
-            "process (once per kernel)",
-            labels=("kernel",)).value(kernel="paged_attention")
-        assert count >= 1
-    finally:
-        pa.reset_kernel_fallback()
+    for attempt in (1, 2):
+        with pytest.raises(pa.KernelCompileError) as err:
+            pa.paged_attention(q, kp, vp, table, lengths,
+                               force_pallas=True)
+        assert calls["n"] == attempt  # nothing remembers the failure
+        assert err.value.kernel == pa.PAGED_KERNEL_NAME
+        assert ((3, 2, 4, 8), "float32") in err.value.shapes
+        assert "pallas toolchain exploded" in str(err.value)
+        assert isinstance(err.value.__cause__, RuntimeError)
+    # the explicit oracle is untouched by a broken kernel
+    np.asarray(pa.paged_attention(q, kp, vp, table, lengths,
+                                  force_reference=True))
+    assert calls["n"] == 2
+    assert "paddle_tpu_kernel_fallbacks_total" not in \
+        REGISTRY.to_prometheus()
 
 
 # -- watchdog over serving dispatch ------------------------------------------

@@ -143,33 +143,30 @@ def test_tree_kernel_branch_isolation():
     assert np.abs(out3[1, :, 2] - out[1, :, 2]).max() > 1e-4
 
 
-def test_tree_kernel_falls_back_once_per_process(monkeypatch):
+def test_tree_kernel_failure_propagates_typed(monkeypatch):
+    """No trip to the reference any more: a refused tree kernel
+    propagates, typed, naming the kernel and its operand shapes."""
     args, kw = _tree_case()
-    want = np.asarray(pa.paged_tree_attention_reference(*args, **kw))
     calls = {"n": 0}
 
     def boom(*a, **k):
         calls["n"] += 1
         raise RuntimeError("pallas toolchain exploded")
 
-    pa.reset_tree_kernel_fallback()
     monkeypatch.setattr(pa, "_tree_pallas", boom)
-    try:
-        got = np.asarray(pa.paged_tree_attention(*args,
-                                                 force_pallas=True, **kw))
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-        assert pa.tree_kernel_fallback_tripped()
-        np.asarray(pa.paged_tree_attention(*args, force_pallas=True,
-                                           **kw))
-        assert calls["n"] == 1  # attempted ONCE per process
-        count = REGISTRY.counter(
-            "paddle_tpu_kernel_fallbacks_total",
-            "Pallas kernels abandoned for their reference path this "
-            "process (once per kernel)",
-            labels=("kernel",)).value(kernel="paged_tree_attention")
-        assert count >= 1
-    finally:
-        pa.reset_tree_kernel_fallback()
+    for attempt in (1, 2):
+        with pytest.raises(pa.KernelCompileError) as err:
+            pa.paged_tree_attention(*args, force_pallas=True, **kw)
+        assert calls["n"] == attempt  # nothing remembers the failure
+        assert err.value.kernel == pa.TREE_KERNEL_NAME
+        assert (tuple(args[0].shape), str(args[0].dtype)) \
+            in err.value.shapes
+        assert "pallas toolchain exploded" in str(err.value)
+    np.asarray(pa.paged_tree_attention(*args, force_reference=True,
+                                       **kw))
+    assert calls["n"] == 2
+    assert "paddle_tpu_kernel_fallbacks_total" not in \
+        REGISTRY.to_prometheus()
 
 
 # -- drafters ----------------------------------------------------------------

@@ -118,13 +118,43 @@ def test_multi_step_record_covers_wall_and_joins_mfu():
     assert r["step_s"] == pytest.approx(r["wall_s"] / 32)
     assert r["feed_bytes"] == _feed()["x"].nbytes
     assert r["fetch_bytes"] > 0
-    # the one-shot cost join priced this executable: per-step FLOPs,
-    # achieved-FLOP/s, achieved-MFU, all finite and positive
+    # the one-shot cost join priced this executable: per-step FLOPs and
+    # achieved-FLOP/s, finite and positive. The CPU test backend is not
+    # in the chip table, so it has NO MFU and NO roofline — never the
+    # nameplate of some chip it is not
     assert r["flops_per_step"] > 0
+    assert math.isfinite(r["achieved_flops_per_sec"])
     assert r["achieved_flops_per_sec"] > 0
-    assert math.isfinite(r["achieved_mfu"]) and r["achieved_mfu"] > 0
+    assert r["achieved_mfu"] is None
+    assert r["roofline_s"] is None and r["predicted_ratio"] is None
     assert r["bound"] in ("compute", "bandwidth", "input", "host", "device")
     assert r["fingerprint"] in step_profiler.cost_table()
+
+
+def test_unknown_device_has_no_mfu_until_a_peak_is_stated():
+    """An unknown device yields no MFU; an explicit FLAGS_peak_tflops is
+    the only way a device outside the chip table gets one."""
+    from paddle_tpu import flags
+    from paddle_tpu.observability import telemetry
+
+    assert telemetry.chip_peaks() is None  # cpu is not in the table
+    assert telemetry.peak_flops() is None
+    assert telemetry.CHIP_PEAKS["tpu v5 lite"].bf16_flops == 197e12
+    main, startup, loss = _build_mlp()
+    exe = fluid.Executor(fluid.CPUPlace())
+    flags.set_flag("peak_tflops", 2.0)
+    step_profiler.enable(True)
+    try:
+        assert telemetry.peak_flops() == 2e12
+        exe.run(startup)
+        exe.run_multi_step(main, 8, feed=_feed(), fetch_list=[loss])
+    finally:
+        step_profiler.enable(False)
+        flags.set_flag("peak_tflops", 0.0)
+    r = [r for r in step_profiler.records() if r["steps"] == 8][0]
+    assert r["achieved_mfu"] == pytest.approx(
+        r["achieved_flops_per_sec"] / 2e12)
+    assert r["roofline_s"] is None  # the flag states FLOP/s, not HBM
 
 
 def test_cost_join_is_one_shot_per_executable():
@@ -271,7 +301,7 @@ def test_jsonl_flush_and_perf_ledger_round_trip(tmp_path):
     assert entry["phase_coverage"] >= 0.9
     assert entry["step_ms"]["p50"] > 0
     assert entry["regressions"] == 0
-    assert math.isfinite(entry["achieved_mfu"])
+    assert "achieved_mfu" not in entry  # cpu: not measured
 
     ledger = tmp_path / "ledger.jsonl"
     for label in ("a", "b"):

@@ -1,26 +1,42 @@
-"""Cross-platform TPU export of every Pallas kernel — no chip required.
+"""Every Pallas kernel, compiled by the chip's own compiler — no chip.
 
-The round-3 hardware window lost its kernel verdicts to a Mosaic
-block-shape error that only surfaced on the real TPU (the LSTM block spec
-violated the (8, 128) trailing-dim tiling rule; fixed in a2f4042). That
-class of bug is catchable WITHOUT hardware: ``jax.export`` with
-``platforms=["tpu"]`` runs the full Pallas->Mosaic lowering, including
-``_check_block_mappings``, on any host. Every Pallas kernel configuration
-the framework ships is exported here so a tiling regression can never
-again wait for a hardware window to be discovered.
+The TPU compiler ships with the installed libtpu and compiles for a chip
+that is *described*, not attached (``topologies.get_topology_desc``), so
+Mosaic's verdict on a kernel is available on the CPU test host. Each
+case here runs the kernel entry point with ``interpret=False`` — the
+entry points pick interpret mode from ``core.lowering.is_tpu_target()``,
+which these tests steer by pinning the ambient compile platform to
+"tpu", exactly what the executor does for a program placed on a TPU
+device — lowers it for a described ``v5e`` chip, compiles it, and
+asserts the Mosaic ``tpu_custom_call`` is in the compiled text. An
+interpret-mode lowering (what ``force_pallas=True`` alone gives on this
+host) holds no such call and proves nothing about the chip: the paged
+decode kernel passed every interpret-mode test while Mosaic refused it
+at every shape.
+
+A passing compile is not a chip run: nothing executes, so these say
+nothing about results or times.
 
 Reference analogy: paddle/fluid/operators/math/jit_kernel_test.cc compiles
 every JIT kernel variant in CI regardless of the deploy target.
 """
 
+import contextlib
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-import importlib
-
+from paddle_tpu.core import lowering
 from paddle_tpu.kernels import gru_cell, lstm_cell
+from paddle_tpu.kernels import paged_attention as pa
 
 # paddle_tpu.kernels re-exports the flash_attention FUNCTION, which
 # shadows the submodule for every import-statement form; importlib
@@ -28,10 +44,64 @@ from paddle_tpu.kernels import gru_cell, lstm_cell
 fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
 
-def _export_tpu(fn, *args):
-    """Lower ``fn`` for the TPU platform (Mosaic lowering included)."""
-    return jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
+@pytest.fixture(scope="module")
+def v5e():
+    """The described (not attached) v5e 2x2 host's devices."""
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu, or one that cannot describe it
+        pytest.skip("cannot describe a v5e topology here: %s" % exc)
+    return list(topo.devices)
 
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to JAX's persistent
+    cache but cannot be read back without the chip (the next one warns
+    and recompiles), so the cache is off around these tests."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@contextlib.contextmanager
+def _tpu_target():
+    """What CompiledProgram does for a program on a TPU Place: the
+    kernel entry points under this see a TPU target, so interpret=False."""
+    lowering._AMBIENT_PLATFORM.append("tpu")
+    try:
+        yield
+    finally:
+        lowering._AMBIENT_PLATFORM.pop()
+
+
+def _compile_for(sharding, fn, *specs):
+    """Trace ``fn`` as for a TPU target, compile it for the described
+    chip(s), and return the compiled text with the Mosaic call count
+    checked non-zero."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in specs]
+
+    def traced(*a):
+        with _tpu_target():
+            return fn(*a)
+
+    text = jax.jit(traced).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, (
+        "no Mosaic kernel in the compiled program (interpret-mode "
+        "lowering or a reference path stood in)")
+    return text
+
+
+def _compile_v5e(v5e, fn, *specs):
+    return _compile_for(SingleDeviceSharding(v5e[0]), fn, *specs)
+
+
+F32, BF16 = jnp.float32, jnp.bfloat16
 
 # the kernel_bench sweep's smallest shape plus a non-multiple batch that
 # exercises the pad-to-block path
@@ -39,104 +109,140 @@ _RNN_SHAPES = [(32, 128, 256), (5, 16, 256)]
 
 
 @pytest.mark.parametrize("bs,seq,d", _RNN_SHAPES)
-def test_lstm_lowers_for_tpu(bs, seq, d):
-    xw = jnp.zeros((bs, seq, 4 * d), jnp.float32)
-    w_h = jnp.zeros((d, 4 * d), jnp.float32)
-    bias = jnp.zeros((4 * d,), jnp.float32)
-
-    _export_tpu(
+def test_lstm_lowers_for_tpu(v5e, bs, seq, d):
+    _compile_v5e(
+        v5e,
         lambda xw, w_h, bias: lstm_cell.fused_lstm(
             xw, w_h, bias, force_pallas=True),
-        xw, w_h, bias)
+        ((bs, seq, 4 * d), F32), ((d, 4 * d), F32), ((4 * d,), F32))
 
 
-def test_lstm_peephole_masked_lowers_for_tpu():
+def test_lstm_peephole_masked_lowers_for_tpu(v5e):
     bs, seq, d = 8, 16, 256
-    xw = jnp.zeros((bs, seq, 4 * d), jnp.float32)
-    w_h = jnp.zeros((d, 4 * d), jnp.float32)
-    bias = jnp.zeros((4 * d,), jnp.float32)
-    peep = tuple(jnp.zeros((d,), jnp.float32) for _ in range(3))
-    mask = jnp.ones((bs, seq), jnp.float32)
 
-    _export_tpu(
-        lambda xw, w_h, bias: lstm_cell.fused_lstm(
-            xw, w_h, bias, peephole=peep, mask=mask, force_pallas=True),
-        xw, w_h, bias)
+    def fn(xw, w_h, bias, p0, p1, p2, mask):
+        return lstm_cell.fused_lstm(
+            xw, w_h, bias, peephole=(p0, p1, p2), mask=mask,
+            force_pallas=True)
+
+    _compile_v5e(
+        v5e, fn, ((bs, seq, 4 * d), F32), ((d, 4 * d), F32),
+        ((4 * d,), F32), ((d,), F32), ((d,), F32), ((d,), F32),
+        ((bs, seq), F32))
 
 
 @pytest.mark.parametrize("bs,seq,d", _RNN_SHAPES)
-def test_gru_lowers_for_tpu(bs, seq, d):
-    xw = jnp.zeros((bs, seq, 3 * d), jnp.float32)
-    w_gate = jnp.zeros((d, 2 * d), jnp.float32)
-    w_cand = jnp.zeros((d, d), jnp.float32)
-    bias = jnp.zeros((3 * d,), jnp.float32)
-
-    _export_tpu(
+def test_gru_lowers_for_tpu(v5e, bs, seq, d):
+    _compile_v5e(
+        v5e,
         lambda xw, wg, wc, b: gru_cell.fused_gru(
             xw, wg, wc, b, force_pallas=True),
-        xw, w_gate, w_cand, bias)
+        ((bs, seq, 3 * d), F32), ((d, 2 * d), F32), ((d, d), F32),
+        ((3 * d,), F32))
 
 
-def _qkv(b, h, t, d, kv_heads=None):
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(b, h, t, d), jnp.float32)
-    k = jnp.asarray(rng.randn(b, kv_heads or h, t, d), jnp.float32)
-    v = jnp.asarray(rng.randn(b, kv_heads or h, t, d), jnp.float32)
-    return q, k, v
+def _flash_grad(**kw):
+    def loss(q, k, v, *mask):
+        masked = dict(kw, mask=mask[0] > 0) if mask else kw
+        return fa.flash_attention(q, k, v, force_pallas=True,
+                                  **masked).astype(F32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _assert_flash_fwd_bwd(text):
+    """Forward, dK/dV and dQ are one named Mosaic call each."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in (fa.FWD_KERNEL_NAME, fa.BWD_DKV_KERNEL_NAME,
+                 fa.BWD_DQ_KERNEL_NAME):
+        assert any(name in line for line in calls), (
+            "%s is not a Mosaic call of the compiled program" % name)
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_fwd_bwd_lowers_for_tpu(causal):
-    q, k, v = _qkv(1, 2, 256, 64)
-
-    def loss(q, k, v):
-        return fa.flash_attention(
-            q, k, v, causal=causal, force_pallas=True).sum()
-
-    _export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+def test_flash_fwd_bwd_lowers_for_tpu(v5e, causal):
+    qkv = ((1, 2, 256, 64), F32)
+    text = _compile_v5e(v5e, _flash_grad(causal=causal), qkv, qkv, qkv)
+    _assert_flash_fwd_bwd(text)
 
 
-def test_flash_gqa_window_lowers_for_tpu():
+def test_flash_gqa_window_lowers_for_tpu(v5e):
     # grouped-query (2 query heads per kv head) + sliding window + key
-    # mask: the full round-3 feature set through fwd AND the FA2 backward
-    q, k, v = _qkv(1, 4, 256, 64, kv_heads=2)
-    mask = jnp.ones((1, 256), bool)
-
-    def loss(q, k, v):
-        return fa.flash_attention(
-            q, k, v, causal=True, mask=mask, kv_group=2, window=128,
-            force_pallas=True).sum()
-
-    _export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    # mask: the full feature set through fwd AND the FA2 backward
+    q, kv = ((1, 4, 256, 64), F32), ((1, 2, 256, 64), F32)
+    text = _compile_v5e(
+        v5e, _flash_grad(causal=True, kv_group=2, window=128),
+        q, kv, kv, ((1, 256), F32))
+    _assert_flash_fwd_bwd(text)
 
 
-def test_flash_uneven_tail_lowers_for_tpu():
+def test_flash_uneven_tail_lowers_for_tpu(v5e):
     # T not a multiple of the default block: exercises the tail-tile path
-    q, k, v = _qkv(1, 2, 192, 64)
-
-    def loss(q, k, v):
-        return fa.flash_attention(q, k, v, force_pallas=True).sum()
-
-    _export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    qkv = ((1, 2, 192, 64), F32)
+    _assert_flash_fwd_bwd(_compile_v5e(v5e, _flash_grad(), qkv, qkv, qkv))
 
 
-def test_ring_flash_lowers_for_tpu():
-    """Ring attention's shard_map + per-block Pallas engine lowers for
-    the TPU platform on the 8-device mesh — guards the Mosaic x
-    shard_map composition (sequence parallelism's hot path) without
-    hardware."""
-    import pytest
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_flash_train_width_lowers_for_tpu(v5e, dtype):
+    """chip_smoke.py's train phase: batch 64 x 8 heads x seq 256 x
+    dh 64, self-attention (causal) forward and backward; bf16 is what
+    the AMP rewrite hands the kernel."""
+    qkv = ((64, 8, 256, 64), dtype)
+    text = _compile_v5e(v5e, _flash_grad(causal=True), qkv, qkv, qkv)
+    _assert_flash_fwd_bwd(text)
 
-    from paddle_tpu.parallel.mesh import build_mesh
+
+def test_ring_flash_lowers_for_tpu(v5e):
+    """Ring attention's shard_map + per-block Pallas engine compiles
+    for the four described chips — guards the Mosaic x shard_map
+    composition (sequence parallelism's hot path) without hardware."""
     from paddle_tpu.parallel.ring_attention import ring_attention
 
-    if len(jax.devices()) < 8:
-        pytest.skip("needs 8 virtual devices")
-    mesh = build_mesh(num_devices=8, data=8)
-    q = jnp.zeros((1, 2, 8 * 128, 64), jnp.float32)
+    mesh = Mesh(v5e[:4], ("data",))
+    seq_sharded = NamedSharding(mesh, PartitionSpec(None, None, "data"))
+    qkv = ((1, 2, 4 * 128, 64), F32)
 
     def loss(q, k, v):
         return ring_attention(q, k, v, mesh, axis_name="data",
                               causal=True, impl="flash").sum()
 
-    _export_tpu(loss, q, q, q)
+    _compile_for(seq_sharded, loss, qkv, qkv, qkv)
+
+
+# chip_smoke.py's serve phase: Transformer-base heads (8 x dh 64) over
+# the paged slot pool
+_SERVE = dict(S=8, H=8, dh=64, T=256)
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("page_size", [16, 128])
+def test_paged_decode_lowers_for_tpu(v5e, page_size, dtype):
+    S, H, dh, T = (_SERVE[k] for k in ("S", "H", "dh", "T"))
+    npp = pa.pages_for(T, page_size)
+    pool = ((1 + S * npp, H, page_size, dh), dtype)
+    text = _compile_v5e(
+        v5e,
+        lambda q, k, v, t, n: pa.paged_attention(
+            q, k, v, t, n, force_pallas=True),
+        ((S, H, dh), dtype), pool, pool, ((S, npp), jnp.int32),
+        ((S,), jnp.int32))
+    assert pa.PAGED_KERNEL_NAME in text
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("n_nodes", [5, 8])
+def test_tree_attention_lowers_for_tpu(v5e, n_nodes, dtype):
+    """The speculative verify kernel at N = k + 1 nodes (k=4 is the
+    drafter default, N=8 the widest tree the tests drive)."""
+    S, H, dh, T = (_SERVE[k] for k in ("S", "H", "dh", "T"))
+    page_size = 16
+    npp = pa.pages_for(T, page_size)
+    pool = ((1 + S * npp, H, page_size, dh), dtype)
+    text = _compile_v5e(
+        v5e,
+        lambda q, k, v, t, b, anc: pa.paged_tree_attention(
+            q, k, v, t, b, anc, max_length=T, force_pallas=True),
+        ((S, H, n_nodes, dh), dtype), pool, pool, ((S, npp), jnp.int32),
+        ((S,), jnp.int32), ((S, n_nodes, n_nodes), jnp.int32))
+    assert pa.TREE_KERNEL_NAME in text

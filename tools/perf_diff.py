@@ -103,11 +103,13 @@ METRICS = {
     "span_coverage": ("higher", "timing"),
     # step observatory (tools/stepprof_smoke.py + the perf ledger):
     # worst per-step phase coverage of the step wall (a drop means a
-    # training phase stopped being attributed), achieved-MFU from the
-    # cost-model join, input-starvation fraction, and the profiled-leg
+    # training phase stopped being attributed), achieved FLOP/s (and,
+    # on a chip the peak table knows, MFU) from the cost-model join,
+    # input-starvation fraction, and the profiled-leg
     # wall over the off-leg control (the overhead contract)
     "phase_coverage": ("higher", "timing"),
     "achieved_mfu": ("higher", "timing"),
+    "achieved_flops_per_sec": ("higher", "timing"),
     "starvation_fraction": ("lower", "timing"),
     "stepprof_overhead": ("lower", "timing"),
 }
@@ -145,6 +147,7 @@ def _bench_model_metrics(m):
     out["span_coverage"] = m.get("span_coverage")
     out["phase_coverage"] = m.get("phase_coverage")
     out["achieved_mfu"] = m.get("achieved_mfu")
+    out["achieved_flops_per_sec"] = m.get("achieved_flops_per_sec")
     out["starvation_fraction"] = m.get("starvation_fraction")
     out["stepprof_overhead"] = m.get("stepprof_overhead")
     ec = m.get("exec_cache") or {}

@@ -1,7 +1,6 @@
 """Training-step decomposition: phase timings + per-HLO-op xprof shares.
 
-The measurement VERDICT r2 called for behind the MFU push ("commit an
-xprof/step-decomposition to BENCH_NOTES"): where does the step time go?
+The measurement behind the MFU push: where does the step time go?
 
 Two independent views, printed as JSON lines:
 
