@@ -1,0 +1,9 @@
+"""Load generator: how late it ran, the 99th percentile over the window's
+requests of (instant sent - instant due), in ms. A starved generator must
+not be read as a fast server."""
+
+from perfbench import metric_lib as lib
+
+
+def read(records):
+    return lib.serve_percentile(records, 'late_ms', 99)
