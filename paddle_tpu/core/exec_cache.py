@@ -39,7 +39,9 @@ and feed ``bench.py``'s ``compile_seconds_cold``/``compile_seconds_warm``
 fields. Backend compile time is observed via ``jax.monitoring`` events, so
 compiles that happen outside this module (stray helper jits) are counted
 too — the numbers are the process's whole compile tax, not just the
-executor's share.
+executor's share. ``trace_seconds`` / ``lower_seconds`` come from the same
+taps: the time JAX spent tracing Python to jaxprs and lowering jaxprs to
+MLIR modules, which no cache layer here saves.
 """
 
 import hashlib
@@ -74,6 +76,11 @@ _stats.update(
     compile_seconds_cold=0.0,    # ...attributable to fresh compiles
     compile_seconds_warm=0.0,    # ...attributable to cache loads
     cache_retrieval_seconds=0.0,
+    # what precedes every backend compile, cache hit or not, and what
+    # the persistent cache cannot save: tracing the Python program to a
+    # jaxpr and lowering the jaxpr to an MLIR module
+    trace_seconds=0.0,
+    lower_seconds=0.0,
 )
 
 _configured = {"dir": None}
@@ -115,6 +122,27 @@ def _on_duration(name, secs, **kw):
     elif name == "/jax/compilation_cache/cache_retrieval_time_sec":
         with _lock:
             _stats["cache_retrieval_seconds"] += secs
+    elif name == "/jax/core/compile/jaxpr_trace_duration":
+        # a jitted function called inside a trace reports its own trace
+        # first and lies inside the outer one's: count the union. The
+        # event fires at the trace's END on the tracing thread, so the
+        # reports this one encloses are the last ones of this thread
+        # that ended after it began
+        end = time.perf_counter()
+        done = getattr(_tls, "traces", None)
+        if done is None:
+            done = _tls.traces = []
+        net = secs
+        while done and done[-1][0] > end - secs:
+            net -= done.pop()[1]
+        done.append((end, secs))
+        if len(done) > 1024:
+            del done[:512]
+        with _lock:
+            _stats["trace_seconds"] += net
+    elif name == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        with _lock:
+            _stats["lower_seconds"] += secs
 
 
 def _record_compile_span(name, secs, kind):

@@ -595,7 +595,7 @@ class Executor(object):
         # forensics shell: the watchdog sees one armed unit of blocking
         # work; any escaping exception lands in the black box before it
         # propagates
-        with _blackbox.guard("Executor.run"):
+        with _blackbox.guard("Executor.run"), _stepprof.DROP_ON_ERROR:
             return self._run_on_device_impl(
                 program, feed, fetch_list, scope, device, return_numpy,
                 as_handle=as_handle, refresh_cache=refresh_cache)
@@ -603,46 +603,43 @@ class Executor(object):
     def _run_on_device_impl(self, program, feed, fetch_list, scope, device,
                             return_numpy, as_handle=False,
                             refresh_cache=False):
-        # flight-recorder guards: one module-bool load each; both False
-        # leaves the hot path identical to the uninstrumented executor
+        # flight-recorder guards: one module-bool load each. The phase
+        # brackets (sp) are always on: every dispatch leaves its small
+        # record (step_profiler.dispatch_records); what is heavy there
+        # waits for FLAGS_step_profile
         telem = _telemetry.ENABLED
         prof = _profiler.enabled()
-        sp = (_stepprof.begin("async" if as_handle else "single")
-              if _stepprof.ENABLED else None)
-        t0 = time.perf_counter() if (telem or prof) else 0.0
-        if sp is not None:
-            sp.enter("feed")
+        sp = _stepprof.begin("async" if as_handle else "single")
+        t0 = sp.t0   # telemetry and the profiler read the span's stamp
+        sp.enter("feed")
         feeds, feed_specs = self._prepare_feeds(program, feed, device)
-        if sp is not None:
-            sp.exit()
+        sp.exit()
         t_feed = time.perf_counter() if telem else 0.0
         fetch_names = [
             v.name if isinstance(v, framework.Variable) else str(v)
             for v in fetch_list
         ]
-        if sp is not None:
-            # a cache hit closes this bracket in microseconds; a fresh
-            # XLA trace shows up as a fat compile phase instead of
-            # silently inflating the step
-            sp.enter("compile")
+        # a cache hit closes this bracket in microseconds; a fresh
+        # XLA trace shows up as a fat compile phase instead of
+        # silently inflating the step
+        sp.enter("compile")
         cp = self._get_compiled(program, feed_specs, fetch_names, scope,
                                 refresh=refresh_cache)
-        if sp is not None:
-            sp.exit()
-            # state gather + step-key derivation assemble the dispatch
-            # inputs just like the feed dict does — same bracket, or
-            # they'd surface as unattributed host time
-            sp.enter("feed")
+        sp.exit()
+        # state gather + step-key derivation assemble the dispatch
+        # inputs just like the feed dict does — same bracket, or
+        # they'd surface as unattributed host time
+        sp.enter("feed")
         state = self._gather_state(cp.state_in, scope, device)
         key = self._step_key(program)
-        if sp is not None:
-            sp.exit()
-            # the bracket opens here, not at _dispatch: pre-dispatch
-            # work — the profiler's own one-shot cost snapshot, the
-            # blackbox record, the nan snapshot — is host dispatch
-            # overhead and must be charged, not hidden in the
-            # unattributed residual
-            sp.enter("dispatch")
+        sp.exit()
+        # the bracket opens here, not at _dispatch: pre-dispatch
+        # work — the profiler's own one-shot cost snapshot, the
+        # blackbox record, the nan snapshot — is host dispatch
+        # overhead and must be charged, not hidden in the
+        # unattributed residual
+        sp.enter("dispatch")
+        if _stepprof.ENABLED:
             sp.pre_dispatch(cp, state, feeds, key, program)
         # per-EXECUTABLE key: two feed shapes of one program do different
         # FLOPs, so the program fingerprint alone would mis-price steps
@@ -667,13 +664,17 @@ class Executor(object):
         nan_snapshot = self._nan_snapshot(cp, state)
         new_state, fetches = self._dispatch(cp, state, feeds, key,
                                             origin="Executor.dispatch")
-        if sp is not None:
-            sp.exit()
-            # scope writeback is output handling on the host clock —
-            # fetch-side work, even when the caller fetched nothing
-            sp.enter("fetch")
+        sp.exit()
+        # scope writeback is output handling on the host clock —
+        # fetch-side work, even when the caller fetched nothing
+        sp.enter("fetch")
         for n, val in new_state.items():
             scope.set_value(n, val)
+        # the donated inputs are dead once the scope holds the outputs:
+        # released here, inside the bracket, and not at this frame's
+        # teardown, where no record would see it (hundreds of arrays:
+        # a tenth of a small dispatch)
+        del state
         if telem:
             # scope binding: the step's outputs replace the donated
             # inputs under the same ledger keys; feeds leave with the
@@ -682,12 +683,11 @@ class Executor(object):
             _memory.track_state(cp, program, new_state, mem_dev)
             _memory.track_fetches(cp.fetch_names, fetches, mem_dev)
             _memory.drop_feeds(feeds, mem_dev)
-        if sp is not None:
-            # the fetch bracket closes AFTER the ledger writeback: when
-            # telemetry is co-enabled its per-step accounting is still
-            # output handling on the host clock, not unattributed
-            # residual
-            sp.exit()
+        # the fetch bracket closes AFTER the ledger writeback: when
+        # telemetry is co-enabled its per-step accounting is still
+        # output handling on the host clock, not unattributed
+        # residual
+        sp.exit()
         if as_handle:
             # dispatch complete, nothing synced: the (optional) nan/inf
             # reductions are already in flight on device, but reading
@@ -711,11 +711,10 @@ class Executor(object):
                 t_dispatch=t0 if telem else None,
                 mem_device=mem_dev,
             )
-            if sp is not None:
-                # the span measured host dispatch latency only; device
-                # + fetch happen in FetchHandle.result on the caller's
-                # clock, so the record is marked dispatch_only
-                _stepprof.finish(sp, feeds=feeds, dispatch_only=True)
+            # the span measured host dispatch latency only; device
+            # + fetch happen in FetchHandle.result on the caller's
+            # clock, so the record is marked dispatch_only
+            _stepprof.finish(sp, feeds=feeds, dispatch_only=True)
             if telem or prof:
                 t1 = time.perf_counter()
                 if telem:
@@ -739,27 +738,24 @@ class Executor(object):
         except RuntimeError as e:
             self._nan_blame(e, program, nan_snapshot, feeds, key, device)
         if return_numpy:
-            if sp is not None:
-                # device bracket: wait for compute to complete BEFORE
-                # the host copy, so device time and d2h materialize are
-                # attributed separately (annotated into the device
-                # timeline when a jax.profiler trace session is live)
-                sp.enter("device")
-                with _stepprof.device_annotation():
-                    for _f in fetches:
-                        if hasattr(_f, "block_until_ready"):
-                            _f.block_until_ready()
-                sp.exit()
-                sp.enter("fetch")
+            # device bracket: wait for compute to complete BEFORE
+            # the host copy, so device time and d2h materialize are
+            # attributed separately (and annotated on the profiler's
+            # clock for whoever traces)
+            sp.enter("device")
+            with _stepprof.device_annotation():
+                for _f in fetches:
+                    if hasattr(_f, "block_until_ready"):
+                        _f.block_until_ready()
+            sp.exit()
+            sp.enter("fetch")
             fetches = _materialize_fetches(fetches, "Executor.run")
-            if sp is not None:
-                sp.exit()
-        if sp is not None:
-            # the span closes BEFORE telemetry's own record-keeping
-            # tail: the observatory reports the same step wall whether
-            # or not other observers are armed, and their bookkeeping
-            # cannot masquerade as unattributed step residual
-            _stepprof.finish(sp, feeds=feeds, fetches=fetches)
+            sp.exit()
+        # the span closes BEFORE telemetry's own record-keeping
+        # tail: the observatory reports the same step wall whether
+        # or not other observers are armed, and their bookkeeping
+        # cannot masquerade as unattributed step residual
+        _stepprof.finish(sp, feeds=feeds, fetches=fetches)
         if telem:
             # sync return: the fetch buffers are the caller's now (numpy
             # in hand, or live arrays the executor no longer owns)
@@ -817,18 +813,15 @@ class Executor(object):
         fetch_list = fetch_list or []
         scope = scope or global_scope()
         device = self.place.jax_device()
-        with jax.default_device(device):
-            sp = (_stepprof.begin("multi_step")
-                  if _stepprof.ENABLED else None)
-            if sp is not None:
-                sp.enter("feed")
+        with jax.default_device(device), _stepprof.DROP_ON_ERROR:
+            sp = _stepprof.begin("multi_step")
+            sp.enter("feed")
             feeds, feed_specs = self._prepare_feeds(program, feed, device)
-            if sp is not None:
-                sp.exit()
-                # cache-key derivation (fingerprint, scope signature) is
-                # executable resolution — compile-phase work, exactly as
-                # in the single-step path where _get_compiled owns it
-                sp.enter("compile")
+            sp.exit()
+            # cache-key derivation (fingerprint, scope signature) is
+            # executable resolution — compile-phase work, exactly as
+            # in the single-step path where _get_compiled owns it
+            sp.enter("compile")
             fetch_names = [
                 v.name if isinstance(v, framework.Variable) else str(v)
                 for v in fetch_list
@@ -880,20 +873,19 @@ class Executor(object):
                 self._cache[key_id] = cp
             else:
                 exec_cache.record_trace_hit()
-            if sp is not None:
-                sp.exit()
-                # input assembly continues on the host clock: state
-                # gather + step-key derivation feed the dispatch
-                sp.enter("feed")
+            sp.exit()
+            # input assembly continues on the host clock: state
+            # gather + step-key derivation feed the dispatch
+            sp.enter("feed")
             state = self._gather_state(cp.state_in, scope, device)
             key = self._step_key(program)
-            if sp is not None:
-                sp.exit()
-                # opens before the pre-dispatch work (cost snapshot,
-                # blackbox record, nan snapshot, watchdog guard): host
-                # dispatch overhead is charged to dispatch, not left in
-                # the unattributed residual
-                sp.enter("dispatch")
+            sp.exit()
+            # opens before the pre-dispatch work (cost snapshot,
+            # blackbox record, nan snapshot, watchdog guard): host
+            # dispatch overhead is charged to dispatch, not left in
+            # the unattributed residual
+            sp.enter("dispatch")
+            if _stepprof.ENABLED:
                 sp.pre_dispatch(cp, state, feeds, key, program)
             telem = _telemetry.ENABLED
             prof = _profiler.enabled()
@@ -920,19 +912,18 @@ class Executor(object):
                 new_state, fetches = self._dispatch(
                     cp, state, feeds, key,
                     origin="Executor.run_multi_step")
-                if sp is not None:
-                    sp.exit()
-                    sp.enter("fetch")
+                sp.exit()
+                sp.enter("fetch")
                 for n, val in new_state.items():
                     scope.set_value(n, val)
+                del state  # the donated inputs, released in the bracket
                 if telem:
                     _memory.track_state(cp, program, new_state, mem_dev)
                     _memory.track_fetches(cp.fetch_names, fetches,
                                           mem_dev)
                     _memory.drop_feeds(feeds, mem_dev)
-                if sp is not None:
-                    # ledger writeback is fetch-side work (see run())
-                    sp.exit()
+                # ledger writeback is fetch-side work (see run())
+                sp.exit()
                 try:
                     self._check_nan_inf(new_state, cp.fetch_names, fetches)
                 except RuntimeError as e:
@@ -941,26 +932,23 @@ class Executor(object):
                                     mutable_state=cp.mutable_state,
                                     multi=True)
                 if return_numpy:
-                    if sp is not None:
-                        sp.enter("device")
-                        with _stepprof.device_annotation():
-                            for _f in fetches:
-                                if hasattr(_f, "block_until_ready"):
-                                    _f.block_until_ready()
-                        sp.exit()
-                        sp.enter("fetch")
+                    sp.enter("device")
+                    with _stepprof.device_annotation():
+                        for _f in fetches:
+                            if hasattr(_f, "block_until_ready"):
+                                _f.block_until_ready()
+                    sp.exit()
+                    sp.enter("fetch")
                     fetches = _materialize_fetches(
                         fetches, "Executor.run_multi_step")
-                    if sp is not None:
-                        sp.exit()
+                    sp.exit()
                 if telem:
                     _memory.drop_fetches(cp.fetch_names, mem_dev)
-            if sp is not None:
-                # span closes before telemetry's record-keeping tail
-                # (see run()): per-step wall is comparable across
-                # observer configurations
-                _stepprof.finish(sp, steps=int(steps), feeds=feeds,
-                                 fetches=fetches)
+            # span closes before telemetry's record-keeping tail
+            # (see run()): per-step wall is comparable across
+            # observer configurations
+            _stepprof.finish(sp, steps=int(steps), feeds=feeds,
+                             fetches=fetches)
             if telem or prof:
                 t1 = time.perf_counter()
                 if telem:

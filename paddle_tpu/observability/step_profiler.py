@@ -14,9 +14,9 @@ record of phase spans —
   XLA trace shows up here instead of silently fattening the step),
 * ``dispatch`` — the jitted call itself (argument marshalling + XLA
   enqueue; chaos' ``exec.dispatch`` faults land inside this bracket),
-* ``device`` — block_until_ready on the fetched arrays (annotated with
-  ``jax.profiler.TraceAnnotation`` when a trace session is live, so the
-  bracket shows up in the device timeline too),
+* ``device`` — block_until_ready on the fetched arrays (a
+  ``jax.profiler.TraceAnnotation``, ``pt:device``: whoever opened a
+  profiler session finds the bracket on the device trace's clock),
 * ``fetch`` — device->host materialization to numpy,
 * ``host`` — the residual (record bookkeeping, scope writes, python).
 
@@ -34,30 +34,43 @@ gauges), and an online regression detector — rolling median + MAD per
 executable; excursions and sustained drifts emit black-box flight
 events naming the guilty phase.
 
-Overhead contract (FLAGS_step_profile, telemetry's discipline): OFF is
-one module-attribute read per step — zero allocations, zero fresh
-compiles, bit-identical results. ON costs one StepSpan + a handful of
-perf_counter calls per step; the cost-model trace is one-shot per
-executable and runs after the timed region.
+The dispatch record: the phase brackets themselves are always on. Every
+executor dispatch leaves ``(origin, end stamp on time.time(), wall
+seconds, seconds by phase)`` in a ring of its own
+(:func:`dispatch_records`), flag or no flag — one StepSpan and a dozen
+perf_counter calls, microseconds against a dispatch of milliseconds —
+so a benchmark can read the host's share of a dispatch from a run that
+switched nothing on.
+
+Overhead contract (FLAGS_step_profile, telemetry's discipline): OFF
+adds nothing to the dispatch record — no ring record, no cost join, no
+detector, no metric write, zero fresh compiles, bit-identical results.
+ON adds those per step; the cost-model trace is one-shot per executable
+and runs after the timed region.
 """
 
 import collections
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from paddle_tpu.observability import lock_witness
+from paddle_tpu.observability import tracing as _tracing
 from paddle_tpu.observability.metrics_registry import REGISTRY
 
 __all__ = [
     "ENABLED", "enable", "reset", "begin", "finish", "records",
     "inflight", "note_input_wait", "note_queue_wait", "cost_table",
     "write_stepprof_jsonl", "StepSpan", "PHASES", "RING_CAP",
-    "device_annotation",
+    "device_annotation", "dispatch_records", "DISPATCH_RING_CAP",
+    "DROP_ON_ERROR",
 ]
 
 ENABLED = False
 
 RING_CAP = 2048
+DISPATCH_RING_CAP = 16384  # a benchmark run makes ~10 000 dispatches
 
 # phase vocabulary — the record's "phases" dict only carries nonzero
 # entries, but consumers (step_breakdown, perf_ledger) treat this tuple
@@ -74,6 +87,8 @@ _DRIFT_N = 5         # consecutive excursions = sustained drift, rebase
 
 _lock = lock_witness.make_lock("observability.step_profiler")
 _records = collections.deque(maxlen=RING_CAP)
+# every dispatch, flag or no flag: (origin, t_end, wall_s, phases)
+_dispatches = collections.deque(maxlen=DISPATCH_RING_CAP)
 _cost = {}           # fingerprint -> per-step cost join (None = tried, failed)
 _reg = {}            # fingerprint/origin -> regression baseline state
 _tls = threading.local()   # .input_wait: seconds banked for the next step
@@ -121,9 +136,10 @@ def enable(on=True):
 
 
 def reset():
-    """Drop the ring, the cost join and the regression baselines (test
+    """Drop the rings, the cost join and the regression baselines (test
     isolation; the executors re-join costs one-shot per executable, so a
     reset mid-run only re-prices on the next new executable)."""
+    _dispatches.clear()
     with _lock:
         _records.clear()
         _cost.clear()
@@ -154,10 +170,11 @@ def note_queue_wait(seconds, depth, site="reader.queue"):
 # -- the per-step span -------------------------------------------------------
 
 class StepSpan(object):
-    """One step's open record. Executors hold one of these across the
-    step and bracket each phase with enter()/exit(); ``finish`` closes
-    it into the ring. Plain slots — the ON-path per-step cost is this
-    object plus a small dict."""
+    """One step's open record. Executors hold one of these across
+    every step and bracket each phase with enter()/exit(); ``finish``
+    closes it into the dispatch ring and, with the observatory on, into
+    the step ring. Plain slots — the per-step cost is this object plus
+    a small dict."""
 
     __slots__ = ("origin", "t0", "phases", "input_wait", "fingerprint",
                  "_cur", "_t_cur", "_cost_cp", "_cost_avals")
@@ -218,44 +235,43 @@ class StepSpan(object):
 
 
 def begin(origin):
-    """Open a span for one step and claim the calling thread's banked
-    input wait. Executors call this as
-    ``sp = _stepprof.begin(...) if _stepprof.ENABLED else None`` — the
-    OFF path is the one attribute read."""
+    """Open a span for one step and, with the observatory on, claim the
+    calling thread's banked input wait (the readers bank it under the
+    same flag). Executors call this on every step."""
     sp = StepSpan(origin)
-    banked = getattr(_tls, "input_wait", 0.0)
-    if banked:
-        sp.input_wait = banked
-        _tls.input_wait = 0.0
+    if ENABLED:
+        banked = getattr(_tls, "input_wait", 0.0)
+        if banked:
+            sp.input_wait = banked
+            _tls.input_wait = 0.0
     return sp
 
 
-class _NullAnnotation(object):
+class _DropOnError(object):
+    """Shell around a bracketed dispatch: only ``finish`` pops the
+    thread's in-flight entry, so a dispatch that raises (nan blame, OOM,
+    a chaos fault) would leave :func:`inflight` a phase that stalls for
+    ever. Stateless: one shared instance."""
+
+    __slots__ = ()
+
     def __enter__(self):
         return self
 
-    def __exit__(self, *exc):
+    def __exit__(self, exc_type, exc, tb):
+        if exc_type is not None:
+            _inflight.pop(threading.get_ident(), None)
         return False
 
 
-_NULL_ANNOTATION = _NullAnnotation()
+DROP_ON_ERROR = _DropOnError()
 
 
-def device_annotation(name="paddle_tpu.step.device"):
-    """The device-phase bracket's trace annotation: a real
-    ``jax.profiler.TraceAnnotation`` when profiler.start_profiler opened
-    a trace session (so the bracket lands in the device timeline), else
-    a shared no-op context."""
-    try:
-        from paddle_tpu import profiler as _profiler
-
-        if _profiler._state.get("jax_trace_dir"):
-            import jax
-
-            return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        pass
-    return _NULL_ANNOTATION
+def device_annotation():
+    """The device-phase bracket on the profiler's clock: free when no
+    profiler session is live, and in anyone's session the wait shows in
+    the host plane beside the program's other spans."""
+    return TraceAnnotation(_tracing.ANNOTATION_PREFIX + "device")
 
 
 # -- cost-model join ---------------------------------------------------------
@@ -387,27 +403,33 @@ def _detect_regression(key, step_s, per_step_phases):
 # -- closing a span ----------------------------------------------------------
 
 def finish(sp, steps=1, feeds=None, fetches=None, dispatch_only=False):
-    """Close a span into a phase-attributed record: residual-host
-    accounting, the cost-model join, achieved-MFU, boundedness verdict,
-    regression detection, ring append + metric writes. Runs entirely
-    after the step's timed region — ``feeds``/``fetches`` are passed as
-    containers (not pre-summed byte counts) so the wall clock stops on
-    the FIRST line here, before any accounting arithmetic."""
+    """Close a span: always into the dispatch ring (the phases with
+    the residual ``host``); with the observatory on also into a
+    phase-attributed record: the cost-model join, achieved-MFU,
+    boundedness verdict, regression detection, ring append + metric
+    writes (returned; None when off). Runs entirely after the step's
+    timed region — ``feeds``/``fetches`` are passed as containers (not
+    pre-summed byte counts) so the wall clock stops on the FIRST line
+    here, before any accounting arithmetic."""
     now = time.perf_counter()
     if sp._cur is not None:
         sp.exit()
-    _inflight.pop(threading.get_ident(), None)
-    steps = max(1, int(steps))
     wall = now - sp.t0
+    measured = sum(sp.phases.values())
+    host = max(0.0, wall - measured)
+    phases = dict(sp.phases)
+    phases["host"] = host
+    _dispatches.append((sp.origin, time.time(), wall, phases))
+    _inflight.pop(threading.get_ident(), None)
+    if not ENABLED:
+        return None
+    steps = max(1, int(steps))
     feed_bytes = (sum(getattr(a, "nbytes", 0) for a in feeds.values())
                   if feeds else 0)
     fetch_bytes = (sum(getattr(f, "nbytes", 0) for f in fetches)
                    if fetches else 0)
-    measured = sum(sp.phases.values())
-    host = max(0.0, wall - measured)
     step_wall = wall + sp.input_wait
-    phases = dict(sp.phases)
-    phases["host"] = host
+    phases = dict(phases)
     if sp.input_wait:
         phases["input_wait"] = sp.input_wait
     # coverage: every explicitly measured second (brackets + source-side
@@ -515,6 +537,19 @@ def records():
     """Snapshot of the ring (oldest first)."""
     with _lock:
         return [dict(r) for r in _records]
+
+
+def dispatch_records(origin=None):
+    """Every dispatch the ring still holds, oldest first, flag or no
+    flag: ``{"origin", "t1", "wall_s", "phases"}`` each. ``t1`` is the
+    dispatch's end on ``time.time()`` (the clock of
+    ``observability/tracing.py``'s spans, so the dispatches of a round
+    can be picked out); ``phases`` holds seconds by phase, the residual
+    ``host`` included. ``origin`` keeps one entry point's: ``single``,
+    ``async``, ``multi_step``, ``parallel``."""
+    return [{"origin": o, "t1": t1, "wall_s": wall, "phases": dict(ph)}
+            for o, t1, wall, ph in _tracing.ring_snapshot(_dispatches)
+            if origin is None or o == origin]
 
 
 def inflight():

@@ -30,13 +30,39 @@ Preemption: a traced request's id lives in the session's
 ``rid -> trace_id`` binding, which rides the decode snapshot dialect —
 a SIGTERM'd process's restored twin re-banks results under the ORIGINAL
 ids (continuation traces carry ``origin="session"``).
+
+Rounds: what a request waits for is the decode worker, so under the
+same guard every pass of ``ServingFrontend``'s worker loop that did
+work is one :class:`Round` in a ring of its own — child spans ``wait``,
+``cancel``, ``enqueue``, ``admit``, ``step``, ``handoff`` and, around
+each executor call of the session, ``<open span>.dispatch``; each
+``name, t0, t1, cpu, parent`` (``cpu``: the worker thread's
+``time.thread_time()`` over the span, so time the thread was not running
+shows — taken on the spans that bound the worker's host time, ``round``,
+``wait``, ``step`` and ``step.dispatch``, and None on the others: that
+clock is a system call, 5 us on the chip's host and 28 us with the
+frontend's threads alive, and two readings on each of a round's ~90
+spans cost the saturated server a hundredth of its rate; ``parent``:
+index into the round's ``spans``, whose entry 0 is the ``round`` itself
+and holds its counts: ``live`` slots and ``backlog`` at the dispatch,
+``tokens`` handed to the streams, which the benchmark's report prints
+beside the rate the clients counted). A request's ``queue``, ``prefill``
+and ``decode.step`` spans name the round they fell into (``round=``), so
+``tools/trace_view.py`` counts its queue wait and its decode in rounds.
+Every round span is also a ``jax.profiler.TraceAnnotation`` named
+``ANNOTATION_PREFIX + name``: free with no profiler session, and inside
+one the spans lie in the host plane on the device trace's own clock.
 """
 
+import contextlib
+import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 from paddle_tpu import flags
 from paddle_tpu.observability import lock_witness
@@ -47,11 +73,17 @@ from paddle_tpu.observability.metrics_registry import (
 
 ENABLED = False
 
-RING = 512  # completed traces kept for exemplar resolution / trace_view
+RING = 4096  # completed traces kept: a benchmark run's requests (~2500)
+ROUND_RING = 4096  # completed rounds kept: a run has under 1000
+ANNOTATION_PREFIX = "pt:"  # the program's spans in a profiler trace
 
 _lock = lock_witness.make_lock("observability.tracing")
 _inflight = {}                 # trace_id -> Trace
 _completed = deque(maxlen=RING)
+_rounds = deque(maxlen=ROUND_RING)
+_round_ids = itertools.count(1)
+_tls = threading.local()       # .round: the Round open on this thread
+_NO_SPAN = contextlib.nullcontext()
 
 # inter-token gaps (consecutive chunk flushes of one stream), observed
 # at finish() — ms-scale, hence the decode-resolution ladder
@@ -81,11 +113,9 @@ class Trace(object):
     lock only guards the in-flight/ring registries."""
 
     __slots__ = ("id", "origin", "endpoint", "t0", "t_client_send",
-                 "spans", "marks", "acc", "baggage", "_root",
-                 "_page_ts")
+                 "spans", "marks", "acc", "done", "_root", "_page_ts")
 
-    def __init__(self, trace_id, endpoint, origin, t_client_send,
-                 baggage):
+    def __init__(self, trace_id, endpoint, origin, t_client_send):
         self.id = trace_id
         self.origin = origin
         self.endpoint = endpoint
@@ -94,7 +124,7 @@ class Trace(object):
         self.spans = []
         self.marks = {}
         self.acc = {}
-        self.baggage = dict(baggage) if baggage else {}
+        self.done = False      # set by finish(): the spans are banked
         self._root = None
         self._page_ts = None
 
@@ -140,12 +170,11 @@ class Trace(object):
 
 
 def start(trace_id=None, endpoint="generate", origin="frontend",
-          t_client_send=None, baggage=None):
+          t_client_send=None):
     """Register a new in-flight trace (root span opens immediately and
     closes at :func:`finish` — the whole server-side handling window is
     always covered). ``trace_id=None`` mints one."""
-    tr = Trace(trace_id or mint_id(), endpoint, origin, t_client_send,
-               baggage)
+    tr = Trace(trace_id or mint_id(), endpoint, origin, t_client_send)
     tr._root = tr.begin("request", endpoint=endpoint, origin=origin)
     with _lock:
         _inflight[tr.id] = tr
@@ -191,6 +220,7 @@ def finish(tr, outcome="ok", **meta):
     derive per-request stats, bank the record, drop the in-flight
     entry. Returns the record."""
     now = time.time()
+    tr.done = True
     with _lock:
         _inflight.pop(tr.id, None)
     for sp in tr.spans:
@@ -260,13 +290,133 @@ def finish(tr, outcome="ok", **meta):
         "t_client_send": tr.t_client_send,
         "stats": stats,
         "spans": tr.spans,
-        "baggage": tr.baggage,
     }
     if meta:
         rec.update(meta)
     with _lock:
         _completed.append(rec)
     return rec
+
+
+# -- the decode worker's rounds ------------------------------------------------
+
+class Round(object):
+    """One pass of the decode worker's loop: the ``round`` span (entry 0
+    of ``spans``, which also holds the round's counts) and its children.
+    Touched by the worker thread only."""
+
+    __slots__ = ("id", "spans", "_open")
+
+    def __init__(self):
+        self.id = next(_round_ids)
+        self.spans = []
+        self._open = []   # (span index, thread_time at begin, annotation)
+
+    def begin(self, name, cpu=False):
+        """Open a child of the innermost open span; a ``name`` with a
+        leading dot is appended to that span's own (``.dispatch`` inside
+        ``admit`` is ``admit.dispatch``) and takes the thread's CPU time
+        if that span does."""
+        parent = self._open[-1][0] if self._open else None
+        if name[0] == ".":
+            name = self.spans[parent]["name"] + name
+            cpu = self._open[-1][1] is not None
+        ann = TraceAnnotation(ANNOTATION_PREFIX + name)
+        ann.__enter__()
+        self._open.append((len(self.spans),
+                           time.thread_time() if cpu else None, ann))
+        self.spans.append({"name": name, "t0": time.time(), "t1": None,
+                           "cpu": None, "parent": parent})
+
+    def end(self):
+        idx, cpu0, ann = self._open.pop()
+        sp = self.spans[idx]
+        if cpu0 is not None:
+            sp["cpu"] = time.thread_time() - cpu0
+        sp["t1"] = time.time()
+        ann.__exit__(None, None, None)
+
+
+class _RoundSpan(object):
+    """``with`` form of one child span."""
+
+    __slots__ = ("_rd", "_name", "_cpu")
+
+    def __init__(self, rd, name, cpu):
+        self._rd = rd
+        self._name = name
+        self._cpu = cpu
+
+    def __enter__(self):
+        self._rd.begin(self._name, self._cpu)
+
+    def __exit__(self, *exc):
+        self._rd.end()
+        return False
+
+
+def round_begin():
+    """Open a round on the calling thread (the decode worker's, under
+    its ``ENABLED`` guard)."""
+    rd = _tls.round = Round()
+    rd.begin("round", cpu=True)
+    return rd
+
+
+def round_end(rd, keep=True):
+    """Close the round with whatever an exception left open and, when
+    the pass did work (``keep``), bank it."""
+    _tls.round = None
+    while rd._open:
+        rd.end()
+    if keep:
+        _rounds.append({"id": rd.id, "spans": rd.spans})
+
+
+def span(name, cpu=False):
+    """``with span("admit"):`` a child span of the round open on this
+    thread; nothing with tracing off or no round open (a session driven
+    without a frontend) -- one attribute read and a shared no-op
+    context. ``cpu=True`` also takes the thread's CPU time over the span
+    (two system calls)."""
+    if not ENABLED:
+        return _NO_SPAN
+    rd = getattr(_tls, "round", None)
+    if rd is None:
+        return _NO_SPAN
+    return _RoundSpan(rd, name, cpu)
+
+
+def round_count(key, delta=1):
+    """Add to a count of the round open on this thread: ``live`` (slots
+    decoding at the dispatch), ``backlog`` (requests queued then) and
+    ``tokens`` (handed to the streams). Call under the ``ENABLED`` guard;
+    nothing without a round."""
+    rd = getattr(_tls, "round", None)
+    if rd is not None:
+        root = rd.spans[0]
+        root[key] = root.get(key, 0) + delta
+
+
+def round_id():
+    """Id of the round open on this thread, or None."""
+    rd = getattr(_tls, "round", None)
+    return rd.id if rd is not None else None
+
+
+def ring_snapshot(ring):
+    """A list of a ring (a ``deque``) that its one writer thread may be
+    appending to: appends take no lock, so the copy is simply retried."""
+    while True:
+        try:
+            return list(ring)
+        except RuntimeError:  # appended to while we copied
+            continue
+
+
+def rounds():
+    """The banked rounds, oldest first: ``{"id", "spans"}`` each."""
+    return ring_snapshot(_rounds)
 
 
 def get(trace_id):
@@ -318,10 +468,12 @@ def perfetto_events(rec, row=0, pid=1):
 
 
 def reset():
-    """Drop every in-flight and completed trace (tests)."""
+    """Drop every in-flight and completed trace and every round
+    (tests)."""
     with _lock:
         _inflight.clear()
         _completed.clear()
+    _rounds.clear()
 
 
 def _init_from_flags():

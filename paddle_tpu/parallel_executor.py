@@ -494,7 +494,8 @@ class ParallelExecutor(object):
         # watchdog — a multichip step that never returns is THE hang this
         # layer exists for — and any escaping exception lands in the
         # black box with this origin before propagating
-        with _blackbox.guard("ParallelExecutor.run"):
+        with _blackbox.guard("ParallelExecutor.run"), \
+                _stepprof.DROP_ON_ERROR:
             return self._run_impl(fetch_list, feed, feed_dict, return_numpy)
 
     def _run_impl(self, fetch_list, feed=None, feed_dict=None,
@@ -514,9 +515,8 @@ class ParallelExecutor(object):
                     "pipeline", time.perf_counter() - t0,
                     fingerprint=program_fingerprint(self._program))
             return fetches
-        sp = _stepprof.begin("parallel") if _stepprof.ENABLED else None
-        if sp is not None:
-            sp.enter("feed")
+        sp = _stepprof.begin("parallel")
+        sp.enter("feed")
         if isinstance(feed, list):
             # per-device feed dicts (fluid API) -> concat along batch.
             merged = {}
@@ -557,20 +557,17 @@ class ParallelExecutor(object):
             feeds[name] = arr
             feed_specs[name] = (tuple(arr.shape), str(arr.dtype))
 
-        if sp is not None:
-            sp.exit()
+        sp.exit()
         fetch_names = [
             v.name if isinstance(v, framework.Variable) else str(v)
             for v in fetch_list
         ]
-        if sp is not None:
-            sp.enter("compile")
+        sp.enter("compile")
         cp = self._get_compiled(feed_specs, fetch_names)
-        if sp is not None:
-            sp.exit()
-            # input assembly continues: state gather (+ reshard) and
-            # step-key derivation run on the host clock before dispatch
-            sp.enter("feed")
+        sp.exit()
+        # input assembly continues: state gather (+ reshard) and
+        # step-key derivation run on the host clock before dispatch
+        sp.enter("feed")
 
         state = {}
         for n in cp.state_in:
@@ -593,12 +590,12 @@ class ParallelExecutor(object):
             jax.random.PRNGKey(self._program.random_seed or self._base_seed),
             self._run_counter,
         )
-        if sp is not None:
-            sp.exit()
-            # opens before the pre-dispatch work (cost snapshot,
-            # blackbox record): host dispatch overhead is charged to
-            # dispatch, not left in the unattributed residual
-            sp.enter("dispatch")
+        sp.exit()
+        # opens before the pre-dispatch work (cost snapshot,
+        # blackbox record): host dispatch overhead is charged to
+        # dispatch, not left in the unattributed residual
+        sp.enter("dispatch")
+        if _stepprof.ENABLED:
             sp.pre_dispatch(cp, state, feeds, key, self._program)
         flops_avals = None
         mem_dev = None
@@ -638,11 +635,11 @@ class ParallelExecutor(object):
 
         new_state, fetches = _Executor._dispatch(
             cp, state, feeds, key, origin="ParallelExecutor.dispatch")
-        if sp is not None:
-            sp.exit()
-            sp.enter("fetch")
+        sp.exit()
+        sp.enter("fetch")
         for n, val in new_state.items():
             self._scope.set_value(n, val)
+        del state  # the donated inputs, released in the bracket
         if telem:
             # per-device ledger entries from the REAL shard sizes: a
             # param fsdp-sharded 4 ways books ~1/4 of its bytes on each
@@ -653,11 +650,10 @@ class ParallelExecutor(object):
                                         fallback_device=mem_dev)
             _memory.track_fetches(cp.fetch_names, fetches, mem_dev)
             _memory.drop_feeds(feeds, mem_dev)
-        if sp is not None:
-            # the fetch bracket closes AFTER the ledger writeback (see
-            # Executor.run): co-enabled telemetry's accounting is
-            # output handling, not unattributed residual
-            sp.exit()
+        # the fetch bracket closes AFTER the ledger writeback (see
+        # Executor.run): co-enabled telemetry's accounting is
+        # output handling, not unattributed residual
+        sp.exit()
         device_times = None
         if telem and return_numpy:
             # per-device dispatch->ready latency, measured on the live
@@ -669,21 +665,18 @@ class ParallelExecutor(object):
             # This blocks on device shards, so it IS device wait — the
             # bracket charges it there, and the later per-fetch
             # block_until_ready returns instantly having been paid here
-            if sp is not None:
-                sp.enter("device")
+            sp.enter("device")
             device_times = _telemetry.device_step_times(
                 list(fetches) + list(new_state.values()), t_disp)
-            if sp is not None:
-                sp.exit()
+            sp.exit()
         if return_numpy:
-            if sp is not None:
-                sp.enter("device")
-                with _stepprof.device_annotation():
-                    for _f in fetches:
-                        if hasattr(_f, "block_until_ready"):
-                            _f.block_until_ready()
-                sp.exit()
-                sp.enter("fetch")
+            sp.enter("device")
+            with _stepprof.device_annotation():
+                for _f in fetches:
+                    if hasattr(_f, "block_until_ready"):
+                        _f.block_until_ready()
+            sp.exit()
+            sp.enter("fetch")
             try:
                 fetches = [self._fetch_to_numpy(f) for f in fetches]
             except Exception as exc:
@@ -694,13 +687,11 @@ class ParallelExecutor(object):
                     _memory.enrich_and_raise(
                         exc, origin="ParallelExecutor.fetch")
                 raise
-            if sp is not None:
-                sp.exit()
-        if sp is not None:
-            # span closes before telemetry's record-keeping tail (see
-            # Executor.run): per-step wall is comparable across
-            # observer configurations
-            _stepprof.finish(sp, feeds=feeds, fetches=fetches)
+            sp.exit()
+        # span closes before telemetry's record-keeping tail (see
+        # Executor.run): per-step wall is comparable across
+        # observer configurations
+        _stepprof.finish(sp, feeds=feeds, fetches=fetches)
         if telem:
             _memory.drop_fetches(cp.fetch_names, mem_dev)
         if telem or prof:

@@ -255,59 +255,17 @@ class _DecodeWorker(object):
     # -- worker loop ---------------------------------------------------------
 
     def _loop(self):
-        s = self._s
         while True:
-            with self._cond:
-                while (not self._incoming and not self._cancels
-                        and not self._ops
-                        and not self._stop and not s.active_slots
-                        and not (s.pending_requests and s.free_slots)):
-                    # the timeout re-checks capacity-deferred backlog
-                    # (a NoFreePage defer relaxes only as leaks/cache
-                    # pressure do, not on any notify)
-                    self._cond.wait(0.25)
-                incoming = list(self._incoming)
-                self._incoming.clear()
-                cancels = list(self._cancels)
-                self._cancels.clear()
-                ops = list(self._ops)
-                self._ops.clear()
-                stop, drain = self._stop, self._drain
-            progressed = bool(incoming or cancels or ops)
-            for stream in cancels:
-                self._teardown(stream)
-            # ops run at this quiesce point: after cancels (so a drain's
-            # "no live streams" check sees the teardowns) and before
-            # this pass's admissions/dispatch
-            self._run_ops(ops)
-            for stream in incoming:
-                if stop:
-                    stream.q.put(error_to_wire(
-                        ServerClosedError("frontend is closed")))
-                    stream.done = True
-                elif not stream.cancelled.is_set():
-                    self._admit(stream)
-            if stop and not drain:
-                self._abort_all()
-                self._fail_ops()
-                return
-            progressed |= self._admit_backlog()
-            if s.active_slots:
-                try:
-                    self._step_once()
-                except Exception as exc:  # noqa: BLE001 - typed below
-                    # a hard decode failure (not the classified-retry
-                    # transients — those were retried inside the
-                    # executor) must not kill the worker and wedge
-                    # every stream: every tracked stream gets the
-                    # typed failure, its slots are cancelled, the
-                    # worker lives on for the next admission
-                    self._fail_tracked(exc)
-                progressed = True
-            if (stop and drain and not s.active_slots
-                    and not s.pending_requests and not self._slot_stream
-                    and not self._rid_stream and not self._beam_stream):
-                self._fail_ops()
+            # tracing on: each pass that does work is one round record
+            # (observability/tracing.py), its phases the child spans
+            rd = _tracing.round_begin() if _tracing.ENABLED else None
+            progressed = False
+            try:
+                alive, progressed = self._pass()
+            finally:
+                if rd is not None:
+                    _tracing.round_end(rd, keep=progressed)
+            if not alive:
                 return
             if not progressed:
                 # a whole pass moved nothing — the backlog is
@@ -321,6 +279,67 @@ class _DecodeWorker(object):
                 with self._cond:
                     if not self._incoming and not self._cancels:
                         self._cond.wait(0.1)
+
+    def _pass(self):
+        """One pass of the worker loop. Returns ``(alive, progressed)``:
+        whether the worker lives on, and whether the pass moved
+        anything."""
+        s = self._s
+        with _tracing.span("wait", cpu=True), self._cond:
+            while (not self._incoming and not self._cancels
+                    and not self._ops
+                    and not self._stop and not s.active_slots
+                    and not (s.pending_requests and s.free_slots)):
+                # the timeout re-checks capacity-deferred backlog
+                # (a NoFreePage defer relaxes only as leaks/cache
+                # pressure do, not on any notify)
+                self._cond.wait(0.25)
+            incoming = list(self._incoming)
+            self._incoming.clear()
+            cancels = list(self._cancels)
+            self._cancels.clear()
+            ops = list(self._ops)
+            self._ops.clear()
+            stop, drain = self._stop, self._drain
+        progressed = bool(incoming or cancels or ops)
+        for stream in cancels:
+            self._teardown(stream)
+        # ops run at this quiesce point: after cancels (so a drain's
+        # "no live streams" check sees the teardowns) and before
+        # this pass's admissions/dispatch
+        self._run_ops(ops)
+        if incoming:
+            with _tracing.span("enqueue"):
+                for stream in incoming:
+                    if stop:
+                        stream.q.put(error_to_wire(
+                            ServerClosedError("frontend is closed")))
+                        stream.done = True
+                    elif not stream.cancelled.is_set():
+                        self._admit(stream)
+        if stop and not drain:
+            self._abort_all()
+            self._fail_ops()
+            return False, progressed
+        progressed |= self._admit_backlog()
+        if s.active_slots:
+            try:
+                self._step_once()
+            except Exception as exc:  # noqa: BLE001 - typed below
+                # a hard decode failure (not the classified-retry
+                # transients — those were retried inside the
+                # executor) must not kill the worker and wedge
+                # every stream: every tracked stream gets the
+                # typed failure, its slots are cancelled, the
+                # worker lives on for the next admission
+                self._fail_tracked(exc)
+            progressed = True
+        if (stop and drain and not s.active_slots
+                and not s.pending_requests and not self._slot_stream
+                and not self._rid_stream and not self._beam_stream):
+            self._fail_ops()
+            return False, progressed
+        return True, progressed
 
     def _admit_backlog(self):
         """Admit queued requests and map the newly admitted ones back
@@ -341,18 +360,7 @@ class _DecodeWorker(object):
         except Exception as e:  # noqa: BLE001 - delivered to the stream
             exc = e
         progressed = before != set(s.pending_requests)
-        # newly admitted = owner entries a wire stream is waiting on
-        # (orphaned rids — a restored process's backlog — stay owned
-        # and bank through the pump discipline on finish)
-        for slot, rid in list(s._owner.items()):
-            stream = self._rid_stream.pop(rid, None)
-            if stream is None:
-                continue
-            if stream.cancelled.is_set():
-                self._safe_cancel(slot)
-                continue
-            self._track(stream, {slot: 0})
-            stream.q.put(self._admitted_event(stream))
+        self._hand_off_admitted()
         if exc is not None:
             # the request that failed was popped but neither admitted
             # nor re-deferred: its id is gone from both views
@@ -365,6 +373,22 @@ class _DecodeWorker(object):
                     stream.q.put(error_to_wire(exc))
             progressed = True
         return progressed
+
+    def _hand_off_admitted(self):
+        """Newly admitted = owner entries a wire stream is waiting on
+        (orphaned rids — a restored process's backlog — stay owned and
+        bank through the pump discipline on finish)."""
+        s = self._s
+        with _tracing.span("handoff"):
+            for slot, rid in list(s._owner.items()):
+                stream = self._rid_stream.pop(rid, None)
+                if stream is None:
+                    continue
+                if stream.cancelled.is_set():
+                    self._safe_cancel(slot)
+                    continue
+                self._track(stream, {slot: 0})
+                stream.q.put(self._admitted_event(stream))
 
     def _fail_tracked(self, exc):
         wire = error_to_wire(exc)
@@ -390,8 +414,9 @@ class _DecodeWorker(object):
                 # beam's K x worst-case reservation never queues);
                 # per-dispatch survivor chunks stream from _step_once,
                 # the final n-best from the session's result bank
-                lane = s.admit_beam(spec["src"], spec["src_len"],
-                                    prefix_tokens=spec["prefix"])
+                with _tracing.span("admit"):
+                    lane = s.admit_beam(spec["src"], spec["src_len"],
+                                        prefix_tokens=spec["prefix"])
                 stream.beam_lane = lane
                 stream.beam_rid = s.register_beam_owner(lane)
                 self._beam_stream[lane] = stream
@@ -433,9 +458,11 @@ class _DecodeWorker(object):
                 # forks are admit-or-reject: their n x worst-case page
                 # reservation is too large to head-of-line park in the
                 # backlog (docs/SERVING.md "Network front end")
-                slots = s.admit_group(
-                    spec["src"], n=spec["n"], src_len=spec["src_len"],
-                    prefix_tokens=spec["prefix"])
+                with _tracing.span("admit"):
+                    slots = s.admit_group(
+                        spec["src"], n=spec["n"],
+                        src_len=spec["src_len"],
+                        prefix_tokens=spec["prefix"])
                 self._track(stream,
                             {slot: m for m, slot in enumerate(slots)})
                 self._trace_admitted(stream, t_admit, kind="group")
@@ -511,11 +538,12 @@ class _DecodeWorker(object):
         if not tid:
             return
         tr = _tracing.inflight_get(tid)
-        if tr is not None:
-            tr.span("admit", t_admit, time.time(), kind=kind,
-                    members=len(stream.live))
+        if tr is None:
+            return
+        tr.span("admit", t_admit, time.time(), kind=kind,
+                members=len(stream.live), round=_tracing.round_id())
         for slot in stream.live:
-            self._s._slot_traces[slot] = tid
+            self._s._slot_traces[slot] = tr
 
     def _track(self, stream, slots_members):
         s = self._s
@@ -561,7 +589,21 @@ class _DecodeWorker(object):
 
     def _step_once(self):
         s = self._s
+        if _tracing.ENABLED:
+            # what the round's length depends on, beside its spans
+            _tracing.round_count("live", len(s._live))
+            _tracing.round_count("backlog", len(s._pending))
         finished = s.step()
+        with _tracing.span("handoff"):
+            tokens = self._hand_off(finished)
+        if _tracing.ENABLED:
+            _tracing.round_count("tokens", tokens)
+
+    def _hand_off(self, finished):
+        """One dispatch's results into the streams' queues. Returns the
+        tokens in the events put."""
+        s = self._s
+        tokens = 0
         # beam streams: one survivor chunk per dispatch (parents +
         # selected tokens + scores + done flags — what a live client
         # renders), the final n-best from the session's bank
@@ -574,6 +616,7 @@ class _DecodeWorker(object):
                           "tokens": [int(t) for t in ev["tokens"]],
                           "scores": [float(x) for x in ev["scores"]],
                           "done": [bool(d) for d in ev["done"]]})
+            tokens += len(ev["tokens"])
         for lane, fin in getattr(s, "last_finished_beams", {}).items():
             stream = self._beam_stream.pop(lane, None)
             if stream is None:
@@ -618,6 +661,7 @@ class _DecodeWorker(object):
                 stream.q.put(end_ev)
                 stream.done = True
                 stream.q.put({"ok": True, "event": "end"})
+                tokens += len(fin["step_tokens"])
         for slot in list(self._slot_stream):
             stream, member = self._slot_stream[slot]
             prev = self._prev_pos[slot]
@@ -642,6 +686,7 @@ class _DecodeWorker(object):
                         ev["id"] = int(stream.rid)
                         ev["seq"] = int(prev + 1)
                     stream.q.put(ev)
+                    tokens += len(toks)
                 if not stream.live and not stream.done:
                     stream.done = True
                     if not stream.cancelled.is_set():
@@ -663,6 +708,7 @@ class _DecodeWorker(object):
                         ev["id"] = int(stream.rid)
                         ev["seq"] = int(prev + 1)
                     stream.q.put(ev)
+                    tokens += new - prev
                 self._prev_pos[slot] = new
         # orphaned finishes (no stream — a restored process's backlog):
         # bank exactly like pump(), so take_result can claim them
@@ -677,6 +723,7 @@ class _DecodeWorker(object):
                 # the trace banks with the result, claimable metadata
                 # rides take_result
                 s._trace_bank(rid)
+        return tokens
 
     def _safe_cancel(self, slot):
         """Session cancel that can never kill the worker thread: the

@@ -467,7 +467,7 @@ class SlotDecodeSession(object):
         self.steps_done = 0      # step() dispatches completed (chaos key)
         # request tracing (observability/tracing.py): rid -> trace id
         # rides the decode snapshot, so a restored process re-emits its
-        # banked streams under the ORIGINAL ids; slot -> trace id is
+        # banked streams under the ORIGINAL ids; slot -> Trace is
         # runtime rebind state admissions rebuild. Both stay empty with
         # FLAGS_request_tracing off — every hot-path hook gates on that.
         self._trace_ids = {}
@@ -492,8 +492,11 @@ class SlotDecodeSession(object):
             self._monitor = None
 
     def _run(self, prog, feed, fetch_list):
-        return self._exe.run(prog, feed=feed, fetch_list=fetch_list,
-                             scope=self._scope)
+        # with tracing on and a decode worker's round open, each
+        # executor call is a child ``<open span>.dispatch``
+        with _tracing.span(".dispatch"):
+            return self._exe.run(prog, feed=feed, fetch_list=fetch_list,
+                                 scope=self._scope)
 
     # -- preemption / degradation plumbing ----------------------------------
     def _begin_op(self):
@@ -1466,6 +1469,10 @@ class SlotDecodeSession(object):
         return self._cancel_one(slot)
 
     def _cancel_one(self, slot):
+        with _tracing.span("cancel"):
+            return self._cancel_slot(slot)
+
+    def _cancel_slot(self, slot):
         self._begin_op()
         try:
             del self._live[slot]
@@ -1519,6 +1526,10 @@ class SlotDecodeSession(object):
         when nothing is in flight."""
         if not self._live:
             return {}
+        with _tracing.span("step", cpu=True):
+            return self._step()
+
+    def _step(self):
         traced = bool(self._slot_traces) and _tracing.ENABLED
         if traced:
             t_step = time.time()
@@ -1612,10 +1623,11 @@ class SlotDecodeSession(object):
             [(slot, st["pos"]) for slot, st in self._live.items()]))
         self._update_pool_gauges()
         t0 = time.perf_counter()
-        (toks,) = self._exe.run_multi_step(
-            self._step_prog, self._steps, feed={},
-            fetch_list=[self._fetch_name], scope=self._scope,
-            stack_fetches=True)
+        with _tracing.span(".dispatch"):
+            (toks,) = self._exe.run_multi_step(
+                self._step_prog, self._steps, feed={},
+                fetch_list=[self._fetch_name], scope=self._scope,
+                stack_fetches=True)
         elapsed = time.perf_counter() - t0
         toks = np.asarray(toks)  # [K, S, 1]
         live_before = len(self._live)
@@ -1755,6 +1767,7 @@ class SlotDecodeSession(object):
             # restored entry's queue span starts at its re-admission
             self._trace_ids[rid] = str(trace_id)
             entry["t_enq"] = time.time()
+            entry["round"] = _tracing.round_id()
         self._pending.append(entry)
         return rid
 
@@ -1784,43 +1797,46 @@ class SlotDecodeSession(object):
         admitted THIS call — what a streaming front end needs to map
         slots back to their wire streams before the next step
         dispatch."""
-        from paddle_tpu.serving.degradation import DegradedError
-
         admitted = {}
         while self._pending and self._free:
-            # the pop -> admit -> owner-record sequence is ONE dispatch
-            # window: a quiesce-point snapshot (or deferred SIGTERM)
-            # firing inside admit's own window would otherwise see the
-            # request in neither _pending nor _owner — a request lost
-            # across the restore
-            self._begin_op()
-            deferred = False
-            try:
-                req = self._pending.popleft()
-                traced = req["id"] in self._trace_ids
-                t_admit = time.time() if traced else 0.0
-                try:
-                    slot = self.admit(req["src"], req["len"],
-                                      prefix_tokens=req["prefix"])
-                except (NoFreePageError, NoFreeGroupError,
-                        DegradedError):
-                    # capacity/degradation reject: defer and let
-                    # in-flight sequences drain — guaranteed progress,
-                    # since the constructor requires the pool to cover
-                    # one sequence and a shed monitor relaxes as the
-                    # pool empties
-                    self._pending.appendleft(req)
-                    deferred = True
-                else:
-                    self._owner[slot] = req["id"]
-                    admitted[slot] = req["id"]
-                    if traced:
-                        self._trace_admitted(req, slot, t_admit)
-            finally:
-                self._end_op()
-            if deferred:
+            with _tracing.span("admit"):
+                slot = self._admit_next()
+            if slot is None:
                 break
+            admitted[slot] = self._owner[slot]
         return admitted
+
+    def _admit_next(self):
+        """Admit the request at the head of the queue; its slot, or
+        None when it was deferred back to the front."""
+        from paddle_tpu.serving.degradation import DegradedError
+
+        # the pop -> admit -> owner-record sequence is ONE dispatch
+        # window: a quiesce-point snapshot (or deferred SIGTERM) firing
+        # inside admit's own window would otherwise see the request in
+        # neither _pending nor _owner — a request lost across the
+        # restore
+        self._begin_op()
+        try:
+            req = self._pending.popleft()
+            traced = req["id"] in self._trace_ids
+            t_admit = time.time() if traced else 0.0
+            try:
+                slot = self.admit(req["src"], req["len"],
+                                  prefix_tokens=req["prefix"])
+            except (NoFreePageError, NoFreeGroupError, DegradedError):
+                # capacity/degradation reject: defer and let in-flight
+                # sequences drain — guaranteed progress, since the
+                # constructor requires the pool to cover one sequence
+                # and a shed monitor relaxes as the pool empties
+                self._pending.appendleft(req)
+                return None
+            self._owner[slot] = req["id"]
+            if traced:
+                self._trace_admitted(req, slot, t_admit)
+            return slot
+        finally:
+            self._end_op()
 
     def pump(self):
         """One scheduler round: :meth:`admit_pending`, then one
@@ -1878,14 +1894,16 @@ class SlotDecodeSession(object):
                                 origin="session")
         t_enq = req.get("t_enq")
         if t_enq is not None:
-            tr.span("queue", t_enq, t_admit, rid=int(rid))
+            tr.span("queue", t_enq, t_admit, rid=int(rid),
+                    round=req.get("round"))
         hit_pages = (getattr(self._prefix_cache, "last_hit_pages", 0)
                      if self._paged and self._prefix_cache is not None
                      else 0)
         tr.span("prefill", t_admit, time.time(), kind="solo",
                 slot=int(slot), rid=int(rid),
-                prefix_hit_pages=int(hit_pages))
-        self._slot_traces[slot] = tid
+                prefix_hit_pages=int(hit_pages),
+                round=_tracing.round_id())
+        self._slot_traces[slot] = tr
 
     def _trace_bank(self, rid):
         """Close a session-origin continuation trace when its result
@@ -1904,11 +1922,12 @@ class SlotDecodeSession(object):
         integration, retire the rid binding, and close session-origin
         traces — a cancelled request must never leave an open span in
         flight (the ring sweep in tests/test_tracing.py pins this)."""
-        tid = self._slot_traces.pop(slot, None)
-        if rid is not None:
-            tid = self._trace_ids.pop(int(rid), None) or tid
-        tr = _tracing.inflight_get(tid) if tid is not None else None
-        if tr is None:
+        tr = self._slot_traces.pop(slot, None)
+        tid = (self._trace_ids.pop(int(rid), None) if rid is not None
+               else None)
+        if (tr is None or tr.done) and tid is not None:
+            tr = _tracing.inflight_get(tid)
+        if tr is None or tr.done:
             return
         tr.sample_pages(0)
         if tr.origin == "session":
@@ -1926,16 +1945,27 @@ class SlotDecodeSession(object):
         """Post-dispatch span emission for every traced slot that was
         live when the step launched: one ``decode.step`` span per slot
         (tokens committed, COW copies coalesced for it, speculative or
-        sequential), accumulator bumps for the derived stats, and a
-        page-seconds sample per trace (summed across a group's slots).
-        Runs OUTSIDE the dispatch window — host-only bookkeeping."""
-        touched = set()
+        sequential, the worker's round), accumulator bumps for the
+        derived stats, and a page-seconds sample per trace (summed
+        across a group's slots). One pass over the bound slots, no
+        lock: a slot is bound to its Trace at admission. Runs OUTSIDE
+        the dispatch window — host-only bookkeeping."""
+        round_id = _tracing.round_id()
+        pages = {}   # Trace -> pages its still-bound slots hold
         for slot, prev in pre_pos.items():
-            tid = self._slot_traces.get(slot)
-            tr = (_tracing.inflight_get(tid) if tid is not None
-                  else None)
+            tr = self._slot_traces.get(slot)
             if tr is None:
                 continue
+            if tr.done:
+                # finished by its handler while the slot decodes on: a
+                # trace re-started under the id (a re-attached stream)
+                # takes the slot's spans from here on; else the slot is
+                # unbound, so this lookup is made once
+                tr = _tracing.inflight_get(tr.id)
+                if tr is None:
+                    del self._slot_traces[slot]
+                    continue
+                self._slot_traces[slot] = tr
             finished_here = slot not in self._live
             if finished_here:
                 trg = out.get(slot)
@@ -1946,7 +1976,7 @@ class SlotDecodeSession(object):
             cow = self._trace_cow.pop(slot, 0)
             tr.span("decode.step", t0, t1, slot=int(slot),
                     tokens=int(delta), cow_copies=int(cow),
-                    speculative=bool(was_spec))
+                    speculative=bool(was_spec), round=round_id)
             if delta > 0:
                 tr.bump("tokens", int(delta))
                 if was_spec:
@@ -1956,17 +1986,13 @@ class SlotDecodeSession(object):
                     tr.bump("tokens_from_spec", int(delta) - 1)
             if cow:
                 tr.bump("cow_copies", int(cow))
+            held = 0
             if finished_here:
                 self._slot_traces.pop(slot, None)
-            touched.add(tid)
-        for tid in touched:
-            tr = _tracing.inflight_get(tid)
-            if tr is None:
-                continue
-            npages = (sum(len(self._slot_pages.get(s, ()))
-                          for s, t in self._slot_traces.items()
-                          if t == tid)
-                      if self._paged else 0)
+            elif self._paged:
+                held = len(self._slot_pages.get(slot, ()))
+            pages[tr] = pages.get(tr, 0) + held
+        for tr, npages in pages.items():
             tr.sample_pages(npages)
 
     def generate(self, src, src_len=None):

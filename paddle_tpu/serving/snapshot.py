@@ -575,10 +575,10 @@ class DecodeSnapshotManager(CheckpointManager):
                 slot = by_rid.get(rid)
                 if slot is None or slot not in s._live:
                     continue
-                if _tracing.inflight_get(tid) is None:
-                    _tracing.start(tid, endpoint="generate",
-                                   origin="session")
-                s._slot_traces[slot] = tid
+                s._slot_traces[slot] = (
+                    _tracing.inflight_get(tid)
+                    or _tracing.start(tid, endpoint="generate",
+                                      origin="session"))
         s._next_req = int(meta["next_req"])
         s.steps_done = int(meta["steps_done"])
         if spec_meta is not None:

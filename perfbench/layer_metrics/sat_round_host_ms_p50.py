@@ -1,0 +1,9 @@
+"""Serving host plane, above the knee: median of a dispatching round's
+host time: the ``round`` span less its ``step.dispatch`` and ``wait``
+children. The inside twin of ``sat_dispatch_gap_p50_ms``."""
+
+from perfbench import program_records as pr
+
+
+def read(records):
+    return pr.read_rounds(records, pr.round_host_ms_p50, log=True)

@@ -360,6 +360,51 @@ def test_cache_stats_exported_through_profiler(cache_dir):
     assert st["compile_seconds_cold"] + st["compile_seconds_warm"] >= 0
 
 
+def test_trace_and_lower_seconds_grow_on_a_fresh_program_only():
+    """Set-up's missing seconds: tracing the program to a jaxpr and
+    lowering it are counted beside the backend compile, once per fresh
+    executable; a cached dispatch adds nothing, and a jitted function
+    traced inside another's trace is not counted twice."""
+    main, startup, out = _build_mlp()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    # earlier tests compiled this very program: drop their executables
+    executor_mod._shared_executables.clear()
+    before = exec_cache.stats()
+    exe.run(main, feed=_feed(), fetch_list=[out])
+    fresh = exec_cache.stats()
+    assert fresh["trace_seconds"] > before["trace_seconds"]
+    assert fresh["lower_seconds"] > before["lower_seconds"]
+    for _ in range(3):
+        exe.run(main, feed=_feed(), fetch_list=[out])
+    cached = exec_cache.stats()
+    assert cached["trace_seconds"] == fresh["trace_seconds"]
+    assert cached["lower_seconds"] == fresh["lower_seconds"]
+
+    import time
+
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        time.sleep(0.05)   # runs while tracing only
+        return x * 2.0
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + inner(x + 1.0)
+
+    t0 = time.perf_counter()
+    outer(jnp.arange(7.0)).block_until_ready()
+    wall = time.perf_counter() - t0
+    traced = exec_cache.stats()["trace_seconds"] - cached["trace_seconds"]
+    # inner is traced once (0.05 s asleep) inside outer's trace; a plain
+    # sum of both reports would read it twice
+    assert 0.05 <= traced <= wall
+    exec_cache.reset_stats()
+    assert exec_cache.stats()["trace_seconds"] == 0.0
+
+
 # -- cache placement ---------------------------------------------------------
 
 _PLACEMENT_PROBE = """

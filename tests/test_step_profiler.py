@@ -206,15 +206,18 @@ def test_detector_names_dispatch_on_injected_stall():
     step_profiler.enable(True)
     try:
         exe.run(startup)
-        # baseline: enough identical steps for the rolling median+MAD
-        # window to open (the detector is silent below _REG_MIN samples)
-        for _ in range(step_profiler._REG_MIN + 2):
-            exe.run(main, feed=_feed(), fetch_list=[loss])
-        assert not any(r.get("regression")
-                       for r in step_profiler.records())
-        # one injected 0.25s stall INSIDE the dispatch bracket
+        exe.run(main, feed=_feed(), fetch_list=[loss])
+        fp = step_profiler.records()[-1]["fingerprint"]
+        # the baseline as numbers (steps timed under six test workers
+        # are not identical): enough 1 ms steps for the rolling
+        # median+MAD window to open (silent below _REG_MIN samples)
+        with step_profiler._lock:
+            step_profiler._reg.pop(fp, None)
+            for _ in range(step_profiler._REG_MIN + 2):
+                assert step_profiler._detect_regression(
+                    fp, 0.001, {"dispatch": 0.0006, "host": 0.0004}) is None
+        # one injected 0.25s stall INSIDE the dispatch bracket, for real
         chaos.configure("slow@site=exec.dispatch,n=1,secs=0.25")
-        rec = None
         exe.run(main, feed=_feed(), fetch_list=[loss])
         rec = [r for r in step_profiler.records()
                if r.get("regression")][-1]
@@ -319,3 +322,133 @@ def test_jsonl_flush_and_perf_ledger_round_trip(tmp_path):
     with pytest.raises(SystemExit) as ex:
         perf_ledger.main(["diff", "--ledger", str(ledger)])
     assert ex.value.code == 1
+
+
+# -- the always-on dispatch record -------------------------------------------
+
+def _run_single(exe, main, loss):
+    exe.run(main, feed=_feed(), fetch_list=[loss])
+
+
+def _run_async(exe, main, loss):
+    exe.run_async(main, feed=_feed(), fetch_list=[loss]).result()
+
+
+def _run_multi(exe, main, loss):
+    exe.run_multi_step(main, 4, feed=_feed(), fetch_list=[loss])
+
+
+@pytest.mark.parametrize("origin,call,waits", [
+    ("single", _run_single, True), ("async", _run_async, False),
+    ("multi_step", _run_multi, True)])
+def test_dispatch_records_with_the_flag_off(origin, call, waits):
+    """Every executor dispatch leaves its small record with
+    FLAGS_step_profile off, the observatory's own ring stays empty, and
+    the phases (the residual ``host`` included) sum to the wall."""
+    import time
+
+    main, startup, loss = _build_mlp()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    step_profiler.reset()
+    t_before = time.time()
+    for _ in range(3):
+        call(exe, main, loss)
+    assert step_profiler.records() == []
+    assert step_profiler.inflight() == []
+    recs = step_profiler.dispatch_records()
+    assert [r["origin"] for r in recs] == [origin] * 3
+    assert step_profiler.dispatch_records(origin) == recs
+    assert step_profiler.dispatch_records("no-such-origin") == []
+    for r in recs:
+        assert set(r["phases"]) <= set(step_profiler.PHASES)
+        assert r["phases"]["dispatch"] > 0.0
+        assert ("device" in r["phases"]) == waits
+        assert sum(r["phases"].values()) == pytest.approx(r["wall_s"])
+        measured = sum(v for p, v in r["phases"].items() if p != "host")
+        assert measured <= r["wall_s"] * (1 + 1e-9)
+        # the end stamp is on time.time(), the request traces' clock
+        assert t_before <= r["t1"] <= time.time()
+    assert [r["t1"] for r in recs] == sorted(r["t1"] for r in recs)
+
+
+@pytest.mark.parametrize("call", [_run_single, _run_multi])
+def test_a_dispatch_that_raises_leaves_no_inflight_entry(call, monkeypatch):
+    """Only ``finish`` pops the thread's in-flight entry, and the
+    brackets run with the flag off: a dispatch that raises inside them
+    must not leave the watchdog a phase that stalls for ever."""
+    main, startup, loss = _build_mlp()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    call(exe, main, loss)
+    seen = []
+    enter = step_profiler.StepSpan.enter
+
+    def boom(self, phase):
+        enter(self, phase)
+        if phase == "dispatch":
+            seen.append(step_profiler.inflight())
+            raise RuntimeError("injected")
+
+    monkeypatch.setattr(step_profiler.StepSpan, "enter", boom)
+    with pytest.raises(RuntimeError, match="injected"):
+        call(exe, main, loss)
+    monkeypatch.undo()
+    assert seen and seen[0][0]["phase"] == "dispatch"
+    assert step_profiler.inflight() == []
+    call(exe, main, loss)  # and the executor lives on
+    assert step_profiler.inflight() == []
+
+
+def test_parallel_executor_dispatch_records_with_the_flag_off():
+    from paddle_tpu.parallel_executor import ParallelExecutor
+
+    main, startup, loss = _build_mlp()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    pe = ParallelExecutor(loss_name=loss.name, main_program=main,
+                          use_tpu=False)
+    step_profiler.reset()
+    for _ in range(2):
+        pe.run(fetch_list=[loss], feed=_feed(bs=pe.device_count))
+    assert step_profiler.records() == []
+    recs = step_profiler.dispatch_records("parallel")
+    assert len(recs) == 2 == len(step_profiler.dispatch_records())
+    for r in recs:
+        assert r["phases"]["dispatch"] > 0.0 and "device" in r["phases"]
+        assert sum(r["phases"].values()) == pytest.approx(r["wall_s"])
+
+
+def test_dispatch_ring_is_bounded_and_the_flag_adds_the_step_record():
+    for i in range(step_profiler.DISPATCH_RING_CAP + 3):
+        step_profiler.finish(step_profiler.begin("ring"))
+    assert len(step_profiler.dispatch_records()) == \
+        step_profiler.DISPATCH_RING_CAP
+    assert step_profiler.records() == []
+    step_profiler.reset()
+    assert step_profiler.dispatch_records() == []
+    step_profiler.enable(True)
+    try:
+        sp = step_profiler.begin("both")
+        sp.enter("dispatch")
+        sp.exit()
+        rec = step_profiler.finish(sp)
+    finally:
+        step_profiler.enable(False)
+    (light,) = step_profiler.dispatch_records()
+    assert light["origin"] == "both" == rec["origin"]
+    assert light["wall_s"] == rec["wall_s"]
+    assert light["phases"]["dispatch"] == rec["phases"]["dispatch"]
+    assert step_profiler.finish(step_profiler.begin("off")) is None
+
+
+def test_device_annotation_is_a_plain_trace_annotation():
+    """Whoever opened the profiler session finds the device wait under
+    the program's prefix; no session, no cost and no error."""
+    from jax.profiler import TraceAnnotation
+
+    ann = step_profiler.device_annotation()
+    assert isinstance(ann, TraceAnnotation)
+    with ann:
+        pass
+    assert not hasattr(step_profiler, "_NullAnnotation")

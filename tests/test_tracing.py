@@ -327,3 +327,311 @@ def test_tracing_off_session_allocates_nothing(trained):
     assert sess._trace_ids == {} and sess._slot_traces == {}
     assert sess._trace_cow == {}
     assert tracing.completed() == [] and not tracing.inflight_ids()
+
+
+# -- the decode worker's rounds ----------------------------------------------
+
+def _round_spans(rounds):
+    return [(r, i, sp) for r in rounds for i, sp in enumerate(r["spans"])]
+
+
+@pytest.fixture(scope="module")
+def served(trained):
+    """One traced scenario through a frontend over a tiny greedy session
+    (greedy never samples eos here: every stream runs to max length, four
+    dispatches): three wire streams, a fork group of two, and one stream
+    cancelled while its slot is live. Returns copies: the autouse fixture
+    resets the rings around every test."""
+    import time
+
+    from paddle_tpu.serving.client import ServingClient
+    from paddle_tpu.serving.frontend import ServingFrontend, _Stream
+
+    src = trained["src"]
+    tracing.reset()
+    tracing.enable(True)
+    sess = _paged(trained, sampler=None, prefix_cache_pages=0)
+    read_tokens = 0
+    try:
+        with ServingFrontend(session=sess) as fe:
+            cl = ServingClient(fe.address)
+            for i in range(3):
+                for ev in cl.generate(src[i], src_len=SEQ):
+                    if ev["event"] == "tokens":
+                        read_tokens += len(ev["tokens"])
+            for ev in cl.generate(src[3], src_len=SEQ, n=2):
+                if ev["event"] == "tokens":
+                    read_tokens += len(ev["tokens"])
+            cl.close()
+            # the cancelled stream, handed to the worker directly so that
+            # the cancel lands while the slot is live: the worker is held
+            # at the top of a pass (its condition) while we look and cancel
+            worker = fe._decode
+            tr = tracing.start(endpoint="generate")
+            stream = _Stream({"src": src[4], "src_len": SEQ, "n": 1,
+                              "prefix": None, "beam": False,
+                              "len_penalty": None, "trace_id": tr.id})
+            worker.submit(stream)
+            while stream.q.get(timeout=60)["event"] != "admitted":
+                pass
+            with worker._cond:
+                was_live = bool(stream.live)
+                worker.cancel(stream)
+            deadline = time.monotonic() + 60
+            while time.monotonic() < deadline and not (
+                    sess.free_slots == S and sess.pool_conserved):
+                time.sleep(0.01)
+            cancelled = tracing.finish(tr, outcome="cancelled")
+        out = {"rounds": tracing.rounds(), "traces": tracing.completed(),
+               "read_tokens": read_tokens, "was_live": was_live,
+               "cancelled": cancelled, "drained": sess.free_slots == S}
+    finally:
+        tracing.enable(False)
+        tracing.reset()
+    return out
+
+
+def test_every_child_span_lies_inside_its_round_and_names_it(served):
+    rounds = served["rounds"]
+    assert rounds and len({r["id"] for r in rounds}) == len(rounds)
+    names = set()
+    for r, i, sp in _round_spans(rounds):
+        names.add(sp["name"])
+        assert sp["t1"] is not None and sp["t1"] >= sp["t0"]
+        # the thread's CPU time is taken where the worker's host time is
+        # bounded, and nowhere else (two system calls a span)
+        if sp["name"] in ("round", "wait", "step", "step.dispatch"):
+            assert 0.0 <= sp["cpu"] <= sp["t1"] - sp["t0"] + 0.005
+        else:
+            assert sp["cpu"] is None
+        if i == 0:
+            assert sp["name"] == "round" and sp["parent"] is None
+            continue
+        parent = r["spans"][sp["parent"]]
+        assert sp["parent"] < i
+        assert parent["t0"] <= sp["t0"] and sp["t1"] <= parent["t1"]
+        if sp["name"].endswith(".dispatch"):
+            # an executor call, named after the span it ran under
+            assert sp["name"] == parent["name"] + ".dispatch"
+    assert {"round", "wait", "enqueue", "admit", "admit.dispatch", "step",
+            "step.dispatch", "handoff", "cancel",
+            "cancel.dispatch"} <= names
+
+
+def test_child_spans_cover_the_rounds_wall(served):
+    """Per round the children account for 95% of the wall: in the median
+    round and over all rounds together (one round in which the scheduler
+    took the thread between two spans must not fail the suite). A round
+    that only queued a request is all bookkeeping: the rounds that
+    dispatched are the ones measured."""
+    dispatched = [r for r in served["rounds"]
+                  if any(sp["name"] == "step" for sp in r["spans"])]
+    assert len(dispatched) >= 8
+    walls = [r["spans"][0]["t1"] - r["spans"][0]["t0"] for r in dispatched]
+    covered = [sum(sp["t1"] - sp["t0"] for sp in r["spans"]
+                   if sp["parent"] == 0) for r in dispatched]
+    shares = sorted(c / w for c, w in zip(covered, walls))
+    assert shares[len(shares) // 2] >= 0.95, shares
+    assert sum(covered) >= 0.95 * sum(walls), shares
+
+
+def test_round_counts_equal_what_the_session_did(served):
+    rounds = served["rounds"]
+    assert served["drained"] and served["was_live"]
+
+    def total(key):
+        return sum(r["spans"][0].get(key, 0) for r in rounds)
+
+    def spans(name):
+        return sum(sp["name"] == name
+                   for _r, _i, sp in _round_spans(rounds))
+
+    # three solo streams, a group of two (ONE admission of two members),
+    # the cancelled stream: admissions and cancels are counted by their
+    # spans, the round keeps no second count of them
+    assert spans("admit") == 5 and spans("cancel") == 1
+    # tokens handed to the streams: all the clients read, plus at most
+    # what the cancelled stream was handed before its cancel landed
+    assert served["read_tokens"] == 5 * (SEQ - 1)
+    assert served["read_tokens"] <= total("tokens") \
+        <= served["read_tokens"] + (SEQ - 1)
+    for r in rounds:
+        root = r["spans"][0]
+        if "live" in root:
+            assert 1 <= root["live"] <= S and root["backlog"] >= 0
+            assert any(sp["name"] == "step" for sp in r["spans"])
+    # nothing but the documented keys: every other count had no reader
+    for _r, i, sp in _round_spans(rounds):
+        extra = set(sp) - {"name", "t0", "t1", "cpu", "parent"}
+        assert extra <= ({"live", "backlog", "tokens"} if i == 0
+                         else set()), sp
+
+
+def test_request_spans_name_a_round_in_the_ring(served):
+    ids = {r["id"] for r in served["rounds"]}
+    seen = 0
+    for rec in served["traces"]:
+        for sp in rec["spans"]:
+            if sp["name"] in ("prefill", "decode.step", "admit"):
+                assert sp["meta"]["round"] in ids, sp
+                seen += 1
+        at = {sp["name"]: sp["meta"]["round"] for sp in rec["spans"]
+              if sp["name"] in ("queue", "prefill")}
+        if "queue" in at:
+            # queued in the worker's pass that took it off the wire,
+            # admitted in that pass or a later one
+            assert at["queue"] is not None
+            assert at["queue"] <= at["prefill"]
+        by_slot = {}
+        for sp in rec["spans"]:
+            if sp["name"] == "decode.step":
+                by_slot.setdefault(sp["meta"]["slot"], []).append(
+                    sp["meta"]["round"])
+        # consecutive dispatches of a slot fall into later rounds
+        for steps in by_slot.values():
+            assert steps == sorted(set(steps))
+    assert seen >= 5 + 5 * 4
+
+
+def test_cancelled_stream_leaves_no_open_span(served):
+    rec = served["cancelled"]
+    assert rec["outcome"] == "cancelled"
+    _sweep_ring([rec] + served["traces"])
+    assert any(sp["name"] == "decode.step" for sp in rec["spans"])
+    cancels = [(r, sp) for r, _i, sp in _round_spans(served["rounds"])
+               if sp["name"] == "cancel"]
+    assert len(cancels) == 1
+    r, sp = cancels[0]
+    kids = [c for c in r["spans"]
+            if c["parent"] == r["spans"].index(sp)]
+    assert [c["name"] for c in kids] == ["cancel.dispatch"]
+    assert all(c["t1"] is not None for c in r["spans"])
+
+
+def test_tracing_off_round_ring_stays_empty(trained):
+    from paddle_tpu.serving.client import ServingClient
+    from paddle_tpu.serving.frontend import ServingFrontend
+
+    tracing.enable(False)
+    sess = _paged(trained, sampler=None, prefix_cache_pages=0)
+    with ServingFrontend(session=sess) as fe:
+        cl = ServingClient(fe.address)
+        out = cl.generate_full(trained["src"][0], src_len=SEQ)
+        cl.close()
+    assert out.shape == (1, SEQ)
+    assert tracing.rounds() == [] and tracing.round_id() is None
+    assert tracing.completed() == []
+
+
+def test_round_spans_are_annotations_with_the_programs_prefix(
+        monkeypatch):
+    """Each round span is also a TraceAnnotation ``pt:<name>``, opened
+    and closed in nesting order; a session driven with no round open
+    writes none."""
+    log = []
+
+    class Ann(object):
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("in", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("out", self.name))
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", Ann)
+    with tracing.span("admit"):
+        pass
+    assert log == []
+    rd = tracing.round_begin()
+    with tracing.span("admit"):
+        with tracing.span(".dispatch"):
+            pass
+    tracing.round_count("tokens", 4)
+    assert tracing.round_id() == rd.id
+    tracing.round_end(rd)
+    assert tracing.ANNOTATION_PREFIX == "pt:"
+    assert log == [("in", "pt:round"), ("in", "pt:admit"),
+                   ("in", "pt:admit.dispatch"), ("out", "pt:admit.dispatch"),
+                   ("out", "pt:admit"), ("out", "pt:round")]
+    (rec,) = tracing.rounds()
+    assert [sp["name"] for sp in rec["spans"]] == [
+        "round", "admit", "admit.dispatch"]
+    assert rec["spans"][0]["tokens"] == 4
+    # a pass that moved nothing is dropped, whatever it left open
+    idle = tracing.round_begin()
+    idle.begin("wait")
+    tracing.round_end(idle, keep=False)
+    assert len(tracing.rounds()) == 1 and tracing.round_id() is None
+
+
+def test_trace_step_is_linear_and_takes_no_module_lock(trained,
+                                                        monkeypatch):
+    """The slot is bound to its Trace at admission: the per-dispatch hook
+    looks nothing up under the module lock while the trace is open, and
+    page-seconds still integrate."""
+    src, src_len = trained["src"], trained["src_len"]
+    sess = _paged(trained, sampler=None, prefix_cache_pages=0)
+    for i in range(3):
+        sess.enqueue(src[i], int(src_len[i]), trace_id=tracing.mint_id())
+    sess.admit_pending()
+    assert all(isinstance(tr, tracing.Trace)
+               for tr in sess._slot_traces.values())
+    lookups = []
+    real = tracing.inflight_get
+    monkeypatch.setattr(tracing, "inflight_get",
+                        lambda tid: lookups.append(tid) or real(tid))
+    sess.step()
+    sess.step()
+    assert lookups == []
+    for tr in sess._slot_traces.values():
+        assert tr.acc["page_seconds"] > 0
+        assert sum(sp["name"] == "decode.step" for sp in tr.spans) == 2
+    for slot in list(sess.active_slots):
+        sess.cancel(slot)
+    assert not sess._slot_traces and sess.pool_conserved
+
+
+def test_baggage_is_gone():
+    tr = tracing.start(endpoint="generate")
+    rec = tracing.finish(tr)
+    assert "baggage" not in rec and not hasattr(tr, "baggage")
+    with pytest.raises(TypeError):
+        tracing.start(baggage={"k": "v"})
+    assert tracing.RING == 4096 and tracing.ROUND_RING >= 1000
+
+
+def test_trace_view_counts_a_requests_wait_and_decode_in_rounds(
+        served, capsys):
+    """``round=`` on the request spans has a reader: the waterfall says
+    how many passes of the worker a request queued and decoded
+    through."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_view", os.path.join(os.path.dirname(__file__), os.pardir,
+                                   "tools", "trace_view.py"))
+    view = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(view)
+    solo = [rec for rec in served["traces"]
+            if any(sp["name"] == "queue" for sp in rec["spans"])]
+    assert len(solo) >= 3
+    full = []
+    for rec in solo:
+        waited, decoded = view._rounds(rec["spans"])
+        assert waited is not None and 0 <= waited <= 1
+        # a stream runs to max length: four dispatches, four rounds (the
+        # cancelled one fewer)
+        assert 1 <= decoded <= 4
+        full += [rec] if decoded == 4 else []
+    assert len(full) >= 3
+    view._waterfall(full[0])
+    out = capsys.readouterr().out
+    assert "queue_rounds=" in out and "decode_rounds=4" in out
+    assert " round=" in out
+    # spans that fell into no round count nothing
+    assert view._rounds([{"name": "queue", "meta": {"round": None}},
+                         {"name": "decode.step", "meta": {}}]) \
+        == (None, None)

@@ -11,7 +11,8 @@ views:
    to the request wall, key meta inline (tokens, cow_copies,
    prefix_hit_pages, speculative) and the derived SLO stats underneath
    (TTFT, queue/prefill/decode split, inter-token p50/p95, page-seconds,
-   speculation fraction, span coverage).
+   speculation fraction, span coverage, and the decode worker's rounds
+   the request queued and decoded through).
 2. ``--slowest N`` — only the N slowest requests by wall time (the
    "which request blew the p99" workflow: the serving histogram's bucket
    exemplar names a trace id, ``--trace`` pulls its waterfall).
@@ -39,7 +40,23 @@ BAR_W = 40
 # meta keys worth a column in the waterfall line (everything else is in
 # the Perfetto export's args)
 _META_KEYS = ("tokens", "cow_copies", "prefix_hit_pages", "speculative",
-              "kind", "members", "batch", "force_closed")
+              "kind", "members", "batch", "force_closed", "round")
+
+
+def _rounds(spans):
+    """(decode-worker rounds the request waited in the queue, rounds it
+    decoded in), from the ``round=`` its spans carry; None where a span
+    fell into no round (a session driven without a frontend)."""
+    at = {}
+    for sp in spans:
+        rnd = (sp.get("meta") or {}).get("round")
+        if rnd is not None:
+            at.setdefault(sp["name"], []).append(rnd)
+    admitted = at.get("prefill") or at.get("admit")
+    waited = (admitted[0] - at["queue"][0]
+              if admitted and "queue" in at else None)
+    decoded = len(set(at["decode.step"])) if "decode.step" in at else None
+    return waited, decoded
 
 
 def _load_traces_jsonl(path):
@@ -69,7 +86,8 @@ def _load_traces_jsonl(path):
 
 
 def _fmt_meta(meta):
-    parts = ["%s=%s" % (k, meta[k]) for k in _META_KEYS if k in meta]
+    parts = ["%s=%s" % (k, meta[k]) for k in _META_KEYS
+             if meta.get(k) is not None]
     return (" " + " ".join(parts)) if parts else ""
 
 
@@ -111,6 +129,11 @@ def _waterfall(rec):
             line.append(fmt % stats[key])
     if stats.get("tokens"):
         line.append("tokens=%d" % stats["tokens"])
+    # round ids count every pass of the worker, so a difference of ids
+    # is a number of passes
+    for key, val in zip(("queue_rounds", "decode_rounds"), _rounds(spans)):
+        if val is not None:
+            line.append("%s=%d" % (key, val))
     print(" ".join(line))
 
 
