@@ -68,6 +68,11 @@ _STAT_KEYS = (
     "aot_hits",              # whole executables deserialized from disk
     "aot_misses",
     "aot_errors",            # corrupt/incompatible AOT entries tolerated
+    # the executor's state gather (executor._GatherPlan): runs that found
+    # their (executable, scope) holders kept from last time, and runs
+    # that had to look them up (first run, or a name came or went)
+    "gather_plan_hits",
+    "gather_plan_rebuilds",
 )
 
 _stats = {k: 0 for k in _STAT_KEYS}
@@ -172,6 +177,12 @@ def record_trace_hit():
 def record_trace_miss():
     with _lock:
         _stats["trace_cache_misses"] += 1
+
+
+def record_gather_plan(rebuilt):
+    with _lock:
+        _stats["gather_plan_rebuilds" if rebuilt
+               else "gather_plan_hits"] += 1
 
 
 def stats():

@@ -12,6 +12,8 @@ dedups the recompute.
 
 import threading
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
@@ -212,6 +214,50 @@ def _make_rng(step_key, attrs):
     return rng
 
 
+# -- the step key --------------------------------------------------------------
+# A run's key is fold_in(PRNGKey(seed), run counter). Made eagerly that is
+# seven primitive binds, each a Python dispatch and a tiny executable of
+# its own; so the executors hand the step's executable the two halves,
+# ``(base_key(seed), np.uint32(counter))``, and the fold is traced into
+# it (``fold_step_key``). Same primitives on the same integers: the bits
+# are those of the eager key.
+_base_keys = {}
+
+
+def base_key(seed, device=None):
+    """``jax.random.PRNGKey(seed)``, made once per (seed, device) and
+    kept: on ``device``, or as a host array where the caller's executable
+    places its own arguments (a mesh)."""
+    k = (seed, device)
+    key = _base_keys.get(k)
+    if key is None:
+        if len(_base_keys) >= 256:   # seeds are few; never grow unbounded
+            _base_keys.clear()
+        key = jax.random.PRNGKey(seed)
+        key = (np.asarray(key) if device is None
+               else jax.device_put(key, device))
+        _base_keys[k] = key
+    return key
+
+
+def step_key(seed, counter, device=None):
+    """The key of run ``counter`` under ``seed``, as a step's executable
+    takes it: both executors make it here, so they cannot drift."""
+    return base_key(seed, device), np.uint32(counter)
+
+
+def fold_step_key(key):
+    """(base key, run counter) -> the run's key. Traced into the step's
+    executable."""
+    base, counter = key
+    return jax.random.fold_in(base, counter)
+
+
+# the run's key as a value, in ONE dispatch: for whoever cannot take the
+# two halves (the pipeline's executable, the NaN replay)
+step_key_value = jax.jit(fold_step_key)
+
+
 _AMBIENT_MESH = []  # trace-time stack: the mesh a sharded compile runs under
 _AMBIENT_PLATFORM = []  # trace-time stack: platform the compile targets
 
@@ -400,7 +446,7 @@ class CompiledProgram(_LazyExecutable):
         def split_step(mut_state, frozen_state, feeds, key):
             state = dict(frozen_state)
             state.update(mut_state)
-            return step(state, feeds, key)
+            return step(state, feeds, fold_step_key(key))
 
         self.shardings = shardings
         self._init_lazy_exec()
@@ -434,6 +480,7 @@ class CompiledProgram(_LazyExecutable):
             )
 
     def __call__(self, state, feeds, key):
+        """``key`` is the pair ``(base_key(seed), np.uint32(counter))``."""
         mut = {n: state[n] for n in self.mutable_state}
         frz = {n: state[n] for n in self.frozen_state}
         fn = self._resolve_exec((mut, frz, feeds, key))
@@ -484,6 +531,8 @@ class MultiStepProgram(_LazyExecutable):
 
         def multi(mut_state, frozen_state, feeds, key):
             import jax.numpy as jnp
+
+            key = fold_step_key(key)
 
             def body(carry, i):
                 state = dict(frozen_state)

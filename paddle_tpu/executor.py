@@ -14,6 +14,7 @@ mutation).
 
 import threading
 import time
+import weakref
 from collections import OrderedDict
 
 import numpy as np
@@ -38,8 +39,12 @@ from paddle_tpu.core.fingerprint import (
     trace_flags_key,
 )
 from paddle_tpu.core.lod import LoDTensor
-from paddle_tpu.core.lowering import CompiledProgram
-from paddle_tpu.core.scope import Scope
+from paddle_tpu.core.lowering import (
+    CompiledProgram,
+    step_key,
+    step_key_value,
+)
+from paddle_tpu.core.scope import Scope, ScopeVariable
 from paddle_tpu.core.types import Place, TPUPlace, np_dtype
 
 _global_scope = Scope()
@@ -130,6 +135,58 @@ def _maybe_verify(program, feed_specs, fetch_names, origin):
         logging.getLogger("paddle_tpu.analysis").info(
             "verify (%s): %d non-error diagnostic(s): %s", origin,
             len(diags), "; ".join(str(d) for d in diags[:5]))
+
+
+def _on_device(arr, device):
+    """Does this jax.Array live on ``device`` alone?"""
+    return arr.sharding.device_set == {device}
+
+
+def _state_value(name, val, device):
+    """A scope value as the step's executable takes it: on ``device``."""
+    if val is None:
+        raise RuntimeError(
+            "persistable variable %r is not initialized in the scope "
+            "(did you run the startup program?)" % name
+        )
+    if not isinstance(val, jax.Array):
+        return jax.device_put(np.asarray(val), device)
+    if not _on_device(val, device):
+        # Scope value lives on another Place's device (e.g. trained
+        # on TPU, now serving on CPU): move it once.
+        return jax.device_put(val, device)
+    return val
+
+
+_UNSET = object()
+
+
+def _declared_feed_dtypes(program):
+    """{feed name: declared numpy dtype or None} of ``program``, filled as
+    names are fed and dropped when the program changes."""
+    memo = getattr(program, "_feed_dtype_memo", None)
+    if memo is None or memo[0] != program._version:
+        memo = program._feed_dtype_memo = (program._version, {})
+    return memo[1]
+
+
+class _GatherPlan(object):
+    """What one executable's state gather found in one scope: the holders
+    of ``cp.state_in`` in order, per holder a weak reference to the value
+    that last passed the device check (weak: a value replaced or erased
+    from outside is not kept alive here), and after the first write-back
+    the holders of the outputs."""
+
+    __slots__ = ("membership", "holders", "seen", "outs")
+
+    def __init__(self, cp, scope, membership):
+        self.membership = membership
+        self.holders = []
+        for n in cp.state_in:
+            # a name that has left the scope reads as an empty holder does
+            self.holders.append((n, scope.find_var(n) or ScopeVariable(n)))
+        self.seen = [None] * len(self.holders)
+        self.outs = None
 
 
 # On-device finiteness scan for FLAGS_check_nan_inf: one fused executable
@@ -276,7 +333,7 @@ class Executor(object):
         if getattr(program, "_deferred_infer", None):
             program.infer_deferred_shapes(
                 feed_shapes={n: s for n, (s, _d) in feed_specs.items()})
-        scope_names = self._scope_names(scope)
+        scope_names = scope.visible_names()
         device = self.place.jax_device()
         key = (
             # content hash, not id(program): CPython reuses id() after GC,
@@ -287,8 +344,9 @@ class Executor(object):
             # Scope contents shape the step signature (state_in): a var
             # initialized later (e.g. startup program ran) must recompile;
             # the NAME SET is the signature, so scopes holding the same
-            # vars share executables (not id(scope))
-            frozenset(scope_names),
+            # vars share executables (not id(scope)); the scope keeps the
+            # frozenset, and with it its hash, while no name comes or goes
+            scope_names,
             program._is_test,
             getattr(program, "_amp_dtype", None),
             # trace-time flags alter the lowered computation; toggling one
@@ -410,58 +468,87 @@ class Executor(object):
 
     # -- shared run plumbing -------------------------------------------------
     def _prepare_feeds(self, program, feed, device):
-        """numpy/LoDTensor feeds -> (device arrays, (shape, dtype) specs),
-        cast to the declared var dtype when compatible."""
+        """numpy/LoDTensor feeds -> (arrays, (shape, dtype) specs), cast to
+        the declared var dtype when compatible. Host values stay numpy:
+        they go to the device as arguments of the step's executable, whose
+        ``in_shardings`` pin it, and not one ``jax.device_put`` each."""
         feeds = {}
         feed_specs = {}
+        declared = _declared_feed_dtypes(program)
         for name, value in feed.items():
             arr, _lod = _as_feed_array(value, self.place)
-            var = program.global_block()._find_var_recursive(name)
-            if (var is not None and var.dtype
-                    and arr.dtype != np_dtype(var.dtype)):
+            want = declared.get(name, _UNSET)
+            if want is _UNSET:
+                var = program.global_block()._find_var_recursive(name)
+                want = declared[name] = (
+                    np_dtype(var.dtype)
+                    if var is not None and var.dtype else None)
+            if want is not None and arr.dtype != want:
                 if np.issubdtype(arr.dtype, np.floating) or np.issubdtype(
                     arr.dtype, np.integer
                 ):
-                    arr = arr.astype(np_dtype(var.dtype))
-            feeds[name] = jax.device_put(arr, device)
+                    arr = arr.astype(want)
+            if isinstance(arr, jax.Array) and not _on_device(arr, device):
+                arr = jax.device_put(arr, device)
+            feeds[name] = arr
             feed_specs[name] = (tuple(arr.shape), str(arr.dtype))
         return feeds, feed_specs
 
     @staticmethod
-    def _scope_names(scope):
-        names = set()
-        s = scope
-        while s is not None:
-            names.update(s.local_var_names())
-            s = s._parent
-        return names
+    def _gather_state(cp, scope, device):
+        """(plan, {name: value on ``device``}) for ``cp.state_in``. The
+        holders are looked up once per (executable, scope) and kept in the
+        scope while no name enters or leaves its chain; a value is looked
+        at only if it is not the array that passed here, or was written
+        back by ``_write_back``, last time."""
+        membership = scope.membership()
+        plan = scope._gather_plans.get(cp)
+        rebuilt = plan is None or plan.membership != membership
+        if rebuilt:
+            plan = scope._gather_plans[cp] = _GatherPlan(
+                cp, scope, membership)
+        exec_cache.record_gather_plan(rebuilt)
+        state = {}
+        seen = plan.seen
+        for i, (n, holder) in enumerate(plan.holders):
+            val = holder.value
+            ref = seen[i]
+            if val is None or ref is None or ref() is not val:
+                moved = _state_value(n, val, device)
+                # a value that had to be moved stays what it is in the
+                # scope, and is moved again next run, as before
+                seen[i] = weakref.ref(val) if moved is val else None
+                val = moved
+            state[n] = val
+        return plan, state
 
     @staticmethod
-    def _gather_state(state_in, scope, device):
-        state = {}
-        for n in state_in:
-            v = scope.find_var(n)
-            if v is None or v.value is None:
-                raise RuntimeError(
-                    "persistable variable %r is not initialized in the scope "
-                    "(did you run the startup program?)" % n
-                )
-            val = v.value
-            if not isinstance(val, jax.Array):
-                val = jax.device_put(np.asarray(val), device)
-            elif val.sharding.device_set != {device}:
-                # Scope value lives on another Place's device (e.g. trained
-                # on TPU, now serving on CPU): move it once.
-                val = jax.device_put(val, device)
-            state[n] = val
-        return state
+    def _write_back(plan, scope, new_state):
+        """The step's outputs into the scope, through the holders."""
+        outs = plan.outs
+        if outs is None:
+            # find-or-create HERE, as ``scope.set_value`` does, and only
+            # now that the dispatch has succeeded. A holder this creates
+            # changes the scope's membership: the next gather builds the
+            # plan anew, and from then on nothing moves
+            index = {n: i for i, (n, _h) in enumerate(plan.holders)}
+            outs = plan.outs = [
+                (n, scope.var(n), index.get(n, -1)) for n in new_state]
+        seen = plan.seen
+        holders = plan.holders
+        for n, holder, i in outs:
+            val = new_state[n]
+            holder.value = val
+            if i >= 0 and holders[i][1] is holder:
+                seen[i] = weakref.ref(val)
 
-    def _step_key(self, program):
+    def _step_key(self, program, device):
+        """The run's key as the step's executable takes it: the base key
+        of the seed, kept on the device, and the run counter as a host
+        integer; ``lowering.fold_step_key`` folds them inside."""
         self._run_counter += 1
-        return jax.random.fold_in(
-            jax.random.PRNGKey(program.random_seed or self._base_seed),
-            self._run_counter,
-        )
+        return step_key(program.random_seed or self._base_seed,
+                        self._run_counter, device)
 
     @staticmethod
     def _dispatch(cp, state, feeds, key, origin="Executor.dispatch"):
@@ -585,6 +672,10 @@ class Executor(object):
             raise exc
         from paddle_tpu.observability import nan_provenance as _nanprov
 
+        # the replay is eager: it wants the run's key as a value and the
+        # feeds as arrays, which the dispatch itself never made
+        key = step_key_value(key)
+        feeds = {n: jnp.asarray(a) for n, a in feeds.items()}
         _nanprov.enrich_and_raise(
             exc, program, snapshot, feeds, key, steps=steps,
             mutable_state=mutable_state, is_test=program._is_test,
@@ -613,7 +704,6 @@ class Executor(object):
         t0 = sp.t0   # telemetry and the profiler read the span's stamp
         sp.enter("feed")
         feeds, feed_specs = self._prepare_feeds(program, feed, device)
-        sp.exit()
         t_feed = time.perf_counter() if telem else 0.0
         fetch_names = [
             v.name if isinstance(v, framework.Variable) else str(v)
@@ -625,14 +715,12 @@ class Executor(object):
         sp.enter("compile")
         cp = self._get_compiled(program, feed_specs, fetch_names, scope,
                                 refresh=refresh_cache)
-        sp.exit()
         # state gather + step-key derivation assemble the dispatch
         # inputs just like the feed dict does — same bracket, or
         # they'd surface as unattributed host time
         sp.enter("feed")
-        state = self._gather_state(cp.state_in, scope, device)
-        key = self._step_key(program)
-        sp.exit()
+        plan, state = self._gather_state(cp, scope, device)
+        key = self._step_key(program, device)
         # the bracket opens here, not at _dispatch: pre-dispatch
         # work — the profiler's own one-shot cost snapshot, the
         # blackbox record, the nan snapshot — is host dispatch
@@ -664,12 +752,10 @@ class Executor(object):
         nan_snapshot = self._nan_snapshot(cp, state)
         new_state, fetches = self._dispatch(cp, state, feeds, key,
                                             origin="Executor.dispatch")
-        sp.exit()
         # scope writeback is output handling on the host clock —
         # fetch-side work, even when the caller fetched nothing
         sp.enter("fetch")
-        for n, val in new_state.items():
-            scope.set_value(n, val)
+        self._write_back(plan, scope, new_state)
         # the donated inputs are dead once the scope holds the outputs:
         # released here, inside the bracket, and not at this frame's
         # teardown, where no record would see it (hundreds of arrays:
@@ -747,7 +833,6 @@ class Executor(object):
                 for _f in fetches:
                     if hasattr(_f, "block_until_ready"):
                         _f.block_until_ready()
-            sp.exit()
             sp.enter("fetch")
             fetches = _materialize_fetches(fetches, "Executor.run")
             sp.exit()
@@ -817,7 +902,6 @@ class Executor(object):
             sp = _stepprof.begin("multi_step")
             sp.enter("feed")
             feeds, feed_specs = self._prepare_feeds(program, feed, device)
-            sp.exit()
             # cache-key derivation (fingerprint, scope signature) is
             # executable resolution — compile-phase work, exactly as
             # in the single-step path where _get_compiled owns it
@@ -830,11 +914,11 @@ class Executor(object):
                 program.infer_deferred_shapes(
                     feed_shapes={n: s
                                  for n, (s, _d) in feed_specs.items()})
-            scope_names = self._scope_names(scope)
+            scope_names = scope.visible_names()
             key_id = (
                 "multi", program_fingerprint(program), int(steps),
                 tuple(sorted(feed_specs.items())), tuple(fetch_names),
-                frozenset(scope_names), program._is_test,
+                scope_names, program._is_test,
                 getattr(program, "_amp_dtype", None), bool(stack_fetches),
                 trace_flags_key(), (device.platform, device.id),
             )
@@ -849,7 +933,7 @@ class Executor(object):
                     "feed_specs": tuple(sorted(
                         (n, (s, d)) for n, (s, d) in feed_specs.items())),
                     "fetch_names": tuple(fetch_names),
-                    "scope_signature": frozenset(scope_names),
+                    "scope_signature": scope_names,
                     "flags": trace_flags_key(),
                     "device": "%s:%d" % (device.platform, device.id),
                     "mode": "multi_step[%d]" % int(steps),
@@ -873,13 +957,11 @@ class Executor(object):
                 self._cache[key_id] = cp
             else:
                 exec_cache.record_trace_hit()
-            sp.exit()
             # input assembly continues on the host clock: state
             # gather + step-key derivation feed the dispatch
             sp.enter("feed")
-            state = self._gather_state(cp.state_in, scope, device)
-            key = self._step_key(program)
-            sp.exit()
+            plan, state = self._gather_state(cp, scope, device)
+            key = self._step_key(program, device)
             # opens before the pre-dispatch work (cost snapshot,
             # blackbox record, nan snapshot, watchdog guard): host
             # dispatch overhead is charged to dispatch, not left in
@@ -912,10 +994,8 @@ class Executor(object):
                 new_state, fetches = self._dispatch(
                     cp, state, feeds, key,
                     origin="Executor.run_multi_step")
-                sp.exit()
                 sp.enter("fetch")
-                for n, val in new_state.items():
-                    scope.set_value(n, val)
+                self._write_back(plan, scope, new_state)
                 del state  # the donated inputs, released in the bracket
                 if telem:
                     _memory.track_state(cp, program, new_state, mem_dev)
@@ -937,7 +1017,6 @@ class Executor(object):
                         for _f in fetches:
                             if hasattr(_f, "block_until_ready"):
                                 _f.block_until_ready()
-                    sp.exit()
                     sp.enter("fetch")
                     fetches = _materialize_fetches(
                         fetches, "Executor.run_multi_step")

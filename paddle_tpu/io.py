@@ -277,10 +277,8 @@ def save_compiled_inference_model(
             "save_compiled_inference_model: feed_shapes missing %s"
             % missing)
 
-    from paddle_tpu.executor import Executor
-
     lowerer = BlockLowerer(program, 0, is_test=True)
-    scope_names = Executor._scope_names(scope)
+    scope_names = scope.visible_names()
     state_in, _ = lowerer.analyze(scope_names, set(feed_names))
     params = {}
     for n in state_in:

@@ -1761,7 +1761,7 @@ def save_compiled_generator(dirname, batch_size, src_vocab_size,
     import numpy as np
 
     from paddle_tpu.core.lowering import BlockLowerer, build_step_fn
-    from paddle_tpu.executor import Executor, global_scope
+    from paddle_tpu.executor import global_scope
     from paddle_tpu.io import _write_compiled_artifact
 
     scope = scope or global_scope()
@@ -1785,7 +1785,7 @@ def save_compiled_generator(dirname, batch_size, src_vocab_size,
             gen_names.add("gen_%s_%d" % (kind, i))
     cache_names = {n for n in gen_names if "cache" in n}
 
-    scope_names = Executor._scope_names(scope)  # walks parent scopes
+    scope_names = scope.visible_names()  # parent scopes too
     prep_lower = BlockLowerer(prepare, 0, is_test=True)
     p_in, p_out = prep_lower.analyze(scope_names,
                                      {"src_word", "src_len"})

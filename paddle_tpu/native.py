@@ -15,6 +15,7 @@ Usage:
 """
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -53,30 +54,48 @@ def _stale():
 
 
 def _build_library():
-    """Compile libptpu_core.so (cmake+ninja, falling back to bare g++)."""
+    """Compile libptpu_core.so (cmake+ninja, falling back to bare g++).
+
+    Safe when several PROCESSES start it at once on a tree with no
+    ``native/build/`` (six test workers importing this module do): an
+    exclusive file lock around the build, and whoever waited for it finds
+    the library built and goes away. The g++ fallback writes a name of
+    its own and renames it, so no reader ever maps half a library."""
     build_dir = os.path.join(_NATIVE_DIR, "build")
-    try:
-        subprocess.run(
-            ["cmake", "-S", _NATIVE_DIR, "-B", build_dir, "-G", "Ninja"],
-            check=True, capture_output=True,
-        )
-        subprocess.run(
-            ["cmake", "--build", build_dir], check=True, capture_output=True
-        )
-        return
-    except (OSError, subprocess.CalledProcessError):
-        pass
     os.makedirs(build_dir, exist_ok=True)
-    subprocess.run(
-        [
-            "g++", "-std=c++17", "-O2", "-fPIC", "-shared", "-pthread",
-            "-I", os.path.join(_NATIVE_DIR, "include"),
-            "-I", os.path.join(_NATIVE_DIR, "src"),
-            os.path.join(_NATIVE_DIR, "src", "c_api.cc"),
-            "-o", _LIB_PATH,
-        ],
-        check=True, capture_output=True,
-    )
+    with open(os.path.join(build_dir, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)   # released when ``lock`` closes
+        if not _stale():
+            return   # another process built it while this one waited
+        try:
+            subprocess.run(
+                ["cmake", "-S", _NATIVE_DIR, "-B", build_dir, "-G", "Ninja"],
+                check=True, capture_output=True,
+            )
+            subprocess.run(
+                ["cmake", "--build", build_dir], check=True,
+                capture_output=True,
+            )
+            return
+        except (OSError, subprocess.CalledProcessError):
+            pass
+        tmp_path = "%s.%d.tmp" % (_LIB_PATH, os.getpid())
+        try:
+            subprocess.run(
+                [
+                    "g++", "-std=c++17", "-O2", "-fPIC", "-shared",
+                    "-pthread",
+                    "-I", os.path.join(_NATIVE_DIR, "include"),
+                    "-I", os.path.join(_NATIVE_DIR, "src"),
+                    os.path.join(_NATIVE_DIR, "src", "c_api.cc"),
+                    "-o", tmp_path,
+                ],
+                check=True, capture_output=True,
+            )
+            os.replace(tmp_path, _LIB_PATH)
+        finally:
+            if os.path.exists(tmp_path):
+                os.remove(tmp_path)
 
 
 def _declare(lib):

@@ -191,7 +191,12 @@ class StepSpan(object):
         self._cost_avals = None
 
     def enter(self, phase):
+        """Open ``phase``; a phase still open closes on the same stamp,
+        so two brackets that follow each other leave no gap between."""
         now = time.perf_counter()
+        cur = self._cur
+        if cur is not None:
+            self.phases[cur] = self.phases.get(cur, 0.0) + (now - self._t_cur)
         self._cur = phase
         self._t_cur = now
         _inflight[threading.get_ident()] = (self.origin, phase, now,
@@ -218,17 +223,7 @@ class StepSpan(object):
             return
         cp._stepprof_cost_done = True
         try:
-            import jax
-
-            aval = jax.ShapeDtypeStruct
-            self._cost_avals = (
-                {n: aval(state[n].shape, state[n].dtype)
-                 for n in cp.mutable_state},
-                {n: aval(state[n].shape, state[n].dtype)
-                 for n in cp.frozen_state},
-                {n: aval(v.shape, v.dtype) for n, v in feeds.items()},
-                aval(key.shape, key.dtype),
-            )
+            self._cost_avals = _telemetry.step_avals(cp, state, feeds, key)
             self._cost_cp = cp
         except Exception:
             self._cost_avals = None

@@ -178,6 +178,22 @@ def executable_fingerprint(cp, program=None):
     return None
 
 
+def step_avals(cp, state, feeds, key):
+    """The avals of one call of ``cp.jitted``: (mutable state, frozen
+    state, feeds, the pair (base key, run counter))."""
+    import jax
+
+    def aval(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+    return (
+        {n: aval(state[n]) for n in cp.mutable_state},
+        {n: aval(state[n]) for n in cp.frozen_state},
+        {n: aval(v) for n, v in feeds.items()},
+        jax.tree_util.tree_map(aval, key),
+    )
+
+
 def capture_step_avals(cp, state, feeds, key):
     """Aval snapshot for the deferred FLOP estimate, taken BEFORE the
     step call (which donates the mutable state buffers). One-shot per
@@ -186,17 +202,7 @@ def capture_step_avals(cp, state, feeds, key):
     if getattr(cp, "_telemetry_flops_done", False):
         return None
     cp._telemetry_flops_done = True
-    import jax
-
-    aval = jax.ShapeDtypeStruct
-    return (
-        {n: aval(state[n].shape, state[n].dtype)
-         for n in cp.mutable_state},
-        {n: aval(state[n].shape, state[n].dtype)
-         for n in cp.frozen_state},
-        {n: aval(v.shape, v.dtype) for n, v in feeds.items()},
-        aval(key.shape, key.dtype),
-    )
+    return step_avals(cp, state, feeds, key)
 
 
 def register_flops_from_avals(cp, fingerprint, avals, steps=1):

@@ -51,7 +51,11 @@ from paddle_tpu.core.fingerprint import (
     trace_flags_key,
 )
 from paddle_tpu.core.lod import LoDTensor
-from paddle_tpu.core.lowering import CompiledProgram
+from paddle_tpu.core.lowering import (
+    CompiledProgram,
+    step_key,
+    step_key_value,
+)
 from paddle_tpu.executor import global_scope
 from paddle_tpu.parallel.mesh import ShardingPolicy, build_mesh
 
@@ -585,11 +589,7 @@ class ParallelExecutor(object):
                 val = self._ensure_sharded(val, cp.shardings.state_sharding(n))
             state[n] = val
 
-        self._run_counter += 1
-        key = jax.random.fold_in(
-            jax.random.PRNGKey(self._program.random_seed or self._base_seed),
-            self._run_counter,
-        )
+        key = self._step_key()
         sp.exit()
         # opens before the pre-dispatch work (cost snapshot,
         # blackbox record): host dispatch overhead is charged to
@@ -797,11 +797,8 @@ class ParallelExecutor(object):
             self._pipeline_entry = entry
         pp = entry["pp"]
         params, accs, scalars = entry["state"]
-        self._run_counter += 1
-        key = jax.random.fold_in(
-            jax.random.PRNGKey(self._program.random_seed or self._base_seed),
-            self._run_counter,
-        )
+        # the pipeline's executable takes the key as a value: one dispatch
+        key = step_key_value(self._step_key())
         params, accs, scalars, loss = pp.jitted(
             params, accs, scalars, feeds, key)
         entry["state"] = (params, accs, scalars)
@@ -813,6 +810,13 @@ class ParallelExecutor(object):
         if return_numpy:
             return [np.reshape(np.asarray(loss), (1,))]
         return [jnp.reshape(loss, (1,))]
+
+    def _step_key(self):
+        """As ``Executor._step_key``; the base key stays on the host, the
+        mesh executable places it (replicated) with its other arguments."""
+        self._run_counter += 1
+        return step_key(self._program.random_seed or self._base_seed,
+                        self._run_counter)
 
     def pipeline_sync_scope(self):
         """Unpack the pipeline's packed params/accumulators back into their

@@ -1,0 +1,402 @@
+"""The executor's warm dispatch path: the run's key is folded inside the
+step's executable from (base key, run counter), the feeds go in as
+arguments and the state gather keeps its holders, and none of it changes
+a bit of what a run computes (ISSUE 25).
+
+The key stream is checked against the step function called with
+``fold_in(PRNGKey(seed), i)`` made the old, eager way.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+
+import paddle_tpu as fluid
+from paddle_tpu.core import exec_cache
+from paddle_tpu.core.lowering import BlockLowerer, build_step_fn
+from paddle_tpu.executor import global_scope
+from paddle_tpu.parallel_executor import ParallelExecutor
+
+SEED = 1234
+W = "w_scale.w_0"   # the name create_parameter gives
+BATCH = 16
+
+
+def _dropout_program(seed=SEED, train=True):
+    """x -> fc -> dropout -> (mean -> SGD): the dropout output is what the
+    step key decides; with ``train`` the parameters are donated and
+    written back every run."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = seed
+    startup.random_seed = 99
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        h = fluid.layers.fc(input=x, size=8) if train else x
+        out = fluid.layers.dropout(h, dropout_prob=0.5)
+        if train:
+            loss = fluid.layers.mean(out)
+            fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    return main, startup, out
+
+
+def _feed():
+    return {"x": np.random.RandomState(0).rand(BATCH, 8).astype("float32")
+            + 1.0}
+
+
+def _old_key(seed, counter):
+    return jax.random.fold_in(jax.random.PRNGKey(seed), counter)
+
+
+class _Reference(object):
+    """The program's own step function, jitted, threaded by hand."""
+
+    def __init__(self, main, out, scope):
+        names = set(scope.local_var_names())
+        state_in, state_out = BlockLowerer(main, 0).analyze(names, {"x"})
+        self.step = jax.jit(build_step_fn(
+            main, ["x"], [out.name], state_in, state_out))
+        self.state = {n: np.asarray(scope.get_value(n)) for n in state_in}
+
+    def run(self, key):
+        new_state, (fetched,) = self.step(self.state, _feed(), key)
+        self.state.update(new_state)
+        return np.asarray(fetched)
+
+
+def _start(train=True, seed=SEED):
+    main, startup, out = _dropout_program(seed, train=train)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    return main, out, exe
+
+
+# -- the key stream -----------------------------------------------------------
+
+@pytest.mark.parametrize("entry", ["run", "run_async"])
+def test_key_stream_matches_the_eager_key(entry):
+    main, out, exe = _start()
+    ref = _Reference(main, out, global_scope())
+    first = exe._run_counter
+    for i in range(1, 6):
+        if entry == "run":
+            got, = exe.run(main, feed=_feed(), fetch_list=[out])
+        else:
+            got, = exe.run_async(main, feed=_feed(),
+                                 fetch_list=[out]).result()
+        want = ref.run(_old_key(SEED, first + i))
+        assert exe._run_counter == first + i
+        assert (got == 0).any() and (got != 0).any()   # a mask was drawn
+        np.testing.assert_array_equal(got, want)
+
+
+def test_key_stream_multi_step_matches_the_eager_key():
+    steps = 3
+    main, out, exe = _start()
+    ref = _Reference(main, out, global_scope())
+    first = exe._run_counter
+    for c in range(1, 4):
+        got, = exe.run_multi_step(main, steps, feed=_feed(),
+                                  fetch_list=[out], stack_fetches=True)
+        key = _old_key(SEED, first + c)
+        want = np.stack([ref.run(jax.random.fold_in(key, i))
+                         for i in range(steps)])
+        np.testing.assert_array_equal(got, want)
+        assert not (got[0] == got[1]).all()   # a key of its own each step
+    # the last step's values ride the carry when nothing is stacked
+    got, = exe.run_multi_step(main, steps, feed=_feed(), fetch_list=[out])
+    key = _old_key(SEED, first + 4)
+    want = [ref.run(jax.random.fold_in(key, i)) for i in range(steps)][-1]
+    np.testing.assert_array_equal(got, want)
+
+
+def test_key_stream_parallel_executor_matches_the_eager_key():
+    main, out, _exe = _start(train=False)
+    pe = ParallelExecutor(main_program=main, use_tpu=False)
+    ref = _Reference(main, out, global_scope())
+    for i in range(1, 5):
+        got, = pe.run(fetch_list=[out.name], feed=_feed())
+        assert pe._run_counter == i
+        np.testing.assert_array_equal(got, ref.run(_old_key(SEED, i)))
+
+
+def test_seedless_program_follows_the_executors_base_seed():
+    main, out, exe = _start(seed=0)
+    ref = _Reference(main, out, global_scope())
+    first = exe._run_counter
+    exe._base_seed = 424242
+    for i in range(1, 4):
+        got, = exe.run(main, feed=_feed(), fetch_list=[out])
+        np.testing.assert_array_equal(
+            got, ref.run(_old_key(424242, first + i)))
+    exe._base_seed = 7   # another stream, with no new executable
+    misses = exec_cache.stats()["trace_cache_misses"]
+    got, = exe.run(main, feed=_feed(), fetch_list=[out])
+    np.testing.assert_array_equal(got, ref.run(_old_key(7, first + 4)))
+    assert exec_cache.stats()["trace_cache_misses"] == misses
+
+
+def test_restored_seed_and_counter_continue_the_stream():
+    """What a checkpoint carries (resilience/checkpoint.py) is enough."""
+    main, out, exe = _start(train=False, seed=0)
+    for _ in range(3):
+        exe.run(main, feed=_feed(), fetch_list=[out])
+    saved = exe._base_seed, exe._run_counter
+    want = [exe.run(main, feed=_feed(), fetch_list=[out])[0]
+            for _ in range(2)]
+    resumed = fluid.Executor(fluid.CPUPlace())
+    resumed._base_seed, resumed._run_counter = saved
+    got = [resumed.run(main, feed=_feed(), fetch_list=[out])[0]
+           for _ in range(2)]
+    np.testing.assert_array_equal(got, want)
+    assert not (want[0] == want[1]).all()
+
+
+def test_executors_sharing_an_executable_keep_their_own_counters():
+    main, out, a = _start(train=False)
+    b = fluid.Executor(fluid.CPUPlace())
+    for _ in range(3):
+        a.run(main, feed=_feed(), fetch_list=[out])
+    misses = exec_cache.stats()["trace_cache_misses"]
+    a_start, b_start = a._run_counter, b._run_counter
+    got_b, = b.run(main, feed=_feed(), fetch_list=[out])
+    got_a, = a.run(main, feed=_feed(), fetch_list=[out])
+    assert exec_cache.stats()["trace_cache_misses"] == misses   # shared
+    assert (a._run_counter, b._run_counter) == (a_start + 1, b_start + 1)
+    ref = _Reference(main, out, global_scope())
+    np.testing.assert_array_equal(got_b, ref.run(_old_key(SEED, b_start + 1)))
+    np.testing.assert_array_equal(got_a, ref.run(_old_key(SEED, a_start + 1)))
+
+
+# -- the warm path makes no eager JAX call -------------------------------------
+
+class _Calls(object):
+    """Counts calls of ``jax.random.PRNGKey``, ``jax.random.fold_in`` and
+    ``jax.device_put`` (the names the program looks up at call time)."""
+
+    def __init__(self, monkeypatch):
+        self.n = {"PRNGKey": 0, "fold_in": 0, "device_put": 0}
+        for mod, name in ((jax.random, "PRNGKey"), (jax.random, "fold_in"),
+                          (jax, "device_put")):
+            monkeypatch.setattr(mod, name, self._counting(
+                getattr(mod, name), name))
+
+    def _counting(self, fn, name):
+        def counted(*args, **kwargs):
+            self.n[name] += 1
+            return fn(*args, **kwargs)
+        return counted
+
+    def reset(self):
+        for k in self.n:
+            self.n[k] = 0
+
+
+def _entry_call(entry, exe, main, out):
+    if entry == "run":
+        return exe.run(main, feed=_feed(), fetch_list=[out])[0]
+    if entry == "run_async":
+        return exe.run_async(main, feed=_feed(), fetch_list=[out]).result()[0]
+    return exe.run_multi_step(main, 2, feed=_feed(), fetch_list=[out])[0]
+
+
+@pytest.mark.parametrize("entry", ["run", "run_async", "run_multi_step"])
+def test_third_run_makes_no_eager_jax_call(entry, monkeypatch):
+    main, out, exe = _start()
+    calls = _Calls(monkeypatch)
+    _entry_call(entry, exe, main, out)   # traces the fold, at most once
+    _entry_call(entry, exe, main, out)
+    calls.reset()
+    before = exec_cache.stats()
+    _entry_call(entry, exe, main, out)
+    after = exec_cache.stats()
+    assert calls.n["PRNGKey"] == 0
+    assert calls.n["fold_in"] == 0
+    assert calls.n["device_put"] <= 1
+    assert after["gather_plan_hits"] == before["gather_plan_hits"] + 1
+    assert after["gather_plan_rebuilds"] == before["gather_plan_rebuilds"]
+    assert after["trace_cache_misses"] == before["trace_cache_misses"]
+
+
+# -- the scope changes under a warm executable ---------------------------------
+
+def _weight_program():
+    """out = x * w with ``w`` a persistable the program only reads."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        w = fluid.layers.create_parameter(
+            shape=[8], dtype="float32", name="w_scale",
+            default_initializer=fluid.initializer.Constant(2.0))
+        out = fluid.layers.elementwise_mul(x, w)
+    return main, startup, out
+
+
+def _set_numpy(scope):
+    scope.set_value(W, np.full([8], 3.0, "float32"))
+    return 3.0, 1
+
+
+def _erase_and_recreate(scope):
+    scope.erase([W])
+    scope.set_value(W, jax.numpy.full([8], 5.0, "float32"))
+    return 5.0, 0
+
+
+def _other_device(scope):
+    other = jax.devices("cpu")[1]
+    scope.set_value(W, jax.device_put(
+        np.full([8], 7.0, "float32"), other))
+    return 7.0, 1
+
+
+def _same_device(scope):
+    scope.set_value(W, jax.numpy.full([8], 11.0, "float32"))
+    return 11.0, 0
+
+
+@pytest.mark.parametrize("change", [
+    _set_numpy, _erase_and_recreate, _other_device, _same_device],
+    ids=lambda f: f.__name__.strip("_"))
+def test_warm_run_sees_a_value_set_from_outside(change, monkeypatch):
+    main, startup, out = _weight_program()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    feed = _feed()
+    for _ in range(3):
+        got, = exe.run(main, feed=feed, fetch_list=[out])
+        np.testing.assert_array_equal(got, feed["x"] * 2.0)
+    scale, moves = change(global_scope())
+    calls = _Calls(monkeypatch)
+    got, = exe.run(main, feed=feed, fetch_list=[out])
+    np.testing.assert_array_equal(got, feed["x"] * scale)
+    assert calls.n["device_put"] == moves   # moved once, or not at all
+    assert calls.n["PRNGKey"] == calls.n["fold_in"] == 0
+    # and the scope keeps what the caller put there
+    kept = global_scope().get_value(W)
+    np.testing.assert_array_equal(np.asarray(kept), np.full([8], scale))
+
+
+@pytest.mark.parametrize("how", ["never_set", "emptied_when_warm"])
+def test_uninitialised_persistable_raises_todays_error(how):
+    main, startup, out = _weight_program()
+    exe = fluid.Executor(fluid.CPUPlace())
+    if how == "never_set":
+        global_scope().var(W)   # a holder, and nothing in it
+    else:
+        exe.run(startup)
+        for _ in range(3):
+            exe.run(main, feed=_feed(), fetch_list=[out])
+        global_scope().set_value(W, None)
+    with pytest.raises(RuntimeError) as err:
+        exe.run(main, feed=_feed(), fetch_list=[out])
+    assert str(err.value) == (
+        "persistable variable 'w_scale.w_0' is not initialized in the scope "
+        "(did you run the startup program?)")
+
+
+def test_variable_created_later_in_a_child_scope_shadows_the_parents():
+    main, startup, out = _weight_program()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    kid = global_scope().new_scope()
+    feed = _feed()
+    for _ in range(3):
+        got, = exe.run(main, feed=feed, fetch_list=[out], scope=kid)
+        np.testing.assert_array_equal(got, feed["x"] * 2.0)
+    kid.set_value(W, jax.numpy.full([8], 4.0, "float32"))
+    got, = exe.run(main, feed=feed, fetch_list=[out], scope=kid)
+    np.testing.assert_array_equal(got, feed["x"] * 4.0)
+    got, = exe.run(main, feed=feed, fetch_list=[out])   # the parent's own
+    np.testing.assert_array_equal(got, feed["x"] * 2.0)
+
+
+def test_an_erased_value_is_not_kept_alive_by_the_gather_plan():
+    import gc
+    import weakref
+
+    main, startup, out = _weight_program()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    for _ in range(3):
+        exe.run(main, feed=_feed(), fetch_list=[out])
+    alive = weakref.ref(global_scope().get_value(W))
+    global_scope().erase([W])
+    gc.collect()
+    assert alive() is None
+    replaced = jax.numpy.full([8], 6.0, "float32")
+    global_scope().set_value(W, replaced)
+    exe.run(main, feed=_feed(), fetch_list=[out])
+    alive = weakref.ref(replaced)
+    global_scope().set_value(W, jax.numpy.full([8], 1.0, "float32"))
+    del replaced
+    gc.collect()
+    assert alive() is None
+
+
+def test_feeds_are_cast_to_the_declared_dtype_and_int64_still_feeds():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data(name="x", shape=[4], dtype="float32")
+        ids = fluid.layers.data(name="ids", shape=[1], dtype="int64")
+        out = fluid.layers.elementwise_add(
+            x, fluid.layers.cast(ids, "float32"))
+    exe = fluid.Executor(fluid.CPUPlace())
+    feed = {"x": np.arange(8, dtype="float64").reshape(2, 4),   # cast
+            "ids": np.array([[1], [2]], dtype="int32")}          # cast
+    want = feed["x"].astype("float32") + feed["ids"].astype("float32")
+    for _ in range(3):
+        got, = exe.run(main, feed=feed, fetch_list=[out])
+        assert got.dtype == np.float32
+        np.testing.assert_array_equal(got, want)
+    # a device array is taken as it is
+    feed["x"] = jax.numpy.asarray(feed["x"], "float32")
+    got, = exe.run(main, feed=feed, fetch_list=[out])
+    np.testing.assert_array_equal(got, want)
+
+
+def test_threads_share_a_gather_plan_while_a_value_is_replaced():
+    """Serving clones run one executable on one scope from many threads;
+    the plan they share must hand every run the scope's current value."""
+    import sys
+    import threading
+
+    main, startup, out = _weight_program()
+    fluid.Executor(fluid.CPUPlace()).run(startup)
+    scope = global_scope()
+    feed = _feed()
+    want = feed["x"] * 2.0
+    fluid.Executor(fluid.CPUPlace()).run(main, feed=feed, fetch_list=[out])
+    wrong, stop = [], threading.Event()
+
+    def serve():
+        exe = fluid.Executor(fluid.CPUPlace())
+        for _ in range(40):
+            got, = exe.run(main, feed=feed, fetch_list=[out], scope=scope)
+            if not (got == want).all():
+                wrong.append(got)
+
+    def replace():
+        i = 0
+        while not stop.is_set():
+            two = np.full([8], 2.0, "float32")
+            scope.set_value(W, two if i % 2 else jax.numpy.asarray(two))
+            i += 1
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        servers = [threading.Thread(target=serve) for _ in range(16)]
+        replacer = threading.Thread(target=replace)
+        replacer.start()
+        for t in servers:
+            t.start()
+        for t in servers:
+            t.join(timeout=120)
+        stop.set()
+        replacer.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in servers + [replacer])
+    assert wrong == []

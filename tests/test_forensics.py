@@ -399,6 +399,11 @@ def test_per_device_metrics_one_label_per_device():
                           use_tpu=False)
     n_dev = pe.device_count
     assert n_dev == 8
+    xfer = REGISTRY.counter("paddle_tpu_device_transfer_bytes_total",
+                            labels=("device",))
+    # the counter is the process's: a file run earlier by this worker
+    # (tests/test_sharding.py) may have fed a mesh with telemetry on
+    before = {dict(k)["device"]: v for k, v in xfer._series().items()}
     rng = np.random.RandomState(0)
     for _ in range(2):
         pe.run(fetch_list=[loss],
@@ -408,9 +413,8 @@ def test_per_device_metrics_one_label_per_device():
     step_g = REGISTRY.gauge("paddle_tpu_device_step_seconds",
                             labels=("device",))
     assert {dict(k)["device"] for k in step_g._series()} == labels
-    xfer = REGISTRY.counter("paddle_tpu_device_transfer_bytes_total",
-                            labels=("device",))
-    series = {dict(k)["device"]: v for k, v in xfer._series().items()}
+    series = {dict(k)["device"]: v - before.get(dict(k)["device"], 0)
+              for k, v in xfer._series().items()}
     assert set(series) == labels
     # x sharded over data axis: 32x32 f32 / 8 = 512B; label 32x1 i64 / 8
     # = 32B; two steps

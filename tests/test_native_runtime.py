@@ -213,3 +213,39 @@ def test_ptpb_lockstep_covers_fused_ops():
     assert reserialized == blob
     back = deserialize_program(blob)
     assert [op.type for op in back.global_block().ops] == types
+
+
+def test_two_processes_build_an_empty_build_directory_at_once(tmp_path):
+    """Six test workers import ``paddle_tpu.native`` at once on a fresh
+    checkout; each must end up with a library it can load."""
+    import shutil
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    tree = str(tmp_path / "native")
+    os.makedirs(tree)
+    for sub in ("src", "include", "tests"):
+        shutil.copytree(os.path.join(repo, "native", sub),
+                        os.path.join(tree, sub))
+    shutil.copy(os.path.join(repo, "native", "CMakeLists.txt"), tree)
+    child = (
+        "import os, sys\n"
+        "sys.path.insert(0, %r)\n"
+        "from paddle_tpu import native\n"
+        "native._NATIVE_DIR = %r\n"
+        "native._LIB_PATH = os.path.join(%r, 'build', 'libptpu_core.so')\n"
+        "assert native.available(), native.last_error()\n"
+        "q = native.NativeBlockingQueue(capacity=3)\n"
+        "q.push(b'abc')\n"
+        "print('loaded', q.size())\n" % (repo, tree, tree))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    procs = [subprocess.Popen([sys.executable, "-c", child], env=env,
+                              stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                              text=True) for _ in range(2)]
+    for p in procs:
+        out, err = p.communicate(timeout=300)
+        assert p.returncode == 0, err[-2000:]
+        assert out.strip() == "loaded 1"
+    left = os.listdir(os.path.join(tree, "build"))
+    assert "libptpu_core.so" in left
+    assert not [f for f in left if f.endswith(".tmp")]

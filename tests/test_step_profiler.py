@@ -284,8 +284,11 @@ def test_jsonl_flush_and_perf_ledger_round_trip(tmp_path):
     step_profiler.enable(True)
     try:
         exe.run(startup)
+        # a batch that makes a warm step a millisecond: on three rows a
+        # warm run is a tenth of that, and one preemption between two
+        # brackets on a loaded test host reads as a quarter of it
         for _ in range(3):
-            exe.run(main, feed=_feed(), fetch_list=[loss])
+            exe.run(main, feed=_feed(bs=1 << 16), fetch_list=[loss])
     finally:
         step_profiler.enable(False)
     jsonl = tmp_path / "t.stepprof.jsonl"

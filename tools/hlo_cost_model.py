@@ -651,7 +651,7 @@ def main():
     exe.run(startup)
     from paddle_tpu.executor import global_scope
     scope = global_scope()
-    scope_names = exe._scope_names(scope)
+    scope_names = scope.visible_names()
 
     lowerer = BlockLowerer(program, 0)
     state_in, state_out = lowerer.analyze(scope_names, set(feed))
