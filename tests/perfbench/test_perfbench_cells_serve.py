@@ -28,10 +28,13 @@ def test_serve_cell_rehearsal(name, trace, tmp_path, capsys):
         assert part in text
     if trace:
         host_side = {"build_s", "compile_s", "cache_misses",
+                     "trace_lower_s",
                      "loadgen_late_p99_ms", "sat_loadgen_late_p99_ms",
                      "queue_wait_p50_ms", "admit_ms_p50",
                      "sat_admits_per_s", "sat_dispatch_gap_p50_ms"}
         assert set(line["metrics"]) <= host_side
+        # a counter of the compile cache's statistics, not of the trace
+        assert line["metrics"]["trace_lower_s"]["value"] > 0
 
 
 def test_bf16_control_is_not_correct(tmp_path):

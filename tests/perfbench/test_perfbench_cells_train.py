@@ -24,7 +24,7 @@ def test_train_cell_rehearsal(name, trace, tmp_path, capsys):
     if trace:
         # on the CPU no device metric is read: only host-side ones
         assert set(line["metrics"]) == {"build_s", "compile_s",
-                                        "cache_misses",
+                                        "cache_misses", "trace_lower_s",
                                         "train_step_host_max_ms",
                                         "train_dispatch_host_ms"}
         assert "busy_s" not in line["device"]
