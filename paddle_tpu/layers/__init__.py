@@ -22,5 +22,6 @@ from paddle_tpu.layers.learning_rate_scheduler import (  # noqa: F401
 from paddle_tpu.layers.sequence import *  # noqa: F401,F403
 from paddle_tpu.layers.rnn import *  # noqa: F401,F403
 from paddle_tpu.layers.attention import *  # noqa: F401,F403
+from paddle_tpu.layers.decoder import *  # noqa: F401,F403
 from paddle_tpu.layers.nlp import *  # noqa: F401,F403
 from paddle_tpu.layers.detection import *  # noqa: F401,F403

@@ -1,0 +1,165 @@
+"""Layers of a decoder-only block with latent (MLA) attention and routed
+experts (``ops/decoder_ops.py``, ``ops/moe_ops.py`` ``dropless_moe_ffn``).
+They take flat token rows ``[N, ...]`` and the parameters as variables:
+the serving builder (``models/latent_moe_decoder.py``) declares those by
+name and a checkpoint fills them."""
+
+from paddle_tpu.layer_helper import LayerHelper
+
+__all__ = [
+    "rms_norm",
+    "gated_ffn",
+    "dense_projection",
+    "dropless_moe_ffn",
+    "latent_rope_rows",
+    "latent_row_write",
+    "latent_row_prefill",
+    "latent_paged_attention",
+    "latent_prefill_attention",
+    "slot_rows_write",
+    "embedding_rows",
+]
+
+
+def _one(op_type, inputs, attrs=None, dtype=None, name=None):
+    helper = LayerHelper(op_type, name=name)
+    first = next(iter(inputs.values()))[0]
+    out = helper.create_variable_for_type_inference(dtype or first.dtype)
+    helper.append_op(type=op_type, inputs=inputs, outputs={"Out": [out]},
+                     attrs=attrs or {})
+    return out
+
+
+def rms_norm(x, scale, epsilon=1e-5, name=None):
+    """``x / sqrt(mean(x^2) + epsilon) * scale`` over the last axis, the
+    statistics in float32."""
+    return _one("rms_norm", {"X": [x], "Scale": [scale]},
+                {"epsilon": float(epsilon)}, name=name)
+
+
+def gated_ffn(x, w_gate, w_up, w_down, name=None):
+    """SwiGLU: ``(silu(x Wg) * (x Wu)) Wd``."""
+    return _one("gated_ffn", {"X": [x], "WGate": [w_gate], "WUp": [w_up],
+                              "WDown": [w_down]}, name=name)
+
+
+def dense_projection(x, w, out_dtype="input", name=None):
+    """``x @ w`` accumulated in float32; ``out_dtype="float32"`` keeps the
+    accumulator (the logits), ``"input"`` rounds to ``x``'s dtype."""
+    return _one("dense_projection", {"X": [x], "W": [w]},
+                {"out_dtype": out_dtype},
+                dtype="float32" if out_dtype == "float32" else None,
+                name=name)
+
+
+def dropless_moe_ffn(x, router_w, router_bias, expert_w_gate, expert_w_up,
+                     expert_w_down, shared=None, valid=None, top_k=1,
+                     norm_topk=True, scale=1.0, name=None):
+    """Routed experts with no capacity and no dropped token: sigmoid
+    scores plus a selection bias, the ``top_k`` largest chosen, the
+    (token, expert) pairs sorted by expert and computed as grouped matrix
+    products; ``shared`` = (gate, up, down) of a shared expert added once.
+    ``valid`` [N] marks the tokens that exist (others are neither computed
+    nor counted). Returns (out [N, D], chosen [N, top_k], tokens each
+    expert got [E])."""
+    helper = LayerHelper("dropless_moe_ffn", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    chosen = helper.create_variable_for_type_inference(
+        "int32", stop_gradient=True)
+    counts = helper.create_variable_for_type_inference(
+        "int32", stop_gradient=True)
+    inputs = {"X": [x], "RouterW": [router_w], "RouterBias": [router_bias],
+              "ExpertWGate": [expert_w_gate], "ExpertWUp": [expert_w_up],
+              "ExpertWDown": [expert_w_down]}
+    if shared is not None:
+        inputs.update(SharedWGate=[shared[0]], SharedWUp=[shared[1]],
+                      SharedWDown=[shared[2]])
+    if valid is not None:
+        inputs["Valid"] = [valid]
+    helper.append_op(
+        type="dropless_moe_ffn", inputs=inputs,
+        outputs={"Out": [out], "Chosen": [chosen],
+                 "ExpertTokens": [counts]},
+        attrs={"top_k": int(top_k), "norm_topk": bool(norm_topk),
+               "scale": float(scale)})
+    return out, chosen, counts
+
+
+def latent_rope_rows(q, kva, kv_norm, heads, nope_dim, rope_dim, theta,
+                     positions=None, period=0, epsilon=1e-5, name=None):
+    """The query ``[N, H, dn + dr]`` with RoPE on its rotary part, and the
+    row to cache ``[N, C + dr]`` = ``[RMSNorm(ckv) | RoPE(k_rope)]``.
+    ``positions`` [N] (decode), or none and ``period`` = the bucket length
+    (prefill: token ``n`` stands at ``n % period``)."""
+    helper = LayerHelper("latent_rope_rows", name=name)
+    q_out = helper.create_variable_for_type_inference(q.dtype)
+    row = helper.create_variable_for_type_inference(q.dtype)
+    inputs = {"Q": [q], "KVA": [kva], "KVNorm": [kv_norm]}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+    helper.append_op(
+        type="latent_rope_rows", inputs=inputs,
+        outputs={"QOut": [q_out], "Row": [row]},
+        attrs={"heads": int(heads), "nope_dim": int(nope_dim),
+               "rope_dim": int(rope_dim), "theta": float(theta),
+               "period": int(period), "epsilon": float(epsilon)})
+    return q_out, row
+
+
+def latent_row_write(pool, rows, page_table, pos, name=None):
+    """Decode's cache write; ``pool`` is updated in place (bound as the
+    op's output, the optimizer-style state convention)."""
+    helper = LayerHelper("latent_row_write", name=name)
+    helper.append_op(
+        type="latent_row_write",
+        inputs={"Pool": [pool], "Rows": [rows], "PageTable": [page_table],
+                "Pos": [pos]},
+        outputs={"PoolOut": [pool]})
+    return pool
+
+
+def latent_row_prefill(pool, rows, page_rows, lens, name=None):
+    """Prefill's cache write, a page at a time; ``pool`` in place."""
+    helper = LayerHelper("latent_row_prefill", name=name)
+    helper.append_op(
+        type="latent_row_prefill",
+        inputs={"Pool": [pool], "Rows": [rows], "PageRows": [page_rows],
+                "Lens": [lens]},
+        outputs={"PoolOut": [pool]})
+    return pool
+
+
+def latent_paged_attention(q, kv_b, pool, page_table, lengths, nope_dim,
+                           name=None):
+    """Absorbed-form decode attention of every slot over its cached rows
+    (``kernels/latent_attention.py``): ``[S, H * v_dim]``."""
+    return _one("latent_paged_attention",
+                {"Q": [q], "KVB": [kv_b], "Pool": [pool],
+                 "PageTable": [page_table], "Lengths": [lengths]},
+                {"nope_dim": int(nope_dim)}, name=name)
+
+
+def latent_prefill_attention(q, rows, kv_b, prompts, nope_dim, name=None):
+    """Expanded-form causal attention of ``prompts`` prompts of equal
+    (bucket) length through the flash kernel: ``[N, H * v_dim]``."""
+    return _one("latent_prefill_attention",
+                {"Q": [q], "Rows": [rows], "KVB": [kv_b]},
+                {"prompts": int(prompts), "nope_dim": int(nope_dim)},
+                name=name)
+
+
+def slot_rows_write(state, index, values, name=None):
+    """``state[index[b]] = values[b]``, in place; an index past the last
+    row (a prefill batch's padding) writes nothing."""
+    helper = LayerHelper("slot_rows_write", name=name)
+    helper.append_op(
+        type="slot_rows_write",
+        inputs={"State": [state], "Index": [index], "Values": [values]},
+        outputs={"StateOut": [state]})
+    return state
+
+
+def embedding_rows(table, ids, name=None):
+    """Rows ``ids`` [N] of an embedding table that is already a variable
+    (``layers.embedding`` creates its own parameter)."""
+    return _one("lookup_table", {"W": [table], "Ids": [ids]}, name=name)

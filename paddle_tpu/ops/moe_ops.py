@@ -161,3 +161,95 @@ register_op(
     grad="auto",
     no_grad_inputs=("Mask",),
 )
+
+
+# -- dropless routed experts --------------------------------------------------
+
+def route_top_k(x, router_w, router_bias, top_k, norm_topk, scale):
+    """Sigmoid-score routing with a selection bias (``noaux_tc`` with one
+    group): ``s = sigmoid(float32(x) float32(Wr))``; the ``top_k`` experts
+    with the largest ``s + b`` are chosen; the weights are the chosen
+    ``s`` (not ``s + b``), normalised over the chosen when ``norm_topk``,
+    times ``scale``. The router runs in float32 at full precision,
+    whatever the activations' dtype. Returns (chosen [N, k] int32,
+    weights [N, k] float32)."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), w * scale
+
+
+def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, valid=None):
+    """Every (token, chosen expert) pair is computed: the pairs are
+    sorted by expert and each expert's rows go through its gated FFN as
+    ONE group of three grouped matrix products
+    (``kernels/grouped_matmul.py``: a Pallas kernel on the TPU,
+    ``jax.lax.ragged_dot`` elsewhere), so
+    no capacity exists and no token can be dropped. Rows of tokens that
+    are not ``valid`` (a prefill batch's padding, a decode step's empty
+    slots) sort behind every group and are not computed. Returns (the
+    weighted sum [N, D] in float32, tokens each expert got [E])."""
+    from paddle_tpu.kernels.grouped_matmul import grouped_matmul
+
+    def product(lhs, rhs):
+        return grouped_matmul(lhs, rhs, counts)
+
+    n, k = chosen.shape
+    e = w_gate.shape[0]
+    flat = chosen.reshape(-1)
+    if valid is not None:
+        flat = jnp.where(jnp.repeat(valid, k), flat, e)
+    order = jnp.argsort(flat, stable=True)
+    counts = jnp.bincount(flat, length=e + 1)[:e].astype(jnp.int32)
+    xs = x[order // k]                                       # [N*k, D]
+    h = jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)
+    y = product(h.astype(x.dtype), w_down)
+    y = y * weights.reshape(-1)[order][:, None]
+    if valid is not None:
+        # rows past the last group are not written by the grouped product
+        y = jnp.where((jnp.arange(n * k) < jnp.sum(counts))[:, None], y, 0.0)
+    # back to (token, rank) order: a gather through the inverse permutation
+    inverse = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    return y[inverse].reshape(n, k, -1).sum(axis=1), counts
+
+
+def _lower_dropless_moe_ffn(ctx, ins, attrs):
+    from paddle_tpu.ops.decoder_ops import gated_ffn
+
+    x = ins["X"][0]                                          # [N, D]
+    valid = ins.get("Valid", [None])[0]
+    if valid is not None:
+        valid = jnp.reshape(valid, (-1,)) > 0
+    with jax.named_scope("dropless_route"):
+        chosen, weights = route_top_k(
+            x, ins["RouterW"][0], ins["RouterBias"][0],
+            int(attrs["top_k"]), bool(attrs.get("norm_topk", True)),
+            float(attrs.get("scale", 1.0)))
+    with jax.named_scope("dropless_experts"):
+        routed, counts = dropless_experts(
+            x, chosen, weights, ins["ExpertWGate"][0], ins["ExpertWUp"][0],
+            ins["ExpertWDown"][0], valid)
+    out = routed
+    if ins.get("SharedWGate"):
+        # the shared expert sees every token once, outside the routing
+        out = out + gated_ffn(x, ins["SharedWGate"][0], ins["SharedWUp"][0],
+                              ins["SharedWDown"][0]).astype(jnp.float32)
+    return {"Out": out.astype(x.dtype), "Chosen": chosen,
+            "ExpertTokens": counts}
+
+
+register_op(
+    "dropless_moe_ffn",
+    inputs=["X", "RouterW", "RouterBias", "ExpertWGate", "ExpertWUp",
+            "ExpertWDown", "SharedWGate", "SharedWUp", "SharedWDown",
+            "Valid"],
+    outputs=["Out", "Chosen", "ExpertTokens"],
+    attrs={"top_k": 1, "norm_topk": True, "scale": 1.0},
+    lower=_lower_dropless_moe_ffn,
+    grad=None,
+)

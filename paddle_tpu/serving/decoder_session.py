@@ -1,0 +1,529 @@
+"""DecoderOnlySession: continuous-batching decode of a decoder-only model
+with latent (MLA) attention and routed experts
+(``models/latent_moe_decoder.py``) over a paged pool of latent rows.
+
+A sibling of ``generation.SlotDecodeSession`` that the frontend's decode
+worker drives in the same way (``enqueue``, ``admit_pending``, ``step``,
+``cancel``, ``pump``, ``take_result``, ``free_slots``, ``active_slots``,
+``pending_requests``, ``pool_conserved``, ``health``): the same
+``ServingFrontend``, the same wire, the same ``ServingClient.generate(src,
+src_len=)``. What differs is the model:
+
+* **The prompt is the source, and admission IS its prefill.** ``src`` is
+  the prompt's token ids (the first ``src_len`` of them). ``admit_pending``
+  gathers the head of the queue, as many requests as slots are free, and
+  prefills them SEVERAL A DISPATCH: one dispatch per length bucket and
+  ``prompts_per_dispatch(bucket)`` prompts (a prompt pays for reading
+  every expert's weights; a dispatch of one would pay it per prompt). The
+  prefill writes the prompt's latent rows into the slot's pages and
+  samples the first token.
+* **One row pool a layer.** ``PagePool`` accounts the pages (refcount 1
+  each: nothing is shared here); a slot's worst case (prompt + new tokens)
+  is reserved at admission, pages are taken as the sequence grows. The
+  page table and the live mask are the HOST's and are fed with every
+  decode dispatch, so growing a slot, finishing it or cancelling it costs
+  no dispatch: ``cancel`` is bookkeeping.
+* **EOS is not looked for**: a stream ends at ``max_new_tokens`` or when
+  its client closes it (the wire's in-band cancel). Seeded weights never
+  emit a meaningful EOS, and public serving benchmarks ignore it.
+
+Not built for this session, and refused with a ``ServingError`` that says
+so: forced prefixes and the prefix cache, fork groups (``n > 1``) and
+copy-on-write, beam lanes, speculation, decode snapshots.
+
+Tracing (``observability/tracing.py``): under the worker's round,
+``admit`` > ``prefill`` > ``prefill.dispatch`` per bucket dispatch and
+``step`` > ``step.dispatch``; the round counts ``prefill_prompts``,
+``prefill_tokens``, ``prefill_dispatches``, ``prefill_bucket_<T>``,
+``pages_in_use`` and ``expert_max_over_mean`` (the most loaded expert's
+tokens over the mean, over the dispatch's steps and expert layers, from
+the counts the expert op returns WITH the step's tokens: no dispatch and
+no device sync of their own).
+"""
+
+import collections
+import time
+
+import numpy as np
+
+from paddle_tpu.kernels.paged_attention import pages_for
+from paddle_tpu.observability import tracing as _tracing
+from paddle_tpu.serving.generation import (
+    NoFreeSlotError,
+    SlotDecodeSession as _SlotSession,
+    _active_slots,
+    _pages_in_use,
+    _sequences_total,
+)
+from paddle_tpu.serving.kv_pool import NoFreePageError, PagePool
+from paddle_tpu.serving.server import ServingError
+
+__all__ = ["DecoderOnlySession"]
+
+
+class DecoderOnlySession(object):
+    """Parameters
+    ----------
+    exe : Executor (or a stand-in with ``run``/``run_multi_step``).
+    desc : dict of the model's config keys (``decoder_dims``).
+    num_slots, max_prompt, max_new_tokens : the pool: a slot holds
+        ``max_prompt + max_new_tokens`` positions.
+    page_size : rows a page; every prefill bucket is a multiple of it.
+    tokens_per_dispatch : decode tokens a slot a dispatch.
+    prefill_buckets, prefill_token_budget : the builder's (defaults:
+        powers of two of the page size; 2048 tokens a dispatch).
+    num_pages : pool size, trash page included (default: full occupancy).
+    probe_rows : the builder's (a check's fetch of a few slots' logits
+        from the serving executable itself; 0: none). The slots are
+        ``probe_slots`` (an array to write into), fed with every step.
+    scope : must hold the parameters (``load_parameters``).
+    """
+
+    def __init__(self, exe, desc, num_slots, max_prompt, max_new_tokens,
+                 page_size, tokens_per_dispatch=4, prefill_buckets=None,
+                 prefill_token_budget=2048, num_pages=None, sampler=None,
+                 scope=None, dtype="bfloat16", probe_rows=0):
+        from paddle_tpu.executor import global_scope
+        from paddle_tpu.models.latent_moe_decoder import (
+            build_latent_moe_decoder,
+        )
+
+        self._exe = exe
+        self._scope = scope or global_scope()
+        self._S = int(num_slots)
+        self._max_prompt = int(max_prompt)
+        self._max_new = int(max_new_tokens)
+        self._K = int(tokens_per_dispatch)
+        self._ps = int(page_size)
+        positions = self._max_prompt + self._max_new
+        if prefill_buckets is None:
+            prefill_buckets, t = [], self._ps
+            while t < self._max_prompt:
+                prefill_buckets.append(t)
+                t *= 2
+            prefill_buckets.append(t)
+        built = build_latent_moe_decoder(
+            desc, self._S, positions, self._ps, prefill_buckets,
+            num_pages=num_pages,
+            prefill_token_budget=prefill_token_budget, sampler=sampler,
+            dtype=dtype, probe_rows=probe_rows)
+        self.geometry = geo = built["geometry"]
+        self._buckets = geo["buckets"]
+        if self._buckets[-1] < self._max_prompt:
+            raise ValueError("the longest prefill bucket (%d) is shorter "
+                             "than max_prompt %d: chunked prefill is not "
+                             "built" % (self._buckets[-1], self._max_prompt))
+        self._per_dispatch = geo["prompts_per_dispatch"]
+        self._npp, self._P = geo["pages_per_slot"], geo["num_pages"]
+        if self._P - 1 < self._npp:
+            raise ValueError("num_pages=%d cannot hold one full sequence "
+                             "(%d pages)" % (self._P, self._npp))
+        self._prefill_progs = built["prefill"]
+        self._step_prog = built["step"]
+        self._fetch = built["fetches"]
+        # what the frontend's worker reads of a session (its package-
+        # internal contract): trg[0] is a placeholder, generated tokens
+        # are trg[1..pos]; no token is ever equal to _eos
+        self._T = self._max_new + 1
+        self._bos, self._eos = 0, -1
+        self._monitor = None
+        self.beam_width = 1
+        self._pool = PagePool(self._P)
+        self._reserved_pages = 0
+        self._free = list(range(self._S - 1, -1, -1))
+        self._live = {}          # slot -> {"pos", "n", "len", "trg"}
+        self._slot_pages = {}    # slot -> [page ids]
+        self._table = np.zeros((self._S, self._npp), "int64")
+        self._live_mask = np.zeros((self._S, 1), "int64")
+        self._pending = collections.deque()
+        self._owner = {}
+        self._results = {}
+        self._next_req = 0
+        self._trace_ids = {}
+        self._slot_traces = {}
+        self._trace_cow = {}     # the shared hooks' (never filled here)
+        self.steps_done = 0
+        self.prefill_dispatches = 0
+        self.last_counters = {}
+        # what the last admit_pending()/step() dispatched, for whoever
+        # times them from outside: [(bucket, [prompt lengths])] and
+        # (live slots, resident rows the dispatch's first step attends)
+        self.last_prefills = []
+        self.last_step = (0, 0)
+        self.probe_slots = np.zeros((int(probe_rows),), "int64")
+        self._exe.run(built["init"], scope=self._scope)
+
+    # -- what this session does not do ---------------------------------------
+    _paged = True
+    _prefix_cache = None
+
+    def _unsupported(self, what):
+        raise ServingError(
+            "DecoderOnlySession does not support %s (forced prefixes and "
+            "the prefix cache, fork groups and copy-on-write, beam and "
+            "speculation are SlotDecodeSession's)" % what)
+
+    def admit_group(self, src, n=1, src_len=None, prefix_tokens=None):
+        if int(n) != 1 or prefix_tokens:
+            self._unsupported("fork groups (n=%d) or forced prefixes" % n)
+        return [self.admit(src, src_len)]
+
+    def admit_beam(self, *a, **kw):
+        self._unsupported("beam search")
+
+    def take_beam_result(self, request_id):
+        return None
+
+    def prefix_cache_stats(self):
+        return {}
+
+    # -- tracing hooks: SlotDecodeSession's, as they are ---------------------
+    _trace_admitted = _SlotSession._trace_admitted
+    _trace_bank = _SlotSession._trace_bank
+    _trace_cancel = _SlotSession._trace_cancel
+    _trace_step = _SlotSession._trace_step
+    _tokens_past = _SlotSession._tokens_past
+
+    # -- introspection -------------------------------------------------------
+    @property
+    def step_program(self):
+        return self._step_prog
+
+    @property
+    def health(self):
+        from paddle_tpu.serving.degradation import HEALTHY
+
+        return HEALTHY
+
+    @property
+    def free_slots(self):
+        return len(self._free)
+
+    @property
+    def active_slots(self):
+        return sorted(self._live)
+
+    @property
+    def pending_requests(self):
+        return [r["id"] for r in self._pending]
+
+    @property
+    def free_pages(self):
+        return self._pool.free_count
+
+    @property
+    def pages_in_use(self):
+        return self._pool.allocated_count
+
+    @property
+    def pool_conserved(self):
+        """free + allocated == pages - 1 (page 0 is the trash page), and
+        every allocated page belongs to exactly one live slot."""
+        held = sum(len(p) for p in self._slot_pages.values())
+        return (self._pool.free_count + self._pool.allocated_count
+                == self._P - 1 and held == self._pool.allocated_count)
+
+    def bucket_of(self, length):
+        for t in self._buckets:
+            if length <= t:
+                return t
+        raise ServingError("a prompt of %d tokens is longer than the "
+                           "longest prefill bucket (%d)"
+                           % (length, self._buckets[-1]))
+
+    # -- the queue -----------------------------------------------------------
+    def enqueue(self, src, src_len=None, prefix_tokens=None, trace_id=None):
+        """Queue one request: ``src`` holds the prompt's ids, the first
+        ``src_len`` of them (all when None). Returns its request id."""
+        if prefix_tokens:
+            self._unsupported("forced prefixes")
+        ids = np.asarray(src, dtype="int64").ravel()
+        n = len(ids) if src_len is None else int(np.ravel(src_len)[0])
+        if not 1 <= n <= min(len(ids), self._max_prompt):
+            raise ServingError(
+                "prompt length %d is outside 1..%d (chunked prefill for "
+                "longer prompts is not built)"
+                % (n, min(len(ids), self._max_prompt)))
+        rid = self._next_req
+        self._next_req += 1
+        entry = {"id": rid, "prompt": ids[:n].copy(), "len": n,
+                 "prefix": None}
+        if trace_id:
+            self._trace_ids[rid] = str(trace_id)
+            entry["t_enq"] = time.time()
+            entry["round"] = _tracing.round_id()
+        self._pending.append(entry)
+        return rid
+
+    def drop_pending(self, request_id):
+        rid = int(request_id)
+        for i, req in enumerate(self._pending):
+            if req["id"] == rid:
+                del self._pending[i]
+                tid = self._trace_ids.pop(rid, None)
+                tr = (_tracing.inflight_get(tid) if tid is not None
+                      else None)
+                if tr is not None and tr.origin == "session":
+                    _tracing.finish(tr, outcome="dropped")
+                return True
+        return False
+
+    # -- admission = batched prefill -----------------------------------------
+    def _reserve(self, length):
+        return pages_for(length + self._max_new, self._ps)
+
+    def admit_pending(self):
+        """Admit the head of the queue, as many requests as slots are
+        free and the pool can reserve, in ONE prefill dispatch per length
+        bucket (more when a bucket holds more prompts than a dispatch
+        takes). Returns ``{slot: request_id}`` of this call's
+        admissions."""
+        take = []
+        self.last_prefills = []
+        reserved = self._reserved_pages
+        while self._pending and len(take) < len(self._free):
+            need = self._reserve(self._pending[0]["len"])
+            if reserved + need > self._P - 1:
+                break      # the pool is reserved: wait for slots to end
+            reserved += need
+            take.append(self._pending.popleft())
+        if not take:
+            return {}
+        admitted = {}
+        waiting = list(take)       # not dispatched yet, in queue order
+        with _tracing.span("admit"):
+            try:
+                for bucket in self._buckets:
+                    mine = [r for r in take
+                            if self.bucket_of(r["len"]) == bucket]
+                    per = self._per_dispatch[bucket]
+                    for at in range(0, len(mine), per):
+                        chunk = mine[at:at + per]
+                        for r in chunk:
+                            waiting.remove(r)
+                        admitted.update(self._prefill(bucket, chunk))
+            except BaseException:
+                # the dispatch at fault was rolled back and its requests
+                # are lost (the frontend tells their streams); the ones
+                # not dispatched yet go back to the head, in order
+                self._pending.extendleft(reversed(waiting))
+                raise
+        return admitted
+
+    def _prefill(self, bucket, reqs):
+        """One prefill dispatch of ``reqs`` (all of ``bucket``): slots
+        and pages are taken first, the rows of padding point nowhere."""
+        B, T = self._per_dispatch[bucket], bucket
+        ids = np.zeros((B, T), "int64")
+        lens = np.zeros((B,), "int64")
+        slot_idx = np.full((B,), self._S, "int64")
+        page_rows = np.zeros((B, self._npp), "int64")
+        last_idx = np.arange(B, dtype="int64") * T
+        slots = []
+        t_admit = time.time()
+        try:
+            for b, r in enumerate(reqs):
+                slot = self._free.pop()
+                slots.append(slot)
+                n = r["len"]
+                pages = [self._pool.acquire()
+                         for _ in range(pages_for(n, self._ps))]
+                self._slot_pages[slot] = pages
+                self._reserved_pages += self._reserve(n)
+                ids[b, :n] = r["prompt"]
+                lens[b], slot_idx[b] = n, slot
+                page_rows[b, :len(pages)] = pages
+                page_rows[b, len(pages):] = pages[-1]
+                last_idx[b] += n - 1
+            with _tracing.span("prefill", cpu=True):
+                if _tracing.ENABLED:
+                    _tracing.round_count("prefill_prompts", len(reqs))
+                    _tracing.round_count("prefill_tokens", int(lens.sum()))
+                    _tracing.round_count("prefill_dispatches", 1)
+                    _tracing.round_count("prefill_bucket_%d" % T, 1)
+                with _tracing.span(".dispatch"):
+                    (first,) = self._exe.run(
+                        self._prefill_progs[T],
+                        feed={"prompt_ids": ids.reshape(-1),
+                              "prompt_len": lens, "slot_idx": slot_idx,
+                              "page_rows": page_rows, "last_idx": last_idx},
+                        fetch_list=[self._fetch["first_token"]],
+                        scope=self._scope)
+            first = np.asarray(first).reshape(-1)
+        except BaseException:
+            for slot, r in zip(slots, reqs):
+                for page in self._slot_pages.pop(slot, ()):
+                    self._pool.deref(page)
+                self._reserved_pages -= self._reserve(r["len"])
+                self._free.append(slot)
+            raise
+        self.prefill_dispatches += 1
+        self.last_prefills.append((T, [r["len"] for r in reqs]))
+        out = {}
+        for b, (slot, r) in enumerate(zip(slots, reqs)):
+            trg = np.zeros((self._T + self._K,), "int64")
+            trg[1] = first[b]
+            # ``pos`` is what the streams have been shown: the first
+            # token is shown with the first decode dispatch's
+            self._live[slot] = {"pos": 0, "n": 1, "len": r["len"],
+                                "trg": trg}
+            self._live_mask[slot, 0] = 1
+            self._write_table_row(slot)
+            self._owner[slot] = out[slot] = r["id"]
+            if r["id"] in self._trace_ids:
+                self._trace_admitted(r, slot, t_admit)
+            _sequences_total.inc(event="admitted")
+        _active_slots.set(len(self._live))
+        _pages_in_use.set(self._pool.allocated_count)
+        return out
+
+    def admit(self, src, src_len=None, prefix_tokens=None):
+        """Admit ONE prompt now (a prefill dispatch of its own); its
+        slot. ``NoFreeSlotError``/``NoFreePageError`` when it cannot."""
+        if not self._free:
+            raise NoFreeSlotError("all %d slots are occupied" % self._S)
+        self.enqueue(src, src_len, prefix_tokens=prefix_tokens)
+        req = self._pending.pop()
+        self.last_prefills = []
+        if (self._reserved_pages + self._reserve(req["len"])
+                > self._P - 1):
+            raise NoFreePageError("the pool cannot reserve this prompt's "
+                                  "worst case")
+        with _tracing.span("admit"):
+            (slot,) = self._prefill(self.bucket_of(req["len"]), [req])
+        self._owner.pop(slot, None)   # a direct admission has no owner
+        return slot
+
+    # -- pages ---------------------------------------------------------------
+    def _write_table_row(self, slot):
+        pages = self._slot_pages[slot]
+        row = self._table[slot]
+        row[:len(pages)] = pages
+        row[len(pages):] = pages[-1]
+
+    def _provision(self, slot, positions):
+        """Pages for ``positions`` resident rows, up to a slot's whole."""
+        need = min(pages_for(positions, self._ps), self._npp)
+        pages = self._slot_pages[slot]
+        if len(pages) >= need:
+            return
+        while len(pages) < need:
+            pages.append(self._pool.acquire())
+        self._write_table_row(slot)
+
+    def _release(self, slot):
+        st = self._live.pop(slot)
+        for page in self._slot_pages.pop(slot):
+            self._pool.deref(page)
+        self._reserved_pages -= self._reserve(st["len"])
+        self._table[slot] = 0
+        self._live_mask[slot, 0] = 0
+        self._free.append(slot)
+        return st
+
+    # -- decode --------------------------------------------------------------
+    def step(self):
+        """``tokens_per_dispatch`` tokens for every live slot in one
+        dispatch; ``{slot: trg}`` of the sequences that reached
+        ``max_new_tokens`` (their slots and pages are free again)."""
+        if not self._live:
+            return {}
+        with _tracing.span("step", cpu=True):
+            return self._step()
+
+    def _step(self):
+        traced = bool(self._slot_traces) and _tracing.ENABLED
+        if traced:
+            t_step = time.time()
+            pre_pos = {s: self._live[s]["pos"]
+                       for s in self._slot_traces if s in self._live}
+        rows = 0
+        for slot, st in self._live.items():
+            # step j writes the row of position len + n - 1 + j
+            self._provision(slot, st["len"] + st["n"] - 1 + self._K)
+            rows += st["len"] + st["n"]
+        self.last_step = (len(self._live), rows)
+        fetch = [self._fetch["token"]]
+        if self._fetch["expert_tokens"]:
+            fetch.append(self._fetch["expert_tokens"])
+        feed = {"page_table": self._table, "live": self._live_mask}
+        if len(self.probe_slots):
+            feed["probe_slots"] = self.probe_slots
+        with _tracing.span(".dispatch"):
+            out = self._exe.run_multi_step(
+                self._step_prog, self._K, feed=feed, fetch_list=fetch,
+                scope=self._scope, stack_fetches=True)
+        toks = np.asarray(out[0])                       # [K, S, 1]
+        self.steps_done += 1
+        self._count(out[1] if len(out) > 1 else None)
+        finished = {}
+        for slot in list(self._live):
+            st = self._live[slot]
+            n = st["n"]
+            st["trg"][n + 1:n + 1 + self._K] = toks[:, slot, 0]
+            st["n"] = st["pos"] = min(n + self._K, self._max_new)
+            if st["n"] >= self._max_new:
+                finished[slot] = self._release(slot)["trg"][:self._T]
+                _sequences_total.inc(event="completed")
+        _active_slots.set(len(self._live))
+        _pages_in_use.set(self._pool.allocated_count)
+        if traced and pre_pos:
+            self._trace_step(pre_pos, finished, t_step, time.time(), False)
+        return finished
+
+    def _count(self, expert_tokens):
+        """The round's counters, from what came back with the tokens."""
+        counters = {"pages_in_use": self._pool.allocated_count}
+        if expert_tokens is not None:
+            c = np.asarray(expert_tokens, "float64")    # [K, layers, E]
+            mean = c.mean(axis=-1)
+            ratio = c.max(axis=-1)[mean > 0] / mean[mean > 0]
+            if ratio.size:
+                counters["expert_max_over_mean"] = float(ratio.mean())
+        self.last_counters = counters
+        if _tracing.ENABLED:
+            for key, value in counters.items():
+                _tracing.round_count(key, value)
+
+    def cancel(self, slot):
+        """Abort one live sequence: its slot and pages are free again,
+        nothing is banked. Host bookkeeping only: the next dispatch is
+        fed a table and a mask without it. True when it was live."""
+        slot = int(slot)
+        if slot not in self._live:
+            return False
+        with _tracing.span("cancel"):
+            self._release(slot)
+            rid = self._owner.pop(slot, None)
+            if self._slot_traces or self._trace_ids:
+                self._trace_cancel(slot, rid)
+        _sequences_total.inc(event="cancelled")
+        _active_slots.set(len(self._live))
+        _pages_in_use.set(self._pool.allocated_count)
+        return True
+
+    def pump(self):
+        """One scheduler round without a frontend: ``admit_pending``, one
+        ``step``; ``{request_id: trg}`` of the requests that finished,
+        which are also banked for ``take_result``."""
+        self.admit_pending()
+        finished = {}
+        for slot, trg in self.step().items():
+            rid = self._owner.pop(slot, None)
+            if rid is not None:
+                finished[rid] = self._results[rid] = trg
+                self._trace_bank(rid)
+        return finished
+
+    def take_result(self, request_id):
+        rid = int(request_id)
+        out = self._results.pop(rid, None)
+        if out is not None:
+            self._trace_ids.pop(rid, None)
+        return out
+
+    def tokens_of(self, slot):
+        """The tokens a live slot has generated so far (the first comes
+        from its prefill)."""
+        st = self._live[int(slot)]
+        return st["trg"][1:st["n"] + 1].copy()

@@ -246,3 +246,43 @@ def test_tree_attention_lowers_for_tpu(v5e, n_nodes, dtype):
         ((S, H, n_nodes, dh), dtype), pool, pool, ((S, npp), jnp.int32),
         ((S,), jnp.int32), ((S, n_nodes, n_nodes), jnp.int32))
     assert pa.TREE_KERNEL_NAME in text
+
+
+# the latent-attention decoder's serving cell (perfbench glm47_flash_6l):
+# 256 slots, 20 heads, a 512 + 64 wide row pool in pages of 128
+def test_latent_decode_lowers_for_tpu_at_the_served_widths(v5e):
+    from paddle_tpu.kernels import latent_attention as la
+
+    S, H, C, R, ps, npp = 256, 20, 512, 64, 128, 12
+    text = _compile_v5e(
+        v5e,
+        lambda ql, qr, pool, t, n: la.latent_paged_attention(
+            ql, qr, pool, t, n, sm_scale=0.0625, force_pallas=True),
+        ((S, H, C), BF16), ((S, H, R), BF16),
+        ((1 + S * npp, ps, la.pool_width(C + R)), BF16), ((S, npp), jnp.int32),
+        ((S,), jnp.int32))
+    assert la.LATENT_KERNEL_NAME in text
+
+
+def test_flash_prefill_width_lowers_for_tpu(v5e):
+    """The same cell's prefill: causal forward at head width 256 in
+    bfloat16, 2 prompts of the longest bucket."""
+    qkv = ((2, 20, 1024, 256), BF16)
+    text = _compile_v5e(
+        v5e, lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+        qkv, qkv, qkv)
+    assert "flash_attention_fwd" in text
+
+
+@pytest.mark.parametrize("rows", [1024, 8192], ids=["decode", "prefill"])
+def test_grouped_matmul_lowers_for_tpu_at_the_served_widths(v5e, rows):
+    """The same cell's routed experts: 64 groups over a decode step's 1024
+    (token, expert) rows and a prefill dispatch's 8192, both products'
+    shapes."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+
+    for k, n in ((2048, 1536), (1536, 2048)):
+        text = _compile_v5e(
+            v5e, lambda a, b, g: gm.grouped_matmul(a, b, g, force_pallas=True),
+            ((rows, k), BF16), ((64, k, n), BF16), ((64,), jnp.int32))
+        assert gm.GROUPED_KERNEL_NAME in text
