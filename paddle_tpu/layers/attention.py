@@ -309,12 +309,16 @@ def grouped_cross_attention(query, k_pool, v_pool, group_of, mask,
                             sm_scale=None, impl="auto", name=None):
     """Group-indexed cross attention for the paged decode step.
 
-    ``query`` [S, H, 1, dh]; ``k_pool``/``v_pool`` [G, H, T_src, dh] —
+    ``query`` [S, H, N, dh] (N = 1 in the step program, the tree's
+    nodes in tree verify); ``k_pool``/``v_pool`` [G, H, T_src, dh] —
     one cross K/V row per admitted SOURCE, not per slot; ``group_of``
-    [S, 1] (or [S]) int group ids; ``mask`` [G, T_src] validity rows.
-    Each slot attends over its group's row, so N slots decoding
+    [S, 1] (or [S]) int group ids; ``mask`` [G, T_src] validity rows,
+    prefix-valid (``sequence_mask`` rows: the kernel reads a row as
+    its count of valid positions). Each slot attends over its group's
+    row; on a TPU the decode kernel reads that row in place by index
+    (kernels/cross_attention_decode.py), so N slots decoding
     continuations of one source cost one group's HBM instead of N
-    dense rows."""
+    dense rows. The reference path gathers the rows."""
     helper = LayerHelper("grouped_cross_attention", name=name)
     out = helper.create_variable_for_type_inference(query.dtype)
     helper.append_op(

@@ -249,27 +249,27 @@ def _lower_grouped_cross_attention(ctx, ins, attrs):
     cross K/V pools are laid out per GROUP (``[G, H, T_src, dh]`` — one
     row per admitted source, however many slots decode continuations of
     it) and each slot reaches its group's row through ``group_of[s]``.
-    N best-of-N slots cost ONE group's HBM instead of N dense rows; the
-    gather is index arithmetic XLA fuses into the attention, so no
-    per-slot copy materializes as pool state."""
-    from paddle_tpu.kernels.flash_attention import flash_attention
+    On a TPU target the decode kernel
+    (kernels/cross_attention_decode.py) resolves ``group_of[s]`` in its
+    K/V index maps, so NO per-slot copy of a row exists and N best-of-N
+    slots cost one group's HBM; ``Mask`` rows are prefix-valid
+    (``sequence_mask``) and reach the kernel as a length a group. The
+    composed reference (``attention_impl`` = ``reference``, and every
+    non-TPU target) gathers the rows — a real copy there."""
+    from paddle_tpu.kernels.cross_attention_decode import (
+        grouped_cross_attention)
 
-    q = ins["Q"][0]  # [S, H, 1, dh]
-    k_pool = ins["KPool"][0]  # [G, H, T_src, dh]
-    v_pool = ins["VPool"][0]
-    gof = jnp.reshape(ins["GroupOf"][0], (-1,)).astype(jnp.int32)  # [S]
-    mask = ins["Mask"][0]  # [G, T_src] validity rows
-    sm_scale = attrs.get("sm_scale", 0.0) or None
     impl = attrs.get("impl", "auto")
     if impl == "auto":
         from paddle_tpu import flags
 
         impl = flags.get("attention_impl")
-    k = k_pool[gof]  # [S, H, T_src, dh]
-    v = v_pool[gof]
-    m = mask[gof][:, None, None, :].astype(bool)  # [S, 1, 1, T_src]
-    return flash_attention(
-        q, k, v, mask=m, sm_scale=sm_scale,
+    return grouped_cross_attention(
+        ins["Q"][0],  # [S, H, N, dh]
+        ins["KPool"][0], ins["VPool"][0],  # [G, H, T_src, dh]
+        ins["GroupOf"][0],  # [S] or [S, 1]
+        ins["Mask"][0],  # [G, T_src] validity rows
+        sm_scale=attrs.get("sm_scale", 0.0) or None,
         force_reference=(impl == "reference"),
         force_pallas=(impl == "pallas"),
     )

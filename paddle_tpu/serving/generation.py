@@ -345,6 +345,10 @@ class SlotDecodeSession(object):
                 if prefix_cache_pages else None)
             self._slot_pages = {}  # slot -> [page ids], ordered by index
             self._slot_group = {}  # slot -> group id
+            # host mirror of the device's (group_of [S], source length
+            # [G]) for the round's cross-attention counts; None = read
+            # it from the scope at the next traced dispatch
+            self._cross_view = None
             self._free_groups = list(range(self._G - 1, -1, -1))
             self._group_members = {}  # group id -> set(slot)
             # reservation-based admission control: every live slot has
@@ -714,6 +718,45 @@ class SlotDecodeSession(object):
         if self._prefix_cache is not None:
             _prefix_hit_rate.set(self._prefix_cache.hit_rate)
 
+    def _note_cross(self, slot, gid, src_len=None):
+        """An admission dispatch landed: ``slot`` reads group ``gid``
+        (whose source is ``src_len`` long, when this dispatch wrote it)."""
+        if self._cross_view is not None:
+            group_of, lengths = self._cross_view
+            group_of[slot] = gid
+            if src_len is not None:
+                lengths[gid] = src_len
+
+    def _count_cross(self, calls):
+        """The round's ``cross_blocks_read`` / ``cross_blocks_grid``:
+        K/V blocks the dispatch's ``calls`` cross-attention kernel calls
+        copy, and the grid steps they run, by the kernel's own grid
+        rules (kernels/cross_attention_decode.grid_accounting) over the
+        device's ``group_of`` and source lengths as the host mirrors
+        them — a released slot keeps its group and is counted, as the
+        kernel reads it. Call under ``tracing.ENABLED``."""
+        from paddle_tpu.kernels.cross_attention_decode import (
+            grid_accounting)
+
+        if self._cross_view is None:
+            scope = self._scope
+            if scope is None:
+                from paddle_tpu.executor import global_scope
+
+                scope = global_scope()
+            self._cross_view = (
+                np.array(scope.get_value("pgd_group_of"),
+                         dtype="int64").reshape(-1),
+                (np.asarray(scope.get_value("pgd_src_mask")) > 0).sum(
+                    axis=-1).astype("int64"))
+        group_of, lengths = self._cross_view
+        acct = grid_accounting(group_of, lengths, self._n_head, self._T,
+                               self._D // self._n_head)
+        _tracing.round_count("cross_blocks_read",
+                             calls * acct["blocks_read"])
+        _tracing.round_count("cross_blocks_grid",
+                             calls * acct["grid_steps"])
+
     def _release_pages(self, slot):
         """Recycle a finished slot's references: the table row is
         pointed back at the trash page FIRST (the still-stepping done
@@ -1011,6 +1054,7 @@ class SlotDecodeSession(object):
                 # under classified retry, re-admit bit-identically
                 _chaos.fault("serve.admit")
             self._run(self._admit_prog, feed, [])
+            self._note_cross(slot0, gid, length)
             write_from = len(cached) * self._ps
             if write_from:
                 self._prefix_cache.tokens_saved += write_from
@@ -1058,6 +1102,7 @@ class SlotDecodeSession(object):
                     jfeed["start_score"] = np.asarray([[-1e9]],
                                                       "float32")
                 self._run(self._join_prog, jfeed, [])
+                self._note_cross(s, gid)
                 if L > 1:
                     _prefill_saved.inc(L - 1)
         except BaseException:
@@ -1090,6 +1135,7 @@ class SlotDecodeSession(object):
         (kept allocated, never freed, and subtracted from the
         reservation capacity so provisioning can still never fail):
         a smaller pool is recoverable, corruption is not."""
+        self._cross_view = None  # the dispatch may or may not have landed
         for s in slots:
             pages = self._slot_pages.pop(s, None)
             self._slot_group.pop(s, None)
@@ -1622,6 +1668,8 @@ class SlotDecodeSession(object):
         self._dispatch_cow(self._cow_window(
             [(slot, st["pos"]) for slot, st in self._live.items()]))
         self._update_pool_gauges()
+        if _tracing.ENABLED:
+            self._count_cross(self._n_layer * self._steps)
         t0 = time.perf_counter()
         with _tracing.span(".dispatch"):
             (toks,) = self._exe.run_multi_step(
@@ -1652,6 +1700,8 @@ class SlotDecodeSession(object):
             span=self._spec_nodes))
         self._update_pool_gauges()
         draft = self._spec_drafter.propose(self._live)
+        if _tracing.ENABLED:
+            self._count_cross(self._n_layer)
         t0 = time.perf_counter()
         out = self._run(self._spec_prog, {
             "spec_draft": draft.astype("int64"),

@@ -550,6 +550,7 @@ class DecodeSnapshotManager(CheckpointManager):
                          for k, v in meta["slot_pages"].items()}
         s._slot_group = {int(k): int(g)
                          for k, g in meta["slot_group"].items()}
+        s._cross_view = None  # the restored device state is the truth
         s._free_groups = [int(g) for g in meta["free_groups"]]
         s._group_members = {int(g): set(int(m) for m in v)
                             for g, v in meta["group_members"].items()}

@@ -248,6 +248,39 @@ def test_tree_attention_lowers_for_tpu(v5e, n_nodes, dtype):
     assert pa.TREE_KERNEL_NAME in text
 
 
+# the decode step's cross attention at the served shapes (perfbench
+# transformer_base: 256 slots and groups, 8 heads of 64, 256 source
+# positions), the tree-verify program's N nodes, transformer_big's 16
+# heads, and a head width of whole lane tiles (row-major pools)
+@pytest.mark.parametrize("S,H,N,T,dh", [
+    (256, 8, 1, 256, 64), (256, 8, 5, 256, 64), (64, 16, 1, 256, 64),
+    (64, 8, 1, 512, 128)], ids=["served", "tree", "16_heads", "dh_128"])
+def test_cross_decode_lowers_for_tpu_and_copies_no_pool(v5e, S, H, N, T, dh):
+    """Mosaic takes the kernel, it goes by the flash forward's name, and
+    the program holds no copy of a pool beside it: the ``[G, H, dh, T]``
+    view of a pool the chip keeps source-minor is a bitcast (the old
+    path gathered and transposed ~0.8 GB a call at the served shapes)."""
+    from paddle_tpu.kernels import cross_attention_decode as cad
+
+    pool = ((S, H, T, dh), F32)
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=SingleDeviceSharding(v5e[0]))
+            for shape, dtype in (((S, H, N, dh), F32), pool, pool,
+                                 ((S,), jnp.int32), ((S, T), F32))]
+
+    def traced(*a):
+        with _tpu_target():
+            return cad.grouped_cross_attention(*a)
+
+    compiled = jax.jit(traced).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%" + cad.CROSS_DECODE_KERNEL_NAME + "." in text
+    assert pa.PAGED_KERNEL_NAME not in text
+    pool_bytes = S * H * T * dh * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
 # the latent-attention decoder's serving cell (perfbench glm47_flash_6l):
 # 256 slots, 20 heads, a 512 + 64 wide row pool in pages of 128
 def test_latent_decode_lowers_for_tpu_at_the_served_widths(v5e):

@@ -460,11 +460,16 @@ def test_round_counts_equal_what_the_session_did(served):
         if "live" in root:
             assert 1 <= root["live"] <= S and root["backlog"] >= 0
             assert any(sp["name"] == "step" for sp in r["spans"])
+            # the dispatch's cross-attention calls (2 layers x 2 token
+            # steps), each a grid of S slots x 1 block of the 8-position
+            # source; a copy where the group changes from slot to slot
+            assert root["cross_blocks_grid"] == 2 * 2 * S
+            assert 2 * 2 <= root["cross_blocks_read"] <= 2 * 2 * S
     # nothing but the documented keys: every other count had no reader
     for _r, i, sp in _round_spans(rounds):
         extra = set(sp) - {"name", "t0", "t1", "cpu", "parent"}
-        assert extra <= ({"live", "backlog", "tokens"} if i == 0
-                         else set()), sp
+        assert extra <= ({"live", "backlog", "tokens", "cross_blocks_read",
+                          "cross_blocks_grid"} if i == 0 else set()), sp
 
 
 def test_request_spans_name_a_round_in_the_ring(served):
