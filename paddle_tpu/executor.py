@@ -12,10 +12,10 @@ through the step function with buffer donation (in-place semantics without
 mutation).
 """
 
-import threading
+import contextlib
 import time
 import weakref
-from collections import OrderedDict
+from collections import OrderedDict, namedtuple
 
 import numpy as np
 
@@ -41,6 +41,7 @@ from paddle_tpu.core.fingerprint import (
 from paddle_tpu.core.lod import LoDTensor
 from paddle_tpu.core.lowering import (
     CompiledProgram,
+    MultiStepProgram,
     step_key,
     step_key_value,
 )
@@ -50,17 +51,37 @@ from paddle_tpu.core.types import Place, TPUPlace, np_dtype
 _global_scope = Scope()
 _scope_stack = [_global_scope]
 
-# Process-global executable registry. Keys are content-addressed
-# (core/fingerprint.py), so structurally identical programs share ONE
-# compile across Executor instances, scopes with identical var-name
-# signatures, and Predictor.Clone() serving threads — where the old
-# id(program)/id(scope) keys forced a recompile per instance (and could
-# alias a dead program's reused id() to a live one after GC). LRU-bounded:
+# Process-global executable registry, of both executors. Keys are
+# content-addressed (core/fingerprint.py), so structurally identical
+# programs share ONE compile across Executor instances, scopes with
+# identical var-name signatures, and Predictor.Clone() serving threads —
+# where the old id(program)/id(scope) keys forced a recompile per instance
+# (and could alias a dead program's reused id() to a live one after GC).
+# A ParallelExecutor's keys carry its mesh's devices and every policy
+# input, so one REBUILT over the same devices (the elastic runtime rebuilds
+# per membership generation) reuses the sharded executable. LRU-bounded:
 # eviction drops only the shared handle; executors that already hold an
 # entry in their instance cache keep using it.
 _shared_executables = OrderedDict()
 _shared_lock = _lock_witness.make_lock("executor.shared_executables")
 _SHARED_CAP = 128
+
+# What tells one entry point from another inside ``_run_step``: the dispatch
+# record's and telemetry's ``origin``, the ``name`` the black box, the
+# watchdog and the verifier see, the ``dispatch`` origin of a retry or an
+# OOM, and the profiler's ``span``.
+_Entry = namedtuple("_Entry", "origin name dispatch span")
+_RUN = _Entry("single", "Executor.run", "Executor.dispatch", "executor.run")
+_RUN_ASYNC = _Entry("async", "Executor.run_async", "Executor.dispatch",
+                    "executor.dispatch")
+_RUN_MULTI = _Entry("multi_step", "Executor.run_multi_step",
+                    "Executor.run_multi_step", "executor.run_multi_step[%d]")
+_RUN_PARALLEL = _Entry("parallel", "ParallelExecutor.run",
+                       "ParallelExecutor.dispatch", "parallel_executor.run")
+
+# An executable's mode, part of its key: one step, or
+# ``("multi", steps, stack_fetches)`` for a scan of ``steps``.
+_SINGLE = ("single",)
 
 
 def global_scope():
@@ -70,43 +91,43 @@ def global_scope():
     return _scope_stack[-1]
 
 
+@contextlib.contextmanager
 def scope_guard(scope):
-    import contextlib
-
-    @contextlib.contextmanager
-    def guard():
-        _scope_stack.append(scope)
-        try:
-            yield
-        finally:
-            _scope_stack.pop()
-
-    return guard()
+    _scope_stack.append(scope)
+    try:
+        yield
+    finally:
+        _scope_stack.pop()
 
 
-def _as_feed_array(value, place):
-    """numpy / LoDTensor / device array -> (array, lod or None). Device
-    arrays (a double-buffered PyReader's prefetched feeds) pass through
-    untouched — np.asarray would block on the in-flight transfer and
-    round-trip the data through the host."""
+def _as_feed_array(value):
+    """numpy / LoDTensor / device array -> array. Device arrays (a
+    double-buffered PyReader's prefetched feeds) pass through untouched —
+    np.asarray would block on the in-flight transfer and round-trip the
+    data through the host."""
     if isinstance(value, LoDTensor):
         # .numpy() IS the backing ndarray; re-wrapping it in np.asarray
         # added a per-feed copy whenever the holder wasn't already a plain
         # contiguous ndarray — pass it through untouched instead
-        return value.numpy(), value.lod() or None
+        return value.numpy()
     if isinstance(value, jax.Array):
-        return value, None
-    return np.asarray(value), None
+        return value
+    return np.asarray(value)
 
 
-def _materialize_fetches(arrays, origin):
+def _fetch_names(fetch_list):
+    return [v.name if isinstance(v, framework.Variable) else str(v)
+            for v in fetch_list]
+
+
+def _materialize_fetches(arrays, origin, to_numpy=np.asarray):
     """Host-materialize fetched device arrays. With async dispatch the
     allocator's RESOURCE_EXHAUSTED often surfaces at the first host read
     rather than inside the dispatch call, so every materialize site —
-    sync return, multi-step return, FetchHandle.result — routes through
-    the same M001 enrichment as the dispatch path."""
+    the sync return of any entry point, FetchHandle.result — routes
+    through the same M001 enrichment as the dispatch path."""
     try:
-        return [np.asarray(a) for a in arrays]
+        return [to_numpy(a) for a in arrays]
     except Exception as exc:
         if _memory.is_oom(exc) and not isinstance(
                 exc, _memory.MemoryExhaustedError):
@@ -137,18 +158,69 @@ def _maybe_verify(program, feed_specs, fetch_names, origin):
             len(diags), "; ".join(str(d) for d in diags[:5]))
 
 
+def _shared_executable(key, build, program, feed_specs, fetch_names,
+                       scope_names, origin, why, extra, refresh=False):
+    """The executable under ``key`` in the process-global registry, or
+    ``build()``'s, published there: what both executors do when their own
+    cache misses. ``refresh`` (use_program_cache=False) bypasses the lookup
+    so THIS run re-traces, but still publishes the fresh compile — evicting
+    instead would yank a live executable out from under unrelated
+    executors / Predictor clones."""
+    if not refresh:
+        with _shared_lock:
+            cp = _shared_executables.get(key)
+            if cp is not None:
+                _shared_executables.move_to_end(key)
+        if cp is not None:
+            exec_cache.record_trace_hit()
+            return cp
+    # compile OUTSIDE the registry lock: an XLA compile (plus any retry
+    # backoff) must never stall other executors' unrelated cache misses.
+    # Two threads racing the same key pay a duplicate compile and the
+    # loser adopts the winner's entry below.
+    exec_cache.record_trace_miss()
+    exec_cache.configure()
+    # FLAGS_verify_program: structural verification on the fresh-compile
+    # path only (never per step) — a bad graph fails here with rule-tagged
+    # diagnostics instead of an eval_shape traceback inside the build
+    _maybe_verify(program, feed_specs, fetch_names, origin=origin)
+    # one structured "why did this retrace" event per fresh compile,
+    # diffed against the nearest cached key
+    _explain.record_compile(dict(
+        why, program=key[0], fetch_names=tuple(fetch_names),
+        feed_specs=tuple(sorted(
+            (n, (s, d)) for n, (s, d) in feed_specs.items()))),
+        forced=refresh)
+
+    def attempt():
+        if _chaos.ENABLED:
+            _chaos.fault("exec.compile")
+        return build()
+
+    # classified-transient failures on the fresh-compile path (flaky
+    # cache reads, preempted backend compiles) retry under
+    # FLAGS_dispatch_retries; verifier/user errors surface immediately
+    cp = _retry.call(attempt, origin=origin.partition(".")[0] + ".compile")
+    # stable cross-process key for the on-disk AOT image layer
+    cp._exec_cache_key = executable_key(
+        program, feed_specs, fetch_names, scope_names, extra=extra)
+    with _shared_lock:
+        winner = None if refresh else _shared_executables.get(key)
+        if winner is not None:
+            return winner
+        _shared_executables[key] = cp
+        while len(_shared_executables) > _SHARED_CAP:
+            _shared_executables.popitem(last=False)
+    return cp
+
+
 def _on_device(arr, device):
     """Does this jax.Array live on ``device`` alone?"""
     return arr.sharding.device_set == {device}
 
 
-def _state_value(name, val, device):
+def _state_value(name, val, cp, device):
     """A scope value as the step's executable takes it: on ``device``."""
-    if val is None:
-        raise RuntimeError(
-            "persistable variable %r is not initialized in the scope "
-            "(did you run the startup program?)" % name
-        )
     if not isinstance(val, jax.Array):
         return jax.device_put(np.asarray(val), device)
     if not _on_device(val, device):
@@ -187,6 +259,58 @@ class _GatherPlan(object):
             self.holders.append((n, scope.find_var(n) or ScopeVariable(n)))
         self.seen = [None] * len(self.holders)
         self.outs = None
+
+
+def _gather_state(cp, scope, place, device):
+    """(plan, {name: value as the executable takes it}) for
+    ``cp.state_in``. The holders are looked up once per (executable,
+    scope) and kept in the scope while no name enters or leaves its chain;
+    a value goes through ``place(name, value, cp, device)`` only if it is
+    not the array that passed here, or was written back by
+    ``_write_back``, last time."""
+    membership = scope.membership()
+    plan = scope._gather_plans.get(cp)
+    rebuilt = plan is None or plan.membership != membership
+    if rebuilt:
+        plan = scope._gather_plans[cp] = _GatherPlan(cp, scope, membership)
+    exec_cache.record_gather_plan(rebuilt)
+    state = {}
+    seen = plan.seen
+    for i, (n, holder) in enumerate(plan.holders):
+        val = holder.value
+        ref = seen[i]
+        if val is None or ref is None or ref() is not val:
+            if val is None:
+                raise RuntimeError(
+                    "persistable variable %r is not initialized in the "
+                    "scope (did you run the startup program?)" % n)
+            moved = place(n, val, cp, device)
+            # a value that had to be moved stays what it is in the
+            # scope, and is moved again next run, as before
+            seen[i] = weakref.ref(val) if moved is val else None
+            val = moved
+        state[n] = val
+    return plan, state
+
+
+def _write_back(plan, scope, new_state):
+    """The step's outputs into the scope, through the holders."""
+    outs = plan.outs
+    if outs is None:
+        # find-or-create HERE, as ``scope.set_value`` does, and only
+        # now that the dispatch has succeeded. A holder this creates
+        # changes the scope's membership: the next gather builds the
+        # plan anew, and from then on nothing moves
+        index = {n: i for i, (n, _h) in enumerate(plan.holders)}
+        outs = plan.outs = [
+            (n, scope.var(n), index.get(n, -1)) for n in new_state]
+    seen = plan.seen
+    holders = plan.holders
+    for n, holder, i in outs:
+        val = new_state[n]
+        holder.value = val
+        if i >= 0 and holders[i][1] is holder:
+            seen[i] = weakref.ref(val)
 
 
 # On-device finiteness scan for FLAGS_check_nan_inf: one fused executable
@@ -324,8 +448,8 @@ class Executor(object):
         self._base_seed = np.random.randint(0, 2**31 - 1)
 
     # -- compilation cache --------------------------------------------------
-    def _get_compiled(self, program, feed_specs, fetch_names, scope,
-                      refresh=False):
+    def _get_compiled(self, program, feed_specs, fetch_names, scope, device,
+                      mode=_SINGLE, refresh=False):
         # Deferred shape inference must resolve BEFORE the fingerprint is
         # taken: filling shapes afterwards would change the content hash
         # and bust this very cache on the next run. No-op unless the
@@ -334,11 +458,11 @@ class Executor(object):
             program.infer_deferred_shapes(
                 feed_shapes={n: s for n, (s, _d) in feed_specs.items()})
         scope_names = scope.visible_names()
-        device = self.place.jax_device()
         key = (
             # content hash, not id(program): CPython reuses id() after GC,
             # and structurally identical programs should share the compile
             program_fingerprint(program),
+            mode,
             tuple(sorted((n, s, d) for n, (s, d) in feed_specs.items())),
             tuple(fetch_names),
             # Scope contents shape the step signature (state_in): a var
@@ -358,65 +482,29 @@ class Executor(object):
         if cp is not None:
             exec_cache.record_trace_hit()
             return cp
-        with _shared_lock:
-            # refresh (use_program_cache=False) bypasses the lookup so
-            # THIS run re-traces, but still publishes the fresh compile —
-            # evicting instead would yank a live executable out from
-            # under unrelated executors / Predictor clones
-            cp = None if refresh else _shared_executables.get(key)
-            if cp is None:
-                exec_cache.record_trace_miss()
-                exec_cache.configure()
-                # FLAGS_verify_program: structural verification on the
-                # fresh-compile path only (never per step) — a bad graph
-                # fails here with rule-tagged diagnostics instead of an
-                # eval_shape traceback inside CompiledProgram
-                _maybe_verify(program, feed_specs, fetch_names,
-                              origin="Executor.run")
-                # one structured "why did this retrace" event per fresh
-                # compile, diffed against the nearest cached key
-                _explain.record_compile({
-                    "program": key[0],
-                    "feed_specs": tuple(sorted(
-                        (n, (s, d)) for n, (s, d) in feed_specs.items())),
-                    "fetch_names": tuple(fetch_names),
-                    "scope_signature": key[3],
-                    "flags": key[6],
-                    "device": "%s:%d" % (device.platform, device.id),
-                    "mode": "single",
-                }, forced=refresh)
-                def _build():
-                    if _chaos.ENABLED:
-                        _chaos.fault("exec.compile")
-                    return CompiledProgram(
-                        program,
-                        feed_specs,
-                        fetch_names,
-                        scope_names,
-                        is_test=program._is_test,
-                        device=device,
-                    )
+        multi = mode is not _SINGLE
 
-                # classified-transient failures on the fresh-compile
-                # path (flaky cache reads, preempted backend compiles)
-                # retry under FLAGS_dispatch_retries; verifier/user
-                # errors surface immediately
-                cp = _retry.call(_build, origin="Executor.compile")
-                # stable cross-process key for the on-disk AOT image
-                # layer; device.id included so executors pinned to
-                # different local devices never share one baked image
-                cp._exec_cache_key = executable_key(
-                    program, feed_specs, fetch_names, scope_names,
-                    extra=("single", device.platform, device.id,
-                           getattr(device, "device_kind", "")),
-                )
-                _shared_executables[key] = cp
-                while len(_shared_executables) > _SHARED_CAP:
-                    _shared_executables.popitem(last=False)
-            else:
-                _shared_executables.move_to_end(key)
-                exec_cache.record_trace_hit()
-        self._cache[key] = cp
+        def build():
+            if multi:
+                return MultiStepProgram(
+                    program, mode[1], feed_specs, fetch_names, scope_names,
+                    is_test=program._is_test, device=device,
+                    stack_fetches=mode[2])
+            return CompiledProgram(
+                program, feed_specs, fetch_names, scope_names,
+                is_test=program._is_test, device=device)
+
+        cp = self._cache[key] = _shared_executable(
+            key, build, program, feed_specs, fetch_names, scope_names,
+            origin=(_RUN_MULTI if multi else _RUN).name,
+            why={"scope_signature": scope_names, "flags": key[7],
+                 "device": "%s:%d" % (device.platform, device.id),
+                 "mode": "multi_step[%d]" % mode[1] if multi else "single"},
+            # device.id included so executors pinned to different local
+            # devices never share one baked image
+            extra=mode + (device.platform, device.id,
+                          getattr(device, "device_kind", "")),
+            refresh=refresh)
         return cp
 
     def compiled_text(self, program):
@@ -426,7 +514,21 @@ class Executor(object):
         ``CompiledProgram.compiled_text``."""
         fp = program_fingerprint(program)
         return [cp.compiled_text() for key, cp in self._cache.items()
-                if fp in key[:2]]
+                if key[0] == fp]
+
+    def _enter(self, entry, program, feed, fetch_list, scope,
+               return_numpy=True, mode=_SINGLE, refresh=False):
+        device = self.place.jax_device()
+        # Everything in the step (feed transfer, key creation, dispatch)
+        # stays on the Place's device: with several backends loaded (TPU
+        # plugin + CPU), stray ops like PRNGKey would otherwise run on the
+        # default platform — wrong device, and unsafe under concurrent
+        # serving.
+        with jax.default_device(device):
+            return _run_step(
+                self, entry, program or framework.default_main_program(),
+                feed or {}, fetch_list or [], scope or global_scope(),
+                device, return_numpy, mode, refresh)
 
     def run(
         self,
@@ -439,32 +541,11 @@ class Executor(object):
         return_numpy=True,
         use_program_cache=True,
     ):
-        program = program or framework.default_main_program()
-        feed = feed or {}
-        fetch_list = fetch_list or []
-        scope = scope or global_scope()
-        device = self.place.jax_device()
-        if not use_program_cache:
-            # reference use_program_cache=False semantics: drop this
-            # program's cached single-run executables from THIS executor
-            # so this run re-traces; the process-global registry is
-            # bypassed (not purged) via refresh — see _get_compiled
-            # (multi-step scan executables are keyed separately and
-            # survive — they are expensive compiles run() never uses)
-            fp = program_fingerprint(program)
-            self._cache = {
-                k: v for k, v in self._cache.items()
-                if k[0] == "multi" or k[0] != fp
-            }
-        # Everything below (feed transfer, key creation, dispatch) stays on
-        # the Place's device: with several backends loaded (TPU plugin +
-        # CPU), stray ops like PRNGKey would otherwise run on the default
-        # platform — wrong device, and unsafe under concurrent serving.
-        with jax.default_device(device):
-            return self._run_on_device(
-                program, feed, fetch_list, scope, device, return_numpy,
-                refresh_cache=not use_program_cache,
-            )
+        # reference use_program_cache=False semantics: this run re-traces;
+        # both of this executor's caches are bypassed (not purged) via
+        # refresh — see _shared_executable
+        return self._enter(_RUN, program, feed, fetch_list, scope,
+                           return_numpy, refresh=not use_program_cache)
 
     # -- shared run plumbing -------------------------------------------------
     def _prepare_feeds(self, program, feed, device):
@@ -476,7 +557,7 @@ class Executor(object):
         feed_specs = {}
         declared = _declared_feed_dtypes(program)
         for name, value in feed.items():
-            arr, _lod = _as_feed_array(value, self.place)
+            arr = _as_feed_array(value)
             want = declared.get(name, _UNSET)
             if want is _UNSET:
                 var = program.global_block()._find_var_recursive(name)
@@ -493,54 +574,6 @@ class Executor(object):
             feeds[name] = arr
             feed_specs[name] = (tuple(arr.shape), str(arr.dtype))
         return feeds, feed_specs
-
-    @staticmethod
-    def _gather_state(cp, scope, device):
-        """(plan, {name: value on ``device``}) for ``cp.state_in``. The
-        holders are looked up once per (executable, scope) and kept in the
-        scope while no name enters or leaves its chain; a value is looked
-        at only if it is not the array that passed here, or was written
-        back by ``_write_back``, last time."""
-        membership = scope.membership()
-        plan = scope._gather_plans.get(cp)
-        rebuilt = plan is None or plan.membership != membership
-        if rebuilt:
-            plan = scope._gather_plans[cp] = _GatherPlan(
-                cp, scope, membership)
-        exec_cache.record_gather_plan(rebuilt)
-        state = {}
-        seen = plan.seen
-        for i, (n, holder) in enumerate(plan.holders):
-            val = holder.value
-            ref = seen[i]
-            if val is None or ref is None or ref() is not val:
-                moved = _state_value(n, val, device)
-                # a value that had to be moved stays what it is in the
-                # scope, and is moved again next run, as before
-                seen[i] = weakref.ref(val) if moved is val else None
-                val = moved
-            state[n] = val
-        return plan, state
-
-    @staticmethod
-    def _write_back(plan, scope, new_state):
-        """The step's outputs into the scope, through the holders."""
-        outs = plan.outs
-        if outs is None:
-            # find-or-create HERE, as ``scope.set_value`` does, and only
-            # now that the dispatch has succeeded. A holder this creates
-            # changes the scope's membership: the next gather builds the
-            # plan anew, and from then on nothing moves
-            index = {n: i for i, (n, _h) in enumerate(plan.holders)}
-            outs = plan.outs = [
-                (n, scope.var(n), index.get(n, -1)) for n in new_state]
-        seen = plan.seen
-        holders = plan.holders
-        for n, holder, i in outs:
-            val = new_state[n]
-            holder.value = val
-            if i >= 0 and holders[i][1] is holder:
-                seen[i] = weakref.ref(val)
 
     def _step_key(self, program, device):
         """The run's key as the step's executable takes it: the base key
@@ -618,28 +651,18 @@ class Executor(object):
         flags_dev = _finite_stack(vals) if vals else None
 
         def finish():
-            if host_bad is not None:
-                raise RuntimeError(
-                    "NaN/Inf detected in variable %r after program run "
-                    "(FLAGS_check_nan_inf)" % host_bad
-                )
-            if flags_dev is None:
-                return
-            finite = np.asarray(flags_dev)
-            if not finite.all():
-                bad = names[int(np.argmin(finite))]
+            bad = host_bad
+            if bad is None and flags_dev is not None:
+                finite = np.asarray(flags_dev)
+                if not finite.all():
+                    bad = names[int(np.argmin(finite))]
+            if bad is not None:
                 raise RuntimeError(
                     "NaN/Inf detected in variable %r after program run "
                     "(FLAGS_check_nan_inf)" % bad
                 )
 
         return finish
-
-    @staticmethod
-    def _check_nan_inf(new_state, fetch_names, fetches):
-        finish = Executor._nan_check_start(new_state, fetch_names, fetches)
-        if finish is not None:
-            finish()
 
     @staticmethod
     def _nan_snapshot(cp, state):
@@ -661,15 +684,12 @@ class Executor(object):
         return snap
 
     @staticmethod
-    def _nan_blame(exc, program, snapshot, feeds, key, device, steps=1,
-                   mutable_state=(), multi=False):
+    def _nan_blame(exc, program, snapshot, feeds, key, device, steps,
+                   mutable_state, multi):
         """The scanner tripped: replay from the snapshot and raise the
-        enriched NonFiniteError naming the first bad op; without a
-        snapshot (provenance off) the plain scanner error passes
-        through. ``multi`` routes through the scan-body replay (per-step
-        fold_in keys) even for steps == 1."""
-        if snapshot is None:
-            raise exc
+        enriched NonFiniteError naming the first bad op. ``multi`` routes
+        through the scan-body replay (per-step fold_in keys) even for
+        steps == 1."""
         from paddle_tpu.observability import nan_provenance as _nanprov
 
         # the replay is eager: it wants the run's key as a value and the
@@ -681,186 +701,30 @@ class Executor(object):
             mutable_state=mutable_state, is_test=program._is_test,
             platform=getattr(device, "platform", None), multi=multi)
 
-    def _run_on_device(self, program, feed, fetch_list, scope, device,
-                       return_numpy, as_handle=False, refresh_cache=False):
-        # forensics shell: the watchdog sees one armed unit of blocking
-        # work; any escaping exception lands in the black box before it
-        # propagates
-        with _blackbox.guard("Executor.run"), _stepprof.DROP_ON_ERROR:
-            return self._run_on_device_impl(
-                program, feed, fetch_list, scope, device, return_numpy,
-                as_handle=as_handle, refresh_cache=refresh_cache)
+    # -- what ``_run_step`` asks of its executor beyond the methods above;
+    # ParallelExecutor answers each for a mesh ------------------------------
+    _place_state = staticmethod(_state_value)
+    _fetch_to_numpy = staticmethod(np.asarray)
+    # the step's outputs replace the donated inputs under one device label
+    _book_state = staticmethod(_memory.track_state)
 
-    def _run_on_device_impl(self, program, feed, fetch_list, scope, device,
-                            return_numpy, as_handle=False,
-                            refresh_cache=False):
-        # flight-recorder guards: one module-bool load each. The phase
-        # brackets (sp) are always on: every dispatch leaves its small
-        # record (step_profiler.dispatch_records); what is heavy there
-        # waits for FLAGS_step_profile
-        telem = _telemetry.ENABLED
-        prof = _profiler.enabled()
-        sp = _stepprof.begin("async" if as_handle else "single")
-        t0 = sp.t0   # telemetry and the profiler read the span's stamp
-        sp.enter("feed")
-        feeds, feed_specs = self._prepare_feeds(program, feed, device)
-        t_feed = time.perf_counter() if telem else 0.0
-        fetch_names = [
-            v.name if isinstance(v, framework.Variable) else str(v)
-            for v in fetch_list
-        ]
-        # a cache hit closes this bracket in microseconds; a fresh
-        # XLA trace shows up as a fat compile phase instead of
-        # silently inflating the step
-        sp.enter("compile")
-        cp = self._get_compiled(program, feed_specs, fetch_names, scope,
-                                refresh=refresh_cache)
-        # state gather + step-key derivation assemble the dispatch
-        # inputs just like the feed dict does — same bracket, or
-        # they'd surface as unattributed host time
-        sp.enter("feed")
-        plan, state = self._gather_state(cp, scope, device)
-        key = self._step_key(program, device)
-        # the bracket opens here, not at _dispatch: pre-dispatch
-        # work — the profiler's own one-shot cost snapshot, the
-        # blackbox record, the nan snapshot — is host dispatch
-        # overhead and must be charged, not hidden in the
-        # unattributed residual
-        sp.enter("dispatch")
-        if _stepprof.ENABLED:
-            sp.pre_dispatch(cp, state, feeds, key, program)
-        # per-EXECUTABLE key: two feed shapes of one program do different
-        # FLOPs, so the program fingerprint alone would mis-price steps
-        fingerprint = (_telemetry.executable_fingerprint(cp, program)
-                       if telem else None)
-        flops_avals = (_telemetry.capture_step_avals(cp, state, feeds, key)
-                       if telem else None)
-        mem_dev = _telemetry.device_label(device) if telem else None
-        if telem:
-            # HBM ledger: feeds enter the device here; the predicted
-            # plan is filed once per executable so the step records and
-            # any OOM dump carry predicted-vs-measured peak
-            _memory.track_feeds(feeds, mem_dev)
-            _memory.register_plan_for(cp, program, feed_specs, fingerprint)
-        if _blackbox.ENABLED:
-            # the event a crash dump's last entry points at: what was
-            # about to run, with the shapes that ran it
-            _blackbox.record_dispatch(
-                "Executor.run_async" if as_handle else "Executor.run",
-                feed_specs=feed_specs, fetch_names=fetch_names,
-                fingerprint=getattr(cp, "_exec_cache_key", None))
-        nan_snapshot = self._nan_snapshot(cp, state)
-        new_state, fetches = self._dispatch(cp, state, feeds, key,
-                                            origin="Executor.dispatch")
-        # scope writeback is output handling on the host clock —
-        # fetch-side work, even when the caller fetched nothing
-        sp.enter("fetch")
-        self._write_back(plan, scope, new_state)
-        # the donated inputs are dead once the scope holds the outputs:
-        # released here, inside the bracket, and not at this frame's
-        # teardown, where no record would see it (hundreds of arrays:
-        # a tenth of a small dispatch)
-        del state
-        if telem:
-            # scope binding: the step's outputs replace the donated
-            # inputs under the same ledger keys; feeds leave with the
-            # host references, fetched activations stay live until
-            # materialized (below / FetchHandle.result)
-            _memory.track_state(cp, program, new_state, mem_dev)
-            _memory.track_fetches(cp.fetch_names, fetches, mem_dev)
-            _memory.drop_feeds(feeds, mem_dev)
-        # the fetch bracket closes AFTER the ledger writeback: when
-        # telemetry is co-enabled its per-step accounting is still
-        # output handling on the host clock, not unattributed
-        # residual
-        sp.exit()
-        if as_handle:
-            # dispatch complete, nothing synced: the (optional) nan/inf
-            # reductions are already in flight on device, but reading
-            # their verdict waits for .result()
-            raw_check = self._nan_check_start(
-                new_state, cp.fetch_names, fetches)
-            if raw_check is not None and nan_snapshot is not None:
-                def nan_check(_raw=raw_check):
-                    try:
-                        _raw()
-                    except RuntimeError as e:
-                        Executor._nan_blame(e, program, nan_snapshot,
-                                            feeds, key, device)
-            else:
-                nan_check = raw_check
-            handle = FetchHandle(
-                fetches, cp.fetch_names,
-                nan_check=nan_check,
-                track=_profiler.async_fetch_begin(cp.fetch_names)
-                if prof else None,
-                t_dispatch=t0 if telem else None,
-                mem_device=mem_dev,
-            )
-            # the span measured host dispatch latency only; device
-            # + fetch happen in FetchHandle.result on the caller's
-            # clock, so the record is marked dispatch_only
-            _stepprof.finish(sp, feeds=feeds, dispatch_only=True)
-            if telem or prof:
-                t1 = time.perf_counter()
-                if telem:
-                    # dispatch_only: this wall is host dispatch latency,
-                    # not step duration — kept out of percentiles/MFU
-                    _telemetry.record_step(
-                        "async", t1 - t0,
-                        feed_bytes=sum(
-                            getattr(a, "nbytes", 0)
-                            for a in feeds.values()),
-                        h2d_seconds=t_feed - t0, fingerprint=fingerprint,
-                        dispatch_only=True)
-                    if flops_avals is not None:
-                        _telemetry.register_flops_from_avals(
-                            cp, fingerprint, flops_avals)
-                if prof:
-                    _profiler.record_span("executor.dispatch", t0, t1)
-            return handle
-        try:
-            self._check_nan_inf(new_state, cp.fetch_names, fetches)
-        except RuntimeError as e:
-            self._nan_blame(e, program, nan_snapshot, feeds, key, device)
-        if return_numpy:
-            # device bracket: wait for compute to complete BEFORE
-            # the host copy, so device time and d2h materialize are
-            # attributed separately (and annotated on the profiler's
-            # clock for whoever traces)
-            sp.enter("device")
-            with _stepprof.device_annotation():
-                for _f in fetches:
-                    if hasattr(_f, "block_until_ready"):
-                        _f.block_until_ready()
-            sp.enter("fetch")
-            fetches = _materialize_fetches(fetches, "Executor.run")
-            sp.exit()
-        # the span closes BEFORE telemetry's own record-keeping
-        # tail: the observatory reports the same step wall whether
-        # or not other observers are armed, and their bookkeeping
-        # cannot masquerade as unattributed step residual
-        _stepprof.finish(sp, feeds=feeds, fetches=fetches)
-        if telem:
-            # sync return: the fetch buffers are the caller's now (numpy
-            # in hand, or live arrays the executor no longer owns)
-            _memory.drop_fetches(cp.fetch_names, mem_dev)
-        if telem or prof:
-            t1 = time.perf_counter()
-            if telem:
-                _telemetry.record_step(
-                    "single", t1 - t0,
-                    feed_bytes=sum(
-                        getattr(a, "nbytes", 0) for a in feeds.values()),
-                    fetch_bytes=sum(
-                        getattr(f, "nbytes", 0) for f in fetches),
-                    h2d_seconds=t_feed - t0, fingerprint=fingerprint)
-                if flops_avals is not None:
-                    _telemetry.register_flops_from_avals(
-                        cp, fingerprint, flops_avals)
-            if prof:
-                _profiler.record_span("executor.run", t0, t1)
-        return fetches
+    @staticmethod
+    def _book_plan(cp, program, feeds, feed_specs, fingerprint, device):
+        """The ledger label feeds, state and fetches book under; the
+        predicted plan is filed once per executable so the step records
+        and any OOM dump carry predicted-vs-measured peak."""
+        _memory.register_plan_for(cp, program, feed_specs, fingerprint)
+        return _telemetry.device_label(device)
+
+    @staticmethod
+    def _device_times(fetches, new_state, t_dispatch):
+        """Per-device dispatch->ready latencies: a mesh's signal."""
+        return None
+
+    @staticmethod
+    def _dispatch_fields(mode):
+        """What the black box's dispatch event carries beyond the specs."""
+        return {"steps": mode[1]} if mode is not _SINGLE else {}
 
     def run_async(self, program=None, feed=None, fetch_list=None,
                   feed_var_name="feed", fetch_var_name="fetch", scope=None):
@@ -871,16 +735,8 @@ class Executor(object):
         Scope state is updated with live (also non-blocking) arrays, so
         back-to-back dispatches chain on device without host round trips.
         """
-        program = program or framework.default_main_program()
-        feed = feed or {}
-        fetch_list = fetch_list or []
-        scope = scope or global_scope()
-        device = self.place.jax_device()
-        with jax.default_device(device):
-            return self._run_on_device(
-                program, feed, fetch_list, scope, device,
-                return_numpy=False, as_handle=True,
-            )
+        return self._enter(_RUN_ASYNC, program, feed, fetch_list, scope,
+                           return_numpy=False)
 
     def run_multi_step(self, program, steps, feed=None, fetch_list=None,
                        scope=None, return_numpy=True, stack_fetches=False):
@@ -891,162 +747,9 @@ class Executor(object):
         Fetches are the LAST step's values; pass stack_fetches=True for
         the per-step trajectory stacked along a leading [steps] axis
         (costs scan output buffers every iteration)."""
-        from paddle_tpu.core.lowering import MultiStepProgram
-
-        program = program or framework.default_main_program()
-        feed = feed or {}
-        fetch_list = fetch_list or []
-        scope = scope or global_scope()
-        device = self.place.jax_device()
-        with jax.default_device(device), _stepprof.DROP_ON_ERROR:
-            sp = _stepprof.begin("multi_step")
-            sp.enter("feed")
-            feeds, feed_specs = self._prepare_feeds(program, feed, device)
-            # cache-key derivation (fingerprint, scope signature) is
-            # executable resolution — compile-phase work, exactly as
-            # in the single-step path where _get_compiled owns it
-            sp.enter("compile")
-            fetch_names = [
-                v.name if isinstance(v, framework.Variable) else str(v)
-                for v in fetch_list
-            ]
-            if getattr(program, "_deferred_infer", None):
-                program.infer_deferred_shapes(
-                    feed_shapes={n: s
-                                 for n, (s, _d) in feed_specs.items()})
-            scope_names = scope.visible_names()
-            key_id = (
-                "multi", program_fingerprint(program), int(steps),
-                tuple(sorted(feed_specs.items())), tuple(fetch_names),
-                scope_names, program._is_test,
-                getattr(program, "_amp_dtype", None), bool(stack_fetches),
-                trace_flags_key(), (device.platform, device.id),
-            )
-            cp = self._cache.get(key_id)
-            if cp is None:
-                exec_cache.record_trace_miss()
-                exec_cache.configure()
-                _maybe_verify(program, feed_specs, fetch_names,
-                              origin="Executor.run_multi_step")
-                _explain.record_compile({
-                    "program": key_id[1],
-                    "feed_specs": tuple(sorted(
-                        (n, (s, d)) for n, (s, d) in feed_specs.items())),
-                    "fetch_names": tuple(fetch_names),
-                    "scope_signature": scope_names,
-                    "flags": trace_flags_key(),
-                    "device": "%s:%d" % (device.platform, device.id),
-                    "mode": "multi_step[%d]" % int(steps),
-                })
-                def _build():
-                    if _chaos.ENABLED:
-                        _chaos.fault("exec.compile")
-                    return MultiStepProgram(
-                        program, steps, feed_specs, fetch_names,
-                        scope_names, is_test=program._is_test,
-                        device=device, stack_fetches=stack_fetches,
-                    )
-
-                cp = _retry.call(_build, origin="Executor.compile")
-                cp._exec_cache_key = executable_key(
-                    program, feed_specs, fetch_names, scope_names,
-                    extra=("multi", int(steps), bool(stack_fetches),
-                           device.platform, device.id,
-                           getattr(device, "device_kind", "")),
-                )
-                self._cache[key_id] = cp
-            else:
-                exec_cache.record_trace_hit()
-            # input assembly continues on the host clock: state
-            # gather + step-key derivation feed the dispatch
-            sp.enter("feed")
-            plan, state = self._gather_state(cp, scope, device)
-            key = self._step_key(program, device)
-            # opens before the pre-dispatch work (cost snapshot,
-            # blackbox record, nan snapshot, watchdog guard): host
-            # dispatch overhead is charged to dispatch, not left in
-            # the unattributed residual
-            sp.enter("dispatch")
-            if _stepprof.ENABLED:
-                sp.pre_dispatch(cp, state, feeds, key, program)
-            telem = _telemetry.ENABLED
-            prof = _profiler.enabled()
-            t0 = time.perf_counter() if (telem or prof) else 0.0
-            fingerprint = (_telemetry.executable_fingerprint(cp, program)
-                           if telem else None)
-            flops_avals = (_telemetry.capture_step_avals(
-                cp, state, feeds, key) if telem else None)
-            mem_dev = _telemetry.device_label(device) if telem else None
-            if telem:
-                _memory.track_feeds(feeds, mem_dev)
-                _memory.register_plan_for(cp, program, feed_specs,
-                                          fingerprint)
-            if _blackbox.ENABLED:
-                _blackbox.record_dispatch(
-                    "Executor.run_multi_step", feed_specs=feed_specs,
-                    fetch_names=fetch_names, steps=int(steps),
-                    fingerprint=getattr(cp, "_exec_cache_key", None))
-            nan_snapshot = self._nan_snapshot(cp, state)
-            # scale: one dispatch legitimately blocks ~K× the per-step
-            # p95 the watchdog's auto timeout is derived from
-            with _blackbox.guard("Executor.run_multi_step",
-                                 scale=int(steps)):
-                new_state, fetches = self._dispatch(
-                    cp, state, feeds, key,
-                    origin="Executor.run_multi_step")
-                sp.enter("fetch")
-                self._write_back(plan, scope, new_state)
-                del state  # the donated inputs, released in the bracket
-                if telem:
-                    _memory.track_state(cp, program, new_state, mem_dev)
-                    _memory.track_fetches(cp.fetch_names, fetches,
-                                          mem_dev)
-                    _memory.drop_feeds(feeds, mem_dev)
-                # ledger writeback is fetch-side work (see run())
-                sp.exit()
-                try:
-                    self._check_nan_inf(new_state, cp.fetch_names, fetches)
-                except RuntimeError as e:
-                    self._nan_blame(e, program, nan_snapshot, feeds, key,
-                                    device, steps=int(steps),
-                                    mutable_state=cp.mutable_state,
-                                    multi=True)
-                if return_numpy:
-                    sp.enter("device")
-                    with _stepprof.device_annotation():
-                        for _f in fetches:
-                            if hasattr(_f, "block_until_ready"):
-                                _f.block_until_ready()
-                    sp.enter("fetch")
-                    fetches = _materialize_fetches(
-                        fetches, "Executor.run_multi_step")
-                    sp.exit()
-                if telem:
-                    _memory.drop_fetches(cp.fetch_names, mem_dev)
-            # span closes before telemetry's record-keeping tail
-            # (see run()): per-step wall is comparable across
-            # observer configurations
-            _stepprof.finish(sp, steps=int(steps), feeds=feeds,
-                             fetches=fetches)
-            if telem or prof:
-                t1 = time.perf_counter()
-                if telem:
-                    _telemetry.record_step(
-                        "multi_step", t1 - t0, steps=int(steps),
-                        feed_bytes=sum(
-                            getattr(a, "nbytes", 0)
-                            for a in feeds.values()),
-                        fetch_bytes=sum(
-                            getattr(f, "nbytes", 0) for f in fetches),
-                        fingerprint=fingerprint)
-                    if flops_avals is not None:
-                        _telemetry.register_flops_from_avals(
-                            cp, fingerprint, flops_avals,
-                            steps=int(steps))
-                if prof:
-                    _profiler.record_span(
-                        "executor.run_multi_step[%d]" % int(steps), t0, t1)
-            return fetches
+        return self._enter(_RUN_MULTI, program, feed, fetch_list, scope,
+                           return_numpy,
+                           mode=("multi", int(steps), bool(stack_fetches)))
 
     def close(self):
         self._cache.clear()
@@ -1059,3 +762,187 @@ class Executor(object):
             fetch_list=[],
             scope=scope,
         )
+
+
+def _with_blame(check, *replay):
+    """``check`` with its error upgraded by the NaN-provenance replay."""
+    def checked():
+        try:
+            check()
+        except RuntimeError as e:
+            Executor._nan_blame(e, *replay)
+
+    return checked
+
+
+def _run_step(ex, entry, program, feed, fetch_list, scope, device,
+              return_numpy=True, mode=_SINGLE, refresh=False):
+    """One step of ``program``, from feeds to fetches: the one path under
+    ``Executor.run``, ``run_async``, ``run_multi_step`` and
+    ``ParallelExecutor.run``, and the one place each observer is hooked.
+    ``entry`` names the caller to them; ``ex`` prepares the feeds, resolves
+    the executable, places state, makes the key and books the ledger, for
+    one ``device`` or (``device`` None) for its mesh."""
+    multi = mode is not _SINGLE
+    steps = mode[1] if multi else 1
+    as_handle = entry is _RUN_ASYNC
+    # forensics shell: the watchdog sees one armed unit of blocking work
+    # (scale: one dispatch of K steps legitimately blocks ~K× the per-step
+    # p95 its auto timeout is derived from); any escaping exception lands
+    # in the black box before it propagates
+    with _blackbox.guard(entry.name, scale=steps), _stepprof.DROP_ON_ERROR:
+        # flight-recorder guards: one module-bool load each. The phase
+        # brackets (sp) are always on: every dispatch leaves its small
+        # record (step_profiler.dispatch_records); what is heavy there
+        # waits for FLAGS_step_profile
+        telem = _telemetry.ENABLED
+        prof = _profiler.enabled()
+        sp = _stepprof.begin(entry.origin)
+        t0 = sp.t0   # telemetry and the profiler read the span's stamp
+        sp.enter("feed")
+        feeds, feed_specs = ex._prepare_feeds(program, feed, device)
+        t_feed = time.perf_counter() if telem else 0.0
+        fetch_names = _fetch_names(fetch_list)
+        # a cache hit closes this bracket in microseconds; a fresh
+        # XLA trace shows up as a fat compile phase instead of
+        # silently inflating the step
+        sp.enter("compile")
+        cp = ex._get_compiled(program, feed_specs, fetch_names, scope,
+                              device, mode, refresh)
+        # state gather + step-key derivation assemble the dispatch
+        # inputs just like the feed dict does — same bracket, or
+        # they'd surface as unattributed host time
+        sp.enter("feed")
+        plan, state = _gather_state(cp, scope, ex._place_state, device)
+        key = ex._step_key(program, device)
+        # the bracket opens here, not at _dispatch: pre-dispatch
+        # work — the profiler's own one-shot cost snapshot, the
+        # blackbox record, the nan snapshot — is host dispatch
+        # overhead and must be charged, not hidden in the
+        # unattributed residual
+        sp.enter("dispatch")
+        if _stepprof.ENABLED:
+            sp.pre_dispatch(cp, state, feeds, key, program)
+        fingerprint = flops_avals = mem_dev = device_times = None
+        if telem:
+            # per-EXECUTABLE key: two feed shapes of one program do
+            # different FLOPs, so the program fingerprint alone would
+            # mis-price steps
+            fingerprint = _telemetry.executable_fingerprint(cp, program)
+            flops_avals = _telemetry.capture_step_avals(
+                cp, state, feeds, key)
+            # HBM ledger: feeds enter the device here
+            mem_dev = ex._book_plan(cp, program, feeds, feed_specs,
+                                    fingerprint, device)
+            _memory.track_feeds(feeds, mem_dev)
+        if _blackbox.ENABLED:
+            # the event a crash dump's last entry points at: what was
+            # about to run, with the shapes that ran it
+            _blackbox.record_dispatch(
+                entry.name, feed_specs=feed_specs, fetch_names=fetch_names,
+                fingerprint=getattr(cp, "_exec_cache_key", None),
+                **ex._dispatch_fields(mode))
+        nan_snapshot = Executor._nan_snapshot(cp, state)
+        t_dispatch = time.perf_counter() if telem else 0.0
+        new_state, fetches = Executor._dispatch(cp, state, feeds, key,
+                                                origin=entry.dispatch)
+        # scope writeback is output handling on the host clock —
+        # fetch-side work, even when the caller fetched nothing
+        sp.enter("fetch")
+        _write_back(plan, scope, new_state)
+        # the donated inputs are dead once the scope holds the outputs:
+        # released here, inside the bracket, and not at this frame's
+        # teardown, where no record would see it (hundreds of arrays:
+        # a tenth of a small dispatch)
+        del state
+        if telem:
+            # scope binding: the step's outputs replace the donated
+            # inputs under the same ledger keys; feeds leave with the
+            # host references, fetched activations stay live until
+            # materialized (below / FetchHandle.result)
+            ex._book_state(cp, program, new_state, mem_dev)
+            _memory.track_fetches(cp.fetch_names, fetches, mem_dev)
+            _memory.drop_feeds(feeds, mem_dev)
+        # the fetch bracket closes AFTER the ledger writeback: when
+        # telemetry is co-enabled its per-step accounting is still
+        # output handling on the host clock, not unattributed
+        # residual
+        sp.exit()
+        # the (optional) nan/inf reductions are in flight on device from
+        # here; reading their verdict is the sync return's, or waits for
+        # FetchHandle.result
+        nan_check = Executor._nan_check_start(
+            new_state, cp.fetch_names, fetches)
+        if nan_check is not None and nan_snapshot is not None:
+            nan_check = _with_blame(
+                nan_check, program, nan_snapshot, feeds, key, device,
+                steps, cp.mutable_state, multi)
+        if as_handle:
+            # dispatch complete, nothing synced. The span measured host
+            # dispatch latency only; device + fetch happen in
+            # FetchHandle.result on the caller's clock, so the record
+            # (and telemetry's: kept out of percentiles/MFU) is marked
+            # dispatch_only
+            result = FetchHandle(
+                fetches, cp.fetch_names,
+                nan_check=nan_check,
+                track=_profiler.async_fetch_begin(cp.fetch_names)
+                if prof else None,
+                t_dispatch=t0 if telem else None,
+                mem_device=mem_dev,
+            )
+            fetches = ()
+        else:
+            if nan_check is not None:
+                nan_check()
+            if return_numpy:
+                # device bracket: wait for compute to complete BEFORE
+                # the host copy, so device time and d2h materialize are
+                # attributed separately (and annotated on the profiler's
+                # clock for whoever traces)
+                sp.enter("device")
+                if telem:
+                    # measured on the live arrays BEFORE any host
+                    # materialization, and only on the path that syncs
+                    # anyway: it blocks on device shards, so it IS device
+                    # wait, and the per-fetch block_until_ready below
+                    # returns instantly having been paid here
+                    device_times = ex._device_times(
+                        fetches, new_state, t_dispatch)
+                with _stepprof.device_annotation():
+                    for _f in fetches:
+                        if hasattr(_f, "block_until_ready"):
+                            _f.block_until_ready()
+                sp.enter("fetch")
+                fetches = _materialize_fetches(fetches, entry.name,
+                                               ex._fetch_to_numpy)
+                sp.exit()
+            result = fetches
+        # the span closes BEFORE telemetry's own record-keeping
+        # tail: the observatory reports the same step wall whether
+        # or not other observers are armed, and their bookkeeping
+        # cannot masquerade as unattributed step residual
+        _stepprof.finish(sp, steps=steps, feeds=feeds, fetches=fetches,
+                         dispatch_only=as_handle)
+        if telem and not as_handle:
+            # sync return: the fetch buffers are the caller's now (numpy
+            # in hand, or live arrays the executor no longer owns)
+            _memory.drop_fetches(cp.fetch_names, mem_dev)
+        if telem or prof:
+            t1 = time.perf_counter()
+            if telem:
+                _telemetry.record_step(
+                    entry.origin, t1 - t0, steps=steps,
+                    feed_bytes=sum(
+                        getattr(a, "nbytes", 0) for a in feeds.values()),
+                    fetch_bytes=sum(
+                        getattr(f, "nbytes", 0) for f in fetches),
+                    h2d_seconds=t_feed - t0, fingerprint=fingerprint,
+                    dispatch_only=as_handle, device_times=device_times)
+                if flops_avals is not None:
+                    _telemetry.register_flops_from_avals(
+                        cp, fingerprint, flops_avals, steps=steps)
+            if prof:
+                _profiler.record_span(
+                    entry.span % steps if multi else entry.span, t0, t1)
+        return result

@@ -25,9 +25,7 @@ the graph; ``sharding_overrides`` remain an *override* on top of the
 derived plan, validated by analysis rule S001 at transpile time.
 """
 
-import threading
 import time
-from collections import OrderedDict
 
 import numpy as np
 
@@ -35,41 +33,22 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu import framework
-from paddle_tpu import profiler as _profiler
 from paddle_tpu.core import exec_cache
 from paddle_tpu.observability import blackbox as _blackbox
-from paddle_tpu.observability import explain as _explain
-from paddle_tpu.observability import lock_witness
 from paddle_tpu.observability import memory as _memory
-from paddle_tpu.observability import step_profiler as _stepprof
 from paddle_tpu.observability import telemetry as _telemetry
-from paddle_tpu.resilience import chaos as _chaos
-from paddle_tpu.resilience import retry as _retry
-from paddle_tpu.core.fingerprint import (
-    executable_key,
-    program_fingerprint,
-    trace_flags_key,
-)
+from paddle_tpu.core.fingerprint import program_fingerprint, trace_flags_key
 from paddle_tpu.core.lod import LoDTensor
-from paddle_tpu.core.lowering import (
-    CompiledProgram,
-    step_key,
-    step_key_value,
+from paddle_tpu.core.lowering import CompiledProgram, step_key_value
+from paddle_tpu.executor import (
+    _RUN_PARALLEL,
+    Executor,
+    _fetch_names,
+    _run_step,
+    _shared_executable,
+    global_scope,
 )
-from paddle_tpu.executor import global_scope
 from paddle_tpu.parallel.mesh import ShardingPolicy, build_mesh
-
-
-# Process-global GSPMD executable registry (the executor.py shared-
-# registry idiom, mesh-aware): content-addressed keys extended with the
-# mesh's device identity and every policy input, so a ParallelExecutor
-# REBUILT over the same devices — the elastic runtime tears one down and
-# rebuilds per membership generation — reuses the compiled sharded
-# executable instead of paying a fresh XLA compile. A fleet that
-# reshapes 2 -> 1 -> 2 compiles twice, not three times.
-_shared_compiled = OrderedDict()
-_shared_lock = lock_witness.make_lock("parallel_executor.shared_cache")
-_SHARED_CAP = 32
 
 
 class ExecutionStrategy(object):
@@ -385,18 +364,19 @@ class ParallelExecutor(object):
         return self._derived_policy(
             self._collect_state_shapes(), feed_specs).derived
 
-    def _get_compiled(self, feed_specs, fetch_names):
-        scope_names = set(self._scope.local_var_names())
+    def _get_compiled(self, program, feed_specs, fetch_names, scope,
+                      device=None, mode=None, refresh=False):
+        scope_names = scope.visible_names()
         mesh_sig = tuple(sorted(self.mesh.shape.items()))
         key = (
             # content hash (core/fingerprint.py), not _version alone: two
             # structurally identical programs share the sharded compile
-            program_fingerprint(self._program),
+            program_fingerprint(program),
+            ("gspmd", mesh_sig),
             tuple(sorted((n, s, d) for n, (s, d) in feed_specs.items())),
             tuple(fetch_names),
-            frozenset(scope_names),
+            scope_names,
             trace_flags_key(),
-            mesh_sig,
         )
         cp = self._cache.get(key)
         if cp is not None:
@@ -407,84 +387,38 @@ class ParallelExecutor(object):
         # input the instance key could hold constant — a REBUILT
         # executor (elastic reshape back to a seen world size, Predictor
         # clones, tests constructing fresh PEs) must only reuse an
-        # executable whose shardings were derived from identical inputs
+        # executable whose shardings were derived from identical inputs.
+        # A fleet that reshapes 2 -> 1 -> 2 compiles twice, not three times
         state_shapes = self._collect_state_shapes()
-        shared_key = key + (
-            tuple(d.id for d in self.mesh.devices.flat),
+        policy_inputs = (
             self._build_strategy.reduce_strategy,
             tuple(sorted(self._model_sharded_vars)),
             tuple(sorted((k, str(v))
                          for k, v in self._sharding_overrides.items())),
-            tuple(sorted(state_shapes.items())),
         )
-        with _shared_lock:
-            cp = _shared_compiled.get(shared_key)
-            if cp is not None:
-                _shared_compiled.move_to_end(shared_key)
-        if cp is not None:
-            exec_cache.record_trace_hit()
-            # the reused executable carries the plan it compiled with —
-            # this instance adopts it as its active plan
-            self._active_plan = getattr(cp, "_sharding_plan", None)
-            self._cache[key] = cp
+
+        def build():
+            policy = self._policy(state_shapes, feed_specs)
+            cp = CompiledProgram(
+                program, feed_specs, fetch_names, scope_names,
+                is_test=program._is_test, shardings=policy)
+            # the derived plan rides the executable: memory planning
+            # divides predicted bytes by each var's shard factor, and
+            # captures/benches read the summary without re-deriving
+            cp._sharding_plan = getattr(policy, "derived", None)
             return cp
-        # compile OUTSIDE the registry lock: an XLA compile (plus any
-        # retry backoff) must never stall other executors' unrelated
-        # cache misses. Two threads racing the same key pay a duplicate
-        # compile — exactly what the old per-instance caching always
-        # paid — and the loser adopts the winner's entry below.
-        exec_cache.record_trace_miss()
-        exec_cache.configure()
-        _explain.record_compile({
-            "program": key[0],
-            "feed_specs": tuple(sorted(
-                (n, (s, d)) for n, (s, d) in feed_specs.items())),
-            "fetch_names": tuple(fetch_names),
-            "scope_signature": frozenset(scope_names),
-            "flags": key[4],
-            "device": "mesh:%s" % (mesh_sig,),
-            "mode": "gspmd",
-        })
-        policy = self._policy(state_shapes, feed_specs)
-        self._active_plan = getattr(policy, "derived", None)
 
-        def _build():
-            if _chaos.ENABLED:
-                _chaos.fault("exec.compile")
-            return CompiledProgram(
-                self._program,
-                feed_specs,
-                fetch_names,
-                scope_names,
-                is_test=self._program._is_test,
-                shardings=policy,
-            )
-
-        cp = _retry.call(_build, origin="ParallelExecutor.compile")
-        # the derived plan rides the executable: memory planning divides
-        # predicted bytes by each var's shard factor, and captures/
-        # benches read the summary without re-deriving
-        cp._sharding_plan = getattr(policy, "derived", None)
-        cp._exec_cache_key = executable_key(
-            self._program, feed_specs, fetch_names, scope_names,
-            extra=("gspmd", mesh_sig,
-                   self._build_strategy.reduce_strategy,
-                   tuple(sorted(self._model_sharded_vars)),
-                   tuple(sorted(
-                       (k, str(v))
-                       for k, v in self._sharding_overrides.items()
-                   ))),
-        )
-        with _shared_lock:
-            existing = _shared_compiled.get(shared_key)
-            if existing is not None:
-                cp = existing  # a concurrent builder won; use its entry
-                self._active_plan = getattr(cp, "_sharding_plan", None)
-            else:
-                _shared_compiled[shared_key] = cp
-                while len(_shared_compiled) > _SHARED_CAP:
-                    _shared_compiled.popitem(last=False)
-        self._cache[key] = cp
+        cp = self._cache[key] = _shared_executable(
+            key + (tuple(d.id for d in self.mesh.devices.flat),
+                   tuple(sorted(state_shapes.items()))) + policy_inputs,
+            build, program, feed_specs, fetch_names, scope_names,
+            origin=_RUN_PARALLEL.name,
+            why={"scope_signature": scope_names, "flags": key[5],
+                 "device": "mesh:%s" % (mesh_sig,), "mode": "gspmd"},
+            extra=("gspmd", mesh_sig) + policy_inputs)
+        # built here or adopted: the executable carries the plan it
+        # compiled with, and this instance takes it as its active plan
+        self._active_plan = cp._sharding_plan
         return cp
 
     def compiled_text(self):
@@ -494,23 +428,18 @@ class ParallelExecutor(object):
         return [cp.compiled_text() for cp in self._cache.values()]
 
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
-        # forensics shell (same contract as Executor.run): armed for the
-        # watchdog — a multichip step that never returns is THE hang this
-        # layer exists for — and any escaping exception lands in the
-        # black box with this origin before propagating
-        with _blackbox.guard("ParallelExecutor.run"), \
-                _stepprof.DROP_ON_ERROR:
-            return self._run_impl(fetch_list, feed, feed_dict, return_numpy)
-
-    def _run_impl(self, fetch_list, feed=None, feed_dict=None,
-                  return_numpy=True):
-        telem = _telemetry.ENABLED
-        prof = _profiler.enabled()
-        t0 = time.perf_counter() if (telem or prof) else 0.0
         feed = feed if feed is not None else (feed_dict or {})
-        if self._pipeline_stages:
+        if not self._pipeline_stages:
+            return _run_step(self, _RUN_PARALLEL, self._program, feed,
+                             fetch_list, self._scope, None, return_numpy)
+        # the pipeline keeps its state packed per stage and its own jitted
+        # callable: outside the step's core, under the same forensics
+        # shell — a multichip step that never returns is THE hang the
+        # watchdog exists for
+        with _blackbox.guard(_RUN_PARALLEL.name):
+            t0 = time.perf_counter()
             fetches = self._run_pipeline(fetch_list, feed, return_numpy)
-            if telem:
+            if _telemetry.ENABLED:
                 # per-stage occupancy: the bubble fraction of the GPipe
                 # schedule, one labeled series per stage
                 _telemetry.record_pipeline_occupancy(
@@ -519,17 +448,20 @@ class ParallelExecutor(object):
                     "pipeline", time.perf_counter() - t0,
                     fingerprint=program_fingerprint(self._program))
             return fetches
-        sp = _stepprof.begin("parallel")
-        sp.enter("feed")
+
+    # -- what ``executor._run_step`` asks of its executor, for a mesh -------
+    # with no device the base key stays on the host: the mesh executable
+    # places it (replicated) with its other arguments
+    _step_key = Executor._step_key
+
+    def _prepare_feeds(self, program, feed, device=None):
         if isinstance(feed, list):
             # per-device feed dicts (fluid API) -> concat along batch.
-            merged = {}
-            for name in feed[0]:
-                merged[name] = np.concatenate(
-                    [np.asarray(d[name]) for d in feed], axis=0
-                )
-            feed = merged
-
+            feed = {
+                name: np.concatenate(
+                    [np.asarray(d[name]) for d in feed], axis=0)
+                for name in feed[0]
+            }
         feeds = {}
         feed_specs = {}
         for name, value in feed.items():
@@ -560,158 +492,57 @@ class ParallelExecutor(object):
                     arr = jax.make_array_from_process_local_data(sh, arr)
             feeds[name] = arr
             feed_specs[name] = (tuple(arr.shape), str(arr.dtype))
+        return feeds, feed_specs
 
-        sp.exit()
-        fetch_names = [
-            v.name if isinstance(v, framework.Variable) else str(v)
-            for v in fetch_list
-        ]
-        sp.enter("compile")
-        cp = self._get_compiled(feed_specs, fetch_names)
-        sp.exit()
-        # input assembly continues: state gather (+ reshard) and
-        # step-key derivation run on the host clock before dispatch
-        sp.enter("feed")
+    def _place_state(self, name, val, cp, device=None):
+        """A scope value as the mesh's executable takes it. State
+        initialized by the single-device startup Executor is committed to
+        one device; donated jit args must already carry the mesh sharding,
+        so reshard explicitly (BCastParamsToDevices role,
+        parallel_executor.cc:180)."""
+        if isinstance(val, jax.Array):
+            return self._ensure_sharded(val, cp.shardings.state_sharding(name))
+        return val
 
-        state = {}
-        for n in cp.state_in:
-            v = self._scope.find_var(n)
-            if v is None or v.value is None:
-                raise RuntimeError(
-                    "persistable var %r not initialized (run startup first)" % n
-                )
-            val = v.value
-            # State initialized by the single-device startup Executor is
-            # committed to one device; donated jit args must already carry
-            # the mesh sharding, so reshard explicitly (BCastParamsToDevices
-            # role, parallel_executor.cc:180).
-            if isinstance(val, jax.Array):
-                val = self._ensure_sharded(val, cp.shardings.state_sharding(n))
-            state[n] = val
+    def _book_plan(self, cp, program, feeds, feed_specs, fingerprint,
+                   device=None):
+        """HBM ledger: feeds/fetches (global sharded arrays) book under
+        one 'mesh' label; STATE books per device from real shard sizes
+        (``_book_state``), so the ledger shows each chip's param/opt_state
+        residency under the derived plan."""
+        _telemetry.record_device_transfer(
+            self._feed_bytes_by_device(cp, feeds))
+        if not getattr(cp, "_memory_plan_done", False):
+            shard_factors = mesh_devices = None
+            if cp._sharding_plan is not None:
+                from paddle_tpu.parallel.sharding import plan_shard_factors
 
-        key = self._step_key()
-        sp.exit()
-        # opens before the pre-dispatch work (cost snapshot,
-        # blackbox record): host dispatch overhead is charged to
-        # dispatch, not left in the unattributed residual
-        sp.enter("dispatch")
-        if _stepprof.ENABLED:
-            sp.pre_dispatch(cp, state, feeds, key, self._program)
-        flops_avals = None
-        mem_dev = None
-        if telem:
-            fingerprint = _telemetry.executable_fingerprint(
-                cp, self._program)
-            flops_avals = _telemetry.capture_step_avals(
-                cp, state, feeds, key)
-            _telemetry.record_device_transfer(
-                self._feed_bytes_by_device(cp, feeds))
-            # HBM ledger: feeds/fetches (global sharded arrays) book
-            # under one 'mesh' label; STATE books per device from real
-            # shard sizes below, so the ledger shows each chip's
-            # param/opt_state residency under the derived plan
-            mem_dev = "mesh"
-            _memory.track_feeds(feeds, mem_dev)
-            if not getattr(cp, "_memory_plan_done", False):
-                shard_factors = mesh_devices = None
-                if getattr(cp, "_sharding_plan", None) is not None:
-                    from paddle_tpu.parallel.sharding import (
-                        plan_shard_factors)
+                shard_factors = plan_shard_factors(cp._sharding_plan)
+                mesh_devices = self.device_count
+            _memory.register_plan_for(cp, program, feed_specs, fingerprint,
+                                      shard_factors=shard_factors,
+                                      mesh_devices=mesh_devices)
+        return "mesh"
 
-                    shard_factors = plan_shard_factors(cp._sharding_plan)
-                    mesh_devices = self.device_count
-                _memory.register_plan_for(cp, self._program, feed_specs,
-                                          fingerprint,
-                                          shard_factors=shard_factors,
-                                          mesh_devices=mesh_devices)
-        if _blackbox.ENABLED:
-            _blackbox.record_dispatch(
-                "ParallelExecutor.run", feed_specs=feed_specs,
-                fetch_names=fetch_names,
-                fingerprint=getattr(cp, "_exec_cache_key", None),
-                mesh=dict(self.mesh.shape))
-        t_disp = time.perf_counter() if telem else 0.0
-        from paddle_tpu.executor import Executor as _Executor
+    @staticmethod
+    def _book_state(cp, program, new_state, mem_dev):
+        # per-device ledger entries from the REAL shard sizes: a
+        # param fsdp-sharded 4 ways books ~1/4 of its bytes on each
+        # device label; replicated state books full bytes on every
+        # device — paddle_tpu_hbm_live_bytes{device,kind} shows the
+        # derived plan's memory win directly
+        _memory.track_state_sharded(cp, program, new_state,
+                                    fallback_device=mem_dev)
 
-        new_state, fetches = _Executor._dispatch(
-            cp, state, feeds, key, origin="ParallelExecutor.dispatch")
-        sp.exit()
-        sp.enter("fetch")
-        for n, val in new_state.items():
-            self._scope.set_value(n, val)
-        del state  # the donated inputs, released in the bracket
-        if telem:
-            # per-device ledger entries from the REAL shard sizes: a
-            # param fsdp-sharded 4 ways books ~1/4 of its bytes on each
-            # device label; replicated state books full bytes on every
-            # device — paddle_tpu_hbm_live_bytes{device,kind} shows the
-            # derived plan's memory win directly
-            _memory.track_state_sharded(cp, self._program, new_state,
-                                        fallback_device=mem_dev)
-            _memory.track_fetches(cp.fetch_names, fetches, mem_dev)
-            _memory.drop_feeds(feeds, mem_dev)
-        # the fetch bracket closes AFTER the ledger writeback (see
-        # Executor.run): co-enabled telemetry's accounting is
-        # output handling, not unattributed residual
-        sp.exit()
-        device_times = None
-        if telem and return_numpy:
-            # per-device dispatch->ready latency, measured on the live
-            # global arrays BEFORE any host materialization — the
-            # straggler/imbalance signal. Only on the return_numpy path,
-            # which syncs anyway: blocking per-shard under
-            # return_numpy=False would turn an async dispatch into a
-            # full per-step device sync and distort the thing measured.
-            # This blocks on device shards, so it IS device wait — the
-            # bracket charges it there, and the later per-fetch
-            # block_until_ready returns instantly having been paid here
-            sp.enter("device")
-            device_times = _telemetry.device_step_times(
-                list(fetches) + list(new_state.values()), t_disp)
-            sp.exit()
-        if return_numpy:
-            sp.enter("device")
-            with _stepprof.device_annotation():
-                for _f in fetches:
-                    if hasattr(_f, "block_until_ready"):
-                        _f.block_until_ready()
-            sp.exit()
-            sp.enter("fetch")
-            try:
-                fetches = [self._fetch_to_numpy(f) for f in fetches]
-            except Exception as exc:
-                # allocator deaths can surface at the host read, not the
-                # dispatch — same M001 forensics as Executor._dispatch
-                if _memory.is_oom(exc) and not isinstance(
-                        exc, _memory.MemoryExhaustedError):
-                    _memory.enrich_and_raise(
-                        exc, origin="ParallelExecutor.fetch")
-                raise
-            sp.exit()
-        # span closes before telemetry's record-keeping tail (see
-        # Executor.run): per-step wall is comparable across
-        # observer configurations
-        _stepprof.finish(sp, feeds=feeds, fetches=fetches)
-        if telem:
-            _memory.drop_fetches(cp.fetch_names, mem_dev)
-        if telem or prof:
-            t1 = time.perf_counter()
-            if telem:
-                _telemetry.record_step(
-                    "parallel", t1 - t0,
-                    feed_bytes=sum(
-                        getattr(a, "nbytes", 0) for a in feeds.values()),
-                    fetch_bytes=sum(
-                        getattr(f, "nbytes", 0) for f in fetches
-                        if hasattr(f, "nbytes")),
-                    fingerprint=fingerprint,
-                    device_times=device_times)
-                if flops_avals is not None:
-                    _telemetry.register_flops_from_avals(
-                        cp, fingerprint, flops_avals)
-            if prof:
-                _profiler.record_span("parallel_executor.run", t0, t1)
-        return fetches
+    @staticmethod
+    def _device_times(fetches, new_state, t_dispatch):
+        """Per-device dispatch->ready latency: the straggler/imbalance
+        signal."""
+        return _telemetry.device_step_times(
+            list(fetches) + list(new_state.values()), t_dispatch)
+
+    def _dispatch_fields(self, mode=None):
+        return {"mesh": dict(self.mesh.shape)}
 
     def _feed_bytes_by_device(self, cp, feeds):
         """{device label: feed bytes} for one step. Global jax arrays
@@ -749,32 +580,14 @@ class ParallelExecutor(object):
     def _run_pipeline(self, fetch_list, feed, return_numpy):
         from paddle_tpu.parallel.program_pipeline import PipelinedProgram
 
-        if isinstance(feed, list):
-            feed = {
-                name: np.concatenate(
-                    [np.asarray(d[name]) for d in feed], axis=0)
-                for name in feed[0]
-            }
-        fetch_names = [
-            v.name if isinstance(v, framework.Variable) else str(v)
-            for v in fetch_list
-        ]
+        fetch_names = _fetch_names(fetch_list)
         if self._loss_name and fetch_names and fetch_names != [
                 self._loss_name]:
             raise ValueError(
                 "pipeline runs fetch only the loss (%r), got %r — params "
                 "live packed per stage; use pipeline_sync_scope() to "
                 "inspect them" % (self._loss_name, fetch_names))
-        feeds = {}
-        feed_specs = {}
-        for name, value in feed.items():
-            arr = (
-                np.asarray(value.numpy())
-                if isinstance(value, LoDTensor)
-                else np.asarray(value)
-            )
-            feeds[name] = arr
-            feed_specs[name] = (tuple(arr.shape), str(arr.dtype))
+        feeds, feed_specs = self._prepare_feeds(self._program, feed)
         sig = (program_fingerprint(self._program),
                tuple(sorted(feed_specs.items())), trace_flags_key())
         entry = self._pipeline_entry
@@ -798,7 +611,7 @@ class ParallelExecutor(object):
         pp = entry["pp"]
         params, accs, scalars = entry["state"]
         # the pipeline's executable takes the key as a value: one dispatch
-        key = step_key_value(self._step_key())
+        key = step_key_value(self._step_key(self._program, None))
         params, accs, scalars, loss = pp.jitted(
             params, accs, scalars, feeds, key)
         entry["state"] = (params, accs, scalars)
@@ -810,13 +623,6 @@ class ParallelExecutor(object):
         if return_numpy:
             return [np.reshape(np.asarray(loss), (1,))]
         return [jnp.reshape(loss, (1,))]
-
-    def _step_key(self):
-        """As ``Executor._step_key``; the base key stays on the host, the
-        mesh executable places it (replicated) with its other arguments."""
-        self._run_counter += 1
-        return step_key(self._program.random_seed or self._base_seed,
-                        self._run_counter)
 
     def pipeline_sync_scope(self):
         """Unpack the pipeline's packed params/accumulators back into their

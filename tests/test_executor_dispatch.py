@@ -5,7 +5,14 @@ a bit of what a run computes (ISSUE 25).
 
 The key stream is checked against the step function called with
 ``fold_in(PRNGKey(seed), i)`` made the old, eager way.
+
+``Executor.run``, ``run_async``, ``run_multi_step`` and
+``ParallelExecutor.run`` reach the device through one function
+(``executor._run_step``, ISSUE 29): what holds of one entry point is
+checked of all four (``ENTRIES``).
 """
+
+import time
 
 import numpy as np
 import pytest
@@ -13,10 +20,21 @@ import pytest
 import jax
 
 import paddle_tpu as fluid
+from paddle_tpu import flags
 from paddle_tpu.core import exec_cache
 from paddle_tpu.core.lowering import BlockLowerer, build_step_fn
 from paddle_tpu.executor import global_scope
+from paddle_tpu.observability import blackbox, memory, nan_provenance
+from paddle_tpu.observability import step_profiler, telemetry
 from paddle_tpu.parallel_executor import ParallelExecutor
+
+ENTRIES = ["run", "run_async", "run_multi_step", "parallel"]
+# the dispatch record's and telemetry's origin, and the black box's name
+ORIGIN = {"run": "single", "run_async": "async",
+          "run_multi_step": "multi_step", "parallel": "parallel"}
+NAME = {"run": "Executor.run", "run_async": "Executor.run_async",
+        "run_multi_step": "Executor.run_multi_step",
+        "parallel": "ParallelExecutor.run"}
 
 SEED = 1234
 W = "w_scale.w_0"   # the name create_parameter gives
@@ -193,23 +211,33 @@ class _Calls(object):
             self.n[k] = 0
 
 
-def _entry_call(entry, exe, main, out):
+def _caller(entry, main, out, exe=None, feed=None):
+    """``call()``: one more dispatch of ``main`` through ``entry``, and
+    what it fetched of ``out``."""
+    feed = feed or _feed()
+    exe = exe or fluid.Executor(fluid.CPUPlace())
     if entry == "run":
-        return exe.run(main, feed=_feed(), fetch_list=[out])[0]
+        return lambda: exe.run(main, feed=feed, fetch_list=[out])[0]
     if entry == "run_async":
-        return exe.run_async(main, feed=_feed(), fetch_list=[out]).result()[0]
-    return exe.run_multi_step(main, 2, feed=_feed(), fetch_list=[out])[0]
+        return lambda: exe.run_async(
+            main, feed=feed, fetch_list=[out]).result()[0]
+    if entry == "run_multi_step":
+        return lambda: exe.run_multi_step(
+            main, 2, feed=feed, fetch_list=[out])[0]
+    pe = ParallelExecutor(main_program=main, use_tpu=False)
+    return lambda: pe.run(fetch_list=[out.name], feed=feed)[0]
 
 
-@pytest.mark.parametrize("entry", ["run", "run_async", "run_multi_step"])
+@pytest.mark.parametrize("entry", ENTRIES)
 def test_third_run_makes_no_eager_jax_call(entry, monkeypatch):
     main, out, exe = _start()
+    call = _caller(entry, main, out, exe)
     calls = _Calls(monkeypatch)
-    _entry_call(entry, exe, main, out)   # traces the fold, at most once
-    _entry_call(entry, exe, main, out)
+    call()   # traces the fold, at most once
+    call()
     calls.reset()
     before = exec_cache.stats()
-    _entry_call(entry, exe, main, out)
+    call()
     after = exec_cache.stats()
     assert calls.n["PRNGKey"] == 0
     assert calls.n["fold_in"] == 0
@@ -217,6 +245,134 @@ def test_third_run_makes_no_eager_jax_call(entry, monkeypatch):
     assert after["gather_plan_hits"] == before["gather_plan_hits"] + 1
     assert after["gather_plan_rebuilds"] == before["gather_plan_rebuilds"]
     assert after["trace_cache_misses"] == before["trace_cache_misses"]
+
+
+# -- one core, each observer hooked once ---------------------------------------
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_each_call_leaves_one_dispatch_record_with_its_phases(entry):
+    main, out, exe = _start()
+    call = _caller(entry, main, out, exe)
+    call()
+    mark = time.time()
+    for _ in range(3):
+        call()
+    mine = [r for r in step_profiler.dispatch_records(ORIGIN[entry])
+            if r["t1"] >= mark]
+    assert len(mine) == 3
+    # the handle of run_async is made before the device is waited for
+    waited = [] if entry == "run_async" else ["device"]
+    for rec in mine:
+        assert list(rec["phases"]) == [
+            "feed", "compile", "dispatch", "fetch"] + waited + ["host"]
+        assert rec["wall_s"] >= sum(rec["phases"].values()) * 0.999
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_each_observer_records_one_event_per_call(entry, tmp_path):
+    main, out, exe = _start()
+    call = _caller(entry, main, out, exe)
+    call()
+    call()
+    telemetry.reset()
+    memory.reset()
+    telemetry.enable(True)
+    blackbox.enable(str(tmp_path / "box.json"), handlers=False)
+    try:
+        mark = time.time()
+        call()
+        steps = telemetry.step_records()
+        events = [e for e in blackbox.events()
+                  if e["kind"] == "dispatch" and e["ts"] >= mark]
+        live = memory.live_by_kind()
+    finally:
+        blackbox.disable()
+        telemetry.enable(False)
+        telemetry.reset()
+        memory.reset()
+    record, = [r for r in step_profiler.dispatch_records(ORIGIN[entry])
+               if r["t1"] >= mark]
+    step, = steps
+    assert step["executor"] == ORIGIN[entry]
+    assert step["steps"] == (2 if entry == "run_multi_step" else 1)
+    assert step["dispatch_only"] == (entry == "run_async")
+    # one clock in every mode: telemetry's wall starts where the span's
+    # does, feed and compile included, and ends after it
+    assert step["wall_s"] >= record["wall_s"]
+    assert 0.0 < step["h2d_seconds"] < step["wall_s"]
+    assert step["feed_bytes"] == _feed()["x"].nbytes
+    event, = events
+    assert event["origin"] == NAME[entry]
+    assert event["fetch_names"] == [out.name]
+    # the ledger's pairs balance: the feeds and the fetched activations
+    # have left, the state the step wrote back is booked
+    assert "feed" not in live and "activation" not in live
+    assert live.get("param", 0) > 0
+
+
+def _nan_program():
+    """x -> scale -> log -> mean: a zero fed makes op 1 (log) emit -inf
+    from finite inputs."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data("x", [4])
+        y = fluid.layers.log(fluid.layers.scale(x, scale=2.0))
+        out = fluid.layers.mean(y)
+    return main, out
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_check_nan_inf_raises_with_blame(entry):
+    main, out = _nan_program()
+    x = np.ones([8, 4], "float32")
+    x[3, 1] = 0.0
+    call = _caller(entry, main, out, feed={"x": x})
+    flags.set_flag("check_nan_inf", True)
+    try:
+        with pytest.raises(nan_provenance.NonFiniteError) as err:
+            call()
+    finally:
+        flags.set_flag("check_nan_inf", False)
+    assert "NaN/Inf detected" in str(err.value)
+    assert err.value.diagnostic.op_type == "log"
+    assert err.value.diagnostic.op_idx == 1
+    assert np.isneginf(call())   # the flag is off
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_verify_program_flag_gates_a_fresh_compile(entry):
+    from paddle_tpu.analysis import ProgramVerifyError
+
+    main = fluid.Program()
+    block = main.global_block()
+    block.create_var(name="a", shape=(8, 2), dtype="float32", is_data=True)
+    block.create_var(name="o", shape=(8, 2), dtype="float32")
+    block.append_op("relu", inputs={"X": ["missing_input"]},
+                    outputs={"Out": ["o"]}, infer_shape=False)
+    call = _caller(entry, main, block.vars["o"],
+                   feed={"a": np.zeros([8, 2], "float32")})
+    flags.set_flag("verify_program", True)
+    try:
+        with pytest.raises(ProgramVerifyError):
+            call()
+    finally:
+        flags.set_flag("verify_program", False)
+
+
+def test_second_executor_reuses_the_first_ones_multi_step_executable():
+    main, out, first = _start()
+    for _ in range(2):   # the first run's write-back may add names
+        first.run_multi_step(main, 3, feed=_feed(), fetch_list=[out])
+    before = exec_cache.stats()
+    second = fluid.Executor(fluid.CPUPlace())
+    second.run_multi_step(main, 3, feed=_feed(), fetch_list=[out])
+    after = exec_cache.stats()
+    assert after["fresh_compiles"] == before["fresh_compiles"]
+    assert after["trace_cache_misses"] == before["trace_cache_misses"]
+    # another scan length (one no other test has compiled) is another
+    second.run_multi_step(main, 5, feed=_feed(), fetch_list=[out])
+    assert (exec_cache.stats()["trace_cache_misses"]
+            == before["trace_cache_misses"] + 1)
 
 
 # -- the scope changes under a warm executable ---------------------------------
@@ -256,23 +412,28 @@ def _same_device(scope):
     return 11.0, 0
 
 
+@pytest.mark.parametrize("entry", ENTRIES)
 @pytest.mark.parametrize("change", [
     _set_numpy, _erase_and_recreate, _other_device, _same_device],
     ids=lambda f: f.__name__.strip("_"))
-def test_warm_run_sees_a_value_set_from_outside(change, monkeypatch):
+def test_warm_run_sees_a_value_set_from_outside(change, entry, monkeypatch):
     main, startup, out = _weight_program()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup)
     feed = _feed()
+    call = _caller(entry, main, out, exe, feed=feed)
     for _ in range(3):
-        got, = exe.run(main, feed=feed, fetch_list=[out])
-        np.testing.assert_array_equal(got, feed["x"] * 2.0)
+        np.testing.assert_array_equal(call(), feed["x"] * 2.0)
     scale, moves = change(global_scope())
     calls = _Calls(monkeypatch)
-    got, = exe.run(main, feed=feed, fetch_list=[out])
-    np.testing.assert_array_equal(got, feed["x"] * scale)
-    assert calls.n["device_put"] == moves   # moved once, or not at all
-    assert calls.n["PRNGKey"] == calls.n["fold_in"] == 0
+    np.testing.assert_array_equal(call(), feed["x"] * scale)
+    if entry == "parallel":
+        # a mesh spreads a device's value, and takes a host value as it
+        # is: its executable traces for the new kind of argument
+        assert calls.n["device_put"] <= 1
+    else:
+        assert calls.n["device_put"] == moves   # moved once, or not at all
+        assert calls.n["PRNGKey"] == calls.n["fold_in"] == 0
     # and the scope keeps what the caller put there
     kept = global_scope().get_value(W)
     np.testing.assert_array_equal(np.asarray(kept), np.full([8], scale))
