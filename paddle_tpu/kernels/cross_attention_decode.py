@@ -14,7 +14,7 @@ training flash kernel at ``block_q = 1``: a grid of ``S x H x T_src /
   length scalar-prefetched (``pltpu.PrefetchScalarGridSpec``): the K/V
   index maps resolve ``group_of[s]`` and the block ``j`` straight into
   the pools, so no gathered copy exists, and one grid step takes ALL
-  heads of a slot (as the paged kernel takes ``[H, page_size, dh]``).
+  heads of a slot (as the paged kernel takes a page of whole token rows).
 * The query keeps its row axis, ``[S, H, N, dh]``: N = 1 in the step
   program, the tree's node count in the tree-verify program. The kernel
   adapts on the shapes it sees; ``source_block`` picks the block of the

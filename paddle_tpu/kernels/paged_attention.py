@@ -5,9 +5,10 @@ Attention", arxiv 2604.15464): ``SlotDecodeSession``'s dense slot pool
 attends over all ``max_length`` positions for every slot regardless of
 how many tokens a slot actually holds, so decode FLOPs/HBM traffic
 scale with ``num_slots x max_length``. Here the KV cache is a PAGE
-POOL — fixed-size pages ``[num_pages, H, page_size, dh]`` plus a
-per-slot page-index table ``[S, pages_per_slot]`` and a length vector
-``[S]`` — and the decode kernel is ragged over it:
+POOL — fixed-size pages ``[num_pages, page_size, H * dh]``, a token's
+row of all heads contiguous — plus a per-slot page-index table
+``[S, pages_per_slot]`` and a length vector ``[S]`` — and the decode
+kernel is ragged over it:
 
 * Grid ``(slot, page)`` with the page table scalar-prefetched
   (``pltpu.PrefetchScalarGridSpec``): the K/V block index maps resolve
@@ -25,6 +26,16 @@ per-slot page-index table ``[S, pages_per_slot]`` and a length vector
 * Empty slots (length 0) produce exactly 0 (the flash kernel's
   fully-masked-row contract extended to decode); an unoccupied slot is
   never NaN bait.
+
+The pool is stored as whole token rows because that is the one shape
+the chip keeps, the row scatter writes and a Pallas operand reads in
+the SAME layout: ``H * dh`` fills the 128 lanes whatever the head
+width, so the compiled step holds no copy of a pool (the per-head
+``[P, H, page_size, dh]`` pool at ``dh`` 64 was stored page-minor,
+scattered head-width-minor and read row-major: six whole-pool copies a
+dispatch). The kernels keep heads on the lanes throughout: a head's
+scores are ``(k * q_row) @ E`` with ``E`` the ``[H * dh, H]`` 0/1 head
+indicator, and its weights are spread back over its lanes by ``E^T``.
 
 ``interpret=True`` runs the same kernel on CPU for tests; the composed
 XLA reference (gather pages through the table, masked softmax) is the
@@ -64,10 +75,6 @@ PAGED_KERNEL_NAME = "paged_decode_attention"
 TREE_KERNEL_NAME = "paged_tree_attention"
 
 _NEG_INF = -1e30
-# a slot whose running max never rose above this saw no visible key
-# (length 0): its output is zeroed, matching flash_attention's
-# fully-masked-row contract
-_MASKED_ROW_M = -1e29
 
 
 def pages_for(length, page_size):
@@ -75,93 +82,133 @@ def pages_for(length, page_size):
     return -(-int(length) // int(page_size))
 
 
+def _gather_slot_rows(pool, page_table, num_heads):
+    """Each slot's pages through the table as a dense
+    ``[S, pages_per_slot * page_size, H, dh]`` float32 view."""
+    S, npp = page_table.shape
+    ps, width = pool.shape[1], pool.shape[2]
+    return pool[page_table].astype(jnp.float32).reshape(
+        S, npp * ps, num_heads, width // num_heads)
+
+
 def paged_attention_reference(q, k_pool, v_pool, page_table, lengths,
                               sm_scale=None):
     """Composed XLA path: gather each slot's pages through the table
-    into a dense ``[S, H, pages_per_slot * page_size, dh]`` view, mask
+    into a dense ``[S, pages_per_slot * page_size, H, dh]`` view, mask
     positions past the slot's length, softmax, weighted sum. Empty
     slots (length 0) return 0, matching the kernel.
 
-    q: [S, H, dh]; k_pool/v_pool: [P, H, page_size, dh];
+    q: [S, H, dh]; k_pool/v_pool: [P, page_size, H * dh];
     page_table: [S, npp] int; lengths: [S] int. Returns [S, H, dh].
     """
     S, H, dh = q.shape
-    ps = k_pool.shape[2]
-    npp = page_table.shape[1]
     if sm_scale is None:
         sm_scale = dh ** -0.5
-    # [S, npp, H, ps, dh] -> [S, H, npp*ps, dh]
-    ks = jnp.transpose(k_pool[page_table], (0, 2, 1, 3, 4)).reshape(
-        S, H, npp * ps, dh)
-    vs = jnp.transpose(v_pool[page_table], (0, 2, 1, 3, 4)).reshape(
-        S, H, npp * ps, dh)
-    s = jnp.einsum("shd,shtd->sht", q.astype(jnp.float32) * sm_scale,
-                   ks.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
-    pos = jnp.arange(npp * ps)[None, None, :]
+    ks = _gather_slot_rows(k_pool, page_table, H)
+    vs = _gather_slot_rows(v_pool, page_table, H)
+    s = jnp.einsum("shd,sthd->sht", q.astype(jnp.float32) * sm_scale,
+                   ks, preferred_element_type=jnp.float32)
+    pos = jnp.arange(ks.shape[1])[None, None, :]
     valid = pos < lengths[:, None, None]
     s = jnp.where(valid, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("sht,shtd->shd", p, vs.astype(jnp.float32))
+    out = jnp.einsum("sht,sthd->shd", p, vs)
     dead = (lengths <= 0)[:, None, None]
     return jnp.where(dead, 0.0, out).astype(q.dtype)
 
 
-def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, page_size, n_pages,
-                         sm_scale):
-    """One (slot, page) grid step: absorb one resident K/V page into the
-    slot's online-softmax state (running max / sum / acc in VMEM
-    scratch, persisting across the page dimension). ``table_ref`` and
+def _head_indicator(num_heads, head_dim):
+    """``E [H * dh, H]``, ``E[j, h] = 1`` where lane ``j`` of a token's
+    row belongs to head ``h``, and its transpose."""
+    e = (jnp.arange(num_heads * head_dim)[:, None] // head_dim
+         == jnp.arange(num_heads)[None, :]).astype(jnp.float32)
+    return e, e.T
+
+
+def _head_dot(x, e):
+    """``x @ e`` for a 0/1 head indicator (or its transpose), at
+    HIGHEST: the product is exact float32 sums of float32 terms, as a
+    per-head reduction on the vector unit would be (DEFAULT would round
+    ``x`` to bfloat16 first). Rows pad to whole sublane tiles."""
+    rows = x.shape[0]
+    pad = -rows % 8
+    if pad:
+        x = jnp.concatenate(
+            [x, jnp.zeros((pad, x.shape[1]), x.dtype)], axis=0)
+    out = jnp.dot(x, e, preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST)
+    return out[:rows] if pad else out
+
+
+def _start_slot(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _absorb_page(q, k_ref, v_ref, visible, e_ref, et_ref, acc_ref, m_ref,
+                 l_ref):
+    """Absorb one resident K/V page into N query rows' online-softmax
+    state: the running max a head ``m [N, 1, H]``, the running sum and
+    acc a lane ``l``, ``acc [N, H*dh]``, in VMEM scratch persisting
+    across the page dimension. ``q`` [N, H*dh] is scaled already;
+    ``visible`` [N, ps, 1] says which of the page's rows a query row
+    sees. A row that sees no key of the page adds nothing (its
+    exp(-inf - -inf) would otherwise count every key), so a slot or node
+    with no visible key at all ends at acc 0."""
+    N, width = q.shape
+    k = k_ref[0].astype(jnp.float32)                     # [ps, H*dh]
+    v = v_ref[0].astype(jnp.float32)
+    ps = k.shape[0]
+    H = e_ref.shape[-1]
+    prod = k[None, :, :] * q[:, None, :]                 # [N, ps, H*dh]
+    sc = _head_dot(prod.reshape(N * ps, width),
+                   e_ref[...]).reshape(N, ps, H)
+    sc = jnp.where(visible, sc, _NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+    pexp = jnp.where(visible, jnp.exp(sc - m_new), 0.0)
+    w = _head_dot(pexp.reshape(N * ps, H),
+                  et_ref[...]).reshape(N, ps, width)
+    alpha = _head_dot(jnp.exp(m_prev - m_new)[:, 0, :], et_ref[...])
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=1)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(w * v[None], axis=1)
+    m_ref[...] = m_new
+
+
+def _finish_slot(o_ref, acc_ref, l_ref):
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, e_ref,
+                         et_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                         page_size, n_pages, sm_scale):
+    """One (slot, page) grid step of the decode: the slot's one query
+    row absorbs one resident page (``_absorb_page``). ``table_ref`` and
     ``len_ref`` are the scalar-prefetch operands — the page table
-    already steered the K/V index maps; the kernel only needs the
-    length for the validity test and the empty-page skip."""
+    already steered the K/V index maps; the kernel only needs the length
+    for the validity test and the empty-page skip."""
     from jax.experimental import pallas as pl
 
     s = pl.program_id(0)
     p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
+    pl.when(p == 0)(lambda: _start_slot(acc_ref, m_ref, l_ref))
     length = len_ref[s]
 
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * sm_scale      # [H, dh]
-        k = k_ref[0].astype(jnp.float32)                 # [H, ps, dh]
-        v = v_ref[0].astype(jnp.float32)
-        sc = jnp.einsum("hnd,htd->hnt", q[:, None, :], k,
-                        preferred_element_type=jnp.float32)[:, 0, :]
         pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 1)
-        sc = jnp.where(pos < length, sc, _NEG_INF)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        pexp = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(pexp, axis=-1,
-                                              keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-            "hnt,htd->hnd", pexp[:, None, :], v,
-            preferred_element_type=jnp.float32)[:, 0, :]
-        m_ref[...] = m_new
+            jnp.int32, (1, page_size, 1), 1)
+        _absorb_page(q_ref[0].astype(jnp.float32) * sm_scale, k_ref,
+                     v_ref, pos < length, e_ref, et_ref, acc_ref, m_ref,
+                     l_ref)
 
     # the ragged bound: a page past the slot's resident length runs NO
     # compute (and, with the host's last-valid-page table aliasing, no
     # fresh DMA either — the repeated index elides the copy)
     pl.when(p * page_size < length)(_compute)
-
-    @pl.when(p == n_pages - 1)
-    def _finish():
-        dead = m_ref[...] <= _MASKED_ROW_M
-        o_ref[0] = jnp.where(
-            dead, 0.0,
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+    pl.when(p == n_pages - 1)(
+        lambda: _finish_slot(o_ref, acc_ref, l_ref))
 
 
 def _paged_pallas(q, k_pool, v_pool, page_table, lengths, sm_scale,
@@ -170,36 +217,50 @@ def _paged_pallas(q, k_pool, v_pool, page_table, lengths, sm_scale,
     from jax.experimental.pallas import tpu as pltpu
 
     S, H, dh = q.shape
-    ps = k_pool.shape[2]
+    ps, width = k_pool.shape[1], k_pool.shape[2]
     npp = page_table.shape[1]
+    e, et = _head_indicator(H, dh)
     kv_spec = pl.BlockSpec(
-        (1, H, ps, dh), lambda s, p, table, lens: (table[s, p], 0, 0, 0))
+        (1, ps, width), lambda s, p, table, lens: (table[s, p], 0, 0))
+    row_spec = pl.BlockSpec(
+        (1, 1, width), lambda s, p, table, lens: (s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S, npp),
         in_specs=[
-            pl.BlockSpec((1, H, dh), lambda s, p, table, lens: (s, 0, 0)),
+            row_spec,
             kv_spec,
             kv_spec,
+            # whole in VMEM for the call: no index map a grid step
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec(
-            (1, H, dh), lambda s, p, table, lens: (s, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((H, dh), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((1, width), jnp.float32),
+            pltpu.VMEM((1, 1, H), jnp.float32),
+            pltpu.VMEM((1, width), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, page_size=ps, n_pages=npp,
             sm_scale=sm_scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, 1, width), q.dtype),
         interpret=interpret,
         name=PAGED_KERNEL_NAME,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
+      q.reshape(S, 1, width), k_pool, v_pool, e, et)
+    return out.reshape(S, H, dh)
+
+
+def _check_pool(q, k_pool):
+    if k_pool.ndim != 3 or k_pool.shape[2] != q.shape[1] * q.shape[-1]:
+        raise ValueError(
+            "a self-attention page pool is [num_pages, page_size, H * dh] "
+            "(whole token rows); got pool %s for %d heads of %d"
+            % (tuple(k_pool.shape), q.shape[1], q.shape[-1]))
 
 
 def paged_attention(q, k_pool, v_pool, page_table, lengths, sm_scale=None,
@@ -207,7 +268,7 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, sm_scale=None,
     """Ragged paged-attention decode over a block-paged KV pool.
 
     q: [S, H, dh] (one query token per slot); k_pool/v_pool:
-    [num_pages, H, page_size, dh]; page_table: [S, pages_per_slot] int
+    [num_pages, page_size, H * dh]; page_table: [S, pages_per_slot] int
     page ids into the pool; lengths: [S] int resident tokens per slot.
     Returns [S, H, dh]. Slots with length 0 return exactly 0.
 
@@ -217,6 +278,7 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, sm_scale=None,
     ``FLAGS_paged_attention=reference``. A kernel the compiler refuses
     raises ``KernelCompileError``; nothing stands in for it.
     """
+    _check_pool(q, k_pool)
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     use_pallas = force_pallas or (not force_reference and _is_tpu_target())
@@ -232,6 +294,11 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, sm_scale=None,
             exc) from exc
 
 
+def token_rows(x, dtype):
+    """``[..., H, dh]`` per-head rows as the pool's ``[..., H * dh]``."""
+    return x.reshape(x.shape[:-2] + (-1,)).astype(dtype)
+
+
 def paged_kv_write(k_pool, v_pool, k_new, v_new, page_table, positions):
     """O(page) cache write: scatter each slot's new K/V row into its
     resident page at ``positions[s]`` — page id resolved through the
@@ -243,15 +310,15 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, page_table, positions):
     the session's convention) scatter harmlessly there — an unoccupied
     slot's write can never corrupt a live slot's page.
     """
-    ps = k_pool.shape[2]
+    ps = k_pool.shape[1]
     S = k_new.shape[0]
     pos = positions.astype(jnp.int32)
     page_ids = page_table[jnp.arange(S), pos // ps]
     offsets = pos % ps
-    k_pool = k_pool.at[page_ids, :, offsets, :].set(
-        k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[page_ids, :, offsets, :].set(
-        v_new.astype(v_pool.dtype))
+    k_pool = k_pool.at[page_ids, offsets].set(
+        token_rows(k_new, k_pool.dtype))
+    v_pool = v_pool.at[page_ids, offsets].set(
+        token_rows(v_new, v_pool.dtype))
     return k_pool, v_pool
 
 
@@ -267,7 +334,7 @@ def paged_tree_attention_reference(q, k_pool, v_pool, page_table,
     (``anc`` includes the diagonal: a node sees its own just-written
     row, the decode-step contract).
 
-    q: [S, H, N, dh]; k_pool/v_pool: [P, H, page_size, dh];
+    q: [S, H, N, dh]; k_pool/v_pool: [P, page_size, H * dh];
     page_table: [S, npp] int; base_lens: [S] int (-1 marks a dead/done
     slot — no visible key, output exactly 0); anc: [S, N, N] 0/1.
     Tree rows whose storage position falls at/after ``max_length``
@@ -275,20 +342,15 @@ def paged_tree_attention_reference(q, k_pool, v_pool, page_table,
     [S, H, N, dh].
     """
     S, H, N, dh = q.shape
-    ps = k_pool.shape[2]
-    npp = page_table.shape[1]
-    L = npp * ps
     if sm_scale is None:
         sm_scale = dh ** -0.5
+    ks = _gather_slot_rows(k_pool, page_table, H)
+    vs = _gather_slot_rows(v_pool, page_table, H)
+    L = ks.shape[1]
     if max_length is None:
         max_length = L
-    ks = jnp.transpose(k_pool[page_table], (0, 2, 1, 3, 4)).reshape(
-        S, H, L, dh)
-    vs = jnp.transpose(v_pool[page_table], (0, 2, 1, 3, 4)).reshape(
-        S, H, L, dh)
-    s = jnp.einsum("shnd,shtd->shnt", q.astype(jnp.float32) * sm_scale,
-                   ks.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)      # [S,H,N,L]
+    s = jnp.einsum("shnd,sthd->shnt", q.astype(jnp.float32) * sm_scale,
+                   ks, preferred_element_type=jnp.float32)  # [S,H,N,L]
     t = jnp.arange(L)[None, :]                              # [1, L]
     base = base_lens.astype(jnp.int32)[:, None]             # [S, 1]
     committed = (t < base)                                  # [S, L]
@@ -303,75 +365,49 @@ def paged_tree_attention_reference(q, k_pool, v_pool, page_table,
     vis4 = visible[:, None, :, :]                           # [S,1,N,L]
     s = jnp.where(vis4, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("shnt,shtd->shnd", p, vs.astype(jnp.float32))
+    out = jnp.einsum("shnt,sthd->shnd", p, vs)
     dead = jnp.logical_not(jnp.any(vis4, axis=-1))[..., None]
     return jnp.where(dead, 0.0, out).astype(q.dtype)
 
 
 def _tree_decode_kernel(table_ref, blen_ref, q_ref, k_ref, v_ref,
-                        anc_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                        page_size, n_pages, n_nodes, max_len, sm_scale):
-    """One (slot, page) grid step of the tree verify: absorb one
-    resident page into N parallel online-softmax rows (one per tree
-    node). Same ragged discipline as ``_paged_decode_kernel`` — the
-    scan bound is ``base + N`` (capped at ``max_len``), pages past it
-    skip compute and (via table tail aliasing) DMA. The ancestor mask
-    is applied to in-tree storage positions with a one-hot contraction
-    (``anc @ onehot(t - base)``) instead of a gather — MXU-friendly
-    and Pallas-safe."""
+                        anc_ref, e_ref, et_ref, o_ref, acc_ref, m_ref,
+                        l_ref, *, page_size, n_pages, n_nodes, max_len,
+                        sm_scale):
+    """One (slot, page) grid step of the tree verify: N query rows (one
+    per tree node) absorb one resident page. Same ragged discipline as
+    ``_paged_decode_kernel`` — the scan bound is ``base + N`` (capped at
+    ``max_len``), pages past it skip compute and (via table tail
+    aliasing) DMA. A node sees the in-tree storage position ``base + j``
+    where ``anc[n, j]`` is set: a compare of ``t - base`` against ``j``
+    on the lanes, no gather."""
     from jax.experimental import pallas as pl
 
     s = pl.program_id(0)
     p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
+    N, ps = n_nodes, page_size
+    pl.when(p == 0)(lambda: _start_slot(acc_ref, m_ref, l_ref))
     base = blen_ref[s]
     scan_len = jnp.where(base >= 0,
                          jnp.minimum(base + n_nodes, max_len), 0)
 
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * sm_scale      # [H, N, dh]
-        k = k_ref[0].astype(jnp.float32)                 # [H, ps, dh]
-        v = v_ref[0].astype(jnp.float32)
-        sc = jnp.einsum("hnd,htd->hnt", q, k,
-                        preferred_element_type=jnp.float32)  # [H,N,ps]
-        jrow = jax.lax.broadcasted_iota(
-            jnp.int32, (n_nodes, page_size), 0)          # [N, ps] = j
-        tcol = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (n_nodes, page_size), 1)          # [N, ps] = t
-        tj = tcol - base
-        onehot = (tj == jrow).astype(jnp.float32)        # [N(j), ps]
-        anc = (anc_ref[0].astype(jnp.int32) > 0).astype(jnp.float32)
-        treevis = jnp.dot(anc, onehot,
-                          preferred_element_type=jnp.float32)  # [N(n),ps]
-        in_tree = (tj >= 0) & (tj < n_nodes) & (tcol < max_len)
-        visible = (tcol < base) | ((treevis > 0.5) & in_tree)
-        sc = jnp.where(visible[None, :, :], sc, _NEG_INF)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        pexp = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(pexp, axis=-1,
-                                              keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-            "hnt,htd->hnd", pexp, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        # page positions on the sublanes, as the scores have them
+        tcol = p * ps + jax.lax.broadcasted_iota(
+            jnp.int32, (N, ps, N), 1)                    # t
+        jlane = jax.lax.broadcasted_iota(jnp.int32, (N, ps, N), 2)
+        on_path = ((tcol - base == jlane)
+                   & (anc_ref[0].astype(jnp.int32) > 0)[:, None, :])
+        treevis = jnp.max(on_path.astype(jnp.float32), axis=-1,
+                          keepdims=True) > 0.5           # [N, ps, 1]
+        t1 = tcol[:, :, :1]
+        visible = (t1 < base) | (treevis & (t1 < max_len))
+        _absorb_page(q_ref[0].astype(jnp.float32) * sm_scale, k_ref,
+                     v_ref, visible, e_ref, et_ref, acc_ref, m_ref, l_ref)
 
     pl.when(p * page_size < scan_len)(_compute)
-
-    @pl.when(p == n_pages - 1)
-    def _finish():
-        dead = m_ref[...] <= _MASKED_ROW_M
-        o_ref[0] = jnp.where(
-            dead, 0.0,
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+    pl.when(p == n_pages - 1)(
+        lambda: _finish_slot(o_ref, acc_ref, l_ref))
 
 
 def _tree_pallas(q, k_pool, v_pool, page_table, base_lens, anc,
@@ -380,38 +416,44 @@ def _tree_pallas(q, k_pool, v_pool, page_table, base_lens, anc,
     from jax.experimental.pallas import tpu as pltpu
 
     S, H, N, dh = q.shape
-    ps = k_pool.shape[2]
+    ps, width = k_pool.shape[1], k_pool.shape[2]
     npp = page_table.shape[1]
+    e, et = _head_indicator(H, dh)
     kv_spec = pl.BlockSpec(
-        (1, H, ps, dh), lambda s, p, table, lens: (table[s, p], 0, 0, 0))
+        (1, ps, width), lambda s, p, table, lens: (table[s, p], 0, 0))
+    rows_spec = pl.BlockSpec(
+        (1, N, width), lambda s, p, table, lens: (s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S, npp),
         in_specs=[
-            pl.BlockSpec((1, H, N, dh),
-                         lambda s, p, table, lens: (s, 0, 0, 0)),
+            rows_spec,
             kv_spec,
             kv_spec,
             pl.BlockSpec((1, N, N), lambda s, p, table, lens: (s, 0, 0)),
+            # whole in VMEM for the call: no index map a grid step
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec(
-            (1, H, N, dh), lambda s, p, table, lens: (s, 0, 0, 0)),
+        out_specs=rows_spec,
         scratch_shapes=[
-            pltpu.VMEM((H, N, dh), jnp.float32),
-            pltpu.VMEM((H, N, 1), jnp.float32),
-            pltpu.VMEM((H, N, 1), jnp.float32),
+            pltpu.VMEM((N, width), jnp.float32),
+            pltpu.VMEM((N, 1, H), jnp.float32),
+            pltpu.VMEM((N, width), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             _tree_decode_kernel, page_size=ps, n_pages=npp, n_nodes=N,
             max_len=int(max_length), sm_scale=sm_scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, N, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, N, width), q.dtype),
         interpret=interpret,
         name=TREE_KERNEL_NAME,
     )(page_table.astype(jnp.int32), base_lens.astype(jnp.int32),
-      q, k_pool, v_pool, anc.astype(jnp.int32))
+      jnp.transpose(q, (0, 2, 1, 3)).reshape(S, N, width), k_pool, v_pool,
+      anc.astype(jnp.int32), e, et)
+    return jnp.transpose(out.reshape(S, N, H, dh), (0, 2, 1, 3))
 
 
 def paged_tree_attention(q, k_pool, v_pool, page_table, base_lens, anc,
@@ -424,10 +466,11 @@ def paged_tree_attention(q, k_pool, v_pool, page_table, base_lens, anc,
     Pallas on TPU targets, composed reference on CPU or under
     ``FLAGS_tree_attention=reference``; a kernel the compiler refuses
     raises ``KernelCompileError``."""
+    _check_pool(q, k_pool)
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if max_length is None:
-        max_length = page_table.shape[1] * k_pool.shape[2]
+        max_length = page_table.shape[1] * k_pool.shape[1]
     use_pallas = force_pallas or (not force_reference and _is_tpu_target())
     if not use_pallas:
         return paged_tree_attention_reference(
@@ -455,8 +498,8 @@ def paged_kv_write_block(k_pool, v_pool, k_new, v_new, page_table,
     k_new/v_new: [S, H, N, dh]; positions: [S, N]. Returns the updated
     pools.
     """
-    ps = k_pool.shape[2]
-    S, H, N, dh = k_new.shape
+    ps = k_pool.shape[1]
+    S = k_new.shape[0]
     npp = page_table.shape[1]
     pos = positions.astype(jnp.int32)
     in_range = pos < npp * ps
@@ -464,10 +507,10 @@ def paged_kv_write_block(k_pool, v_pool, k_new, v_new, page_table,
     page_ids = jnp.where(in_range,
                          page_table[jnp.arange(S)[:, None], page_idx], 0)
     offsets = jnp.where(in_range, pos % ps, 0)
-    k_rows = jnp.transpose(k_new, (0, 2, 1, 3)).astype(k_pool.dtype)
-    v_rows = jnp.transpose(v_new, (0, 2, 1, 3)).astype(v_pool.dtype)
-    k_pool = k_pool.at[page_ids, :, offsets, :].set(k_rows)
-    v_pool = v_pool.at[page_ids, :, offsets, :].set(v_rows)
+    k_rows = token_rows(jnp.transpose(k_new, (0, 2, 1, 3)), k_pool.dtype)
+    v_rows = token_rows(jnp.transpose(v_new, (0, 2, 1, 3)), v_pool.dtype)
+    k_pool = k_pool.at[page_ids, offsets].set(k_rows)
+    v_pool = v_pool.at[page_ids, offsets].set(v_rows)
     return k_pool, v_pool
 
 
@@ -485,7 +528,7 @@ def paged_kv_compact(k_pool, v_pool, page_table, base, path, accept_len):
     base: [S] int (committed rows; -1 for dead slots), path: [S, N]
     node indices, accept_len: [S] int. Returns the updated pools.
     """
-    ps = k_pool.shape[2]
+    ps = k_pool.shape[1]
     S, N = path.shape
     npp = page_table.shape[1]
     L = npp * ps
@@ -499,14 +542,14 @@ def paged_kv_compact(k_pool, v_pool, page_table, base, path, accept_len):
     sp = jnp.clip(src_pos, 0, L - 1)
     s_page = page_table[jnp.arange(S)[:, None], sp // ps]
     s_off = sp % ps
-    k_rows = k_pool[s_page, :, s_off, :]                    # [S,N,H,dh]
-    v_rows = v_pool[s_page, :, s_off, :]
+    k_rows = k_pool[s_page, s_off]                          # [S,N,H*dh]
+    v_rows = v_pool[s_page, s_off]
     dp = jnp.clip(dst_pos, 0, L - 1)
     d_page = jnp.where(active,
                        page_table[jnp.arange(S)[:, None], dp // ps], 0)
     d_off = jnp.where(active, dp % ps, 0)
-    k_pool = k_pool.at[d_page, :, d_off, :].set(k_rows)
-    v_pool = v_pool.at[d_page, :, d_off, :].set(v_rows)
+    k_pool = k_pool.at[d_page, d_off].set(k_rows)
+    v_pool = v_pool.at[d_page, d_off].set(v_rows)
     return k_pool, v_pool
 
 

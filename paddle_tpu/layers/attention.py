@@ -173,7 +173,8 @@ def paged_attention(query, k_pool, v_pool, page_table, lengths,
     """Ragged paged-attention decode (kernels/paged_attention.py).
 
     ``query`` [S, H, 1, dh] (one token per slot), ``k_pool``/``v_pool``
-    [num_pages, H, page_size, dh], ``page_table`` [S, pages_per_slot]
+    [num_pages, page_size, H * dh] (whole token rows, heads
+    contiguous), ``page_table`` [S, pages_per_slot]
     int page ids, ``lengths`` [S] (or [S, 1]) resident tokens per slot.
     Per-slot cost is bounded by the slot's OWN length — empty pages and
     unoccupied slots are skipped, so decode traffic scales with tokens
@@ -193,7 +194,8 @@ def paged_attention(query, k_pool, v_pool, page_table, lengths,
 def paged_kv_write(k_pool, v_pool, k_new, v_new, page_table, pos,
                    name=None):
     """O(page) KV-pool write: each slot's new K/V row ``[S, H, 1, dh]``
-    lands at (``page_table[s, pos // page_size]``, ``pos % page_size``).
+    lands as one ``H * dh`` row of the ``[num_pages, page_size, H * dh]``
+    pool at (``page_table[s, pos // page_size]``, ``pos % page_size``).
     Pass the pool vars as both input and output (the optimizer-style
     in-place state convention): this layer binds ``KOut``/``VOut`` back
     onto the pool vars, so the executor threads the update."""

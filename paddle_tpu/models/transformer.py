@@ -892,7 +892,9 @@ def build_paged_slot_decoder(
 ):
     """Block-paged continuous-batching decode: the slot pool's dense
     per-slot self caches (``[S, H, T, dh]``) become a PAGE POOL —
-    fixed-size KV pages ``[num_pages, H, page_size, dh]`` shared by
+    fixed-size KV pages ``[num_pages, page_size, H * dh]`` (a token's
+    row of all heads contiguous: the one layout the chip stores, the
+    row scatter writes and the kernel reads without a copy) shared by
     every slot through a per-slot page-index table — and the step
     program becomes a SELF-CONTAINED loop body (token selection,
     position advance and the next token's embedding input all live on
@@ -1027,6 +1029,7 @@ def build_paged_slot_decoder(
     ps = int(page_size)
     npp = pages_for(T, ps)  # pages per slot at full length
     P = int(num_pages) if num_pages else 1 + S * npp
+    pool_shape = [P, ps, n_head * dh]  # whole token rows a page
     G = int(num_groups) if num_groups else S
     K = int(beam_width)
     if K < 1:
@@ -1082,8 +1085,7 @@ def build_paged_slot_decoder(
                                              "float32", 0.0))
                 for kind in ("kpool", "vpool"):
                     persist("pgd_%s_%d" % (kind, i),
-                            nn.fill_constant([P, n_head, ps, dh],
-                                             "float32", 0.0))
+                            nn.fill_constant(pool_shape, "float32", 0.0))
             persist("pgd_group_of",
                     nn.fill_constant([S, 1], "int64", 0), "int64")
             persist("pgd_table",
@@ -1209,8 +1211,8 @@ def build_paged_slot_decoder(
             h = nn.elementwise_add(nn.scale(emb, scale=D ** 0.5), pe_all)
             for i in range(n_layer):
                 name = "dec_%d" % i
-                kpool = pvar("pgd_kpool_%d" % i, [P, n_head, ps, dh])
-                vpool = pvar("pgd_vpool_%d" % i, [P, n_head, ps, dh])
+                kpool = pvar("pgd_kpool_%d" % i, pool_shape)
+                vpool = pvar("pgd_vpool_%d" % i, pool_shape)
                 nx = _prenorm(h, name + "_sattn")
                 k1 = heads(nn.fc(nx, dh * n_head, num_flatten_dims=2,
                                  bias_attr=False, name=name + "_smha_k"))
@@ -1314,8 +1316,8 @@ def build_paged_slot_decoder(
             h = nn.elementwise_add(nn.scale(emb, scale=D ** 0.5), pe_row)
             for i in range(n_layer):
                 name = "dec_%d" % i
-                kpool = pvar("pgd_kpool_%d" % i, [P, n_head, ps, dh])
-                vpool = pvar("pgd_vpool_%d" % i, [P, n_head, ps, dh])
+                kpool = pvar("pgd_kpool_%d" % i, pool_shape)
+                vpool = pvar("pgd_vpool_%d" % i, pool_shape)
                 nx = _prenorm(h, name + "_sattn")
                 q = heads(nn.fc(nx, dh * n_head, num_flatten_dims=2,
                                 bias_attr=False, name=name + "_smha_q"))
@@ -1454,10 +1456,8 @@ def build_paged_slot_decoder(
                 spec_pools = []
                 for i in range(n_layer):
                     name = "dec_%d" % i
-                    kpool = pvar("pgd_kpool_%d" % i,
-                                 [P, n_head, ps, dh])
-                    vpool = pvar("pgd_vpool_%d" % i,
-                                 [P, n_head, ps, dh])
+                    kpool = pvar("pgd_kpool_%d" % i, pool_shape)
+                    vpool = pvar("pgd_vpool_%d" % i, pool_shape)
                     nx = _prenorm(h, name + "_sattn")
                     q = heads(nn.fc(nx, dh * n_head, num_flatten_dims=2,
                                     bias_attr=False,
@@ -1545,7 +1545,7 @@ def build_draft_decoder(
     decoder-only LM (no cross attention — cheapness is the point) that
     shares the target's token embedding (``trg_emb``) and position
     table (``pgd_pe_table``) and runs over the SAME paged geometry —
-    its own K/V pools ``pgd_draft_{k,v}pool_0 [P, H, ps, dh]`` indexed
+    its own K/V pools ``pgd_draft_{k,v}pool_0 [P, ps, H * dh]`` indexed
     through the target's ``pgd_table`` row per slot, so draft cache
     residency exactly tracks slot page residency with zero extra
     bookkeeping.
@@ -1584,6 +1584,7 @@ def build_draft_decoder(
     ps = int(page_size)
     npp = pages_for(T, ps)
     P = int(num_pages) if num_pages else 1 + S * npp
+    pool_shape = [P, ps, n_head * dh]
     di = int(d_inner) if d_inner else 2 * D
 
     def heads(x):
@@ -1599,8 +1600,8 @@ def build_draft_decoder(
                 out = blk.create_var(name="pgd_draft_%s_0" % kind,
                                      shape=None, dtype="float32",
                                      persistable=True)
-                nn.assign(nn.fill_constant([P, n_head, ps, dh],
-                                           "float32", 0.0), output=out)
+                nn.assign(nn.fill_constant(pool_shape, "float32", 0.0),
+                          output=out)
 
         step = fluid.Program()
         step_startup = fluid.Program()
@@ -1619,8 +1620,8 @@ def build_draft_decoder(
                             append_batch_size=False)
             ptable = pvar("pgd_table", [S, npp], "int64")
             pe_table = pvar("pgd_pe_table", [T, D])
-            kpool = pvar("pgd_draft_kpool_0", [P, n_head, ps, dh])
-            vpool = pvar("pgd_draft_vpool_0", [P, n_head, ps, dh])
+            kpool = pvar("pgd_draft_kpool_0", pool_shape)
+            vpool = pvar("pgd_draft_vpool_0", pool_shape)
             ddone = nn.elementwise_sub(
                 nn.fill_constant([S, 1], "int64", 1), dlive)
             lengths = nn.elementwise_mul(
@@ -1697,6 +1698,7 @@ def build_cow_batch_prog(num_slots, max_length, n_layer, n_head,
     ps = int(page_size)
     npp = pages_for(T, ps)
     P = int(num_pages)
+    pool_shape = [P, ps, n_head * dh]
     n = int(pairs)
     if n < 1:
         raise ValueError("build_cow_batch_prog needs pairs >= 1")
@@ -1716,10 +1718,10 @@ def build_cow_batch_prog(num_slots, max_length, n_layer, n_head,
             idxs = [nn.fill_constant([1], "int64", i) for i in range(n)]
             for i in range(n_layer):
                 kpool = blk.create_var(name="pgd_kpool_%d" % i,
-                                       shape=[P, n_head, ps, dh],
+                                       shape=pool_shape,
                                        dtype="float32", persistable=True)
                 vpool = blk.create_var(name="pgd_vpool_%d" % i,
-                                       shape=[P, n_head, ps, dh],
+                                       shape=pool_shape,
                                        dtype="float32", persistable=True)
                 for j in range(n):
                     fluid.layers.paged_copy_page(

@@ -162,7 +162,7 @@ def _lower_paged_attention(ctx, ins, attrs):
     from paddle_tpu.kernels.paged_attention import paged_attention
 
     q = ins["Q"][0]  # [S, H, 1, dh]
-    k_pool = ins["KPool"][0]  # [P, H, page_size, dh]
+    k_pool = ins["KPool"][0]  # [P, page_size, H * dh]
     v_pool = ins["VPool"][0]
     table = jnp.reshape(ins["PageTable"][0],
                         (q.shape[0], -1)).astype(jnp.int32)
@@ -211,7 +211,7 @@ def _lower_paged_tree_attention(ctx, ins, attrs):
     from paddle_tpu.kernels.paged_attention import paged_tree_attention
 
     q = ins["Q"][0]  # [S, H, N, dh]
-    k_pool = ins["KPool"][0]  # [P, H, page_size, dh]
+    k_pool = ins["KPool"][0]  # [P, page_size, H * dh]
     v_pool = ins["VPool"][0]
     S, H, N, dh = q.shape
     table = jnp.reshape(ins["PageTable"][0], (S, -1)).astype(jnp.int32)
@@ -294,7 +294,7 @@ def _lower_paged_copy_page(ctx, ins, attrs):
     private bit-identical copy before its table row repoints. Both
     pools move in one op so a COW is one fused dispatch per layer, not
     two."""
-    k_pool = ins["KPool"][0]  # [P, H, page_size, dh]
+    k_pool = ins["KPool"][0]  # [P, page_size, H * dh]
     v_pool = ins["VPool"][0]
     src = jnp.reshape(ins["Src"][0], ()).astype(jnp.int32)
     dst = jnp.reshape(ins["Dst"][0], ()).astype(jnp.int32)
@@ -325,24 +325,28 @@ def _lower_paged_kv_prefill(ctx, ins, attrs):
     prefix-cache hit already covers (below ``write_from``) and pad/tail
     positions route to the trash page (page 0), so a hit prefills ONLY
     the uncached suffix and cached page bits are never touched."""
-    k_pool = ins["KPool"][0]  # [P, H, page_size, dh]
+    from paddle_tpu.kernels.paged_attention import token_rows
+
+    k_pool = ins["KPool"][0]  # [P, page_size, H * dh]
     v_pool = ins["VPool"][0]
     k_new = ins["KNew"][0]  # [1, H, T, dh]
     v_new = ins["VNew"][0]
     row = jnp.reshape(ins["PageRow"][0], (-1,)).astype(jnp.int32)  # [npp]
     wf = jnp.reshape(ins["WriteFrom"][0], ()).astype(jnp.int32)
     ln = jnp.reshape(ins["Len"][0], ()).astype(jnp.int32)
-    ps = k_pool.shape[2]
+    ps = k_pool.shape[1]
     T = k_new.shape[2]
     p = jnp.arange(T, dtype=jnp.int32)
     live = (p >= wf) & (p < ln - 1)
     pages = jnp.where(live, row[p // ps], 0)
     offs = p % ps
-    kt = jnp.transpose(k_new[0], (1, 0, 2))  # [T, H, dh]
-    vt = jnp.transpose(v_new[0], (1, 0, 2))
+
+    def rows(x, dtype):  # [1, H, T, dh] -> the pool's [T, H * dh]
+        return token_rows(jnp.transpose(x[0], (1, 0, 2)), dtype)
+
     return {
-        "KOut": k_pool.at[pages, :, offs, :].set(kt.astype(k_pool.dtype)),
-        "VOut": v_pool.at[pages, :, offs, :].set(vt.astype(v_pool.dtype)),
+        "KOut": k_pool.at[pages, offs].set(rows(k_new, k_pool.dtype)),
+        "VOut": v_pool.at[pages, offs].set(rows(v_new, v_pool.dtype)),
     }
 
 

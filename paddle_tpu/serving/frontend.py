@@ -791,8 +791,8 @@ class ServingFrontend(object):
         Bound on queued (not yet admitted) solo generate requests;
         beyond it admissions reject with ``QueueFullError``.
     stream_poll_s : float
-        Cadence at which an idle stream handler polls its connection
-        for an in-band cancel / EOF.
+        Cadence at which a stream handler polls its connection for an
+        in-band cancel / EOF, idle or not.
     install_signal_handlers : bool
         SIGTERM/SIGINT stop the transport and CHAIN to the previously
         installed handler — install a ``DecodeSnapshotManager``'s
@@ -1030,20 +1030,20 @@ class ServingFrontend(object):
             with self._mu:
                 self._active_streams += 1
             self._decode.submit(stream)
+            poll_at = time.monotonic() + self._poll
             while True:
-                try:
-                    msg = stream.q.get(timeout=self._poll)
-                except queue.Empty:
-                    verdict = self._poll_conn(conn)
-                    if verdict == "cancel":
-                        self._decode.cancel(stream)
-                        outcome = "cancelled"
-                        yield {"ok": True, "event": "cancelled"}
-                        return
-                    if verdict == "eof":
-                        self._decode.cancel(stream)
-                        outcome = "disconnect"
-                        return
+                msg, verdict, poll_at = self._next_event(
+                    stream, conn, poll_at)
+                if verdict == "cancel":
+                    self._decode.cancel(stream)
+                    outcome = "cancelled"
+                    yield {"ok": True, "event": "cancelled"}
+                    return
+                if verdict == "eof":
+                    self._decode.cancel(stream)
+                    outcome = "disconnect"
+                    return
+                if msg is None:
                     continue
                 if not msg.get("ok", False):
                     outcome = _outcome(error_from_wire(msg))
@@ -1093,6 +1093,24 @@ class ServingFrontend(object):
                 # closes stragglers), so the ring never holds a trace
                 # with dangling open spans
                 _tracing.finish(tr, outcome=outcome)
+
+    def _next_event(self, stream, conn, poll_at):
+        """``(message, verdict, next poll time)``: the stream's next
+        message (None when none came within the cadence) and, when the
+        cadence has come round, what ``_poll_conn`` says of the
+        connection. The connection is polled every ``stream_poll_s``
+        whether or not the stream is idle: a decode round shorter than
+        the cadence keeps the queue from ever running empty, and an
+        in-band cancel read only on an empty queue then waits for the
+        stream's last token."""
+        try:
+            msg = stream.q.get(timeout=self._poll)
+        except queue.Empty:
+            msg = None
+        now = time.monotonic()
+        if msg is not None and now < poll_at:
+            return msg, None, poll_at
+        return msg, self._poll_conn(conn), now + self._poll
 
     def _poll_conn(self, conn):
         """'cancel' when the client sent an in-band cancel line, 'eof'
@@ -1197,20 +1215,20 @@ class ServingFrontend(object):
             with self._mu:
                 self._active_streams += 1
             self._decode.submit(stream)
+            poll_at = time.monotonic() + self._poll
             while True:
-                try:
-                    msg = stream.q.get(timeout=self._poll)
-                except queue.Empty:
-                    verdict = self._poll_conn(conn)
-                    if verdict == "cancel":
-                        self._decode.cancel(stream)
-                        outcome = "cancelled"
-                        yield {"ok": True, "event": "cancelled"}
-                        return
-                    if verdict == "eof":
-                        self._decode.cancel(stream)
-                        outcome = "disconnect"
-                        return
+                msg, verdict, poll_at = self._next_event(
+                    stream, conn, poll_at)
+                if verdict == "cancel":
+                    self._decode.cancel(stream)
+                    outcome = "cancelled"
+                    yield {"ok": True, "event": "cancelled"}
+                    return
+                if verdict == "eof":
+                    self._decode.cancel(stream)
+                    outcome = "disconnect"
+                    return
+                if msg is None:
                     continue
                 if not msg.get("ok", False):
                     outcome = _outcome(error_from_wire(msg))

@@ -81,6 +81,10 @@ __all__ = ["DecodeSnapshotManager", "SnapshotMismatchError",
 
 DIALECT = "decode_snapshot"
 DIALECT_VERSION = 1
+# what a ``pgd_*pool_i__live`` page holds: whole token rows
+# ``[page_size, H * dh]``. A snapshot whose meta names no layout was
+# written when a page was per-head ``[H, page_size, dh]``.
+POOL_LAYOUT = "token_rows"
 
 _HANDLED_SIGNALS = (signal.SIGTERM, signal.SIGINT)
 
@@ -254,6 +258,7 @@ class DecodeSnapshotManager(CheckpointManager):
             snap["req_%d_beam_scores" % rid] = np.asarray(res["scores"])
         meta = {
             "version": DIALECT_VERSION,
+            "pool_layout": POOL_LAYOUT,
             "config": self._config(),
             # beam slots carry their hypothesis lifecycle (done latch +
             # accumulated score) beside the position
@@ -474,6 +479,16 @@ class DecodeSnapshotManager(CheckpointManager):
         def load(name):
             return assemble_var(step_dir, vars_meta[name])
 
+        per_head = meta.get("pool_layout") != POOL_LAYOUT
+
+        def load_pages(name):
+            pages = load(name)
+            if per_head:  # [n, H, page_size, dh] -> [n, page_size, H * dh]
+                pages = np.ascontiguousarray(
+                    np.transpose(pages, (0, 2, 1, 3))).reshape(
+                        pages.shape[0], pages.shape[2], -1)
+            return pages
+
         # -- phase 1: load + validate (no session mutation) ---------------
         small = {name: load(name) for name in self._small_vars()}
         live_trg = load("live_trg")
@@ -484,7 +499,8 @@ class DecodeSnapshotManager(CheckpointManager):
             for kind in ("kpool", "vpool"):
                 if live_pages:
                     gathered["pgd_%s_%d" % (kind, i)] = (
-                        live_pages, load("pgd_%s_%d__live" % (kind, i)))
+                        live_pages,
+                        load_pages("pgd_%s_%d__live" % (kind, i)))
             for kind in ("kcross", "vcross"):
                 if live_groups:
                     gathered["pgd_%s_%d" % (kind, i)] = (
@@ -497,7 +513,7 @@ class DecodeSnapshotManager(CheckpointManager):
                     name = "pgd_draft_%s_0" % kind
                     if name + "__live" in vars_meta:
                         gathered[name] = (live_pages,
-                                          load(name + "__live"))
+                                          load_pages(name + "__live"))
             spec_dparams = {
                 pname: load("spec_dparam__" + pname)
                 for pname in (spec_meta.get("drafter") or {}).get(

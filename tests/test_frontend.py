@@ -605,6 +605,45 @@ def test_inband_cancel_reclaims_and_connection_stays_usable(trained):
     assert np.array_equal(wire[0], o[0])
 
 
+def test_inband_cancel_is_read_while_chunks_outrun_the_poll_cadence():
+    """A stream whose chunks come faster than ``stream_poll_s`` (a decode
+    round of 44 ms against the cadence of 50) never finds its queue empty:
+    the handler still polls the connection once a cadence, so the client's
+    in-band cancel is read then and not after the stream's last token."""
+    import queue as queue_mod
+    from types import SimpleNamespace
+
+    fe = ServingFrontend.__new__(ServingFrontend)
+    fe._poll = 0.05
+    ours, theirs = socket.socketpair()
+    try:
+        conn = SimpleNamespace(sock=ours, rfile=ours.makefile("rb"))
+        stream = SimpleNamespace(q=queue_mod.Queue())
+        chunk = {"ok": True, "event": "tokens", "tokens": [7]}
+        stream.q.put(chunk)
+        poll_at = time.monotonic() + fe._poll
+        # inside the cadence a waiting chunk is handed on, unpolled
+        msg, verdict, poll_at = fe._next_event(stream, conn, poll_at)
+        assert msg == chunk and verdict is None
+        theirs.sendall(b'{"method": "cancel"}\n')
+        verdicts = []
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < 2.0 and "cancel" not in verdicts:
+            stream.q.put(chunk)  # the queue never runs empty
+            msg, verdict, poll_at = fe._next_event(stream, conn, poll_at)
+            assert msg == chunk
+            verdicts.append(verdict)
+            time.sleep(0.005)
+        assert verdicts[-1] == "cancel", verdicts
+        assert time.monotonic() - t0 < 4 * fe._poll
+        # an idle stream polls as before: nothing to read, no verdict
+        msg, verdict, poll_at = fe._next_event(stream, conn, poll_at)
+        assert msg is None and verdict is None
+    finally:
+        ours.close()
+        theirs.close()
+
+
 def _oracle_after_cancel(oracle, src):
     slot = oracle.admit(src[3], SEQ)
     oracle.cancel(slot)

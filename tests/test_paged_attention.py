@@ -28,12 +28,13 @@ CFG = dict(src_vocab_size=VOCAB, trg_vocab_size=VOCAB, n_layer=1,
 
 # -- kernel ------------------------------------------------------------------
 
-def _pools(rng, S, H, dh, ps, npp, lengths):
-    """Random pools + a ragged table: page 0 reserved (trash), each
-    slot's tail aliased to its last valid page."""
+def _pools(rng, S, H, dh, ps, npp, lengths, tail="last"):
+    """Random pools of whole token rows ``[P, ps, H * dh]`` + a ragged
+    table: page 0 reserved (trash), each slot's tail aliased to its last
+    valid page (``tail="trash"``: left on page 0)."""
     P = 1 + S * npp
-    kp = rng.randn(P, H, ps, dh).astype("float32")
-    vp = rng.randn(P, H, ps, dh).astype("float32")
+    kp = rng.randn(P, ps, H * dh).astype("float32")
+    vp = rng.randn(P, ps, H * dh).astype("float32")
     table = np.zeros((S, npp), np.int32)
     nxt = 1
     for s in range(S):
@@ -41,9 +42,36 @@ def _pools(rng, S, H, dh, ps, npp, lengths):
         for p in range(n):
             table[s, p] = nxt
             nxt += 1
-        for p in range(n, npp):
-            table[s, p] = table[s, max(n - 1, 0)]
+        if tail == "last":
+            for p in range(n, npp):
+                table[s, p] = table[s, max(n - 1, 0)]
     return kp, vp, table
+
+
+def _per_head(pool, H):
+    """The same pool as the old per-head layout ``[P, H, ps, dh]``."""
+    P, ps, width = pool.shape
+    return np.ascontiguousarray(
+        pool.reshape(P, ps, H, width // H).transpose(0, 2, 1, 3))
+
+
+def _oracle_per_head(q, kp_old, vp_old, table, lengths):
+    """Plain numpy attention over per-head ``[P, H, ps, dh]`` pools: a
+    slot's keys are its pages' rows in table order, cut at its length;
+    an empty slot is 0."""
+    S, H, dh = q.shape
+    out = np.zeros((S, H, dh), "float64")
+    for s in range(S):
+        n = int(lengths[s])
+        if n == 0:
+            continue
+        for h in range(H):
+            k = np.concatenate([kp_old[p, h] for p in table[s]])[:n]
+            v = np.concatenate([vp_old[p, h] for p in table[s]])[:n]
+            sc = k.astype("float64") @ q[s, h].astype("float64") * dh ** -0.5
+            w = np.exp(sc - sc.max())
+            out[s, h] = (w / w.sum()) @ v.astype("float64")
+    return out
 
 
 def test_kernel_parity_ragged_non_multiple_lengths():
@@ -65,6 +93,78 @@ def test_kernel_parity_ragged_non_multiple_lengths():
         jnp.asarray(table), jnp.asarray(lengths), force_pallas=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=2e-6, atol=2e-6)
+
+
+# heads x head width x page size x pages a slot, and the lengths of the
+# slots: ragged and off the page grid, a full slot, one token, an empty
+# slot first / in the middle / last, a length on a page boundary
+@pytest.mark.parametrize("tail", ["last", "trash"])
+@pytest.mark.parametrize("H,dh,ps,npp,lengths", [
+    (2, 16, 4, 8, [7, 1, 32, 13, 30]),
+    (8, 64, 16, 4, [0, 17, 64, 16, 0, 33]),
+    (4, 128, 8, 3, [24, 8, 0, 9]),
+    (16, 8, 8, 2, [3, 0]),
+    (1, 32, 16, 2, [0, 31, 32]),
+], ids=["2x16", "served_8x64", "dh_128", "16_heads", "one_head"])
+def test_kernel_reference_and_per_head_oracle_agree(H, dh, ps, npp,
+                                                    lengths, tail):
+    """Three readings of one random pool: the interpret-mode kernel and
+    the composed reference on whole token rows, and a numpy oracle on the
+    SAME pool transposed to the old per-head pages. The table's tail is
+    aliased to the slot's last page (what the session does) or left on
+    the trash page; an empty slot is exactly 0 in all three."""
+    import jax.numpy as jnp
+
+    S = len(lengths)
+    lengths = np.asarray(lengths, np.int32)
+    rng = np.random.RandomState(H * 131 + dh)
+    q = rng.randn(S, H, dh).astype("float32")
+    kp, vp, table = _pools(rng, S, H, dh, ps, npp, lengths, tail=tail)
+    want = _oracle_per_head(q, _per_head(kp, H), _per_head(vp, H), table,
+                            lengths)
+    args = [jnp.asarray(x) for x in (q, kp, vp, table, lengths)]
+    ref = np.asarray(pa.paged_attention(*args, force_reference=True))
+    ker = np.asarray(pa.paged_attention(*args, force_pallas=True))
+    np.testing.assert_allclose(ref, want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(ker, want, rtol=2e-5, atol=2e-6)
+    for s in np.flatnonzero(lengths == 0):
+        assert np.abs(ref[s]).max() == 0.0 and np.abs(ker[s]).max() == 0.0
+
+
+def test_kernel_skips_pages_past_the_length():
+    """A page past the slot's length is never computed on: NaN there
+    (the trash page the table's tail sits on) changes nothing."""
+    import jax.numpy as jnp
+
+    S, H, dh, ps, npp = 2, 2, 8, 4, 3
+    lengths = np.array([5, 4], np.int32)
+    rng = np.random.RandomState(8)
+    q = rng.randn(S, H, dh).astype("float32")
+    kp, vp, table = _pools(rng, S, H, dh, ps, npp, lengths, tail="trash")
+    want = np.asarray(pa.paged_attention(
+        *[jnp.asarray(x) for x in (q, kp, vp, table, lengths)],
+        force_pallas=True))
+    kp[0] = vp[0] = np.nan
+    got = np.asarray(pa.paged_attention(
+        *[jnp.asarray(x) for x in (q, kp, vp, table, lengths)],
+        force_pallas=True))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_a_per_head_pool_is_refused_by_name():
+    """The old ``[P, H, ps, dh]`` pool does not pass for whole token
+    rows: a ValueError that says which shape a pool has."""
+    import jax.numpy as jnp
+
+    q = jnp.zeros((2, 2, 8), jnp.float32)
+    pool = jnp.zeros((5, 2, 4, 8), jnp.float32)
+    table = jnp.zeros((2, 2), jnp.int32)
+    with pytest.raises(ValueError, match="page_size, H \\* dh"):
+        pa.paged_attention(q, pool, pool, table, jnp.zeros((2,), jnp.int32))
+    with pytest.raises(ValueError, match="page_size, H \\* dh"):
+        pa.paged_tree_attention(
+            jnp.zeros((2, 2, 3, 8), jnp.float32), pool, pool, table,
+            jnp.zeros((2,), jnp.int32), jnp.ones((2, 3, 3), jnp.int32))
 
 
 def test_kernel_empty_slots_are_zero_not_nan():
@@ -111,15 +211,80 @@ def test_paged_kv_write_lands_in_page_and_trash_is_isolated():
     k2, v2 = np.asarray(k2), np.asarray(v2)
     for s, p in ((0, 5), (1, 2)):
         page, off = table[s, p // ps], p % ps
-        np.testing.assert_array_equal(k2[page, :, off, :], knew[s])
-        np.testing.assert_array_equal(v2[page, :, off, :], vnew[s])
+        np.testing.assert_array_equal(
+            k2[page, off].reshape(H, dh), knew[s])
+        np.testing.assert_array_equal(
+            v2[page, off].reshape(H, dh), vnew[s])
     # everything else bit-identical (trash page 0 excepted)
     mask = np.ones_like(kp, bool)
     mask[0] = False
     for s, p in ((0, 5), (1, 2)):
-        mask[table[s, p // ps], :, p % ps, :] = False
+        mask[table[s, p // ps], p % ps] = False
     np.testing.assert_array_equal(k2[mask], kp[mask])
     np.testing.assert_array_equal(v2[mask], vp[mask])
+
+
+# write positions of the two live slots: the first and the last row of a
+# page, the first row of a slot's next page, the last row a slot can hold
+@pytest.mark.parametrize("pos", [(0, 3), (4, 7), (3, 4), (7, 0)],
+                         ids=["0_3", "4_7", "3_4", "7_0"])
+def test_paged_kv_write_at_page_boundaries_matches_per_head(pos):
+    """A row written at a page boundary lands where the old per-head
+    write put it (``pool[page, :, off, :]`` of the transposed pool), and
+    a slot parked on the trash page writes only there."""
+    import jax.numpy as jnp
+
+    S, H, dh, ps, npp = 3, 2, 4, 4, 2
+    lengths = np.array([8, 8, 0], np.int32)
+    rng = np.random.RandomState(6)
+    kp, vp, table = _pools(rng, S, H, dh, ps, npp, lengths)
+    knew = rng.randn(S, H, dh).astype("float32")
+    vnew = rng.randn(S, H, dh).astype("float32")
+    pos = np.array(pos + (5,), np.int32)  # slot 2: any position, on trash
+    k2, v2 = pa.paged_kv_write(
+        *[jnp.asarray(x) for x in (kp, vp, knew, vnew, table, pos)])
+    want_k, want_v = _per_head(kp, H), _per_head(vp, H)
+    for s in range(S):
+        page, off = table[s, pos[s] // ps], pos[s] % ps
+        want_k[page, :, off, :] = knew[s]
+        want_v[page, :, off, :] = vnew[s]
+    assert table[2, pos[2] // ps] == 0
+    np.testing.assert_array_equal(_per_head(np.asarray(k2), H), want_k)
+    np.testing.assert_array_equal(_per_head(np.asarray(v2), H), want_v)
+
+
+def test_prefill_and_copy_page_ops_write_whole_token_rows():
+    """``paged_kv_prefill`` lands a prefix's ``[1, H, T, dh]`` rows as
+    whole token rows (positions below ``write_from`` and the pad tail on
+    the trash page), and ``paged_copy_page`` duplicates one page."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops
+
+    H, dh, ps, npp, T = 2, 4, 4, 2, 8
+    rng = np.random.RandomState(7)
+    kp, vp, _ = _pools(rng, 1, H, dh, ps, npp, [T])
+    knew = rng.randn(1, H, T, dh).astype("float32")
+    vnew = rng.randn(1, H, T, dh).astype("float32")
+    row = np.array([2, 1], np.int32)
+    out = attention_ops._lower_paged_kv_prefill(None, {
+        "KPool": [jnp.asarray(kp)], "VPool": [jnp.asarray(vp)],
+        "KNew": [jnp.asarray(knew)], "VNew": [jnp.asarray(vnew)],
+        "PageRow": [jnp.asarray(row)], "WriteFrom": [jnp.asarray([2])],
+        "Len": [jnp.asarray([7])]}, {})
+    k2, v2 = np.asarray(out["KOut"]), np.asarray(out["VOut"])
+    want_k, want_v = kp.copy(), vp.copy()
+    for t in range(2, 6):  # write_from <= t < len - 1
+        want_k[row[t // ps], t % ps] = knew[0, :, t].reshape(-1)
+        want_v[row[t // ps], t % ps] = vnew[0, :, t].reshape(-1)
+    np.testing.assert_array_equal(k2[1:], want_k[1:])
+    np.testing.assert_array_equal(v2[1:], want_v[1:])
+    out = attention_ops._lower_paged_copy_page(None, {
+        "KPool": [jnp.asarray(k2)], "VPool": [jnp.asarray(v2)],
+        "Src": [jnp.asarray([2])], "Dst": [jnp.asarray([1])]}, {})
+    k3 = np.asarray(out["KOut"])
+    np.testing.assert_array_equal(k3[1], k2[2])
+    np.testing.assert_array_equal(k3[2:], k2[2:])
+    np.testing.assert_array_equal(np.asarray(out["VOut"])[1], v2[2])
 
 
 def test_grid_accounting_scales_with_resident_pages():

@@ -301,6 +301,73 @@ def test_snapshot_restores_mid_speculation_bit_exact(trained, tmp_path,
         DecodeSnapshotManager(plain, snap).restore()
 
 
+@pytest.mark.parametrize("speculative", [
+    None, {"k": 2, "drafter": "model"}], ids=["plain", "model_drafter"])
+def test_per_head_snapshot_restores_transposed(trained, tmp_path,
+                                               monkeypatch, speculative):
+    """A snapshot written when a page was per-head ``[H, page_size, dh]``
+    (its meta names no layout) restores into today's session of whole
+    token rows: the live pages are transposed on load, the draft pools'
+    too, and the restored session decodes token for token as the one
+    that wrote it. Today's meta names its layout."""
+    import re
+
+    from paddle_tpu.serving import snapshot as snapshot_mod
+
+    src, src_len = trained["src"], trained["src_len"]
+    H = CFG["n_head"]
+
+    def sess():
+        return _paged(trained, steps=1, speculative=speculative)
+
+    victim = sess()
+    vrids = [victim.enqueue(src[i], int(src_len[i])) for i in range(5)]
+    vdone = {}
+    for _ in range(2):
+        vdone.update(victim.pump())
+    assert victim._live, "snapshot point too late"
+    capture = DecodeSnapshotManager._capture
+    seen = []
+
+    def capture_per_head(self):
+        snap, meta = capture(self)
+        assert meta.pop("pool_layout") == snapshot_mod.POOL_LAYOUT
+        for name, pages in snap.items():
+            if re.fullmatch(r"pgd_(draft_)?[kv]pool_\d+__live", name):
+                n, ps, width = pages.shape
+                snap[name] = np.ascontiguousarray(pages.reshape(
+                    n, ps, H, width // H).transpose(0, 2, 1, 3))
+                seen.append(name)
+        return snap, meta
+
+    monkeypatch.setattr(DecodeSnapshotManager, "_capture", capture_per_head)
+    snap_dir = str(tmp_path / "snap")
+    mgr = DecodeSnapshotManager(victim, snap_dir)
+    mgr.save()
+    mgr.close(save=False)
+    monkeypatch.undo()
+    assert len(seen) == (2 * CFG["n_layer"] + (2 if speculative else 0))
+
+    restored = sess()
+    mgr2 = DecodeSnapshotManager(restored, snap_dir)
+    assert mgr2.restore() is not None
+    for name in ("pgd_kpool_0", "pgd_vpool_%d" % (CFG["n_layer"] - 1)):
+        np.testing.assert_array_equal(
+            np.asarray(restored._scope.get_value(name))[
+                sorted(victim._pool._ref)],
+            np.asarray(victim._scope.get_value(name))[
+                sorted(victim._pool._ref)], err_msg=name)
+    rdone, vdone2 = dict(vdone), dict(vdone)
+    for _ in range(40):
+        vdone2.update(victim.pump())
+        rdone.update(restored.pump())
+        if len(rdone) >= len(vrids) and len(vdone2) >= len(vrids):
+            break
+    for rid in vrids:
+        np.testing.assert_array_equal(rdone[rid], vdone2[rid])
+    mgr2.close(save=False)
+
+
 def test_ckpt_inspect_crosschecks_speculative_bindings(trained,
                                                        tmp_path,
                                                        capsys):
@@ -547,8 +614,8 @@ def test_paged_attention_kernel_failure_propagates_typed(monkeypatch):
 
     rng = np.random.RandomState(5)
     q = rng.randn(2, 2, 8).astype("float32")
-    kp = rng.randn(3, 2, 4, 8).astype("float32")
-    vp = rng.randn(3, 2, 4, 8).astype("float32")
+    kp = rng.randn(3, 4, 16).astype("float32")
+    vp = rng.randn(3, 4, 16).astype("float32")
     table = np.asarray([[1, 1], [2, 2]], "int32")
     lengths = np.asarray([3, 4], "int32")
 
@@ -565,7 +632,7 @@ def test_paged_attention_kernel_failure_propagates_typed(monkeypatch):
                                force_pallas=True)
         assert calls["n"] == attempt  # nothing remembers the failure
         assert err.value.kernel == pa.PAGED_KERNEL_NAME
-        assert ((3, 2, 4, 8), "float32") in err.value.shapes
+        assert ((3, 4, 16), "float32") in err.value.shapes
         assert "pallas toolchain exploded" in str(err.value)
         assert isinstance(err.value.__cause__, RuntimeError)
     # the explicit oracle is untouched by a broken kernel

@@ -61,8 +61,8 @@ def _pools(rng, S, H, dh, ps, npp, lengths):
     """Random pools + ragged table, page 0 reserved as trash (mirrors
     test_paged_attention)."""
     P = 1 + S * npp
-    kp = rng.randn(P, H, ps, dh).astype("float32")
-    vp = rng.randn(P, H, ps, dh).astype("float32")
+    kp = rng.randn(P, ps, H * dh).astype("float32")
+    vp = rng.randn(P, ps, H * dh).astype("float32")
     table = np.zeros((S, npp), np.int32)
     nxt = 1
     for s in range(S):
@@ -128,19 +128,139 @@ def test_tree_kernel_branch_isolation():
     pg = int(np.asarray(table)[1, 0])
     kp2, vp2 = np.asarray(kp).copy(), np.asarray(vp).copy()
     for row in (1, 3):
-        kp2[pg, :, row] = 0.0
-        vp2[pg, :, row] = 0.0
+        kp2[pg, row] = 0.0
+        vp2[pg, row] = 0.0
     out2 = np.asarray(pa.paged_tree_attention_reference(
         q, jnp.asarray(kp2), jnp.asarray(vp2), table, base, anc, **kw))
     np.testing.assert_allclose(out2[1, :, 2], out[1, :, 2],
                                rtol=1e-6, atol=1e-6)
     # zeroing its ANCHOR (ancestor, row 0) does move node 2
     kp3, vp3 = np.asarray(kp).copy(), np.asarray(vp).copy()
-    kp3[pg, :, 0] = 0.0
-    vp3[pg, :, 0] = 0.0
+    kp3[pg, 0] = 0.0
+    vp3[pg, 0] = 0.0
     out3 = np.asarray(pa.paged_tree_attention_reference(
         q, jnp.asarray(kp3), jnp.asarray(vp3), table, base, anc, **kw))
     assert np.abs(out3[1, :, 2] - out[1, :, 2]).max() > 1e-4
+
+
+def _per_head(pool, H):
+    """A whole-token-row pool as the old per-head ``[P, H, ps, dh]``."""
+    P, ps, width = pool.shape
+    return np.ascontiguousarray(
+        pool.reshape(P, ps, H, width // H).transpose(0, 2, 1, 3))
+
+
+def _tree_oracle_per_head(q, kp_old, vp_old, table, base, anc, max_length):
+    """Plain numpy tree verify over per-head pools: node ``n`` of a slot
+    sees its committed rows and the in-budget tree rows on its own root
+    path; a node that sees nothing is 0."""
+    S, H, N, dh = q.shape
+    out = np.zeros((S, H, N, dh), "float64")
+    for s in range(S):
+        b = int(base[s])
+        if b < 0:
+            continue
+        for h in range(H):
+            k = np.concatenate([kp_old[p, h] for p in table[s]])
+            v = np.concatenate([vp_old[p, h] for p in table[s]])
+            for n in range(N):
+                rows = list(range(b)) + [
+                    b + j for j in range(N)
+                    if anc[s, n, j] and b + j < max_length]
+                if not rows:
+                    continue
+                sc = (k[rows].astype("float64")
+                      @ q[s, h, n].astype("float64")) * dh ** -0.5
+                w = np.exp(sc - sc.max())
+                out[s, h, n] = (w / w.sum()) @ v[rows].astype("float64")
+    return out
+
+
+@pytest.mark.parametrize("H,dh,ps,npp,N,base", [
+    (2, 16, 4, 8, 4, [7, 0, 25, 30, -1]),
+    (8, 64, 16, 3, 5, [0, 15, 16, 44, -1, 47]),
+    (4, 128, 8, 2, 8, [8, -1, 3]),
+    (16, 8, 8, 2, 3, [13, 15]),
+], ids=["2x16", "served_8x64", "dh_128", "16_heads"])
+def test_tree_kernel_reference_and_per_head_oracle_agree(H, dh, ps, npp, N,
+                                                         base):
+    """The interpret-mode tree kernel and the composed reference on
+    whole token rows against a numpy oracle on the same pool transposed
+    to per-head pages: bases off the page grid and on a page boundary, a
+    tree that straddles ``max_length``, a dead slot (exactly 0)."""
+    import jax.numpy as jnp
+
+    S = len(base)
+    base = np.asarray(base, np.int32)
+    L = npp * ps
+    rng = np.random.RandomState(N * 17 + H)
+    q = rng.randn(S, H, N, dh).astype("float32")
+    kp, vp, table = _pools(rng, S, H, dh, ps, npp,
+                           np.minimum(np.maximum(base, 0) + N, L))
+    parents = [-1] + [int(rng.randint(0, j)) for j in range(1, N)]
+    anc = np.stack([tree_from_parents(parents) if s % 2 else
+                    chain_tree(N - 1)[1] for s in range(S)]).astype("int64")
+    want = _tree_oracle_per_head(q, _per_head(kp, H), _per_head(vp, H),
+                                 table, base, anc, L)
+    args = [jnp.asarray(x) for x in (q, kp, vp, table, base, anc)]
+    ref = np.asarray(pa.paged_tree_attention(
+        *args, max_length=L, force_reference=True))
+    ker = np.asarray(pa.paged_tree_attention(
+        *args, max_length=L, force_pallas=True))
+    np.testing.assert_allclose(ref, want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(ker, want, rtol=2e-5, atol=2e-6)
+    for s in np.flatnonzero(base < 0):
+        assert np.abs(ref[s]).max() == 0.0 and np.abs(ker[s]).max() == 0.0
+
+
+@pytest.mark.parametrize("pos", [0, 3, 5, 13], ids=lambda p: "pos_%d" % p)
+def test_tree_write_and_compact_match_per_head(pos):
+    """``paged_kv_write_block`` lands N rows a slot where the per-head
+    write put them (a block that crosses a page boundary, one that runs
+    past the table's coverage onto the trash page), and
+    ``paged_kv_compact`` moves the accepted path's rows to their
+    canonical positions reading the pre-compaction pool."""
+    import jax.numpy as jnp
+
+    S, H, dh, ps, npp, N = 2, 2, 4, 4, 4, 4
+    L = npp * ps
+    rng = np.random.RandomState(pos)
+    kp, vp, table = _pools(rng, S, H, dh, ps, npp, [L, L])
+    knew = rng.randn(S, H, N, dh).astype("float32")
+    vnew = rng.randn(S, H, N, dh).astype("float32")
+    base = np.array([pos, 1], np.int32)
+    positions = base[:, None] + np.arange(N)[None, :]
+    k2, v2 = pa.paged_kv_write_block(
+        *[jnp.asarray(x) for x in (kp, vp, knew, vnew, table, positions)])
+    want_k, want_v = _per_head(kp, H), _per_head(vp, H)
+    for s in range(S):
+        for i in range(N):
+            t = positions[s, i]
+            page, off = (table[s, t // ps], t % ps) if t < L else (0, 0)
+            want_k[page, :, off, :] = knew[s, :, i]
+            want_v[page, :, off, :] = vnew[s, :, i]
+    np.testing.assert_array_equal(_per_head(np.asarray(k2), H)[1:],
+                                  want_k[1:])
+    np.testing.assert_array_equal(_per_head(np.asarray(v2), H)[1:],
+                                  want_v[1:])
+    # accept 3 tokens a slot along the path anchor -> node 2 -> node 3
+    path = np.array([[0, 2, 3, 3], [0, 2, 3, 3]], np.int32)
+    k3, v3 = pa.paged_kv_compact(
+        k2, v2, jnp.asarray(table), jnp.asarray(base), jnp.asarray(path),
+        jnp.asarray([3, 3], np.int32))
+    want_k3, want_v3 = want_k.copy(), want_v.copy()
+    for s in range(S):
+        for j in (1, 2):
+            src, dst = base[s] + path[s, j], base[s] + j
+            if src >= L or dst >= L:
+                continue
+            sp, dp = table[s, src // ps], table[s, dst // ps]
+            want_k3[dp, :, dst % ps, :] = want_k[sp, :, src % ps, :]
+            want_v3[dp, :, dst % ps, :] = want_v[sp, :, src % ps, :]
+    np.testing.assert_array_equal(_per_head(np.asarray(k3), H)[1:],
+                                  want_k3[1:])
+    np.testing.assert_array_equal(_per_head(np.asarray(v3), H)[1:],
+                                  want_v3[1:])
 
 
 def test_tree_kernel_failure_propagates_typed(monkeypatch):

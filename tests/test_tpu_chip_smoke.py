@@ -23,9 +23,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 import chip_smoke  # noqa: E402
 
+# seq 64 at 2 tokens a dispatch: the first stream decodes for 32 rounds,
+# long enough on a loaded host for the late ones' first tokens to fall
+# inside it (at seq 16 the serve rehearsal lost that race one run in six)
 TINY = dict(chip_smoke.FULL, n_layer=1, n_head=2, d_model=32, d_inner=64,
-            vocab=64, seq=16, batch=4, train_steps=3, num_slots=2,
-            page_size=4, decode_steps=2, src_lens=(16, 3, 9), late_after=1)
+            vocab=64, seq=64, batch=4, train_steps=3, num_slots=2,
+            page_size=16, decode_steps=2, src_lens=(64, 3, 9), late_after=1)
 
 
 def test_train_phase_rehearsal():

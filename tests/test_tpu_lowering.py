@@ -220,7 +220,7 @@ _SERVE = dict(S=8, H=8, dh=64, T=256)
 def test_paged_decode_lowers_for_tpu(v5e, page_size, dtype):
     S, H, dh, T = (_SERVE[k] for k in ("S", "H", "dh", "T"))
     npp = pa.pages_for(T, page_size)
-    pool = ((1 + S * npp, H, page_size, dh), dtype)
+    pool = ((1 + S * npp, page_size, H * dh), dtype)
     text = _compile_v5e(
         v5e,
         lambda q, k, v, t, n: pa.paged_attention(
@@ -238,7 +238,7 @@ def test_tree_attention_lowers_for_tpu(v5e, n_nodes, dtype):
     S, H, dh, T = (_SERVE[k] for k in ("S", "H", "dh", "T"))
     page_size = 16
     npp = pa.pages_for(T, page_size)
-    pool = ((1 + S * npp, H, page_size, dh), dtype)
+    pool = ((1 + S * npp, page_size, H * dh), dtype)
     text = _compile_v5e(
         v5e,
         lambda q, k, v, t, b, anc: pa.paged_tree_attention(
@@ -246,6 +246,92 @@ def test_tree_attention_lowers_for_tpu(v5e, n_nodes, dtype):
         ((S, H, n_nodes, dh), dtype), pool, pool, ((S, npp), jnp.int32),
         ((S,), jnp.int32), ((S, n_nodes, n_nodes), jnp.int32))
     assert pa.TREE_KERNEL_NAME in text
+
+
+# the paged step as the session runs it at the served shapes (perfbench
+# transformer_base: 4097 pages of 16, 8 heads of 64, 256 slots of 256
+# positions, float32, 4 tokens a dispatch), then one thing changed a case
+_STEP = dict(H=8, dh=64, ps=16, dtype=F32)
+_STEP_CASES = {
+    "served": {}, "16_heads": dict(H=16), "dh_128": dict(dh=128),
+    "bf16": dict(dtype=BF16), "pages_of_128": dict(ps=128),
+}
+
+
+@pytest.mark.parametrize("n_nodes", [None, 5], ids=["step", "tree_5"])
+@pytest.mark.parametrize("case", list(_STEP_CASES))
+def test_paged_step_copies_no_pool(v5e, case, n_nodes):
+    """Write + attention inside the multi-step scan, pools donated: the
+    compiled program holds no ``copy``, ``transpose`` or gather of a
+    pool-sized array, its temporaries are under 1/8 of one pool, and the
+    pool keeps one layout with no padded lane from parameter to Mosaic
+    operand. The per-head ``[P, H, page_size, dh]`` pool failed all
+    three at ``dh`` 64: stored page-minor, scattered head-width-minor,
+    read row-major, 1 + 4 + 1 copies a pool and dispatch and 1.6 GB of
+    temporaries a layer."""
+    import re
+
+    cfg = dict(_STEP, **_STEP_CASES[case])
+    H, dh, ps, dtype = (cfg[k] for k in ("H", "dh", "ps", "dtype"))
+    S, T, steps = 256, 256, 4
+    npp = pa.pages_for(T, ps)
+    P = 1 + 4096 * 16 // ps
+    pool_shape = (P, ps, H * dh)
+    new_shape = (S, H, dh) if n_nodes is None else (S, H, n_nodes, dh)
+
+    def step(kp, vp, q, kn, vn, table, pos):
+        def body(carry, _):
+            kp, vp, pos, acc = carry
+            if n_nodes is None:
+                kp, vp = pa.paged_kv_write(kp, vp, kn, vn, table, pos)
+                out = pa.paged_attention(q, kp, vp, table, pos + 1)
+            else:
+                kp, vp = pa.paged_kv_write_block(
+                    kp, vp, kn, vn, table,
+                    pos[:, None] + jnp.arange(n_nodes)[None, :])
+                out = pa.paged_tree_attention(
+                    q, kp, vp, table, pos,
+                    jnp.ones((S, n_nodes, n_nodes), jnp.int32),
+                    max_length=T)
+            return (kp, vp, pos + 1, acc + out), None
+
+        with _tpu_target():
+            carry, _ = jax.lax.scan(
+                body, (kp, vp, pos, jnp.zeros_like(q)), None, length=steps)
+        return carry[0], carry[1], carry[3]
+
+    sharding = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+            for shape, dt in (
+                (pool_shape, dtype), (pool_shape, dtype), (new_shape, dtype),
+                (new_shape, dtype), (new_shape, dtype),
+                ((S, npp), jnp.int32), ((S,), jnp.int32))]
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert (pa.PAGED_KERNEL_NAME if n_nodes is None
+            else pa.TREE_KERNEL_NAME) in text
+    pool_elems = P * ps * H * dh
+    moved = []
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+            r"(copy|transpose|gather|copy-start)\(", line)
+        if m and m.group(1):
+            elems = 1
+            for d in m.group(1).split(","):
+                elems *= int(d)
+            if elems >= pool_elems:
+                moved.append(line.strip()[:160])
+    assert not moved, "the program moves a whole pool:\n" + "\n".join(moved)
+    pool_bytes = pool_elems * jnp.dtype(dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+    # one layout of the pool, row-major, the whole row on the lanes
+    dims = "%d,%d,%d" % pool_shape
+    layouts = set(re.findall(
+        r"\[%s\]\{([\d,]+)" % dims, text))
+    assert layouts == {"2,1,0"}, layouts
+    assert not re.search(r"\[%d,%d,%d,%d\]" % (P, H, ps, dh), text)
 
 
 # the decode step's cross attention at the served shapes (perfbench

@@ -138,11 +138,76 @@ def _bench_flash(fluid, shapes, steps, warmup, window=0):
     return rows
 
 
+def _bench_paged_decode(shapes, calls, steps, warmup):
+    """The paged decode kernel alone against the composed gather +
+    softmax, at the served pool (pages of whole token rows) with some of
+    the slots live: ``calls`` dependent calls inside one jit (a decode
+    dispatch makes tokens x layers of them), so the time a call is the
+    kernel's own and not a dispatch's. A live slot holds a uniform share
+    of a target drawn as the serving cells draw theirs (lognormal source,
+    median 24, sigma 0.6, x 0.9-1.3); the other slots are empty and sit
+    on the trash page."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.kernels import paged_attention as pa
+
+    rows = []
+    for S, live, H, dh, ps, T in shapes:
+        npp = pa.pages_for(T, ps)
+        rng = np.random.RandomState(live)
+        target = np.clip(
+            np.exp(rng.normal(np.log(24.0), 0.6, S))
+            * rng.uniform(0.9, 1.3, S), 4, T)
+        lengths = np.ceil(target * rng.uniform(0.0, 1.0, S)).astype("int32")
+        lengths[rng.permutation(S)[live:]] = 0
+        table = np.zeros((S, npp), "int32")
+        nxt = 1
+        for slot in range(S):
+            n = pa.pages_for(lengths[slot], ps)
+            table[slot, :n] = np.arange(nxt, nxt + n)
+            table[slot, n:] = table[slot, max(n - 1, 0)]
+            nxt += n
+        P = 1 + S * npp
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
+        kp = jax.random.normal(k1, (P, ps, H * dh), jnp.float32)
+        vp = jax.random.normal(k2, (P, ps, H * dh), jnp.float32)
+        q = jax.random.normal(k3, (S, H, dh), jnp.float32)
+        table, lens = jnp.asarray(table), jnp.asarray(lengths)
+        times = {}
+        for impl in ("reference", "pallas"):
+            def run(q, kp, vp, impl=impl):
+                def body(q, _):
+                    out = pa.paged_attention(
+                        q, kp, vp, table, lens,
+                        force_reference=impl == "reference",
+                        force_pallas=impl == "pallas")
+                    return q + 1e-3 * out, None
+
+                return jax.lax.scan(body, q, None, length=calls)[0]
+
+            fn = jax.jit(run)
+            times[impl] = _time_steps(lambda: fn(q, kp, vp),
+                                      steps, warmup) / calls
+        row = {"kernel": pa.PAGED_KERNEL_NAME,
+               "shape": [S, live, H, dh, ps, T],
+               "resident_pages": int(sum(pa.pages_for(n, ps)
+                                         for n in lengths)),
+               "xla_ms": round(times["reference"] * 1e3, 4),
+               "pallas_ms": round(times["pallas"] * 1e3, 4),
+               "speedup": round(times["reference"] / times["pallas"], 3)}
+        print(json.dumps(row))
+        rows.append(row)
+    return rows
+
+
 def _on_tpu():
     return os.environ.get("BENCH_PLATFORM") != "cpu"
 
 
-_FAMILIES = ("dynamic_lstm", "dynamic_gru", "flash_attention")
+_FAMILIES = ("dynamic_lstm", "dynamic_gru", "flash_attention",
+             "paged_decode")
 
 
 def _orchestrate(args):
@@ -196,7 +261,7 @@ def main():
     ap.add_argument("--quick", action="store_true",
                     help="tiny shapes + few steps (CPU smoke)")
     ap.add_argument("--family", choices=_FAMILIES,
-                    help="internal: run ONE family in this process")
+                    help="run ONE family, in this process")
     args = ap.parse_args()
 
     if args.family is None:
@@ -219,10 +284,16 @@ def main():
         steps, warmup = 3, 1
         rnn_shapes = [(4, 16, 32)]
         fa_shapes = [(1, 2, 128, 32)]
+        paged_shapes, paged_calls = [(8, 3, 2, 16, 8, 32)], 2
     else:
         steps, warmup = 20, 5
         rnn_shapes = [(32, 128, 256), (64, 256, 512), (16, 512, 1024)]
         fa_shapes = [(8, 8, 1024, 64), (4, 8, 2048, 64), (2, 8, 4096, 128)]
+        # perfbench transformer_base: 256 slots of 256 positions, 8 heads
+        # of 64, pages of 16; ~32 slots live in the steady cell, all 256
+        # above the knee; 24 calls a dispatch (4 tokens x 6 layers)
+        paged_shapes = [(256, 32, 8, 64, 16, 256), (256, 256, 8, 64, 16, 256)]
+        paged_calls = 24
 
     # child mode: exactly one family, crash loudly (the parent records
     # the traceback from stderr and keeps the other families)
@@ -232,6 +303,8 @@ def main():
     elif args.family == "dynamic_gru":
         _bench_rnn(fluid, "dynamic_gru", "use_pallas_gru", rnn_shapes,
                    steps, warmup)
+    elif args.family == "paged_decode":
+        _bench_paged_decode(paged_shapes, paged_calls, steps, warmup)
     else:
         _bench_flash(fluid, fa_shapes, steps, warmup)
         # sliding-window leg: same longest shape, window = seq/8 — the
