@@ -144,20 +144,25 @@ def random_parameters(desc, seed=0, dtype="float32"):
     return out
 
 
+def load_named(scope, named, shapes=None):
+    """Put ``named`` ({name: array}) into ``scope``; with ``shapes``
+    ({name: (shape, dtype)}) every parameter must be there with its
+    shape. Any decoder builder's ``load_parameters``."""
+    for name, (shape, _dt) in (shapes or {}).items():
+        if name not in named:
+            raise KeyError("the checkpoint has no parameter %r" % name)
+        if tuple(named[name].shape) != shape:
+            raise ValueError("%s: the model needs %s, the checkpoint has %s"
+                             % (name, shape, tuple(named[name].shape)))
+    for name, value in named.items():
+        scope.var(name).set(value)
+
+
 def load_parameters(scope, named, desc=None, dtype=None):
     """Put a checkpoint's arrays into ``scope`` under the programs' names.
     With ``desc`` every parameter must be there with its shape."""
-    if desc is not None:
-        for name, (shape, dt) in parameter_shapes(
-                desc, dtype or "bfloat16").items():
-            if name not in named:
-                raise KeyError("the checkpoint has no parameter %r" % name)
-            if tuple(named[name].shape) != shape:
-                raise ValueError("%s: the model needs %s, the checkpoint "
-                                 "has %s" % (name, shape,
-                                             tuple(named[name].shape)))
-    for name, value in named.items():
-        scope.var(name).set(value)
+    load_named(scope, named, desc and parameter_shapes(desc,
+                                                       dtype or "bfloat16"))
 
 
 def build_latent_moe_decoder(desc, num_slots, max_positions, page_size,
@@ -383,5 +388,10 @@ def build_latent_moe_decoder(desc, num_slots, max_positions, page_size,
             "buckets": buckets,
             "prompts_per_dispatch": per_dispatch,
             "prefill_token_budget": int(prefill_token_budget),
-            "moe_layers": moe_layers, "dtype": dtype},
+            "moe_layers": moe_layers, "dtype": dtype,
+            # what a slot owns: pages of latent rows, nothing of fixed size
+            "state": {"page_pools": collections.OrderedDict(
+                ("lmd_pool_%d" % i, {"shape": (P, ps, d["Wp"]),
+                                     "dtype": dtype})
+                for i in range(d["L"])), "slot_arrays": {}}},
     }

@@ -32,3 +32,4 @@ from paddle_tpu.ops import quantize_ops  # noqa: F401
 from paddle_tpu.ops import fused_ops  # noqa: F401
 from paddle_tpu.ops import moe_ops  # noqa: F401
 from paddle_tpu.ops import decoder_ops  # noqa: F401
+from paddle_tpu.ops import ssm_ops  # noqa: F401

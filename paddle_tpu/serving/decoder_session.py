@@ -1,6 +1,21 @@
 """DecoderOnlySession: continuous-batching decode of a decoder-only model
-with latent (MLA) attention and routed experts
-(``models/latent_moe_decoder.py``) over a paged pool of latent rows.
+over the state its builder declares: page pools that grow with a slot's
+sequence, fixed-size arrays indexed by the slot itself, or both.
+
+The model is chosen from its description (``builder_for``): a dict with
+``mamba_d_state`` is the hybrid state-space decoder
+(``models/hybrid_ssm_decoder.py``: recurrent state and a convolution
+window a slot a state-space layer, K/V page pools for its few attention
+layers), one with ``kv_lora_rank`` the latent-attention, routed-expert
+decoder (``models/latent_moe_decoder.py``: one pool of latent rows a
+layer). The session knows no model: the queue, the buckets, batched
+admission, the reservation and growth of pages, results and the trace
+hooks are one code path, and a builder's ``geometry["state"]`` names its
+``page_pools`` and ``slot_arrays``. Fixed-size state costs the host
+nothing: a prefill installs it for the slots it admits (after each
+prompt's last real token, whatever its bucket was padded to; a reused slot
+starts from its own prefill), and the live mask fed with every decode
+dispatch keeps a dead slot's rows as they are.
 
 A sibling of ``generation.SlotDecodeSession`` that the frontend's decode
 worker drives in the same way (``enqueue``, ``admit_pending``, ``step``,
@@ -13,13 +28,15 @@ src_len=)``. What differs is the model:
   the prompt's token ids (the first ``src_len`` of them). ``admit_pending``
   gathers the head of the queue, as many requests as slots are free, and
   prefills them SEVERAL A DISPATCH: one dispatch per length bucket and
-  ``prompts_per_dispatch(bucket)`` prompts (a prompt pays for reading
-  every expert's weights; a dispatch of one would pay it per prompt). The
-  prefill writes the prompt's latent rows into the slot's pages and
-  samples the first token.
-* **One row pool a layer.** ``PagePool`` accounts the pages (refcount 1
-  each: nothing is shared here); a slot's worst case (prompt + new tokens)
-  is reserved at admission, pages are taken as the sequence grows. The
+  ``prompts_per_dispatch(bucket)`` prompts (a dispatch reads every
+  weight of the model, every expert's too; a dispatch of one would pay
+  that per prompt). The prefill writes the prompt's rows into the slot's
+  pages, installs the slot's fixed-size state and samples the first token.
+* **One page table for every pool.** ``PagePool`` accounts the pages
+  (refcount 1 each: nothing is shared here) whatever the builder keeps in
+  them (one pool of latent rows a layer, or a K and a V pool an attention
+  layer); a slot's worst case (prompt + new tokens) is reserved at
+  admission, pages are taken as the sequence grows. The
   page table and the live mask are the HOST's and are fed with every
   decode dispatch, so growing a slot, finishing it or cancelling it costs
   no dispatch: ``cancel`` is bookkeeping.
@@ -35,10 +52,14 @@ Tracing (``observability/tracing.py``): under the worker's round,
 ``admit`` > ``prefill`` > ``prefill.dispatch`` per bucket dispatch and
 ``step`` > ``step.dispatch``; the round counts ``prefill_prompts``,
 ``prefill_tokens``, ``prefill_dispatches``, ``prefill_bucket_<T>``,
-``pages_in_use`` and ``expert_max_over_mean`` (the most loaded expert's
-tokens over the mean, over the dispatch's steps and expert layers, from
-the counts the expert op returns WITH the step's tokens: no dispatch and
-no device sync of their own).
+``prefill_pad_tokens`` (bucket rows the dispatch walks for nothing: its
+``prompts x bucket`` less the prompts' own tokens), ``pages_in_use``,
+``state_slots_live`` (with a model that has per-slot arrays: the slots
+whose rows the decode dispatch had to update) and, with routed experts,
+``expert_max_over_mean`` (the most loaded expert's tokens over the mean,
+over the dispatch's steps and expert layers, from the counts the expert
+op returns WITH the step's tokens: no dispatch and no device sync of
+their own).
 """
 
 import collections
@@ -58,14 +79,36 @@ from paddle_tpu.serving.generation import (
 from paddle_tpu.serving.kv_pool import NoFreePageError, PagePool
 from paddle_tpu.serving.server import ServingError
 
-__all__ = ["DecoderOnlySession"]
+__all__ = ["DecoderOnlySession", "builder_for"]
+
+
+def builder_for(desc):
+    """The function that builds ``desc``'s serving programs, chosen by the
+    description's own keys."""
+    if "mamba_d_state" in desc:
+        from paddle_tpu.models.hybrid_ssm_decoder import (
+            build_hybrid_ssm_decoder,
+        )
+
+        return build_hybrid_ssm_decoder
+    if "kv_lora_rank" in desc:
+        from paddle_tpu.models.latent_moe_decoder import (
+            build_latent_moe_decoder,
+        )
+
+        return build_latent_moe_decoder
+    raise ServingError(
+        "DecoderOnlySession knows no builder for this description (keys "
+        "%s): it serves a hybrid state-space decoder (mamba_d_state) or a "
+        "latent-attention decoder (kv_lora_rank)" % sorted(desc))
 
 
 class DecoderOnlySession(object):
     """Parameters
     ----------
     exe : Executor (or a stand-in with ``run``/``run_multi_step``).
-    desc : dict of the model's config keys (``decoder_dims``).
+    desc : dict of the model's config keys; ``builder_for`` chooses the
+        builder from them.
     num_slots, max_prompt, max_new_tokens : the pool: a slot holds
         ``max_prompt + max_new_tokens`` positions.
     page_size : rows a page; every prefill bucket is a multiple of it.
@@ -84,9 +127,6 @@ class DecoderOnlySession(object):
                  prefill_token_budget=2048, num_pages=None, sampler=None,
                  scope=None, dtype="bfloat16", probe_rows=0):
         from paddle_tpu.executor import global_scope
-        from paddle_tpu.models.latent_moe_decoder import (
-            build_latent_moe_decoder,
-        )
 
         self._exe = exe
         self._scope = scope or global_scope()
@@ -102,7 +142,7 @@ class DecoderOnlySession(object):
                 prefill_buckets.append(t)
                 t *= 2
             prefill_buckets.append(t)
-        built = build_latent_moe_decoder(
+        built = builder_for(desc)(
             desc, self._S, positions, self._ps, prefill_buckets,
             num_pages=num_pages,
             prefill_token_budget=prefill_token_budget, sampler=sampler,
@@ -118,6 +158,7 @@ class DecoderOnlySession(object):
         if self._P - 1 < self._npp:
             raise ValueError("num_pages=%d cannot hold one full sequence "
                              "(%d pages)" % (self._P, self._npp))
+        self._slot_state = bool(geo["state"]["slot_arrays"])
         self._prefill_progs = built["prefill"]
         self._step_prog = built["step"]
         self._fetch = built["fetches"]
@@ -339,6 +380,8 @@ class DecoderOnlySession(object):
                 if _tracing.ENABLED:
                     _tracing.round_count("prefill_prompts", len(reqs))
                     _tracing.round_count("prefill_tokens", int(lens.sum()))
+                    _tracing.round_count("prefill_pad_tokens",
+                                         B * T - int(lens.sum()))
                     _tracing.round_count("prefill_dispatches", 1)
                     _tracing.round_count("prefill_bucket_%d" % T, 1)
                 with _tracing.span(".dispatch"):
@@ -444,7 +487,7 @@ class DecoderOnlySession(object):
             rows += st["len"] + st["n"]
         self.last_step = (len(self._live), rows)
         fetch = [self._fetch["token"]]
-        if self._fetch["expert_tokens"]:
+        if self._fetch.get("expert_tokens"):
             fetch.append(self._fetch["expert_tokens"])
         feed = {"page_table": self._table, "live": self._live_mask}
         if len(self.probe_slots):
@@ -474,6 +517,8 @@ class DecoderOnlySession(object):
     def _count(self, expert_tokens):
         """The round's counters, from what came back with the tokens."""
         counters = {"pages_in_use": self._pool.allocated_count}
+        if self._slot_state:
+            counters["state_slots_live"] = self.last_step[0]
         if expert_tokens is not None:
             c = np.asarray(expert_tokens, "float64")    # [K, layers, E]
             mean = c.mean(axis=-1)

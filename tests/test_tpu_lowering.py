@@ -405,3 +405,74 @@ def test_grouped_matmul_lowers_for_tpu_at_the_served_widths(v5e, rows):
             v5e, lambda a, b, g: gm.grouped_matmul(a, b, g, force_pallas=True),
             ((rows, k), BF16), ((64, k, n), BF16), ((64,), jnp.int32))
         assert gm.GROUPED_KERNEL_NAME in text
+
+
+# the hybrid state-space decoder's serving cell (perfbench jamba2_3b): 256
+# slots, d_inner 5120, d_state 16, 20 query heads on one 128-wide K/V row
+_SSM = dict(S=256, d=5120, n=16)
+
+
+@pytest.mark.parametrize("B,T", [(2, 1024), (16, 128)],
+                         ids=["longest_bucket", "shortest_bucket"])
+def test_ssm_prefill_kernels_lower_for_tpu_at_the_served_widths(v5e, B, T):
+    from paddle_tpu.kernels import selective_scan as ss
+
+    d, n = _SSM["d"], _SSM["n"]
+    text = _compile_v5e(
+        v5e, lambda x, w, b: ss.causal_conv(x, w, b, force_pallas=True),
+        ((B, T, d), BF16), ((4, d), BF16), ((d,), BF16))
+    assert ss.CONV_KERNEL_NAME in text
+    text = _compile_v5e(
+        v5e, lambda x, dt, b, c, a, skip, lens: ss.prefill_scan(
+            x, dt, b, c, a, skip, lens, force_pallas=True),
+        ((B, T, d), BF16), ((B, T, d), F32), ((B, n, T), F32),
+        ((B, n, T), F32), ((n, d), F32), ((d,), F32), ((B,), jnp.int32))
+    assert ss.SCAN_KERNEL_NAME in text
+
+
+def test_ssm_one_token_kernels_update_the_state_in_place(v5e):
+    """The decode step's two state-space kernels at the served sizes,
+    state and window donated: Mosaic takes both, the compiled program
+    holds no copy of either array and its temporaries are a small
+    fraction of the 84 MB state of one layer."""
+    import re
+
+    from paddle_tpu.kernels import selective_scan as ss
+
+    S, d, n = _SSM["S"], _SSM["d"], _SSM["n"]
+
+    def step(state, window, x, dt, b, c, a, skip, w, bias, live):
+        with _tpu_target():
+            xc, window = ss.conv_step(window, x, w, bias, live)
+            y, state = ss.state_update(state, xc, dt, b, c, a, skip, live)
+        return state, window, y
+
+    sharding = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+            for shape, dt in (
+                ((S, n, d), F32), ((3, S, d), BF16), ((S, d), BF16),
+                ((S, d), F32), ((S, n), F32), ((S, n), F32), ((n, d), F32),
+                ((d,), F32), ((4, d), BF16), ((d,), BF16), ((S,), jnp.int32))]
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert ss.UPDATE_KERNEL_NAME in text and ss.CONV_STEP_KERNEL_NAME in text
+    moved = [line.strip()[:160] for line in text.splitlines() if re.match(
+        r"\s*(?:ROOT )?%%\S+ = \w+\[(%d,%d,%d|3,%d,%d)\]\S* "
+        r"(copy|transpose|gather|copy-start)\(" % (S, n, d, S, d), line)]
+    assert not moved, "the step moves a whole state array:\n" + "\n".join(moved)
+    assert compiled.memory_analysis().temp_size_in_bytes < S * n * d * 4 // 8
+    assert set(re.findall(r"\[%d,%d,%d\]\{([\d,]+)" % (S, n, d), text)) \
+        == {"2,1,0"}
+
+
+def test_gqa_decode_lowers_for_tpu_at_the_served_widths(v5e):
+    from paddle_tpu.kernels import gqa_paged_attention as gq
+
+    S, H, dh, ps, npp = 256, 20, 128, 128, 12
+    pool = ((1 + S * npp, ps, dh), BF16)
+    text = _compile_v5e(
+        v5e, lambda q, k, v, t, n: gq.gqa_paged_attention(
+            q, k, v, t, n, force_pallas=True),
+        ((S, H, dh), BF16), pool, pool, ((S, npp), jnp.int32),
+        ((S,), jnp.int32))
+    assert gq.GQA_KERNEL_NAME in text

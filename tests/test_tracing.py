@@ -378,8 +378,13 @@ def served(trained):
                 was_live = bool(stream.live)
                 worker.cancel(stream)
             deadline = time.monotonic() + 60
+            # the slot is free as soon as the cancel ran; its round's record
+            # (with the ``cancel`` span) is written when the worker's pass
+            # ENDS, a moment later on a loaded machine
             while time.monotonic() < deadline and not (
-                    sess.free_slots == S and sess.pool_conserved):
+                    sess.free_slots == S and sess.pool_conserved
+                    and any(sp["name"] == "cancel"
+                            for r in tracing.rounds() for sp in r["spans"])):
                 time.sleep(0.01)
             cancelled = tracing.finish(tr, outcome="cancelled")
         out = {"rounds": tracing.rounds(), "traces": tracing.completed(),
