@@ -810,9 +810,14 @@ def gather(input, index):
     return out
 
 
-def scatter(input, index, updates, name=None, overwrite=True):
+def scatter(input, index, updates, name=None, overwrite=True, out=None):
+    """Rows of ``updates`` written (or added) into ``input`` at ``index``
+    along axis 0; a row whose index lies past the end is dropped. Pass
+    ``out=input`` bound to a persistable var for the in-place
+    state-update form (``dynamic_update_slice``'s convention)."""
     helper = LayerHelper("scatter", name=name)
-    out = helper.create_variable_for_type_inference(input.dtype)
+    if out is None:
+        out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op(
         type="scatter",
         inputs={"X": [input], "Ids": [index], "Updates": [updates]},

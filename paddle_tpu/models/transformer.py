@@ -872,6 +872,109 @@ def build_slot_decoder(
     return init, admit, step, tok.name
 
 
+def _slot_state_feeds(npp, beam, rows=1):
+    """The feeds admit/join share for a member's registration, ``rows``
+    members a call."""
+    nn = fluid.layers
+    slot = nn.data("slot_idx", shape=[rows], dtype="int64",
+                   append_batch_size=False)
+    gidx = nn.data("group_idx", shape=[rows], dtype="int64",
+                   append_batch_size=False)
+    page_row = nn.data("page_row", shape=[npp], dtype="int64")
+    start_tok = nn.data("start_tok", shape=[1], dtype="int64")
+    start_pos = nn.data("start_pos", shape=[1], dtype="int64")
+    if not beam:
+        return slot, gidx, page_row, start_tok, start_pos
+    # the lane's accumulated log-prob seed: 0 for hypothesis 0,
+    # -1e9 for the rest (first-step duplicate suppression)
+    start_score = nn.data("start_score", shape=[1], dtype="float32")
+    return slot, gidx, page_row, start_tok, start_pos, start_score
+
+
+def _write_rows(blk, name, shape, value, index, rows, dtype="float32"):
+    """Write ``value``'s ``rows`` leading rows into the persistable
+    ``name`` at ``index`` along axis 0, in place: one dynamic-update-slice
+    for one row, one row scatter for several (an index past the end is
+    DROPPED, which is how a batch's rows of padding write nothing)."""
+    nn = fluid.layers
+    p = blk.create_var(name=name, shape=shape, dtype=dtype,
+                       persistable=True)
+    if rows == 1:
+        nn.dynamic_update_slice(p, value, index, axis=0, out=p)
+    else:
+        nn.scatter(p, index, value, out=p)
+
+
+def _register_member(blk, S, npp, feeds, rows=1):
+    """Install ``rows`` slots' group id, table row and loop state."""
+    nn = fluid.layers
+    slot, gidx, page_row, start_tok, start_pos = feeds[:5]
+
+    def srow(name, value, dtype="int64"):
+        _write_rows(blk, name, [S, npp] if name == "pgd_table" else [S, 1],
+                    value, slot, rows, dtype)
+
+    srow("pgd_group_of", nn.reshape(gidx, shape=[rows, 1]))
+    srow("pgd_table", page_row)
+    srow("pgd_tok", start_tok)
+    srow("pgd_pos", start_pos)
+    srow("pgd_done", nn.fill_constant([rows, 1], "int64", 0))
+    if len(feeds) > 5:
+        srow("pgd_score", feeds[5], "float32")
+
+
+def _build_admit_prog(rows, S, T, D, G, npp, n_layer, n_head, d_inner,
+                      src_vocab_size, beam=False):
+    """The paged decoder's admission program for ``rows`` sources a
+    call: ONE encoder forward over ``[rows, T]`` (each row padded to
+    ``T`` and masked by its own length, so a row's arithmetic does not
+    depend on its batch mates), each layer's cross K/V and the source
+    mask written into the rows' groups, the rows' slots registered.
+    ``rows=1`` is the program ``build_paged_slot_decoder`` has always
+    returned (one dynamic-update-slice a write); ``rows > 1`` scatters,
+    and a row whose ``group_idx`` / ``slot_idx`` lie past the pools' ends
+    is padding that writes nothing. Built under the caller's
+    ``unique_name`` scope."""
+    nn = fluid.layers
+    dh = D // n_head
+    admit = fluid.Program()
+    with fluid.program_guard(admit, fluid.Program()):
+        blk = admit.global_block()
+        src = nn.data("src_word", shape=[T], dtype="int64")
+        src_len = nn.data("src_len", shape=[1], dtype="int64")
+        member_feeds = _slot_state_feeds(npp, beam, rows)
+        gidx = member_feeds[1]
+        src_mask = nn.sequence_mask(src_len, maxlen=T,
+                                    dtype="float32")  # [rows, T]
+        emb = nn.embedding(
+            input=src, size=[src_vocab_size, D],
+            param_attr=fluid.ParamAttr(name="src_emb"))
+        enc = nn.add_position_encoding(nn.scale(emb, scale=D ** 0.5))
+        for i in range(n_layer):
+            enc = encoder_layer(enc, src_mask, n_head, D, d_inner,
+                                0.0, True, "enc_%d" % i)
+        enc = _prenorm(enc, "enc_final")
+
+        def heads(x):
+            return nn.transpose(
+                nn.reshape(x, shape=[0, 0, n_head, dh]), perm=[0, 2, 1, 3])
+
+        _write_rows(blk, "pgd_src_mask", [G, T], src_mask, gidx, rows)
+        for i in range(n_layer):
+            kc = heads(nn.fc(enc, dh * n_head, num_flatten_dims=2,
+                             bias_attr=False,
+                             name="dec_%d_cmha_k" % i))
+            vc = heads(nn.fc(enc, dh * n_head, num_flatten_dims=2,
+                             bias_attr=False,
+                             name="dec_%d_cmha_v" % i))
+            _write_rows(blk, "pgd_kcross_%d" % i, [G, n_head, T, dh], kc,
+                        gidx, rows)
+            _write_rows(blk, "pgd_vcross_%d" % i, [G, n_head, T, dh], vc,
+                        gidx, rows)
+        _register_member(blk, S, npp, member_feeds, rows)
+    return admit
+
+
 def build_paged_slot_decoder(
     num_slots,
     src_vocab_size=1000,
@@ -934,7 +1037,9 @@ def build_paged_slot_decoder(
       (tok=start_tok, pos=start_pos, done=0). The self pages are NOT
       zeroed — every position a slot attends over was written by that
       slot (or its fork parent) first, so stale page bits are never
-      read.
+      read. Several queued sources at once go through
+      :func:`build_admit_batch_prog`, the same program with a rung's
+      rows a feed (the session builds one per rung of its ladder).
     * ``join_prog`` (per extra group member; feeds ``slot_idx``,
       ``group_idx``, ``page_row``, ``start_tok``, ``start_pos``):
       registers another slot onto an EXISTING group — no encoder
@@ -1100,83 +1205,15 @@ def build_paged_slot_decoder(
                 persist("pgd_score",
                         nn.fill_constant([S, 1], "float32", 0.0))
 
-        def slot_state_feeds():
-            """The feeds admit/join share for one member's registration."""
-            slot = nn.data("slot_idx", shape=[1], dtype="int64",
-                           append_batch_size=False)
-            gidx = nn.data("group_idx", shape=[1], dtype="int64",
-                           append_batch_size=False)
-            page_row = nn.data("page_row", shape=[npp], dtype="int64")
-            start_tok = nn.data("start_tok", shape=[1], dtype="int64")
-            start_pos = nn.data("start_pos", shape=[1], dtype="int64")
-            if not beam:
-                return slot, gidx, page_row, start_tok, start_pos
-            # the lane's accumulated log-prob seed: 0 for hypothesis 0,
-            # -1e9 for the rest (first-step duplicate suppression)
-            start_score = nn.data("start_score", shape=[1],
-                                  dtype="float32")
-            return (slot, gidx, page_row, start_tok, start_pos,
-                    start_score)
-
-        def register_member(blk, slot, gidx, page_row, start_tok,
-                            start_pos, start_score=None):
-            """Install one slot's group id, table row and loop state."""
-            def srow(name, value, dtype="int64"):
-                p = blk.create_var(name=name,
-                                   shape=[S, npp] if name == "pgd_table"
-                                   else [S, 1],
-                                   dtype=dtype, persistable=True)
-                nn.dynamic_update_slice(p, value, slot, axis=0, out=p)
-
-            srow("pgd_group_of", nn.reshape(gidx, shape=[1, 1]))
-            srow("pgd_table", page_row)
-            srow("pgd_tok", start_tok)
-            srow("pgd_pos", start_pos)
-            srow("pgd_done", nn.fill_constant([1, 1], "int64", 0))
-            if start_score is not None:
-                srow("pgd_score", start_score, "float32")
-
-        admit = fluid.Program()
-        admit_startup = fluid.Program()
-        with fluid.program_guard(admit, admit_startup):
-            blk = admit.global_block()
-            src = nn.data("src_word", shape=[T], dtype="int64")
-            src_len = nn.data("src_len", shape=[1], dtype="int64")
-            member_feeds = slot_state_feeds()
-            gidx = member_feeds[1]
-            src_mask = nn.sequence_mask(src_len, maxlen=T,
-                                        dtype="float32")  # [1, T]
-            emb = nn.embedding(
-                input=src, size=[src_vocab_size, D],
-                param_attr=fluid.ParamAttr(name="src_emb"))
-            enc = nn.add_position_encoding(nn.scale(emb, scale=D ** 0.5))
-            for i in range(n_layer):
-                enc = encoder_layer(enc, src_mask, n_head, D, d_inner,
-                                    0.0, True, "enc_%d" % i)
-            enc = _prenorm(enc, "enc_final")
-
-            def grow(name, shape, value, dtype="float32"):
-                p = blk.create_var(name=name, shape=shape, dtype=dtype,
-                                   persistable=True)
-                nn.dynamic_update_slice(p, value, gidx, axis=0, out=p)
-
-            grow("pgd_src_mask", [G, T], src_mask)
-            for i in range(n_layer):
-                kc = heads(nn.fc(enc, dh * n_head, num_flatten_dims=2,
-                                 bias_attr=False,
-                                 name="dec_%d_cmha_k" % i))
-                vc = heads(nn.fc(enc, dh * n_head, num_flatten_dims=2,
-                                 bias_attr=False,
-                                 name="dec_%d_cmha_v" % i))
-                grow("pgd_kcross_%d" % i, [G, n_head, T, dh], kc)
-                grow("pgd_vcross_%d" % i, [G, n_head, T, dh], vc)
-            register_member(blk, *member_feeds)
+        admit = _build_admit_prog(
+            1, S, T, D, G, npp, n_layer, n_head, d_inner, src_vocab_size,
+            beam)
 
         join = fluid.Program()
         join_startup = fluid.Program()
         with fluid.program_guard(join, join_startup):
             blk = join.global_block()
-            register_member(blk, *slot_state_feeds())
+            _register_member(blk, S, npp, _slot_state_feeds(npp, beam))
 
         prefill = fluid.Program()
         prefill_startup = fluid.Program()
@@ -1735,6 +1772,41 @@ def build_cow_batch_prog(num_slots, max_length, n_layer, n_head,
                     t, nn.gather(page_rows, idxs[j]),
                     nn.gather(slot_idxs, idxs[j]), axis=0, out=t)
     return prog
+
+
+def build_admit_batch_prog(rows, num_slots, src_vocab_size=1000,
+                           max_length=64, n_layer=2, n_head=4, d_model=128,
+                           d_inner=512, page_size=8, num_groups=None):
+    """One admission dispatch for ``rows`` queued sources (the sibling of
+    :func:`build_cow_batch_prog`; ``SlotDecodeSession.admit_pending``
+    batches the head of its queue through it): the paged decoder's
+    ``admit_prog`` with ``rows`` rows a feed — ``src_word [rows, T]``,
+    ``src_len [rows, 1]``, ``slot_idx`` / ``group_idx [rows]``,
+    ``page_row [rows, npp]``, ``start_tok`` / ``start_pos [rows, 1]`` —
+    one encoder forward, each layer's cross K/V and the source mask
+    scattered into the rows' groups, the rows' slots registered. A row of
+    padding carries ``group_idx = num_groups`` and ``slot_idx =
+    num_slots``: past the end, so its writes are dropped and an
+    all-padding call leaves every ``pgd_`` array as it was. ``rows`` is a
+    rung of the session's ladder (rung 1 is ``admit_prog`` itself). Built
+    under a fresh ``unique_name`` scope, so parameters bind by the
+    training build's names and equal geometry gives an equal
+    fingerprint."""
+    from paddle_tpu import unique_name
+
+    from paddle_tpu.kernels.paged_attention import pages_for
+
+    rows = int(rows)
+    if rows < 2:
+        raise ValueError(
+            "build_admit_batch_prog needs rows >= 2 (one row is "
+            "build_paged_slot_decoder's admit_prog)")
+    S, T = int(num_slots), int(max_length)
+    G = int(num_groups) if num_groups else S
+    with unique_name.guard({}):
+        return _build_admit_prog(
+            rows, S, T, int(d_model), G, pages_for(T, int(page_size)),
+            n_layer, n_head, d_inner, src_vocab_size)
 
 
 def save_compiled_generator(dirname, batch_size, src_vocab_size,

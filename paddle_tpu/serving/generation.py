@@ -89,6 +89,19 @@ __all__ = ["SlotDecodeSession", "Sampler", "NoFreeSlotError",
            "NoFreePageError", "NoFreeGroupError"]
 
 
+# rows of the batched admission executables: ``admit_pending`` pads the
+# head run of its queue up to the smallest rung that holds it and splits
+# a run above the top one (rung 1 is the builder's own ``admit_prog``; a
+# session of fewer slots than the top rung tops its ladder at its slots).
+# Two rungs, not more: a rung costs the served session 4-6 s of set-up
+# (its program built, traced and lowered). The padding of the top rung
+# costs 4-5 ms of device time in the way of the batch's first tokens,
+# two one-row dispatches' worth: a run that would fill less than
+# 1/_ADMIT_MIN_FILL of its rung goes one request at a time through rung 1
+_ADMIT_RUNGS = (1, 32)
+_ADMIT_MIN_FILL = 8
+
+
 class NoFreeSlotError(ServingError):
     """admit() with every slot occupied — the generation-side admission
     reject; retry after a step() frees slots."""
@@ -397,6 +410,31 @@ class SlotDecodeSession(object):
                     "slot_idxs": np.zeros(rung, "int64"),
                     "page_rows": np.zeros((rung, self._npp), "int64"),
                 }, [])
+            # batched admission (admit_pending): one encoder dispatch for
+            # the head run of the queue, its row count a rung of a short
+            # ladder. Every rung is built and warmed HERE by an
+            # all-padding call (rows whose slot and group index lie past
+            # the pools' ends: the scatter drops them, no ``pgd_`` array
+            # changes) — a server that enqueues one request at a time
+            # while it warms up would otherwise compile the larger rungs
+            # under its first burst. Beam sessions admit by lane
+            # (admit_beam) and keep no ladder.
+            self._admit_progs = {1: self._admit_prog}
+            self.admit_dispatches = 0  # encoder dispatches run (tests)
+            self.admit_rows = 0        # sources they encoded
+            top = min(_ADMIT_RUNGS[-1], self._S)
+            self._admit_rungs = () if self._beam_width > 1 else tuple(
+                [r for r in _ADMIT_RUNGS if r < top] + [top])
+            for rung in self._admit_rungs[1:]:
+                self._admit_progs[rung] = \
+                    transformer.build_admit_batch_prog(
+                        rung, self._S, max_length=self._T,
+                        d_model=self._D, page_size=self._ps,
+                        num_groups=self._G,
+                        **{k: v for k, v in decoder_cfg.items()
+                           if k != "trg_vocab_size"})
+                self._run(self._admit_progs[rung],
+                          self._admit_feed((), rung), [])
             # beam bookkeeping (beam_width > 1): lanes of K aligned
             # slots; per-step parent permutations mirrored here
             self._beam_live = {}      # lane -> {"slots": [...]}
@@ -460,6 +498,7 @@ class SlotDecodeSession(object):
                 num_slots, max_length=max_length, d_model=d_model,
                 eos_id=eos_id, sampler=sampler, **decoder_cfg)
             self._run(self._init_prog, {}, [])
+            self._admit_rungs = ()  # the dense layout admits one by one
         self._free = list(range(self._S - 1, -1, -1))
         self._live = {}  # slot -> {"trg": [T] int64, "pos": int}
         # session-level request queue: generate() drains it, snapshot
@@ -526,15 +565,20 @@ class SlotDecodeSession(object):
         window a preemption snapshot must NOT land inside."""
         return self._dispatch_depth > 0
 
-    def _health_load(self):
+    def _health_load(self, taking=0):
         """Load fraction the degradation monitor keys on: page
         occupancy (reservations over the leak-shrunk capacity) and slot
-        occupancy, whichever is tighter."""
-        slot_load = len(self._live) / float(self._S)
+        occupancy, whichever is tighter. ``taking`` counts admissions
+        the caller has decided on and not made yet (a batch's earlier
+        members), so a batch's k-th request sees the load sequential
+        admission would have shown it."""
+        slot_load = (len(self._live) + taking) / float(self._S)
         if not self._paged:
             return slot_load
         cap = max(1, self._P - 1 - self._leaked_pages)
-        return max(slot_load, self._reserved_pages / float(cap))
+        reserved = (self._reserved_pages
+                    + taking * self._pages_for(self._T, self._ps))
+        return max(slot_load, reserved / float(cap))
 
     def _on_health_transition(self, frm, to):
         from paddle_tpu.serving.degradation import BROWNOUT, HEALTHY
@@ -545,7 +589,7 @@ class SlotDecodeSession(object):
             # prefix cache for capacity
             self.clear_prefix_cache()
 
-    def _gate_admission(self, n):
+    def _gate_admission(self, n, taking=0):
         """Degradation gate, BEFORE any slot/page/queue mutation (a
         degraded reject is never a partial admission) and OUTSIDE the
         classified-retry wrap (a shed session must answer the caller
@@ -555,7 +599,7 @@ class SlotDecodeSession(object):
             return
         from paddle_tpu.serving.degradation import BROWNOUT, SHED
 
-        state = self._monitor.observe(self._health_load())
+        state = self._monitor.observe(self._health_load(taking))
         if state == SHED:
             raise self._monitor.reject("admission (draining in-flight)")
         if state == BROWNOUT and n > 1:
@@ -1054,6 +1098,7 @@ class SlotDecodeSession(object):
                 # under classified retry, re-admit bit-identically
                 _chaos.fault("serve.admit")
             self._run(self._admit_prog, feed, [])
+            self._count_admit(1)
             self._note_cross(slot0, gid, length)
             write_from = len(cached) * self._ps
             if write_from:
@@ -1149,7 +1194,9 @@ class SlotDecodeSession(object):
                     self._leaked_pages += len(set(pages))
                     self._leaked_page_ids.update(pages)
                 else:
-                    for pg in pages:
+                    # last acquired first: the pool's free list is a
+                    # stack, and a retry must pop the same pages
+                    for pg in reversed(pages):
                         self._pool.deref(pg)
         # restore the free stack exactly (pop order == re-pop order, so
         # a retried admission lands in the same slots => same PRNG
@@ -1840,9 +1887,17 @@ class SlotDecodeSession(object):
 
     def admit_pending(self):
         """The admission half of :meth:`pump`: admit queued requests in
-        order while capacity allows (a pool/group reservation reject —
+        order while capacity allows (a pool/group reservation reject --
         or a degradation reject, when the monitor is armed — defers the
         request back to the FRONT; admission order is the service
+        contract). On a paged session the head RUN of plain requests (no
+        forced prefix) is admitted by ONE batched encoder dispatch
+        (:meth:`_admit_run`), into the slots, groups and pages
+        one-at-a-time admission would have given them; a request with a
+        forced prefix ends the run and goes through :meth:`admit`. An
+        admission dispatch that fails past the retry budget raises: its
+        requests are rolled back and lost to the caller, the queue
+        behind them is untouched (``DecoderOnlySession.admit_pending``'s
         contract). Returns ``{slot: request_id}`` for the requests
         admitted THIS call — what a streaming front end needs to map
         slots back to their wire streams before the next step
@@ -1850,11 +1905,152 @@ class SlotDecodeSession(object):
         admitted = {}
         while self._pending and self._free:
             with _tracing.span("admit"):
-                slot = self._admit_next()
-            if slot is None:
+                if self._admit_rungs and not self._pending[0]["prefix"]:
+                    got = self._admit_run()
+                else:
+                    slot = self._admit_next()
+                    got = {} if slot is None else {slot: self._owner[slot]}
+            if not got:
                 break
-            admitted[slot] = self._owner[slot]
+            admitted.update(got)
         return admitted
+
+    def _plain_run(self):
+        """How many requests at the head of the queue ONE dispatch
+        admits: plain ones, as many as free slots, free groups and the
+        page reservation allow and the top rung holds; one alone where
+        they would leave their rung nearly empty (``_ADMIT_MIN_FILL``);
+        each let through the degradation gate as sequential admission
+        would have been (its load counts the members before it). 0 when
+        the head itself has to wait."""
+        from paddle_tpu.serving.degradation import DegradedError
+
+        worst = self._pages_for(self._T, self._ps)
+        capacity = self._P - 1 - self._leaked_pages
+        room = min(len(self._free), len(self._free_groups),
+                   (capacity - self._reserved_pages) // worst,
+                   self._admit_rungs[-1])
+        run = 0
+        for req in self._pending:
+            if run >= room or req["prefix"]:
+                break
+            run += 1
+        if run and run * _ADMIT_MIN_FILL < self._rung_of(run):
+            run = 1
+        for n in range(run):
+            try:
+                self._gate_admission(1, taking=n)
+            except DegradedError:
+                return n
+        return run
+
+    def _rung_of(self, rows):
+        return next(r for r in self._admit_rungs if r >= rows)
+
+    def _admit_feed(self, members, rung):
+        """The admission program's feed for ``members`` (``[(request,
+        slot, group)]``) padded to ``rung`` rows. A row of padding
+        encodes one token of id 0 and carries a slot and a group index
+        past the pools' ends, so the scatter drops every write of it."""
+        pad = rung - len(members)
+
+        def col(vals, fill):
+            return np.asarray(list(vals) + [fill] * pad, dtype="int64")
+
+        return {
+            "src_word": np.concatenate(
+                [m[0]["src"] for m in members]
+                + [np.zeros((pad, self._T), "int64")], axis=0),
+            "src_len": col((m[0]["len"] for m in members), 1).reshape(
+                rung, 1),
+            "slot_idx": col((m[1] for m in members), self._S),
+            "group_idx": col((m[2] for m in members), self._G),
+            "page_row": np.concatenate(
+                [self._page_row(self._slot_pages[m[1]]) for m in members]
+                + [np.zeros((pad, self._npp), "int64")], axis=0),
+            "start_tok": np.full((rung, 1), self._bos, "int64"),
+            "start_pos": np.zeros((rung, 1), "int64"),
+        }
+
+    def _count_admit(self, rows, pad=0):
+        """One encoder dispatch admitted ``rows`` sources beside ``pad``
+        rows of padding."""
+        self.admit_dispatches += 1
+        self.admit_rows += rows
+        if _tracing.ENABLED:
+            _tracing.round_count("admit_dispatches", 1)
+            _tracing.round_count("admit_rows", rows)
+            _tracing.round_count("admit_pad_rows", pad)
+
+    def _admit_run(self):
+        """Admit the head run of plain requests through ONE batched
+        dispatch; ``{slot: request_id}``, empty when the head has to
+        wait (no group, no page reservation, or the monitor rejects it:
+        the queue is left as it was)."""
+        # pop -> dispatch -> owner-record of the whole batch is ONE
+        # dispatch window: a quiesce-point snapshot sees each request in
+        # _pending or in _owner, never in neither
+        self._begin_op()
+        try:
+            n = self._plain_run()
+            if not n:
+                return {}
+            reqs = [self._pending.popleft() for _ in range(n)]
+            t_admit = time.time() if self._trace_ids else 0.0
+            # classified retry around the whole batch attempt: a failed
+            # attempt rolls every member back, so the retried one lands
+            # in the SAME slots, groups and pages
+            slots = _retry.call(lambda: self._admit_run_attempt(reqs),
+                                origin="serve.admit")
+            got = {}
+            for req, slot in zip(reqs, slots):
+                self._owner[slot] = got[slot] = req["id"]
+                if req["id"] in self._trace_ids:
+                    self._trace_admitted(req, slot, t_admit)
+            return got
+        finally:
+            self._end_op()
+
+    def _admit_run_attempt(self, reqs):
+        """Slots, groups and pages for ``reqs`` taken in queue order —
+        request by request what ``_admit_group_attempt`` takes for a solo
+        admission, so every id is the one sequential admission gives —
+        then one dispatch of the smallest rung that holds them."""
+        worst = self._pages_for(self._T, self._ps)
+        cover = min(self._steps, self._T)  # the first dispatch's writes
+        rung = self._rung_of(len(reqs))
+        members = []
+        try:
+            for req in reqs:
+                self._reserved_pages += worst
+                gid = self._free_groups.pop()
+                slot = self._take_slot()
+                members.append((req, slot, gid))
+                self._slot_pages[slot] = []
+                self._slot_group[slot] = gid
+                self._provision(slot, cover)
+            if _chaos.ENABLED:
+                # slots popped, pages provisioned, nothing dispatched
+                _chaos.fault("serve.admit")
+            self._run(self._admit_progs[rung],
+                      self._admit_feed(members, rung), [])
+        except BaseException:
+            # last taken first: the group stack and the page pool's free
+            # list are LIFO, and a retry must pop the same ids
+            for _req, slot, gid in reversed(members):
+                self._rollback_admission([slot], gid, 1)
+            raise
+        self._count_admit(len(reqs), rung - len(reqs))
+        for req, slot, gid in members:
+            self._note_cross(slot, gid, req["len"])
+            self._group_members[gid] = {slot}
+            trg = np.full(self._T, self._eos, dtype="int64")
+            trg[0] = self._bos
+            self._live[slot] = {"trg": trg, "pos": 0}
+            _sequences_total.inc(event="admitted")
+        _active_slots.set(len(self._live))
+        self._update_pool_gauges()
+        return [m[1] for m in members]
 
     def _admit_next(self):
         """Admit the request at the head of the queue; its slot, or

@@ -559,6 +559,56 @@ def test_generate_backlog_exceeding_slots_completes_concurrently(
         assert np.array_equal(results[i][0], want[0]), "row %d" % i
 
 
+def test_a_lost_admission_batch_fails_each_of_its_streams_typed(trained):
+    """Three wire streams queued together are ONE admission batch; its
+    dispatch fails past the retry budget (a ``serve.admit`` fault, no
+    retries), the batch is rolled back whole, and EACH of the three
+    streams is shown the typed error. The worker lives on: the next
+    request serves, and the pool is conserved."""
+    src = trained["src"]
+    sess = _paged(trained, sampler=None, prefix_cache_pages=0,
+                  num_groups=S)
+    admit_pending = sess.admit_pending
+
+    def once_three_are_queued():
+        # hold admission back until the three streams are in the queue,
+        # so that they form one batch
+        if chaos.ENABLED and len(sess.pending_requests) < 3:
+            return {}
+        return admit_pending()
+
+    sess.admit_pending = once_three_are_queued
+    errors, results = {}, {}
+    with ServingFrontend(session=sess) as fe:
+
+        def one(i):
+            cl = ServingClient(fe.address)
+            try:
+                results[i] = cl.generate_full(src[i], src_len=SEQ)
+            except ServingError as exc:
+                errors[i] = exc
+            finally:
+                cl.close()
+
+        chaos.configure("io@site=serve.admit,n=1")
+        threads = [threading.Thread(target=one, args=(i,))
+                   for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert chaos.fires("serve.admit") == 1
+        chaos.disable()
+        assert sorted(errors) == [0, 1, 2] and not results
+        for exc in errors.values():
+            assert "chaos" in str(exc)
+        assert sess.admit_dispatches == 0 and _drained(sess)
+        cl = ServingClient(fe.address)
+        assert cl.generate_full(src[3], src_len=SEQ).shape == (1, SEQ)
+        cl.close()
+        assert _drained(sess)
+
+
 def test_client_disconnect_mid_stream_reclaims_pool(trained):
     src = trained["src"]
     sess = _paged(trained)

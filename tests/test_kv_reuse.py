@@ -322,17 +322,15 @@ def test_generate_deferred_request_ordering_pinned(trained):
     # in-flight one defers
     sess = _paged(trained, num_pages=1 + pa.pages_for(SEQ, 4))
     order = []
-    orig_admit = sess.admit
+    orig_admit_pending = sess.admit_pending
 
-    def spy_admit(src, src_len=None, **kw):
-        slot = orig_admit(src, src_len, **kw)  # deferred retries raise
-        for i in range(len(trained["src"])):
-            if np.array_equal(np.ravel(src), trained["src"][i]):
-                order.append(i)
-                break
-        return slot
+    def spy_admit_pending():
+        admitted = orig_admit_pending()  # request ids are row numbers
+        assert len(admitted) <= 1, "the pool covers one sequence"
+        order.extend(admitted.values())
+        return admitted
 
-    sess.admit = spy_admit
+    sess.admit_pending = spy_admit_pending
     out = sess.generate(trained["src"], trained["src_len"])
     np.testing.assert_array_equal(out, trained["want"])
     assert order == [0, 1, 2, 3], \

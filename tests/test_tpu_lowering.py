@@ -29,6 +29,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
@@ -99,6 +100,25 @@ def _compile_for(sharding, fn, *specs):
 
 def _compile_v5e(v5e, fn, *specs):
     return _compile_for(SingleDeviceSharding(v5e[0]), fn, *specs)
+
+
+def _assert_moves_no_pool(text, pool_elems):
+    """No ``copy``, ``transpose`` or gather in the compiled ``text`` whose
+    result holds a pool's elements or more."""
+    import re
+
+    moved = []
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+            r"(copy|transpose|gather|copy-start)\(", line)
+        if m and m.group(1):
+            elems = 1
+            for d in m.group(1).split(","):
+                elems *= int(d)
+            if elems >= pool_elems:
+                moved.append(line.strip()[:160])
+    assert not moved, "the program moves a whole pool:\n" + "\n".join(moved)
 
 
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -312,18 +332,7 @@ def test_paged_step_copies_no_pool(v5e, case, n_nodes):
     assert (pa.PAGED_KERNEL_NAME if n_nodes is None
             else pa.TREE_KERNEL_NAME) in text
     pool_elems = P * ps * H * dh
-    moved = []
-    for line in text.splitlines():
-        m = re.match(
-            r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
-            r"(copy|transpose|gather|copy-start)\(", line)
-        if m and m.group(1):
-            elems = 1
-            for d in m.group(1).split(","):
-                elems *= int(d)
-            if elems >= pool_elems:
-                moved.append(line.strip()[:160])
-    assert not moved, "the program moves a whole pool:\n" + "\n".join(moved)
+    _assert_moves_no_pool(text, pool_elems)
     pool_bytes = pool_elems * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
     # one layout of the pool, row-major, the whole row on the lanes
@@ -332,6 +341,74 @@ def test_paged_step_copies_no_pool(v5e, case, n_nodes):
         r"\[%s\]\{([\d,]+)" % dims, text))
     assert layouts == {"2,1,0"}, layouts
     assert not re.search(r"\[%d,%d,%d,%d\]" % (P, H, ps, dh), text)
+
+
+def test_batched_admission_copies_no_cross_pool(v5e):
+    """The top rung of ``SlotDecodeSession``'s admission ladder at the
+    served shapes (perfbench transformer_base: 32 sources of 256
+    positions into 256 groups of ``[8, 256, 64]`` float32 cross rows, 12
+    pools of 134 MB): the compiled program scatters the batch's rows in
+    place. It holds no ``copy``, ``transpose`` or gather of a pool's
+    size, aliases all twelve cross pools, the source mask and the five
+    per-slot state arrays onto their inputs, keeps each pool in the one
+    layout the decode step's cross kernel reads, and its temporaries
+    (the encoder's activations for 8192 token places) stay under one
+    pool. The sibling of ``test_paged_step_copies_no_pool``."""
+    import re
+
+    from paddle_tpu.models import transformer
+
+    rows, S, T, H, dh, L, ps = 32, 256, 256, 8, 64, 6, 16
+    prog = transformer.build_admit_batch_prog(
+        rows, S, src_vocab_size=32000, max_length=T, n_layer=L, n_head=H,
+        d_model=H * dh, d_inner=2048, page_size=ps)
+    state = {name: var for name, var in prog.global_block().vars.items()
+             if var.persistable}
+    npp = pa.pages_for(T, ps)
+    feed_specs = {
+        "src_word": ((rows, T), "int64"), "src_len": ((rows, 1), "int64"),
+        "slot_idx": ((rows,), "int64"), "group_idx": ((rows,), "int64"),
+        "page_row": ((rows, npp), "int64"),
+        "start_tok": ((rows, 1), "int64"), "start_pos": ((rows, 1), "int64")}
+    cp = lowering.CompiledProgram(prog, feed_specs, [], frozenset(state),
+                                  is_test=prog._is_test, device=v5e[0])
+    sharding = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+    def of(names):
+        return {n: spec(state[n].shape,
+                        jnp.int32 if "int" in str(state[n].dtype) else F32)
+                for n in names}
+
+    pools = ["pgd_%scross_%d" % (kv, i) for kv in "kv" for i in range(L)]
+    slot_state = ["pgd_group_of", "pgd_table", "pgd_tok", "pgd_pos",
+                  "pgd_done"]
+    assert set(cp.mutable_state) == set(pools + slot_state + ["pgd_src_mask"])
+    compiled = cp.jitted.lower(
+        of(cp.mutable_state), of(cp.frozen_state),
+        {n: spec(shape, jnp.int32) for n, (shape, _d) in feed_specs.items()},
+        (spec((2,), jnp.uint32), spec((), jnp.uint32))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= L   # the encoder's flash forward
+    pool_elems = S * H * T * dh
+    _assert_moves_no_pool(text, pool_elems)
+    memory = compiled.memory_analysis()
+    mutable_bytes = sum(
+        4 * int(np.prod(state[n].shape)) for n in cp.mutable_state)
+    assert memory.alias_size_in_bytes == mutable_bytes, (
+        memory.alias_size_in_bytes, mutable_bytes)
+    head = text.split("entry_computation_layout", 1)[0]
+    assert head.count("may-alias") + head.count("must-alias") == len(
+        cp.mutable_state)
+    print("batched admission, rung %d: temp_size_in_bytes %d"
+          % (rows, memory.temp_size_in_bytes))
+    assert memory.temp_size_in_bytes < 4 * pool_elems, \
+        memory.temp_size_in_bytes
+    layouts = set(re.findall(r"\[%d,%d,%d,%d\]\{([\d,]+)" % (S, H, T, dh),
+                             text))
+    assert layouts == {"2,3,1,0"}, layouts
 
 
 # the decode step's cross attention at the served shapes (perfbench

@@ -21,6 +21,8 @@ streams, zero fresh compiles, no minted context) is proved over real
 sockets by tools/trace_smoke.py (CI ``trace`` stage).
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -114,6 +116,9 @@ def test_trace_lifecycle_and_derived_stats():
         tr.bump("cow_copies", 1)
     tr.mark("first_token")
     tr.mark("first_token")  # idempotent: first occurrence wins
+    # the queue span above ends 1 ms after the trace began: on a fast
+    # machine the body takes less, and the root span would not cover it
+    time.sleep(max(0.0, tr.t0 + 0.002 - time.time()))
     rec = tracing.finish(tr, outcome="ok")
     assert tr.id not in tracing.inflight_ids()
     st = rec["stats"]
@@ -455,6 +460,11 @@ def test_round_counts_equal_what_the_session_did(served):
     # the cancelled stream: admissions and cancels are counted by their
     # spans, the round keeps no second count of them
     assert spans("admit") == 5 and spans("cancel") == 1
+    # each of them one encoder dispatch of one source (the group's two
+    # members share theirs), none padded: rung 1
+    assert total("admit_dispatches") == total("admit_rows") == 5
+    assert spans("admit.dispatch") == 5 + 1   # + the group's join
+    assert total("admit_pad_rows") == 0
     # tokens handed to the streams: all the clients read, plus at most
     # what the cancelled stream was handed before its cancel landed
     assert served["read_tokens"] == 5 * (SEQ - 1)
@@ -474,7 +484,50 @@ def test_round_counts_equal_what_the_session_did(served):
     for _r, i, sp in _round_spans(rounds):
         extra = set(sp) - {"name", "t0", "t1", "cpu", "parent"}
         assert extra <= ({"live", "backlog", "tokens", "cross_blocks_read",
-                          "cross_blocks_grid"} if i == 0 else set()), sp
+                          "cross_blocks_grid", "admit_dispatches",
+                          "admit_rows", "admit_pad_rows"}
+                         if i == 0 else set()), sp
+
+
+def test_a_round_that_admits_several_holds_one_admit_span(trained):
+    """Three queued requests, one worker pass: ONE ``admit`` span with
+    ONE ``admit.dispatch`` child (the batch's encoder dispatch),
+    ``admit_rows`` the admissions, the rung's other rows counted as
+    padding; each request's own trace keeps its ``queue`` and ``prefill``
+    spans, which begin and end with the batch."""
+    sess = _paged(trained, sampler=None, prefix_cache_pages=0,
+                  num_groups=S)
+    tracing.enable(True)
+    try:
+        rd = tracing.round_begin()
+        traces = [tracing.start(endpoint="generate") for _ in range(3)]
+        for i, tr in enumerate(traces):
+            sess.enqueue(trained["src"][i], SEQ, trace_id=tr.id)
+        admitted = sess.admit_pending()
+        tracing.round_end(rd)
+        (rnd,) = tracing.rounds()
+    finally:
+        tracing.enable(False)
+    assert sorted(admitted) == [0, 1, 2]
+    names = [sp["name"] for sp in rnd["spans"]]
+    assert names.count("admit") == 1 and names.count("admit.dispatch") == 1
+    dispatch = rnd["spans"][names.index("admit.dispatch")]
+    assert rnd["spans"][dispatch["parent"]]["name"] == "admit"
+    root = rnd["spans"][0]
+    rung = next(r for r in sess._admit_rungs if r >= 3)
+    assert (root["admit_rows"], root["admit_dispatches"],
+            root["admit_pad_rows"]) == (3, 1, rung - 3)
+    assert (sess.admit_rows, sess.admit_dispatches) == (3, 1)
+    for slot, tr in enumerate(traces):
+        by_name = {sp["name"]: sp for sp in tr.spans}
+        queue, prefill = by_name["queue"], by_name["prefill"]
+        assert prefill["meta"]["slot"] == slot
+        assert prefill["meta"]["round"] == queue["meta"]["round"] == rnd["id"]
+        # the batch's start and end, inside the round's admit span
+        admit = rnd["spans"][names.index("admit")]
+        assert queue["t1"] == prefill["t0"] >= admit["t0"]
+        assert dispatch["t1"] <= prefill["t1"] <= admit["t1"] + 0.005
+        tracing.finish(tr)
 
 
 def test_request_spans_name_a_round_in_the_ring(served):
