@@ -24,5 +24,6 @@ from paddle_tpu.layers.rnn import *  # noqa: F401,F403
 from paddle_tpu.layers.attention import *  # noqa: F401,F403
 from paddle_tpu.layers.decoder import *  # noqa: F401,F403
 from paddle_tpu.layers.ssm import *  # noqa: F401,F403
+from paddle_tpu.layers.window import *  # noqa: F401,F403
 from paddle_tpu.layers.nlp import *  # noqa: F401,F403
 from paddle_tpu.layers.detection import *  # noqa: F401,F403

@@ -187,12 +187,16 @@ def load_parameters(scope, named, desc=None, dtype=None):
 def build_hybrid_ssm_decoder(desc, num_slots, max_positions, page_size,
                              prefill_buckets, num_pages=None,
                              prefill_token_budget=2048, sampler=None,
-                             dtype="bfloat16", probe_rows=0):
+                             dtype="bfloat16", probe_rows=0,
+                             tokens_per_dispatch=1):
     """Build the serving programs (module docstring). Returns what
     ``models.latent_moe_decoder.build_latent_moe_decoder`` returns:
     ``init``, ``prefill`` ({bucket: program}), ``step``, ``fetches`` and
     ``geometry``, whose ``state`` declares the page pools and the
-    per-slot arrays by name."""
+    per-slot arrays by name.
+
+    ``tokens_per_dispatch`` is every builder's (the session passes it): no
+    pool of this model is sized by it."""
     from paddle_tpu import unique_name
     from paddle_tpu.models.transformer import _sampler_attrs
 

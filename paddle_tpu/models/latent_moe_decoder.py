@@ -168,7 +168,8 @@ def load_parameters(scope, named, desc=None, dtype=None):
 def build_latent_moe_decoder(desc, num_slots, max_positions, page_size,
                              prefill_buckets, num_pages=None,
                              prefill_token_budget=2048, sampler=None,
-                             dtype="bfloat16", probe_rows=0):
+                             dtype="bfloat16", probe_rows=0,
+                             tokens_per_dispatch=1):
     """Build the serving programs (module docstring). Returns a dict:
     ``init``, ``prefill`` ({bucket: program}), ``step``, ``fetches`` (the
     names to fetch: ``token``, ``first_token``, ``expert_tokens`` and, for
@@ -182,7 +183,10 @@ def build_latent_moe_decoder(desc, num_slots, max_positions, page_size,
     them from the SAME executable it serves with, every dispatch. It is
     not free: the gather makes the step write all slots' logits out
     (158 MB a token step at the published widths) where the program
-    without it fuses the sampler's argmax into the head's product."""
+    without it fuses the sampler's argmax into the head's product.
+
+    ``tokens_per_dispatch`` is every builder's (the session passes it): no
+    pool of this model is sized by it."""
     from paddle_tpu import unique_name
     from paddle_tpu.models.transformer import _sampler_attrs
 

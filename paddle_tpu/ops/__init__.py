@@ -33,3 +33,4 @@ from paddle_tpu.ops import fused_ops  # noqa: F401
 from paddle_tpu.ops import moe_ops  # noqa: F401
 from paddle_tpu.ops import decoder_ops  # noqa: F401
 from paddle_tpu.ops import ssm_ops  # noqa: F401
+from paddle_tpu.ops import window_ops  # noqa: F401

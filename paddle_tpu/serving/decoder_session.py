@@ -1,6 +1,7 @@
 """DecoderOnlySession: continuous-batching decode of a decoder-only model
 over the state its builder declares: page pools that grow with a slot's
-sequence, fixed-size arrays indexed by the slot itself, or both.
+sequence, page pools that hold a slot's last ``window`` positions only,
+fixed-size arrays indexed by the slot itself, or any of them together.
 
 The model is chosen from its description (``builder_for``): a dict with
 ``mamba_d_state`` is the hybrid state-space decoder
@@ -8,10 +9,15 @@ The model is chosen from its description (``builder_for``): a dict with
 window a slot a state-space layer, K/V page pools for its few attention
 layers), one with ``kv_lora_rank`` the latent-attention, routed-expert
 decoder (``models/latent_moe_decoder.py``: one pool of latent rows a
-layer). The session knows no model: the queue, the buckets, batched
-admission, the reservation and growth of pages, results and the trace
-hooks are one code path, and a builder's ``geometry["state"]`` names its
-``page_pools`` and ``slot_arrays``. Fixed-size state costs the host
+layer), one with ``layer_types`` and a ``sliding_window`` the decoder of
+window and full attention layers with routed experts
+(``models/windowed_moe_decoder.py``: a ring of K/V pages a slot a window
+layer beside a full layer's growing pools). The session knows no model:
+the queue, the buckets, batched admission, the reservation, growth and
+return of pages, results and the trace hooks are one code path, and a
+builder's ``geometry["state"]`` names its ``page_pools`` and
+``slot_arrays`` and, under ``windowed``, which pools are rings and of
+what window. Fixed-size state costs the host
 nothing: a prefill installs it for the slots it admits (after each
 prompt's last real token, whatever its bucket was padded to; a reused slot
 starts from its own prefill), and the live mask fed with every decode
@@ -32,14 +38,20 @@ src_len=)``. What differs is the model:
   weight of the model, every expert's too; a dispatch of one would pay
   that per prompt). The prefill writes the prompt's rows into the slot's
   pages, installs the slot's fixed-size state and samples the first token.
-* **One page table for every pool.** ``PagePool`` accounts the pages
-  (refcount 1 each: nothing is shared here) whatever the builder keeps in
-  them (one pool of latent rows a layer, or a K and a V pool an attention
-  layer); a slot's worst case (prompt + new tokens) is reserved at
-  admission, pages are taken as the sequence grows. The
-  page table and the live mask are the HOST's and are fed with every
-  decode dispatch, so growing a slot, finishing it or cancelling it costs
-  no dispatch: ``cancel`` is bookkeeping.
+* **One page table for every KIND of pool.** The pools that grow with
+  the sequence share one table (one pool of latent rows a layer, or a K
+  and a V pool an attention layer); a builder's windowed pools share
+  another, a RING of ``R`` columns a slot in which logical page ``j``
+  (rows ``j * page_size`` and on) sits in column ``j % R``. Each kind has
+  its own ``PagePool`` (refcount 1 a page: nothing is shared here), its
+  own count of reserved pages and its own feeds. A slot's worst case is
+  reserved at admission in every kind (prompt + new tokens; never more
+  than ``R`` in a ring), pages are taken as the sequence grows, and a
+  ring's page goes back to its free list the moment its last row is
+  behind every query the next dispatch can hold. The tables and the live
+  mask are the HOST's and are fed with every decode dispatch, so growing
+  a slot, returning a page behind its window, finishing it or cancelling
+  it costs no dispatch: ``cancel`` is bookkeeping.
 * **EOS is not looked for**: a stream ends at ``max_new_tokens`` or when
   its client closes it (the wire's in-band cancel). Seeded weights never
   emit a meaningful EOS, and public serving benchmarks ignore it.
@@ -53,9 +65,14 @@ Tracing (``observability/tracing.py``): under the worker's round,
 ``step`` > ``step.dispatch``; the round counts ``prefill_prompts``,
 ``prefill_tokens``, ``prefill_dispatches``, ``prefill_bucket_<T>``,
 ``prefill_pad_tokens`` (bucket rows the dispatch walks for nothing: its
-``prompts x bucket`` less the prompts' own tokens), ``pages_in_use``,
-``state_slots_live`` (with a model that has per-slot arrays: the slots
-whose rows the decode dispatch had to update) and, with routed experts,
+``prompts x bucket`` less the prompts' own tokens), ``pages_in_use``
+(with windowed pools also by kind, ``full_pages_in_use`` and
+``window_pages_in_use``, with ``window_pages_released``, the pages the
+round gave back from behind its slots' windows, and ``full_rows_visible``
+/ ``window_rows_visible``, the rows the decode dispatch's slots could see
+in a full layer and in a window layer), ``state_slots_live`` (with a
+model that has per-slot arrays: the slots whose rows the decode dispatch
+had to update) and, with routed experts,
 ``expert_max_over_mean`` (the most loaded expert's tokens over the mean,
 over the dispatch's steps and expert layers, from the counts the expert
 op returns WITH the step's tokens: no dispatch and no device sync of
@@ -97,10 +114,107 @@ def builder_for(desc):
         )
 
         return build_latent_moe_decoder
+    if "layer_types" in desc and desc.get("sliding_window") is not None:
+        from paddle_tpu.models.windowed_moe_decoder import (
+            build_windowed_moe_decoder,
+        )
+
+        return build_windowed_moe_decoder
     raise ServingError(
         "DecoderOnlySession knows no builder for this description (keys "
-        "%s): it serves a hybrid state-space decoder (mamba_d_state) or a "
-        "latent-attention decoder (kv_lora_rank)" % sorted(desc))
+        "%s): it serves a hybrid state-space decoder (mamba_d_state), a "
+        "latent-attention decoder (kv_lora_rank) or a decoder of window "
+        "and full attention layers (layer_types with a sliding_window)"
+        % sorted(desc))
+
+
+class _PageKind(object):
+    """The pages of one kind of pool: its ``PagePool``, the worst cases
+    reserved in it, every slot's pages and the table fed for them.
+
+    A slot holds the logical pages ``lo .. lo + len(pages) - 1`` (page
+    ``j``: the rows of positions ``j * page_size`` and on). With no
+    ``window`` ``lo`` stays 0 and the table row lists the pages in order,
+    its tail aliased to the last one (the kernels' no-copy rule). With a
+    ``window`` the row is a ring (page ``j`` in column ``j % cols``) and
+    pages wholly before the first row a query can still see are given
+    back."""
+
+    def __init__(self, slots, page_size, cols, num_pages, limit, table_feed,
+                 rows_feed, window=None):
+        self.ps, self.cols, self.P = int(page_size), int(cols), \
+            int(num_pages)
+        self.limit = int(limit)       # logical pages of a whole sequence
+        self.window = window
+        self.table_feed, self.rows_feed = table_feed, rows_feed
+        self.pool = PagePool(self.P)
+        self.reserved = 0
+        self.table = np.zeros((slots, self.cols), "int64")
+        self.pages = {}               # slot -> [page ids], logical order
+        self.lo = {}                  # slot -> its first logical page
+
+    def worst_case(self, positions):
+        return min(pages_for(positions, self.ps), self.cols)
+
+    def hold(self, slot, write_at, rows_end):
+        """Give ``slot`` the pages of the rows a dispatch whose first
+        query stands at ``write_at`` can see, up to row ``rows_end - 1``
+        (the last it writes). Returns the pages given back."""
+        pages = self.pages.setdefault(slot, [])
+        lo = self.lo.setdefault(slot, 0)
+        released = 0
+        if self.window:
+            first = max(write_at - self.window + 1, 0) // self.ps
+            while pages and lo < first:
+                self.table[slot, lo % self.cols] = 0
+                self.pool.deref(pages.pop(0))
+                lo += 1
+                released += 1
+            self.lo[slot] = lo = lo if pages else first
+        need = min(pages_for(rows_end, self.ps), self.limit) - lo
+        if len(pages) < need:
+            while len(pages) < need:
+                pages.append(self.pool.acquire())
+            self.write_row(slot, self.table[slot])
+        return released
+
+    def write_row(self, slot, row):
+        """``slot``'s pages into ``row`` (its table row, or a prefill
+        dispatch's)."""
+        pages, lo = self.pages[slot], self.lo[slot]
+        if self.window:
+            row[(lo + np.arange(len(pages))) % self.cols] = pages
+        else:
+            row[:len(pages)] = pages
+            row[len(pages):] = pages[-1]
+
+    def drop(self, slot):
+        for page in self.pages.pop(slot, ()):
+            self.pool.deref(page)
+        self.lo.pop(slot, None)
+        self.table[slot] = 0
+
+    @property
+    def conserved(self):
+        """free + allocated == pages - 1 (page 0 is the trash page), and
+        every allocated page belongs to exactly one live slot."""
+        held = sum(len(p) for p in self.pages.values())
+        return (self.pool.free_count + self.pool.allocated_count
+                == self.P - 1 and held == self.pool.allocated_count)
+
+
+class _PagesOfSlot(object):
+    """What the shared trace hooks sample (``_slot_pages.get(slot, ())``):
+    every page ONE slot holds, of every kind; nothing is built for the
+    slots nobody asks about."""
+
+    def __init__(self, kinds):
+        self._kinds = kinds
+
+    def get(self, slot, default=()):
+        if slot not in self._kinds[0].pages:
+            return default
+        return [p for k in self._kinds for p in k.pages[slot]]
 
 
 class DecoderOnlySession(object):
@@ -115,11 +229,20 @@ class DecoderOnlySession(object):
     tokens_per_dispatch : decode tokens a slot a dispatch.
     prefill_buckets, prefill_token_budget : the builder's (defaults:
         powers of two of the page size; 2048 tokens a dispatch).
-    num_pages : pool size, trash page included (default: full occupancy).
+    num_pages : size of the pools that grow with the sequence, trash page
+        included (default: full occupancy; a ring is always at full
+        occupancy).
     probe_rows : the builder's (a check's fetch of a few slots' logits
         from the serving executable itself; 0: none). The slots are
         ``probe_slots`` (an array to write into), fed with every step.
     scope : must hold the parameters (``load_parameters``).
+
+    ``admit_token_budget`` (an attribute, None at first: no bound) bounds
+    the bucket token places ONE ``admit_pending`` call may dispatch: with
+    long prompts the prefill of every free slot at once keeps the live
+    streams' decode waiting for as many dispatches (96 empty slots of
+    ~2800-token prompts: 17 s on one v5e); under a budget the rest of the
+    queue is admitted by the next rounds, a decode dispatch between them.
     """
 
     def __init__(self, exe, desc, num_slots, max_prompt, max_new_tokens,
@@ -146,7 +269,8 @@ class DecoderOnlySession(object):
             desc, self._S, positions, self._ps, prefill_buckets,
             num_pages=num_pages,
             prefill_token_budget=prefill_token_budget, sampler=sampler,
-            dtype=dtype, probe_rows=probe_rows)
+            dtype=dtype, probe_rows=probe_rows,
+            tokens_per_dispatch=self._K)
         self.geometry = geo = built["geometry"]
         self._buckets = geo["buckets"]
         if self._buckets[-1] < self._max_prompt:
@@ -154,10 +278,19 @@ class DecoderOnlySession(object):
                              "than max_prompt %d: chunked prefill is not "
                              "built" % (self._buckets[-1], self._max_prompt))
         self._per_dispatch = geo["prompts_per_dispatch"]
-        self._npp, self._P = geo["pages_per_slot"], geo["num_pages"]
-        if self._P - 1 < self._npp:
+        npp = geo["pages_per_slot"]
+        if geo["num_pages"] - 1 < npp:
             raise ValueError("num_pages=%d cannot hold one full sequence "
-                             "(%d pages)" % (self._P, self._npp))
+                             "(%d pages)" % (geo["num_pages"], npp))
+        # the pools that grow with the sequence first, then the rings
+        self._kinds = [_PageKind(self._S, self._ps, npp, geo["num_pages"],
+                                 npp, "page_table", "page_rows")]
+        for ring in geo["state"].get("windowed", ()):
+            self._kinds.append(_PageKind(
+                self._S, self._ps, ring["pages_per_slot"],
+                ring["num_pages"], npp, ring["table_feed"],
+                ring["rows_feed"], window=int(ring["window"])))
+        self._slot_pages = _PagesOfSlot(self._kinds)
         self._slot_state = bool(geo["state"]["slot_arrays"])
         self._prefill_progs = built["prefill"]
         self._step_prog = built["step"]
@@ -169,12 +302,8 @@ class DecoderOnlySession(object):
         self._bos, self._eos = 0, -1
         self._monitor = None
         self.beam_width = 1
-        self._pool = PagePool(self._P)
-        self._reserved_pages = 0
         self._free = list(range(self._S - 1, -1, -1))
         self._live = {}          # slot -> {"pos", "n", "len", "trg"}
-        self._slot_pages = {}    # slot -> [page ids]
-        self._table = np.zeros((self._S, self._npp), "int64")
         self._live_mask = np.zeros((self._S, 1), "int64")
         self._pending = collections.deque()
         self._owner = {}
@@ -188,10 +317,14 @@ class DecoderOnlySession(object):
         self.last_counters = {}
         # what the last admit_pending()/step() dispatched, for whoever
         # times them from outside: [(bucket, [prompt lengths])] and
-        # (live slots, resident rows the dispatch's first step attends)
+        # (live slots, resident rows the dispatch's first step attends);
+        # of those rows, the ones a window layer's queries could see
         self.last_prefills = []
         self.last_step = (0, 0)
+        self.last_window_rows = 0
+        self._released = 0       # ring pages given back since _count()
         self.probe_slots = np.zeros((int(probe_rows),), "int64")
+        self.admit_token_budget = None
         self._exe.run(built["init"], scope=self._scope)
 
     # -- what this session does not do ---------------------------------------
@@ -250,19 +383,30 @@ class DecoderOnlySession(object):
 
     @property
     def free_pages(self):
-        return self._pool.free_count
+        """Of the pools that grow with the sequence."""
+        return self._kinds[0].pool.free_count
 
     @property
     def pages_in_use(self):
-        return self._pool.allocated_count
+        """Over every kind of pool."""
+        return sum(k.pool.allocated_count for k in self._kinds)
+
+    @property
+    def pages_in_use_by_kind(self):
+        """``[pages]``: the growing pools' first, then each ring's."""
+        return [k.pool.allocated_count for k in self._kinds]
+
+    def slot_pages(self, slot):
+        """``[(first logical page, [page ids])]`` of a live slot, a kind
+        of pool each, in ``pages_in_use_by_kind``'s order."""
+        return [(k.lo[slot], list(k.pages[slot])) for k in self._kinds]
 
     @property
     def pool_conserved(self):
-        """free + allocated == pages - 1 (page 0 is the trash page), and
-        every allocated page belongs to exactly one live slot."""
-        held = sum(len(p) for p in self._slot_pages.values())
-        return (self._pool.free_count + self._pool.allocated_count
-                == self._P - 1 and held == self._pool.allocated_count)
+        """In every kind of pool: free + allocated == pages - 1 (page 0
+        is the trash page), and every allocated page belongs to exactly
+        one live slot."""
+        return all(k.conserved for k in self._kinds)
 
     def bucket_of(self, length):
         for t in self._buckets:
@@ -310,23 +454,36 @@ class DecoderOnlySession(object):
         return False
 
     # -- admission = batched prefill -----------------------------------------
-    def _reserve(self, length):
-        return pages_for(length + self._max_new, self._ps)
+    def _reserve(self, length, sign=1):
+        """Reserve (``sign`` -1: give back) a prompt's worst case in
+        every kind of pool."""
+        for kind in self._kinds:
+            kind.reserved += sign * kind.worst_case(length + self._max_new)
+
+    def _fits(self, lengths):
+        """Whether every kind of pool can reserve the worst cases of
+        prompts of ``lengths`` beside what it has reserved."""
+        return all(
+            kind.reserved + sum(kind.worst_case(n + self._max_new)
+                                for n in lengths) <= kind.P - 1
+            for kind in self._kinds)
 
     def admit_pending(self):
         """Admit the head of the queue, as many requests as slots are
-        free and the pool can reserve, in ONE prefill dispatch per length
-        bucket (more when a bucket holds more prompts than a dispatch
-        takes). Returns ``{slot: request_id}`` of this call's
-        admissions."""
-        take = []
+        free, the pool can reserve and ``admit_token_budget`` allows (one
+        at least), in ONE prefill dispatch per length bucket (more when a
+        bucket holds more prompts than a dispatch takes). Returns ``{slot:
+        request_id}`` of this call's admissions."""
+        take, places = [], 0
         self.last_prefills = []
-        reserved = self._reserved_pages
         while self._pending and len(take) < len(self._free):
-            need = self._reserve(self._pending[0]["len"])
-            if reserved + need > self._P - 1:
-                break      # the pool is reserved: wait for slots to end
-            reserved += need
+            if not self._fits([r["len"] for r in take]
+                              + [self._pending[0]["len"]]):
+                break      # a pool is reserved: wait for slots to end
+            places += self.bucket_of(self._pending[0]["len"])
+            if take and self.admit_token_budget \
+                    and places > self.admit_token_budget:
+                break      # the next round's, after a decode dispatch
             take.append(self._pending.popleft())
         if not take:
             return {}
@@ -358,7 +515,7 @@ class DecoderOnlySession(object):
         ids = np.zeros((B, T), "int64")
         lens = np.zeros((B,), "int64")
         slot_idx = np.full((B,), self._S, "int64")
-        page_rows = np.zeros((B, self._npp), "int64")
+        page_rows = [np.zeros((B, k.cols), "int64") for k in self._kinds]
         last_idx = np.arange(B, dtype="int64") * T
         slots = []
         t_admit = time.time()
@@ -367,14 +524,13 @@ class DecoderOnlySession(object):
                 slot = self._free.pop()
                 slots.append(slot)
                 n = r["len"]
-                pages = [self._pool.acquire()
-                         for _ in range(pages_for(n, self._ps))]
-                self._slot_pages[slot] = pages
-                self._reserved_pages += self._reserve(n)
+                self._reserve(n)
+                for kind, rows in zip(self._kinds, page_rows):
+                    # the next query stands at position n
+                    kind.hold(slot, n, n)
+                    kind.write_row(slot, rows[b])
                 ids[b, :n] = r["prompt"]
                 lens[b], slot_idx[b] = n, slot
-                page_rows[b, :len(pages)] = pages
-                page_rows[b, len(pages):] = pages[-1]
                 last_idx[b] += n - 1
             with _tracing.span("prefill", cpu=True):
                 if _tracing.ENABLED:
@@ -384,20 +540,21 @@ class DecoderOnlySession(object):
                                          B * T - int(lens.sum()))
                     _tracing.round_count("prefill_dispatches", 1)
                     _tracing.round_count("prefill_bucket_%d" % T, 1)
+                feed = {"prompt_ids": ids.reshape(-1), "prompt_len": lens,
+                        "slot_idx": slot_idx, "last_idx": last_idx}
+                for kind, rows in zip(self._kinds, page_rows):
+                    feed[kind.rows_feed] = rows
                 with _tracing.span(".dispatch"):
                     (first,) = self._exe.run(
-                        self._prefill_progs[T],
-                        feed={"prompt_ids": ids.reshape(-1),
-                              "prompt_len": lens, "slot_idx": slot_idx,
-                              "page_rows": page_rows, "last_idx": last_idx},
+                        self._prefill_progs[T], feed=feed,
                         fetch_list=[self._fetch["first_token"]],
                         scope=self._scope)
             first = np.asarray(first).reshape(-1)
         except BaseException:
             for slot, r in zip(slots, reqs):
-                for page in self._slot_pages.pop(slot, ()):
-                    self._pool.deref(page)
-                self._reserved_pages -= self._reserve(r["len"])
+                for kind in self._kinds:
+                    kind.drop(slot)
+                self._reserve(r["len"], -1)
                 self._free.append(slot)
             raise
         self.prefill_dispatches += 1
@@ -411,13 +568,12 @@ class DecoderOnlySession(object):
             self._live[slot] = {"pos": 0, "n": 1, "len": r["len"],
                                 "trg": trg}
             self._live_mask[slot, 0] = 1
-            self._write_table_row(slot)
             self._owner[slot] = out[slot] = r["id"]
             if r["id"] in self._trace_ids:
                 self._trace_admitted(r, slot, t_admit)
             _sequences_total.inc(event="admitted")
         _active_slots.set(len(self._live))
-        _pages_in_use.set(self._pool.allocated_count)
+        _pages_in_use.set(self.pages_in_use)
         return out
 
     def admit(self, src, src_len=None, prefix_tokens=None):
@@ -428,8 +584,7 @@ class DecoderOnlySession(object):
         self.enqueue(src, src_len, prefix_tokens=prefix_tokens)
         req = self._pending.pop()
         self.last_prefills = []
-        if (self._reserved_pages + self._reserve(req["len"])
-                > self._P - 1):
+        if not self._fits([req["len"]]):
             raise NoFreePageError("the pool cannot reserve this prompt's "
                                   "worst case")
         with _tracing.span("admit"):
@@ -438,28 +593,11 @@ class DecoderOnlySession(object):
         return slot
 
     # -- pages ---------------------------------------------------------------
-    def _write_table_row(self, slot):
-        pages = self._slot_pages[slot]
-        row = self._table[slot]
-        row[:len(pages)] = pages
-        row[len(pages):] = pages[-1]
-
-    def _provision(self, slot, positions):
-        """Pages for ``positions`` resident rows, up to a slot's whole."""
-        need = min(pages_for(positions, self._ps), self._npp)
-        pages = self._slot_pages[slot]
-        if len(pages) >= need:
-            return
-        while len(pages) < need:
-            pages.append(self._pool.acquire())
-        self._write_table_row(slot)
-
     def _release(self, slot):
         st = self._live.pop(slot)
-        for page in self._slot_pages.pop(slot):
-            self._pool.deref(page)
-        self._reserved_pages -= self._reserve(st["len"])
-        self._table[slot] = 0
+        for kind in self._kinds:
+            kind.drop(slot)
+        self._reserve(st["len"], -1)
         self._live_mask[slot, 0] = 0
         self._free.append(slot)
         return st
@@ -480,16 +618,23 @@ class DecoderOnlySession(object):
             t_step = time.time()
             pre_pos = {s: self._live[s]["pos"]
                        for s in self._slot_traces if s in self._live}
-        rows = 0
+        rows = ring_rows = 0
+        ring = self._kinds[-1].window or 0
         for slot, st in self._live.items():
             # step j writes the row of position len + n - 1 + j
-            self._provision(slot, st["len"] + st["n"] - 1 + self._K)
-            rows += st["len"] + st["n"]
+            at = st["len"] + st["n"] - 1
+            for kind in self._kinds:
+                self._released += kind.hold(slot, at, at + self._K)
+            rows += at + 1
+            ring_rows += min(at + 1, ring)
         self.last_step = (len(self._live), rows)
+        self.last_window_rows = ring_rows
         fetch = [self._fetch["token"]]
         if self._fetch.get("expert_tokens"):
             fetch.append(self._fetch["expert_tokens"])
-        feed = {"page_table": self._table, "live": self._live_mask}
+        feed = {"live": self._live_mask}
+        for kind in self._kinds:
+            feed[kind.table_feed] = kind.table
         if len(self.probe_slots):
             feed["probe_slots"] = self.probe_slots
         with _tracing.span(".dispatch"):
@@ -509,14 +654,23 @@ class DecoderOnlySession(object):
                 finished[slot] = self._release(slot)["trg"][:self._T]
                 _sequences_total.inc(event="completed")
         _active_slots.set(len(self._live))
-        _pages_in_use.set(self._pool.allocated_count)
+        _pages_in_use.set(self.pages_in_use)
         if traced and pre_pos:
             self._trace_step(pre_pos, finished, t_step, time.time(), False)
         return finished
 
     def _count(self, expert_tokens):
         """The round's counters, from what came back with the tokens."""
-        counters = {"pages_in_use": self._pool.allocated_count}
+        counters = {"pages_in_use": self.pages_in_use}
+        if len(self._kinds) > 1:
+            full = self._kinds[0].pool.allocated_count
+            counters.update(
+                full_pages_in_use=full,
+                window_pages_in_use=counters["pages_in_use"] - full,
+                window_pages_released=self._released,
+                full_rows_visible=self.last_step[1],
+                window_rows_visible=self.last_window_rows)
+            self._released = 0
         if self._slot_state:
             counters["state_slots_live"] = self.last_step[0]
         if expert_tokens is not None:
@@ -544,7 +698,7 @@ class DecoderOnlySession(object):
                 self._trace_cancel(slot, rid)
         _sequences_total.inc(event="cancelled")
         _active_slots.set(len(self._live))
-        _pages_in_use.set(self._pool.allocated_count)
+        _pages_in_use.set(self.pages_in_use)
         return True
 
     def pump(self):

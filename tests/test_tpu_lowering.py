@@ -553,3 +553,34 @@ def test_gqa_decode_lowers_for_tpu_at_the_served_widths(v5e):
         ((S, H, dh), BF16), pool, pool, ((S, npp), jnp.int32),
         ((S,), jnp.int32))
     assert gq.GQA_KERNEL_NAME in text
+
+
+def test_window_decode_lowers_for_tpu_at_the_served_widths(v5e):
+    """The window layers' decode of the 26B-A3B cell: 32 query heads on 4
+    key/value heads of 128, a ring of 18 pages of 128 rows a slot, 96
+    slots; its name holds no other kernel's."""
+    from paddle_tpu.kernels import gqa_paged_attention as gq
+    from paddle_tpu.kernels import window_paged_attention as wp
+
+    S, H, Hkv, dh, ps, R = 96, 32, 4, 128, 128, 18
+    pool = ((1 + S * R, ps, Hkv * dh), BF16)
+    text = _compile_v5e(
+        v5e, lambda q, k, v, t, n: wp.window_paged_attention(
+            q, k, v, t, n, 2048, force_pallas=True),
+        ((S, H, dh), BF16), pool, pool, ((S, R), jnp.int32),
+        ((S,), jnp.int32))
+    assert wp.WINDOW_KERNEL_NAME in text
+    assert gq.GQA_KERNEL_NAME not in text
+    _assert_moves_no_pool(text, (1 + S * R) * ps * Hkv * dh)
+
+
+def test_flash_window_prefill_width_lowers_for_tpu(v5e):
+    """The same cell's longest prefill bucket on a window layer: one
+    prompt of 8192, 32 heads on 4, a band of 2048, at the tiles
+    ``ops/window_ops.py`` asks for."""
+    q, kv = ((1, 32, 8192, 128), BF16), ((1, 4, 8192, 128), BF16)
+    text = _compile_v5e(
+        v5e, lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, kv_group=8, window=2048, block_q=512,
+            block_k=512), q, kv, kv)
+    assert "flash_attention_fwd" in text
