@@ -1809,6 +1809,40 @@ def build_admit_batch_prog(rows, num_slots, src_vocab_size=1000,
             n_layer, n_head, d_inner, src_vocab_size)
 
 
+def build_table_batch_prog(rows, num_slots, max_length=64, page_size=8):
+    """One table dispatch for ``rows`` slots (the sibling of
+    :func:`build_admit_batch_prog`; ``SlotDecodeSession`` repoints the
+    slots one release hands it through it): ``table_prog`` with ``rows``
+    rows a feed -- ``slot_idx [rows]``, ``page_row [rows, npp]`` -- one
+    row scatter into ``pgd_table``. A row of padding carries ``slot_idx =
+    num_slots``: past the end, so its write is dropped and an all-padding
+    call leaves the table as it was. ``rows`` is a rung of the session's
+    ladder (rung 1 is ``table_prog`` itself). Built under a fresh
+    ``unique_name`` scope, so equal geometry gives an equal
+    fingerprint."""
+    from paddle_tpu import unique_name
+
+    from paddle_tpu.kernels.paged_attention import pages_for
+
+    rows = int(rows)
+    if rows < 2:
+        raise ValueError(
+            "build_table_batch_prog needs rows >= 2 (one row is "
+            "build_paged_slot_decoder's table_prog)")
+    nn = fluid.layers
+    npp = pages_for(int(max_length), int(page_size))
+    with unique_name.guard({}):
+        prog = fluid.Program()
+        with fluid.program_guard(prog, fluid.Program()):
+            slot = nn.data("slot_idx", shape=[rows], dtype="int64",
+                           append_batch_size=False)
+            page_row = nn.data("page_row", shape=[npp], dtype="int64")
+            _write_rows(prog.global_block(), "pgd_table",
+                        [int(num_slots), npp], page_row, slot, rows,
+                        "int64")
+    return prog
+
+
 def save_compiled_generator(dirname, batch_size, src_vocab_size,
                             trg_vocab_size, max_length, n_layer, n_head,
                             d_model, d_inner, scope=None, bos_id=1,

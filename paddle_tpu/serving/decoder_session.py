@@ -696,10 +696,19 @@ class DecoderOnlySession(object):
             rid = self._owner.pop(slot, None)
             if self._slot_traces or self._trace_ids:
                 self._trace_cancel(slot, rid)
+        if _tracing.ENABLED:
+            _tracing.round_count("cancel_rows", 1)
         _sequences_total.inc(event="cancelled")
         _active_slots.set(len(self._live))
         _pages_in_use.set(self.pages_in_use)
         return True
+
+    def cancel_many(self, slots):
+        """:meth:`cancel` for each of ``slots`` (``SlotDecodeSession``'s
+        method of the same name batches a table dispatch; here a cancel
+        dispatches nothing, so there is nothing to batch). The slots
+        that were live."""
+        return [slot for slot in slots if self.cancel(slot)]
 
     def pump(self):
         """One scheduler round without a frontend: ``admit_pending``, one

@@ -302,8 +302,9 @@ class _DecodeWorker(object):
             self._ops.clear()
             stop, drain = self._stop, self._drain
         progressed = bool(incoming or cancels or ops)
-        for stream in cancels:
-            self._teardown(stream)
+        # the pass's cancelled streams are torn down together: their
+        # live slots are ONE cancel_many, one table dispatch
+        self._teardown(cancels)
         # ops run at this quiesce point: after cancels (so a drain's
         # "no live streams" check sees the teardowns) and before
         # this pass's admissions/dispatch
@@ -379,27 +380,35 @@ class _DecodeWorker(object):
         (orphaned rids — a restored process's backlog — stay owned and
         bank through the pump discipline on finish)."""
         s = self._s
+        late = []  # admitted after their client had cancelled
         with _tracing.span("handoff"):
             for slot, rid in list(s._owner.items()):
                 stream = self._rid_stream.pop(rid, None)
                 if stream is None:
                     continue
                 if stream.cancelled.is_set():
-                    self._safe_cancel(slot)
+                    late.append(slot)
                     continue
                 self._track(stream, {slot: 0})
                 stream.q.put(self._admitted_event(stream))
+            self._safe_cancel(late)
 
     def _fail_tracked(self, exc):
         wire = error_to_wire(exc)
-        for stream in set(
-                list(st for st, _m in self._slot_stream.values())
-                + list(self._beam_stream.values())):
-            # teardown marks the stream done; the terminal error line
-            # must still be delivered (a tracked stream has not yet
-            # seen a terminal event — it was live until this failure)
-            self._teardown(stream)
+        streams = self._tracked_streams()
+        # teardown marks the streams done; the terminal error line must
+        # still be delivered (a tracked stream has not yet seen a
+        # terminal event — it was live until this failure)
+        self._teardown(streams)
+        for stream in streams:
             stream.q.put(dict(wire))
+
+    def _tracked_streams(self):
+        """The streams that own a live slot or a beam lane, each once,
+        in the order they were tracked."""
+        return list(dict.fromkeys(
+            [st for st, _m in self._slot_stream.values()]
+            + list(self._beam_stream.values())))
 
     def _admit(self, stream):
         s = self._s
@@ -725,51 +734,57 @@ class _DecodeWorker(object):
                 s._trace_bank(rid)
         return tokens
 
-    def _safe_cancel(self, slot):
-        """Session cancel that can never kill the worker thread: the
+    def _safe_cancel(self, slots):
+        """Session cancel of ``slots`` together (``cancel_many``: one
+        table dispatch on a paged Transformer session, bookkeeping on a
+        decoder-only one) that can never kill the worker thread: the
         session absorbs repoint failures as recorded leaks; anything
         that still escapes (an invariant break) is logged loudly — a
         dead decode worker wedges EVERY stream, which is strictly
-        worse than one slot in a degraded state."""
+        worse than a few slots in a degraded state."""
+        if not slots:
+            return
         try:
-            self._s.cancel(slot)
+            self._s.cancel_many(slots)
         except Exception:  # noqa: BLE001 - logged, worker survives
             import logging
 
             logging.getLogger("paddle_tpu.serving").exception(
-                "cancel of slot %s failed during stream teardown",
-                slot)
+                "cancel of slots %s failed during stream teardown",
+                slots)
 
-    def _teardown(self, stream):
-        """Disconnect/cancel reclamation: live slots are cancelled
-        (slot + page references returned — ``pool_conserved`` holds
-        after this), a queued request leaves the backlog."""
+    def _teardown(self, streams):
+        """Disconnect/cancel reclamation of ``streams`` as one batch:
+        each stream's bookkeeping in turn (a queued request leaves the
+        backlog), then every live slot of theirs cancelled by ONE
+        session call (slots + page references returned —
+        ``pool_conserved`` holds after this)."""
         s = self._s
-        stream.done = True
-        if stream.beam_lane is not None:
-            self._beam_stream.pop(stream.beam_lane, None)
-            stream.beam_lane = None
-        for slot in list(stream.live):
-            self._slot_stream.pop(slot, None)
-            self._prev_pos.pop(slot, None)
-            # on a beam session the FIRST cancel releases the whole
-            # lane; sibling cancels return False harmlessly
-            self._safe_cancel(slot)
-        stream.live.clear()
-        if stream.rid is not None:
-            s.drop_pending(stream.rid)
-            self._rid_stream.pop(stream.rid, None)
-            stream.rid = None
+        slots = []
+        for stream in streams:
+            stream.done = True
+            if stream.beam_lane is not None:
+                self._beam_stream.pop(stream.beam_lane, None)
+                stream.beam_lane = None
+            for slot in list(stream.live):
+                self._slot_stream.pop(slot, None)
+                self._prev_pos.pop(slot, None)
+                # on a beam session the lane's FIRST slot releases the
+                # whole lane; its siblings are no longer live by then
+                slots.append(slot)
+            stream.live.clear()
+            if stream.rid is not None:
+                s.drop_pending(stream.rid)
+                self._rid_stream.pop(stream.rid, None)
+                stream.rid = None
+        self._safe_cancel(slots)
 
     def _abort_all(self):
         closed = ServerClosedError("frontend closed before completion")
-        for stream in set(
-                list(st for st, _m in self._slot_stream.values())
-                + list(self._beam_stream.values())):
-            self._teardown(stream)
-            stream.q.put(error_to_wire(closed))
-        for stream in list(self._rid_stream.values()):
-            self._teardown(stream)
+        streams = list(dict.fromkeys(
+            self._tracked_streams() + list(self._rid_stream.values())))
+        self._teardown(streams)
+        for stream in streams:
             stream.q.put(error_to_wire(closed))
 
 

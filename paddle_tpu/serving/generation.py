@@ -97,7 +97,9 @@ __all__ = ["SlotDecodeSession", "Sampler", "NoFreeSlotError",
 # (its program built, traced and lowered). The padding of the top rung
 # costs 4-5 ms of device time in the way of the batch's first tokens,
 # two one-row dispatches' worth: a run that would fill less than
-# 1/_ADMIT_MIN_FILL of its rung goes one request at a time through rung 1
+# 1/_ADMIT_MIN_FILL of its rung goes one request at a time through rung 1.
+# The release path's table programs (``_repoint``) go by the same rungs
+# without the fill rule: their padding is dropped rows of one row scatter
 _ADMIT_RUNGS = (1, 32)
 _ADMIT_MIN_FILL = 8
 
@@ -423,8 +425,24 @@ class SlotDecodeSession(object):
             self.admit_dispatches = 0  # encoder dispatches run (tests)
             self.admit_rows = 0        # sources they encoded
             top = min(_ADMIT_RUNGS[-1], self._S)
-            self._admit_rungs = () if self._beam_width > 1 else tuple(
-                [r for r in _ADMIT_RUNGS if r < top] + [top])
+            self._rungs = tuple([r for r in _ADMIT_RUNGS if r < top] + [top])
+            self._admit_rungs = () if self._beam_width > 1 else self._rungs
+            # batched release (_release_many): the slots ONE cancel_many,
+            # one dispatch's finishers or one rollback give up are
+            # pointed at the trash page by one table dispatch of the same
+            # ladder (rung 1 is the builder's own ``table_prog``), each
+            # rung built and warmed here by an all-padding call too
+            self._table_progs = {1: self._table_prog}
+            self.release_dispatches = 0  # table dispatches run (tests)
+            self.release_rows = 0        # slots they repointed
+            self.release_pad_rows = 0    # rows of padding beside them
+            for rung in self._rungs[1:]:
+                self._table_progs[rung] = \
+                    transformer.build_table_batch_prog(
+                        rung, self._S, max_length=self._T,
+                        page_size=self._ps)
+                self._run(self._table_progs[rung],
+                          self._trash_feed((), rung), [])
             for rung in self._admit_rungs[1:]:
                 self._admit_progs[rung] = \
                     transformer.build_admit_batch_prog(
@@ -740,11 +758,43 @@ class SlotDecodeSession(object):
         self.cow_pairs += len(copies)
         _cow_dispatches.inc()
 
-    def _write_table_row(self, slot, pages):
-        self._run(self._table_prog, {
-            "slot_idx": np.asarray([slot], dtype="int64"),
-            "page_row": self._page_row(pages),
-        }, [])
+    def _trash_feed(self, slots, rung):
+        """A table program's feed that points ``slots``' rows at the
+        trash page, padded to ``rung`` rows. A row of padding carries a
+        slot index past the table's end, so the scatter drops it."""
+        return {
+            "slot_idx": np.asarray(
+                list(slots) + [self._S] * (rung - len(slots)), "int64"),
+            "page_row": np.zeros((rung, self._npp), "int64"),
+        }
+
+    def _repoint(self, slots):
+        """Point the table rows of ``slots`` at the trash page: ONE
+        dispatch of the smallest rung that holds them (a lone slot goes
+        through ``table_prog`` itself), in chunks above the top rung. A
+        dispatch that fails past the retry budget may or may not have
+        landed, so every slot of it LEAKS its pages (recorded, never
+        freed, the capacity bound shrunk: a stale row could still write
+        them) -- its ``_slot_pages`` entry is gone when this returns."""
+        top = self._rungs[-1]
+        for at in range(0, len(slots), top):
+            chunk = slots[at:at + top]
+            rung = self._rung_of(len(chunk))
+            try:
+                self._run(self._table_progs[rung],
+                          self._trash_feed(chunk, rung), [])
+            except BaseException as exc:
+                for slot in chunk:
+                    pages = set(self._slot_pages.pop(slot))
+                    self._leaked_pages += len(
+                        pages - self._leaked_page_ids)
+                    self._leaked_page_ids.update(pages)
+                if not isinstance(exc, Exception):
+                    raise  # an interrupt or exit is not ours to absorb
+                continue
+            self.release_dispatches += 1
+            self.release_rows += len(chunk)
+            self.release_pad_rows += rung - len(chunk)
 
     def _update_pool_gauges(self):
         in_use = self._pool.allocated_count
@@ -801,30 +851,37 @@ class SlotDecodeSession(object):
         _tracing.round_count("cross_blocks_grid",
                              calls * acct["grid_steps"])
 
-    def _release_pages(self, slot):
-        """Recycle a finished slot's references: the table row is
-        pointed back at the trash page FIRST (the still-stepping done
-        slot's writes must never land in a recycled page), then every
-        page reference drops — a page frees only when its LAST
-        reference (fork sibling or prefix-cache entry) goes. The
-        slot's group loses a member; the group id frees with its last
-        member."""
-        self._write_table_row(slot, [])
-        for pg in self._slot_pages.pop(slot):
-            self._pool.deref(pg)
+    def _release_many(self, slots):
+        """Recycle the references of ``slots`` (finished or cancelled,
+        already out of ``_live``): EVERY table row is pointed back at
+        the trash page FIRST, by one dispatch (the still-stepping done
+        slots' writes must never land in a recycled page, and no other
+        dispatch may run before the repoint), then slot by slot in the
+        order given every page reference drops -- a page frees only when
+        its LAST reference (fork sibling or prefix-cache entry) goes --
+        and the slot's group loses a member; the group id frees with its
+        last member. The pool's free list and the group stack end as
+        releasing the slots one by one would leave them. A slot whose
+        repoint failed keeps its pages allocated (``_repoint``); its
+        books close all the same, so it re-admits cleanly."""
+        self._repoint(slots)
         drafter = getattr(self, "_spec_drafter", None)
-        if drafter is not None:
-            # the slot's next occupant must not inherit this one's
-            # draft-cache watermark
-            drafter.forget(slot)
-        gid = self._slot_group.pop(slot, None)
-        members = self._group_members.get(gid)
-        if members is not None:
-            members.discard(slot)
-            if not members:
-                del self._group_members[gid]
-                self._free_groups.append(gid)
-        self._reserved_pages -= self._pages_for(self._T, self._ps)
+        worst = self._pages_for(self._T, self._ps)
+        for slot in slots:
+            for pg in self._slot_pages.pop(slot, ()):
+                self._pool.deref(pg)
+            if drafter is not None:
+                # the slot's next occupant must not inherit this one's
+                # draft-cache watermark
+                drafter.forget(slot)
+            gid = self._slot_group.pop(slot, None)
+            members = self._group_members.get(gid)
+            if members is not None:
+                members.discard(slot)
+                if not members:
+                    del self._group_members[gid]
+                    self._free_groups.append(gid)
+            self._reserved_pages -= worst
 
     @property
     def free_pages(self):
@@ -1171,33 +1228,24 @@ class SlotDecodeSession(object):
         """A failed admission dispatch must leave NO device table row
         pointing at pages that return to the free list: repoint each
         admitted slot's row at the trash page FIRST (the same order
-        ``_release_pages`` uses), THEN drop the page references — the
+        ``_release_many`` uses, one dispatch for the group's members),
+        THEN drop the page references — the
         admit dispatch may have committed device-side before the host
         raised (post-dispatch chaos fault, fetch failure), and a
         recycled page receiving a stale row's writes is silent
         corruption of whichever sequence owns it next. If even the
         repoint dispatch fails, the pages are deliberately LEAKED
-        (kept allocated, never freed, and subtracted from the
+        (``_repoint``: kept allocated, never freed, and subtracted from the
         reservation capacity so provisioning can still never fail):
         a smaller pool is recoverable, corruption is not."""
         self._cross_view = None  # the dispatch may or may not have landed
+        self._repoint([s for s in slots if s in self._slot_pages])
         for s in slots:
-            pages = self._slot_pages.pop(s, None)
             self._slot_group.pop(s, None)
-            leak = False
-            if pages is not None:
-                try:
-                    self._write_table_row(s, [])
-                except BaseException:
-                    leak = True
-                if leak:
-                    self._leaked_pages += len(set(pages))
-                    self._leaked_page_ids.update(pages)
-                else:
-                    # last acquired first: the pool's free list is a
-                    # stack, and a retry must pop the same pages
-                    for pg in reversed(pages):
-                        self._pool.deref(pg)
+            # last acquired first: the pool's free list is a stack, and
+            # a retry must pop the same pages
+            for pg in reversed(self._slot_pages.pop(s, ())):
+                self._pool.deref(pg)
         # restore the free stack exactly (pop order == re-pop order, so
         # a retried admission lands in the same slots => same PRNG
         # streams). Beam-lane admissions own their slot bookkeeping
@@ -1475,8 +1523,10 @@ class SlotDecodeSession(object):
                     finished[s] = self._live[s]["trg"]
                     del self._live[s]
                     self._free.append(s)
-                    self._release_pages(s)
                     _sequences_total.inc(event="completed")
+                # the lane's rows in one table dispatch, before the next
+                # lane's reorder can dispatch anything
+                self._release_many(slots)
                 del self._beam_live[lane]
                 self._free_lanes.append(lane)
                 self._last_parents.pop(lane, None)
@@ -1528,87 +1578,98 @@ class SlotDecodeSession(object):
 
     def cancel(self, slot):
         """Abort one in-flight sequence — the disconnect/cancel
-        teardown a network front end needs: the slot frees, its page
-        references drop (the table row is repointed at the trash page
-        FIRST, the ``_release_pages`` discipline, so recycled pages can
-        never receive a stale row's writes), its group loses a member
-        and any request ownership is dropped WITHOUT banking a result.
-        Returns True when the slot was live. Call between dispatches
-        (never mid-``step``); :attr:`pool_conserved` holds afterwards —
-        a killed client costs capacity nothing.
+        teardown a network front end needs, the one-slot case of
+        :meth:`cancel_many`: the slot frees, its page references drop
+        (the table row is repointed at the trash page FIRST, the
+        ``_release_many`` discipline, so recycled pages can never
+        receive a stale row's writes), its group loses a member and any
+        request ownership is dropped WITHOUT banking a result. Returns
+        True when the slot was live. Call between dispatches (never
+        mid-``step``); :attr:`pool_conserved` holds afterwards — a
+        killed client costs capacity nothing.
 
         On a BEAM session a slot is one hypothesis of a lane, and a
         lane is one request: cancelling any member releases the WHOLE
         beam (every sibling slot, the lane, the owner binding — nothing
         banks)."""
-        slot = int(slot)
-        if slot not in self._live:
-            return False
-        if self._beam_width > 1:
-            lane = slot // self._beam_width
-            binfo = self._beam_live.pop(lane, None)
-            if binfo is None:
-                return False
-            ok = True
-            for s in binfo["slots"]:
-                if s in self._live:
-                    ok = self._cancel_one(s) and ok
-            self._free_lanes.append(lane)
-            self._last_parents.pop(lane, None)
-            self._beam_events.pop(lane, None)
-            self._beam_owner.pop(lane, None)  # cancelled, never banked
-            _active_beams.set(len(self._beam_live))
-            return ok
-        return self._cancel_one(slot)
+        return bool(self.cancel_many([slot]))
 
-    def _cancel_one(self, slot):
-        with _tracing.span("cancel"):
-            return self._cancel_slot(slot)
+    def cancel_many(self, slots):
+        """Abort several in-flight sequences in ONE teardown (what a
+        front end's worker pass hands over: every stream it found
+        cancelled): all their table rows are repointed by one dispatch
+        of the smallest rung that holds them (a lone slot runs
+        ``table_prog``, as :meth:`cancel` always has), then the slots,
+        pages and groups come back in the order given — exactly what
+        cancelling them one by one leaves. A beam session releases the
+        whole lane of every slot given, each lane once. Returns the
+        slots of ``slots`` that were live (a lane's later siblings are
+        not: the first released them)."""
+        hit, release, lanes = [], [], []
+        for slot in (int(s) for s in slots):
+            if slot not in self._live or slot in release:
+                continue
+            if self._beam_width > 1:
+                lane = slot // self._beam_width
+                if lane not in self._beam_live:
+                    continue
+                lanes.append(lane)
+                release.extend(s for s in self._beam_live[lane]["slots"]
+                               if s in self._live)
+            else:
+                release.append(slot)
+            hit.append(slot)
+        if release:
+            with _tracing.span("cancel"):
+                self._cancel_slots(release, lanes)
+        return hit
 
-    def _cancel_slot(self, slot):
+    def _cancel_slots(self, slots, lanes):
+        if self._paged:
+            before = (self.release_dispatches, self.release_pad_rows)
         self._begin_op()
         try:
-            del self._live[slot]
+            for slot in slots:
+                del self._live[slot]
             if self._paged:
-                try:
-                    self._release_pages(slot)
-                except BaseException:
-                    if slot not in self._slot_pages:
-                        raise  # deref-path invariant break: a real bug
-                    # the trash-repoint dispatch failed: the device row
-                    # may still point at these pages — LEAK them
-                    # (recorded, so ckpt_inspect --verify exempts them)
-                    # instead of freeing pages a stale row could write;
-                    # the group/reservation books still close, so the
-                    # slot re-admits cleanly. Same corruption-beats-
-                    # capacity rule as _rollback_admission.
-                    pages = self._slot_pages.pop(slot)
-                    self._leaked_pages += len(set(pages))
-                    self._leaked_page_ids.update(pages)
-                    gid = self._slot_group.pop(slot, None)
-                    members = self._group_members.get(gid)
-                    if members is not None:
-                        members.discard(slot)
-                        if not members:
-                            del self._group_members[gid]
-                            self._free_groups.append(gid)
-                    self._reserved_pages -= self._pages_for(self._T,
-                                                            self._ps)
-            self._free.append(slot)
-            # inside the op window: a quiesce snapshot at _end_op must
-            # never bank a freed slot with a stale owner entry (a later
-            # occupant of the slot would finish into the cancelled
-            # request's result id)
-            rid = self._owner.pop(slot, None)
-            if self._slot_traces or self._trace_ids:
-                self._trace_cancel(slot, rid)
+                # a repoint dispatch that fails leaks its slots' pages
+                # (recorded, so ckpt_inspect --verify exempts them)
+                # instead of freeing pages a stale row could write; the
+                # group/reservation books still close, so the slots
+                # re-admit cleanly. Same corruption-beats-capacity rule
+                # as _rollback_admission.
+                self._release_many(slots)
+            for slot in slots:
+                self._free.append(slot)
+                # inside the op window: a quiesce snapshot at _end_op
+                # must never bank a freed slot with a stale owner entry
+                # (a later occupant of the slot would finish into the
+                # cancelled request's result id)
+                rid = self._owner.pop(slot, None)
+                if self._slot_traces or self._trace_ids:
+                    self._trace_cancel(slot, rid)
+            for lane in lanes:
+                del self._beam_live[lane]
+                self._free_lanes.append(lane)
+                self._last_parents.pop(lane, None)
+                self._beam_events.pop(lane, None)
+                self._beam_owner.pop(lane, None)  # cancelled, never banked
         finally:
             self._end_op()
-        _sequences_total.inc(event="cancelled")
+        if _tracing.ENABLED:
+            _tracing.round_count("cancel_rows", len(slots))
+            if self._paged:
+                _tracing.round_count(
+                    "cancel_dispatches",
+                    self.release_dispatches - before[0])
+                _tracing.round_count(
+                    "cancel_pad_rows", self.release_pad_rows - before[1])
+        _sequences_total.inc(len(slots), event="cancelled")
         _active_slots.set(len(self._live))
+        if lanes:
+            _active_beams.set(len(self._beam_live))
         if self._paged:
             self._update_pool_gauges()
-        return True
 
     def step(self):
         """Advance every in-flight sequence through the step
@@ -1796,9 +1857,10 @@ class SlotDecodeSession(object):
                     finished[slot] = st["trg"]
                     del self._live[slot]
                     self._free.append(slot)
-                    self._release_pages(slot)
                     _sequences_total.inc(event="completed")
                     break
+        if finished:
+            self._release_many(list(finished))  # one table dispatch
         _active_slots.set(len(self._live))
         return finished
 
@@ -1820,9 +1882,11 @@ class SlotDecodeSession(object):
                     finished[slot] = st["trg"]
                     del self._live[slot]
                     self._free.append(slot)
-                    if self._paged:
-                        self._release_pages(slot)
                     _sequences_total.inc(event="completed")
+        if finished and self._paged:
+            # the trajectory's finishers together, in finishing order:
+            # one table dispatch, before any other dispatch can run
+            self._release_many(list(finished))
         _active_slots.set(len(self._live))
         return finished
 
@@ -1945,7 +2009,7 @@ class SlotDecodeSession(object):
         return run
 
     def _rung_of(self, rows):
-        return next(r for r in self._admit_rungs if r >= rows)
+        return next(r for r in self._rungs if r >= rows)
 
     def _admit_feed(self, members, rung):
         """The admission program's feed for ``members`` (``[(request,

@@ -655,6 +655,96 @@ def test_inband_cancel_reclaims_and_connection_stays_usable(trained):
     assert np.array_equal(wire[0], o[0])
 
 
+def _cancel_in_one_pass(sess, specs):
+    """Behind a bare decode worker: admit one stream a spec, hold the
+    worker inside its step dispatch while every stream is cancelled, let
+    it go, and wait for the teardown. The streams' first events."""
+    from paddle_tpu.serving.frontend import _DecodeWorker, _Stream
+
+    gate = threading.Event()
+    step = sess.step
+    sess.step = lambda: (gate.wait(30), step())[1]
+    worker = _DecodeWorker(sess)
+    try:
+        streams = [_Stream(dict(spec)) for spec in specs]
+        for stream in streams:
+            worker.submit(stream)
+        first = [[stream.q.get(timeout=60) for _ in range(2)]
+                 for stream in streams]            # queued, admitted
+        assert sorted(sess.active_slots) == list(range(len(specs)))
+        # the worker is inside its step: the cancels wait for one pass
+        for stream in streams:
+            worker.cancel(stream)
+        gate.set()
+        assert _drained(sess)
+        assert all(stream.done for stream in streams)
+    finally:
+        gate.set()
+        worker.stop(drain=False, timeout=30)
+    return first
+
+
+def test_a_pass_with_n_cancelled_streams_makes_one_table_dispatch(trained):
+    """Three streams cancelled while the worker steps are torn down by its
+    next pass together: ONE ``cancel_many``, one table dispatch of three
+    rows, the slots back in cancel order, the pool conserved."""
+    sess = _paged(trained, sampler=None, prefix_cache_pages=0,
+                  num_groups=S)
+    calls = []
+    cancel_many = sess.cancel_many
+    sess.cancel_many = lambda slots: (calls.append(list(slots)),
+                                      cancel_many(slots))[1]
+    specs = [{"src": trained["src"][i], "src_len": SEQ, "n": 1,
+              "prefix": None} for i in range(3)]
+    first = _cancel_in_one_pass(sess, specs)
+    assert [ev[1]["event"] for ev in first] == ["admitted"] * 3
+    assert calls == [[0, 1, 2]]
+    assert (sess.release_dispatches, sess.release_rows) == (1, 3)
+    assert sess._free[-3:] == [0, 1, 2] and sess.pool_conserved
+
+
+def test_a_decoder_only_session_behind_the_worker_cancels_with_none():
+    """The same worker, the same call, a session whose cancel is host
+    bookkeeping: ``cancel_many`` is its ``cancel`` slot by slot and no
+    executor call is made for it."""
+    from test_latent_moe_decoder import make_session, prompts_of
+
+    sess, _tree = make_session(max_new_tokens=400)
+    runs = []
+    exe = sess._exe
+
+    class _Counted(object):
+        """Between the session and its executor: counts the calls."""
+
+        def __getattr__(self, name):
+            call = getattr(exe, name)
+            if name not in ("run", "run_multi_step"):
+                return call
+            return lambda *a, **kw: (runs.append(name), call(*a, **kw))[1]
+
+    sess._exe = _Counted()
+    calls = []
+    cancel_many = sess.cancel_many
+
+    def counted_cancel_many(slots):
+        before = len(runs)
+        out = cancel_many(slots)
+        calls.append((list(slots), len(runs) - before))
+        return out
+
+    sess.cancel_many = counted_cancel_many
+    specs = []
+    for prompt in prompts_of([7, 21, 12], seed=4):
+        src = np.zeros(32, "int64")
+        src[:len(prompt)] = prompt
+        specs.append({"src": src, "src_len": len(prompt), "n": 1,
+                      "prefix": None})
+    _cancel_in_one_pass(sess, specs)
+    # (the session fills slots bucket by bucket, so not in stream order)
+    assert [(sorted(slots), n) for slots, n in calls] == [([0, 1, 2], 0)]
+    assert sess.pool_conserved and sess.pages_in_use == 0
+
+
 def test_inband_cancel_is_read_while_chunks_outrun_the_poll_cadence():
     """A stream whose chunks come faster than ``stream_poll_s`` (a decode
     round of 44 ms against the cadence of 50) never finds its queue empty:

@@ -411,6 +411,53 @@ def test_batched_admission_copies_no_cross_pool(v5e):
     assert layouts == {"2,3,1,0"}, layouts
 
 
+def test_batched_table_repoint_writes_the_table_in_place(v5e):
+    """The top rung of ``SlotDecodeSession``'s release ladder at the
+    served shapes (perfbench transformer_base: 32 of 256 slots' rows of
+    16 pages): the compiled program is one row scatter into the donated
+    ``pgd_table``. The table is its only state and aliases its input;
+    the program names no page pool and holds no op of a pool's size (the
+    16 KB table itself the compiler may turn into the scatter's layout
+    and back: two copies of 4096 words), and its temporaries stay within
+    a few tables' bytes."""
+    import re
+
+    from paddle_tpu.models import transformer
+
+    rows, S, T, ps = 32, 256, 256, 16
+    prog = transformer.build_table_batch_prog(rows, S, max_length=T,
+                                              page_size=ps)
+    npp = pa.pages_for(T, ps)
+    state = {name: var for name, var in prog.global_block().vars.items()
+             if var.persistable}
+    assert set(state) == {"pgd_table"}
+    feed_specs = {"slot_idx": ((rows,), "int64"),
+                  "page_row": ((rows, npp), "int64")}
+    cp = lowering.CompiledProgram(prog, feed_specs, [], frozenset(state),
+                                  is_test=prog._is_test, device=v5e[0])
+    assert list(cp.mutable_state) == ["pgd_table"] and not cp.frozen_state
+    sharding = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+    compiled = cp.jitted.lower(
+        {"pgd_table": spec((S, npp), jnp.int32)}, {},
+        {n: spec(shape, jnp.int32) for n, (shape, _d) in feed_specs.items()},
+        (spec((2,), jnp.uint32), spec((), jnp.uint32))).compile()
+    text = compiled.as_text()
+    assert "scatter" in text and "tpu_custom_call" not in text
+    _assert_moves_no_pool(text, (1 + S * npp) * ps * 512)
+    assert not re.search(r"\[%d,%d,\d+\]" % (1 + S * npp, ps), text)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 4 * S * npp, \
+        memory.alias_size_in_bytes
+    head = text.split("entry_computation_layout", 1)[0]
+    assert head.count("may-alias") + head.count("must-alias") == 1
+    assert memory.temp_size_in_bytes <= 4 * 4 * S * npp, \
+        memory.temp_size_in_bytes
+
+
 # the decode step's cross attention at the served shapes (perfbench
 # transformer_base: 256 slots and groups, 8 heads of 64, 256 source
 # positions), the tree-verify program's N nodes, transformer_big's 16

@@ -457,9 +457,13 @@ def test_round_counts_equal_what_the_session_did(served):
                    for _r, _i, sp in _round_spans(rounds))
 
     # three solo streams, a group of two (ONE admission of two members),
-    # the cancelled stream: admissions and cancels are counted by their
-    # spans, the round keeps no second count of them
+    # the cancelled stream: an admission is counted by its span; a
+    # cancel span is a BATCH (here of one), the round's ``cancel_rows``
+    # counts the slots and ``cancel_dispatches`` the table dispatches (a
+    # lone cancel: rung 1, no padding)
     assert spans("admit") == 5 and spans("cancel") == 1
+    assert total("cancel_rows") == total("cancel_dispatches") == 1
+    assert spans("cancel.dispatch") == 1 and total("cancel_pad_rows") == 0
     # each of them one encoder dispatch of one source (the group's two
     # members share theirs), none padded: rung 1
     assert total("admit_dispatches") == total("admit_rows") == 5
@@ -485,8 +489,47 @@ def test_round_counts_equal_what_the_session_did(served):
         extra = set(sp) - {"name", "t0", "t1", "cpu", "parent"}
         assert extra <= ({"live", "backlog", "tokens", "cross_blocks_read",
                           "cross_blocks_grid", "admit_dispatches",
-                          "admit_rows", "admit_pad_rows"}
+                          "admit_rows", "admit_pad_rows",
+                          "cancel_dispatches", "cancel_rows",
+                          "cancel_pad_rows"}
                          if i == 0 else set()), sp
+
+
+def test_a_round_that_cancels_several_holds_one_cancel_span(trained):
+    """Three live slots handed to ``cancel_many`` in one worker pass:
+    ONE ``cancel`` span with ONE ``cancel.dispatch`` child (the batch's
+    table dispatch), ``cancel_rows`` the slots, the rung's other rows
+    counted as padding; a lone cancel after it is a span of its own
+    through rung 1."""
+    sess = _paged(trained, sampler=None, prefix_cache_pages=0,
+                  num_groups=S)
+    for i in range(S):
+        sess.enqueue(trained["src"][i], SEQ)
+    assert sorted(sess.admit_pending()) == list(range(S))
+    tracing.enable(True)
+    try:
+        rd = tracing.round_begin()
+        assert sess.cancel_many([2, 0, 1]) == [2, 0, 1]
+        tracing.round_end(rd)
+        rd = tracing.round_begin()
+        assert sess.cancel(3) is True
+        tracing.round_end(rd)
+        batch, lone = tracing.rounds()
+    finally:
+        tracing.enable(False)
+    names = [sp["name"] for sp in batch["spans"]]
+    assert names.count("cancel") == 1 and names.count("cancel.dispatch") == 1
+    dispatch = batch["spans"][names.index("cancel.dispatch")]
+    assert batch["spans"][dispatch["parent"]]["name"] == "cancel"
+    root = batch["spans"][0]
+    rung = sess._rung_of(3)
+    assert (root["cancel_rows"], root["cancel_dispatches"],
+            root["cancel_pad_rows"]) == (3, 1, rung - 3)
+    root = lone["spans"][0]
+    assert (root["cancel_rows"], root["cancel_dispatches"],
+            root["cancel_pad_rows"]) == (1, 1, 0)
+    assert (sess.release_rows, sess.release_dispatches) == (4, 2)
+    assert sess.pool_conserved and sess.free_slots == S
 
 
 def test_a_round_that_admits_several_holds_one_admit_span(trained):
