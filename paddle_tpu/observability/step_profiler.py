@@ -40,7 +40,12 @@ seconds, seconds by phase)`` in a ring of its own
 (:func:`dispatch_records`), flag or no flag — one StepSpan and a dozen
 perf_counter calls, microseconds against a dispatch of milliseconds —
 so a benchmark can read the host's share of a dispatch from a run that
-switched nothing on.
+switched nothing on. With request tracing on
+(``observability/tracing.ENABLED``, read once a dispatch in
+:func:`begin`) the record also says how long the calling thread RAN in
+each phase (``time.thread_time()`` at every bracket). A phase's wall
+less its CPU is time the thread was blocked; in ``device`` that is the
+wait for the chip, anywhere else the interpreter lock by elimination.
 
 Overhead contract (FLAGS_step_profile, telemetry's discipline): OFF
 adds nothing to the dispatch record — no ring record, no cost join, no
@@ -87,7 +92,8 @@ _DRIFT_N = 5         # consecutive excursions = sustained drift, rebase
 
 _lock = lock_witness.make_lock("observability.step_profiler")
 _records = collections.deque(maxlen=RING_CAP)
-# every dispatch, flag or no flag: (origin, t_end, wall_s, phases)
+# every dispatch, flag or no flag: (origin, t_end, wall_s, phases, the
+# thread's CPU seconds by phase or None)
 _dispatches = collections.deque(maxlen=DISPATCH_RING_CAP)
 _cost = {}           # fingerprint -> per-step cost join (None = tried, failed)
 _reg = {}            # fingerprint/origin -> regression baseline state
@@ -177,7 +183,8 @@ class StepSpan(object):
     a small dict."""
 
     __slots__ = ("origin", "t0", "phases", "input_wait", "fingerprint",
-                 "_cur", "_t_cur", "_cost_cp", "_cost_avals")
+                 "_cur", "_t_cur", "_cost_cp", "_cost_avals",
+                 "_cpu", "_cpu0", "_cpu_cur")
 
     def __init__(self, origin):
         self.origin = origin
@@ -189,6 +196,22 @@ class StepSpan(object):
         self._t_cur = 0.0
         self._cost_cp = None
         self._cost_avals = None
+        # with request tracing on (begin): {phase: the thread's CPU
+        # seconds}, and its CPU clock at the first and the last bracket
+        self._cpu = None
+        self._cpu0 = self._cpu_cur = None
+
+    def _account(self, phase):
+        """Book the thread's CPU since the last bracket to ``phase``
+        (None: to the residual). The first bracket follows ``begin`` at
+        once, so its reading is the span's first."""
+        now = time.thread_time()
+        if phase is not None:
+            self._cpu[phase] = (self._cpu.get(phase, 0.0)
+                                + now - self._cpu_cur)
+        elif self._cpu0 is None:
+            self._cpu0 = now
+        self._cpu_cur = now
 
     def enter(self, phase):
         """Open ``phase``; a phase still open closes on the same stamp,
@@ -197,6 +220,8 @@ class StepSpan(object):
         cur = self._cur
         if cur is not None:
             self.phases[cur] = self.phases.get(cur, 0.0) + (now - self._t_cur)
+        if self._cpu is not None:
+            self._account(cur)
         self._cur = phase
         self._t_cur = now
         _inflight[threading.get_ident()] = (self.origin, phase, now,
@@ -207,6 +232,8 @@ class StepSpan(object):
         cur = self._cur
         if cur is not None:
             self.phases[cur] = self.phases.get(cur, 0.0) + (now - self._t_cur)
+            if self._cpu is not None:
+                self._account(cur)
             self._cur = None
             _inflight[threading.get_ident()] = (self.origin, "host", now,
                                                 self.t0)
@@ -232,8 +259,11 @@ class StepSpan(object):
 def begin(origin):
     """Open a span for one step and, with the observatory on, claim the
     calling thread's banked input wait (the readers bank it under the
-    same flag). Executors call this on every step."""
+    same flag). With request tracing on the span also keeps the thread's
+    CPU seconds by phase. Executors call this on every step."""
     sp = StepSpan(origin)
+    if _tracing.ENABLED:
+        sp._cpu = {}
     if ENABLED:
         banked = getattr(_tls, "input_wait", 0.0)
         if banked:
@@ -406,6 +436,9 @@ def finish(sp, steps=1, feeds=None, fetches=None, dispatch_only=False):
     timed region — ``feeds``/``fetches`` are passed as containers (not
     pre-summed byte counts) so the wall clock stops on the FIRST line
     here, before any accounting arithmetic."""
+    cpu = sp._cpu
+    # the thread's last reading lies inside the wall, like the brackets'
+    cpu_end = time.thread_time() if cpu is not None else None
     now = time.perf_counter()
     if sp._cur is not None:
         sp.exit()
@@ -414,7 +447,13 @@ def finish(sp, steps=1, feeds=None, fetches=None, dispatch_only=False):
     host = max(0.0, wall - measured)
     phases = dict(sp.phases)
     phases["host"] = host
-    _dispatches.append((sp.origin, time.time(), wall, phases))
+    if cpu is not None:
+        # the residual's: the whole span's (from its first bracket to
+        # here, or to a phase this call closed) less the brackets'
+        first = cpu_end if sp._cpu0 is None else sp._cpu0
+        cpu["host"] = (max(cpu_end, sp._cpu_cur or 0.0) - first
+                       - sum(cpu.values()))
+    _dispatches.append((sp.origin, time.time(), wall, phases, cpu))
     _inflight.pop(threading.get_ident(), None)
     if not ENABLED:
         return None
@@ -540,10 +579,13 @@ def dispatch_records(origin=None):
     dispatch's end on ``time.time()`` (the clock of
     ``observability/tracing.py``'s spans, so the dispatches of a round
     can be picked out); ``phases`` holds seconds by phase, the residual
-    ``host`` included. ``origin`` keeps one entry point's: ``single``,
-    ``async``, ``multi_step``, ``parallel``."""
-    return [{"origin": o, "t1": t1, "wall_s": wall, "phases": dict(ph)}
-            for o, t1, wall, ph in _tracing.ring_snapshot(_dispatches)
+    ``host`` included; ``cpu`` the seconds the calling thread ran in each
+    of those phases, None for a dispatch made with request tracing off.
+    ``origin`` keeps one entry point's: ``single``, ``async``,
+    ``multi_step``, ``parallel``."""
+    return [{"origin": o, "t1": t1, "wall_s": wall, "phases": dict(ph),
+             "cpu": None if cpu is None else dict(cpu)}
+            for o, t1, wall, ph, cpu in _tracing.ring_snapshot(_dispatches)
             if origin is None or o == origin]
 
 

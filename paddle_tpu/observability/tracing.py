@@ -36,17 +36,21 @@ same guard every pass of ``ServingFrontend``'s worker loop that did
 work is one :class:`Round` in a ring of its own — child spans ``wait``,
 ``cancel``, ``enqueue``, ``admit``, ``step``, ``handoff`` and, around
 each executor call of the session, ``<open span>.dispatch``; each
-``name, t0, t1, cpu, parent`` (``cpu``: the worker thread's
-``time.thread_time()`` over the span, so time the thread was not running
-shows — taken on the spans that bound the worker's host time, ``round``,
-``wait``, ``step`` and ``step.dispatch``, and None on the others: that
-clock is a system call, 5 us on the chip's host and 28 us with the
-frontend's threads alive, and two readings on each of a round's ~90
-spans cost the saturated server a hundredth of its rate; ``parent``:
+``name, t0, t1, cpu, parent``. ``cpu`` is the worker thread's
+``time.thread_time()`` over the span, read inside both wall stamps on
+every span: seconds the thread ran. What is left of the wall, less the
+executor's wait for the chip inside the span and less the ``wait``
+span's own condition wait, is time the thread was BLOCKED: the
+interpreter lock by elimination, beside what else can hide there (a
+core that was not free, a ``queue.put``'s mutex, a host-to-device
+copy's wait). ``parent``:
 index into the round's ``spans``, whose entry 0 is the ``round`` itself
 and holds its counts: ``live`` slots and ``backlog`` at the dispatch,
 ``tokens`` handed to the streams, which the benchmark's report prints
-beside the rate the clients counted). A request's ``queue``, ``prefill``
+beside the rate the clients counted, and ``handler_cpu`` /
+``handler_chunks``, the handler threads' CPU seconds and chunks written
+so far (``ServingFrontend`` keeps them a request; a reader takes last
+less first over its rounds). A request's ``queue``, ``prefill``
 and ``decode.step`` spans name the round they fell into (``round=``), so
 ``tools/trace_view.py`` counts its queue wait and its decode in rounds.
 Every round span is also a ``jax.profiler.TraceAnnotation`` named
@@ -312,27 +316,24 @@ class Round(object):
         self.spans = []
         self._open = []   # (span index, thread_time at begin, annotation)
 
-    def begin(self, name, cpu=False):
+    def begin(self, name):
         """Open a child of the innermost open span; a ``name`` with a
         leading dot is appended to that span's own (``.dispatch`` inside
-        ``admit`` is ``admit.dispatch``) and takes the thread's CPU time
-        if that span does."""
+        ``admit`` is ``admit.dispatch``)."""
         parent = self._open[-1][0] if self._open else None
         if name[0] == ".":
             name = self.spans[parent]["name"] + name
-            cpu = self._open[-1][1] is not None
         ann = TraceAnnotation(ANNOTATION_PREFIX + name)
         ann.__enter__()
-        self._open.append((len(self.spans),
-                           time.thread_time() if cpu else None, ann))
         self.spans.append({"name": name, "t0": time.time(), "t1": None,
                            "cpu": None, "parent": parent})
+        # the CPU clock is read INSIDE the wall stamps: cpu <= t1 - t0
+        self._open.append((len(self.spans) - 1, time.thread_time(), ann))
 
     def end(self):
         idx, cpu0, ann = self._open.pop()
         sp = self.spans[idx]
-        if cpu0 is not None:
-            sp["cpu"] = time.thread_time() - cpu0
+        sp["cpu"] = time.thread_time() - cpu0
         sp["t1"] = time.time()
         ann.__exit__(None, None, None)
 
@@ -340,15 +341,14 @@ class Round(object):
 class _RoundSpan(object):
     """``with`` form of one child span."""
 
-    __slots__ = ("_rd", "_name", "_cpu")
+    __slots__ = ("_rd", "_name")
 
-    def __init__(self, rd, name, cpu):
+    def __init__(self, rd, name):
         self._rd = rd
         self._name = name
-        self._cpu = cpu
 
     def __enter__(self):
-        self._rd.begin(self._name, self._cpu)
+        self._rd.begin(self._name)
 
     def __exit__(self, *exc):
         self._rd.end()
@@ -359,7 +359,7 @@ def round_begin():
     """Open a round on the calling thread (the decode worker's, under
     its ``ENABLED`` guard)."""
     rd = _tls.round = Round()
-    rd.begin("round", cpu=True)
+    rd.begin("round")
     return rd
 
 
@@ -373,18 +373,17 @@ def round_end(rd, keep=True):
         _rounds.append({"id": rd.id, "spans": rd.spans})
 
 
-def span(name, cpu=False):
+def span(name):
     """``with span("admit"):`` a child span of the round open on this
     thread; nothing with tracing off or no round open (a session driven
     without a frontend) -- one attribute read and a shared no-op
-    context. ``cpu=True`` also takes the thread's CPU time over the span
-    (two system calls)."""
+    context."""
     if not ENABLED:
         return _NO_SPAN
     rd = getattr(_tls, "round", None)
     if rd is None:
         return _NO_SPAN
-    return _RoundSpan(rd, name, cpu)
+    return _RoundSpan(rd, name)
 
 
 def round_count(key, delta=1):

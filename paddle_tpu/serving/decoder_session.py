@@ -63,7 +63,7 @@ copy-on-write, beam lanes, speculation, decode snapshots.
 Tracing (``observability/tracing.py``): under the worker's round,
 ``admit`` > ``prefill`` > ``prefill.dispatch`` per bucket dispatch and
 ``step`` > ``step.dispatch``; the round counts ``prefill_prompts``,
-``prefill_tokens``, ``prefill_dispatches``, ``prefill_bucket_<T>``,
+``prefill_tokens``, ``prefill_dispatches``,
 ``prefill_pad_tokens`` (bucket rows the dispatch walks for nothing: its
 ``prompts x bucket`` less the prompts' own tokens), ``pages_in_use``
 (with windowed pools also by kind, ``full_pages_in_use`` and
@@ -532,14 +532,13 @@ class DecoderOnlySession(object):
                 ids[b, :n] = r["prompt"]
                 lens[b], slot_idx[b] = n, slot
                 last_idx[b] += n - 1
-            with _tracing.span("prefill", cpu=True):
+            with _tracing.span("prefill"):
                 if _tracing.ENABLED:
                     _tracing.round_count("prefill_prompts", len(reqs))
                     _tracing.round_count("prefill_tokens", int(lens.sum()))
                     _tracing.round_count("prefill_pad_tokens",
                                          B * T - int(lens.sum()))
                     _tracing.round_count("prefill_dispatches", 1)
-                    _tracing.round_count("prefill_bucket_%d" % T, 1)
                 feed = {"prompt_ids": ids.reshape(-1), "prompt_len": lens,
                         "slot_idx": slot_idx, "last_idx": last_idx}
                 for kind, rows in zip(self._kinds, page_rows):
@@ -609,7 +608,7 @@ class DecoderOnlySession(object):
         ``max_new_tokens`` (their slots and pages are free again)."""
         if not self._live:
             return {}
-        with _tracing.span("step", cpu=True):
+        with _tracing.span("step"):
             return self._step()
 
     def _step(self):

@@ -163,6 +163,44 @@ class _Stream(object):
         self.beam_rid = None   # ... and its banked-result claim id
 
 
+class _HandlerAccount(object):
+    """What the handler threads cost the interpreter, kept with tracing
+    on: each handler's CPU seconds so far (request parsing and socket
+    writes included) and the chunks it wrote, under its connection's id
+    (one handler thread a connection) while the connection is open, and
+    summed for the closed. A handler writes its own key at the end of a
+    request (one clock read a request, never a chunk; a single-key store
+    needs no lock); the lock keeps a close's fold and the worker's sum
+    apart, so the totals only grow."""
+
+    def __init__(self):
+        self._mu = lock_witness.make_lock("serving.frontend.handlers")
+        self.open = {}          # connection id -> (cpu seconds, chunks)
+        self._closed = (0.0, 0)
+
+    def request_done(self, conn_id, chunks):
+        """On the handler's own thread."""
+        self.open[conn_id] = (time.thread_time(),
+                              self.open.get(conn_id, (0.0, 0))[1] + chunks)
+
+    def closed(self, conn_id):
+        """On the handler's own thread, as its connection closes."""
+        with self._mu:
+            acct = self.open.pop(conn_id, None)
+            if acct is not None:
+                self._closed = (self._closed[0] + time.thread_time(),
+                                self._closed[1] + acct[1])
+
+    def totals(self):
+        """``(cpu seconds, chunks)`` of every handler so far."""
+        with self._mu:
+            cpu, chunks = self._closed
+            for acct in list(self.open.values()):
+                cpu += acct[0]
+                chunks += acct[1]
+        return cpu, chunks
+
+
 class _DecodeWorker(object):
     """The one thread that owns the SlotDecodeSession.
 
@@ -175,8 +213,10 @@ class _DecodeWorker(object):
     banked in the session's result bank, exactly like ``pump()``.
     """
 
-    def __init__(self, session, max_backlog=64):
+    def __init__(self, session, max_backlog=64, handlers=None):
         self._s = session
+        # the frontend's account of its handler threads, for the rounds
+        self._handlers = _HandlerAccount() if handlers is None else handlers
         self._cond = lock_witness.make_condition("serving.frontend.decode")
         self._incoming = deque()
         self._cancels = deque()
@@ -264,6 +304,8 @@ class _DecodeWorker(object):
                 alive, progressed = self._pass()
             finally:
                 if rd is not None:
+                    if progressed:
+                        self._count_handlers()
                     _tracing.round_end(rd, keep=progressed)
             if not alive:
                 return
@@ -280,12 +322,20 @@ class _DecodeWorker(object):
                     if not self._incoming and not self._cancels:
                         self._cond.wait(0.1)
 
+    def _count_handlers(self):
+        """The handler threads' account so far onto the round's root
+        (keys new to the round, so the add is a store). A reader takes
+        last less first over its rounds."""
+        cpu, chunks = self._handlers.totals()
+        _tracing.round_count("handler_cpu", cpu)
+        _tracing.round_count("handler_chunks", chunks)
+
     def _pass(self):
         """One pass of the worker loop. Returns ``(alive, progressed)``:
         whether the worker lives on, and whether the pass moved
         anything."""
         s = self._s
-        with _tracing.span("wait", cpu=True), self._cond:
+        with _tracing.span("wait"), self._cond:
             while (not self._incoming and not self._cancels
                     and not self._ops
                     and not self._stop and not s.active_slots
@@ -835,8 +885,12 @@ class ServingFrontend(object):
         self._batching = server
         self._session = session
         self._snap_mgr = snapshot_manager
+        # written by the handlers at the end of a traced request, summed
+        # by the decode worker a round
+        self._handlers = _HandlerAccount()
         self._decode = (_DecodeWorker(session,
-                                      max_backlog=max_stream_backlog)
+                                      max_backlog=max_stream_backlog,
+                                      handlers=self._handlers)
                         if session is not None else None)
         self._poll = float(stream_poll_s)
         self._mu = lock_witness.make_lock("serving.frontend.mu")
@@ -871,6 +925,8 @@ class ServingFrontend(object):
         for stream in list(conn.state.get("streams", ())):
             if self._decode is not None:
                 self._decode.cancel(stream)
+        if self._handlers.open:
+            self._handlers.closed(conn.id)
         with self._mu:
             self._conns -= 1
             _fe_active_conns.set(self._conns)
@@ -990,6 +1046,7 @@ class ServingFrontend(object):
         outcome = "error"
         first_token = False
         stream = None
+        chunks = 0   # counted with tracing on only
         tr = None
         if _tracing.ENABLED:
             # continue the client-minted trace (or mint one for
@@ -1080,6 +1137,7 @@ class ServingFrontend(object):
                                   tokens=len(msg.get("tokens", ())))
                     yield msg
                     tr.end(sp)
+                    chunks += 1
                 else:
                     yield msg
                 if msg.get("event") == "end":
@@ -1108,6 +1166,7 @@ class ServingFrontend(object):
                 # closes stragglers), so the ring never holds a trace
                 # with dangling open spans
                 _tracing.finish(tr, outcome=outcome)
+                self._handlers.request_done(conn.id, chunks)
 
     def _next_event(self, stream, conn, poll_at):
         """``(message, verdict, next poll time)``: the stream's next

@@ -1680,7 +1680,7 @@ class SlotDecodeSession(object):
         when nothing is in flight."""
         if not self._live:
             return {}
-        with _tracing.span("step", cpu=True):
+        with _tracing.span("step"):
             return self._step()
 
     def _step(self):

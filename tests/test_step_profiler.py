@@ -372,7 +372,49 @@ def test_dispatch_records_with_the_flag_off(origin, call, waits):
         assert measured <= r["wall_s"] * (1 + 1e-9)
         # the end stamp is on time.time(), the request traces' clock
         assert t_before <= r["t1"] <= time.time()
+        # request tracing is off too: the thread's CPU is not kept
+        assert r["cpu"] is None
     assert [r["t1"] for r in recs] == sorted(r["t1"] for r in recs)
+
+
+@pytest.mark.parametrize("origin,call", [
+    ("single", _run_single), ("async", _run_async),
+    ("multi_step", _run_multi)])
+def test_dispatch_records_hold_the_threads_cpu_with_request_tracing_on(
+        origin, call, monkeypatch):
+    """With ``tracing.ENABLED`` a dispatch record says how long the
+    calling thread ran in each phase of ``phases``; with it off the CPU
+    clock is not read and the key holds None."""
+    import time
+
+    from paddle_tpu.observability import tracing
+
+    main, startup, loss = _build_mlp()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    call(exe, main, loss)
+    step_profiler.reset()
+    readings = []
+    thread_time = time.thread_time
+    monkeypatch.setattr(time, "thread_time",
+                        lambda: readings.append(1) or thread_time())
+    call(exe, main, loss)
+    assert readings == []
+    tracing.enable(True)
+    try:
+        call(exe, main, loss)
+    finally:
+        tracing.enable(False)
+    off, on = step_profiler.dispatch_records(origin)
+    assert off["cpu"] is None
+    # a reading at every bracket and one at the end; the first bracket's
+    # is the span's first (``begin`` takes none of its own)
+    assert len(readings) >= len(on["phases"])
+    assert set(on["cpu"]) == set(on["phases"])
+    assert "host" in on["cpu"] and "dispatch" in on["cpu"]
+    # the readings lie inside the wall's stamps
+    assert 0.0 <= sum(on["cpu"].values()) <= on["wall_s"]
+    assert all(v >= -1e-9 for v in on["cpu"].values())
 
 
 @pytest.mark.parametrize("call", [_run_single, _run_multi])
