@@ -35,12 +35,29 @@ front end" for the full wire grammar):
 Disconnect safety is the load-bearing property: a client that dies (or
 cancels) mid-stream must cost the pool NOTHING. Three hooks converge on
 the same teardown — the substrate's per-connection close callback, the
-in-band ``cancel`` line, and the stream generator's ``GeneratorExit``
-(a failed socket write) — each routing to ``SlotDecodeSession.cancel``
-/ ``drop_pending`` on the decode worker thread, which returns the slot
-and drops the page references; ``pool_conserved`` (free +
-unique-allocated == P - 1) holds afterwards, asserted by the tests and
-the CI ``net`` stage's kill-mid-stream leg.
+in-band ``cancel`` line or EOF read by the connection watcher, and the
+stream generator's ``GeneratorExit`` (a failed socket write) — each
+routing to ``SlotDecodeSession.cancel`` / ``drop_pending`` on the decode
+worker thread, which returns the slot and drops the page references;
+``pool_conserved`` (free + unique-allocated == P - 1) holds afterwards,
+asserted by the tests and the CI ``net`` stage's kill-mid-stream leg.
+
+Who reads a cancel: ONE watcher thread (``_ConnWatcher``) holds a
+selector over the sockets of the connections that have a stream in
+flight. A handler thread blocks on its stream's queue and wakes only for
+a message of the decode worker's or the watcher's verdict; it polls
+nothing. On a ``cancel`` line or EOF the watcher cancels the stream on
+the decode worker at once and posts the verdict; the handler writes the
+terminal ``cancelled`` event (or just returns on EOF). Every cancel line
+is answered EXACTLY once, and a client may send its next request as soon
+as it has read a terminal event, so: a handler takes its connection out
+of the watcher (``unwatch``: under the lock every read of the watcher's
+is made under) BEFORE it writes ``end``, ``cancelled`` or an error line,
+and if the watcher had already consumed a cancel line that no
+``cancelled`` has answered, the handler writes that one ack (``{"event":
+"cancelled", "idle": true}``, what the substrate answers a cancel with
+no stream in flight) right after its terminal line. A cancel line still
+in the socket at the hand-back is the substrate's to answer, as ever.
 
 Preemption composes with PR 13: construct the
 ``DecodeSnapshotManager(install_signal_handlers=True)`` FIRST, then the
@@ -54,7 +71,8 @@ One dedicated decode-worker thread owns the session (admissions,
 steps, cancellations all serialize through it — the session is not
 thread-safe and must not become so: the zero-compile contract lives in
 its single-threaded dispatch discipline); handler threads only move
-messages between that worker and their sockets.
+messages between that worker and their sockets, and the watcher thread
+only reads cancels (``_DecodeWorker.cancel`` is any thread's to call).
 """
 
 import base64
@@ -62,6 +80,7 @@ import json
 import os
 import queue
 import select
+import selectors
 import shutil
 import signal
 import socket
@@ -145,15 +164,28 @@ def _outcome(exc):
     return "error"
 
 
+# what the connection watcher puts on a stream's queue beside the decode
+# worker's messages (dicts): its verdict on the stream's connection
+_CANCEL = "cancel"   # the client's in-band cancel line was read
+_EOF = "eof"         # the client is gone
+_LOOK = "look"       # TLS: bytes arrived, the handler reads them itself
+
+
 class _Stream(object):
     """One wire generate stream: the handler thread consumes ``q``;
-    the decode worker produces into it and tracks the live slots."""
+    the decode worker produces into it and tracks the live slots; the
+    connection watcher puts its verdict there."""
 
     __slots__ = ("q", "spec", "cancelled", "live", "rid", "done",
-                 "beam_lane", "beam_rid")
+                 "beam_lane", "beam_rid", "wakeups", "empty_wakeups")
 
     def __init__(self, spec):
         self.q = queue.Queue()
+        # the handler's returns from q.get(), and those of them that
+        # brought nothing to write or act on (written by the handler
+        # alone, folded into the watcher's counts as the stream ends)
+        self.wakeups = 0
+        self.empty_wakeups = 0
         self.spec = spec       # {"src", "src_len", "n", "prefix", "beam"}
         self.cancelled = threading.Event()
         self.live = {}         # slot -> member index
@@ -201,6 +233,312 @@ class _HandlerAccount(object):
         return cpu, chunks
 
 
+class _Watch(object):
+    """One connection with a stream in flight, as the watcher holds it."""
+
+    __slots__ = ("conn", "stream", "armed", "owed")
+
+    def __init__(self, conn, stream):
+        self.conn = conn
+        self.stream = stream
+        self.armed = False     # registered in the selector
+        self.owed = False      # a cancel line was consumed, not answered
+
+
+class _ConnWatcher(object):
+    """The one thread that reads every streaming connection's in-band
+    cancel or EOF, so that a handler thread sleeps on its stream's queue
+    until there is something to write.
+
+    A handler puts its connection in (``watch``) when its stream starts
+    and takes it out (``unwatch``) BEFORE it writes any terminal event.
+    In between, the watcher blocks in a selector over the watched
+    sockets (no interpreter lock held); when one turns readable it
+    peeks, reads a whole line if there is one, and on a ``cancel`` line
+    or EOF cancels the stream on the decode worker itself and posts the
+    verdict (``_CANCEL`` / ``_EOF``) to the stream's queue.
+
+    The hand-back rule that keeps the wire in step: every read of a
+    connection happens under ``_mu`` and only while its entry is still
+    the watched one, and ``unwatch`` takes ``_mu`` too — once it
+    returns, the watcher is not reading that connection and never will
+    again, so the next request's line is the substrate's. ``unwatch``
+    returns whether the watcher consumed a cancel line (the protocol
+    answers each exactly once): the handler that writes another
+    terminal event first (the stream ended while the cancel was in
+    flight) owes the client the one ``cancelled`` ack after it.
+
+    A readable socket that holds no whole line yet (a fragmented
+    cancel, a trickling client) is taken out of the selector and looked
+    at again after ``pause_s``: ``readline`` would block the watcher and
+    a level-triggered selector would spin it. A TLS connection is never
+    read here (an SSL object is not safe to read on one thread while
+    another writes): raw readability posts ``_LOOK`` and the handler
+    reads the line on its own thread, then puts the connection back
+    (``rearm``) if it was not a cancel.
+    """
+
+    def __init__(self, cancel, pause_s, tls=False):
+        self._cancel = cancel    # _DecodeWorker.cancel: any thread's
+        self._pause = float(pause_s)
+        self._tls = bool(tls)
+        self._mu = lock_witness.make_lock("serving.frontend.watcher")
+        self._sel = selectors.DefaultSelector()
+        # epoll and kqueue take a registration while select() blocks;
+        # the portable fallbacks have to be woken to see it
+        self._live_sel = type(self._sel).__name__ in (
+            "EpollSelector", "KqueueSelector", "DevpollSelector")
+        self._watched = {}       # connection id -> _Watch
+        self._paused = {}        # connection id -> when to look again (a
+        #                          partial line: watched, out of the selector)
+        self._stop = False
+        self._wake_r, self._wake_w = socket.socketpair()
+        self._wake_r.setblocking(False)
+        self._sel.register(self._wake_r, selectors.EVENT_READ, None)
+        # counts (written under _mu, read without; all but the gauge
+        # only grow)
+        self._wakeups = 0
+        self._verdicts = {_CANCEL: 0, _EOF: 0, _LOOK: 0}  # posted
+        self._cpu = 0.0
+        self._handler_wakeups = 0        # of the streams taken out
+        self._handler_empty_wakeups = 0
+        self._thread = threading.Thread(
+            target=self._loop, name="paddle-tpu-frontend-watcher",
+            daemon=True)
+        self._thread.start()
+
+    # -- handler-thread API --------------------------------------------------
+
+    def watch(self, conn, stream):
+        with self._mu:
+            if self._stop:
+                return
+            w = self._watched[conn.id] = _Watch(conn, stream)
+            verdict = self._arm(w)
+        self._deliver(w, verdict)
+
+    def unwatch(self, conn):
+        """Take ``conn`` out (idempotent). True when the watcher
+        consumed a cancel line of this stream's: whoever writes the
+        stream's terminal event answers it."""
+        with self._mu:
+            w = self._watched.pop(conn.id, None)
+            if w is None:
+                return False
+            self._disarm(w)
+            self._handler_wakeups += w.stream.wakeups
+            self._handler_empty_wakeups += w.stream.empty_wakeups
+            return w.owed
+
+    def rearm(self, conn):
+        """TLS: the handler read what ``_LOOK`` announced and it was no
+        cancel; watch the connection's raw socket again."""
+        with self._mu:
+            w = self._watched.get(conn.id)
+            if w is None or w.armed or self._stop:
+                return
+            verdict = self._arm(w)
+        self._deliver(w, verdict)
+
+    def counts(self):
+        """The counts beside ``handler_cpu`` / ``handler_chunks`` (docs/
+        OBSERVABILITY.md): all but ``watching`` only grow; the handlers'
+        wake-ups are those of the streams that have ended. (A TLS
+        frontend's ``_LOOK`` posts are wake-ups here and nothing else:
+        the handler that reads the line cancels for itself.) Takes no
+        lock: the decode worker reads them once a traced round, just
+        when a round's cancels keep ``_mu`` busy, and a wait for a lock
+        is a wake-up the worker pays for (6 ms a round when this took
+        ``_mu``: my chip runs, PR 37)."""
+        return {
+            "watching": len(self._watched),
+            "wakeups": self._wakeups,
+            "cancel": self._verdicts[_CANCEL],
+            "eof": self._verdicts[_EOF],
+            "cpu": self._cpu,
+            "handler_wakeups": self._handler_wakeups,
+            "handler_empty_wakeups": self._handler_empty_wakeups,
+        }
+
+    def close(self, timeout=10.0):
+        """Stop the thread; a handler still parked on a watched stream
+        is woken with ``_EOF`` (its connection is about to be severed)."""
+        with self._mu:
+            if self._stop:
+                return
+            self._stop = True
+            for w in self._watched.values():
+                self._disarm(w)
+                w.stream.q.put(_EOF)
+        self._poke()
+        self._thread.join(timeout=timeout)
+        with self._mu:
+            self._sel.close()
+            self._wake_r.close()
+            self._wake_w.close()
+
+    # -- selector bookkeeping (under _mu) ------------------------------------
+
+    def _poke(self):
+        try:
+            self._wake_w.send(b"\0")
+        except OSError:
+            pass
+
+    def _arm(self, w):
+        """Into the selector; the verdict to deliver if that failed."""
+        self._paused.pop(w.conn.id, None)
+        try:
+            self._sel.register(w.conn.sock, selectors.EVENT_READ, w)
+        except (ValueError, OSError):
+            # a socket that is already closed (the frontend is closing):
+            # the verdict wakes its handler
+            return self._settle(w, _EOF)
+        w.armed = True
+        if not self._live_sel:
+            self._poke()
+        return None
+
+    def _disarm(self, w):
+        self._paused.pop(w.conn.id, None)
+        if w.armed:
+            w.armed = False
+            try:
+                self._sel.unregister(w.conn.sock)
+            except (KeyError, ValueError, OSError):
+                pass
+
+    def _settle(self, w, verdict):
+        """Under ``_mu``: the connection is read no further, and an
+        ``unwatch`` from here on says what the client is owed. Returns
+        the verdict for ``_deliver``."""
+        self._disarm(w)
+        self._verdicts[verdict] += 1
+        w.owed = verdict == _CANCEL
+        return verdict
+
+    def _deliver(self, w, verdict):
+        """Outside ``_mu`` (a handler's ``unwatch`` does not wait for the
+        decode worker's condition): ``_CANCEL`` / ``_EOF`` end the
+        stream, so it is cancelled on the decode worker from here; then
+        the verdict onto the stream's queue, one wake-up of its handler.
+        If the handler took the connection out in between (its stream
+        ended first) it has answered what was owed and reads no more."""
+        if verdict is None:
+            return
+        if verdict != _LOOK:
+            self._cancel(w.stream)
+        w.stream.q.put(verdict)
+
+    # -- the thread ----------------------------------------------------------
+
+    def _loop(self):
+        while True:
+            with self._mu:
+                if self._stop:
+                    return
+                timeout = None
+                if self._paused:
+                    timeout = max(0.0, min(self._paused.values())
+                                  - time.monotonic())
+            try:
+                ready = self._sel.select(timeout)
+            except (OSError, ValueError):
+                # a watched socket closed under a select()-based selector:
+                # its handler's failed write takes it out; do not spin
+                time.sleep(self._pause)
+                ready = []
+            due = [key.data for key, _ in ready]
+            now = time.monotonic()
+            with self._mu:
+                self._wakeups += 1
+                due.extend(self._watched[cid]
+                           for cid, at in self._paused.items() if at <= now)
+            for w in due:
+                if w is None:
+                    try:
+                        self._wake_r.recv(4096)
+                    except OSError:
+                        pass
+                    continue
+                # one connection a hold of _mu: an unwatch waits for one
+                # peek and one line, not for the batch
+                with self._mu:
+                    verdict = None
+                    if (self._watched.get(w.conn.id) is w
+                            and (w.armed or w.conn.id in self._paused)
+                            and not self._stop):
+                        verdict = self._look(w)
+                self._deliver(w, verdict)
+            if _tracing.ENABLED:
+                self._cpu = time.thread_time()
+
+    def _look(self, w):
+        """Under ``_mu``, a connection that turned readable (or whose
+        pause is over): the verdict to deliver, if any."""
+        if self._tls:
+            return self._settle(w, _LOOK)
+        verdict = _read_verdict(w.conn, block=False)
+        if verdict == _PARTIAL:
+            self._disarm(w)
+            self._paused[w.conn.id] = time.monotonic() + self._pause
+            return None
+        if verdict is None:
+            # a whole line that was no cancel, consumed and ignored
+            return None if w.armed else self._arm(w)
+        return self._settle(w, verdict)
+
+
+_MSG_DONTWAIT = getattr(socket, "MSG_DONTWAIT", 0)
+_PARTIAL = "partial"   # _read_verdict: bytes, but no whole line yet
+
+
+def _peer_closed(sock):
+    """The peer has shut its side down (Linux's POLLRDHUP; elsewhere
+    False): what a peek that still finds a partial line cannot say."""
+    if not hasattr(select, "POLLRDHUP"):
+        return False
+    poller = select.poll()
+    poller.register(sock, select.POLLRDHUP)
+    return bool(poller.poll(0))
+
+
+def _read_verdict(conn, block):
+    """What a readable streaming connection holds: ``_CANCEL`` for the
+    client's in-band cancel line, ``_EOF`` when it disconnected,
+    ``_PARTIAL`` when the bytes there hold no whole line yet
+    (``readline`` would block), None for anything else. Safe
+    mid-stream: the protocol sends nothing else while a stream is in
+    flight, so a pipelined request line is protocol misuse, consumed and
+    ignored. ``block`` (TLS, on the handler's own thread: an SSL socket
+    cannot be peeked) reads the line without looking first."""
+    if not block:
+        try:
+            peek = conn.sock.recv(4096, socket.MSG_PEEK | _MSG_DONTWAIT)
+        except (BlockingIOError, InterruptedError):
+            return None
+        except (OSError, ValueError):
+            return _EOF
+        if not peek:
+            return _EOF
+        if b"\n" not in peek:
+            # (a partial line and then EOF is EOF: no newline will come)
+            return _EOF if _peer_closed(conn.sock) else _PARTIAL
+    try:
+        line = conn.rfile.readline()
+    except (OSError, ValueError):
+        return _EOF
+    if not line:
+        return _EOF
+    try:
+        msg = json.loads(line)
+    except ValueError:
+        return _EOF
+    if isinstance(msg, dict) and msg.get("method") == "cancel":
+        return _CANCEL
+    return None
+
+
 class _DecodeWorker(object):
     """The one thread that owns the SlotDecodeSession.
 
@@ -217,6 +555,7 @@ class _DecodeWorker(object):
         self._s = session
         # the frontend's account of its handler threads, for the rounds
         self._handlers = _HandlerAccount() if handlers is None else handlers
+        self.watcher = None      # the frontend's _ConnWatcher, likewise
         self._cond = lock_witness.make_condition("serving.frontend.decode")
         self._incoming = deque()
         self._cancels = deque()
@@ -329,6 +668,12 @@ class _DecodeWorker(object):
         cpu, chunks = self._handlers.totals()
         _tracing.round_count("handler_cpu", cpu)
         _tracing.round_count("handler_chunks", chunks)
+        if self.watcher is not None:
+            counts = self.watcher.counts()
+            for key in ("handler_wakeups", "handler_empty_wakeups"):
+                _tracing.round_count(key, counts.pop(key))
+            for key, n in counts.items():
+                _tracing.round_count("watcher_" + key, n)
 
     def _pass(self):
         """One pass of the worker loop. Returns ``(alive, progressed)``:
@@ -856,8 +1201,11 @@ class ServingFrontend(object):
         Bound on queued (not yet admitted) solo generate requests;
         beyond it admissions reject with ``QueueFullError``.
     stream_poll_s : float
-        Cadence at which a stream handler polls its connection for an
-        in-band cancel / EOF, idle or not.
+        Pause before the connection watcher looks again at a streaming
+        connection whose readable bytes hold no whole line yet (a
+        fragmented cancel). Nothing else runs on a cadence: a cancel
+        line or EOF is acted on as it arrives, and a handler thread
+        sleeps until its stream has something to write.
     install_signal_handlers : bool
         SIGTERM/SIGINT stop the transport and CHAIN to the previously
         installed handler — install a ``DecodeSnapshotManager``'s
@@ -892,7 +1240,14 @@ class ServingFrontend(object):
                                       max_backlog=max_stream_backlog,
                                       handlers=self._handlers)
                         if session is not None else None)
-        self._poll = float(stream_poll_s)
+        # reads every streaming connection's cancel or EOF, so that the
+        # handlers block on their streams' queues alone
+        self._watcher = None
+        if self._decode is not None:
+            self._watcher = _ConnWatcher(
+                self._decode.cancel, pause_s=stream_poll_s,
+                tls=ssl_context is not None)
+            self._decode.watcher = self._watcher
         self._mu = lock_witness.make_lock("serving.frontend.mu")
         self._closed = False
         self._counts = {}
@@ -1038,8 +1393,9 @@ class ServingFrontend(object):
     def _generate(self, req, conn):
         """Streaming dispatch: a GENERATOR the substrate drains line by
         line. Decode-worker messages flow to the socket as produced;
-        between messages the handler polls its connection for an
-        in-band cancel or EOF; a failed write surfaces as
+        between messages the handler sleeps on the stream's queue while
+        the connection watcher reads the socket for an in-band cancel
+        or EOF (``_next_event``); a failed write surfaces as
         ``GeneratorExit`` — every exit path funnels the stream into the
         worker's teardown."""
         t0 = time.monotonic()
@@ -1101,47 +1457,32 @@ class ServingFrontend(object):
             conn.state.setdefault("streams", set()).add(stream)
             with self._mu:
                 self._active_streams += 1
+            self._watcher.watch(conn, stream)
             self._decode.submit(stream)
-            poll_at = time.monotonic() + self._poll
             while True:
-                msg, verdict, poll_at = self._next_event(
-                    stream, conn, poll_at)
-                if verdict == "cancel":
-                    self._decode.cancel(stream)
-                    outcome = "cancelled"
-                    yield {"ok": True, "event": "cancelled"}
-                    return
-                if verdict == "eof":
-                    self._decode.cancel(stream)
-                    outcome = "disconnect"
-                    return
-                if msg is None:
-                    continue
-                if not msg.get("ok", False):
-                    outcome = _outcome(error_from_wire(msg))
-                    yield msg
-                    return
-                if (msg.get("event") in ("tokens", "beam")
-                        and not first_token):
-                    first_token = True
-                    if tr is not None:
-                        tr.mark("first_token")
-                    _fe_ttft.observe(
-                        time.monotonic() - t0,
-                        exemplar=(tr.id if tr is not None else None))
-                if tr is not None and msg.get("event") in ("tokens",
-                                                           "beam"):
-                    # the span brackets the substrate's write+flush of
-                    # this chunk: t1 lands when the generator resumes
-                    sp = tr.begin("wire.flush",
-                                  tokens=len(msg.get("tokens", ())))
-                    yield msg
-                    tr.end(sp)
-                    chunks += 1
-                else:
-                    yield msg
-                if msg.get("event") == "end":
-                    outcome = "ok"
+                lines, ended = self._next_event(stream, conn)
+                for msg in lines:
+                    chunk = msg.get("event") in ("tokens", "beam")
+                    if chunk and not first_token:
+                        first_token = True
+                        if tr is not None:
+                            tr.mark("first_token")
+                        _fe_ttft.observe(
+                            time.monotonic() - t0,
+                            exemplar=(tr.id if tr is not None else None))
+                    if chunk and tr is not None:
+                        # the span brackets the substrate's write+flush
+                        # of this chunk: t1 lands when the generator
+                        # resumes
+                        sp = tr.begin("wire.flush",
+                                      tokens=len(msg.get("tokens", ())))
+                        yield msg
+                        tr.end(sp)
+                        chunks += 1
+                    else:
+                        yield msg
+                if ended is not None:
+                    outcome = ended
                     return
         except GeneratorExit:
             # the substrate closed us: the client's socket died mid-
@@ -1152,6 +1493,7 @@ class ServingFrontend(object):
             raise
         finally:
             if stream is not None:
+                self._watcher.unwatch(conn)  # a failed write gets here
                 streams = conn.state.get("streams")
                 if streams is not None:
                     streams.discard(stream)
@@ -1168,60 +1510,49 @@ class ServingFrontend(object):
                 _tracing.finish(tr, outcome=outcome)
                 self._handlers.request_done(conn.id, chunks)
 
-    def _next_event(self, stream, conn, poll_at):
-        """``(message, verdict, next poll time)``: the stream's next
-        message (None when none came within the cadence) and, when the
-        cadence has come round, what ``_poll_conn`` says of the
-        connection. The connection is polled every ``stream_poll_s``
-        whether or not the stream is idle: a decode round shorter than
-        the cadence keeps the queue from ever running empty, and an
-        in-band cancel read only on an empty queue then waits for the
-        stream's last token."""
-        try:
-            msg = stream.q.get(timeout=self._poll)
-        except queue.Empty:
-            msg = None
-        now = time.monotonic()
-        if msg is not None and now < poll_at:
-            return msg, None, poll_at
-        return msg, self._poll_conn(conn), now + self._poll
+    def _next_event(self, stream, conn):
+        """Sleep until the stream has something to write: ``(lines,
+        ended)``, the wire lines to write now and, when they end the
+        stream, its outcome (else None). The decode worker's messages
+        and the connection watcher's verdict arrive on the one queue,
+        so nothing here runs on a timer.
 
-    def _poll_conn(self, conn):
-        """'cancel' when the client sent an in-band cancel line, 'eof'
-        when it disconnected, None otherwise. Safe mid-stream: the
-        protocol sends nothing else while a stream is in flight, so
-        raw-socket readability means cancel or EOF."""
-        try:
-            readable, _, _ = select.select([conn.sock], [], [], 0)
-        except (OSError, ValueError):
-            return "eof"
-        if not readable:
-            return None
-        try:
-            peek = conn.sock.recv(4096, socket.MSG_PEEK)
-        except OSError:
-            return "eof"
-        if not peek:
-            return "eof"
-        if b"\n" not in peek:
-            # a partial line (fragmented cancel, or a stalled client
-            # trickling bytes): readline would BLOCK the handler
-            # thread with no timeout — keep streaming and poll again
-            return None
-        try:
-            line = conn.rfile.readline()
-        except OSError:
-            return "eof"
-        if not line:
-            return "eof"
-        try:
-            msg = json.loads(line)
-        except ValueError:
-            return "eof"
-        if msg.get("method") == "cancel":
-            return "cancel"
-        return None  # pipelined mid-stream request: protocol misuse,
-        #              ignored (the line is consumed)
+        Before a terminal line is handed out the connection is taken
+        out of the watcher: the client may send its next request the
+        moment it has read that line, and the watcher must not be the
+        one to read it. If the watcher had consumed a cancel line that
+        no ``cancelled`` event has answered (the stream ended, or
+        failed, while the cancel was in flight), the one ack follows
+        the terminal line here, as the substrate's idle-cancel answer
+        would have had the line stayed in the socket."""
+        while True:
+            msg = stream.q.get()
+            stream.wakeups += 1
+            if msg == _LOOK:
+                # TLS: the bytes are read on the thread that writes
+                msg = _read_verdict(conn, block=True)
+                if msg is None:
+                    stream.empty_wakeups += 1
+                    self._watcher.rearm(conn)
+                    continue
+                self._decode.cancel(stream)
+            if msg == _CANCEL:
+                self._watcher.unwatch(conn)
+                return [{"ok": True, "event": "cancelled"}], "cancelled"
+            if msg == _EOF:
+                self._watcher.unwatch(conn)
+                return [], "disconnect"
+            if not msg.get("ok", False):
+                ended = _outcome(error_from_wire(msg))
+            elif msg.get("event") == "end":
+                ended = "ok"
+            else:
+                return [msg], None
+            lines = [msg]
+            if self._watcher.unwatch(conn):
+                lines.append({"ok": True, "event": "cancelled",
+                              "idle": True})
+            return lines, ended
 
     def _take_result(self, req):
         t0 = time.monotonic()
@@ -1265,8 +1596,8 @@ class ServingFrontend(object):
         router's failover/drain splice endpoint. The first event is
         ``resumed`` replaying the request's tokens from absolute
         position 1; after that the stream behaves exactly like
-        ``generate`` (the same consume loop, cancel/EOF polling and
-        teardown discipline)."""
+        ``generate`` (the same ``_next_event``, watched for cancel/EOF
+        alike, the same teardown discipline)."""
         t0 = time.monotonic()
         outcome = "error"
         stream = None
@@ -1288,29 +1619,14 @@ class ServingFrontend(object):
             conn.state.setdefault("streams", set()).add(stream)
             with self._mu:
                 self._active_streams += 1
+            self._watcher.watch(conn, stream)
             self._decode.submit(stream)
-            poll_at = time.monotonic() + self._poll
             while True:
-                msg, verdict, poll_at = self._next_event(
-                    stream, conn, poll_at)
-                if verdict == "cancel":
-                    self._decode.cancel(stream)
-                    outcome = "cancelled"
-                    yield {"ok": True, "event": "cancelled"}
-                    return
-                if verdict == "eof":
-                    self._decode.cancel(stream)
-                    outcome = "disconnect"
-                    return
-                if msg is None:
-                    continue
-                if not msg.get("ok", False):
-                    outcome = _outcome(error_from_wire(msg))
+                lines, ended = self._next_event(stream, conn)
+                for msg in lines:
                     yield msg
-                    return
-                yield msg
-                if msg.get("event") == "end":
-                    outcome = "ok"
+                if ended is not None:
+                    outcome = ended
                     return
         except GeneratorExit:
             outcome = "disconnect"
@@ -1319,6 +1635,7 @@ class ServingFrontend(object):
             raise
         finally:
             if stream is not None:
+                self._watcher.unwatch(conn)
                 streams = conn.state.get("streams")
                 if streams is not None:
                     streams.discard(stream)
@@ -1460,6 +1777,9 @@ class ServingFrontend(object):
                 "health": s.health,
                 "prefix": s.prefix_cache_stats(),
             }
+            # the connection watcher's counts and the handlers'
+            # wake-ups (docs/OBSERVABILITY.md)
+            out["watcher"] = self._watcher.counts()
         return out
 
     # -- lifecycle -----------------------------------------------------------
@@ -1488,6 +1808,10 @@ class ServingFrontend(object):
                     if not self._active_streams:
                         break
                 time.sleep(0.01)
+        if self._watcher is not None:
+            # after the drain (a cancel is still read during it); wakes
+            # whatever handler is still parked on its stream's queue
+            self._watcher.close()
         self._sync_io()
         srv, self._json_server = self._json_server, None
         close_json_server(srv)

@@ -661,7 +661,8 @@ class ServingRouter(object):
 
     def _poll_downstream(self, conn):
         """'cancel' / 'eof' / None for the CLIENT-side connection —
-        the frontend's ``_poll_conn`` discipline, one tier up."""
+        what the frontend's ``_read_verdict`` reads, one tier up and still
+        polled by the relay's own thread (ROADMAP D15)."""
         try:
             readable, _, _ = select.select([conn.sock], [], [], 0)
         except (OSError, ValueError):

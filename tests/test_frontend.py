@@ -745,43 +745,540 @@ def test_a_decoder_only_session_behind_the_worker_cancels_with_none():
     assert sess.pool_conserved and sess.pages_in_use == 0
 
 
-def test_inband_cancel_is_read_while_chunks_outrun_the_poll_cadence():
-    """A stream whose chunks come faster than ``stream_poll_s`` (a decode
-    round of 44 ms against the cadence of 50) never finds its queue empty:
-    the handler still polls the connection once a cadence, so the client's
-    in-band cancel is read then and not after the stream's last token."""
-    import queue as queue_mod
-    from types import SimpleNamespace
+# ---------------------------------------------------------------------------
+# the connection watcher: who reads a cancel or EOF while a stream is in flight
+# ---------------------------------------------------------------------------
 
-    fe = ServingFrontend.__new__(ServingFrontend)
-    fe._poll = 0.05
-    ours, theirs = socket.socketpair()
+def _until(cond, timeout=30.0):
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
+class _Wired(object):
+    """The frontend's consume path on socket pairs, with no session: a real
+    ``_ConnWatcher`` whose cancels are recorded, ``n`` connections as the
+    substrate hands them to a dispatcher, one watched stream each. The
+    test plays the decode worker (puts to a stream's queue), the client
+    (writes to ``clients[i]``) and the handler (``next_event(i)``)."""
+
+    def __init__(self, n=1, pause_s=0.05):
+        from types import SimpleNamespace
+
+        from paddle_tpu.serving.frontend import _ConnWatcher, _Stream
+
+        self.cancelled = []
+        self.fe = ServingFrontend.__new__(ServingFrontend)
+        self.fe._decode = SimpleNamespace(cancel=self.cancelled.append)
+        self.watcher = self.fe._watcher = _ConnWatcher(
+            self.cancelled.append, pause_s=pause_s)
+        self.conns, self.clients, self.streams = [], [], []
+        for i in range(n):
+            ours, theirs = socket.socketpair()
+            self.conns.append(SimpleNamespace(
+                id=i + 1, sock=ours, rfile=ours.makefile("rb")))
+            self.clients.append(theirs)
+            self.streams.append(_Stream({}))
+            self.watcher.watch(self.conns[i], self.streams[i])
+
+    def next_event(self, i=0):
+        return self.fe._next_event(self.streams[i], self.conns[i])
+
+    def close(self):
+        self.watcher.close()
+        for conn, theirs in zip(self.conns, self.clients):
+            conn.rfile.close()
+            conn.sock.close()
+            theirs.close()
+
+
+_CHUNK = {"ok": True, "event": "tokens", "tokens": [7]}
+_END = {"ok": True, "event": "end"}
+_ACK = {"ok": True, "event": "cancelled"}
+_IDLE_ACK = {"ok": True, "event": "cancelled", "idle": True}
+_CANCEL_LINE = b'{"method": "cancel"}\n'
+
+
+def test_inband_cancel_is_read_while_the_queue_never_runs_empty():
+    """A stream whose chunks come faster than its handler drains them (a
+    decode round of 44 ms against the old poll cadence of 50) never finds
+    its queue empty. The cancel is not the handler's to find any more:
+    the watcher reads it as it arrives, cancels the stream on the decode
+    worker and posts the verdict behind the chunks already queued, so it
+    is answered then and not after the stream's last token."""
+    w = _Wired()
+    stop = threading.Event()
+
+    def decode_worker():
+        while not stop.is_set():
+            if w.streams[0].q.qsize() < 2:
+                w.streams[0].q.put(_CHUNK)
+            time.sleep(0.002)
+
+    feeder = threading.Thread(target=decode_worker)
+    feeder.start()
     try:
-        conn = SimpleNamespace(sock=ours, rfile=ours.makefile("rb"))
-        stream = SimpleNamespace(q=queue_mod.Queue())
-        chunk = {"ok": True, "event": "tokens", "tokens": [7]}
-        stream.q.put(chunk)
-        poll_at = time.monotonic() + fe._poll
-        # inside the cadence a waiting chunk is handed on, unpolled
-        msg, verdict, poll_at = fe._next_event(stream, conn, poll_at)
-        assert msg == chunk and verdict is None
-        theirs.sendall(b'{"method": "cancel"}\n')
-        verdicts = []
+        assert w.next_event() == ([_CHUNK], None)
+        w.clients[0].sendall(_CANCEL_LINE)
         t0 = time.monotonic()
-        while time.monotonic() - t0 < 2.0 and "cancel" not in verdicts:
-            stream.q.put(chunk)  # the queue never runs empty
-            msg, verdict, poll_at = fe._next_event(stream, conn, poll_at)
-            assert msg == chunk
-            verdicts.append(verdict)
+        lines, ended = w.next_event()
+        while ended is None and time.monotonic() - t0 < 2.0:
+            assert lines == [_CHUNK]  # the queue never ran empty
             time.sleep(0.005)
-        assert verdicts[-1] == "cancel", verdicts
-        assert time.monotonic() - t0 < 4 * fe._poll
-        # an idle stream polls as before: nothing to read, no verdict
-        msg, verdict, poll_at = fe._next_event(stream, conn, poll_at)
-        assert msg is None and verdict is None
+            lines, ended = w.next_event()
+        assert (lines, ended) == ([_ACK], "cancelled")
+        assert time.monotonic() - t0 < 0.5
+        # cancelled on the worker by the watcher, taken out by the handler
+        assert w.cancelled == [w.streams[0]]
+        counts = w.watcher.counts()
+        assert (counts["cancel"], counts["eof"]) == (1, 0)
+        assert counts["watching"] == 0
+        assert counts["handler_empty_wakeups"] == 0
+        # (folded in as the handler took its connection out)
+        assert counts["handler_wakeups"] == w.streams[0].wakeups > 1
     finally:
-        ours.close()
-        theirs.close()
+        stop.set()
+        feeder.join()
+        w.close()
+
+
+@pytest.mark.parametrize("terminal", [
+    _END, error_to_wire(ServingError("decode failed"))],
+    ids=["end", "error"])
+@pytest.mark.parametrize("cancel", ["consumed", "unread", "none"])
+def test_the_hand_back_answers_a_cancel_exactly_once(terminal, cancel):
+    """The stream's own terminal event races the client's cancel. The
+    handler takes the connection out of the watcher BEFORE it writes the
+    terminal line; a cancel line the watcher had consumed by then is
+    answered by the handler, once, right after that line (the idle ack
+    the substrate would have given), and one it had not is left in the
+    socket for the substrate, as is the next request's line: the watcher
+    never reads a connection that was handed back."""
+    w = _Wired()
+    try:
+        w.streams[0].q.put(dict(terminal))  # the stream ended first
+        if cancel == "consumed":
+            w.clients[0].sendall(_CANCEL_LINE)
+            assert _until(lambda: w.watcher.counts()["cancel"] == 1)
+        lines, ended = w.next_event()
+        assert ended == ("ok" if terminal is _END else "error")
+        assert lines == ([terminal, _IDLE_ACK] if cancel == "consumed"
+                         else [terminal])
+        counts = w.watcher.counts()
+        assert counts["watching"] == 0
+        # what the client sends now is the substrate's to read
+        sent = []
+        if cancel == "unread":
+            sent.append(_CANCEL_LINE)
+        sent.append(b'{"method": "health"}\n')
+        for line in sent:
+            w.clients[0].sendall(line)
+        time.sleep(0.1)
+        assert w.watcher.counts()["wakeups"] == counts["wakeups"]
+        assert [w.conns[0].rfile.readline() for _ in sent] == sent
+        # the verdict the watcher posted is in a queue nobody reads
+        assert w.cancelled == ([w.streams[0]] if cancel == "consumed"
+                               else [])
+        assert w.watcher.unwatch(w.conns[0]) is False  # idempotent
+    finally:
+        w.close()
+
+
+@pytest.mark.parametrize("tail", [b'cel"}\n', b"", b'ned"}\n'],
+                         ids=["cancel", "eof", "other"])
+def test_a_partial_line_neither_blocks_nor_spins_the_watcher(tail):
+    """Readable bytes with no newline yet (a cancel line sent in two
+    fragments, a trickling client): ``readline`` would block the watcher
+    and a level-triggered selector would spin it, so the socket is taken
+    out and looked at again after ``stream_poll_s``. Meanwhile the
+    handler sleeps, and another connection's cancel is read at once."""
+    w = _Wired(n=2, pause_s=0.05)
+    try:
+        t0 = time.monotonic()
+        w.clients[0].sendall(b'{"method": "can')
+        time.sleep(0.3)
+        counts = w.watcher.counts()
+        # one a pause, not one a spin of the selector
+        assert 1 <= counts["wakeups"] <= 2 + (time.monotonic() - t0) / 0.05
+        assert (counts["cancel"], counts["eof"]) == (0, 0)
+        assert counts["watching"] == 2
+        assert w.streams[0].q.empty() and not w.cancelled
+        w.clients[1].sendall(_CANCEL_LINE)       # not behind the partial
+        assert w.next_event(1) == ([_ACK], "cancelled")
+        if tail:
+            w.clients[0].sendall(tail)
+        else:
+            w.clients[0].shutdown(socket.SHUT_WR)
+        if tail.startswith(b"ned"):
+            # a whole line that is no cancel: consumed and ignored, the
+            # stream goes on and the connection is watched as before
+            w.streams[0].q.put(_CHUNK)
+            assert w.next_event(0) == ([_CHUNK], None)
+            assert _until(lambda: not w.watcher._paused)
+            w.clients[0].sendall(_CANCEL_LINE)
+        assert w.next_event(0) == (
+            ([_ACK], "cancelled") if tail else ([], "disconnect"))
+        assert w.cancelled == [w.streams[1], w.streams[0]]
+        counts = w.watcher.counts()
+        assert counts["cancel"] + counts["eof"] == 2
+        assert counts["eof"] == (0 if tail else 1)
+        assert counts["handler_empty_wakeups"] == 0
+    finally:
+        w.close()
+
+
+def _gated(sess):
+    """Hold the decode worker inside its step dispatch until the returned
+    event is set: the streams it serves are in flight and idle."""
+    gate = threading.Event()
+    step = sess.step
+    sess.step = lambda: (gate.wait(60), step())[1]
+    return gate
+
+
+@pytest.mark.parametrize("how", ["eof", "kill"])
+def test_a_client_gone_mid_stream_is_read_by_the_watcher(trained, how):
+    """The client closes its socket (``eof``) or is killed (``kill``: the
+    close sends a reset) while its stream is in flight and its handler
+    asleep on the queue: the watcher reads it, the stream is cancelled on
+    the worker and observed as ``disconnect``; slot and pages are free
+    when the worker's next pass has run."""
+    import struct
+
+    src = trained["src"]
+    sess = _paged(trained)
+    with ServingFrontend(session=sess) as fe:
+        cl = ServingClient(fe.address)
+        cl.generate_full(src[0], src_len=SEQ)   # the compiles, not the race
+        gate = _gated(sess)
+        try:
+            gen = cl.generate(src[2], src_len=SEQ)
+            assert [next(gen)["event"], next(gen)["event"]] == [
+                "queued", "admitted"]
+            assert _until(
+                lambda: fe._watcher.counts()["watching"] == 1)
+            if how == "kill":
+                cl._sock.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                                    struct.pack("ii", 1, 0))
+            cl.close()
+            # read while the worker is still inside its step
+            assert _until(lambda: fe._watcher.counts()["eof"] == 1)
+            assert sess.active_slots
+        finally:
+            gate.set()
+        assert _drained(sess)
+        assert sess.free_slots == S and sess.pool_conserved
+        assert sess.free_pages == sess._P - 1 - sess.cached_pages
+        assert _until(lambda: fe.stats()["active_streams"] == 0)
+        stats = fe.stats()
+        assert stats["requests"]["generate"] == {"ok": 1, "disconnect": 1}
+        assert stats["watcher"]["watching"] == 0
+        assert stats["watcher"]["cancel"] == 0
+        assert stats["watcher"]["handler_empty_wakeups"] == 0
+
+
+def _parked_streams(fe, src, n):
+    """``n`` client threads, each consuming one stream to its end (into
+    ``got``, or its exception into ``errors``)."""
+    got, errors = {}, {}
+
+    def consume(i):
+        cl = ServingClient(fe.address, timeout_s=60.0)
+        try:
+            got[i] = cl.generate_full(src[i], src_len=SEQ)
+        except Exception as exc:  # noqa: BLE001 - asserted by the caller
+            errors[i] = exc
+        finally:
+            cl.close()
+
+    threads = [threading.Thread(target=consume, args=(i,))
+               for i in range(n)]
+    for t in threads:
+        t.start()
+    return threads, got, errors
+
+
+def test_idle_streams_wake_nobody_and_the_gauge_follows_them(trained):
+    """Six streams in flight for a second with nothing to write (four
+    admitted, the worker held inside their step; two behind them): no
+    handler thread wakes, the watcher does not wake, its gauge reads
+    six; when they have ended it reads none. (The polling handlers woke
+    twenty times a second for every open stream.)"""
+    src = trained["src"]
+    sess = _paged(trained)
+    with ServingFrontend(session=sess) as fe:
+        ServingClient(fe.address).generate_full(src[0], src_len=SEQ)
+        gate = _gated(sess)
+        try:
+            threads, got, errors = _parked_streams(fe, src, 6)
+            assert _until(lambda: fe._watcher.counts()["watching"] == 6)
+            time.sleep(0.3)   # the first pass's events are written
+
+            def woken():   # (a live stream's are on the stream)
+                streams = [w.stream
+                           for w in list(fe._watcher._watched.values())]
+                return (sum(st.wakeups for st in streams),
+                        sum(st.empty_wakeups for st in streams))
+
+            before, handlers = fe._watcher.counts(), woken()
+            time.sleep(1.0)
+            assert woken() == handlers and handlers[1] == 0
+            after = fe.stats()["watcher"]
+            assert after == before
+            assert after["watching"] == 6
+        finally:
+            gate.set()
+        for t in threads:
+            t.join(timeout=60)
+        assert not errors and sorted(got) == list(range(6))
+        assert _until(lambda: fe._watcher.counts()["watching"] == 0)
+        done = fe._watcher.counts()
+        assert done["handler_wakeups"] > before["handler_wakeups"]
+        assert (done["cancel"], done["eof"]) == (0, 0)
+        assert done["handler_empty_wakeups"] == 0
+        assert _drained(sess)
+
+
+def test_close_joins_the_watcher_and_wakes_every_parked_handler(trained):
+    """``close`` with a decode worker that cannot answer (held inside its
+    step past the join's timeout): the handlers parked on their streams'
+    queues are woken by the watcher as it stops, their connections are
+    severed, no client hangs, and the watcher's thread is joined."""
+    src = trained["src"]
+    sess = _paged(trained)
+    fe = ServingFrontend(session=sess)
+    ServingClient(fe.address).generate_full(src[0], src_len=SEQ)
+    gate = _gated(sess)
+    try:
+        threads, got, errors = _parked_streams(fe, src, 3)
+        assert _until(lambda: fe._watcher.counts()["watching"] == 3)
+        fe.close(drain=False, timeout=0.5)
+        assert not fe._watcher._thread.is_alive()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+        assert not got and sorted(errors) == [0, 1, 2]
+        assert all(isinstance(exc, (ServerClosedError, StreamBrokenError,
+                                    ConnectionError, OSError))
+                   for exc in errors.values()), errors
+        assert _until(lambda: fe.stats()["active_streams"] == 0)
+        assert fe._watcher.counts()["watching"] == 0
+    finally:
+        gate.set()
+    fe._decode._thread.join(timeout=30)
+    assert _drained(sess)
+    fe.close()   # idempotent, the watcher included
+
+
+def test_an_attached_stream_is_watched_too(trained):
+    """``attach`` shares ``generate``'s consume loop: its connection is in
+    the watcher while the stream is in flight, its cancel is read there,
+    answered once, and the connection serves the next request."""
+    src = trained["src"]
+    sess = _paged(trained)
+    with ServingFrontend(session=sess) as fe:
+        cl = ServingClient(fe.address)
+        cl.generate_full(src[0], src_len=SEQ)
+        try:
+            chaos.configure("slow@site=serve.dispatch,p=1.0,secs=0.3")
+            rid = fe._decode.call(lambda: sess.enqueue(src[5], SEQ))
+            assert _until(lambda: rid in sess._owner.values())
+            cl._send_line({"method": "attach", "id": int(rid)})
+            first = cl._recv_line()
+            assert first["event"] == "resumed" and not first["finished"]
+            assert fe._watcher.counts()["watching"] == 1
+            cl._send_line({"method": "cancel"})
+            events = [cl._recv_line()]
+            while events[-1].get("event") != "cancelled":
+                events.append(cl._recv_line())
+        finally:
+            chaos.disable()
+        assert events[-1] == _ACK
+        assert all(ev["event"] == "tokens" for ev in events[:-1])
+        assert _drained(sess)
+        assert cl.generate_full(src[1], src_len=SEQ).shape == (1, SEQ)
+        cl.close()
+        stats = fe.stats()
+        assert stats["requests"]["attach"] == {"cancelled": 1}
+        assert (stats["watcher"]["cancel"], stats["watcher"]["eof"]) \
+            == (1, 0)
+        assert stats["watcher"]["watching"] == 0
+
+
+def _tls_contexts(tmp_path):
+    """A self-signed server context and the client context that trusts it."""
+    import datetime
+    import ipaddress
+    import ssl
+
+    x509 = pytest.importorskip("cryptography.x509")
+    from cryptography.hazmat.primitives import hashes, serialization
+    from cryptography.hazmat.primitives.asymmetric import ec
+
+    key = ec.generate_private_key(ec.SECP256R1())
+    name = x509.Name([x509.NameAttribute(
+        x509.oid.NameOID.COMMON_NAME, "localhost")])
+    now = datetime.datetime.now(datetime.timezone.utc)
+    cert = (x509.CertificateBuilder()
+            .subject_name(name).issuer_name(name)
+            .public_key(key.public_key())
+            .serial_number(x509.random_serial_number())
+            .not_valid_before(now - datetime.timedelta(days=1))
+            .not_valid_after(now + datetime.timedelta(days=1))
+            .add_extension(x509.SubjectAlternativeName([x509.IPAddress(
+                ipaddress.ip_address("127.0.0.1"))]), critical=False)
+            .sign(key, hashes.SHA256()))
+    cert_pem, key_pem = str(tmp_path / "cert.pem"), str(tmp_path / "key.pem")
+    with open(cert_pem, "wb") as f:
+        f.write(cert.public_bytes(serialization.Encoding.PEM))
+    with open(key_pem, "wb") as f:
+        f.write(key.private_bytes(
+            serialization.Encoding.PEM, serialization.PrivateFormat.PKCS8,
+            serialization.NoEncryption()))
+    server = ssl.SSLContext(ssl.PROTOCOL_TLS_SERVER)
+    server.load_cert_chain(cert_pem, key_pem)
+    client = ssl.SSLContext(ssl.PROTOCOL_TLS_CLIENT)
+    client.load_verify_locations(cert_pem)
+    return server, client
+
+
+@pytest.mark.parametrize("how", ["cancel", "eof"])
+def test_a_tls_stream_is_cancelled_by_its_own_handler(trained, tmp_path,
+                                                      how):
+    """An SSL socket cannot be peeked, and is not safe to read on one
+    thread while another writes: on a TLS frontend the watcher only says
+    that bytes came (the raw socket turned readable) and the stream's
+    handler, woken once, reads the line on the thread that writes. The
+    cancel is answered with the stream's own ``cancelled`` and frees the
+    slot; the connection serves the next request. (The polling handler
+    peeked, which an SSL socket refuses: an untyped error line, and the
+    slot decoded on to its stream's natural end.)"""
+    src = trained["src"]
+    sess = _paged(trained)
+    server_ctx, client_ctx = _tls_contexts(tmp_path)
+    with ServingFrontend(session=sess, ssl_context=server_ctx) as fe:
+        cl = ServingClient(fe.address, ssl_context=client_ctx)
+        cl.generate_full(src[0], src_len=SEQ)
+        gate = _gated(sess)
+        try:
+            gen = cl.generate(src[2], src_len=SEQ)
+            assert [next(gen)["event"], next(gen)["event"]] == [
+                "queued", "admitted"]
+            assert _until(
+                lambda: fe._watcher.counts()["watching"] == 1)
+            if how == "cancel":
+                gen.close()   # sends the cancel line, reads to the ack
+            else:
+                cl.close()
+            outcome = {"cancel": "cancelled", "eof": "disconnect"}[how]
+            assert _until(lambda: (fe.stats()["requests"]["generate"]
+                                   == {"ok": 1, outcome: 1}))
+            assert sess.active_slots   # the worker is still in its step
+        finally:
+            gate.set()
+        assert _drained(sess)
+        if how == "cancel":
+            assert cl.generate_full(src[1], src_len=SEQ).shape == (1, SEQ)
+            cl.close()
+        counts = fe.stats()["watcher"]
+        assert counts["watching"] == 0
+        assert counts["handler_empty_wakeups"] == 0
+
+
+def _race_cancels(cl, src, rounds, out):
+    """One connected client: ``rounds`` streams, each cancelled after 0 to 5 of its
+    chunks have been read (the last of them with the stream's own ``end``
+    already behind them). Every cancel must bring exactly ONE
+    ``cancelled`` back, the stream's own or an ack behind its ``end``, and
+    the very next line on the connection must answer the next request.
+    ``out``: how many went each way, or the failure."""
+    request = {"method": "generate", "n": 1, "src_len": SEQ,
+               "src": encode_array(src.astype("int64"))}
+    by_stream = by_ack = 0
+    try:
+        for i in range(rounds):
+            cl._send_line(request)
+            chunks, ended = 0, False
+            while chunks < i % 6 and not ended:
+                ev = cl._recv_line()
+                assert ev["ok"], ev
+                chunks += ev["event"] == "tokens"
+                ended = ev["event"] == "end"
+            cl._send_line({"method": "cancel"})
+            ev = cl._recv_line()
+            while ev.get("event") != "cancelled":
+                assert ev["ok"] and ev["event"] in (
+                    "queued", "admitted", "tokens", "end"), ev
+                ended |= ev["event"] == "end"
+                ev = cl._recv_line()
+            # a stream's own ``cancelled`` ends it; an ack follows an end
+            assert ev == (_IDLE_ACK if ended else _ACK), (i, ev)
+            by_ack += ended
+            by_stream += not ended
+            # in step: the next line is the next request's answer
+            cl._send_line({"method": "health"})
+            assert "health" in cl._recv_line(), i
+        out.append((by_stream, by_ack))
+    except BaseException as exc:  # noqa: BLE001 - reported by the test
+        out.append(exc)
+        raise
+    finally:
+        cl.close()
+
+
+@pytest.mark.parametrize("clients,rounds", [(1, 300), (16, 25)])
+def test_a_cancel_racing_the_end_is_answered_exactly_once(trained, clients,
+                                                          rounds):
+    """Over real sockets and a real session, a few hundred cancels that
+    race their streams' ends: however each race resolves (the handler's
+    terminal ``cancelled``; ``end`` and then the ack the handler owed;
+    ``end`` and then the substrate's idle ack) the client reads exactly
+    one ``cancelled`` and its connection stays in step. With sixteen
+    clients (more threads than cores, a short switch interval) the
+    watcher, sixteen handlers and the worker share the hand-back's lock."""
+    src = trained["src"]
+    sess = _paged(trained, sampler=None)
+    interval = sys.getswitchinterval()
+    with ServingFrontend(session=sess) as fe:
+        ServingClient(fe.address).generate_full(src[1], src_len=SEQ)
+        out = []
+        # connected one by one: the substrate listens with a backlog of 5
+        conns = [ServingClient(fe.address, timeout_s=60.0)
+                 for _ in range(clients)]
+        for cl in conns:
+            cl._send_line({"method": "health"})
+            assert "health" in cl._recv_line()
+        threads = [threading.Thread(target=_race_cancels,
+                                    args=(cl, src[1], rounds, out))
+                   for cl in conns]
+        try:
+            if clients > 1:
+                sys.setswitchinterval(1e-5)
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=240)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert len(out) == clients
+        assert not [exc for exc in out if isinstance(exc, BaseException)]
+        by_stream = sum(n for n, _ in out)
+        by_ack = sum(n for _, n in out)
+        assert _drained(sess)
+        assert _until(lambda: fe.stats()["active_streams"] == 0)
+        stats = fe.stats()
+        assert by_stream + by_ack == clients * rounds
+        assert by_stream and by_ack
+        assert stats["requests"]["generate"] == {
+            "ok": 1 + by_ack, "cancelled": by_stream}
+        # the cancels the watcher read: every stream's own, and those of
+        # the acks that the handlers owed (the substrate gave the others)
+        assert by_stream <= stats["watcher"]["cancel"] <= clients * rounds
+        assert stats["watcher"]["eof"] == 0
+        assert stats["watcher"]["watching"] == 0
+        assert stats["watcher"]["handler_empty_wakeups"] == 0
 
 
 def _oracle_after_cancel(oracle, src):

@@ -418,6 +418,7 @@ def served(trained):
                "read_tokens": read_tokens, "read_chunks": read_chunks,
                "handlers_open": dict(fe._handlers.open),
                "handlers_total": fe._handlers.totals(),
+               "watcher": fe._watcher.counts(),
                "was_live": was_live,
                "cancelled": cancelled, "drained": sess.free_slots == S}
     finally:
@@ -513,7 +514,7 @@ def test_round_counts_equal_what_the_session_did(served):
                           "admit_rows", "admit_pad_rows",
                           "cancel_dispatches", "cancel_rows",
                           "cancel_pad_rows", "handler_cpu",
-                          "handler_chunks"}
+                          "handler_chunks"} | set(_WATCHER_KEYS)
                          if i == 0 else set()), sp
 
 
@@ -534,6 +535,41 @@ def test_round_roots_hold_the_handler_threads_account(served):
     total_cpu, total_chunks = served["handlers_total"]
     assert total_chunks == served["read_chunks"] > 0
     assert chunks[-1] <= total_chunks and cpu[-1] <= total_cpu
+
+
+_WATCHER_KEYS = ("handler_wakeups", "handler_empty_wakeups",
+                 "watcher_watching", "watcher_wakeups", "watcher_cancel",
+                 "watcher_eof", "watcher_cpu")
+
+
+def test_round_roots_hold_the_connection_watchers_counts(served):
+    """Beside the handlers' account every round's root holds the
+    connection watcher's counts as they stood at its end: the handlers'
+    wake-ups (each brought a message: none came back empty, and a handler
+    that polled would have counted more wake-ups than events), the
+    connections watched (the scenario's one, while a stream of its was in
+    flight), the watcher's own wake-ups, verdicts and CPU. The scenario's
+    clients read their streams to the end and its cancelled stream was
+    handed to the worker directly: the watcher had nothing to read."""
+    roots = [r["spans"][0] for r in served["rounds"]]
+    assert all(key in root for root in roots for key in _WATCHER_KEYS)
+    for key in _WATCHER_KEYS:
+        if key != "watcher_watching":
+            seen = [root[key] for root in roots]
+            assert seen == sorted(seen) and seen[0] >= 0, key
+    assert {root["watcher_watching"] for root in roots} <= {0, 1}
+    assert any(root["watcher_watching"] == 1 for root in roots)
+    assert all(root["handler_empty_wakeups"] == 0 for root in roots)
+    assert all(root["watcher_cancel"] == root["watcher_eof"] == 0
+               for root in roots)
+    final = served["watcher"]
+    assert final["watching"] == 0
+    assert roots[-1]["handler_wakeups"] <= final["handler_wakeups"]
+    # a wake-up an event written: the four streams' chunks and their
+    # queued / admitted / end lines, and nothing else
+    assert final["handler_wakeups"] == served["read_chunks"] + 3 * 3 + 2
+    assert (final["cancel"], final["eof"],
+            final["handler_empty_wakeups"]) == (0, 0, 0)
 
 
 def test_two_connections_leave_two_keys_that_fold_as_they_close(trained):
