@@ -2,107 +2,19 @@
 and routed experts behind the same ``ServingFrontend``, wire and clients
 as entry ``frontend``: a ``DecoderOnlySession``
 (``serving/decoder_session.py``) over a paged pool of latent rows, prompts
-prefilled several a dispatch."""
+prefilled several a dispatch. The run is ``decoder_family``'s; the
+model's own parts are ``serve_glm_common``'s."""
 
-import os
-import time
-
-# the program's modules this entry needs, BEFORE anything is started: a
-# program that lacks them (the parent of the PR that brought them) fails
-# here, at once, and leaves no child process behind
+# a program that lacks these fails here, at once, with no child started
 from paddle_tpu.kernels import latent_attention  # noqa: F401
 from paddle_tpu.serving.decoder_session import DecoderOnlySession  # noqa: F401
 
-from perfbench import harness, loadgen, serve_common, serve_glm_common
+from perfbench import decoder_family, serve_glm_common as common
 
 
 def run(ctx):
-    import paddle_tpu as fluid
-    from paddle_tpu.observability import tracing
-
-    cell, setup, cfg, traffic = ctx.cell, ctx.setup, ctx.cell.config, \
-        ctx.cell.traffic
-    devices = ctx.devices[:1]
-    place = fluid.TPUPlace() if devices[0].platform != "cpu" \
-        else fluid.CPUPlace()
-    # the child imports and plans while the server builds
-    client = serve_glm_common.Client(
-        cell, traffic, ctx.seed, ctx.seconds,
-        os.path.join(ctx.out_dir, "loadgen.json"))
-    try:
-        server = serve_glm_common.Server(cell, ctx.seed, place, setup)
-        try:
-            numbers = serve_glm_common.Checker(cell, server).numbers(ctx.seed)
-            correct = serve_glm_common.verdict(numbers,
-                                               cfg["check"]["limits"])
-            setup.part("reference_check")
-            server.warm()
-            ctx.steady()
-            setup.part("warmup_dispatches")
-            cache = ctx.cache_stats()
-            server.instrument()
-            if ctx.trace:
-                tracing.enable(True)
-            server.start(traffic.get("max_stream_backlog", 4096))
-            setup.part("frontend_start")
-
-            def opened(t_open):
-                setup.part("ramp")
-                ctx.window_opened(time.perf_counter()
-                                  - (time.time() - t_open))
-
-            summary, _records, host = serve_common.drive(
-                server, cell, traffic, ctx.seed, ctx.seconds, ctx.out_dir,
-                client=client, on_open=opened,
-                profiler=ctx.profiler if ctx.trace else None)
-        finally:
-            tracing.enable(False)
-            server.close()
-    finally:
-        client.kill()
-
-    sess = server.session
-    drained = bool(sess.pool_conserved and not sess.active_slots)
-    harness.log("check pool conserved after the run: %s (limit True) %s"
-                % (drained, "ok" if drained else "NOT CORRECT"))
-    n = summary["attempted"]
-    harness.log("requests due in the window %d, failed %d; tokens "
-                "delivered in the window %.1f -> serve_tokens_per_s %.1f"
-                % (n, summary["failed"], summary["tokens_in_window"],
-                   summary["tokens_per_s"]))
-    for name in ("ttft_ms", "tpot_ms"):
-        vals = summary[name]
-        if vals:
-            harness.log("%s over %d requests (not judged above the knee): "
-                        "median %.3f, p95 %.3f"
-                        % (name, len(vals), loadgen.percentile(vals, 50),
-                           loadgen.percentile(vals, 95)))
-    steps = host["step"]
-    if steps:
-        harness.log("decode dispatches in the window %d: %d slots live in "
-                    "the median, %d resident rows; prefill dispatches %d "
-                    "for %d prompts"
-                    % (len(steps),
-                       loadgen.percentile([s[2][0] for s in steps], 50),
-                       loadgen.percentile([s[2][1] for s in steps], 50),
-                       sum(len(a[2]) for a in host["admit"]),
-                       sum(len(p[1]) for a in host["admit"] for p in a[2])))
-    return {
-        "correct": bool(correct and drained),
-        "attempted": n, "failed": summary["failed"],
-        "end_to_end": {"serve_tokens_per_s": summary["tokens_per_s"]},
-        "cache": cache, "devices": devices,
-        "serve": {"summary": summary, "host": host,
-                      "seconds": ctx.seconds,
-                      "traced_s": ctx.profiler.window_s or 0.0,
-                      "geometry": sess.geometry},
-    }
+    return decoder_family.run(ctx, common)
 
 
 def make_checker(cell, devices):
-    import paddle_tpu as fluid
-
-    server = serve_glm_common.Server(
-        cell, 0, fluid.TPUPlace() if devices[0].platform != "cpu"
-        else fluid.CPUPlace(), harness.Setup(time.perf_counter()))
-    return serve_glm_common.Checker(cell, server)
+    return decoder_family.make_checker(cell, devices, common)

@@ -2,7 +2,6 @@
 ``SlotDecodeSession`` (``serving/generation.py``) behind a
 ``ServingFrontend`` (``serving/frontend.py``), clients over the wire."""
 
-import os
 import time
 
 from perfbench import harness, loadgen, serve_common
@@ -19,8 +18,8 @@ def run(ctx):
     place = fluid.TPUPlace() if devices[0].platform != "cpu" \
         else fluid.CPUPlace()
     # the child imports and plans while the server builds
-    client = serve_common.Client(cell, traffic, ctx.seed, ctx.seconds,
-                                 os.path.join(ctx.out_dir, "loadgen.json"))
+    client = serve_common.transformer_client(cell, traffic, ctx.seed,
+                                             ctx.seconds, ctx.out_dir)
     try:
         server = serve_common.Server(cell, ctx.seed, place, setup)
         try:
@@ -43,8 +42,7 @@ def run(ctx):
                                   - (time.time() - t_open))
 
             summary, _records, host = serve_common.drive(
-                server, cell, traffic, ctx.seed, ctx.seconds, ctx.out_dir,
-                client=client, on_open=opened,
+                server, traffic, ctx.seconds, client, on_open=opened,
                 profiler=ctx.profiler if ctx.trace else None)
             traces = tracing.completed() if ctx.trace else []
         finally:

@@ -1,7 +1,7 @@
 """The host ledger: where the decode worker's not-running time goes, and
 what the threads that share its interpreter cost, read from the PROGRAM's
 own account (``program_records.py`` has the rings; this file adds the
-arithmetic for the four ``host_`` readers and prints the ledger they come
+arithmetic for the six ``host_`` readers and prints the ledger they come
 from). ONE definition for every saturated serving cell: nothing here goes
 by a span's name but ``round``, ``wait``, ``handoff`` and the ``.dispatch``
 suffix, and the wait for the chip is taken from the executor's dispatch
@@ -14,7 +14,13 @@ What the program keeps (``docs/OBSERVABILITY.md``), with tracing on:
 * every round's root: ``handler_cpu`` and ``handler_chunks``, the handler
   threads' CPU seconds (their socket writes' kernel time included, which
   holds no interpreter lock) and the chunks they wrote, as they stood at
-  the round's end (a handler writes them when a request of its ends).
+  the round's end (a handler writes them when a request of its ends);
+* every round's root, who WAKES: ``handler_wakeups`` (the handlers'
+  returns from their streams' queues, of the streams that have ended) and
+  ``watcher_cancel`` (the cancel verdicts the one thread that reads every
+  streaming connection has posted), totals as they stood at the round's
+  end, beside the round's own ``cancel_rows`` (slots its cancels
+  released).
 
 The split of a wall: **blocked = wall - cpu - device**, ``device`` being
 the ``device`` phase (the executor's wait for the chip) of the dispatch
@@ -38,13 +44,11 @@ keeps no counter on the lock itself.
 A program without the account (the parent of the PR that brought it) reads
 None everywhere, as does a run with no device trace (the CPU rehearsals).
 
-BENCHMARK.json does NOT list the four readers of ``layer_metrics/host_*.py``
-yet: tests of the benchmark pin the END of ``per_layer``
-(``tests/perfbench/test_perfbench_cancel_rows.py``) and the serving cells'
-lists of per-layer names (``..._glm.py``, ``..._jamba.py``,
-``..._trinity.py``), so only a ``benchmark`` PR, which may edit them, can
-append the entries (PERF.md section 7). The first reader a traced run calls
-prints the ledger.
+BENCHMARK.json lists the six readers of ``layer_metrics/host_*.py`` for
+``serve_base_saturated`` alone: in the decoder-only cells ``blocked`` is
+mostly the benchmark's own tap fetching for itself (the ``outside``
+column), so a reading there would name the wrong thing. The first reader
+a traced run calls prints the ledger.
 """
 
 import bisect
@@ -169,27 +173,64 @@ def shares(parts):
 
 # -- the handlers -------------------------------------------------------------
 
-def handler_line(rounds):
-    """``{"wall", "cpu", "chunks", "worker_cpu"}`` between the END of the
-    window's first round and the end of its last: the handler threads'
-    CPU seconds and their chunks (last root less first), and the worker's
-    own CPU over the same rounds. None where the roots hold no account."""
+def grown(rounds, key):
+    """``(gain, seconds)``: what the roots' running total ``key`` gained
+    between the END of the window's first round and the end of its last,
+    and the time between those ends. None with fewer than two rounds or
+    where a root holds no such total."""
     roots = [r["spans"][0] for r in rounds]
-    if len(roots) < 2 or any("handler_cpu" not in root for root in roots):
+    if len(roots) < 2 or any(key not in root for root in roots):
         return None
     wall = roots[-1]["t1"] - roots[0]["t1"]
     if wall <= 0:
         return None
-    return {"wall": wall,
-            "cpu": roots[-1]["handler_cpu"] - roots[0]["handler_cpu"],
-            "chunks": (roots[-1]["handler_chunks"]
-                       - roots[0]["handler_chunks"]),
-            "worker_cpu": sum(root["cpu"] for root in roots[1:])}
+    return roots[-1][key] - roots[0][key], wall
+
+
+def handler_line(rounds):
+    """``{"wall", "cpu", "chunks", "worker_cpu"}`` over ``grown``'s
+    stretch: the handler threads' CPU seconds and their chunks, and the
+    worker's own CPU over the same rounds. None where the roots hold no
+    account."""
+    cpu = grown(rounds, "handler_cpu")
+    if cpu is None:
+        return None
+    return {"wall": cpu[1], "cpu": cpu[0],
+            "chunks": grown(rounds, "handler_chunks")[0],
+            "worker_cpu": sum(r["spans"][0]["cpu"] for r in rounds[1:])}
 
 
 def handler_cpu_share(rounds):
     line = handler_line(rounds)
     return None if line is None else 100.0 * line["cpu"] / line["wall"]
+
+
+def handler_wakeups_per_s(rounds):
+    """The handlers' returns from ``stream.q.get()`` a wall second."""
+    gain = grown(rounds, "handler_wakeups")
+    return None if gain is None else gain[0] / gain[1]
+
+
+def cancel_verdicts_and_rows(rounds):
+    """``(the watcher's cancel verdicts, the slots the rounds' cancels
+    released)``, both after the window's first round; None where the
+    roots hold no verdicts."""
+    gain = grown(rounds, "watcher_cancel")
+    if gain is None:
+        return None
+    return gain[0], sum(r["spans"][0].get("cancel_rows", 0)
+                        for r in rounds[1:])
+
+
+def watcher_verdicts_per_cancel_row(rounds):
+    """1.0 says every released slot was a cancel line the watcher read (a
+    stream cancelled while still queued has a verdict and no slot; a
+    connection whose ``close`` hook or failed write got there first a
+    slot and no verdict). A verdict is applied by the worker's NEXT pass,
+    so a round's burst at either edge of the window moves the reading by
+    a round's share of it. None where nothing was cancelled."""
+    both = cancel_verdicts_and_rows(rounds)
+    return None if both is None or not both[1] else both[0] / float(both[1])
 
 
 # -- the executor's calls -----------------------------------------------------
@@ -242,7 +283,7 @@ def _call_line(what, means):
 
 
 def log_ledger(win):
-    """The ledger behind the four ``host_`` readers, once a run."""
+    """The ledger behind the ``host_`` readers, once a run."""
     rounds, dispatches = win["rounds"], win["dispatches"]
     mine = pr.dispatched(rounds)
     if not spans_hold_account(mine):
@@ -289,6 +330,12 @@ def log_ledger(win):
                     % (handlers, 1e6 * line["cpu"] / max(line["chunks"], 1),
                        line["chunks"], line["wall"], worker,
                        worker + handlers))
+    both = cancel_verdicts_and_rows(rounds)
+    if both is not None:
+        harness.log("  who wakes: the handlers %.1f times a second; the "
+                    "watcher posted %d cancel verdicts for the %d slots "
+                    "the rounds' cancels released"
+                    % ((handler_wakeups_per_s(rounds),) + both))
     harness.log(_call_line("executor calls between the rounds",
                            call_means(dispatches)))
     after, quiet = handoff_split(rounds, dispatches)
@@ -333,9 +380,15 @@ def read_lockwait_share(records):
     return None if parts is None else shares(parts)["blocked"]
 
 
-def read_handler_cpu_share(records):
+def read_rounds_stat(records, stat):
+    """``stat`` of the window's rounds: ``handler_cpu_share``,
+    ``handler_wakeups_per_s`` or ``watcher_verdicts_per_cancel_row``."""
     win = window(records)
-    return None if win is None else handler_cpu_share(win["rounds"])
+    return None if win is None else stat(win["rounds"])
+
+
+def read_handler_cpu_share(records):
+    return read_rounds_stat(records, handler_cpu_share)
 
 
 def read_call_mean(records, key):

@@ -376,7 +376,8 @@ def round_counts(rounds):
     program keeps on a round."""
     return {key: [r["spans"][0][key] for r in dispatched(rounds)
                   if key in r["spans"][0]]
-            for key in ("live", "backlog", "tokens")}
+            for key in ("live", "backlog", "tokens", "admit_rows",
+                        "cancel_rows")}
 
 
 def log_rounds(records, rounds):
@@ -394,6 +395,16 @@ def log_rounds(records, rounds):
         harness.log("  %-16s %6.1f a round, %9.3f ms together in the "
                     "median, %9.3f ms self" % (name, n, total, own))
     counts = round_counts(rounds)
+    # an ``admit`` or a ``cancel`` span is a batch: what the batches held
+    # is the round's counts, where the program keeps them
+    held = ["%.1f %s (%s)" % (sum(counts[key]) / float(len(covers)), what,
+                              key)
+            for key, what in (("admit_rows", "requests admitted"),
+                              ("cancel_rows", "slots released by cancels"))
+            if counts[key]]
+    if held:
+        harness.log("  a round's batches held, in the mean: "
+                    + ", ".join(held))
     if counts["tokens"]:
         # the clients count what they asked for; the worker also hands
         # out what a stream decoded past its client's end

@@ -293,14 +293,16 @@ class Server(object):
 
 
 class Client(object):
-    """The load generator's child process."""
+    """The load generator's child process. ``vocab`` (token ids are drawn
+    below it) and ``max_length`` (the longest request) are the caller's
+    to say: a configuration's keys for them are its family's."""
 
-    def __init__(self, cell, traffic, seed, seconds, out_path):
+    def __init__(self, cell, traffic, seed, seconds, out_dir, vocab,
+                 max_length):
+        self.out_path = os.path.join(out_dir, "loadgen.json")
         spec = {"traffic": traffic, "seed": int(seed),
-                "seconds": float(seconds), "out": out_path,
-                "vocab": cell.config["src_vocab_size"],
-                "max_length": cell.config["max_length"]}
-        self.out_path = out_path
+                "seconds": float(seconds), "out": self.out_path,
+                "vocab": int(vocab), "max_length": int(max_length)}
         env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
             [cell.root] + ([os.environ["PYTHONPATH"]]
                            if os.environ.get("PYTHONPATH") else [])))
@@ -350,12 +352,16 @@ def length_weights(plan):
             "mean_cross_context": float(np.sum(ls * lt) / np.sum(lt))}
 
 
-def drive(server, cell, traffic, seed, seconds, out_dir, client=None,
-          on_open=None, profiler=None):
-    """One measured window against a warm server. Returns the summary of
-    the client's records and the host's."""
-    client = client or Client(cell, traffic, seed, seconds,
-                              os.path.join(out_dir, "loadgen.json"))
+def transformer_client(cell, traffic, seed, seconds, out_dir):
+    """The Transformer cells' client: source ids from the source
+    vocabulary, requests up to ``max_length``."""
+    return Client(cell, traffic, seed, seconds, out_dir,
+                  cell.config["src_vocab_size"], cell.config["max_length"])
+
+
+def drive(server, traffic, seconds, client, on_open=None, profiler=None):
+    """One measured window of ``client``'s plan against a warm server.
+    Returns the summary of the client's records and the host's."""
     try:
         client.ready()
         address = server.frontend.address

@@ -7,13 +7,19 @@ produced with the plain reference; and the host-side records the ``glm_``
 per-layer metrics read.
 """
 
+import importlib
 import time
-import types
 
 import numpy as np
 
-from perfbench import harness, serve_common, weights_glm
+from perfbench import decoder_family, harness, weights_glm
 from perfbench.reference import latent_moe_decoder as reference
+
+
+def client_sizes(cfg):
+    """What the load generator's child is told: the vocabulary its ids
+    are drawn from and the longest prompt."""
+    return cfg["vocab_size"], cfg["pool"]["max_prompt"]
 
 
 def session_kwargs(cfg):
@@ -225,41 +231,16 @@ class Checker(object):
             raise RuntimeError("pool not drained after the check")
         return out
 
+    # the family's reference, and the configuration's key for its leading
+    # dense layers, which choose no experts
+    reference = reference
+    dense_key = "first_k_dense_replace"
+
     def _against_reference(self, tree, tokens, n_prompt, got, chosen):
         """The three numbers' parts for one sequence."""
-        import jax.numpy as jnp
-
-        cfg, P = self.cfg, int(self.cfg["check"]["positions"])
-        dense = int(cfg.get("first_k_dense_replace", 0))
-        k = cfg["num_experts_per_tok"]
-        # one compiled reference whatever the seed's lengths: the sequence
-        # is padded to its range's end (causal: the padding changes no
-        # position before it)
-        total = next(hi for _lo, hi in cfg["check"]["prompt_len_ranges"]
-                     if n_prompt < hi) + P
-        pad = total - len(tokens)
-        toks = np.concatenate([tokens, np.zeros(pad, "int64")])
-        per_layer = [None] * dense + [
-            jnp.asarray(np.concatenate(
-                [c, np.zeros((pad, k), c.dtype)]), jnp.int32)
-            for c in chosen]
-        out = reference.forward(
-            tree, toks, cfg, chosen=per_layer,
-            logits_at=np.arange(n_prompt - 1, n_prompt + P))
-        want = out["logits"]
-        err = float(jnp.sum(jnp.square(got - want)))
-        norm = float(jnp.sum(jnp.square(want)))
-        differ, margin = 0, 0.0
-        for mine, own, biased in zip(chosen, out["own"], out["biased"]):
-            own = np.asarray(own)[:len(tokens)]
-            biased = np.asarray(biased)[:len(tokens)]
-            extra = ~(mine[:, :, None] == own[:, None, :]).any(-1)  # [T, k]
-            differ += int(extra.sum())
-            if extra.any():
-                last = np.take_along_axis(biased, own, -1).min(-1)   # [T]
-                gap = last[:, None] - np.take_along_axis(biased, mine, -1)
-                margin = max(margin, float(gap[extra].max()))
-        return err, norm, differ, len(tokens) * len(chosen) * k, margin
+        return decoder_family.against_reference(
+            self.reference, self.cfg, int(self.cfg.get(self.dense_key, 0)),
+            tree, tokens, n_prompt, got, chosen)
 
     def _numbers(self, tree, served):
         err = norm = differ = choices = 0
@@ -279,7 +260,8 @@ class Checker(object):
     def numbers(self, seed):
         named = self.server.load_weights(seed)
         served = self._serve(self._prompts(seed), seed)
-        return self._numbers(weights_glm.tree(named, self.cfg), served)
+        return self._numbers(self.server.weights.tree(named, self.cfg),
+                             served)
 
     def control_numbers(self, seed):
         """The reference itself in the program's place, one precision
@@ -317,6 +299,11 @@ def verdict(numbers, limits):
 class Server(object):
     """The system under test, built and warmed once."""
 
+    # the family's builder module in the program (``parameter_shapes``,
+    # ``load_parameters``) and its weights from the seed
+    model = "paddle_tpu.models.latent_moe_decoder"
+    weights = weights_glm
+
     def __init__(self, cell, seed, place, setup):
         import paddle_tpu as fluid
         from paddle_tpu.serving.decoder_session import DecoderOnlySession
@@ -337,18 +324,14 @@ class Server(object):
     def load_weights(self, seed):
         """The seed's weights into the scope (the last seed's are dropped
         first: the chip does not hold two sets). Returns them by name."""
-        from paddle_tpu.models.latent_moe_decoder import (
-            load_parameters,
-            parameter_shapes,
-        )
-
+        model = importlib.import_module(self.model)
         dtype = self.cfg.get("dtype", "bfloat16")
-        names = list(parameter_shapes(self.cfg, dtype))
+        names = list(model.parameter_shapes(self.cfg, dtype))
         if self._seed != seed:
             self.scope.erase([n for n in names if self.scope.has(n)])
-            load_parameters(self.scope,
-                            weights_glm.make(self.cfg, seed, dtype),
-                            self.cfg, dtype)
+            model.load_parameters(self.scope,
+                                  self.weights.make(self.cfg, seed, dtype),
+                                  self.cfg, dtype)
             self._seed = seed
         return {n: self.scope.get_value(n) for n in names}
 
@@ -411,16 +394,3 @@ class Server(object):
         if self.frontend is not None:
             self.frontend.close(drain=False, timeout=30.0)
             self.frontend = None
-
-
-class Client(serve_common.Client):
-    """The load generator's child process (the same ``loadgen.py``), told
-    this configuration's vocabulary and longest prompt: ``serve_common``'s
-    client reads them under the Transformer's keys."""
-
-    def __init__(self, cell, traffic, seed, seconds, out_path):
-        view = types.SimpleNamespace(
-            root=cell.root, dir=cell.dir,
-            config={"src_vocab_size": cell.config["vocab_size"],
-                    "max_length": cell.config["pool"]["max_prompt"]})
-        super().__init__(view, traffic, seed, seconds, out_path)

@@ -3,7 +3,7 @@
 ``models/hybrid_ssm_decoder.py`` gives recurrent state beside K/V pages,
 behind the same ``ServingFrontend``, wire, load generator and host-side
 records as the latent-attention decoder's cell (``serve_glm_common.py``:
-its ``Server``, ``Client``, ``verdict`` and the way its ``Checker`` fills
+its ``Server``, ``client_sizes``, ``verdict`` and the way its ``Checker`` fills
 every slot as the window does are used as they are); what is this model's
 own is here: its weights, what the tap asks of a dispatch, and the
 comparison with ``reference/hybrid_ssm_decoder.py``: logits AND the
@@ -16,7 +16,7 @@ from perfbench import harness, serve_glm_common, weights_jamba
 from perfbench.reference import hybrid_ssm_decoder as reference
 
 verdict = serve_glm_common.verdict
-Client = serve_glm_common.Client
+client_sizes = serve_glm_common.client_sizes
 fp8_operands = serve_glm_common.fp8_operands
 
 
@@ -79,26 +79,13 @@ class Server(serve_glm_common.Server):
     """The system under test, built and warmed once: the latent decoder's
     server with this model's weights and tap."""
 
+    model = "paddle_tpu.models.hybrid_ssm_decoder"
+    weights = weights_jamba
+
     def __init__(self, cell, seed, place, setup):
         super().__init__(cell, seed, place, setup)
         self.tap = self.session._exe = Tap(self.tap._exe,
                                            self.session._fetch)
-
-    def load_weights(self, seed):
-        from paddle_tpu.models.hybrid_ssm_decoder import (
-            load_parameters,
-            parameter_shapes,
-        )
-
-        dtype = self.cfg.get("dtype", "bfloat16")
-        names = list(parameter_shapes(self.cfg, dtype))
-        if self._seed != seed:
-            self.scope.erase([n for n in names if self.scope.has(n)])
-            load_parameters(self.scope,
-                            weights_jamba.make(self.cfg, seed, dtype),
-                            self.cfg, dtype)
-            self._seed = seed
-        return {n: self.scope.get_value(n) for n in names}
 
     def slot_states(self, slots):
         """The recurrent state of ``slots`` as the served arrays hold it:
@@ -222,11 +209,6 @@ class Checker(serve_glm_common.Checker):
         return {"logit_rel_l2": float(np.sqrt(err / norm)),
                 "state_rel_l2": float(np.sqrt(s_err.sum() / s_norm.sum())),
                 "state_slow_rel_l2": float(np.sqrt(s_err[0] / s_norm[0]))}
-
-    def numbers(self, seed):
-        named = self.server.load_weights(seed)
-        served = self._serve(self._prompts(seed), seed)
-        return self._numbers(weights_jamba.tree(named, self.cfg), served)
 
     def control_numbers(self, seed):
         """The reference itself in the program's place, one precision

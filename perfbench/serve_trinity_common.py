@@ -5,7 +5,7 @@ layers with routed experts: a ``DecoderOnlySession``
 window layer beside a full layer's growing pools, behind the same
 ``ServingFrontend``, wire, load generator and host-side records as the
 latent-attention decoder's cell (``serve_glm_common.py``: its ``Tap``,
-``Client``, ``verdict``, the way its ``Checker`` fills every slot as the
+``client_sizes``, ``verdict``, the way its ``Checker`` fills every slot as the
 window does and what it makes of the experts' choices are used as they
 are); what is this model's own is here: its weights, what a decode
 dispatch's record says of the window, and the comparison with
@@ -20,7 +20,7 @@ from perfbench import serve_glm_common, weights_trinity
 from perfbench.reference import afmoe_decoder as reference
 
 verdict = serve_glm_common.verdict
-Client = serve_glm_common.Client
+client_sizes = serve_glm_common.client_sizes
 fp8_operands = serve_glm_common.fp8_operands
 
 
@@ -28,21 +28,8 @@ class Server(serve_glm_common.Server):
     """The system under test, built and warmed once: the latent decoder's
     server with this model's weights."""
 
-    def load_weights(self, seed):
-        from paddle_tpu.models.windowed_moe_decoder import (
-            load_parameters,
-            parameter_shapes,
-        )
-
-        dtype = self.cfg.get("dtype", "bfloat16")
-        names = list(parameter_shapes(self.cfg, dtype))
-        if self._seed != seed:
-            self.scope.erase([n for n in names if self.scope.has(n)])
-            load_parameters(self.scope,
-                            weights_trinity.make(self.cfg, seed, dtype),
-                            self.cfg, dtype)
-            self._seed = seed
-        return {n: self.scope.get_value(n) for n in names}
+    model = "paddle_tpu.models.windowed_moe_decoder"
+    weights = weights_trinity
 
     def start(self, backlog):
         """The frontend's worker admits under the pool's
@@ -85,46 +72,8 @@ class Checker(serve_glm_common.Checker):
     ``expert_choice_diff_share`` and ``expert_choice_margin_max`` of the
     reference's own choice, as that checker defines them."""
 
-    def _against_reference(self, tree, tokens, n_prompt, got, chosen):
-        """The three numbers' parts for one sequence."""
-        import jax.numpy as jnp
-
-        cfg, P = self.cfg, int(self.cfg["check"]["positions"])
-        dense = int(cfg.get("num_dense_layers", 0))
-        k = cfg["num_experts_per_tok"]
-        # one compiled reference a range whatever the seed's lengths: the
-        # sequence is padded to its range's end (causal: the padding
-        # changes no position before it)
-        total = next(hi for _lo, hi in cfg["check"]["prompt_len_ranges"]
-                     if n_prompt < hi) + P
-        pad = total - len(tokens)
-        toks = np.concatenate([tokens, np.zeros(pad, "int64")])
-        per_layer = [None] * dense + [
-            jnp.asarray(np.concatenate(
-                [c, np.zeros((pad, k), c.dtype)]), jnp.int32)
-            for c in chosen]
-        out = reference.forward(
-            tree, toks, cfg, chosen=per_layer,
-            logits_at=np.arange(n_prompt - 1, n_prompt + P))
-        want = out["logits"]
-        err = float(jnp.sum(jnp.square(got - want)))
-        norm = float(jnp.sum(jnp.square(want)))
-        differ, margin = 0, 0.0
-        for mine, own, biased in zip(chosen, out["own"], out["biased"]):
-            own = np.asarray(own)[:len(tokens)]
-            biased = np.asarray(biased)[:len(tokens)]
-            extra = ~(mine[:, :, None] == own[:, None, :]).any(-1)  # [T, k]
-            differ += int(extra.sum())
-            if extra.any():
-                last = np.take_along_axis(biased, own, -1).min(-1)   # [T]
-                gap = last[:, None] - np.take_along_axis(biased, mine, -1)
-                margin = max(margin, float(gap[extra].max()))
-        return err, norm, differ, len(tokens) * len(chosen) * k, margin
-
-    def numbers(self, seed):
-        named = self.server.load_weights(seed)
-        served = self._serve(self._prompts(seed), seed)
-        return self._numbers(weights_trinity.tree(named, self.cfg), served)
+    reference = reference
+    dense_key = "num_dense_layers"
 
     def control_numbers(self, seed):
         """The reference itself in the program's place, over the tokens

@@ -96,7 +96,9 @@ def main(argv=None):
             watch.start()
             t_open = []
             summary, records, _host = serve_common.drive(
-                server, cell, traffic, seed, args.hold, out_dir,
+                server, traffic, args.hold,
+                serve_common.transformer_client(cell, traffic, seed,
+                                                args.hold, out_dir),
                 on_open=lambda t: t_open.append(
                     time.perf_counter() - (time.time() - t)))
             stalls = watch.stop(t_open[0], args.hold)

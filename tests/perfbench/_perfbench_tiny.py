@@ -78,3 +78,33 @@ def check_line(line, cell, trace):
     if not trace:
         assert set(line["metrics"]) == set(units)
         assert line["metrics"]["setup_s"]["value"] > 0
+
+
+# -- what a cell's declaration test asks of BENCHMARK.json -------------------
+
+# the host-plane and device readers every ``DecoderOnlySession`` cell lists
+# (they read ``records["serve"]`` as every decoder-only entry fills it)
+DECODER_SHARED = [
+    "glm_prefill_prompts_per_dispatch_p50", "glm_round_host_ms_p50",
+    "glm_device_idle_share", "glm_loadgen_late_p99_ms",
+    "glm_admit_self_ms_p50", "glm_cancel_ms_p50", "glm_handoff_ms_p50",
+    "glm_worker_offcpu_share", "glm_exec_host_ms_per_dispatch",
+    "glm_idle_unattributed_share"]
+SETUP = ["build_s", "compile_s", "cache_misses", "trace_lower_s"]
+
+
+def check_cell_declares(bench, root, cell, names, bare):
+    """Set-up's four and every name of ``names`` are entries of ``bench``
+    that ``cell`` reports, and the reader of each of ``names`` under
+    ``root`` reads None on each of the ``bare`` records (nothing to read
+    is no number). By NAME: what else the cell lists, and who else lists
+    these entries, is a later PR's."""
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    for name in SETUP + names:
+        assert name in declared, "%s declares no %s" % (cell, name)
+        assert cell in declared[name].get("workloads", [cell]), (cell, name)
+    for name in names:
+        reader = harness.load_module(os.path.join(
+            root, "perfbench", "layer_metrics", name + ".py"), name)
+        for records in bare:
+            assert reader.read(records) is None, name

@@ -3,9 +3,13 @@ names lead to, and the rule that a later PR adds a cell, a configuration
 (of this model family or of another), a traffic mix or a per-layer metric
 as NEW files and NEW entries only.
 
-The contract's checks are plain functions of ``(bench, root)``: the tests
-call them on the real tree, and the tests that rehearse a later PR's
-addition call them on a copy that holds the addition."""
+The contract's checks are plain functions of ``(bench, root)``, and so is
+every other assertion under ``tests/perfbench/`` on BENCHMARK.json's lists
+(``DECLARATIONS``): the tests call them on the real tree, and the tests
+that rehearse a later PR's addition call them on a copy that holds the
+addition. A later PR's own declaration test is one more such function: it
+finds its entries by NAME, and ``test_an_addition_at_the_end_breaks_no_
+declaration_test`` is what it has to keep passing."""
 
 import copy
 import hashlib
@@ -18,7 +22,13 @@ import sys
 
 import pytest
 
-from _perfbench_tiny import ROOT
+import test_perfbench_admit_rows
+import test_perfbench_cancel_rows
+import test_perfbench_glm
+import test_perfbench_host_ledger
+import test_perfbench_jamba
+import test_perfbench_trinity
+from _perfbench_tiny import DECODER_SHARED, ROOT
 from test_perfbench_program_records import check_new_metrics_declared
 
 from perfbench import harness, loadgen
@@ -152,6 +162,15 @@ CHECKS = {"top_level": check_top_level, "configs": check_configs,
           "layers": check_layers,
           "new_metrics": lambda bench, root: check_new_metrics_declared(
               bench)}
+
+
+# every function under tests/perfbench/ that reads BENCHMARK.json's lists:
+# the contract's own and each family's and each addition's declaration
+DECLARATIONS = dict(CHECKS, **{
+    mod.__name__[len("test_perfbench_"):]: mod.check_declared
+    for mod in (test_perfbench_admit_rows, test_perfbench_cancel_rows,
+                test_perfbench_host_ledger, test_perfbench_glm,
+                test_perfbench_jamba, test_perfbench_trinity)})
 
 
 def test_top_level_keys_and_sizes(bench):
@@ -384,11 +403,18 @@ def _add_second_family(root, bench):
 
 
 @pytest.fixture
-def second_family(tmp_path, bench):
-    """(the bench with the addition, the root of the copy that holds it,
-    the digest of the copy's ``perfbench/`` before the addition)."""
+def copied_tree(tmp_path, bench):
+    """(a copy of the bench to add to, the root of a copy of the tree)."""
     root = _copy_perfbench(tmp_path)
     shutil.copy(os.path.join(ROOT, "PERF.md"), root)
+    return copy.deepcopy(bench), root
+
+
+@pytest.fixture
+def second_family(copied_tree):
+    """(the bench with the addition, the root of the copy that holds it,
+    the digest of the copy's ``perfbench/`` before the addition)."""
+    bench, root = copied_tree
     before = _digest(os.path.join(root, "perfbench"))
     return _add_second_family(root, bench), root, before
 
@@ -401,7 +427,7 @@ def test_a_second_family_is_new_files_and_new_entries(second_family, bench):
     new, root, before = second_family
     assert not set(LATER_LM) & {"d_model", "d_inner", "n_head", "n_layer"}
     assert LATER_LM["hidden_size"] % LATER_LM["num_attention_heads"]
-    for check in CHECKS.values():
+    for check in DECLARATIONS.values():
         check(new, root)
     assert [m["name"] for m in new["per_layer"]][-3:] == [
         m["name"] for m in LATER_METRICS]
@@ -449,6 +475,131 @@ def test_a_second_family_is_new_files_and_new_entries(second_family, bench):
     for w in bench["workloads"]:
         assert harness.Cell(w["name"], root=root).per_layer() == \
             harness.Cell(w["name"]).per_layer()
+
+
+# -- a later PR's per-layer metric for a cell that is there, and a further
+# -- cell of a family that is there ------------------------------------------
+
+# the end-to-end metric a cell's new per-layer metric moves
+MOVED = {"train_big_1chip": "train_tokens_per_s",
+         "serve_base_steady": "ttft_p95_ms"}
+DECODER_CELLS = ["serve_glm_saturated", "serve_jamba_saturated",
+                 "serve_trinity_longctx"]
+
+
+def _append_metric(root, new, cell):
+    """What a ``tracing`` or ``perf_opt`` PR's follow-up adds: a reader
+    file and an entry at the END of ``per_layer`` that lists ``cell``."""
+    name = "later_%s_count" % cell
+    with open(os.path.join(root, "perfbench", "layer_metrics",
+                           name + ".py"), "w") as f:
+        f.write(LATER_READER % name)
+    new["per_layer"].append({
+        "name": name, "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "serving host plane",
+        "moves": MOVED.get(cell, "serve_tokens_per_s"),
+        "workloads": [cell]})
+    return name
+
+
+def _append_decoder_cell(root, new):
+    """What the next decoder-only ``model_config`` PR adds besides its own
+    names: a configuration served through an entry that is there, a cell,
+    and the cell's name at the end of the ``workloads`` of every entry the
+    three decoder-only cells share."""
+    pb = os.path.join(root, "perfbench")
+    cfg = dict(harness.load_json(os.path.join(
+        pb, "configs", "glm47_flash_6l.json")), name="later_decoder")
+    with open(os.path.join(pb, "configs", "later_decoder.json"), "w") as f:
+        json.dump(cfg, f)
+    new["configs"].append({
+        "name": "later_decoder", "source": cfg["source"],
+        "file": "perfbench/configs/later_decoder.json",
+        "reduced": cfg["reduced"], "why": "a later PR's decoder-only model"})
+    new["workloads"].append({
+        "name": "later_decoder_saturated", "config": "later_decoder",
+        "traffic": "closed_320_clients", "chips": 1,
+        "why": "closed loop above the knee: a later PR's cell"})
+    shared = set(DECODER_SHARED) | {"trace_lower_s", "serve_tokens_per_s"}
+    for m in new["end_to_end"] + new["per_layer"]:
+        if m["name"] in shared:
+            assert set(DECODER_CELLS) <= set(m["workloads"]), m["name"]
+            m["workloads"] = m["workloads"] + ["later_decoder_saturated"]
+
+
+def _write(root, new):
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(new, f)
+
+
+def test_an_addition_at_the_end_breaks_no_declaration_test(copied_tree,
+                                                           bench):
+    """A per-layer entry appended at the END of ``per_layer`` for each
+    cell in turn, then a further decoder-only cell listed under every
+    entry the decoder-only cells share: after each step every function
+    under tests/perfbench/ that reads BENCHMARK.json's lists holds on the
+    copy, and the harness lists the new metric for its cell and for no
+    other. A later PR that pins a position or a count fails here."""
+    new, root = copied_tree
+    for cell in [w["name"] for w in bench["workloads"]]:
+        name = _append_metric(root, new, cell)
+        _write(root, new)
+        for check in DECLARATIONS.values():
+            check(new, root)
+        for w in bench["workloads"]:
+            mine = harness.Cell(w["name"], root=root)
+            listed = [m["name"] for m in mine.per_layer()]
+            assert (name in listed) == (w["name"] == cell)
+            if w["name"] == cell:
+                assert listed[-1] == name
+                assert mine.per_layer()[-1] == new["per_layer"][-1]
+    _append_decoder_cell(root, new)
+    _write(root, new)
+    for check in DECLARATIONS.values():
+        check(new, root)
+    later = harness.Cell("later_decoder_saturated", root=root)
+    assert later.entry().__name__ == "perfbench_entry_decoder_frontend"
+    assert [m["name"] for m in later.per_layer()] == [
+        "build_s", "compile_s", "cache_misses", "trace_lower_s"] \
+        + DECODER_SHARED
+    # nothing that was there changed but the lists the new cell joined
+    for key in ("configs", "workloads"):
+        assert new[key][:len(bench[key])] == bench[key]
+    for was, now in zip(bench["per_layer"] + bench["end_to_end"],
+                        new["per_layer"][:len(bench["per_layer"])]
+                        + new["end_to_end"]):
+        assert dict(now, workloads=None) == dict(was, workloads=None)
+        assert now.get("workloads", [])[:len(was.get("workloads", []))] \
+            == was.get("workloads", [])
+
+
+def _pin_the_lists_end(new, root):
+    """PR 34's: the LAST entry IS ``sat_cancel_rows_per_dispatch_p50``."""
+    assert new["per_layer"][-1] == test_perfbench_cancel_rows.ENTRY
+
+
+def _pin_a_cells_names(new, root):
+    """PR 27's: the GLM cell lists four set-up names and 17 ``glm_``."""
+    names = [m["name"] for m in
+             harness.Cell("serve_glm_saturated", root=root).per_layer()]
+    assert len([n for n in names if n.startswith("glm_")]) == 17
+    assert [n for n in names if not n.startswith("glm_")] == [
+        "build_s", "compile_s", "cache_misses", "trace_lower_s"]
+
+
+@pytest.mark.parametrize("pin,cell", [
+    (_pin_the_lists_end, "train_big_1chip"),
+    (_pin_a_cells_names, "serve_glm_saturated")],
+    ids=lambda x: x.__name__[5:] if callable(x) else x)
+def test_a_pin_by_position_or_by_count_fails_on_an_addition(pin, cell,
+                                                            copied_tree):
+    """The two kinds of assertion that held every per-layer addition out
+    until PR 38 hold on the tree as it is only while nobody appends."""
+    new, root = copied_tree
+    _append_metric(root, new, cell)
+    _write(root, new)
+    with pytest.raises(AssertionError):
+        pin(new, root)
 
 
 def _rewrite_config(root, name, drop=(), **changes):

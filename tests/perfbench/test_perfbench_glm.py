@@ -120,6 +120,27 @@ def _records(cfg, **serve):
             "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
 
 
+# the per-layer entries PR 27 declared for this cell, by name
+DECLARED = [
+    "glm_decode_dispatch_device_ms", "glm_prefill_dispatch_device_ms",
+    "glm_decode_hbm_roofline", "glm_expert_matmul_roofline",
+    "glm_latent_decode_attention_roofline", "glm_prefill_attention_roofline",
+    "glm_expert_time_share"] + tiny.DECODER_SHARED
+HOST = {"step": [(0.0, 0.1, (250, 150_000)), (0.1, 0.2, (252, 151_000)),
+                 (0.2, 0.3, (0, 0)), (5.0, 5.1, (256, 1))],
+        "admit": [(0.05, 0.09, [(512, [400, 300]), (128, [90])]),
+                  (4.0, 4.1, [(128, [1])])]}
+
+
+def check_declared(bench, root):
+    """Every name this family declared is there, lists this cell, and its
+    reader gives no number on records without a device trace."""
+    cfg = harness.Cell(CELL, root=root).config
+    tiny.check_cell_declares(bench, root, CELL, DECLARED, [
+        _records(cfg, host=HOST, seconds=51.0, traced_s=3.0),
+        {"config": cfg}])
+
+
 def test_glm_readers_on_synthetic_records():
     cfg = harness.Cell(CELL).config
     step_ops = ["latent_paged_decode_attention", "gmm"]
@@ -134,11 +155,7 @@ def test_glm_readers_on_synthetic_records():
         ["%gmm.7 = f32[1024,1536]{1,0} custom-call(", 0.09, 190],
         ["%sort.128 = (f32[256,64]{0,1}, s32[256,64]{0,1}) sort(", 0.001, 80],
         ["%flash_attention_fwd.2 = (bf16[2,20,1024,256]", 0.002, 6]]}
-    host = {"step": [(0.0, 0.1, (250, 150_000)), (0.1, 0.2, (252, 151_000)),
-                     (0.2, 0.3, (0, 0)), (5.0, 5.1, (256, 1))],
-            "admit": [(0.05, 0.09, [(512, [400, 300]), (128, [90])]),
-                      (4.0, 4.1, [(128, [1])])]}
-    rec = _records(cfg, host=host, seconds=51.0, traced_s=3.0)
+    rec = _records(cfg, host=HOST, seconds=51.0, traced_s=3.0)
     rec["trace"] = trace
     assert lib.decode_dispatches(rec) == [(250, 150_000), (252, 151_000)]
     assert lib.prefill_dispatches(rec) == [(512, [400, 300]), (128, [90])]
@@ -155,17 +172,8 @@ def test_glm_readers_on_synthetic_records():
                  lib.latent_decode_attention_roofline,
                  lib.prefill_attention_roofline):
         assert 0 < read(rec) < 100
-    # nothing to read is no number
-    bare = _records(cfg, host=host, seconds=51.0, traced_s=3.0)
-    names = [m["name"] for m in harness.Cell(CELL).per_layer()]
-    assert len([n for n in names if n.startswith("glm_")]) == 17
-    assert [n for n in names if not n.startswith("glm_")] == [
-        "build_s", "compile_s", "cache_misses", "trace_lower_s"]
-    for name in [n for n in names if n.startswith("glm_")]:
-        reader = harness.load_module(
-            "%s/perfbench/layer_metrics/%s.py" % (tiny.ROOT, name), name)
-        assert reader.read(bare) is None, name
-        assert reader.read({"config": cfg}) is None, name
+    check_declared(harness.load_json(tiny.ROOT + "/BENCHMARK.json"),
+                   tiny.ROOT)
     rounds = [{"id": 1, "spans": [
         {"name": "round", "t0": 0.0, "t1": 0.100, "cpu": 0.03,
          "parent": None, "prefill_prompts": 6, "prefill_dispatches": 2},

@@ -1,4 +1,4 @@
-"""The host ledger (``perfbench/host_ledger.py``) and its four ``host_``
+"""The host ledger (``perfbench/host_ledger.py``) and its six ``host_``
 readers: on fabricated rounds and dispatch records with known answers (the
 cells' shapes: executor calls under ``cancel`` and ``admit``, or under
 ``admit`` > ``prefill`` with a caller that fetches for itself; executor
@@ -23,13 +23,20 @@ from perfbench import program_records as pr
 
 CELLS = ["serve_base_saturated", "serve_glm_saturated",
          "serve_jamba_saturated", "serve_trinity_longctx"]
+# (name, unit, better, source, layer): what BENCHMARK.json says of each
 METRICS = [
-    ("host_worker_lockwait_share", "%", "program_span",
+    ("host_worker_lockwait_share", "%", "lower", "program_span",
      "serving host plane"),
-    ("host_handler_cpu_share", "%", "program_counter",
+    ("host_handler_cpu_share", "%", "lower", "program_counter",
      "serving host plane"),
-    ("host_exec_call_cpu_ms", "ms", "program_counter", "model step"),
-    ("host_exec_call_blocked_ms", "ms", "program_counter", "model step")]
+    ("host_exec_call_cpu_ms", "ms", "lower", "program_counter",
+     "model step"),
+    ("host_exec_call_blocked_ms", "ms", "lower", "program_counter",
+     "model step"),
+    ("host_handler_wakeups_per_s", "1/s", "lower", "program_counter",
+     "serving host plane"),
+    ("host_watcher_verdicts_per_cancel_row", "count", "higher",
+     "program_counter", "serving host plane")]
 OPENING = 99.5   # time.time() at the fabricated window's opening
 
 
@@ -50,7 +57,9 @@ def _round(t, shape, k):
     spans = [
         _span("round", t, t + 1.0, None, 0.2, live=4, backlog=2,
               tokens=16, handler_cpu=1.0 + 0.4 * k,
-              handler_chunks=100 + 200 * k),
+              handler_chunks=100 + 200 * k,
+              handler_wakeups=500 + 300 * k, watcher_cancel=40 + 26 * k,
+              cancel_rows=13 * (k + 1)),
         _span("wait", t, t + 0.1, 0, 0.0)]
     if shape == "base":
         spans += [
@@ -106,10 +115,12 @@ def _as_calls_made_untraced(rounds, calls):
 
 
 def _as_the_parent_keeps_them(rounds, calls):
-    """``cpu`` on four span names, no handlers' account; plain records."""
+    """``cpu`` on four span names, no handlers' account and no count of
+    who wakes (``cancel_rows`` it has); plain records."""
     for r in rounds:
         for sp in r["spans"]:
-            for key in ("handler_cpu", "handler_chunks"):
+            for key in ("handler_cpu", "handler_chunks",
+                        "handler_wakeups", "watcher_cancel"):
                 sp.pop(key, None)
             if sp["name"] not in ("round", "wait", "step", "step.dispatch"):
                 sp["cpu"] = None
@@ -142,7 +153,16 @@ EXPECTED = {
     "host_exec_call_blocked_ms": {
         "base": 1e3 * (0.209 - 0.075) / 3,
         "decoder": 1e3 * (0.209 - 0.075) / 3,
-        "calls_untraced": None, "parent": None}}
+        "calls_untraced": None, "parent": None},
+    # 300 wake-ups between two rounds' ends, 1 s apart
+    "host_handler_wakeups_per_s": {
+        "base": 300.0, "decoder": 300.0, "calls_untraced": 300.0,
+        "parent": None},
+    # 26 verdicts for the 26 slots the rounds AFTER the first released
+    # (the first's 13 were cancelled before its end: not the window's)
+    "host_watcher_verdicts_per_cancel_row": {
+        "base": 1.0, "decoder": 1.0, "calls_untraced": 1.0,
+        "parent": None}}
 
 
 def _fabricate(case):
@@ -190,10 +210,13 @@ def test_a_reader_on_fabricated_records(name, case, monkeypatch, capsys):
     assert out.count("host ledger") == 1
     if case == "parent":
         assert "hold no thread account" in out
-    elif case == "calls_untraced":
-        assert "executor calls between the rounds: none" in out
+        assert "who wakes" not in out
     else:
-        assert "single calls with the handlers quiet: 4 calls" in out
+        assert ("who wakes: the handlers 300.0 times a second; the watcher "
+                "posted 26 cancel verdicts for the 26 slots") in out
+        assert ("executor calls between the rounds: none" if case
+                == "calls_untraced" else "single calls with the handlers "
+                "quiet: 4 calls") in out
     # and nothing without a device trace
     assert reader.read({"trace": None}) is None
 
@@ -259,22 +282,35 @@ def test_single_calls_split_by_the_handoff_before_them():
     assert host_ledger.handoff_split(rounds, calls) == (None, None)
 
 
-@pytest.mark.parametrize("name,unit,source,layer", METRICS)
-def test_an_entry_of_the_benchmark_is_its_readers(name, unit, source, layer):
-    """The reader is a file the harness can load; BENCHMARK.json lists
-    none of the four yet (``host_ledger.py``'s docstring says why), and an
-    entry a ``benchmark`` PR adds is found by its NAME, wherever in the
-    list it stands, and says what the reader reads."""
+def check_entry(bench, name, unit, better, source, layer):
+    """BENCHMARK.json lists the reader, found by its NAME wherever in the
+    list it stands, and says what it reads; the saturated serving cells
+    alone may list it, ``serve_base_saturated`` among them (a cell whose
+    tap fetches for itself reads its own fetch as ``blocked``:
+    ``host_ledger.py``)."""
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    assert (entry["unit"], entry["better"], entry["source"],
+            entry["layer"], entry["moves"]) == (
+        unit, better, source, layer, "serve_tokens_per_s")
+    assert "serve_base_saturated" in entry["workloads"]
+    assert set(entry["workloads"]) <= set(CELLS)
+
+
+def check_declared(bench, root):
+    for metric in METRICS:
+        check_entry(bench, *metric)
+
+
+@pytest.mark.parametrize("name,unit,better,source,layer", METRICS)
+def test_an_entry_of_the_benchmark_is_its_readers(name, unit, better, source,
+                                                  layer):
+    """The reader is a file the harness can load, it reads nothing
+    without a device trace, and BENCHMARK.json declares it."""
     reader = harness.load_module(os.path.join(
         harness.ROOT, "perfbench", "layer_metrics", name + ".py"), name)
     assert reader.read({"trace": None}) is None
-    bench = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
-    for entry in bench["per_layer"]:
-        if entry["name"] == name:
-            assert (entry["unit"], entry["better"], entry["source"],
-                    entry["layer"], entry["moves"]) == (
-                unit, "lower", source, layer, "serve_tokens_per_s")
-            assert set(entry["workloads"]) <= set(CELLS)
+    check_entry(harness.load_json(os.path.join(
+        harness.ROOT, "BENCHMARK.json")), name, unit, better, source, layer)
 
 
 # -- the cells' own rounds, on the CPU ----------------------------------------

@@ -15,11 +15,6 @@ from perfbench import harness, kernel_costs_jamba as costs
 from perfbench import metric_lib_jamba as lib
 
 CELL = "serve_jamba_saturated"
-SHARED = ["glm_prefill_prompts_per_dispatch_p50", "glm_round_host_ms_p50",
-          "glm_device_idle_share", "glm_loadgen_late_p99_ms",
-          "glm_admit_self_ms_p50", "glm_cancel_ms_p50", "glm_handoff_ms_p50",
-          "glm_worker_offcpu_share", "glm_exec_host_ms_per_dispatch",
-          "glm_idle_unattributed_share"]
 
 
 @pytest.fixture
@@ -129,6 +124,28 @@ def _records(cfg, **serve):
             "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
 
 
+# the per-layer entries PR 31 declared for this cell, by name: eight of
+# its own and the decoder-only cells' shared ones
+DECLARED = [
+    "jamba_decode_dispatch_device_ms", "jamba_prefill_dispatch_device_ms",
+    "jamba_decode_hbm_roofline", "jamba_state_update_roofline",
+    "jamba_prefill_scan_roofline", "jamba_gqa_decode_attention_roofline",
+    "jamba_ssm_time_share", "jamba_prefill_pad_share"] + tiny.DECODER_SHARED
+HOST = {"step": [(0.0, 0.1, (250, 150_000)), (0.1, 0.2, (252, 151_000)),
+                 (0.2, 0.3, (0, 0)), (5.0, 5.1, (256, 1))],
+        "admit": [(0.05, 0.09, [(512, [400, 300]), (128, [90])]),
+                  (4.0, 4.1, [(128, [1])])]}
+
+
+def check_declared(bench, root):
+    """Every name this family declared is there, lists this cell, and its
+    reader gives no number on records without a device trace."""
+    cfg = harness.Cell(CELL, root=root).config
+    tiny.check_cell_declares(bench, root, CELL, DECLARED, [
+        _records(cfg, host=HOST, seconds=51.0, traced_s=3.0),
+        {"config": cfg}])
+
+
 def test_jamba_readers_on_synthetic_records():
     cfg = harness.Cell(CELL).config
     step_ops = [lib.UPDATE_KERNEL, lib.CONV_STEP_KERNEL, lib.GQA_KERNEL]
@@ -149,11 +166,7 @@ def test_jamba_readers_on_synthetic_records():
         ["%ssm_causal_conv.4 = bf16[2,1024,5120]{2,1,0} custom-call(",
          0.002, 26],
         ["%fusion.12 = bf16[256,8192]{1,0} fusion(", 0.3, 900]]}
-    host = {"step": [(0.0, 0.1, (250, 150_000)), (0.1, 0.2, (252, 151_000)),
-                     (0.2, 0.3, (0, 0)), (5.0, 5.1, (256, 1))],
-            "admit": [(0.05, 0.09, [(512, [400, 300]), (128, [90])]),
-                      (4.0, 4.1, [(128, [1])])]}
-    rec = _records(cfg, host=host, seconds=51.0, traced_s=3.0)
+    rec = _records(cfg, host=HOST, seconds=51.0, traced_s=3.0)
     rec["trace"] = trace
     assert lib.decode_dispatch_ms(rec) == pytest.approx(80.0)
     assert lib.prefill_dispatch_ms(rec) == pytest.approx(90.0)
@@ -169,18 +182,8 @@ def test_jamba_readers_on_synthetic_records():
     assert 30 < lib.state_update_roofline(rec) < 60
     assert 0 < lib.prefill_scan_roofline(rec) < 20
     assert 0 < lib.gqa_decode_attention_roofline(rec) < 100
-    # nothing to read is no number
-    bare = _records(cfg, host=host, seconds=51.0, traced_s=3.0)
-    names = [m["name"] for m in harness.Cell(CELL).per_layer()]
-    mine = [n for n in names if n.startswith("jamba_")]
-    assert len(mine) == 8
-    assert [n for n in names if not n.startswith("jamba_")] == [
-        "build_s", "compile_s", "cache_misses", "trace_lower_s"] + SHARED
-    for name in mine + SHARED:
-        reader = harness.load_module(
-            "%s/perfbench/layer_metrics/%s.py" % (tiny.ROOT, name), name)
-        assert reader.read(bare) is None, name
-        assert reader.read({"config": cfg}) is None, name
+    check_declared(harness.load_json(tiny.ROOT + "/BENCHMARK.json"),
+                   tiny.ROOT)
     rounds = [{"id": i, "spans": [
         {"name": "round", "t0": 0.0, "t1": 0.1, "cpu": 0.03, "parent": None,
          "prefill_tokens": 700, "prefill_pad_tokens": 1348,

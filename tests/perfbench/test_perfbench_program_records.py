@@ -81,7 +81,8 @@ def test_round_statistics():
     assert table["step"][1] == pytest.approx(400.0)
     assert table["step"][2] == pytest.approx(50.0)
     assert pr.round_counts(rounds) == {
-        "live": [3, 3], "backlog": [2, 2], "tokens": [12, 12]}
+        "live": [3, 3], "backlog": [2, 2], "tokens": [12, 12],
+        "admit_rows": [], "cancel_rows": []}
 
 
 def _ramp_round(rid, t):
@@ -139,6 +140,22 @@ def test_log_rounds_prints_the_rounds_counts_beside_the_clients_rate(capfd):
     assert "24 tokens handed to the streams = 12.0 tokens/s" in out
     assert "against 11.5 that the clients counted" in out
     assert "admit.dispatch" in out
+    # a program that counts no rows: no line of them
+    assert "admit_rows" not in out
+    # an ``admit`` or a ``cancel`` span is a batch: the report says how
+    # many requests and slots the batches held (a round without the key
+    # admitted or released none)
+    rounds[0]["spans"][0].update(admit_rows=25, cancel_rows=27)
+    rounds[1]["spans"][0].update(admit_rows=23)
+    pr.log_rounds({}, rounds)
+    out = "".join(capfd.readouterr())
+    assert ("a round's batches held, in the mean: 24.0 requests admitted "
+            "(admit_rows), 13.5 slots released by cancels (cancel_rows)"
+            ) in out
+    # a session that counts no admissions (it counts prompts a prefill)
+    del rounds[0]["spans"][0]["admit_rows"], rounds[1]["spans"][0]["admit_rows"]
+    pr.log_rounds({}, rounds)
+    assert "in the mean: 13.5 slots released" in "".join(capfd.readouterr())
 
 
 def test_offcpu_share():

@@ -15,11 +15,6 @@ from perfbench import harness, kernel_costs_trinity as costs
 from perfbench import metric_lib_trinity as lib
 
 CELL = "serve_trinity_longctx"
-SHARED = ["glm_prefill_prompts_per_dispatch_p50", "glm_round_host_ms_p50",
-          "glm_device_idle_share", "glm_loadgen_late_p99_ms",
-          "glm_admit_self_ms_p50", "glm_cancel_ms_p50", "glm_handoff_ms_p50",
-          "glm_worker_offcpu_share", "glm_exec_host_ms_per_dispatch",
-          "glm_idle_unattributed_share"]
 LIMITS = ("logit_rel_l2", "expert_choice_diff_share",
           "expert_choice_margin_max")
 
@@ -163,6 +158,32 @@ def _records(cfg, **serve):
             "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
 
 
+# the per-layer entries PR 33 declared for this cell, by name: eleven of
+# its own and the decoder-only cells' shared ones
+DECLARED = [
+    "trinity_decode_dispatch_device_ms", "trinity_prefill_dispatch_device_ms",
+    "trinity_decode_hbm_roofline", "trinity_window_decode_attention_roofline",
+    "trinity_full_decode_attention_roofline",
+    "trinity_prefill_attention_roofline", "trinity_expert_matmul_roofline",
+    "trinity_expert_time_share", "trinity_attention_time_share",
+    "trinity_window_rows_share", "trinity_prefill_pad_share"] \
+    + tiny.DECODER_SHARED
+HOST = {"step": [(0.0, 0.1, (90, 250_000, 140_000)),
+                 (0.1, 0.2, (92, 260_000, 150_000)),
+                 (0.2, 0.3, (0, 0, 0)), (5.0, 5.1, (96, 1, 1))],
+        "admit": [(0.05, 0.09, [(4096, [3000, 2500]), (256, [200])]),
+                  (4.0, 4.1, [(256, [1])])]}
+
+
+def check_declared(bench, root):
+    """Every name this family declared is there, lists this cell, and its
+    reader gives no number on records without a device trace."""
+    cfg = harness.Cell(CELL, root=root).config
+    tiny.check_cell_declares(bench, root, CELL, DECLARED, [
+        _records(cfg, host=HOST, seconds=51.0, traced_s=3.0),
+        {"config": cfg}])
+
+
 def test_trinity_readers_on_hand_made_records():
     cfg = harness.Cell(CELL).config
     step_ops = [lib.WINDOW_KERNEL, lib.FULL_KERNEL, lib.EXPERT_KERNEL]
@@ -180,19 +201,14 @@ def test_trinity_readers_on_hand_made_records():
         ["%gmm.4 = f32[768,1024]{1,0} custom-call(", 0.2, 300],
         ["%sort.9 = (s32[768]) sort(", 0.01, 100],
         ["%fusion.12 = bf16[96,6144]{1,0} fusion(", 0.3, 900]]}
-    host = {"step": [(0.0, 0.1, (90, 250_000, 140_000)),
-                     (0.1, 0.2, (92, 260_000, 150_000)),
-                     (0.2, 0.3, (0, 0, 0)), (5.0, 5.1, (96, 1, 1))],
-            "admit": [(0.05, 0.09, [(4096, [3000, 2500]), (256, [200])]),
-                      (4.0, 4.1, [(256, [1])])]}
-    rec = _records(cfg, host=host, seconds=51.0, traced_s=3.0)
+    rec = _records(cfg, host=HOST, seconds=51.0, traced_s=3.0)
     rec["trace"] = trace
     assert lib.decode_dispatch_ms(rec) == pytest.approx(60.0)
     assert lib.prefill_dispatch_ms(rec) == pytest.approx(300.0)
     # 4 token steps of ~8.5 GB at 819 GB/s over 60 ms
     want = [sum(costs.decode_step_bytes(cfg, rows + live * j, seen)
                 for j in range(4)) / 819e9 / 0.06
-            for _t0, _t1, (live, rows, seen) in host["step"][:2]]
+            for _t0, _t1, (live, rows, seen) in HOST["step"][:2]]
     hbm = lib.decode_hbm_roofline(rec)
     assert hbm == pytest.approx(100 * sum(want) / 2) and 60 < hbm < 80
     # 4 window layers x 4 steps x 2 dispatches of ~0.29 GB at 819 GB/s
@@ -202,24 +218,14 @@ def test_trinity_readers_on_hand_made_records():
     full = lib.full_decode_attention_roofline(rec)
     assert full == pytest.approx(
         100 * sum((rows + live * j) * 2048 for _t0, _t1, (live, rows, _s)
-                  in host["step"][:2] for j in range(4)) / 819e9 / 0.020,
+                  in HOST["step"][:2] for j in range(4)) / 819e9 / 0.020,
         rel=0.01)
     assert 0 < lib.prefill_attention_roofline(rec) < 100
     assert 0 < lib.expert_matmul_roofline(rec) < 100
     assert lib.expert_time_share(rec) == pytest.approx(100 * 0.21 / 0.6)
     assert lib.attention_time_share(rec) == pytest.approx(100 * 0.08 / 0.6)
-    # nothing to read is no number
-    bare = _records(cfg, host=host, seconds=51.0, traced_s=3.0)
-    names = [m["name"] for m in harness.Cell(CELL).per_layer()]
-    mine = [n for n in names if n.startswith("trinity_")]
-    assert len(mine) == 11
-    assert [n for n in names if not n.startswith("trinity_")] == [
-        "build_s", "compile_s", "cache_misses", "trace_lower_s"] + SHARED
-    for name in mine + SHARED:
-        reader = harness.load_module(
-            "%s/perfbench/layer_metrics/%s.py" % (tiny.ROOT, name), name)
-        assert reader.read(bare) is None, name
-        assert reader.read({"config": cfg}) is None, name
+    check_declared(harness.load_json(tiny.ROOT + "/BENCHMARK.json"),
+                   tiny.ROOT)
     rounds = [{"id": i, "spans": [
         {"name": "round", "t0": 0.0, "t1": 0.1, "cpu": 0.03, "parent": None,
          "prefill_tokens": 5700, "prefill_pad_tokens": 2492,
