@@ -28,6 +28,16 @@ from paddle_tpu.kernels.paged_attention import KernelCompileError
 GROUPED_KERNEL_NAME = "gmm"
 _ROWS = 128       # rows a tile: a group of a few rows pays one tile of these
 _COLS = 512       # output columns a tile
+_DEPTH = 2048     # the contraction in ONE tile up to this (two buffers of a
+#                   [6144, 512] weight tile pass the 16 MB of scoped VMEM)
+
+
+def _depth_tile(k):
+    """The whole contraction where it fits a tile, else its largest
+    divisor of whole lanes under ``_DEPTH``."""
+    if k <= _DEPTH:
+        return k
+    return next((t for t in range(_DEPTH, 127, -128) if k % t == 0), k)
 
 
 def grouped_matmul_reference(lhs, rhs, group_sizes):
@@ -53,7 +63,7 @@ def grouped_matmul(lhs, rhs, group_sizes, force_reference=False,
     try:
         out = gmm(lhs, rhs, group_sizes.astype(jnp.int32),
                   preferred_element_type=jnp.float32,
-                  tiling=(_ROWS, k, min(n, _COLS)),
+                  tiling=(_ROWS, _depth_tile(k), min(n, _COLS)),
                   interpret=not _is_tpu_target())
     except Exception as exc:
         raise KernelCompileError(

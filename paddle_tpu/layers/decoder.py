@@ -16,6 +16,11 @@ __all__ = [
     "latent_row_prefill",
     "latent_paged_attention",
     "latent_prefill_attention",
+    "indexer_rows",
+    "index_select_decode",
+    "sparse_latent_paged_attention",
+    "index_select_prefill",
+    "sparse_latent_prefill_attention",
     "slot_rows_write",
     "embedding_rows",
 ]
@@ -54,14 +59,16 @@ def dense_projection(x, w, out_dtype="input", name=None):
 
 def dropless_moe_ffn(x, router_w, router_bias, expert_w_gate, expert_w_up,
                      expert_w_down, shared=None, valid=None, top_k=1,
-                     norm_topk=True, scale=1.0, name=None):
+                     norm_topk=True, scale=1.0, held_first=None, name=None):
     """Routed experts with no capacity and no dropped token: sigmoid
     scores plus a selection bias, the ``top_k`` largest chosen, the
     (token, expert) pairs sorted by expert and computed as grouped matrix
     products; ``shared`` = (gate, up, down) of a shared expert added once.
     ``valid`` [N] marks the tokens that exist (others are neither computed
-    nor counted). Returns (out [N, D], chosen [N, top_k], tokens each
-    expert got [E])."""
+    nor counted). With ``held_first`` the expert weights are a shard of
+    the router's experts that starts there: the pairs of experts held
+    elsewhere are left out of the sum. Returns (out [N, D], chosen [N, top_k],
+    tokens each held expert got [E])."""
     helper = LayerHelper("dropless_moe_ffn", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     chosen = helper.create_variable_for_type_inference(
@@ -81,16 +88,19 @@ def dropless_moe_ffn(x, router_w, router_bias, expert_w_gate, expert_w_up,
         outputs={"Out": [out], "Chosen": [chosen],
                  "ExpertTokens": [counts]},
         attrs={"top_k": int(top_k), "norm_topk": bool(norm_topk),
-               "scale": float(scale)})
+               "scale": float(scale),
+               "held_first": -1 if held_first is None else int(held_first)})
     return out, chosen, counts
 
 
 def latent_rope_rows(q, kva, kv_norm, heads, nope_dim, rope_dim, theta,
-                     positions=None, period=0, epsilon=1e-5, name=None):
+                     positions=None, period=0, epsilon=1e-5,
+                     interleave=False, name=None):
     """The query ``[N, H, dn + dr]`` with RoPE on its rotary part, and the
     row to cache ``[N, C + dr]`` = ``[RMSNorm(ckv) | RoPE(k_rope)]``.
     ``positions`` [N] (decode), or none and ``period`` = the bucket length
-    (prefill: token ``n`` stands at ``n % period``)."""
+    (prefill: token ``n`` stands at ``n % period``). ``interleave``:
+    RoPE over adjacent pairs, else over the two halves."""
     helper = LayerHelper("latent_rope_rows", name=name)
     q_out = helper.create_variable_for_type_inference(q.dtype)
     row = helper.create_variable_for_type_inference(q.dtype)
@@ -102,7 +112,8 @@ def latent_rope_rows(q, kva, kv_norm, heads, nope_dim, rope_dim, theta,
         outputs={"QOut": [q_out], "Row": [row]},
         attrs={"heads": int(heads), "nope_dim": int(nope_dim),
                "rope_dim": int(rope_dim), "theta": float(theta),
-               "period": int(period), "epsilon": float(epsilon)})
+               "period": int(period), "epsilon": float(epsilon),
+               "interleave": bool(interleave)})
     return q_out, row
 
 
@@ -144,6 +155,83 @@ def latent_prefill_attention(q, rows, kv_b, prompts, nope_dim, name=None):
     (bucket) length through the flash kernel: ``[N, H * v_dim]``."""
     return _one("latent_prefill_attention",
                 {"Q": [q], "Rows": [rows], "KVB": [kv_b]},
+                {"prompts": int(prompts), "nope_dim": int(nope_dim)},
+                name=name)
+
+
+def indexer_rows(cq, x, w_q, w_k, k_scale, k_shift, w_w, heads, rope_dim,
+                 theta, positions=None, period=0, interleave=True,
+                 epsilon=1e-6, name=None):
+    """A sparse-attention indexer's rows a token
+    (``ops/sparse_attention_ops.py``): its heads' queries ``[N, J, dI]``
+    from the compressed query ``cq``, its one key ``[N, dI]`` (the narrow
+    pool's row) and its heads' weights ``[N, J]`` float32 from the block's
+    normed input ``x``; ``positions`` / ``period`` as ``latent_rope_rows``."""
+    helper = LayerHelper("indexer_rows", name=name)
+    q = helper.create_variable_for_type_inference(x.dtype)
+    k = helper.create_variable_for_type_inference(x.dtype)
+    w = helper.create_variable_for_type_inference("float32")
+    inputs = {"CQ": [cq], "X": [x], "WQ": [w_q], "WK": [w_k],
+              "KScale": [k_scale], "KShift": [k_shift], "WW": [w_w]}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+    helper.append_op(
+        type="indexer_rows", inputs=inputs,
+        outputs={"Q": [q], "K": [k], "W": [w]},
+        attrs={"heads": int(heads), "rope_dim": int(rope_dim),
+               "theta": float(theta), "period": int(period),
+               "interleave": bool(interleave), "epsilon": float(epsilon)})
+    return q, k, w
+
+
+def index_select_decode(q, w, pool, page_table, lengths, top_k, name=None):
+    """Every slot's ``top_k`` cached positions of largest index score, as
+    positions ``[S, top_k]`` int32 (``-1``: none, last in the row)."""
+    helper = LayerHelper("index_select_decode", name=name)
+    out = helper.create_variable_for_type_inference(
+        "int32", stop_gradient=True)
+    helper.append_op(
+        type="index_select_decode",
+        inputs={"Q": [q], "W": [w], "Pool": [pool],
+                "PageTable": [page_table], "Lengths": [lengths]},
+        outputs={"Selected": [out]}, attrs={"top_k": int(top_k)})
+    return out
+
+
+def sparse_latent_paged_attention(q, kv_b, pool, page_table, selected,
+                                  nope_dim, name=None):
+    """``latent_paged_attention`` of every slot over its ``selected``
+    positions alone."""
+    return _one("sparse_latent_paged_attention",
+                {"Q": [q], "KVB": [kv_b], "Pool": [pool],
+                 "PageTable": [page_table], "Selected": [selected]},
+                {"nope_dim": int(nope_dim)}, name=name)
+
+
+def index_select_prefill(q, k, w, lens, prompts, top_k, name=None):
+    """Every prompt row's ``top_k`` earlier positions of largest index
+    score, as a mask ``[prompts, T, T]`` int8 (nothing for the rows past
+    a prompt's length ``lens`` [prompts])."""
+    helper = LayerHelper("index_select_prefill", name=name)
+    out = helper.create_variable_for_type_inference(
+        "int8", stop_gradient=True)
+    helper.append_op(
+        type="index_select_prefill",
+        inputs={"Q": [q], "K": [k], "W": [w], "Lens": [lens]},
+        outputs={"Mask": [out]},
+        attrs={"prompts": int(prompts), "top_k": int(top_k)})
+    return out
+
+
+def sparse_latent_prefill_attention(q, rows, kv_b, mask, lens, prompts,
+                                    nope_dim, name=None):
+    """Expanded-form prefill attention under a choice's ``mask`` (None:
+    every earlier position), bfloat16 on the MXU; the rows past a
+    prompt's length ``lens`` [prompts] are not computed."""
+    inputs = {"Q": [q], "Rows": [rows], "KVB": [kv_b], "Lens": [lens]}
+    if mask is not None:
+        inputs["Mask"] = [mask]
+    return _one("sparse_latent_prefill_attention", inputs,
                 {"prompts": int(prompts), "nope_dim": int(nope_dim)},
                 name=name)
 

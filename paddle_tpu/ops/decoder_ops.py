@@ -54,6 +54,26 @@ def rope_rotate_half(x, positions, theta):
         x.dtype)
 
 
+def rope_interleaved(x, positions, theta):
+    """RoPE over adjacent pairs ``(x[2i], x[2i+1])`` (the complex-number
+    convention of the DeepSeek / GLM modelling code, ``rope_interleave``):
+    shapes as ``rope_rotate_half``; the result keeps the pairs in place."""
+    half = x.shape[-1] // 2
+    inv_freq = jnp.power(
+        _F32(theta), -jnp.arange(half, dtype=_F32) / half)
+    ang = positions.astype(_F32)[:, None] * inv_freq[None, :]
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (half,)
+    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    pairs = x.astype(_F32).reshape(x.shape[:-1] + (half, 2))
+    re, im = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([re * cos - im * sin, re * sin + im * cos], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def rope(interleave):
+    return rope_interleaved if interleave else rope_rotate_half
+
+
 def _lower_rms_norm(ctx, ins, attrs):
     return {"Out": rms_norm(ins["X"][0], ins["Scale"][0],
                             float(attrs.get("epsilon", 1e-5)))}
@@ -99,13 +119,14 @@ def _lower_latent_rope_rows(ctx, ins, attrs):
     H, dn = int(attrs["heads"]), int(attrs["nope_dim"])
     dr, theta = int(attrs["rope_dim"]), float(attrs["theta"])
     C = kva.shape[-1] - dr
+    rotate = rope(attrs.get("interleave", False))
     q = q.reshape(q.shape[0], H, dn + dr)
     q = jnp.concatenate(
-        [q[..., :dn], rope_rotate_half(q[..., dn:], pos, theta)], -1)
+        [q[..., :dn], rotate(q[..., dn:], pos, theta)], -1)
     ckv = rms_norm(kva[:, :C], ins["KVNorm"][0],
                    float(attrs.get("epsilon", 1e-5)))
     row = jnp.concatenate(
-        [ckv, rope_rotate_half(kva[:, C:], pos, theta)], -1)
+        [ckv, rotate(kva[:, C:], pos, theta)], -1)
     return {"QOut": q, "Row": row}
 
 
@@ -113,7 +134,7 @@ register_op(
     "latent_rope_rows", inputs=["Q", "KVA", "KVNorm", "Positions"],
     outputs=["QOut", "Row"],
     attrs={"heads": 1, "nope_dim": 0, "rope_dim": 0, "theta": 10000.0,
-           "period": 0, "epsilon": 1e-5},
+           "period": 0, "epsilon": 1e-5, "interleave": False},
     lower=_lower_latent_rope_rows, grad=None)
 
 

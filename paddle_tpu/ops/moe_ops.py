@@ -183,7 +183,8 @@ def route_top_k(x, router_w, router_bias, top_k, norm_topk, scale):
     return chosen.astype(jnp.int32), w * scale
 
 
-def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, valid=None):
+def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, valid=None,
+                     first=None):
     """Every (token, chosen expert) pair is computed: the pairs are
     sorted by expert and each expert's rows go through its gated FFN as
     ONE group of three grouped matrix products
@@ -191,8 +192,13 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, valid=None):
     ``jax.lax.ragged_dot`` elsewhere), so
     no capacity exists and no token can be dropped. Rows of tokens that
     are not ``valid`` (a prefill batch's padding, a decode step's empty
-    slots) sort behind every group and are not computed. Returns (the
-    weighted sum [N, D] in float32, tokens each expert got [E])."""
+    slots) sort behind every group and are not computed. With ``first``
+    the weights are a SHARD of the experts, ``first .. first + E - 1`` of
+    those the router chose among: a pair whose expert is not held sorts
+    behind the groups like a token that does not exist, and its part of
+    the sum is left out (the chip that holds the expert adds it). Returns
+    (the weighted sum [N, D] in float32, tokens each held expert got
+    [E])."""
     from paddle_tpu.kernels.grouped_matmul import grouped_matmul
 
     def product(lhs, rhs):
@@ -201,7 +207,13 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, valid=None):
     n, k = chosen.shape
     e = w_gate.shape[0]
     flat = chosen.reshape(-1)
-    if valid is not None:
+    if first is not None:
+        flat = flat - first
+        held = (flat >= 0) & (flat < e)
+        if valid is not None:
+            held = held & jnp.repeat(valid, k)
+        flat = jnp.where(held, flat, e)
+    elif valid is not None:
         flat = jnp.where(jnp.repeat(valid, k), flat, e)
     order = jnp.argsort(flat, stable=True)
     counts = jnp.bincount(flat, length=e + 1)[:e].astype(jnp.int32)
@@ -209,13 +221,18 @@ def dropless_experts(x, chosen, weights, w_gate, w_up, w_down, valid=None):
     h = jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)
     y = product(h.astype(x.dtype), w_down)
     y = y * weights.reshape(-1)[order][:, None]
-    if valid is not None:
+    if valid is not None or first is not None:
         # rows past the last group are not written by the grouped product
         y = jnp.where((jnp.arange(n * k) < jnp.sum(counts))[:, None], y, 0.0)
     # back to (token, rank) order: a gather through the inverse permutation
     inverse = jnp.zeros((n * k,), jnp.int32).at[order].set(
         jnp.arange(n * k, dtype=jnp.int32))
     return y[inverse].reshape(n, k, -1).sum(axis=1), counts
+
+
+# tokens a block of a held shard's dispatch (16384 token places x 8
+# choices would sort 131072 rows of 6144 for the ~8192 that are held)
+_HELD_TOKEN_BLOCK = 2048
 
 
 def _lower_dropless_moe_ffn(ctx, ins, attrs):
@@ -230,10 +247,30 @@ def _lower_dropless_moe_ffn(ctx, ins, attrs):
             x, ins["RouterW"][0], ins["RouterBias"][0],
             int(attrs["top_k"]), bool(attrs.get("norm_topk", True)),
             float(attrs.get("scale", 1.0)))
+    first = int(attrs.get("held_first", -1))
+    first = None if first < 0 else first
+    n = x.shape[0] // _HELD_TOKEN_BLOCK
     with jax.named_scope("dropless_experts"):
-        routed, counts = dropless_experts(
-            x, chosen, weights, ins["ExpertWGate"][0], ins["ExpertWUp"][0],
-            ins["ExpertWDown"][0], valid)
+        experts = (ins["ExpertWGate"][0], ins["ExpertWUp"][0],
+                   ins["ExpertWDown"][0])
+        if first is None or n < 2 or x.shape[0] % _HELD_TOKEN_BLOCK:
+            routed, counts = dropless_experts(x, chosen, weights, *experts,
+                                              valid=valid, first=first)
+        else:
+            # a shard's pairs are few among the rows sorted for them: a
+            # large dispatch's tokens go through in blocks, so that the
+            # sorted copies stay ``block * top_k`` rows whatever it holds
+            def one(part):
+                return dropless_experts(part[0], part[1], part[2], *experts,
+                                        valid=part[3], first=first)
+
+            live = (jnp.ones((x.shape[0],), bool) if valid is None
+                    else valid)
+            routed, counts = jax.lax.map(one, tuple(
+                a.reshape((n, _HELD_TOKEN_BLOCK) + a.shape[1:])
+                for a in (x, chosen, weights, live)))
+            routed = routed.reshape((x.shape[0],) + routed.shape[2:])
+            counts = counts.sum(axis=0)
     out = routed
     if ins.get("SharedWGate"):
         # the shared expert sees every token once, outside the routing
@@ -249,7 +286,9 @@ register_op(
             "ExpertWDown", "SharedWGate", "SharedWUp", "SharedWDown",
             "Valid"],
     outputs=["Out", "Chosen", "ExpertTokens"],
-    attrs={"top_k": 1, "norm_topk": True, "scale": 1.0},
+    # held_first >= 0: the expert weights are the shard that starts there
+    # (the router keeps all its outputs)
+    attrs={"top_k": 1, "norm_topk": True, "scale": 1.0, "held_first": -1},
     lower=_lower_dropless_moe_ffn,
     grad=None,
 )

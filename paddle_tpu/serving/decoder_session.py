@@ -9,7 +9,9 @@ The model is chosen from its description (``builder_for``): a dict with
 window a slot a state-space layer, K/V page pools for its few attention
 layers), one with ``kv_lora_rank`` the latent-attention, routed-expert
 decoder (``models/latent_moe_decoder.py``: one pool of latent rows a
-layer), one with ``layer_types`` and a ``sliding_window`` the decoder of
+layer and, with ``index_topk``, an indexer's NARROWER pool beside it in
+the layers that have one: pools of two row widths under the one growing
+page table), one with ``layer_types`` and a ``sliding_window`` the decoder of
 window and full attention layers with routed experts
 (``models/windowed_moe_decoder.py``: a ring of K/V pages a slot a window
 layer beside a full layer's growing pools). The session knows no model:
@@ -36,8 +38,14 @@ src_len=)``. What differs is the model:
   prefills them SEVERAL A DISPATCH: one dispatch per length bucket and
   ``prompts_per_dispatch(bucket)`` prompts (a dispatch reads every
   weight of the model, every expert's too; a dispatch of one would pay
-  that per prompt). The prefill writes the prompt's rows into the slot's
-  pages, installs the slot's fixed-size state and samples the first token.
+  that per prompt). A prefill program walks every token place of its
+  shape, a row of padding like a prompt's: with ``prefill_rungs`` the
+  builder gives a bucket one program a RUNG of prompt rows (1, 2, 4, ...
+  up to the most a dispatch takes) and a dispatch runs the least rung
+  that holds its prompts, so a loop that frees one slot at a time does
+  not walk the whole budget for one prompt. The prefill writes the
+  prompt's rows into the slot's pages, installs the slot's fixed-size
+  state and samples the first token.
 * **One page table for every KIND of pool.** The pools that grow with
   the sequence share one table (one pool of latent rows a layer, or a K
   and a V pool an attention layer); a builder's windowed pools share
@@ -64,19 +72,28 @@ Tracing (``observability/tracing.py``): under the worker's round,
 ``admit`` > ``prefill`` > ``prefill.dispatch`` per bucket dispatch and
 ``step`` > ``step.dispatch``; the round counts ``prefill_prompts``,
 ``prefill_tokens``, ``prefill_dispatches``,
-``prefill_pad_tokens`` (bucket rows the dispatch walks for nothing: its
-``prompts x bucket`` less the prompts' own tokens), ``pages_in_use``
+``prefill_pad_tokens`` (token places the dispatch walks for nothing: its
+program's ``rows x bucket`` less the prompts' own tokens), ``pages_in_use``
 (with windowed pools also by kind, ``full_pages_in_use`` and
 ``window_pages_in_use``, with ``window_pages_released``, the pages the
 round gave back from behind its slots' windows, and ``full_rows_visible``
 / ``window_rows_visible``, the rows the decode dispatch's slots could see
 in a full layer and in a window layer), ``state_slots_live`` (with a
 model that has per-slot arrays: the slots whose rows the decode dispatch
-had to update) and, with routed experts,
+had to update), under learned sparse attention ``latent_rows_resident``
+(the rows the decode dispatch's slots hold a layer),
+``latent_rows_selected`` (the sum over its slots of min(rows,
+``index_topk``): what a layer's attention reads of them) and
+``index_pages_in_use`` (the pages of an indexer's narrow pool: the latent
+pools' own, one table) and, with routed experts,
 ``expert_max_over_mean`` (the most loaded expert's tokens over the mean,
 over the dispatch's steps and expert layers, from the counts the expert
 op returns WITH the step's tokens: no dispatch and no device sync of
-their own).
+their own) and, where the model holds a SHARD of its experts,
+``experts_routed_tokens`` / ``experts_held_tokens`` (the (token, expert)
+choices of the dispatch's steps and those that fell on a held expert)
+and ``experts_held_hit`` (the held experts a step and layer that got a
+token), from the same counts.
 """
 
 import collections
@@ -111,8 +128,13 @@ def builder_for(desc):
     if "kv_lora_rank" in desc:
         from paddle_tpu.models.latent_moe_decoder import (
             build_latent_moe_decoder,
+            check_served,
         )
 
+        # a description the builder does not serve (group-limited routing,
+        # an index_topk_pattern, indexer_types that start with `shared`)
+        # is refused here, by the key at fault, before anything is built
+        check_served(desc)
         return build_latent_moe_decoder
     if "layer_types" in desc and desc.get("sliding_window") is not None:
         from paddle_tpu.models.windowed_moe_decoder import (
@@ -229,6 +251,10 @@ class DecoderOnlySession(object):
     tokens_per_dispatch : decode tokens a slot a dispatch.
     prefill_buckets, prefill_token_budget : the builder's (defaults:
         powers of two of the page size; 2048 tokens a dispatch).
+    prefill_rungs : ask the builder for a prefill program a rung of prompt
+        rows under a bucket's most (module docstring): more programs to
+        compile, for dispatches of few LONG prompts. A builder that has
+        no rungs refuses the keyword.
     num_pages : size of the pools that grow with the sequence, trash page
         included (default: full occupancy; a ring is always at full
         occupancy).
@@ -248,7 +274,8 @@ class DecoderOnlySession(object):
     def __init__(self, exe, desc, num_slots, max_prompt, max_new_tokens,
                  page_size, tokens_per_dispatch=4, prefill_buckets=None,
                  prefill_token_budget=2048, num_pages=None, sampler=None,
-                 scope=None, dtype="bfloat16", probe_rows=0):
+                 scope=None, dtype="bfloat16", probe_rows=0,
+                 prefill_rungs=False):
         from paddle_tpu.executor import global_scope
 
         self._exe = exe
@@ -270,7 +297,8 @@ class DecoderOnlySession(object):
             num_pages=num_pages,
             prefill_token_budget=prefill_token_budget, sampler=sampler,
             dtype=dtype, probe_rows=probe_rows,
-            tokens_per_dispatch=self._K)
+            tokens_per_dispatch=self._K,
+            **({"prefill_rungs": True} if prefill_rungs else {}))
         self.geometry = geo = built["geometry"]
         self._buckets = geo["buckets"]
         if self._buckets[-1] < self._max_prompt:
@@ -292,7 +320,11 @@ class DecoderOnlySession(object):
                 ring["rows_feed"], window=int(ring["window"])))
         self._slot_pages = _PagesOfSlot(self._kinds)
         self._slot_state = bool(geo["state"]["slot_arrays"])
-        self._prefill_progs = built["prefill"]
+        # {bucket: {prompt rows: program}}; one rung, the most a dispatch
+        # takes, where the builder gives no others
+        self._prefill_progs = built.get("prefill_rungs") or {
+            T: {self._per_dispatch[T]: prog}
+            for T, prog in built["prefill"].items()}
         self._step_prog = built["step"]
         self._fetch = built["fetches"]
         # what the frontend's worker reads of a session (its package-
@@ -322,6 +354,10 @@ class DecoderOnlySession(object):
         self.last_prefills = []
         self.last_step = (0, 0)
         self.last_window_rows = 0
+        # under learned sparse attention, the rows a layer's attention
+        # reads of ``last_step``'s resident ones: index_topk a slot at most
+        self._index_topk = int(geo.get("index_topk") or 0)
+        self.last_selected_rows = 0
         self._released = 0       # ring pages given back since _count()
         self.probe_slots = np.zeros((int(probe_rows),), "int64")
         self.admit_token_budget = None
@@ -510,8 +546,10 @@ class DecoderOnlySession(object):
 
     def _prefill(self, bucket, reqs):
         """One prefill dispatch of ``reqs`` (all of ``bucket``): slots
-        and pages are taken first, the rows of padding point nowhere."""
-        B, T = self._per_dispatch[bucket], bucket
+        and pages are taken first, the rows of padding point nowhere.
+        It runs the least rung of rows that holds them."""
+        T = bucket
+        B = min(r for r in self._prefill_progs[T] if r >= len(reqs))
         ids = np.zeros((B, T), "int64")
         lens = np.zeros((B,), "int64")
         slot_idx = np.full((B,), self._S, "int64")
@@ -545,7 +583,7 @@ class DecoderOnlySession(object):
                     feed[kind.rows_feed] = rows
                 with _tracing.span(".dispatch"):
                     (first,) = self._exe.run(
-                        self._prefill_progs[T], feed=feed,
+                        self._prefill_progs[T][B], feed=feed,
                         fetch_list=[self._fetch["first_token"]],
                         scope=self._scope)
             first = np.asarray(first).reshape(-1)
@@ -617,7 +655,7 @@ class DecoderOnlySession(object):
             t_step = time.time()
             pre_pos = {s: self._live[s]["pos"]
                        for s in self._slot_traces if s in self._live}
-        rows = ring_rows = 0
+        rows = ring_rows = chosen_rows = 0
         ring = self._kinds[-1].window or 0
         for slot, st in self._live.items():
             # step j writes the row of position len + n - 1 + j
@@ -626,8 +664,10 @@ class DecoderOnlySession(object):
                 self._released += kind.hold(slot, at, at + self._K)
             rows += at + 1
             ring_rows += min(at + 1, ring)
+            chosen_rows += min(at + 1, self._index_topk)
         self.last_step = (len(self._live), rows)
         self.last_window_rows = ring_rows
+        self.last_selected_rows = chosen_rows
         fetch = [self._fetch["token"]]
         if self._fetch.get("expert_tokens"):
             fetch.append(self._fetch["expert_tokens"])
@@ -672,12 +712,30 @@ class DecoderOnlySession(object):
             self._released = 0
         if self._slot_state:
             counters["state_slots_live"] = self.last_step[0]
+        if self._index_topk:
+            # the narrow pools share the latent pools' table: a page in
+            # use is one page of every pool of either width
+            counters.update(
+                latent_rows_resident=self.last_step[1],
+                latent_rows_selected=self.last_selected_rows,
+                index_pages_in_use=self._kinds[0].pool.allocated_count)
         if expert_tokens is not None:
             c = np.asarray(expert_tokens, "float64")    # [K, layers, E]
             mean = c.mean(axis=-1)
             ratio = c.max(axis=-1)[mean > 0] / mean[mean > 0]
             if ratio.size:
                 counters["expert_max_over_mean"] = float(ratio.mean())
+            experts = self.geometry.get("experts")
+            if experts and experts["held"] < experts["of"]:
+                # a shard of the experts: the (token, expert) choices the
+                # dispatch's steps made, those that fell on a held expert,
+                # and the held experts a step and layer that got any
+                counters.update(
+                    experts_routed_tokens=int(
+                        c.shape[0] * c.shape[1] * self.last_step[0]
+                        * experts["top_k"]),
+                    experts_held_tokens=int(c.sum()),
+                    experts_held_hit=float((c > 0).sum(axis=-1).mean()))
         self.last_counters = counters
         if _tracing.ENABLED:
             for key, value in counters.items():
