@@ -10,19 +10,24 @@ row of all heads contiguous — plus a per-slot page-index table
 ``[S, pages_per_slot]`` and a length vector ``[S]`` — and the decode
 kernel is ragged over it:
 
-* Grid ``(slot, page)`` with the page table scalar-prefetched
-  (``pltpu.PrefetchScalarGridSpec``): the K/V block index maps resolve
-  ``table[s, p]`` BEFORE the kernel body runs, so each grid step DMAs
-  exactly one resident page — the classic TPU paged-attention shape.
-* Per-slot lengths bound the scan: pages at ``p * page_size >=
-  length[s]`` skip their compute entirely (``pl.when``), and the host
-  fills a slot's unprovisioned table tail with its LAST valid page id,
-  so the skipped steps' index maps repeat the previous block and the
-  Pallas pipeline elides the copy (revolving-buffer rule: a repeated
-  block index issues no new DMA). Decode traffic is proportional to
-  pages actually RESIDENT, not ``S x max_length`` —
-  ``grid_accounting`` models exactly that contract and the bench/CI
-  legs pin it.
+* Grid ``(slot,)`` with the page table and the lengths scalar-prefetched
+  (``pltpu.PrefetchScalarGridSpec``) and the pools left in HBM
+  (``memory_space=pl.ANY``): a grid step is a slot, and the slot's
+  RESIDENT pages, ``ceil(length[s] / page_size)`` of them, are walked by
+  a loop inside the body (``_walk_resident_pages``), each fetched by the
+  kernel's own double-buffered copy of ``pool[table[s, p]]`` while the
+  page before it is absorbed; a slot's first page is started under the
+  last page of the slot before it.
+* Per-slot lengths bound the walk: a page at ``p * page_size >=
+  length[s]`` is never copied, never computed on and its table entry
+  never read, and a slot of length 0 costs its grid step and nothing
+  else (a grid step costs ~0.11 us even when it skips, so a grid over
+  all ``S x pages_per_slot`` pairs is ~0.46 ms of a call at the served
+  shapes whatever is resident: PERF.md section 6, PR 30 and PR 41).
+  Decode traffic AND grid steps follow pages actually RESIDENT, not
+  ``S x max_length``: ``grid_accounting`` models exactly that contract
+  and the bench/CI legs pin it. The kernels do not need the host's fill
+  of a slot's unprovisioned table tail with its LAST valid page id.
 * Empty slots (length 0) produce exactly 0 (the flash kernel's
   fully-masked-row contract extended to decode); an unoccupied slot is
   never NaN bait.
@@ -50,7 +55,7 @@ import jax.numpy as jnp
 
 # Pinned-Place-aware backend test, shared with the flash kernel so the
 # two kernels' impl routing can never diverge.
-from paddle_tpu.kernels.flash_attention import _is_tpu_target
+from paddle_tpu.kernels.flash_attention import _is_tpu_target, _mosaic_params
 
 
 class KernelCompileError(RuntimeError):
@@ -181,34 +186,125 @@ def _finish_slot(o_ref, acc_ref, l_ref):
                 ).astype(o_ref.dtype)
 
 
-def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, e_ref,
-                         et_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                         page_size, n_pages, sm_scale):
-    """One (slot, page) grid step of the decode: the slot's one query
-    row absorbs one resident page (``_absorb_page``). ``table_ref`` and
-    ``len_ref`` are the scalar-prefetch operands — the page table
-    already steered the K/V index maps; the kernel only needs the length
-    for the validity test and the empty-page skip."""
+def _walk_resident_pages(table_ref, slot, pages_of, pools, bufs, sem,
+                         ahead_ref, absorb):
+    """Walk one slot's resident pages INSIDE a kernel body whose grid is
+    ``(slots,)``. ``pages_of(slot)`` is how many of the slot's table
+    entries are resident; page ``p`` of the slot is ``table_ref[slot,
+    p]`` of every pool in ``pools`` (refs left in HBM, ``[P, page_size,
+    width]``), fetched by the kernel's own copy into one half of the
+    matching ``bufs`` scratch (``[2, page_size, width]``) while the page
+    before it is absorbed from the other half; ``sem`` is a DMA
+    semaphore a pool and half (``[len(pools), 2]``).
+    ``absorb(p, *page_refs)`` gets the page's index in the slot and a
+    ``[1, page_size, width]`` ref a pool.
+
+    The halves alternate over the whole CALL, not a slot: ``ahead_ref``
+    (SMEM ``int32[2]``, kept from grid step to grid step, which
+    therefore run in order) holds the pages walked so far and whether
+    this slot's first page is in flight already, started by the slot
+    before it under its own last page. Above the knee every slot is live
+    with a page or two, and a first page fetched with nothing to hide
+    behind was a quarter of the call (0.316 -> 0.234 ms at 256 live, my
+    chip run, PR 41).
+
+    Only resident table entries are ever read: a slot with none issues
+    no copy and runs no ``absorb``, and what the table holds past a
+    slot's resident pages is never looked at."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    @pl.when(slot == 0)
+    def _first_slot():
+        ahead_ref[0] = 0
+        ahead_ref[1] = 0
+
+    def resident(of_slot):
+        # never past the table's row, whatever length a caller hands in
+        return jnp.minimum(pages_of(of_slot), table_ref.shape[1])
+
+    n = resident(slot)
+
+    def copies(of_slot, p, half):
+        page = table_ref[of_slot, p]
+        return [pltpu.make_async_copy(pool.at[page], buf.at[half],
+                                      sem.at[i, half])
+                for i, (pool, buf) in enumerate(zip(pools, bufs))]
+
+    @pl.when(n > 0)
+    def _walk():
+        walked = ahead_ref[0]
+        n_slots = pl.num_programs(0)
+        nxt = jnp.minimum(slot + 1, n_slots - 1)
+        n_next = jnp.where(slot + 1 < n_slots, resident(nxt), 0)
+
+        @pl.when(ahead_ref[1] == 0)
+        def _first_page():
+            for c in copies(slot, 0, walked % 2):
+                c.start()
+
+        def body(p, carry):
+            half = (walked + p) % 2
+
+            @pl.when(p + 1 < n)
+            def _next_page():
+                for c in copies(slot, p + 1, 1 - half):
+                    c.start()
+
+            @pl.when((p + 1 == n) & (n_next > 0))
+            def _next_slot():
+                for c in copies(nxt, 0, 1 - half):
+                    c.start()
+
+            for c in copies(slot, p, half):
+                c.wait()
+            absorb(p, *[buf.at[pl.ds(half, 1)] for buf in bufs])
+            return carry
+
+        jax.lax.fori_loop(0, n, body, 0)
+        ahead_ref[0] = walked + n
+        ahead_ref[1] = (n_next > 0).astype(jnp.int32)
+
+
+def _walk_scratch(k_pool, v_pool):
+    """The scratch ``_walk_resident_pages`` needs for a K and a V pool:
+    two halves a pool, a DMA semaphore a pool and half, its state."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [
+        pltpu.VMEM((2,) + tuple(k_pool.shape[1:]), k_pool.dtype),
+        pltpu.VMEM((2,) + tuple(v_pool.shape[1:]), v_pool.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SMEM((2,), jnp.int32),
+    ]
+
+
+def _paged_decode_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, e_ref,
+                         et_ref, o_ref, k_buf, v_buf, sem, ahead_ref,
+                         acc_ref, m_ref, l_ref, *, page_size, sm_scale):
+    """One grid step is one SLOT of the decode: its one query row absorbs
+    the slot's resident pages (``_absorb_page``) in table order, walked
+    by ``_walk_resident_pages``. ``table_ref`` and ``len_ref`` are the
+    scalar-prefetch operands; the pools stay in HBM."""
     from jax.experimental import pallas as pl
 
     s = pl.program_id(0)
-    p = pl.program_id(1)
-    pl.when(p == 0)(lambda: _start_slot(acc_ref, m_ref, l_ref))
     length = len_ref[s]
+    _start_slot(acc_ref, m_ref, l_ref)
+    q = q_ref[0].astype(jnp.float32) * sm_scale
 
-    def _compute():
+    def absorb(p, k_ref, v_ref):
         pos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size, 1), 1)
-        _absorb_page(q_ref[0].astype(jnp.float32) * sm_scale, k_ref,
-                     v_ref, pos < length, e_ref, et_ref, acc_ref, m_ref,
-                     l_ref)
+        _absorb_page(q, k_ref, v_ref, pos < length, e_ref, et_ref, acc_ref,
+                     m_ref, l_ref)
 
-    # the ragged bound: a page past the slot's resident length runs NO
-    # compute (and, with the host's last-valid-page table aliasing, no
-    # fresh DMA either — the repeated index elides the copy)
-    pl.when(p * page_size < length)(_compute)
-    pl.when(p == n_pages - 1)(
-        lambda: _finish_slot(o_ref, acc_ref, l_ref))
+    # the ragged bound: ceil(length / page_size) pages, 0 for an empty slot
+    _walk_resident_pages(
+        table_ref, s,
+        lambda slot: (len_ref[slot] + page_size - 1) // page_size,
+        (k_hbm, v_hbm), (k_buf, v_buf), sem, ahead_ref, absorb)
+    _finish_slot(o_ref, acc_ref, l_ref)
 
 
 def _paged_pallas(q, k_pool, v_pool, page_table, lengths, sm_scale,
@@ -218,25 +314,23 @@ def _paged_pallas(q, k_pool, v_pool, page_table, lengths, sm_scale,
 
     S, H, dh = q.shape
     ps, width = k_pool.shape[1], k_pool.shape[2]
-    npp = page_table.shape[1]
     e, et = _head_indicator(H, dh)
-    kv_spec = pl.BlockSpec(
-        (1, ps, width), lambda s, p, table, lens: (table[s, p], 0, 0))
     row_spec = pl.BlockSpec(
-        (1, 1, width), lambda s, p, table, lens: (s, 0, 0))
+        (1, 1, width), lambda s, table, lens: (s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, npp),
+        grid=(S,),
         in_specs=[
             row_spec,
-            kv_spec,
-            kv_spec,
+            # the pools stay where they are: the body copies a page
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             # whole in VMEM for the call: no index map a grid step
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=row_spec,
-        scratch_shapes=[
+        scratch_shapes=_walk_scratch(k_pool, v_pool) + [
             pltpu.VMEM((1, width), jnp.float32),
             pltpu.VMEM((1, 1, H), jnp.float32),
             pltpu.VMEM((1, width), jnp.float32),
@@ -244,12 +338,12 @@ def _paged_pallas(q, k_pool, v_pool, page_table, lengths, sm_scale,
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_decode_kernel, page_size=ps, n_pages=npp,
-            sm_scale=sm_scale),
+            _paged_decode_kernel, page_size=ps, sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, 1, width), q.dtype),
         interpret=interpret,
         name=PAGED_KERNEL_NAME,
+        **_mosaic_params(interpret, ("arbitrary",)),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       q.reshape(S, 1, width), k_pool, v_pool, e, et)
     return out.reshape(S, H, dh)
@@ -370,28 +464,32 @@ def paged_tree_attention_reference(q, k_pool, v_pool, page_table,
     return jnp.where(dead, 0.0, out).astype(q.dtype)
 
 
-def _tree_decode_kernel(table_ref, blen_ref, q_ref, k_ref, v_ref,
-                        anc_ref, e_ref, et_ref, o_ref, acc_ref, m_ref,
-                        l_ref, *, page_size, n_pages, n_nodes, max_len,
-                        sm_scale):
-    """One (slot, page) grid step of the tree verify: N query rows (one
-    per tree node) absorb one resident page. Same ragged discipline as
-    ``_paged_decode_kernel`` — the scan bound is ``base + N`` (capped at
-    ``max_len``), pages past it skip compute and (via table tail
-    aliasing) DMA. A node sees the in-tree storage position ``base + j``
-    where ``anc[n, j]`` is set: a compare of ``t - base`` against ``j``
-    on the lanes, no gather."""
+def _tree_decode_kernel(table_ref, blen_ref, q_ref, k_hbm, v_hbm,
+                        anc_ref, e_ref, et_ref, o_ref, k_buf, v_buf, sem,
+                        ahead_ref, acc_ref, m_ref, l_ref, *, page_size,
+                        n_nodes, max_len, sm_scale):
+    """One grid step is one slot of the tree verify: N query rows (one
+    per tree node) absorb the slot's resident pages. Same ragged
+    discipline as ``_paged_decode_kernel``: the scan bound is
+    ``base + N`` (capped at ``max_len``) and only the pages under it are
+    walked. A node sees the in-tree storage position ``base + j`` where
+    ``anc[n, j]`` is set: a compare of ``t - base`` against ``j`` on the
+    lanes, no gather."""
     from jax.experimental import pallas as pl
 
     s = pl.program_id(0)
-    p = pl.program_id(1)
     N, ps = n_nodes, page_size
-    pl.when(p == 0)(lambda: _start_slot(acc_ref, m_ref, l_ref))
+    _start_slot(acc_ref, m_ref, l_ref)
     base = blen_ref[s]
-    scan_len = jnp.where(base >= 0,
-                         jnp.minimum(base + n_nodes, max_len), 0)
+    q = q_ref[0].astype(jnp.float32) * sm_scale
 
-    def _compute():
+    def pages_of(slot):
+        committed = blen_ref[slot]
+        scan_len = jnp.where(
+            committed >= 0, jnp.minimum(committed + n_nodes, max_len), 0)
+        return (scan_len + ps - 1) // ps
+
+    def absorb(p, k_ref, v_ref):
         # page positions on the sublanes, as the scores have them
         tcol = p * ps + jax.lax.broadcasted_iota(
             jnp.int32, (N, ps, N), 1)                    # t
@@ -402,12 +500,13 @@ def _tree_decode_kernel(table_ref, blen_ref, q_ref, k_ref, v_ref,
                           keepdims=True) > 0.5           # [N, ps, 1]
         t1 = tcol[:, :, :1]
         visible = (t1 < base) | (treevis & (t1 < max_len))
-        _absorb_page(q_ref[0].astype(jnp.float32) * sm_scale, k_ref,
-                     v_ref, visible, e_ref, et_ref, acc_ref, m_ref, l_ref)
+        _absorb_page(q, k_ref, v_ref, visible, e_ref, et_ref, acc_ref,
+                     m_ref, l_ref)
 
-    pl.when(p * page_size < scan_len)(_compute)
-    pl.when(p == n_pages - 1)(
-        lambda: _finish_slot(o_ref, acc_ref, l_ref))
+    _walk_resident_pages(
+        table_ref, s, pages_of, (k_hbm, v_hbm), (k_buf, v_buf), sem,
+        ahead_ref, absorb)
+    _finish_slot(o_ref, acc_ref, l_ref)
 
 
 def _tree_pallas(q, k_pool, v_pool, page_table, base_lens, anc,
@@ -417,26 +516,24 @@ def _tree_pallas(q, k_pool, v_pool, page_table, base_lens, anc,
 
     S, H, N, dh = q.shape
     ps, width = k_pool.shape[1], k_pool.shape[2]
-    npp = page_table.shape[1]
     e, et = _head_indicator(H, dh)
-    kv_spec = pl.BlockSpec(
-        (1, ps, width), lambda s, p, table, lens: (table[s, p], 0, 0))
     rows_spec = pl.BlockSpec(
-        (1, N, width), lambda s, p, table, lens: (s, 0, 0))
+        (1, N, width), lambda s, table, lens: (s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, npp),
+        grid=(S,),
         in_specs=[
             rows_spec,
-            kv_spec,
-            kv_spec,
-            pl.BlockSpec((1, N, N), lambda s, p, table, lens: (s, 0, 0)),
+            # the pools stay where they are: the body copies a page
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, N, N), lambda s, table, lens: (s, 0, 0)),
             # whole in VMEM for the call: no index map a grid step
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_specs=rows_spec,
-        scratch_shapes=[
+        scratch_shapes=_walk_scratch(k_pool, v_pool) + [
             pltpu.VMEM((N, width), jnp.float32),
             pltpu.VMEM((N, 1, H), jnp.float32),
             pltpu.VMEM((N, width), jnp.float32),
@@ -444,12 +541,13 @@ def _tree_pallas(q, k_pool, v_pool, page_table, base_lens, anc,
     )
     out = pl.pallas_call(
         functools.partial(
-            _tree_decode_kernel, page_size=ps, n_pages=npp, n_nodes=N,
+            _tree_decode_kernel, page_size=ps, n_nodes=N,
             max_len=int(max_length), sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, N, width), q.dtype),
         interpret=interpret,
         name=TREE_KERNEL_NAME,
+        **_mosaic_params(interpret, ("arbitrary",)),
     )(page_table.astype(jnp.int32), base_lens.astype(jnp.int32),
       jnp.transpose(q, (0, 2, 1, 3)).reshape(S, N, width), k_pool, v_pool,
       anc.astype(jnp.int32), e, et)
@@ -556,10 +654,12 @@ def paged_kv_compact(k_pool, v_pool, page_table, base, path, accept_len):
 def grid_accounting(lengths, page_size, num_heads, head_dim,
                     max_length, itemsize=4, num_groups=None,
                     n_layer=1, src_length=None):
-    """Model the decode kernel's HBM traffic from its own grid
-    semantics: one K page + one V page DMA'd per RESIDENT page (the
-    ``pl.when`` skip + last-valid-page table aliasing elide both
-    compute and copy for pages past a slot's length), plus the
+    """Model the decode kernel's work from its own grid semantics:
+    ``grid_steps`` grid steps, one a slot, and ``page_walks`` turns of
+    the walk inside them, one a RESIDENT page, each copying one K page
+    and one V page (a page past a slot's length is never copied or
+    computed on; ``total_page_slots`` is what a (slot, page) grid
+    would step through), plus the
     [S, H, dh] query/output blocks. ``dense_hbm_bytes`` is what the
     dense slot pool moves for the same step — every slot's full
     ``[H, max_length, dh]`` K and V regardless of occupancy — so the
@@ -585,6 +685,8 @@ def grid_accounting(lengths, page_size, num_heads, head_dim,
     dense_kv = 2 * S * num_heads * int(max_length) * head_dim * itemsize
     out = {
         "valid_pages": valid_pages,
+        "grid_steps": S,
+        "page_walks": valid_pages,
         "total_page_slots": total_page_slots,
         "page_bytes": page_bytes,
         "hbm_bytes": kv_bytes + qo_bytes,

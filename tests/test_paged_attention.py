@@ -151,6 +151,122 @@ def test_kernel_skips_pages_past_the_length():
     np.testing.assert_array_equal(got, want)
 
 
+# the slot walk's edges at pages of 4: the lengths of a call's slots, and
+# what the table holds past a slot's resident pages
+_WALK_LENGTHS = {
+    "empty_first_and_last": [0, 9, 0],
+    "one_token": [1, 1],
+    "exactly_one_page": [4, 4, 4],
+    "one_past_a_page": [5, 9, 13],
+    "full_slots": [24, 24],
+    "all_empty": [0, 0, 0, 0],
+    "one_live_of_many": [0, 0, 0, 0, 0, 17, 0, 0],
+    "every_slot_live": [3, 4, 5, 8, 12, 24, 1, 7],
+    "runs_of_empty_between_live": [6, 0, 0, 11, 0, 2, 0, 0, 24],
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("lengths", list(_WALK_LENGTHS.values()),
+                         ids=list(_WALK_LENGTHS))
+def test_slot_walk_reads_only_resident_pages(lengths, dtype):
+    """The kernel walks ``ceil(length / page_size)`` pages a slot inside
+    its body: it agrees with the composed reference at every edge of the
+    walk (no page, one row, a page boundary and one past it, a full slot,
+    live slots next to each other and apart), an empty slot is exactly 0,
+    and a table whose entries past a slot's resident pages are POISON (an
+    id outside the pool) gives the same bits: nothing past a slot's
+    resident pages is used (that no copy is even started for one is the
+    next test's count)."""
+    import jax.numpy as jnp
+
+    S, H, dh, ps, npp = len(lengths), 4, 16, 4, 6
+    lengths = np.asarray(lengths, np.int32)
+    rng = np.random.RandomState(int(lengths.sum()) + S)
+    kp, vp, table = _pools(rng, S, H, dh, ps, npp, lengths)
+    q, kp, vp = (jnp.asarray(x, dtype) for x in
+                 (rng.randn(S, H, dh), kp, vp))
+    ref = np.asarray(pa.paged_attention_reference(
+        q, kp, vp, jnp.asarray(table), jnp.asarray(lengths)), "float32")
+    ker = np.asarray(pa.paged_attention(
+        q, kp, vp, jnp.asarray(table), jnp.asarray(lengths),
+        force_pallas=True), "float32")
+    tol = 2e-6 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(ker, ref, rtol=tol, atol=tol)
+    assert np.abs(ker[lengths == 0]).max(initial=0.0) == 0.0
+    poisoned = table.copy()
+    for s in range(S):
+        poisoned[s, pa.pages_for(lengths[s], ps):] = 2 ** 30
+    got = np.asarray(pa.paged_attention(
+        q, kp, vp, jnp.asarray(poisoned), jnp.asarray(lengths),
+        force_pallas=True), "float32")
+    np.testing.assert_array_equal(got, ker)
+
+
+def test_slot_walk_stops_at_the_end_of_a_table_row():
+    """A length past what a table row covers reads the row's pages and
+    no entry past them: the same bits as the full slot, and the
+    reference's (it sees every position of the row as valid)."""
+    import jax.numpy as jnp
+
+    S, H, dh, ps, npp = 3, 2, 16, 4, 6
+    full = np.array([npp * ps, 3, npp * ps], np.int32)
+    rng = np.random.RandomState(6)
+    kp, vp, table = _pools(rng, S, H, dh, ps, npp, full)
+    args = [jnp.asarray(x) for x in
+            (rng.randn(S, H, dh).astype("float32"), kp, vp, table)]
+    want = np.asarray(pa.paged_attention(
+        *args, jnp.asarray(full), force_pallas=True))
+    past = jnp.asarray(np.array([npp * ps + 3, 3, 40], np.int32))
+    got = np.asarray(pa.paged_attention(*args, past, force_pallas=True))
+    np.testing.assert_array_equal(got, want)
+    ref = np.asarray(pa.paged_attention_reference(*args, past))
+    np.testing.assert_allclose(got, ref, rtol=2e-6, atol=2e-6)
+
+
+def test_slot_walk_starts_two_copies_a_resident_page(monkeypatch):
+    """Every copy the kernel starts is counted (interpret mode runs the
+    body as JAX, so a callback beside ``start`` sees each one): a K and a
+    V page a RESIDENT page, what ``grid_accounting`` calls
+    ``page_walks``, whatever the slots before and after hold; none at
+    all for a call of empty slots."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    started = []
+    real = pltpu.make_async_copy
+
+    class Counted(object):
+        def __init__(self, copy):
+            self.copy = copy
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    monkeypatch.setattr(pltpu, "make_async_copy",
+                        lambda *a, **kw: Counted(real(*a, **kw)))
+    S, H, dh, ps, npp = 9, 2, 16, 4, 6
+    rng = np.random.RandomState(11)
+    for lengths in ([6, 0, 0, 11, 0, 2, 0, 0, 24], [0] * S,
+                    [3, 4, 5, 8, 12, 24, 1, 7, 9]):
+        lengths = np.asarray(lengths, np.int32)
+        kp, vp, table = _pools(rng, S, H, dh, ps, npp, lengths)
+        del started[:]
+        out = pa.paged_attention(
+            *[jnp.asarray(x) for x in (rng.randn(S, H, dh).astype("float32"),
+                                       kp, vp, table, lengths)],
+            force_pallas=True)
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+        acct = pa.grid_accounting(lengths, ps, H, dh, npp * ps)
+        assert len(started) == 2 * acct["page_walks"], (lengths, len(started))
+
+
 def test_a_per_head_pool_is_refused_by_name():
     """The old ``[P, H, ps, dh]`` pool does not pass for whole token
     rows: a ValueError that says which shape a pool has."""
@@ -296,6 +412,10 @@ def test_grid_accounting_scales_with_resident_pages():
     lengths = [3, 17, 0, 0, 0, 0, 0, 0]
     acc = pa.grid_accounting(lengths, ps, H, dh, T)
     assert acc["valid_pages"] == pa.pages_for(3, ps) + pa.pages_for(17, ps)
+    # the kernel's grid is a step a slot, its walk a turn a resident page
+    assert acc["grid_steps"] == len(lengths)
+    assert acc["page_walks"] == acc["valid_pages"] == 6
+    assert acc["total_page_slots"] == 8 * 16
     # raggedness: 6 pages of 128 page-slots -> far under the dense bytes
     assert acc["hbm_bytes"] < 0.1 * acc["dense_hbm_bytes"]
     # proportionality in the KV term: doubling resident pages doubles

@@ -121,6 +121,19 @@ def _assert_moves_no_pool(text, pool_elems):
     assert not moved, "the program moves a whole pool:\n" + "\n".join(moved)
 
 
+def _pallas_grids(jaxpr):
+    """``{kernel name: grid}`` of every ``pallas_call`` in ``jaxpr`` and
+    the jaxprs nested in it (a scan's body, a cond's branches)."""
+    grids = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids[eqn.params["name"]] = tuple(
+                eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids.update(_pallas_grids(sub))
+    return grids
+
+
 F32, BF16 = jnp.float32, jnp.bfloat16
 
 # the kernel_bench sweep's smallest shape plus a non-multiple batch that
@@ -329,8 +342,10 @@ def test_paged_step_copies_no_pool(v5e, case, n_nodes):
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert (pa.PAGED_KERNEL_NAME if n_nodes is None
-            else pa.TREE_KERNEL_NAME) in text
+    kernel = pa.PAGED_KERNEL_NAME if n_nodes is None else pa.TREE_KERNEL_NAME
+    assert kernel in text
+    # a grid step is a slot: the resident pages are walked inside the body
+    assert _pallas_grids(jax.make_jaxpr(step)(*args).jaxpr) == {kernel: (S,)}
     pool_elems = P * ps * H * dh
     _assert_moves_no_pool(text, pool_elems)
     pool_bytes = pool_elems * jnp.dtype(dtype).itemsize

@@ -190,10 +190,12 @@ def _bench_paged_decode(shapes, calls, steps, warmup):
             fn = jax.jit(run)
             times[impl] = _time_steps(lambda: fn(q, kp, vp),
                                       steps, warmup) / calls
+        acct = pa.grid_accounting(lengths, ps, H, dh, T)
         row = {"kernel": pa.PAGED_KERNEL_NAME,
                "shape": [S, live, H, dh, ps, T],
-               "resident_pages": int(sum(pa.pages_for(n, ps)
-                                         for n in lengths)),
+               "grid_steps": acct["grid_steps"],
+               "page_walks": acct["page_walks"],
+               "total_page_slots": acct["total_page_slots"],
                "xla_ms": round(times["reference"] * 1e3, 4),
                "pallas_ms": round(times["pallas"] * 1e3, 4),
                "speedup": round(times["reference"] / times["pallas"], 3)}
@@ -290,9 +292,10 @@ def main():
         rnn_shapes = [(32, 128, 256), (64, 256, 512), (16, 512, 1024)]
         fa_shapes = [(8, 8, 1024, 64), (4, 8, 2048, 64), (2, 8, 4096, 128)]
         # perfbench transformer_base: 256 slots of 256 positions, 8 heads
-        # of 64, pages of 16; ~32 slots live in the steady cell, all 256
-        # above the knee; 24 calls a dispatch (4 tokens x 6 layers)
-        paged_shapes = [(256, 32, 8, 64, 16, 256), (256, 256, 8, 64, 16, 256)]
+        # of 64, pages of 16; 8 slots live in the steady cell (32 before
+        # PR 30), all 256 above the knee; 24 calls a dispatch (4 tokens x
+        # 6 layers)
+        paged_shapes = [(256, live, 8, 64, 16, 256) for live in (8, 32, 256)]
         paged_calls = 24
 
     # child mode: exactly one family, crash loudly (the parent records
