@@ -2,7 +2,9 @@
 experts (``ops/decoder_ops.py``, ``ops/moe_ops.py`` ``dropless_moe_ffn``).
 They take flat token rows ``[N, ...]`` and the parameters as variables:
 the serving builder (``models/latent_moe_decoder.py``) declares those by
-name and a checkpoint fills them."""
+name and a checkpoint fills them. The last four are a gated delta-rule
+linear-attention mixer's (``ops/linear_attention_ops.py``,
+``models/linear_attn_moe_decoder.py``)."""
 
 from paddle_tpu.layer_helper import LayerHelper
 
@@ -23,6 +25,10 @@ __all__ = [
     "sparse_latent_prefill_attention",
     "slot_rows_write",
     "embedding_rows",
+    "delta_rule_gates",
+    "delta_rule_prefill",
+    "delta_rule_state_update",
+    "gated_head_norm",
 ]
 
 
@@ -251,3 +257,58 @@ def embedding_rows(table, ids, name=None):
     """Rows ``ids`` [N] of an embedding table that is already a variable
     (``layers.embedding`` creates its own parameter)."""
     return _one("lookup_table", {"W": [table], "Ids": [ids]}, name=name)
+
+
+def delta_rule_gates(f, dt_bias, a_log, b, heads, beta_scale=1.0, name=None):
+    """A delta-rule mixer's gates (``ops/linear_attention_ops.py``): the
+    log decay a key channel ``-exp(a_log) * softplus(f + dt_bias)`` [N,
+    heads * dk] and the step ``beta_scale * sigmoid(b)`` [N, heads], both
+    float32."""
+    helper = LayerHelper("delta_rule_gates", name=name)
+    g, beta = [helper.create_variable_for_type_inference("float32")
+               for _ in range(2)]
+    helper.append_op(
+        type="delta_rule_gates",
+        inputs={"X": [f], "DtBias": [dt_bias], "ALog": [a_log], "B": [b]},
+        outputs={"G": [g], "Beta": [beta]},
+        attrs={"heads": int(heads), "beta_scale": float(beta_scale)})
+    return g, beta
+
+
+def delta_rule_prefill(q, k, v, g, beta, lens, name=None):
+    """The gated delta rule over a prefill dispatch's prompts (one a
+    bucket row), in chunks. Returns (out [N, heads * dv] float32, state
+    [prompts, heads, dk, dv] float32 after each prompt's last real
+    token)."""
+    helper = LayerHelper("delta_rule_prefill", name=name)
+    out, state = [helper.create_variable_for_type_inference("float32")
+                  for _ in range(2)]
+    helper.append_op(
+        type="delta_rule_prefill",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
+                "Lens": [lens]},
+        outputs={"Out": [out], "State": [state]})
+    return out, state
+
+
+def delta_rule_state_update(state, q, k, v, g, beta, live, name=None):
+    """One token of the gated delta rule for every slot: out [S, heads *
+    dv] float32; ``state`` [S, heads, dk, dv] is updated in place (a slot
+    that is not live keeps its own and reads 0)."""
+    helper = LayerHelper("delta_rule_state_update", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="delta_rule_state_update",
+        inputs={"State": [state], "Q": [q], "K": [k], "V": [v], "G": [g],
+                "Beta": [beta], "Live": [live]},
+        outputs={"Out": [out], "StateOut": [state]})
+    return out
+
+
+def gated_head_norm(x, scale, gate, heads, epsilon=1e-5, name=None):
+    """``RMSNorm`` over each of ``heads`` heads of ``x`` (one ``scale``
+    vector) times ``sigmoid(gate)``, in ``gate``'s dtype."""
+    return _one("gated_head_norm",
+                {"X": [x], "Scale": [scale], "Gate": [gate]},
+                {"heads": int(heads), "epsilon": float(epsilon)},
+                dtype=gate.dtype, name=name)

@@ -34,4 +34,5 @@ from paddle_tpu.ops import moe_ops  # noqa: F401
 from paddle_tpu.ops import decoder_ops  # noqa: F401
 from paddle_tpu.ops import sparse_attention_ops  # noqa: F401
 from paddle_tpu.ops import ssm_ops  # noqa: F401
+from paddle_tpu.ops import linear_attention_ops  # noqa: F401
 from paddle_tpu.ops import window_ops  # noqa: F401

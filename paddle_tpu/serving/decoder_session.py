@@ -14,7 +14,12 @@ the layers that have one: pools of two row widths under the one growing
 page table), one with ``layer_types`` and a ``sliding_window`` the decoder of
 window and full attention layers with routed experts
 (``models/windowed_moe_decoder.py``: a ring of K/V pages a slot a window
-layer beside a full layer's growing pools). The session knows no model:
+layer beside a full layer's growing pools), one with ``linear_attn_config``
+the decoder of gated delta-rule linear-attention layers with a few
+grouped-query attention layers among them and routed experts
+(``models/linear_attn_moe_decoder.py``: a float32 MATRIX state a head and
+a convolution window a slot a linear layer, K/V page pools for the
+attention layers, a held shard of the experts). The session knows no model:
 the queue, the buckets, batched admission, the reservation, growth and
 return of pages, results and the trace hooks are one code path, and a
 builder's ``geometry["state"]`` names its ``page_pools`` and
@@ -80,7 +85,13 @@ round gave back from behind its slots' windows, and ``full_rows_visible``
 / ``window_rows_visible``, the rows the decode dispatch's slots could see
 in a full layer and in a window layer), ``state_slots_live`` (with a
 model that has per-slot arrays: the slots whose rows the decode dispatch
-had to update), under learned sparse attention ``latent_rows_resident``
+had to update; ``state_bytes_live``, the bytes of those arrays a token step
+reads and writes for them; ``kv_rows_visible``, the rows the dispatch's
+slots could see in an attention layer's pools beside them), where the
+builder's prefill walks a recurrence in chunks (``geometry
+["prefill_chunk"]``) ``prefill_chunks`` / ``prefill_chunks_padded`` (the
+chunks a prefill dispatch walked for real tokens, and those of its token
+places that held only padding), under learned sparse attention ``latent_rows_resident``
 (the rows the decode dispatch's slots hold a layer),
 ``latent_rows_selected`` (the sum over its slots of min(rows,
 ``index_topk``): what a layer's attention reads of them) and
@@ -101,6 +112,7 @@ import time
 
 import numpy as np
 
+from paddle_tpu.core.types import np_dtype
 from paddle_tpu.kernels.paged_attention import pages_for
 from paddle_tpu.observability import tracing as _tracing
 from paddle_tpu.serving.generation import (
@@ -142,12 +154,20 @@ def builder_for(desc):
         )
 
         return build_windowed_moe_decoder
+    if "linear_attn_config" in desc:
+        from paddle_tpu.models import linear_attn_moe_decoder as lad
+
+        # refused by the key at fault before anything is built: leading
+        # dense layers, value heads of their own, full-rank gates, RoPE
+        lad.check_served(desc)
+        return lad.build_linear_attn_moe_decoder
     raise ServingError(
         "DecoderOnlySession knows no builder for this description (keys "
         "%s): it serves a hybrid state-space decoder (mamba_d_state), a "
-        "latent-attention decoder (kv_lora_rank) or a decoder of window "
-        "and full attention layers (layer_types with a sliding_window)"
-        % sorted(desc))
+        "latent-attention decoder (kv_lora_rank), a decoder of window "
+        "and full attention layers (layer_types with a sliding_window) or "
+        "a decoder of delta-rule linear-attention and grouped-query "
+        "attention layers (linear_attn_config)" % sorted(desc))
 
 
 class _PageKind(object):
@@ -320,6 +340,11 @@ class DecoderOnlySession(object):
                 ring["rows_feed"], window=int(ring["window"])))
         self._slot_pages = _PagesOfSlot(self._kinds)
         self._slot_state = bool(geo["state"]["slot_arrays"])
+        # what one slot owns of the per-slot arrays, in bytes
+        self._slot_state_bytes = sum(
+            int(np.prod(a["shape"])) * np.dtype(np_dtype(a["dtype"])).itemsize
+            for a in geo["state"]["slot_arrays"].values()) // self._S
+        self._prefill_chunk = int(geo.get("prefill_chunk") or 0)
         # {bucket: {prompt rows: program}}; one rung, the most a dispatch
         # takes, where the builder gives no others
         self._prefill_progs = built.get("prefill_rungs") or {
@@ -577,6 +602,12 @@ class DecoderOnlySession(object):
                     _tracing.round_count("prefill_pad_tokens",
                                          B * T - int(lens.sum()))
                     _tracing.round_count("prefill_dispatches", 1)
+                    if self._prefill_chunk:
+                        walked = int((-(-lens // self._prefill_chunk)).sum())
+                        _tracing.round_count("prefill_chunks", walked)
+                        _tracing.round_count(
+                            "prefill_chunks_padded",
+                            B * -(-T // self._prefill_chunk) - walked)
                 feed = {"prompt_ids": ids.reshape(-1), "prompt_len": lens,
                         "slot_idx": slot_idx, "last_idx": last_idx}
                 for kind, rows in zip(self._kinds, page_rows):
@@ -711,7 +742,11 @@ class DecoderOnlySession(object):
                 window_rows_visible=self.last_window_rows)
             self._released = 0
         if self._slot_state:
-            counters["state_slots_live"] = self.last_step[0]
+            counters.update(
+                state_slots_live=self.last_step[0],
+                state_bytes_live=2 * self.last_step[0]
+                * self._slot_state_bytes,
+                kv_rows_visible=self.last_step[1])
         if self._index_topk:
             # the narrow pools share the latent pools' table: a page in
             # use is one page of every pool of either width

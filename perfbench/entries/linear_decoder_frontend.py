@@ -1,0 +1,23 @@
+"""Entry ``linear_decoder_frontend``: a decoder-only model of gated
+delta-rule linear-attention layers with a few grouped-query attention
+layers among them and a held shard of its routed experts, behind the same
+``ServingFrontend``, wire and clients as the other decoder-only entries: a
+``DecoderOnlySession`` (``serving/decoder_session.py``) over K/V page
+pools for the attention layers and, a slot a linear layer, a float32
+matrix state a head and a convolution window; prompts of up to 8192 tokens
+prefilled in buckets, the delta rule in chunks. The run is
+``decoder_family``'s; the model's own parts are ``serve_solar_common``'s."""
+
+# a program that lacks these fails here, at once, with no child started
+from paddle_tpu.kernels import delta_rule  # noqa: F401
+from paddle_tpu.ops import linear_attention_ops  # noqa: F401
+
+from perfbench import decoder_family, serve_solar_common as common
+
+
+def run(ctx):
+    return decoder_family.run(ctx, common)
+
+
+def make_checker(cell, devices):
+    return decoder_family.make_checker(cell, devices, common)

@@ -646,3 +646,110 @@ def test_flash_window_prefill_width_lowers_for_tpu(v5e):
             q, k, v, causal=True, kv_group=8, window=2048, block_q=512,
             block_k=512), q, kv, kv)
     assert "flash_attention_fwd" in text
+
+
+# -- the delta-rule linear-attention decoder (kernels/delta_rule.py) ----------
+
+@pytest.mark.parametrize("B,T", [(1, 8192), (16, 512)])
+def test_delta_rule_prefill_lowers_for_tpu_at_the_served_widths(v5e, B, T):
+    """The chunked delta rule of the 250B-A15B cell: 64 heads of 128 x
+    128, one prompt of 8192 tokens and a bucket row of 16 of 512."""
+    from paddle_tpu.kernels import delta_rule as dr
+
+    H, d = 64, 128
+    text = _compile_v5e(
+        v5e, lambda q, k, v, g, beta, lens: dr.chunk_prefill(
+            q, k, v, g, beta, lens, force_pallas=True),
+        ((B, T, H * d), BF16), ((B, T, H * d), BF16), ((B, T, H * d), BF16),
+        ((B, T, H * d), F32), ((B, T, H), F32), ((B,), jnp.int32))
+    assert dr.CHUNK_KERNEL_NAME in text
+
+
+def test_delta_rule_update_updates_the_state_in_place(v5e):
+    """The one-token update at the served sizes (96 slots, 64 heads, a
+    128 x 128 float32 state a head), the state donated: Mosaic takes it,
+    the compiled program holds no copy of the state, keeps its one layout
+    (``dv`` on the lanes) and its temporaries are a small fraction of the
+    403 MB state of one layer."""
+    import re
+
+    from paddle_tpu.kernels import delta_rule as dr
+
+    S, H, d = 96, 64, 128
+
+    def step(state, q, k, v, g, beta, live):
+        with _tpu_target():
+            return dr.state_update(state, q, k, v, g, beta, live)
+
+    sharding = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+            for shape, dt in (
+                ((S, H, d, d), F32), ((S, H * d), BF16), ((S, H * d), BF16),
+                ((S, H * d), BF16), ((S, H * d), F32), ((S, H), F32),
+                ((S,), jnp.int32))]
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert dr.STATE_KERNEL_NAME in text
+    moved = [line.strip()[:160] for line in text.splitlines() if re.match(
+        r"\s*(?:ROOT )?%%\S+ = \w+\[%d,%d,%d,%d\]\S* "
+        r"(copy|transpose|gather|copy-start)\(" % (S, H, d, d), line)]
+    assert not moved, "the step moves a whole state array:\n" + "\n".join(moved)
+    assert compiled.memory_analysis().temp_size_in_bytes < S * H * d * d * 4 // 8
+    assert set(re.findall(r"\[%d,%d,%d,%d\]\{([\d,]+)" % (S, H, d, d), text)) \
+        == {"3,2,1,0"}
+
+
+def test_linear_decoder_step_copies_no_state_array_and_no_pool(v5e):
+    """The whole decode program of the delta-rule decoder (an attention
+    layer then three linear layers at the served head widths, 8 slots, 4
+    token steps a dispatch) compiled for the described chip as the
+    executor builds it: no copy, transpose or gather of a matrix state, a
+    convolution window or a K/V pool."""
+    import re
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import _described_compile as dc
+    from paddle_tpu.models import linear_attn_moe_decoder as lad
+
+    S, positions, ps = 8, 1024, 128
+    desc = dict(
+        hidden_size=512, num_attention_heads=64, num_key_value_heads=8,
+        head_dim=128, linear_attn_config=dict(
+            short_conv_kernel_size=4, head_dim=128, num_heads=64,
+            num_kv_heads=None),
+        num_hidden_layers=4, gqa_layers=[0], vocab_size=2048,
+        moe_intermediate_size=256, n_routed_experts=8,
+        expert_shard={"of": 64, "first": 0}, n_shared_experts=1,
+        num_experts_per_tok=8, norm_topk_prob=True, routed_scaling_factor=1,
+        rms_norm_eps=1e-5, first_k_dense_replace=0, use_rope=False,
+        use_gqa_gate=True, kda_use_full_proj=False,
+        kda_allow_neg_eigval=True, tie_word_embeddings=False)
+    built = lad.build_linear_attn_moe_decoder(
+        desc, S, positions, ps, [512, 1024], prefill_token_budget=1024,
+        tokens_per_dispatch=4)
+    geo = built["geometry"]
+    state = dict(lad.parameter_shapes(desc, "bfloat16"))
+    moved_shapes = []
+    for kind in ("page_pools", "slot_arrays"):
+        for name, a in geo["state"][kind].items():
+            state[name] = (a["shape"], a["dtype"])
+            moved_shapes.append(",".join(str(n) for n in a["shape"]))
+    state["lad_tok"] = state["lad_pos"] = ((S, 1), "int64")
+    fetch = built["fetches"]
+    with _tpu_target():
+        text = dc.compile_program(
+            built["step"], v5e[0], state,
+            {"page_table": ((S, geo["pages_per_slot"]), "int64"),
+             "live": ((S, 1), "int64")},
+            [fetch["token"], fetch["expert_tokens"]], steps=4).as_text()
+    from paddle_tpu.kernels import delta_rule as dr
+
+    assert dr.STATE_KERNEL_NAME in text and "ssm_conv_step" in text
+    assert "gqa_paged_decode_attention" in text
+    moved = [line.strip()[:160] for line in text.splitlines() if re.match(
+        r"\s*(?:ROOT )?%%\S+ = \w+\[(%s)\]\S* "
+        r"(copy|transpose|gather|copy-start)\(" % "|".join(
+            sorted(set(moved_shapes))), line)]
+    assert not moved, "the step moves a state array or a pool:\n" \
+        + "\n".join(moved)

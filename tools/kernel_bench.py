@@ -204,12 +204,101 @@ def _bench_paged_decode(shapes, calls, steps, warmup):
     return rows
 
 
+def _bench_delta_rule(update_shapes, prefill_shapes, calls, steps, warmup):
+    """The two delta-rule kernels alone against their composed references
+    (``kernels/delta_rule.py``): the one-token update of every slot's
+    matrix state, ``calls`` dependent calls inside one jit with the state
+    donated (a decode dispatch makes tokens x linear layers of them), and
+    the chunked prefill of one prompt against the plain loop over its
+    tokens. Beside the times, the largest difference of the kernel's state
+    from the reference's over the largest state element."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.kernels import delta_rule as dr
+
+    def inputs(key, rows, H, dk, dv):
+        ks = jax.random.split(key, 5)
+        q, k = (jax.random.normal(ks[i], rows + (H * dk,), jnp.bfloat16)
+                for i in range(2))
+        v = jax.random.normal(ks[2], rows + (H * dv,), jnp.bfloat16)
+        g = -jnp.exp(jax.random.uniform(
+            ks[3], rows + (H * dk,), jnp.float32, np.log(1e-3), np.log(4.0)))
+        beta = jax.random.uniform(ks[4], rows + (H,), jnp.float32, 0.0, 2.0)
+        return q, k, v, g, beta
+
+    def worst(got, want):
+        return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+    rows = []
+    for S, live, H, dk, dv in update_shapes:
+        q, k, v, g, beta = inputs(jax.random.PRNGKey(S), (S,), H, dk, dv)
+        mask = jnp.asarray(np.arange(S) < live, jnp.int32)
+        times, last = {}, {}
+        for impl in ("reference", "pallas"):
+            def run(state, impl=impl):
+                def body(state, _):
+                    _o, state = dr.state_update(
+                        state, q, k, v, g, beta, mask,
+                        force_reference=impl == "reference",
+                        force_pallas=impl == "pallas")
+                    return state, None
+
+                return jax.lax.scan(body, state, None, length=calls)[0]
+
+            fn = jax.jit(run, donate_argnums=0)
+            box = [jnp.zeros((S, H, dk, dv), jnp.float32)]
+
+            def once(fn=fn, box=box):
+                box[0] = fn(box[0])
+                return box[0][0, 0, 0]
+
+            times[impl] = _time_steps(once, steps, warmup) / calls
+            last[impl] = jax.jit(run)(jnp.zeros((S, H, dk, dv), jnp.float32))
+        moved = 2 * live * H * dk * dv * 4
+        row = {"kernel": dr.STATE_KERNEL_NAME, "shape": [S, live, H, dk, dv],
+               "xla_ms": round(times["reference"] * 1e3, 4),
+               "pallas_ms": round(times["pallas"] * 1e3, 4),
+               "speedup": round(times["reference"] / times["pallas"], 3),
+               "state_gbytes_per_s": round(moved / times["pallas"] / 1e9, 1),
+               "state_max_err": worst(last["pallas"], last["reference"])}
+        print(json.dumps(row))
+        rows.append(row)
+    for B, T, n, H, dk, dv in prefill_shapes:
+        q, k, v, g, beta = inputs(jax.random.PRNGKey(T), (B, T), H, dk, dv)
+        lens = jnp.full((B,), n, jnp.int32)
+        times, last = {}, {}
+        for impl in ("reference", "pallas"):
+            fn = jax.jit(lambda q, k, v, g, beta, impl=impl: dr.chunk_prefill(
+                q, k, v, g, beta, lens,
+                force_reference=impl == "reference",
+                force_pallas=impl == "pallas"))
+            last[impl] = fn(q, k, v, g, beta)
+            times[impl] = _time_steps(
+                lambda fn=fn: fn(q, k, v, g, beta)[1][0, 0, 0],
+                max(steps // 4, 1), 1)
+        row = {"kernel": dr.CHUNK_KERNEL_NAME, "shape": [B, T, n, H, dk, dv],
+               "xla_ms": round(times["reference"] * 1e3, 3),
+               "pallas_ms": round(times["pallas"] * 1e3, 3),
+               "speedup": round(times["reference"] / times["pallas"], 3),
+               "us_per_chunk_head": round(
+                   times["pallas"] * 1e6 / (B * H * -(-n // dr.CHUNK)), 3),
+               "state_max_err": worst(last["pallas"][1],
+                                      last["reference"][1]),
+               "out_max_err": worst(last["pallas"][0],
+                                    last["reference"][0])}
+        print(json.dumps(row))
+        rows.append(row)
+    return rows
+
+
 def _on_tpu():
     return os.environ.get("BENCH_PLATFORM") != "cpu"
 
 
 _FAMILIES = ("dynamic_lstm", "dynamic_gru", "flash_attention",
-             "paged_decode")
+             "paged_decode", "delta_rule")
 
 
 def _orchestrate(args):
@@ -287,6 +376,8 @@ def main():
         rnn_shapes = [(4, 16, 32)]
         fa_shapes = [(1, 2, 128, 32)]
         paged_shapes, paged_calls = [(8, 3, 2, 16, 8, 32)], 2
+        update_shapes, prefill_shapes = [(4, 3, 2, 16, 16)], \
+            [(1, 128, 100, 2, 16, 16)]
     else:
         steps, warmup = 20, 5
         rnn_shapes = [(32, 128, 256), (64, 256, 512), (16, 512, 1024)]
@@ -297,6 +388,12 @@ def main():
         # 6 layers)
         paged_shapes = [(256, live, 8, 64, 16, 256) for live in (8, 32, 256)]
         paged_calls = 24
+        # perfbench solar_open2_4l: 96 slots (all live, and two thirds)
+        # of 64 heads of 128 x 128, 12 calls a dispatch (4 tokens x 3
+        # linear layers); one 8192-token prompt, whole and ended inside a
+        # chunk a third of the way
+        update_shapes = [(96, live, 64, 128, 128) for live in (96, 64)]
+        prefill_shapes = [(1, 8192, n, 64, 128, 128) for n in (8192, 2700)]
 
     # child mode: exactly one family, crash loudly (the parent records
     # the traceback from stderr and keeps the other families)
@@ -308,6 +405,9 @@ def main():
                    steps, warmup)
     elif args.family == "paged_decode":
         _bench_paged_decode(paged_shapes, paged_calls, steps, warmup)
+    elif args.family == "delta_rule":
+        _bench_delta_rule(update_shapes, prefill_shapes,
+                          2 if args.quick else 12, steps, warmup)
     else:
         _bench_flash(fluid, fa_shapes, steps, warmup)
         # sliding-window leg: same longest shape, window = seq/8 — the
