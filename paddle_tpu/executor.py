@@ -517,7 +517,8 @@ class Executor(object):
                 if key[0] == fp]
 
     def _enter(self, entry, program, feed, fetch_list, scope,
-               return_numpy=True, mode=_SINGLE, refresh=False):
+               return_numpy=True, mode=_SINGLE, refresh=False,
+               in_flight=None):
         device = self.place.jax_device()
         # Everything in the step (feed transfer, key creation, dispatch)
         # stays on the Place's device: with several backends loaded (TPU
@@ -528,7 +529,7 @@ class Executor(object):
             return _run_step(
                 self, entry, program or framework.default_main_program(),
                 feed or {}, fetch_list or [], scope or global_scope(),
-                device, return_numpy, mode, refresh)
+                device, return_numpy, mode, refresh, in_flight)
 
     def run(
         self,
@@ -739,17 +740,24 @@ class Executor(object):
                            return_numpy=False)
 
     def run_multi_step(self, program, steps, feed=None, fetch_list=None,
-                       scope=None, return_numpy=True, stack_fetches=False):
+                       scope=None, return_numpy=True, stack_fetches=False,
+                       in_flight=None):
         """Run ``steps`` iterations of ``program`` inside ONE compiled
         executable (lax.scan over the step function) — one host dispatch
         per K steps instead of per step. ``feed`` is constant across the
         steps (real pipelines use in-graph reader ops and need none).
         Fetches are the LAST step's values; pass stack_fetches=True for
         the per-step trajectory stacked along a leading [steps] axis
-        (costs scan output buffers every iteration)."""
+        (costs scan output buffers every iteration). ``in_flight`` is
+        called once, with no argument, after the dispatch is launched and
+        before anything waits for it (with ``return_numpy=False``: before
+        the return): host work the caller has that can run beside the
+        device. It must not raise, and its seconds are in the dispatch
+        record's wall and in none of its phases."""
         return self._enter(_RUN_MULTI, program, feed, fetch_list, scope,
                            return_numpy,
-                           mode=("multi", int(steps), bool(stack_fetches)))
+                           mode=("multi", int(steps), bool(stack_fetches)),
+                           in_flight=in_flight)
 
     def close(self):
         self._cache.clear()
@@ -776,7 +784,7 @@ def _with_blame(check, *replay):
 
 
 def _run_step(ex, entry, program, feed, fetch_list, scope, device,
-              return_numpy=True, mode=_SINGLE, refresh=False):
+              return_numpy=True, mode=_SINGLE, refresh=False, in_flight=None):
     """One step of ``program``, from feeds to fetches: the one path under
     ``Executor.run``, ``run_async``, ``run_multi_step`` and
     ``ParallelExecutor.run``, and the one place each observer is hooked.
@@ -877,6 +885,11 @@ def _run_step(ex, entry, program, feed, fetch_list, scope, device,
             nan_check = _with_blame(
                 nan_check, program, nan_snapshot, feeds, key, device,
                 steps, cp.mutable_state, multi)
+        if in_flight is not None:
+            # the dispatch (and the optional scan) is launched and nothing
+            # has waited for it: the caller's own host work runs here,
+            # beside the device, between two brackets
+            sp.outside(in_flight)
         if as_handle:
             # dispatch complete, nothing synced. The span measured host
             # dispatch latency only; device + fetch happen in

@@ -184,7 +184,7 @@ class StepSpan(object):
 
     __slots__ = ("origin", "t0", "phases", "input_wait", "fingerprint",
                  "_cur", "_t_cur", "_cost_cp", "_cost_avals",
-                 "_cpu", "_cpu0", "_cpu_cur")
+                 "_cpu", "_cpu0", "_cpu_cur", "_out", "_out_cpu")
 
     def __init__(self, origin):
         self.origin = origin
@@ -200,6 +200,8 @@ class StepSpan(object):
         # seconds}, and its CPU clock at the first and the last bracket
         self._cpu = None
         self._cpu0 = self._cpu_cur = None
+        # a caller's own work run inside the span (``outside``)
+        self._out = self._out_cpu = 0.0
 
     def _account(self, phase):
         """Book the thread's CPU since the last bracket to ``phase``
@@ -237,6 +239,20 @@ class StepSpan(object):
             self._cur = None
             _inflight[threading.get_ident()] = (self.origin, "host", now,
                                                 self.t0)
+
+    def outside(self, fn):
+        """Run ``fn()``, a caller's own host work, BETWEEN two brackets:
+        its seconds (and the thread's CPU in them) stay in the span's
+        wall and are kept out of every phase, the residual ``host``
+        too, so the phases go on saying what the dispatch itself cost."""
+        t0 = time.perf_counter()
+        cpu0 = time.thread_time() if self._cpu is not None else 0.0
+        try:
+            fn()
+        finally:
+            self._out += time.perf_counter() - t0
+            if self._cpu is not None:
+                self._out_cpu += time.thread_time() - cpu0
 
     def pre_dispatch(self, cp, state, feeds, key, program=None):
         """Stamp the executable fingerprint and — one-shot per
@@ -444,7 +460,7 @@ def finish(sp, steps=1, feeds=None, fetches=None, dispatch_only=False):
         sp.exit()
     wall = now - sp.t0
     measured = sum(sp.phases.values())
-    host = max(0.0, wall - measured)
+    host = max(0.0, wall - measured - sp._out)
     phases = dict(sp.phases)
     phases["host"] = host
     if cpu is not None:
@@ -452,7 +468,7 @@ def finish(sp, steps=1, feeds=None, fetches=None, dispatch_only=False):
         # here, or to a phase this call closed) less the brackets'
         first = cpu_end if sp._cpu0 is None else sp._cpu0
         cpu["host"] = (max(cpu_end, sp._cpu_cur or 0.0) - first
-                       - sum(cpu.values()))
+                       - sum(cpu.values()) - sp._out_cpu)
     _dispatches.append((sp.origin, time.time(), wall, phases, cpu))
     _inflight.pop(threading.get_ident(), None)
     if not ENABLED:
@@ -468,7 +484,7 @@ def finish(sp, steps=1, feeds=None, fetches=None, dispatch_only=False):
         phases["input_wait"] = sp.input_wait
     # coverage: every explicitly measured second (brackets + source-side
     # input wait) over the step's full wall — the ≥0.95 CI gate
-    coverage = ((measured + sp.input_wait) / step_wall
+    coverage = ((measured + sp._out + sp.input_wait) / step_wall
                 if step_wall > 0 else 1.0)
     starvation = sp.input_wait / step_wall if step_wall > 0 else 0.0
     step_s = step_wall / steps

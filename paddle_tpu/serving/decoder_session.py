@@ -370,6 +370,9 @@ class DecoderOnlySession(object):
         self._slot_traces = {}
         self._trace_cow = {}     # the shared hooks' (never filled here)
         self.steps_done = 0
+        # as SlotDecodeSession's: run between the decode dispatch's
+        # launch and its wait
+        self.in_flight = None
         self.prefill_dispatches = 0
         self.last_counters = {}
         # what the last admit_pending()/step() dispatched, for whoever
@@ -710,7 +713,8 @@ class DecoderOnlySession(object):
         with _tracing.span(".dispatch"):
             out = self._exe.run_multi_step(
                 self._step_prog, self._K, feed=feed, fetch_list=fetch,
-                scope=self._scope, stack_fetches=True)
+                scope=self._scope, stack_fetches=True,
+                in_flight=self.in_flight)
         toks = np.asarray(out[0])                       # [K, S, 1]
         self.steps_done += 1
         self._count(out[1] if len(out) > 1 else None)

@@ -180,7 +180,10 @@ class _Stream(object):
                  "beam_lane", "beam_rid", "wakeups", "empty_wakeups")
 
     def __init__(self, spec):
-        self.q = queue.Queue()
+        # every event a put and a wake-up of the handler, some thousand
+        # a second under one interpreter lock: the queue whose put and
+        # get are one C call each, not a mutex and three conditions
+        self.q = queue.SimpleQueue()
         # the handler's returns from q.get(), and those of them that
         # brought nothing to write or act on (written by the handler
         # alone, folded into the watcher's counts as the stream ends)
@@ -454,24 +457,45 @@ class _ConnWatcher(object):
                 self._wakeups += 1
                 due.extend(self._watched[cid]
                            for cid, at in self._paused.items() if at <= now)
-            for w in due:
-                if w is None:
-                    try:
-                        self._wake_r.recv(4096)
-                    except OSError:
-                        pass
-                    continue
-                # one connection a hold of _mu: an unwatch waits for one
-                # peek and one line, not for the batch
-                with self._mu:
-                    verdict = None
-                    if (self._watched.get(w.conn.id) is w
-                            and (w.armed or w.conn.id in self._paused)
-                            and not self._stop):
-                        verdict = self._look(w)
-                self._deliver(w, verdict)
+            self._serve(due)
             if _tracing.ENABLED:
                 self._cpu = time.thread_time()
+
+    def _serve(self, due):
+        """Look at each of ``due`` and deliver its verdict. Runs on the
+        watcher's thread and, from ``poll``, on the decode worker's: a
+        connection both were told of is settled by whoever takes ``_mu``
+        first, and the other finds it disarmed."""
+        for w in due:
+            if w is None:
+                try:
+                    self._wake_r.recv(4096)
+                except OSError:
+                    pass
+                continue
+            # one connection a hold of _mu: an unwatch waits for one
+            # peek and one line, not for the batch
+            with self._mu:
+                verdict = None
+                if (self._watched.get(w.conn.id) is w
+                        and (w.armed or w.conn.id in self._paused)
+                        and not self._stop):
+                    verdict = self._look(w)
+            self._deliver(w, verdict)
+
+    def poll(self):
+        """On the CALLER's thread (the decode worker's, at the top of a
+        pass): the verdicts of the connections readable right now. The
+        watcher's own thread needs the interpreter several times a
+        cancel line; behind a round's handlers, all woken at once, it
+        fell a second behind its clients (PERF.md section 6, PR 43), and
+        a slot decodes for nobody until its cancel is read."""
+        try:
+            ready = self._sel.select(0)
+        except (OSError, ValueError):
+            return
+        # (the wake-up pipe is the thread's own to empty)
+        self._serve([key.data for key, _ in ready if key.data is not None])
 
     def _look(self, w):
         """Under ``_mu``, a connection that turned readable (or whose
@@ -549,6 +573,22 @@ class _DecodeWorker(object):
     per-dispatch token increments to its wire stream. Finished slots
     that no stream owns (a restored process's orphaned backlog) are
     banked in the session's result bank, exactly like ``pump()``.
+
+    Every event the worker sends goes through ONE first-in-first-out
+    outbox (``_emit``), so a stream's order (``admitted``, ``tokens``
+    with contiguous ``seq``, ``end`` or an error line) cannot invert.
+    With no request waiting for a slot each phase of a pass puts its
+    events as it ends. While requests wait (``_hold``), a dispatch's
+    events are kept until the NEXT decode dispatch is launched and put
+    while the chip runs it (``_in_flight``, which the session hands to
+    its dispatch): the wake-ups of some hundred handler threads then
+    stand beside the device's time, not in line with it, at the price of
+    one pass's cancels and admissions of delay for those tokens, which
+    below the knee would buy nothing. The clients answer those events
+    (cancels among them) while the worker's thread is busiest, so while
+    it holds events it reads the connections' verdicts itself at the top
+    of a pass (``_ConnWatcher.poll``) and does not wait for the
+    watcher's thread to be given the interpreter.
     """
 
     def __init__(self, session, max_backlog=64, handlers=None):
@@ -567,6 +607,8 @@ class _DecodeWorker(object):
         self._rid_stream = {}    # rid -> stream (queued, not yet admitted)
         self._prev_pos = {}      # slot -> last streamed position
         self._beam_stream = {}   # lane -> stream (beam generations)
+        self._outbox = deque()   # (stream, event) not yet in stream.q
+        self._hold = False       # the outbox waits for the next launch
         self._max_backlog = int(max_backlog)
         self._thread = threading.Thread(
             target=self._loop, name="paddle-tpu-frontend-decode",
@@ -631,6 +673,55 @@ class _DecodeWorker(object):
             box["exc"] = ServerClosedError("frontend is closed")
             done.set()
 
+    # -- the outbox (worker thread only) -------------------------------------
+
+    def _emit(self, stream, event):
+        self._outbox.append((stream, event))
+
+    def _flush(self, deferred=False):
+        """Put the outbox's events, oldest first. The caller stands in a
+        round span (``handoff`` wherever it is not a phase's own)."""
+        out = self._outbox
+        n = len(out)
+        while out:
+            stream, event = out.popleft()
+            stream.q.put(event)
+        if n and _tracing.ENABLED:
+            _tracing.round_count("handoff_events", n)
+            if deferred:
+                _tracing.round_count("handoff_deferred", n)
+
+    def _settle(self):
+        """A phase's end: its events go out now, unless they wait for
+        the next launch."""
+        if not self._hold:
+            self._flush()
+
+    def _release(self):
+        """Whatever is held goes out now: no launch will carry it (the
+        pass made no dispatch, is about to block, or something has to
+        see the streams as the session has them: an op at its quiesce
+        point, a failure's or a close's error lines)."""
+        self._hold = False
+        if self._outbox:
+            with _tracing.span("handoff"):
+                self._flush()
+
+    def _in_flight(self):
+        """The session's ``in_flight``: runs inside the decode dispatch,
+        launched and not yet waited for. Must not raise into the
+        executor; what a failure leaves is put when ``step`` returns."""
+        if not self._outbox:
+            return
+        try:
+            with _tracing.span("handoff"):
+                self._flush(deferred=True)
+        except Exception:  # noqa: BLE001 - logged, the dispatch goes on
+            import logging
+
+            logging.getLogger("paddle_tpu.serving").exception(
+                "event flush inside the decode dispatch failed")
+
     # -- worker loop ---------------------------------------------------------
 
     def _loop(self):
@@ -680,6 +771,10 @@ class _DecodeWorker(object):
         whether the worker lives on, and whether the pass moved
         anything."""
         s = self._s
+        if self._hold and self.watcher is not None:
+            # what the clients answered to the flush made in flight
+            with _tracing.span("verdicts"):
+                self.watcher.poll()
         with _tracing.span("wait"), self._cond:
             while (not self._incoming and not self._cancels
                     and not self._ops
@@ -688,6 +783,7 @@ class _DecodeWorker(object):
                 # the timeout re-checks capacity-deferred backlog
                 # (a NoFreePage defer relaxes only as leaks/cache
                 # pressure do, not on any notify)
+                self._release()
                 self._cond.wait(0.25)
             incoming = list(self._incoming)
             self._incoming.clear()
@@ -702,25 +798,31 @@ class _DecodeWorker(object):
         self._teardown(cancels)
         # ops run at this quiesce point: after cancels (so a drain's
         # "no live streams" check sees the teardowns) and before
-        # this pass's admissions/dispatch
-        self._run_ops(ops)
+        # this pass's admissions/dispatch; the streams have what the
+        # session has by then
+        if ops:
+            self._release()
+            self._run_ops(ops)
         if incoming:
             with _tracing.span("enqueue"):
                 for stream in incoming:
                     if stop:
-                        stream.q.put(error_to_wire(
+                        self._emit(stream, error_to_wire(
                             ServerClosedError("frontend is closed")))
                         stream.done = True
                     elif not stream.cancelled.is_set():
                         self._admit(stream)
+                self._settle()
         if stop and not drain:
             self._abort_all()
             self._fail_ops()
             return False, progressed
         progressed |= self._admit_backlog()
+        stepped = False
         if s.active_slots:
             try:
                 self._step_once()
+                stepped = True
             except Exception as exc:  # noqa: BLE001 - typed below
                 # a hard decode failure (not the classified-retry
                 # transients — those were retried inside the
@@ -730,6 +832,8 @@ class _DecodeWorker(object):
                 # worker lives on for the next admission
                 self._fail_tracked(exc)
             progressed = True
+        if not stepped:
+            self._release()
         if (stop and drain and not s.active_slots
                 and not s.pending_requests and not self._slot_stream
                 and not self._rid_stream and not self._beam_stream):
@@ -766,7 +870,8 @@ class _DecodeWorker(object):
                 stream = self._rid_stream.pop(rid, None)
                 if stream is not None and not stream.done:
                     stream.done = True
-                    stream.q.put(error_to_wire(exc))
+                    self._emit(stream, error_to_wire(exc))
+            self._settle()
             progressed = True
         return progressed
 
@@ -785,7 +890,8 @@ class _DecodeWorker(object):
                     late.append(slot)
                     continue
                 self._track(stream, {slot: 0})
-                stream.q.put(self._admitted_event(stream))
+                self._emit(stream, self._admitted_event(stream))
+            self._settle()
             self._safe_cancel(late)
 
     def _fail_tracked(self, exc):
@@ -796,7 +902,8 @@ class _DecodeWorker(object):
         # terminal event — it was live until this failure)
         self._teardown(streams)
         for stream in streams:
-            stream.q.put(dict(wire))
+            self._emit(stream, dict(wire))
+        self._release()
 
     def _tracked_streams(self):
         """The streams that own a live slot or a beam lane, each once,
@@ -827,7 +934,7 @@ class _DecodeWorker(object):
                 for k, slot in enumerate(s.beam_slots(lane)):
                     stream.live[slot] = k
                 self._trace_admitted(stream, t_admit, kind="beam")
-                stream.q.put(self._admitted_event(stream))
+                self._emit(stream, self._admitted_event(stream))
             elif spec["n"] == 1:
                 # the shed answer at the WIRE edge: a shed session
                 # refuses with the typed retriable DegradedError
@@ -857,7 +964,7 @@ class _DecodeWorker(object):
                 ev = {"ok": True, "event": "queued", "id": int(rid)}
                 if tid:
                     ev["trace_id"] = tid
-                stream.q.put(ev)
+                self._emit(stream, ev)
             else:
                 # forks are admit-or-reject: their n x worst-case page
                 # reservation is too large to head-of-line park in the
@@ -870,10 +977,10 @@ class _DecodeWorker(object):
                 self._track(stream,
                             {slot: m for m, slot in enumerate(slots)})
                 self._trace_admitted(stream, t_admit, kind="group")
-                stream.q.put(self._admitted_event(stream))
+                self._emit(stream, self._admitted_event(stream))
         except Exception as exc:  # noqa: BLE001 - typed to the wire
             stream.done = True
-            stream.q.put(error_to_wire(exc))
+            self._emit(stream, error_to_wire(exc))
 
     def _attach_stream(self, stream):
         """Re-bind a wire stream to an EXISTING solo request by rid —
@@ -895,12 +1002,12 @@ class _DecodeWorker(object):
             trg = s.take_result(rid)
             toks = self._final_tokens(trg, 0)
             stream.done = True
-            stream.q.put({
+            self._emit(stream, {
                 "ok": True, "event": "resumed", "id": rid, "seq": 1,
                 "bos": int(s._bos),
                 "tokens": [int(t) for t in toks], "finished": True,
                 "max_length": int(s._T), "eos": int(s._eos)})
-            stream.q.put({"ok": True, "event": "end", "id": rid})
+            self._emit(stream, {"ok": True, "event": "end", "id": rid})
             return
         slot = next((sl for sl, r in s._owner.items() if r == rid),
                     None)
@@ -911,7 +1018,7 @@ class _DecodeWorker(object):
             stream.rid = rid
             self._track(stream, {slot: 0})
             pos = s._live[slot]["pos"]
-            stream.q.put({
+            self._emit(stream, {
                 "ok": True, "event": "resumed", "id": rid, "seq": 1,
                 "bos": int(s._bos),
                 "tokens": [int(t)
@@ -925,7 +1032,7 @@ class _DecodeWorker(object):
                 stream.spec["prefix"] = pend.get("prefix")
             stream.rid = rid
             self._rid_stream[rid] = stream
-            stream.q.put({
+            self._emit(stream, {
                 "ok": True, "event": "resumed", "id": rid, "seq": 1,
                 "bos": int(s._bos),
                 "tokens": [], "finished": False,
@@ -997,9 +1104,23 @@ class _DecodeWorker(object):
             # what the round's length depends on, beside its spans
             _tracing.round_count("live", len(s._live))
             _tracing.round_count("backlog", len(s._pending))
-        finished = s.step()
+        # step() stays THE call into the session (whoever times the
+        # worker wraps it), so what runs between the dispatch's launch
+        # and its wait reaches the session as an attribute
+        s.in_flight = self._in_flight
+        try:
+            finished = s.step()
+        finally:
+            s.in_flight = None
+            # what is left: a step path that launched nothing through
+            # the hook (dense, beam, speculative), a dispatch that failed
+            self._release()
         with _tracing.span("handoff"):
+            # while requests wait for a slot, this dispatch's events
+            # ride the next launch; with none waiting they go out now
+            self._hold = bool(s.pending_requests)
             tokens = self._hand_off(finished)
+            self._settle()
         if _tracing.ENABLED:
             _tracing.round_count("tokens", tokens)
 
@@ -1015,11 +1136,12 @@ class _DecodeWorker(object):
             stream = self._beam_stream.get(lane)
             if stream is None or stream.cancelled.is_set():
                 continue
-            stream.q.put({"ok": True, "event": "beam",
-                          "parents": [int(p) for p in ev["parents"]],
-                          "tokens": [int(t) for t in ev["tokens"]],
-                          "scores": [float(x) for x in ev["scores"]],
-                          "done": [bool(d) for d in ev["done"]]})
+            self._emit(stream, {
+                "ok": True, "event": "beam",
+                "parents": [int(p) for p in ev["parents"]],
+                "tokens": [int(t) for t in ev["tokens"]],
+                "scores": [float(x) for x in ev["scores"]],
+                "done": [bool(d) for d in ev["done"]]})
             tokens += len(ev["tokens"])
         for lane, fin in getattr(s, "last_finished_beams", {}).items():
             stream = self._beam_stream.pop(lane, None)
@@ -1034,7 +1156,7 @@ class _DecodeWorker(object):
             if not stream.cancelled.is_set():
                 # the final survivor chunk first (the step that ended
                 # the beam still moved tokens), then the n-best
-                stream.q.put({
+                self._emit(stream, {
                     "ok": True, "event": "beam",
                     "parents": [int(p) for p in fin["parents"]],
                     "tokens": [int(t) for t in fin["step_tokens"]],
@@ -1062,9 +1184,9 @@ class _DecodeWorker(object):
                     end_ev["scores"] = [float(x) for x in pscores]
                     end_ev["order"] = [int(i) for i in order]
                     end_ev["len_penalty"] = float(lp)
-                stream.q.put(end_ev)
+                self._emit(stream, end_ev)
                 stream.done = True
-                stream.q.put({"ok": True, "event": "end"})
+                self._emit(stream, {"ok": True, "event": "end"})
                 tokens += len(fin["step_tokens"])
         for slot in list(self._slot_stream):
             stream, member = self._slot_stream[slot]
@@ -1089,7 +1211,7 @@ class _DecodeWorker(object):
                         # request carries seq=1)
                         ev["id"] = int(stream.rid)
                         ev["seq"] = int(prev + 1)
-                    stream.q.put(ev)
+                    self._emit(stream, ev)
                     tokens += len(toks)
                 if not stream.live and not stream.done:
                     stream.done = True
@@ -1097,7 +1219,7 @@ class _DecodeWorker(object):
                         end_ev = {"ok": True, "event": "end"}
                         if stream.rid is not None:
                             end_ev["id"] = int(stream.rid)
-                        stream.q.put(end_ev)
+                        self._emit(stream, end_ev)
             else:
                 st = s._live.get(slot)
                 if st is None:
@@ -1111,7 +1233,7 @@ class _DecodeWorker(object):
                     if stream.rid is not None:
                         ev["id"] = int(stream.rid)
                         ev["seq"] = int(prev + 1)
-                    stream.q.put(ev)
+                    self._emit(stream, ev)
                     tokens += new - prev
                 self._prev_pos[slot] = new
         # orphaned finishes (no stream — a restored process's backlog):
@@ -1180,7 +1302,8 @@ class _DecodeWorker(object):
             self._tracked_streams() + list(self._rid_stream.values())))
         self._teardown(streams)
         for stream in streams:
-            stream.q.put(error_to_wire(closed))
+            self._emit(stream, error_to_wire(closed))
+        self._release()
 
 
 class ServingFrontend(object):

@@ -526,6 +526,10 @@ class SlotDecodeSession(object):
         self._results = {}       # request id -> [T] tokens, until taken
         self._next_req = 0
         self.steps_done = 0      # step() dispatches completed (chaos key)
+        # whoever drives step() may leave a callable here: the decode
+        # dispatch runs it once between its launch and its wait (the
+        # decode worker's event flush; run_multi_step's ``in_flight``)
+        self.in_flight = None
         # request tracing (observability/tracing.py): rid -> trace id
         # rides the decode snapshot, so a restored process re-emits its
         # banked streams under the ORIGINAL ids; slot -> Trace is
@@ -1783,7 +1787,7 @@ class SlotDecodeSession(object):
             (toks,) = self._exe.run_multi_step(
                 self._step_prog, self._steps, feed={},
                 fetch_list=[self._fetch_name], scope=self._scope,
-                stack_fetches=True)
+                stack_fetches=True, in_flight=self.in_flight)
         elapsed = time.perf_counter() - t0
         toks = np.asarray(toks)  # [K, S, 1]
         live_before = len(self._live)

@@ -268,6 +268,77 @@ def test_each_call_leaves_one_dispatch_record_with_its_phases(entry):
         assert rec["wall_s"] >= sum(rec["phases"].values()) * 0.999
 
 
+def _multi_step_fetches(in_flight, return_numpy, calls=3):
+    """A fresh start of the dropout program, then ``calls`` dispatches
+    of two steps each with ``in_flight`` handed in: what each fetched,
+    as the call returned it."""
+    main, out, exe = _start()
+    return [exe.run_multi_step(main, 2, feed=_feed(), fetch_list=[out],
+                               return_numpy=return_numpy,
+                               in_flight=in_flight)[0]
+            for _ in range(calls)]
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["plain", "traced"])
+@pytest.mark.parametrize("return_numpy", [True, False])
+def test_in_flight_runs_once_a_call_between_the_launch_and_the_wait(
+        return_numpy, traced, monkeypatch):
+    """``run_multi_step(in_flight=fn)``: ``fn`` is called exactly once a
+    call, after the dispatch is launched and before anything waits for it
+    (with ``return_numpy=False`` nothing in the call does, and the live
+    arrays come back); the dispatch record keeps its phases, and the
+    hook's seconds (30 ms asleep, 20 ms of CPU) are in its wall and in
+    none of them, the residual ``host`` neither; what is fetched is
+    bit-equal with and without it."""
+    from paddle_tpu import executor as executor_mod
+    from paddle_tpu.observability import tracing
+
+    log = []
+    dispatch = executor_mod.Executor._dispatch
+    materialize = executor_mod._materialize_fetches
+    monkeypatch.setattr(
+        executor_mod.Executor, "_dispatch", staticmethod(
+            lambda *a, **kw: (dispatch(*a, **kw), log.append("launch"))[0]))
+    monkeypatch.setattr(
+        executor_mod, "_materialize_fetches",
+        lambda *a, **kw: (log.append("wait"), materialize(*a, **kw))[1])
+
+    def hook():
+        log.append("in_flight")
+        time.sleep(0.03)
+        until = time.thread_time() + 0.02
+        while time.thread_time() < until:
+            pass
+
+    plain = _multi_step_fetches(None, return_numpy)
+    del log[:]
+    monkeypatch.setattr(tracing, "ENABLED", traced)
+    mark = time.time()
+    hooked = _multi_step_fetches(hook, return_numpy)
+    monkeypatch.setattr(tracing, "ENABLED", False)
+    # _start() runs the startup program: a launch and no hook
+    waited = ["wait"] if return_numpy else []
+    assert log == ["launch", "wait"] + 3 * (
+        ["launch", "in_flight"] + waited)
+    for got, want in zip(hooked, plain):
+        assert isinstance(got, np.ndarray) == return_numpy
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    mine = [r for r in step_profiler.dispatch_records("multi_step")
+            if r["t1"] >= mark]
+    assert len(mine) == 3
+    for rec in mine:
+        assert list(rec["phases"]) == ["feed", "compile", "dispatch",
+                                       "fetch"] + (
+            ["device"] if return_numpy else []) + ["host"]
+        assert rec["wall_s"] >= 0.05
+        assert sum(rec["phases"].values()) <= rec["wall_s"] - 0.05 + 1e-4
+        if traced:
+            assert set(rec["cpu"]) == set(rec["phases"])
+            assert sum(rec["cpu"].values()) < 0.02
+        else:
+            assert rec["cpu"] is None
+
+
 @pytest.mark.parametrize("entry", ENTRIES)
 def test_each_observer_records_one_event_per_call(entry, tmp_path):
     main, out, exe = _start()

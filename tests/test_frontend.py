@@ -17,6 +17,7 @@ the frontend banks its backlog and dies by the signal).
 
 import json
 import os
+import queue
 import signal
 import socket
 import subprocess
@@ -745,15 +746,334 @@ def test_a_decoder_only_session_behind_the_worker_cancels_with_none():
     assert sess.pool_conserved and sess.pages_in_use == 0
 
 
-# ---------------------------------------------------------------------------
-# the connection watcher: who reads a cancel or EOF while a stream is in flight
-# ---------------------------------------------------------------------------
-
 def _until(cond, timeout=30.0):
     deadline = time.monotonic() + timeout
     while not cond() and time.monotonic() < deadline:
         time.sleep(0.005)
     return cond()
+
+
+# ---------------------------------------------------------------------------
+# the outbox: while requests wait for a slot, a dispatch's events ride the
+# next launch
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module", params=["paged", "decoder_only"])
+def served(request, trained):
+    """``(session, spec)`` of each kind of session the worker drives, four
+    slots and at most four decode dispatches a request; ``spec(i)`` is
+    request i's, over eight prompts."""
+    if request.param == "paged":
+        sess = _paged(trained, sampler=None, prefix_cache_pages=0,
+                      num_groups=S)
+        prompts = [(row, SEQ) for row in trained["src"]]
+    else:
+        from test_latent_moe_decoder import make_session, prompts_of
+
+        sess, _tree = make_session(max_new_tokens=8)
+        prompts = []
+        for prompt in prompts_of([7, 21, 12, 5, 9, 30, 16, 3], seed=4):
+            src = np.zeros(32, "int64")
+            src[:len(prompt)] = prompt
+            prompts.append((src, len(prompt)))
+
+    def spec(i):
+        src, n = prompts[i % len(prompts)]
+        return {"src": src, "src_len": n, "n": 1, "prefix": None}
+
+    return sess, spec
+
+
+class _LoggedQueue(queue.SimpleQueue):
+    def __init__(self, log, index):
+        queue.SimpleQueue.__init__(self)
+        self._log, self._index = log, index
+
+    def put(self, item, *a, **kw):
+        self._log.append(("put", self._index, item))
+        queue.SimpleQueue.put(self, item, *a, **kw)
+
+
+class _Outboxed(object):
+    """A bare decode worker over ``sess`` with its thread's doings in ONE
+    log: every decode dispatch's ``launch`` and ``collect`` (this object
+    stands between the session and its executor and wraps the
+    ``in_flight`` it is handed; ``collect`` says whether requests waited
+    for a slot then), the events the worker ``built`` from a dispatch
+    (read off its outbox as ``_hand_off`` returns) and every ``put`` to a
+    stream's queue. ``hook=False`` drops the ``in_flight``: a session
+    whose step never calls it. ``lockstep=True`` makes every
+    ``session.step()`` wait for ``allow()``."""
+
+    def __init__(self, sess, spec, hook=True, lockstep=False):
+        from paddle_tpu.serving.frontend import _DecodeWorker, _Stream
+
+        self.log, self.streams = [], []
+        self.sess, self.spec, self.hook = sess, spec, hook
+        self._make_stream = _Stream
+        self.exe, self.dispatches = sess._exe, 0
+        self.fail_next = False
+        self._sem = threading.Semaphore(0) if lockstep else None
+        self._step = sess.step
+        sess._exe, sess.step = self, self._gated_step
+        self.worker = _DecodeWorker(sess)
+        hand_off = self.worker._hand_off
+
+        def logged_hand_off(finished):
+            before = len(self.worker._outbox)
+            out = hand_off(finished)
+            self.log.append(("built", self.dispatches,
+                             [ev for _st, ev in
+                              list(self.worker._outbox)[before:]]))
+            return out
+
+        self.worker._hand_off = logged_hand_off
+
+    # -- between the session and its executor --------------------------------
+    def __getattr__(self, name):
+        return getattr(self.exe, name)
+
+    def run_multi_step(self, *a, **kw):
+        self.dispatches += 1
+        k = self.dispatches
+        fn = kw.pop("in_flight", None)
+        if self.hook and fn is not None:
+            def launched():
+                self.log.append(("launch", k))
+                fn()
+
+            kw["in_flight"] = launched
+        else:
+            self.log.append(("launch", k))
+        out = self.exe.run_multi_step(*a, **kw)
+        self.log.append(("collect", k, bool(self.sess.pending_requests)))
+        return out
+
+    def _gated_step(self):
+        if self._sem is not None:
+            assert self._sem.acquire(timeout=60)
+        if self.fail_next:
+            self.fail_next = False
+            raise RuntimeError("the decode dispatch is lost")
+        return self._step()
+
+    # -- the test's side -----------------------------------------------------
+    def allow(self, steps=1):
+        for _ in range(steps):
+            self._sem.release()
+
+    def submit(self, n):
+        for _ in range(n):
+            stream = self._make_stream(self.spec(len(self.streams)))
+            stream.q = _LoggedQueue(self.log, len(self.streams))
+            self.streams.append(stream)
+            self.worker.submit(stream)
+
+    def built(self, k):
+        """The events built from dispatch ``k``, once they are."""
+        assert _until(lambda: any(e[:2] == ("built", k) for e in self.log))
+        return [e[2] for e in self.log if e[:2] == ("built", k)][0]
+
+    def where_put(self):
+        """{id(event): where it was put}: ``("flight", k)`` between
+        dispatch k's launch and its collect, ``("line", k)`` after
+        dispatch k's collect and before the next launch."""
+        at, out = ("line", 0), {}
+        for entry in list(self.log):
+            if entry[0] == "launch":
+                at = ("flight", entry[1])
+            elif entry[0] == "collect":
+                at = ("line", entry[1])
+            elif entry[0] == "put":
+                out[id(entry[2])] = at
+        return out
+
+    def events(self, i):
+        """Everything stream ``i`` was sent, in the order it was put."""
+        return [e[2] for e in list(self.log) if e[:2] == ("put", i)]
+
+    def close(self):
+        if self._sem is not None:
+            self.allow(1000)
+        try:
+            self.worker.stop(drain=False, timeout=30)
+            assert not self.worker._thread.is_alive()
+        finally:
+            self.sess._exe, self.sess.step = self.exe, self._step
+            del self.sess.step      # the class's own again
+        assert _drained(self.sess)
+
+
+def _assert_stream_order(events, whole):
+    """``queued``, ``admitted``, ``tokens`` with ``seq`` contiguous from
+    the admission's ``pos``, then ``end``; an error line may end it
+    anywhere, and nothing follows either. ``whole``: the stream was
+    neither cancelled nor failed, so all of that. The tokens it was
+    sent."""
+    names = [ev.get("event") if ev.get("ok") else "error" for ev in events]
+    tokens, body = [], list(zip(names, events))
+    if body and body[0][0] == "queued":
+        body.pop(0)
+    if not body or names[-1] == "error" and len(body) == 1:
+        assert not whole    # cancelled or failed while still queued
+        return tokens
+    assert body[0][0] == "admitted", names
+    seq = body[0][1]["pos"] + 1
+    for at, (name, ev) in enumerate(body[1:], 1):
+        if name == "tokens":
+            assert ev["seq"] == seq, (names, ev, seq)
+            seq += len(ev["tokens"])
+            tokens += ev["tokens"]
+        else:
+            assert name in ("end", "error") and at == len(body) - 1, names
+    if whole:
+        assert names[-1] == "end" and tokens, names
+    return tokens
+
+
+def test_with_a_backlog_a_dispatch_s_events_ride_the_next_launch(served):
+    """Ten requests over four slots, the worker a step at a time. A
+    dispatch collected while requests wait for a slot has its events put
+    AFTER the next dispatch's launch and BEFORE its collect; once nothing
+    waits they are put as before, ahead of the next launch."""
+    sess, spec = served
+    box = _Outboxed(sess, spec, lockstep=True)
+    try:
+        box.submit(10)
+        box.allow()
+        box.built(1)       # the rest are enqueued by the pass after it
+        box.allow(1000)
+        assert _until(lambda: all(st.done for st in box.streams), 120)
+        assert _until(lambda: not box.worker._outbox)
+    finally:
+        box.close()
+    where = box.where_put()
+    backlog = {e[1]: e[2] for e in box.log if e[0] == "collect"}
+    held = at_once = 0
+    for entry in box.log:
+        if entry[0] != "built" or not entry[2]:
+            continue
+        k = entry[1]
+        want = ("flight", k + 1) if backlog[k] else ("line", k)
+        assert {where[id(ev)] for ev in entry[2]} == {want}, (k, backlog)
+        held += backlog[k]
+        at_once += not backlog[k]
+    assert held >= 2 and at_once >= 1, backlog
+    for i in range(10):
+        _assert_stream_order(box.events(i), whole=True)
+
+
+@pytest.mark.parametrize("hook", [True, False], ids=["hook", "no_hook"])
+def test_no_stream_is_sent_its_events_out_of_order(served, hook):
+    """56 requests over four slots with the worker running free, every
+    seventh cancelled (queued or mid-flight): each stream is sent
+    ``admitted``, contiguous ``seq`` and ``end`` in that order, streams of
+    one prompt the same tokens. A session whose step never calls the hook
+    loses and reorders nothing either: its events wait for ``step`` to
+    return."""
+    sess, spec = served
+    box = _Outboxed(sess, spec, hook=hook)
+    cancelled = set(range(3, 56, 7))
+    try:
+        box.submit(56)
+        for i in sorted(cancelled):
+            if i % 2:      # mid-flight: once it holds a slot
+                _until(lambda: box.streams[i].live or box.streams[i].done)
+            box.worker.cancel(box.streams[i])
+        assert _until(lambda: all(st.done for st in box.streams), 240)
+        assert _until(lambda: not box.worker._outbox)
+    finally:
+        box.close()
+    by_prompt = {}
+    for i in range(56):
+        tokens = _assert_stream_order(box.events(i),
+                                      whole=i not in cancelled)
+        if i not in cancelled:
+            by_prompt.setdefault(i % 8, []).append(tokens)
+    assert all(t == same[0] for same in by_prompt.values() for t in same)
+    flights = [at for at in box.where_put().values() if at[0] == "flight"]
+    assert bool(flights) == hook
+
+
+@pytest.mark.parametrize("how", ["op", "failure", "close"])
+def test_nothing_is_left_in_the_outbox_behind(served, how):
+    """With a dispatch's events held for the next launch: an op at its
+    quiesce point, a failed dispatch's error lines and a
+    ``close(drain=False)`` each find (or leave) the outbox empty, and the
+    held events are put before whatever they send."""
+    sess, spec = served
+    box = _Outboxed(sess, spec, lockstep=True)
+    worker, seen = box.worker, {}
+    fail_tracked = worker._fail_tracked
+
+    def logged_fail_tracked(exc):
+        fail_tracked(exc)
+        seen["failure"] = (len(worker._outbox), worker._hold)
+
+    worker._fail_tracked = logged_fail_tracked
+    try:
+        box.submit(10)
+        box.allow()
+        box.built(1)
+        box.allow()
+        held = box.built(2)         # four slots live, six requests queued
+        assert held and _until(lambda: worker._hold)
+        # the worker stands before dispatch 3 with dispatch 2's events
+        assert _until(lambda: len(worker._outbox) >= len(held))
+        assert not set(map(id, held)) & set(box.where_put())
+        if how == "op":
+            def op():
+                box.log.append(("op",))
+                return len(worker._outbox), worker._hold
+
+            got = []
+            t = threading.Thread(
+                target=lambda: got.append(worker.call(op)))
+            t.start()
+            assert _until(lambda: worker._ops)
+            box.allow()             # dispatch 3: its events are held too
+            t.join(timeout=60)
+            assert got == [(0, False)]
+            last = box.built(3)
+            where = box.where_put()
+            assert {where[id(ev)] for ev in held} == {("flight", 3)}
+            assert {where[id(ev)] for ev in last} == {("line", 3)}
+            log = list(box.log)
+            assert max(n for n, e in enumerate(log) if e[0] == "put"
+                       and any(e[2] is ev for ev in last)) < log.index(
+                           ("op",))
+        elif how == "failure":
+            tracked = [i for i, st in enumerate(box.streams) if st.live]
+            assert len(tracked) == 4
+            box.fail_next = True
+            box.allow()
+            assert _until(lambda: "failure" in seen)
+            assert seen["failure"] == (0, False)
+            for i in tracked:
+                events = box.events(i)
+                assert "lost" in events[-1]["error"], events[-1]
+                _assert_stream_order(events, whole=False)
+            assert all(id(ev) in box.where_put() for ev in held)
+        else:
+            t = threading.Thread(
+                target=lambda: worker.stop(drain=False, timeout=60))
+            t.start()
+            assert _until(lambda: worker._stop)
+            box.allow()
+            t.join(timeout=60)
+            assert not worker._thread.is_alive() and not worker._outbox
+            for i in range(10):
+                events = box.events(i)
+                assert not events[-1].get("ok"), events[-1]
+                _assert_stream_order(events, whole=False)
+            assert all(id(ev) in box.where_put() for ev in held)
+    finally:
+        box.close()
+
+
+# ---------------------------------------------------------------------------
+# the connection watcher: who reads a cancel or EOF while a stream is in flight
+# ---------------------------------------------------------------------------
 
 
 class _Wired(object):
@@ -840,6 +1160,34 @@ def test_inband_cancel_is_read_while_the_queue_never_runs_empty():
     finally:
         stop.set()
         feeder.join()
+        w.close()
+
+
+def test_a_poll_on_the_callers_thread_posts_each_verdict_once():
+    """``_ConnWatcher.poll()``, which the decode worker calls at the top
+    of a pass while it holds events: the cancel lines readable NOW are
+    read on the caller's thread, racing the watcher's own; each stream is
+    cancelled on the decode worker once and handed one verdict, and a
+    connection with nothing to read is left alone."""
+    w = _Wired(n=8)
+    try:
+        for i in range(0, 8, 2):
+            w.clients[i].sendall(_CANCEL_LINE)
+        for _ in range(50):
+            w.watcher.poll()
+        want = [w.streams[i] for i in range(0, 8, 2)]
+        assert _until(lambda: len(w.cancelled) >= 4)
+        time.sleep(0.05)
+        w.watcher.poll()
+        assert sorted(map(id, w.cancelled)) == sorted(map(id, want))
+        for i in range(8):
+            if i % 2:
+                assert w.streams[i].q.empty()
+            else:
+                assert w.next_event(i) == ([_ACK], "cancelled")
+                assert w.streams[i].q.empty()
+        assert w.watcher.counts()["cancel"] == 4
+    finally:
         w.close()
 
 

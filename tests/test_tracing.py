@@ -496,6 +496,12 @@ def test_round_counts_equal_what_the_session_did(served):
     assert served["read_tokens"] == 5 * (SEQ - 1)
     assert served["read_tokens"] <= total("tokens") \
         <= served["read_tokens"] + (SEQ - 1)
+    # events put to the streams' queues: the chunks the clients read and
+    # each of their four streams' ``admitted`` and ``end`` at the least;
+    # none of them from inside a decode dispatch, where they are put
+    # while requests wait for a slot (here no two streams were open)
+    assert total("handoff_events") >= served["read_chunks"] + 4 * 2
+    assert total("handoff_deferred") == 0
     for r in rounds:
         root = r["spans"][0]
         if "live" in root:
@@ -514,7 +520,8 @@ def test_round_counts_equal_what_the_session_did(served):
                           "admit_rows", "admit_pad_rows",
                           "cancel_dispatches", "cancel_rows",
                           "cancel_pad_rows", "handler_cpu",
-                          "handler_chunks"} | set(_WATCHER_KEYS)
+                          "handler_chunks", "handoff_events",
+                          "handoff_deferred"} | set(_WATCHER_KEYS)
                          if i == 0 else set()), sp
 
 
