@@ -226,6 +226,49 @@ def test_flash_train_width_lowers_for_tpu(v5e, dtype):
     _assert_flash_fwd_bwd(text)
 
 
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["causal", "key_mask"])
+def test_flash_train_shape_grid_and_operand_dtype(v5e, masked):
+    """``train_big_1chip``'s attention, bfloat16 [64, 16, 256, 64], forward
+    and backward (decoder self-attention; encoder and cross attention
+    under their key mask): each of the three Mosaic calls steps a grid of
+    at most 256 (one tile a sequence, four heads or more a step; tiles of
+    128 a head took 4096), and q, k, v and dO reach them as they arrive:
+    no float32 array of their shape is made anywhere in the compiled
+    program (a widened dO was read by both backward kernels)."""
+    import re
+
+    qkv = ((64, 16, 256, 64), BF16)
+    specs = [qkv] * 3 + ([((64, 256), F32)] if masked else [])
+    fn = _flash_grad(causal=not masked)
+    text = _compile_v5e(v5e, fn, *specs)
+    _assert_flash_fwd_bwd(text)
+
+    def traced(*a):
+        with _tpu_target():
+            return fn(*a)
+
+    grids = _pallas_grids(jax.make_jaxpr(traced)(*[
+        jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in specs]).jaxpr)
+    assert set(grids) == {fa.FWD_KERNEL_NAME, fa.BWD_DKV_KERNEL_NAME,
+                          fa.BWD_DQ_KERNEL_NAME}
+    for name, grid in grids.items():
+        assert np.prod(grid) <= 256, (name, grid)
+        # one tile a sequence: only batch and head blocks are stepped
+        assert grid[0] == 64 and set(grid[2:]) == {1}, (name, grid)
+    # an instruction of a fused computation lives in registers; every
+    # other one's result is an array of the program
+    widened, fused = [], False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            fused = head.group(1).startswith("fused_computation")
+        elif not fused and re.match(
+                r"\s*(?:ROOT )?%\S+ = f32\[64,16,256,64\]", line):
+            widened.append(line.strip()[:160])
+    assert not widened, "a float32 copy of an operand:\n" + "\n".join(widened)
+
+
 def test_ring_flash_lowers_for_tpu(v5e):
     """Ring attention's shard_map + per-block Pallas engine compiles
     for the four described chips — guards the Mosaic x shard_map

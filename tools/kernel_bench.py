@@ -293,12 +293,132 @@ def _bench_delta_rule(update_shapes, prefill_shapes, calls, steps, warmup):
     return rows
 
 
+def _flash_tile_cases(quick):
+    """(label, q shape, kv heads, dtype, kwargs, backward?, tiles) of the
+    sweep: the training cell's self/cross attention and the decoder-only
+    cells' prefill widths, each under the rule (``None``) and under the
+    ``(block_q, block_k, heads_per_step)`` it is swept against."""
+    if quick:
+        return [("quick", (1, 4, 32, 16), 4, "float32",
+                 dict(causal=True), True, [None, (16, 16, 2)])]
+    train = (64, 16, 256, 64)
+    swept = [None] + [(t, t, hb) for t in (128, 256)
+                      for hb in (1, 2, 4, 8, 16)]
+    cases = [("train_causal", train, 16, "bfloat16", dict(causal=True),
+              True, swept),
+             ("train_key_mask", train, 16, "bfloat16", dict(mask=True),
+              True, [None, (128, 128, 1), (256, 256, 1), (256, 256, 4),
+                     (256, 256, 8)])]
+    for T in (256, 1024):     # glm47_flash_6l: 20 heads of 256
+        cases.append(("glm_prefill", (2, 20, T, 256), 20, "bfloat16",
+                      dict(causal=True), False,
+                      [None, (128, 128, 1), (min(T, 512),) * 2 + (1,)]))
+    for T in (256, 1024):     # jamba2_3b: 20 heads of 128 on ONE kv head
+        cases.append(("jamba_prefill", (2, 20, T, 128), 1, "bfloat16",
+                      dict(causal=True, kv_group=20), False,
+                      [None, (128, 128, 1), (min(T, 512),) * 2 + (1,)]))
+    for T in (512, 2048):     # solar_open2_4l: 64 heads of 128 on 8
+        cases.append(("solar_prefill", (1, 64, T, 128), 8, "bfloat16",
+                      dict(causal=True, kv_group=8), False,
+                      [None, (128, 128, 1), (512, 512, 1)]))
+    # trinity_mini_5l's window layer at ops/window_ops.py's tiles
+    cases.append(("trinity_prefill", (1, 32, 8192, 128), 4, "bfloat16",
+                  dict(causal=True, kv_group=8, window=2048), False,
+                  [(512, 512, 1), (512, 512, 2), (512, 512, 4)]))
+    return cases
+
+
+def _bench_flash_tiles(cases, steps, fa=None):
+    """The three flash kernels alone, by their device time in a profiler
+    trace (``perfbench/trace_reduce.py``; found by their pallas_call
+    names): the tile rule of ``kernels/flash_attention.py`` against the
+    tiles and heads a grid step it is swept over. ``_choose_tiles`` is
+    replaced for a swept row and nothing else of the kernel is."""
+    import importlib
+    import shutil
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench import trace_reduce
+
+    fa = fa or importlib.import_module("paddle_tpu.kernels.flash_attention")
+    names = (fa.FWD_KERNEL_NAME, fa.BWD_DKV_KERNEL_NAME,
+             fa.BWD_DQ_KERNEL_NAME)
+    rule = getattr(fa, "_choose_tiles", None)
+    rows = []
+    for label, qshape, kvh, dtype, kw, backward, swept in cases:
+        B, H, T, d = qshape
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        q = jax.random.normal(keys[0], qshape, jnp.float32).astype(dtype)
+        k, v = (jax.random.normal(kk, (B, kvh, T, d),
+                                  jnp.float32).astype(dtype)
+                for kk in keys[1:3])
+        w = jax.random.normal(keys[3], qshape, jnp.float32).astype(dtype)
+        kw = dict(kw)
+        if kw.pop("mask", False):
+            kw["mask"] = jnp.ones((B, T), bool)
+        for tiles in swept:
+            if tiles is not None and rule is not None:
+                fa._choose_tiles = lambda *a, **k_: tiles
+            elif tiles is not None:   # a tree without the rule: its tiles
+                kw = dict(kw, block_q=tiles[0], block_k=tiles[1])
+
+            def attend(q_, k_, v_):
+                return fa.flash_attention(q_, k_, v_, **kw)
+
+            def loss(q_, k_, v_):
+                return (attend(q_, k_, v_) * w).astype(jnp.float32).sum()
+
+            fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2))
+                         if backward else attend)
+            try:
+                jax.block_until_ready(fn(q, k, v))
+                trace_dir = tempfile.mkdtemp()
+                with jax.profiler.trace(trace_dir):
+                    for _ in range(steps):
+                        jax.block_until_ready(fn(q, k, v))
+                path = [os.path.join(r, f) for r, _d, fs in
+                        os.walk(trace_dir) for f in fs
+                        if f.endswith(".xplane.pb")][0]
+                ops = next(iter(trace_reduce.flatten(
+                    path)["devices"].values()))["ops"]
+                shutil.rmtree(trace_dir, ignore_errors=True)
+                ms = {n: 0.0 for n in names}
+                for name, _t0, dur in ops:
+                    # under jax.grad the calls are jvp_<name>_ and
+                    # transpose_jvp_<name>__
+                    stem = trace_reduce.op_name(name)
+                    for n in names:
+                        if n in stem:
+                            ms[n] += dur / 1e6 / steps
+                row = {"kernel": "flash_tiles", "case": label,
+                       "shape": list(qshape), "kv_heads": kvh,
+                       "dtype": dtype,
+                       "tiles": "rule" if tiles is None else list(tiles),
+                       "fwd_ms": round(ms[names[0]], 4)}
+                if backward:
+                    row.update(dkv_ms=round(ms[names[1]], 4),
+                               dq_ms=round(ms[names[2]], 4))
+            except Exception as exc:  # a refused tile is a row, not the end
+                row = {"kernel": "flash_tiles", "case": label,
+                       "shape": list(qshape), "tiles": list(tiles or ()),
+                       "error": (str(exc) or repr(exc))[-300:]}
+            finally:
+                if rule is not None:
+                    fa._choose_tiles = rule
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+    return rows
+
+
 def _on_tpu():
     return os.environ.get("BENCH_PLATFORM") != "cpu"
 
 
 _FAMILIES = ("dynamic_lstm", "dynamic_gru", "flash_attention",
-             "paged_decode", "delta_rule")
+             "flash_tiles", "paged_decode", "delta_rule")
 
 
 def _orchestrate(args):
@@ -408,6 +528,9 @@ def main():
     elif args.family == "delta_rule":
         _bench_delta_rule(update_shapes, prefill_shapes,
                           2 if args.quick else 12, steps, warmup)
+    elif args.family == "flash_tiles":
+        _bench_flash_tiles(_flash_tile_cases(args.quick),
+                           2 if args.quick else 5)
     else:
         _bench_flash(fluid, fa_shapes, steps, warmup)
         # sliding-window leg: same longest shape, window = seq/8 — the
