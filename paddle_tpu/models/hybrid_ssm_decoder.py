@@ -24,38 +24,28 @@ d_state, d_inner]`` float32 and the convolution's window ``hsd_win_<i>``
 ``[d_conv - 1, slots, d_inner]`` (``kernels/selective_scan.py`` says why
 ``d_inner`` is the minor axis).
 
-``build_hybrid_ssm_decoder`` returns what ``build_latent_moe_decoder``
-returns, with the same feeds, so ``serving.decoder_session
-.DecoderOnlySession`` dispatches either:
+The programs' frame (buckets, budget, rungs, feeds, sampler, fetches) is
+``models/decoder_programs.py``'s. Here a prefill's scan walks each
+prompt's REAL tokens and the state installed for its slot is the one after
+its last real token: the bucket's padding is nothing to it. A reused slot's
+rows are overwritten whole, so it starts from its own prefill and never
+from its predecessor. In a step the state arrays and the pools are donated
+and updated in place; a slot that is not live keeps its state rows as they
+are (finite: zero or a finished stream's).
 
-* ``init`` zeroes the state.
-* ``prefill[T]``: ``prompts_per_dispatch(T)`` prompts a dispatch, one a
-  bucket row. The scan walks each prompt's REAL tokens and the state
-  installed for its slot is the one after its last real token: the
-  bucket's padding is nothing to it. A reused slot's rows are overwritten
-  whole, so it starts from its own prefill and never from its
-  predecessor.
-* ``step``: one token for every slot, ``tokens_per_dispatch`` a dispatch
-  by ``Executor.run_multi_step``; the state arrays and the pools are the
-  program's own persistable variables, donated and updated in place. A
-  slot that is not live keeps its state rows as they are (finite: zero or
-  a finished stream's), writes its K/V row to the trash page and attends
-  nothing.
-
-Parameters are declared by name (``parameter_shapes``) and loaded
-(``load_parameters``); nothing is initialised twice. The matrices are
-stored input-major (``[in, out]``), ``A_log`` and the convolution's
-weight with ``d_inner`` minor (``[d_state, d_inner]``, ``[d_conv,
-d_inner]``): a checkpoint's loader transposes once.
+The matrices are stored input-major (``[in, out]``), ``A_log`` and the
+convolution's weight with ``d_inner`` minor (``[d_state, d_inner]``,
+``[d_conv, d_inner]``): a checkpoint's loader transposes once.
 """
 
 import collections
+import functools
 
 import numpy as np
 
 import paddle_tpu as fluid
 from paddle_tpu.core.types import np_dtype
-from paddle_tpu.kernels.paged_attention import pages_for
+from paddle_tpu.models import decoder_programs
 
 __all__ = ["hybrid_dims", "layer_kinds", "parameter_shapes",
            "random_parameters", "load_parameters",
@@ -175,80 +165,39 @@ def random_parameters(desc, seed=0, dtype="float32"):
     return out
 
 
-def load_parameters(scope, named, desc=None, dtype=None):
-    """Put a checkpoint's arrays into ``scope`` under the programs' names.
-    With ``desc`` every parameter must be there with its shape."""
-    from paddle_tpu.models.latent_moe_decoder import load_named
-
-    load_named(scope, named, desc and parameter_shapes(desc,
-                                                       dtype or "bfloat16"))
+load_parameters = functools.partial(decoder_programs.load_parameters,
+                                    parameter_shapes)
 
 
-def build_hybrid_ssm_decoder(desc, num_slots, max_positions, page_size,
-                             prefill_buckets, num_pages=None,
-                             prefill_token_budget=2048, sampler=None,
-                             dtype="bfloat16", probe_rows=0,
-                             tokens_per_dispatch=1):
-    """Build the serving programs (module docstring). Returns what
-    ``models.latent_moe_decoder.build_latent_moe_decoder`` returns:
-    ``init``, ``prefill`` ({bucket: program}), ``step``, ``fetches`` and
-    ``geometry``, whose ``state`` declares the page pools and the
-    per-slot arrays by name.
-
-    ``tokens_per_dispatch`` is every builder's (the session passes it): no
-    pool of this model is sized by it."""
-    from paddle_tpu import unique_name
-    from paddle_tpu.models.transformer import _sampler_attrs
-
+def _family(desc, dtype, tokens_per_dispatch):
+    """This family's layers and state (``decoder_programs.DecoderFamily``)."""
     nn = fluid.layers
     d = hybrid_dims(desc)
     kinds = layer_kinds(desc)
-    S, ps = int(num_slots), int(page_size)
-    npp = pages_for(max_positions, ps)
-    P = int(num_pages) if num_pages else 1 + S * npp
-    samp = _sampler_attrs(sampler)
-    buckets = sorted(int(t) for t in prefill_buckets)
-    if any(t % ps for t in buckets):
-        raise ValueError("every prefill bucket (%s) must be a multiple of "
-                         "the page size %d: rows are written a page at a "
-                         "time" % (buckets, ps))
-    per_dispatch = {t: max(1, int(prefill_token_budget) // t)
-                    for t in buckets}
-    shapes = parameter_shapes(desc, dtype)
     row = d["Hkv"] * d["dh"]
     di, n, k1 = d["di"], d["n"], d["kw"] - 1
-    page_pools, slot_arrays = collections.OrderedDict(), \
-        collections.OrderedDict()
-    for i, kind in enumerate(kinds):
-        if kind == ATTENTION:
-            for part in "kv":
-                page_pools["hsd_%s_%d" % (part, i)] = {
-                    "shape": (P, ps, row), "dtype": dtype}
-        else:
-            slot_arrays["hsd_ssm_%d" % i] = {
-                "shape": (S, n, di), "dtype": "float32", "slot_axis": 0}
-            slot_arrays["hsd_win_%d" % i] = {
-                "shape": (k1, S, di), "dtype": dtype, "slot_axis": 1}
 
-    def declare(blk, name, shape, dt):
-        return blk.create_var(name=name, shape=list(shape), dtype=dt,
-                              persistable=True)
+    def state(S, P, ps, npp):
+        page_pools, slot_arrays = collections.OrderedDict(), \
+            collections.OrderedDict()
+        for i, kind in enumerate(kinds):
+            if kind == ATTENTION:
+                for part in "kv":
+                    page_pools["hsd_%s_%d" % (part, i)] = {
+                        "shape": (P, ps, row), "dtype": dtype}
+            else:
+                slot_arrays["hsd_ssm_%d" % i] = {
+                    "shape": (S, n, di), "dtype": "float32", "slot_axis": 0}
+                slot_arrays["hsd_win_%d" % i] = {
+                    "shape": (k1, S, di), "dtype": dtype, "slot_axis": 1}
+        return {"page_pools": page_pools, "slot_arrays": slot_arrays}
 
-    def state(blk):
-        arrays = {name: declare(blk, name, spec["shape"], spec["dtype"])
-                  for name, spec in list(page_pools.items())
-                  + list(slot_arrays.items())}
-        return (arrays, declare(blk, "hsd_tok", (S, 1), "int64"),
-                declare(blk, "hsd_pos", (S, 1), "int64"))
-
-    def blocks(blk, x, attend, mix):
+    def blocks(f, x, attend, mix):
         """The L blocks and the final norm on token rows ``x`` [N, D];
         ``attend(i, q, k, v)`` writes an attention layer's rows and
         attends, ``mix(i, x, z, w)`` runs a state-space layer's
         convolution and recurrence (``w(part)``: its parameters)."""
-        def w(name):
-            return declare(blk, name, *shapes[name])
-
+        w = f.w
         for i, kind in enumerate(kinds):
             p = "hsd_%d_" % i
             nx = nn.rms_norm(x, w(p + "in_norm"), d["eps"])
@@ -265,7 +214,7 @@ def build_hybrid_ssm_decoder(desc, num_slots, max_positions, page_size,
             nx = nn.rms_norm(x, w(p + "ff_norm"), d["eps"])
             x = nn.elementwise_add(x, nn.gated_ffn(
                 nx, w(p + "ffn_gate"), w(p + "ffn_up"), w(p + "ffn_down")))
-        return nn.rms_norm(x, w("hsd_final_norm"), d["eps"])
+        return nn.rms_norm(x, w("hsd_final_norm"), d["eps"]), []
 
     def selective(xc, w):
         """Delta, B and C of the convolved rows ``xc``."""
@@ -274,143 +223,55 @@ def build_hybrid_ssm_decoder(desc, num_slots, max_positions, page_size,
             u, w("dt_norm"), w("b_norm"), w("c_norm"), w("dt_proj"),
             w("dt_bias"), dt_rank=d["r"], d_state=n, epsilon=d["eps"])
 
-    def logits_of(blk, rows, name, count):
-        out = nn.tied_vocab_projection(
-            rows, declare(blk, "hsd_embed", *shapes["hsd_embed"]))
-        return nn.assign(
-            nn.reshape(out, shape=[count, 1, d["V"]]),
-            output=blk.create_var(name=name, dtype="float32"))
+    def prefill(f, x):
+        def attend(i, q, k, v):
+            nn.latent_row_prefill(f.state["hsd_k_%d" % i], k,
+                                  f.page_rows, f.lens)
+            nn.latent_row_prefill(f.state["hsd_v_%d" % i], v,
+                                  f.page_rows, f.lens)
+            return nn.gqa_prefill_attention(
+                q, k, v, prompts=f.rows, heads=d["H"], kv_heads=d["Hkv"])
 
-    with unique_name.guard({}):
-        init = fluid.Program()
-        with fluid.program_guard(init, fluid.Program()):
-            arrays, tok, pos = state(init.global_block())
-            for name, var in arrays.items():
-                spec = page_pools.get(name) or slot_arrays[name]
-                nn.assign(nn.fill_constant(list(spec["shape"]),
-                                           spec["dtype"], 0.0), output=var)
-            nn.assign(nn.fill_constant([S, 1], "int64", 0), output=tok)
-            nn.assign(nn.fill_constant([S, 1], "int64", 0), output=pos)
+        def mix(i, xs, z, w):
+            xc, window = nn.ssm_causal_conv(
+                xs, w("conv_w"), w("conv_b"), f.lens)
+            delta, b, c = selective(xc, w)
+            y, last = nn.ssm_prefill_scan(
+                xc, delta, b, c, w("a_log"), w("d_skip"), z, f.lens)
+            nn.slot_state_write(f.state["hsd_ssm_%d" % i], f.slot_idx,
+                                last, axis=0)
+            nn.slot_state_write(f.state["hsd_win_%d" % i], f.slot_idx,
+                                window, axis=1)
+            return y
 
-        prefill = {}
-        for T in buckets:
-            B = per_dispatch[T]
-            prog = prefill[T] = fluid.Program()
-            with unique_name.guard({}), \
-                    fluid.program_guard(prog, fluid.Program()):
-                blk = prog.global_block()
-                arrays, tok, pos = state(blk)
+        return blocks(f, x, attend, mix)
 
-                def feed(name, shape):
-                    return nn.data(name, shape=shape, dtype="int64",
-                                   append_batch_size=False)
+    def step(f, x):
+        def attend(i, q, k, v):
+            k_pool, v_pool = f.state["hsd_k_%d" % i], \
+                f.state["hsd_v_%d" % i]
+            nn.latent_row_write(k_pool, k, f.table, f.pos)
+            nn.latent_row_write(v_pool, v, f.table, f.pos)
+            return nn.gqa_paged_attention(q, k_pool, v_pool, f.table,
+                                          f.lengths, heads=d["H"])
 
-                ids = feed("prompt_ids", [B * T])
-                lens = feed("prompt_len", [B])
-                slot_idx = feed("slot_idx", [B])
-                page_rows = feed("page_rows", [B, npp])
-                last_idx = feed("last_idx", [B])
-                x = nn.embedding_rows(
-                    declare(blk, "hsd_embed", *shapes["hsd_embed"]), ids)
+        def mix(i, xs, z, w):
+            xc = nn.ssm_conv_step(f.state["hsd_win_%d" % i], xs,
+                                  w("conv_w"), w("conv_b"), f.live)
+            delta, b, c = selective(xc, w)
+            return nn.ssm_state_update(
+                f.state["hsd_ssm_%d" % i], xc, delta, b, c, w("a_log"),
+                w("d_skip"), z, f.live)
 
-                def attend(i, q, k, v, B=B, arrays=arrays,
-                           page_rows=page_rows, lens=lens):
-                    nn.latent_row_prefill(arrays["hsd_k_%d" % i], k,
-                                          page_rows, lens)
-                    nn.latent_row_prefill(arrays["hsd_v_%d" % i], v,
-                                          page_rows, lens)
-                    return nn.gqa_prefill_attention(
-                        q, k, v, prompts=B, heads=d["H"],
-                        kv_heads=d["Hkv"])
+        return blocks(f, x, attend, mix)
 
-                def mix(i, xs, z, w, arrays=arrays, lens=lens,
-                        slot_idx=slot_idx):
-                    xc, window = nn.ssm_causal_conv(
-                        xs, w("conv_w"), w("conv_b"), lens)
-                    delta, b, c = selective(xc, w)
-                    y, last = nn.ssm_prefill_scan(
-                        xc, delta, b, c, w("a_log"), w("d_skip"), z, lens)
-                    nn.slot_state_write(arrays["hsd_ssm_%d" % i], slot_idx,
-                                        last, axis=0)
-                    nn.slot_state_write(arrays["hsd_win_%d" % i], slot_idx,
-                                        window, axis=1)
-                    return y
+    return decoder_programs.DecoderFamily(
+        "hsd", parameter_shapes(desc, dtype), d["V"], state, prefill,
+        step, geometry={"row_width": row, "layer_kinds": kinds},
+        # tied embeddings; no expert layer asks which rows are real
+        head=lambda f, rows: nn.tied_vocab_projection(
+            rows, f.w("hsd_embed")), mask=False)
 
-                x = blocks(blk, x, attend, mix)
-                logits = logits_of(blk, nn.gather(x, last_idx),
-                                   "hsd_first_logits", B)
-                lens2 = nn.reshape(lens, shape=[B, 1])
-                first, _p, _d = nn.slot_decode_sample(
-                    logits, lens2, eos_id=0,
-                    max_length=int(max_positions) + 2, **samp)
-                nn.assign(first, output=blk.create_var(
-                    name="hsd_first_tok", dtype="int64"))
-                nn.slot_rows_write(tok, slot_idx, first)
-                nn.slot_rows_write(pos, slot_idx, lens2)
 
-        step = fluid.Program()
-        with unique_name.guard({}), \
-                fluid.program_guard(step, fluid.Program()):
-            blk = step.global_block()
-            arrays, tok, pos = state(blk)
-            table = nn.data("page_table", shape=[S, npp], dtype="int64",
-                            append_batch_size=False)
-            live = nn.data("live", shape=[S, 1], dtype="int64",
-                           append_batch_size=False)
-            # resident rows AFTER this step's write; 0 for an empty slot
-            lengths = nn.elementwise_mul(
-                nn.increment(pos, value=1, in_place=False), live)
-            done = nn.elementwise_sub(
-                nn.fill_constant([S, 1], "int64", 1), live)
-            x = nn.embedding_rows(
-                declare(blk, "hsd_embed", *shapes["hsd_embed"]), tok)
-
-            def attend(i, q, k, v):
-                k_pool, v_pool = arrays["hsd_k_%d" % i], \
-                    arrays["hsd_v_%d" % i]
-                nn.latent_row_write(k_pool, k, table, pos)
-                nn.latent_row_write(v_pool, v, table, pos)
-                return nn.gqa_paged_attention(q, k_pool, v_pool, table,
-                                              lengths, heads=d["H"])
-
-            def mix(i, xs, z, w):
-                xc = nn.ssm_conv_step(arrays["hsd_win_%d" % i], xs,
-                                      w("conv_w"), w("conv_b"), live)
-                delta, b, c = selective(xc, w)
-                return nn.ssm_state_update(
-                    arrays["hsd_ssm_%d" % i], xc, delta, b, c, w("a_log"),
-                    w("d_skip"), z, live)
-
-            x = blocks(blk, x, attend, mix)
-            logits = logits_of(blk, x, "hsd_logits", S)
-            if probe_rows:
-                probe = nn.data("probe_slots", shape=[int(probe_rows)],
-                                dtype="int64", append_batch_size=False)
-                nn.assign(
-                    nn.gather(nn.reshape(logits, shape=[S, d["V"]]), probe),
-                    output=blk.create_var(name="hsd_probe_logits",
-                                          dtype="float32"))
-            tok_new, pos_new, _done = nn.slot_decode_sample(
-                logits, pos, done=done, eos_id=0,
-                max_length=int(max_positions) + 2, **samp)
-            nn.assign(tok_new, output=blk.create_var(
-                name="hsd_step_tok", dtype="int64"))
-            nn.assign(tok_new, output=tok)
-            nn.assign(pos_new, output=pos)
-
-    return {
-        "init": init, "prefill": prefill, "step": step,
-        "fetches": {
-            "token": "hsd_step_tok", "first_token": "hsd_first_tok",
-            "logits": "hsd_logits", "first_logits": "hsd_first_logits",
-            "probe_logits": "hsd_probe_logits" if probe_rows else None,
-            "expert_tokens": None},
-        "geometry": {
-            "num_slots": S, "page_size": ps, "pages_per_slot": npp,
-            "num_pages": P, "row_width": row, "buckets": buckets,
-            "prompts_per_dispatch": per_dispatch,
-            "prefill_token_budget": int(prefill_token_budget),
-            "layer_kinds": kinds, "dtype": dtype,
-            "state": {"page_pools": page_pools,
-                      "slot_arrays": slot_arrays}},
-    }
+build_hybrid_ssm_decoder = functools.partial(
+    decoder_programs.build_decoder_programs, _family)

@@ -30,44 +30,21 @@ top-k, the expert op computes the held experts' part of the sum and leaves
 the rest out. ``vocab_size`` may be a slice of the published vocabulary:
 embedding, head and sampling are over what it says.
 
-``build_latent_moe_decoder`` returns the programs a
-``serving.decoder_session.DecoderOnlySession`` dispatches:
-
-* ``init``: the zeroed row pools ``lmd_pool_<i>`` ``[pages, page_size,
-  pool_width(row)]`` (the row rounded up to whole lanes, 576 -> 640: the
-  lanes past the row stay zero) and the per-slot loop state
-  ``lmd_tok``/``lmd_pos`` ``[S, 1]``.
-* ``prefill[T]``, one per length bucket ``T`` (a multiple of the page
-  size): ``prompts_per_dispatch(T)`` prompts a dispatch, under the
-  builder's token budget; with ``prefill_rungs`` also one program a RUNG
-  of prompt rows under it (1, 2, 4, ...: ``prefill_rungs[T][rows]``), so
-  that a dispatch of few prompts walks their rows and not the budget's.
-  Feeds ``prompt_ids [B*T]``, ``prompt_len [B]``,
-  ``slot_idx [B]`` (``num_slots`` for a row of padding: nothing is
-  written for it), ``page_rows [B, pages_per_slot]``, ``last_idx [B]``
-  (the flat index of each prompt's last token). Writes the latent rows of
-  every layer, samples each prompt's first token from its last position's
-  logits and installs ``lmd_tok``/``lmd_pos`` for its slot.
-* ``step``: one decode token for every slot; ``Executor.run_multi_step``
-  runs ``tokens_per_dispatch`` of them a dispatch. Feeds ``page_table
-  [S, pages_per_slot]`` and ``live [S, 1]`` from the host's mirror (a
-  slot that is not live has length 0, writes to the trash page and is
-  neither routed nor counted), so a cancel or a page grown costs no
-  dispatch of its own.
-
-The parameters are declared by name (``parameter_shapes``) and come from a
-checkpoint: ``load_parameters`` puts them into the scope; there is no
-startup initialiser (a 4 G-parameter model is never made twice).
+State: a pool of latent rows a layer, ``lmd_pool_<i>`` ``[pages,
+page_size, pool_width(row)]`` (the row rounded up to whole lanes, 576 ->
+640: the lanes past the row stay zero). The programs' frame (buckets,
+budget, rungs, feeds, sampler, fetches) is ``models/decoder_programs.py``'s.
 """
 
 import collections
+import functools
 
 import numpy as np
 
 import paddle_tpu as fluid
 from paddle_tpu.core.types import np_dtype
 from paddle_tpu.kernels.latent_attention import pool_width
-from paddle_tpu.kernels.paged_attention import pages_for
+from paddle_tpu.models import decoder_programs
 
 __all__ = ["decoder_dims", "parameter_shapes", "random_parameters",
            "load_parameters", "build_latent_moe_decoder"]
@@ -233,103 +210,41 @@ def random_parameters(desc, seed=0, dtype="float32"):
     return out
 
 
-def load_named(scope, named, shapes=None):
-    """Put ``named`` ({name: array}) into ``scope``; with ``shapes``
-    ({name: (shape, dtype)}) every parameter must be there with its
-    shape. Any decoder builder's ``load_parameters``."""
-    for name, (shape, _dt) in (shapes or {}).items():
-        if name not in named:
-            raise KeyError("the checkpoint has no parameter %r" % name)
-        if tuple(named[name].shape) != shape:
-            raise ValueError("%s: the model needs %s, the checkpoint has %s"
-                             % (name, shape, tuple(named[name].shape)))
-    for name, value in named.items():
-        scope.var(name).set(value)
+load_parameters = functools.partial(decoder_programs.load_parameters,
+                                    parameter_shapes)
 
 
-def load_parameters(scope, named, desc=None, dtype=None):
-    """Put a checkpoint's arrays into ``scope`` under the programs' names.
-    With ``desc`` every parameter must be there with its shape."""
-    load_named(scope, named, desc and parameter_shapes(desc,
-                                                       dtype or "bfloat16"))
-
-
-def build_latent_moe_decoder(desc, num_slots, max_positions, page_size,
-                             prefill_buckets, num_pages=None,
-                             prefill_token_budget=2048, sampler=None,
-                             dtype="bfloat16", probe_rows=0,
-                             tokens_per_dispatch=1, prefill_rungs=False):
-    """Build the serving programs (module docstring). Returns a dict:
-    ``init``, ``prefill`` ({bucket: program}), ``prefill_rungs`` ({bucket:
-    {prompt rows: program}}: the same programs, and with ``prefill_rungs``
-    one for every power of two of rows under a bucket's most), ``step``,
-    ``fetches`` (the names to fetch: ``token``, ``first_token``,
-    ``expert_tokens`` and, for checks, ``logits``, ``first_logits``,
-    ``chosen``, ``first_chosen``) and ``geometry`` (slots, pages, buckets
-    and prompts a dispatch).
-
-    ``probe_rows`` > 0 gives the step program one more feed,
-    ``probe_slots [probe_rows]``, and the fetch ``probe_logits``
-    ``[probe_rows, vocab]``: the logits of those slots alone, so that
-    whoever compares a served stream's logits with a reference can fetch
-    them from the SAME executable it serves with, every dispatch. It is
-    not free: the gather makes the step write all slots' logits out
-    (158 MB a token step at the published widths) where the program
-    without it fuses the sampler's argmax into the head's product.
-
-    ``tokens_per_dispatch`` is every builder's (the session passes it): no
-    pool of this model is sized by it."""
-    from paddle_tpu import unique_name
-    from paddle_tpu.models.transformer import _sampler_attrs
-
+def _family(desc, dtype, tokens_per_dispatch):
+    """This family's layers and state (``decoder_programs.DecoderFamily``).
+    Beside the frame's fetches: ``chosen`` / ``first_chosen`` (a token's
+    experts a layer), ``expert_tokens`` and, under ``index_topk``, the
+    positions each ``full`` layer chose: ``selected`` ``[layers, S, topk]``
+    int32 of a step; of a prefill whose bucket passes ``index_topk``
+    ``first_selected``, each prompt's last row as a mask ``[layers, B, T]``
+    int8."""
     nn = fluid.layers
     d = decoder_dims(desc)
-    S, ps = int(num_slots), int(page_size)
-    npp = pages_for(max_positions, ps)
-    P = int(num_pages) if num_pages else 1 + S * npp
-    samp = _sampler_attrs(sampler)
-    buckets = sorted(int(t) for t in prefill_buckets)
-    if any(t % ps for t in buckets):
-        raise ValueError("every prefill bucket (%s) must be a multiple of "
-                         "the page size %d: rows are written a page at a "
-                         "time" % (buckets, ps))
-    per_dispatch = {t: max(1, int(prefill_token_budget) // t)
-                    for t in buckets}
-    # the rows a bucket's programs are built for: the most a dispatch
-    # takes and, with prefill_rungs, every power of two under it
-    rungs = {t: [2 ** j for j in range((most - 1).bit_length())
-                 if prefill_rungs] + [most]
-             for t, most in per_dispatch.items()}
-    shapes = parameter_shapes(desc, dtype)
-    moe_layers = list(range(d["dense"], d["L"]))
     topk = d["topk"]
     full_layers = [i for i, kind in enumerate(d["indexer"])
                    if kind == "full"]
 
-    def declare(blk, name, shape, dt):
-        return blk.create_var(name=name, shape=list(shape), dtype=dt,
-                              persistable=True)
+    def state(S, P, ps, npp):
+        # what a slot owns: pages of latent rows and, under the same table,
+        # of the indexers' narrower keys (a ``full`` layer's); nothing of
+        # fixed size
+        return {"page_pools": collections.OrderedDict(
+            [("lmd_pool_%d" % i, {"shape": (P, ps, d["Wp"]), "dtype": dtype})
+             for i in range(d["L"])]
+            + [("lmd_ipool_%d" % i, {"shape": (P, ps, d["dI"]),
+                                     "dtype": dtype})
+               for i in full_layers]), "slot_arrays": {}}
 
-    ipools = {}    # layer -> its narrow pool in the program being built
-
-    def state(blk):
-        pools = [declare(blk, "lmd_pool_%d" % i, (P, ps, d["Wp"]), dtype)
-                 for i in range(d["L"])]
-        # the indexers' keys: a narrow pool a ``full`` layer, same pages
-        for i in full_layers:
-            ipools[i] = declare(blk, "lmd_ipool_%d" % i, (P, ps, d["dI"]),
-                                dtype)
-        return (pools, declare(blk, "lmd_tok", (S, 1), "int64"),
-                declare(blk, "lmd_pos", (S, 1), "int64"))
-
-    def blocks(blk, x, attend, valid):
+    def blocks(f, x, attend):
         """The L blocks and the final norm on token rows ``x`` [N, D];
         ``attend(i, q, kva, w, cq, nx)`` writes layer ``i``'s rows and
         attends (``w(part)``: the layer's parameter). Returns (x, chosen
         per expert layer, tokens per expert per expert layer)."""
-        def w(name):
-            return declare(blk, name, *shapes[name])
-
+        w = f.w
         chosen, counts = [], []
         for i in range(d["L"]):
             p = "lmd_%d_" % i
@@ -351,20 +266,13 @@ def build_latent_moe_decoder(desc, num_slots, max_positions, page_size,
                 ff, ch, cnt = nn.dropless_moe_ffn(
                     nx, w(p + "router"), w(p + "router_bias"),
                     w(p + "experts_gate"), w(p + "experts_up"),
-                    w(p + "experts_down"), shared=shared, valid=valid,
+                    w(p + "experts_down"), shared=shared, valid=f.valid,
                     top_k=d["k"], norm_topk=d["norm_topk"],
                     scale=d["scale"], held_first=d["first"])
                 chosen.append(ch)
                 counts.append(cnt)
             x = nn.elementwise_add(x, ff)
         return nn.rms_norm(x, w("lmd_final_norm"), d["eps"]), chosen, counts
-
-    def stacked(name, parts, shape, dtype="int32"):
-        """The per-layer parts as ONE fetchable variable."""
-        out = nn.concat([nn.reshape(c, shape=[1] + shape) for c in parts],
-                        axis=0)
-        return nn.assign(out, output=fluid.default_main_program()
-                         .global_block().create_var(name=name, dtype=dtype))
 
     rope_attrs = dict(heads=d["H"], nope_dim=d["dn"], rope_dim=d["dr"],
                       theta=d["theta"], epsilon=d["eps"],
@@ -377,190 +285,75 @@ def build_latent_moe_decoder(desc, num_slots, max_positions, page_size,
             w("idx_k_shift"), w("idx_w"), heads=d["J"], rope_dim=d["dr"],
             theta=d["theta"], interleave=d["idx_interleave"], **where)
 
-    with unique_name.guard({}):
-        init = fluid.Program()
-        with fluid.program_guard(init, fluid.Program()):
-            blk = init.global_block()
-            pools, tok, pos = state(blk)
-            for pool in pools:
-                nn.assign(nn.fill_constant([P, ps, d["Wp"]], dtype, 0.0),
-                          output=pool)
-            for pool in ipools.values():
-                nn.assign(nn.fill_constant([P, ps, d["dI"]], dtype, 0.0),
-                          output=pool)
-            nn.assign(nn.fill_constant([S, 1], "int64", 0), output=tok)
-            nn.assign(nn.fill_constant([S, 1], "int64", 0), output=pos)
+    def prefill(f, x):
+        B, T = f.rows, f.bucket
+        masks = []     # the choice of each ``full`` layer so far
 
-        by_rows = {T: {} for T in buckets}
-        for T, B in [(T, B) for T in buckets for B in rungs[T]]:
-            prog = by_rows[T][B] = fluid.Program()
-            with unique_name.guard({}), \
-                    fluid.program_guard(prog, fluid.Program()):
-                blk = prog.global_block()
-                pools, tok, pos = state(blk)
+        def attend(i, q, kva, w, cq, nx):
+            q, row = nn.latent_rope_rows(
+                q, kva, w("kv_norm"), period=T, **rope_attrs)
+            nn.latent_row_prefill(f.state["lmd_pool_%d" % i], row,
+                                  f.page_rows, f.lens)
+            if i in full_layers:
+                qi, ki, wi = indexer(i, w, cq, nx, period=T)
+                nn.latent_row_prefill(f.state["lmd_ipool_%d" % i], ki,
+                                      f.page_rows, f.lens)
+                if T > topk:
+                    masks.append(nn.index_select_prefill(
+                        qi, ki, wi, f.lens, prompts=B, top_k=topk))
+            if not topk:
+                return nn.latent_prefill_attention(
+                    q, row, w("kv_b"), prompts=B, nope_dim=d["dn"])
+            # no mask in a bucket of at most index_topk rows: every
+            # earlier position is chosen
+            return nn.sparse_latent_prefill_attention(
+                q, row, w("kv_b"), masks[-1] if masks else None, f.lens,
+                prompts=B, nope_dim=d["dn"])
 
-                def feed(name, shape):
-                    return nn.data(name, shape=shape, dtype="int64",
-                                   append_batch_size=False)
+        x, chosen, _counts = blocks(f, x, attend)
+        # each prompt's LAST row of every choice, for checks
+        return x, [("first_chosen", chosen), ("first_selected", lambda: [
+            nn.gather(nn.reshape(m, shape=[B * T, T]), f.last_idx)
+            for m in masks])]
 
-                ids = feed("prompt_ids", [B * T])
-                lens = feed("prompt_len", [B])
-                slot_idx = feed("slot_idx", [B])
-                page_rows = feed("page_rows", [B, npp])
-                last_idx = feed("last_idx", [B])
-                valid = nn.reshape(
-                    nn.sequence_mask(lens, maxlen=T, dtype="int64"),
-                    shape=[B * T])
-                x = nn.embedding_rows(
-                    declare(blk, "lmd_embed", *shapes["lmd_embed"]), ids)
+    def step(f, x):
+        selected = []  # the choice of each ``full`` layer so far
 
-                masks = []     # the choice of each ``full`` layer so far
-
-                def attend(i, q, kva, w, cq, nx, B=B, T=T, pools=pools,
-                           page_rows=page_rows, lens=lens, masks=masks):
-                    q, row = nn.latent_rope_rows(
-                        q, kva, w("kv_norm"), period=T, **rope_attrs)
-                    nn.latent_row_prefill(pools[i], row, page_rows, lens)
-                    if i in ipools:
-                        qi, ki, wi = indexer(i, w, cq, nx, period=T)
-                        nn.latent_row_prefill(ipools[i], ki, page_rows, lens)
-                        if T > topk:
-                            masks.append(nn.index_select_prefill(
-                                qi, ki, wi, lens, prompts=B, top_k=topk))
-                    if not topk:
-                        return nn.latent_prefill_attention(
-                            q, row, w("kv_b"), prompts=B, nope_dim=d["dn"])
-                    # no mask in a bucket of at most index_topk rows:
-                    # every earlier position is chosen
-                    return nn.sparse_latent_prefill_attention(
-                        q, row, w("kv_b"), masks[-1] if masks else None,
-                        lens, prompts=B, nope_dim=d["dn"])
-
-                x, chosen, counts = blocks(blk, x, attend, valid)
-                last = nn.gather(x, last_idx)                  # [B, D]
-                logits = nn.dense_projection(
-                    last, declare(blk, "lmd_head", *shapes["lmd_head"]),
-                    out_dtype="float32")
-                logits = nn.assign(
-                    nn.reshape(logits, shape=[B, 1, d["V"]]),
-                    output=blk.create_var(name="lmd_first_logits",
-                                          dtype="float32"))
-                lens2 = nn.reshape(lens, shape=[B, 1])
-                first, _p, _d = nn.slot_decode_sample(
-                    logits, lens2, eos_id=0,
-                    max_length=int(max_positions) + 2, **samp)
-                nn.assign(first, output=blk.create_var(
-                    name="lmd_first_tok", dtype="int64"))
-                nn.slot_rows_write(tok, slot_idx, first)
-                nn.slot_rows_write(pos, slot_idx, lens2)
-                if chosen:
-                    stacked("lmd_first_chosen", chosen, [B * T, d["k"]])
-                if masks:
-                    # each prompt's LAST row of every choice, for checks
-                    stacked("lmd_first_selected", [
-                        nn.gather(nn.reshape(m, shape=[B * T, T]), last_idx)
-                        for m in masks], [B, T], dtype="int8")
-
-        step = fluid.Program()
-        with unique_name.guard({}), \
-                fluid.program_guard(step, fluid.Program()):
-            blk = step.global_block()
-            pools, tok, pos = state(blk)
-            table = nn.data("page_table", shape=[S, npp], dtype="int64",
-                            append_batch_size=False)
-            live = nn.data("live", shape=[S, 1], dtype="int64",
-                           append_batch_size=False)
-            # resident rows AFTER this step's write; 0 for an empty slot
-            lengths = nn.elementwise_mul(
-                nn.increment(pos, value=1, in_place=False), live)
-            done = nn.elementwise_sub(
-                nn.fill_constant([S, 1], "int64", 1), live)
-            x = nn.embedding_rows(
-                declare(blk, "lmd_embed", *shapes["lmd_embed"]), tok)
-
-            selected = []  # the choice of each ``full`` layer so far
-
-            def attend(i, q, kva, w, cq, nx):
-                q, row = nn.latent_rope_rows(
-                    q, kva, w("kv_norm"), positions=pos, **rope_attrs)
-                nn.latent_row_write(pools[i], row, table, pos)
-                if not topk:
-                    return nn.latent_paged_attention(
-                        q, w("kv_b"), pools[i], table, lengths,
-                        nope_dim=d["dn"])
-                if i in ipools:
-                    qi, ki, wi = indexer(i, w, cq, nx, positions=pos)
-                    nn.latent_row_write(ipools[i], ki, table, pos)
-                    selected.append(nn.index_select_decode(
-                        qi, wi, ipools[i], table, lengths, top_k=topk))
-                return nn.sparse_latent_paged_attention(
-                    q, w("kv_b"), pools[i], table, selected[-1],
+        def attend(i, q, kva, w, cq, nx):
+            pool = f.state["lmd_pool_%d" % i]
+            q, row = nn.latent_rope_rows(
+                q, kva, w("kv_norm"), positions=f.pos, **rope_attrs)
+            nn.latent_row_write(pool, row, f.table, f.pos)
+            if not topk:
+                return nn.latent_paged_attention(
+                    q, w("kv_b"), pool, f.table, f.lengths,
                     nope_dim=d["dn"])
+            if i in full_layers:
+                ipool = f.state["lmd_ipool_%d" % i]
+                qi, ki, wi = indexer(i, w, cq, nx, positions=f.pos)
+                nn.latent_row_write(ipool, ki, f.table, f.pos)
+                selected.append(nn.index_select_decode(
+                    qi, wi, ipool, f.table, f.lengths, top_k=topk))
+            return nn.sparse_latent_paged_attention(
+                q, w("kv_b"), pool, f.table, selected[-1],
+                nope_dim=d["dn"])
 
-            x, chosen, counts = blocks(blk, x, attend, live)
-            logits = nn.dense_projection(
-                x, declare(blk, "lmd_head", *shapes["lmd_head"]),
-                out_dtype="float32")
-            logits = nn.assign(
-                nn.reshape(logits, shape=[S, 1, d["V"]]),
-                output=blk.create_var(name="lmd_logits", dtype="float32"))
-            if probe_rows:
-                probe = nn.data("probe_slots", shape=[int(probe_rows)],
-                                dtype="int64", append_batch_size=False)
-                nn.assign(
-                    nn.gather(nn.reshape(logits, shape=[S, d["V"]]), probe),
-                    output=blk.create_var(name="lmd_probe_logits",
-                                          dtype="float32"))
-            tok_new, pos_new, _done = nn.slot_decode_sample(
-                logits, pos, done=done, eos_id=0,
-                max_length=int(max_positions) + 2, **samp)
-            nn.assign(tok_new, output=blk.create_var(
-                name="lmd_step_tok", dtype="int64"))
-            if chosen:
-                stacked("lmd_chosen", chosen, [S, d["k"]])
-                stacked("lmd_expert_tokens", counts, [d["E"]])
-            if selected:
-                stacked("lmd_selected", selected, [S, topk])
-            nn.assign(tok_new, output=tok)
-            nn.assign(pos_new, output=pos)
+        x, chosen, counts = blocks(f, x, attend)
+        return x, [("chosen", chosen), ("expert_tokens", counts),
+                   ("selected", selected)]
 
-    has_moe = bool(moe_layers)
-    return {
-        "init": init, "step": step, "prefill_rungs": by_rows,
-        "prefill": {T: by_rows[T][per_dispatch[T]] for T in buckets},
-        "fetches": {
-            "token": "lmd_step_tok", "first_token": "lmd_first_tok",
-            "logits": "lmd_logits", "first_logits": "lmd_first_logits",
-            "probe_logits": "lmd_probe_logits" if probe_rows else None,
-            "expert_tokens": "lmd_expert_tokens" if has_moe else None,
-            "chosen": "lmd_chosen" if has_moe else None,
-            "first_chosen": "lmd_first_chosen" if has_moe else None,
-            # the positions each ``full`` layer chose: [layers, S, topk]
-            # int32 of a step; of a prefill whose bucket passes index_topk
-            # each prompt's last row as a mask [layers, B, T] int8
-            "selected": "lmd_selected" if topk else None,
-            "first_selected": "lmd_first_selected" if topk else None},
-        "geometry": {
-            "num_slots": S, "page_size": ps, "pages_per_slot": npp,
-            "num_pages": P, "row_width": d["W"], "pool_width": d["Wp"],
-            "buckets": buckets,
-            "prompts_per_dispatch": per_dispatch,
-            "prefill_rungs": rungs,
-            "prefill_token_budget": int(prefill_token_budget),
-            "moe_layers": moe_layers, "dtype": dtype,
-            # learned sparse attention: what a query attends at most, and
-            # the layers that keep an indexer's narrow pool
+    return decoder_programs.DecoderFamily(
+        "lmd", parameter_shapes(desc, dtype), d["V"], state, prefill,
+        step, geometry={
+            "row_width": d["W"], "pool_width": d["Wp"],
+            "moe_layers": list(range(d["dense"], d["L"])),
+            # learned sparse attention: what a query attends at most,
+            # and the layers that keep an indexer's narrow pool
             "index_topk": topk, "index_layers": full_layers,
             "index_row_width": d["dI"] if topk else 0,
-            # the experts held of those routed among, and a token's choices
-            "experts": {"held": d["E"], "of": d["Er"], "top_k": d["k"]},
-            # what a slot owns: pages of latent rows (and, under the same
-            # table, of the indexers' narrower keys), nothing of fixed size
-            "state": {"page_pools": collections.OrderedDict(
-                [("lmd_pool_%d" % i, {"shape": (P, ps, d["Wp"]),
-                                      "dtype": dtype})
-                 for i in range(d["L"])]
-                + [("lmd_ipool_%d" % i, {"shape": (P, ps, d["dI"]),
-                                         "dtype": dtype})
-                   for i in full_layers]), "slot_arrays": {}}},
-    }
+            # the experts held of those routed among, a token's choices
+            "experts": {"held": d["E"], "of": d["Er"], "top_k": d["k"]}})
+
+
+build_latent_moe_decoder = functools.partial(
+    decoder_programs.build_decoder_programs, _family)

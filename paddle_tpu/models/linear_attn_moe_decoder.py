@@ -33,35 +33,27 @@ With ``expert_shard`` (``{"of": E_all, "first": f}``) ``n_routed_experts``
 counts the experts HELD here (``models/latent_moe_decoder.py`` has the
 rule); ``vocab_size`` may be a slice of the published vocabulary.
 
-``build_linear_attn_moe_decoder`` returns what ``build_latent_moe_decoder``
-returns, with the same feeds, so ``serving.decoder_session
-.DecoderOnlySession`` dispatches any of the four:
+The programs' frame (buckets, budget, rungs, feeds, sampler, fetches) is
+``models/decoder_programs.py``'s. Here a prefill's delta rule walks each
+prompt's REAL tokens in chunks and the state installed for its slot is the
+one after its last real token; a reused slot's rows are overwritten whole.
+In a step the state arrays and the pools are donated and updated in place;
+a slot that is not live keeps its state rows.
 
-* ``init`` zeroes the state.
-* ``prefill[T]`` (with ``prefill_rungs`` one program a rung of prompt
-  rows): the delta rule walks each prompt's REAL tokens in chunks and the
-  state installed for its slot is the one after its last real token; a
-  reused slot's rows are overwritten whole.
-* ``step``: one token for every slot, ``tokens_per_dispatch`` a dispatch;
-  the state arrays and the pools are donated and updated in place. A slot
-  that is not live keeps its state rows, writes its K/V row to the trash
-  page and is neither routed nor counted.
-
-Parameters are declared by name (``parameter_shapes``) and loaded
-(``load_parameters``). The matrices are stored input-major; the three
-projections and the three convolutions of a linear layer are stored as ONE
-``q | k | v`` matrix and one ``[taps, q | k | v]`` weight: a checkpoint's
-loader concatenates once.
+The matrices are stored input-major; the three projections and the three
+convolutions of a linear layer are stored as ONE ``q | k | v`` matrix and
+one ``[taps, q | k | v]`` weight: a checkpoint's loader concatenates once.
 """
 
 import collections
+import functools
 
 import numpy as np
 
 import paddle_tpu as fluid
 from paddle_tpu.core.types import np_dtype
 from paddle_tpu.kernels.delta_rule import CHUNK
-from paddle_tpu.kernels.paged_attention import pages_for
+from paddle_tpu.models import decoder_programs
 
 __all__ = ["linear_dims", "check_served", "layer_kinds", "parameter_shapes",
            "random_parameters", "load_parameters",
@@ -216,86 +208,43 @@ def random_parameters(desc, seed=0, dtype="float32"):
     return out
 
 
-def load_parameters(scope, named, desc=None, dtype=None):
-    """Put a checkpoint's arrays into ``scope`` under the programs' names.
-    With ``desc`` every parameter must be there with its shape."""
-    from paddle_tpu.models.latent_moe_decoder import load_named
-
-    load_named(scope, named, desc and parameter_shapes(desc,
-                                                       dtype or "bfloat16"))
+load_parameters = functools.partial(decoder_programs.load_parameters,
+                                    parameter_shapes)
 
 
-def build_linear_attn_moe_decoder(desc, num_slots, max_positions, page_size,
-                                  prefill_buckets, num_pages=None,
-                                  prefill_token_budget=2048, sampler=None,
-                                  dtype="bfloat16", probe_rows=0,
-                                  tokens_per_dispatch=1,
-                                  prefill_rungs=False):
-    """Build the serving programs (module docstring). Returns what
-    ``models.latent_moe_decoder.build_latent_moe_decoder`` returns:
-    ``init``, ``prefill`` ({bucket: program}), ``prefill_rungs``, ``step``,
-    ``fetches`` and ``geometry``, whose ``state`` declares the page pools
-    and the per-slot arrays by name and whose ``prefill_chunk`` is the
-    tokens a chunk of the delta rule's prefill walks.
-
-    ``tokens_per_dispatch`` is every builder's (the session passes it): no
-    pool of this model is sized by it."""
-    from paddle_tpu import unique_name
-    from paddle_tpu.models.transformer import _sampler_attrs
-
+def _family(desc, dtype, tokens_per_dispatch):
+    """This family's layers and state (``decoder_programs.DecoderFamily``);
+    ``geometry["prefill_chunk"]`` is the tokens a chunk of the delta
+    rule's prefill walks."""
     nn = fluid.layers
     d = linear_dims(desc)
     kinds = layer_kinds(desc)
-    S, ps = int(num_slots), int(page_size)
-    npp = pages_for(max_positions, ps)
-    P = int(num_pages) if num_pages else 1 + S * npp
-    samp = _sampler_attrs(sampler)
-    buckets = sorted(int(t) for t in prefill_buckets)
-    if any(t % ps for t in buckets):
-        raise ValueError("every prefill bucket (%s) must be a multiple of "
-                         "the page size %d: rows are written a page at a "
-                         "time" % (buckets, ps))
-    per_dispatch = {t: max(1, int(prefill_token_budget) // t)
-                    for t in buckets}
-    rungs = {t: [2 ** j for j in range((most - 1).bit_length())
-                 if prefill_rungs] + [most]
-             for t, most in per_dispatch.items()}
-    shapes = parameter_shapes(desc, dtype)
     row, lw, Hl, dl = d["Hkv"] * d["dh"], d["lw"], d["Hl"], d["dl"]
-    page_pools, slot_arrays = collections.OrderedDict(), \
-        collections.OrderedDict()
-    for i, kind in enumerate(kinds):
-        if kind == GQA:
-            for part in "kv":
-                page_pools["lad_%s_%d" % (part, i)] = {
-                    "shape": (P, ps, row), "dtype": dtype}
-        else:
-            slot_arrays["lad_s_%d" % i] = {
-                "shape": (S, Hl, dl, dl), "dtype": "float32", "slot_axis": 0}
-            slot_arrays["lad_win_%d" % i] = {
-                "shape": (d["kw"] - 1, S, 3 * lw), "dtype": dtype,
-                "slot_axis": 1}
 
-    def declare(blk, name, shape, dt):
-        return blk.create_var(name=name, shape=list(shape), dtype=dt,
-                              persistable=True)
+    def state(S, P, ps, npp):
+        page_pools, slot_arrays = collections.OrderedDict(), \
+            collections.OrderedDict()
+        for i, kind in enumerate(kinds):
+            if kind == GQA:
+                for part in "kv":
+                    page_pools["lad_%s_%d" % (part, i)] = {
+                        "shape": (P, ps, row), "dtype": dtype}
+            else:
+                slot_arrays["lad_s_%d" % i] = {
+                    "shape": (S, Hl, dl, dl), "dtype": "float32",
+                    "slot_axis": 0}
+                slot_arrays["lad_win_%d" % i] = {
+                    "shape": (d["kw"] - 1, S, 3 * lw), "dtype": dtype,
+                    "slot_axis": 1}
+        return {"page_pools": page_pools, "slot_arrays": slot_arrays}
 
-    def state(blk):
-        arrays = {name: declare(blk, name, spec["shape"], spec["dtype"])
-                  for name, spec in list(page_pools.items())
-                  + list(slot_arrays.items())}
-        return (arrays, declare(blk, "lad_tok", (S, 1), "int64"),
-                declare(blk, "lad_pos", (S, 1), "int64"))
-
-    def blocks(blk, x, attend, mix, valid):
+    def blocks(f, x, attend, mix):
         """The L blocks and the final norm on token rows ``x`` [N, D];
         ``attend(i, q, k, v)`` writes an attention layer's rows and
         attends, ``mix(i, qkv, g, beta, w)`` runs a linear layer's
         convolution and delta rule (``w(part)``: its parameters). Returns
         (x, chosen per layer, tokens per held expert per layer)."""
-        def w(name):
-            return declare(blk, name, *shapes[name])
-
+        w = f.w
         chosen, counts = [], []
         for i, kind in enumerate(kinds):
             p = "lad_%d_" % i
@@ -329,7 +278,7 @@ def build_linear_attn_moe_decoder(desc, num_slots, max_positions, page_size,
             ff, ch, cnt = nn.dropless_moe_ffn(
                 nx, w(p + "router"), w(p + "router_bias"),
                 w(p + "experts_gate"), w(p + "experts_up"),
-                w(p + "experts_down"), shared=shared, valid=valid,
+                w(p + "experts_down"), shared=shared, valid=f.valid,
                 top_k=d["k"], norm_topk=d["norm_topk"], scale=d["scale"],
                 held_first=d["first"])
             chosen.append(ch)
@@ -341,159 +290,60 @@ def build_linear_attn_moe_decoder(desc, num_slots, max_positions, page_size,
         # the convolution of ``ssm_ops`` takes a bias; this model has none
         return nn.fill_constant([3 * lw], dtype, 0.0)
 
-    def stacked(name, parts, shape):
-        """The per-layer parts as ONE fetchable variable."""
-        out = nn.concat([nn.reshape(c, shape=[1] + shape) for c in parts],
-                        axis=0)
-        return nn.assign(out, output=fluid.default_main_program()
-                         .global_block().create_var(name=name,
-                                                    dtype="int32"))
+    def prefill(f, x):
+        def attend(i, q, k, v):
+            nn.latent_row_prefill(f.state["lad_k_%d" % i], k,
+                                  f.page_rows, f.lens)
+            nn.latent_row_prefill(f.state["lad_v_%d" % i], v,
+                                  f.page_rows, f.lens)
+            # the flash kernel at the long buckets' tiles; no band
+            return nn.window_prefill_attention(
+                q, k, v, prompts=f.rows, heads=d["H"], kv_heads=d["Hkv"],
+                window=0)
 
-    def logits_of(blk, rows, name, count):
-        out = nn.dense_projection(
-            rows, declare(blk, "lad_head", *shapes["lad_head"]),
-            out_dtype="float32")
-        return nn.assign(nn.reshape(out, shape=[count, 1, d["V"]]),
-                         output=blk.create_var(name=name, dtype="float32"))
+        def mix(i, qkv, g, beta, w):
+            qkv, window = nn.ssm_causal_conv(
+                qkv, w("conv_w"), no_bias(), f.lens)
+            q, k, v = nn.split(qkv, 3, dim=-1)
+            o, last = nn.delta_rule_prefill(q, k, v, g, beta, f.lens)
+            nn.slot_state_write(f.state["lad_s_%d" % i], f.slot_idx,
+                                last, axis=0)
+            nn.slot_state_write(f.state["lad_win_%d" % i], f.slot_idx,
+                                window, axis=1)
+            return o
 
-    def feed(name, shape):
-        return nn.data(name, shape=shape, dtype="int64",
-                       append_batch_size=False)
+        x, chosen, _counts = blocks(f, x, attend, mix)
+        return x, [("first_chosen", chosen)]
 
-    with unique_name.guard({}):
-        init = fluid.Program()
-        with fluid.program_guard(init, fluid.Program()):
-            arrays, tok, pos = state(init.global_block())
-            for name, var in arrays.items():
-                spec = page_pools.get(name) or slot_arrays[name]
-                nn.assign(nn.fill_constant(list(spec["shape"]),
-                                           spec["dtype"], 0.0), output=var)
-            nn.assign(nn.fill_constant([S, 1], "int64", 0), output=tok)
-            nn.assign(nn.fill_constant([S, 1], "int64", 0), output=pos)
+    def step(f, x):
+        def attend(i, q, k, v):
+            k_pool, v_pool = f.state["lad_k_%d" % i], \
+                f.state["lad_v_%d" % i]
+            nn.latent_row_write(k_pool, k, f.table, f.pos)
+            nn.latent_row_write(v_pool, v, f.table, f.pos)
+            return nn.gqa_paged_attention(q, k_pool, v_pool, f.table,
+                                          f.lengths, heads=d["H"])
 
-        by_rows = {T: {} for T in buckets}
-        for T, B in [(T, B) for T in buckets for B in rungs[T]]:
-            prog = by_rows[T][B] = fluid.Program()
-            with unique_name.guard({}), \
-                    fluid.program_guard(prog, fluid.Program()):
-                blk = prog.global_block()
-                arrays, tok, pos = state(blk)
-                ids = feed("prompt_ids", [B * T])
-                lens = feed("prompt_len", [B])
-                slot_idx = feed("slot_idx", [B])
-                page_rows = feed("page_rows", [B, npp])
-                last_idx = feed("last_idx", [B])
-                valid = nn.reshape(
-                    nn.sequence_mask(lens, maxlen=T, dtype="int64"),
-                    shape=[B * T])
-                x = nn.embedding_rows(
-                    declare(blk, "lad_embed", *shapes["lad_embed"]), ids)
+        def mix(i, qkv, g, beta, w):
+            qkv = nn.ssm_conv_step(f.state["lad_win_%d" % i], qkv,
+                                   w("conv_w"), no_bias(), f.live)
+            q, k, v = nn.split(qkv, 3, dim=-1)
+            return nn.delta_rule_state_update(
+                f.state["lad_s_%d" % i], q, k, v, g, beta, f.live)
 
-                def attend(i, q, k, v, B=B, arrays=arrays,
-                           page_rows=page_rows, lens=lens):
-                    nn.latent_row_prefill(arrays["lad_k_%d" % i], k,
-                                          page_rows, lens)
-                    nn.latent_row_prefill(arrays["lad_v_%d" % i], v,
-                                          page_rows, lens)
-                    # the flash kernel at the long buckets' tiles; no band
-                    return nn.window_prefill_attention(
-                        q, k, v, prompts=B, heads=d["H"],
-                        kv_heads=d["Hkv"], window=0)
+        x, chosen, counts = blocks(f, x, attend, mix)
+        return x, [("chosen", chosen), ("expert_tokens", counts)]
 
-                def mix(i, qkv, g, beta, w, arrays=arrays, lens=lens,
-                        slot_idx=slot_idx):
-                    qkv, window = nn.ssm_causal_conv(
-                        qkv, w("conv_w"), no_bias(), lens)
-                    q, k, v = nn.split(qkv, 3, dim=-1)
-                    o, last = nn.delta_rule_prefill(q, k, v, g, beta, lens)
-                    nn.slot_state_write(arrays["lad_s_%d" % i], slot_idx,
-                                        last, axis=0)
-                    nn.slot_state_write(arrays["lad_win_%d" % i], slot_idx,
-                                        window, axis=1)
-                    return o
-
-                x, chosen, counts = blocks(blk, x, attend, mix, valid)
-                logits = logits_of(blk, nn.gather(x, last_idx),
-                                   "lad_first_logits", B)
-                lens2 = nn.reshape(lens, shape=[B, 1])
-                first, _p, _d = nn.slot_decode_sample(
-                    logits, lens2, eos_id=0,
-                    max_length=int(max_positions) + 2, **samp)
-                nn.assign(first, output=blk.create_var(
-                    name="lad_first_tok", dtype="int64"))
-                nn.slot_rows_write(tok, slot_idx, first)
-                nn.slot_rows_write(pos, slot_idx, lens2)
-                stacked("lad_first_chosen", chosen, [B * T, d["k"]])
-
-        step = fluid.Program()
-        with unique_name.guard({}), \
-                fluid.program_guard(step, fluid.Program()):
-            blk = step.global_block()
-            arrays, tok, pos = state(blk)
-            table = feed("page_table", [S, npp])
-            live = feed("live", [S, 1])
-            # resident rows AFTER this step's write; 0 for an empty slot
-            lengths = nn.elementwise_mul(
-                nn.increment(pos, value=1, in_place=False), live)
-            done = nn.elementwise_sub(
-                nn.fill_constant([S, 1], "int64", 1), live)
-            x = nn.embedding_rows(
-                declare(blk, "lad_embed", *shapes["lad_embed"]), tok)
-
-            def attend(i, q, k, v):
-                k_pool, v_pool = arrays["lad_k_%d" % i], \
-                    arrays["lad_v_%d" % i]
-                nn.latent_row_write(k_pool, k, table, pos)
-                nn.latent_row_write(v_pool, v, table, pos)
-                return nn.gqa_paged_attention(q, k_pool, v_pool, table,
-                                              lengths, heads=d["H"])
-
-            def mix(i, qkv, g, beta, w):
-                qkv = nn.ssm_conv_step(arrays["lad_win_%d" % i], qkv,
-                                       w("conv_w"), no_bias(), live)
-                q, k, v = nn.split(qkv, 3, dim=-1)
-                return nn.delta_rule_state_update(
-                    arrays["lad_s_%d" % i], q, k, v, g, beta, live)
-
-            x, chosen, counts = blocks(blk, x, attend, mix, live)
-            logits = logits_of(blk, x, "lad_logits", S)
-            if probe_rows:
-                probe = feed("probe_slots", [int(probe_rows)])
-                nn.assign(
-                    nn.gather(nn.reshape(logits, shape=[S, d["V"]]), probe),
-                    output=blk.create_var(name="lad_probe_logits",
-                                          dtype="float32"))
-            tok_new, pos_new, _done = nn.slot_decode_sample(
-                logits, pos, done=done, eos_id=0,
-                max_length=int(max_positions) + 2, **samp)
-            nn.assign(tok_new, output=blk.create_var(
-                name="lad_step_tok", dtype="int64"))
-            stacked("lad_chosen", chosen, [S, d["k"]])
-            stacked("lad_expert_tokens", counts, [d["E"]])
-            nn.assign(tok_new, output=tok)
-            nn.assign(pos_new, output=pos)
-
-    return {
-        "init": init, "step": step, "prefill_rungs": by_rows,
-        "prefill": {T: by_rows[T][per_dispatch[T]] for T in buckets},
-        "fetches": {
-            "token": "lad_step_tok", "first_token": "lad_first_tok",
-            "logits": "lad_logits", "first_logits": "lad_first_logits",
-            "probe_logits": "lad_probe_logits" if probe_rows else None,
-            "expert_tokens": "lad_expert_tokens",
-            "chosen": "lad_chosen", "first_chosen": "lad_first_chosen"},
-        "geometry": {
-            "num_slots": S, "page_size": ps, "pages_per_slot": npp,
-            "num_pages": P, "row_width": row, "buckets": buckets,
-            "prompts_per_dispatch": per_dispatch,
-            "prefill_rungs": rungs,
-            "prefill_token_budget": int(prefill_token_budget),
-            "layer_kinds": kinds, "moe_layers": list(range(d["L"])),
-            "dtype": dtype,
+    return decoder_programs.DecoderFamily(
+        "lad", parameter_shapes(desc, dtype), d["V"], state, prefill,
+        step, geometry={
+            "row_width": row, "layer_kinds": kinds,
+            "moe_layers": list(range(d["L"])),
             # the tokens a chunk of the linear layers' prefill walks
             "prefill_chunk": CHUNK,
-            # the experts held of those routed among, and a token's choices
-            "experts": {"held": d["E"], "of": d["Er"], "top_k": d["k"]},
-            "state": {"page_pools": page_pools,
-                      "slot_arrays": slot_arrays}},
-    }
+            # the experts held of those routed among, a token's choices
+            "experts": {"held": d["E"], "of": d["Er"], "top_k": d["k"]}})
+
+
+build_linear_attn_moe_decoder = functools.partial(
+    decoder_programs.build_decoder_programs, _family)

@@ -28,31 +28,23 @@ pools, ``R``, the ring's size and the names of its two feeds), enough for
 the rows a dispatch's first query can see to the row its last step
 writes, at any alignment (``kernels/window_paged_attention.py``).
 
-``build_windowed_moe_decoder`` returns what ``build_latent_moe_decoder``
-returns, with the same feeds plus the ring's (``window_rows [B, R]`` for
-a prefill, ``window_table [S, R]`` for a step), so
-``serving.decoder_session.DecoderOnlySession`` dispatches any of the
-three:
-
-* ``init`` zeroes the pools.
-* ``prefill[T]``: ``prompts_per_dispatch(T)`` prompts a dispatch through
-  the flash kernel (``window=`` on a window layer); a full layer writes
-  every page of the prompt, a window layer only those its ring keeps
-  after the prompt.
-* ``step``: one token for every slot, ``tokens_per_dispatch`` a dispatch
-  by ``Executor.run_multi_step``.
-
-Parameters are declared by name (``parameter_shapes``) and loaded
-(``load_parameters``); the matrices are stored input-major.
+The programs' frame (buckets, budget, rungs, feeds, sampler, fetches) is
+``models/decoder_programs.py``'s, which gives a declared ring its feeds
+(``window_rows [B, R]`` for a prefill, ``window_table [S, R]`` for a
+step). A prefill runs the flash kernel (``window=`` on a window layer); a
+full layer writes every page of the prompt, a window layer only those its
+ring keeps after the prompt. The matrices are stored input-major.
 """
 
 import collections
+import functools
 
 import numpy as np
 
 import paddle_tpu as fluid
 from paddle_tpu.core.types import np_dtype
 from paddle_tpu.kernels.paged_attention import pages_for
+from paddle_tpu.models import decoder_programs
 
 __all__ = ["windowed_dims", "parameter_shapes", "random_parameters",
            "load_parameters", "ring_pages_per_slot",
@@ -175,78 +167,45 @@ def random_parameters(desc, seed=0, dtype="float32"):
     return out
 
 
-def load_parameters(scope, named, desc=None, dtype=None):
-    """Put a checkpoint's arrays into ``scope`` under the programs' names.
-    With ``desc`` every parameter must be there with its shape."""
-    from paddle_tpu.models.latent_moe_decoder import load_named
-
-    load_named(scope, named, desc and parameter_shapes(desc,
-                                                       dtype or "bfloat16"))
+load_parameters = functools.partial(decoder_programs.load_parameters,
+                                    parameter_shapes)
 
 
-def build_windowed_moe_decoder(desc, num_slots, max_positions, page_size,
-                               prefill_buckets, num_pages=None,
-                               prefill_token_budget=2048, sampler=None,
-                               dtype="bfloat16", probe_rows=0,
-                               tokens_per_dispatch=1):
-    """Build the serving programs (module docstring). Returns what
-    ``models.latent_moe_decoder.build_latent_moe_decoder`` returns;
+def _family(desc, dtype, tokens_per_dispatch):
+    """This family's layers and state (``decoder_programs.DecoderFamily``);
     ``geometry["state"]["windowed"]`` declares the window layers' rings.
-    ``num_pages`` sizes the full layers' pools; a ring is always at full
-    occupancy (``1 + num_slots * R`` pages)."""
-    from paddle_tpu import unique_name
-    from paddle_tpu.models.transformer import _sampler_attrs
-
+    The session's ``num_pages`` sizes the full layers' pools; a ring is
+    always at full occupancy (``1 + num_slots * R`` pages)."""
     nn = fluid.layers
     d = windowed_dims(desc)
     kinds, W = d["kinds"], d["W"]
-    S, ps = int(num_slots), int(page_size)
-    npp = pages_for(max_positions, ps)
-    P = int(num_pages) if num_pages else 1 + S * npp
-    R = min(ring_pages_per_slot(W, tokens_per_dispatch, ps), npp)
-    Pw = 1 + S * R
-    samp = _sampler_attrs(sampler)
-    buckets = sorted(int(t) for t in prefill_buckets)
-    if any(t % ps for t in buckets):
-        raise ValueError("every prefill bucket (%s) must be a multiple of "
-                         "the page size %d: rows are written a page at a "
-                         "time" % (buckets, ps))
-    per_dispatch = {t: max(1, int(prefill_token_budget) // t)
-                    for t in buckets}
-    shapes = parameter_shapes(desc, dtype)
     row = d["Hkv"] * d["dh"]
-    moe_layers = list(range(d["dense"], d["L"]))
-    page_pools = collections.OrderedDict()
-    for i, kind in enumerate(kinds):
-        for part in "kv":
-            page_pools["wmd_%s_%d" % (part, i)] = {
-                "shape": (Pw if kind == SLIDING else P, ps, row),
-                "dtype": dtype}
-    windowed = [{
-        "window": W, "pages_per_slot": R, "num_pages": Pw,
-        "pools": [n for n in page_pools
-                  if kinds[int(n.rsplit("_", 1)[1])] == SLIDING],
-        "table_feed": "window_table", "rows_feed": "window_rows"}] \
-        if SLIDING in kinds else []
 
-    def declare(blk, name, shape, dt):
-        return blk.create_var(name=name, shape=list(shape), dtype=dt,
-                              persistable=True)
+    def state(S, P, ps, npp):
+        R = min(ring_pages_per_slot(W, tokens_per_dispatch, ps), npp)
+        Pw = 1 + S * R
+        page_pools = collections.OrderedDict()
+        for i, kind in enumerate(kinds):
+            for part in "kv":
+                page_pools["wmd_%s_%d" % (part, i)] = {
+                    "shape": (Pw if kind == SLIDING else P, ps, row),
+                    "dtype": dtype}
+        windowed = [{
+            "window": W, "pages_per_slot": R, "num_pages": Pw,
+            "pools": [n for n in page_pools
+                      if kinds[int(n.rsplit("_", 1)[1])] == SLIDING],
+            "table_feed": "window_table", "rows_feed": "window_rows"}] \
+            if SLIDING in kinds else []
+        return {"page_pools": page_pools, "slot_arrays": {},
+                "windowed": windowed}
 
-    def state(blk):
-        pools = {name: declare(blk, name, spec["shape"], spec["dtype"])
-                 for name, spec in page_pools.items()}
-        return (pools, declare(blk, "wmd_tok", (S, 1), "int64"),
-                declare(blk, "wmd_pos", (S, 1), "int64"))
-
-    def blocks(blk, x, attend, valid):
+    def blocks(f, x, attend):
         """The L blocks and the final norm on token rows ``x`` [N, D];
-        ``attend(i, q, k, v)`` writes layer ``i``'s rows and attends (q
-        and k after their norms and RoPE). Returns (x, chosen per expert
-        layer, tokens per expert per expert layer)."""
-        def w(name):
-            return declare(blk, name, *shapes[name])
-
+        ``attend(i, q, k, v, q_norm, k_norm)`` norms q and k a head, turns
+        them on a sliding layer, writes layer ``i``'s rows and attends.
+        Returns (x, chosen per expert layer, tokens per expert per expert
+        layer)."""
+        w = f.w
         chosen, counts = [], []
         x = nn.scale(x, scale=d["embed_scale"])
         for i in range(d["L"]):
@@ -269,7 +228,7 @@ def build_windowed_moe_decoder(desc, num_slots, max_positions, page_size,
                 ff, ch, cnt = nn.dropless_moe_ffn(
                     nx, w(p + "router"), w(p + "router_bias"),
                     w(p + "experts_gate"), w(p + "experts_up"),
-                    w(p + "experts_down"), shared=shared, valid=valid,
+                    w(p + "experts_down"), shared=shared, valid=f.valid,
                     top_k=d["k"], norm_topk=d["norm_topk"],
                     scale=d["scale"])
                 chosen.append(ch)
@@ -278,161 +237,56 @@ def build_windowed_moe_decoder(desc, num_slots, max_positions, page_size,
                 ff, w(p + "post_mlp_norm"), d["eps"]))
         return nn.rms_norm(x, w("wmd_final_norm"), d["eps"]), chosen, counts
 
-    def stacked(name, parts, shape):
-        """The per-layer parts as ONE fetchable variable."""
-        out = nn.concat([nn.reshape(c, shape=[1] + shape) for c in parts],
-                        axis=0)
-        return nn.assign(out, output=fluid.default_main_program()
-                         .global_block().create_var(name=name,
-                                                    dtype="int32"))
-
-    def logits_of(blk, rows, name, count):
-        out = nn.dense_projection(
-            rows, declare(blk, "wmd_head", *shapes["wmd_head"]),
-            out_dtype="float32")
-        return nn.assign(nn.reshape(out, shape=[count, 1, d["V"]]),
-                         output=blk.create_var(name=name, dtype="float32"))
-
-    def feed(name, shape):
-        return nn.data(name, shape=shape, dtype="int64",
-                       append_batch_size=False)
-
     norm_attrs = dict(heads=d["H"], kv_heads=d["Hkv"], theta=d["theta"],
                       epsilon=d["eps"])
 
-    with unique_name.guard({}):
-        init = fluid.Program()
-        with fluid.program_guard(init, fluid.Program()):
-            pools, tok, pos = state(init.global_block())
-            for name, var in pools.items():
-                spec = page_pools[name]
-                nn.assign(nn.fill_constant(list(spec["shape"]),
-                                           spec["dtype"], 0.0), output=var)
-            nn.assign(nn.fill_constant([S, 1], "int64", 0), output=tok)
-            nn.assign(nn.fill_constant([S, 1], "int64", 0), output=pos)
-
-        prefill = {}
-        for T in buckets:
-            B = per_dispatch[T]
-            prog = prefill[T] = fluid.Program()
-            with unique_name.guard({}), \
-                    fluid.program_guard(prog, fluid.Program()):
-                blk = prog.global_block()
-                pools, tok, pos = state(blk)
-                ids = feed("prompt_ids", [B * T])
-                lens = feed("prompt_len", [B])
-                slot_idx = feed("slot_idx", [B])
-                page_rows = feed("page_rows", [B, npp])
-                ring_rows = feed("window_rows", [B, R]) if windowed \
-                    else None
-                last_idx = feed("last_idx", [B])
-                valid = nn.reshape(
-                    nn.sequence_mask(lens, maxlen=T, dtype="int64"),
-                    shape=[B * T])
-                x = nn.embedding_rows(
-                    declare(blk, "wmd_embed", *shapes["wmd_embed"]), ids)
-
-                def attend(i, q, k, v, q_norm, k_norm, B=B, T=T,
-                           pools=pools, page_rows=page_rows,
-                           ring_rows=ring_rows, lens=lens):
-                    sliding = kinds[i] == SLIDING
-                    q, k = nn.qk_norm_rope(q, k, q_norm, k_norm,
-                                           rope=sliding, period=T,
-                                           **norm_attrs)
-                    for part, rows in (("k", k), ("v", v)):
-                        pool = pools["wmd_%s_%d" % (part, i)]
-                        if sliding:
-                            nn.window_row_prefill(pool, rows, ring_rows,
-                                                  lens, W)
-                        else:
-                            nn.latent_row_prefill(pool, rows, page_rows,
-                                                  lens)
-                    return nn.window_prefill_attention(
-                        q, k, v, prompts=B, heads=d["H"],
-                        kv_heads=d["Hkv"], window=W if sliding else 0)
-
-                x, chosen, counts = blocks(blk, x, attend, valid)
-                logits = logits_of(blk, nn.gather(x, last_idx),
-                                   "wmd_first_logits", B)
-                lens2 = nn.reshape(lens, shape=[B, 1])
-                first, _p, _d = nn.slot_decode_sample(
-                    logits, lens2, eos_id=0,
-                    max_length=int(max_positions) + 2, **samp)
-                nn.assign(first, output=blk.create_var(
-                    name="wmd_first_tok", dtype="int64"))
-                nn.slot_rows_write(tok, slot_idx, first)
-                nn.slot_rows_write(pos, slot_idx, lens2)
-                if chosen:
-                    stacked("wmd_first_chosen", chosen, [B * T, d["k"]])
-
-        step = fluid.Program()
-        with unique_name.guard({}), \
-                fluid.program_guard(step, fluid.Program()):
-            blk = step.global_block()
-            pools, tok, pos = state(blk)
-            table = feed("page_table", [S, npp])
-            ring_table = feed("window_table", [S, R]) if windowed else None
-            live = feed("live", [S, 1])
-            # resident rows AFTER this step's write; 0 for an empty slot
-            lengths = nn.elementwise_mul(
-                nn.increment(pos, value=1, in_place=False), live)
-            done = nn.elementwise_sub(
-                nn.fill_constant([S, 1], "int64", 1), live)
-            x = nn.embedding_rows(
-                declare(blk, "wmd_embed", *shapes["wmd_embed"]), tok)
-
-            def attend(i, q, k, v, q_norm, k_norm):
-                sliding = kinds[i] == SLIDING
-                q, k = nn.qk_norm_rope(q, k, q_norm, k_norm, rope=sliding,
-                                       positions=pos, **norm_attrs)
-                k_pool, v_pool = pools["wmd_k_%d" % i], \
-                    pools["wmd_v_%d" % i]
+    def prefill(f, x):
+        def attend(i, q, k, v, q_norm, k_norm):
+            sliding = kinds[i] == SLIDING
+            q, k = nn.qk_norm_rope(q, k, q_norm, k_norm, rope=sliding,
+                                   period=f.bucket, **norm_attrs)
+            for part, rows in (("k", k), ("v", v)):
+                pool = f.state["wmd_%s_%d" % (part, i)]
                 if sliding:
-                    nn.window_row_write(k_pool, k, ring_table, pos)
-                    nn.window_row_write(v_pool, v, ring_table, pos)
-                    return nn.window_paged_attention(
-                        q, k_pool, v_pool, ring_table, lengths,
-                        heads=d["H"], window=W)
-                nn.latent_row_write(k_pool, k, table, pos)
-                nn.latent_row_write(v_pool, v, table, pos)
-                return nn.gqa_paged_attention(q, k_pool, v_pool, table,
-                                              lengths, heads=d["H"])
+                    nn.window_row_prefill(pool, rows, f.ring_rows[0],
+                                          f.lens, W)
+                else:
+                    nn.latent_row_prefill(pool, rows, f.page_rows, f.lens)
+            return nn.window_prefill_attention(
+                q, k, v, prompts=f.rows, heads=d["H"], kv_heads=d["Hkv"],
+                window=W if sliding else 0)
 
-            x, chosen, counts = blocks(blk, x, attend, live)
-            logits = logits_of(blk, x, "wmd_logits", S)
-            if probe_rows:
-                probe = feed("probe_slots", [int(probe_rows)])
-                nn.assign(
-                    nn.gather(nn.reshape(logits, shape=[S, d["V"]]), probe),
-                    output=blk.create_var(name="wmd_probe_logits",
-                                          dtype="float32"))
-            tok_new, pos_new, _done = nn.slot_decode_sample(
-                logits, pos, done=done, eos_id=0,
-                max_length=int(max_positions) + 2, **samp)
-            nn.assign(tok_new, output=blk.create_var(
-                name="wmd_step_tok", dtype="int64"))
-            if chosen:
-                stacked("wmd_chosen", chosen, [S, d["k"]])
-                stacked("wmd_expert_tokens", counts, [d["E"]])
-            nn.assign(tok_new, output=tok)
-            nn.assign(pos_new, output=pos)
+        x, chosen, _counts = blocks(f, x, attend)
+        return x, [("first_chosen", chosen)]
 
-    has_moe = bool(moe_layers)
-    return {
-        "init": init, "prefill": prefill, "step": step,
-        "fetches": {
-            "token": "wmd_step_tok", "first_token": "wmd_first_tok",
-            "logits": "wmd_logits", "first_logits": "wmd_first_logits",
-            "probe_logits": "wmd_probe_logits" if probe_rows else None,
-            "expert_tokens": "wmd_expert_tokens" if has_moe else None,
-            "chosen": "wmd_chosen" if has_moe else None,
-            "first_chosen": "wmd_first_chosen" if has_moe else None},
-        "geometry": {
-            "num_slots": S, "page_size": ps, "pages_per_slot": npp,
-            "num_pages": P, "row_width": row, "buckets": buckets,
-            "prompts_per_dispatch": per_dispatch,
-            "prefill_token_budget": int(prefill_token_budget),
-            "layer_kinds": kinds, "moe_layers": moe_layers, "dtype": dtype,
-            "state": {"page_pools": page_pools, "slot_arrays": {},
-                      "windowed": windowed}},
-    }
+    def step(f, x):
+        def attend(i, q, k, v, q_norm, k_norm):
+            sliding = kinds[i] == SLIDING
+            q, k = nn.qk_norm_rope(q, k, q_norm, k_norm, rope=sliding,
+                                   positions=f.pos, **norm_attrs)
+            k_pool, v_pool = f.state["wmd_k_%d" % i], \
+                f.state["wmd_v_%d" % i]
+            if sliding:
+                ring = f.ring_tables[0]
+                nn.window_row_write(k_pool, k, ring, f.pos)
+                nn.window_row_write(v_pool, v, ring, f.pos)
+                return nn.window_paged_attention(
+                    q, k_pool, v_pool, ring, f.lengths, heads=d["H"],
+                    window=W)
+            nn.latent_row_write(k_pool, k, f.table, f.pos)
+            nn.latent_row_write(v_pool, v, f.table, f.pos)
+            return nn.gqa_paged_attention(q, k_pool, v_pool, f.table,
+                                          f.lengths, heads=d["H"])
+
+        x, chosen, counts = blocks(f, x, attend)
+        return x, [("chosen", chosen), ("expert_tokens", counts)]
+
+    return decoder_programs.DecoderFamily(
+        "wmd", parameter_shapes(desc, dtype), d["V"], state, prefill,
+        step, geometry={
+            "row_width": row, "layer_kinds": kinds,
+            "moe_layers": list(range(d["dense"], d["L"]))})
+
+
+build_windowed_moe_decoder = functools.partial(
+    decoder_programs.build_decoder_programs, _family)

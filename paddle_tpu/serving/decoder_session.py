@@ -3,7 +3,9 @@ over the state its builder declares: page pools that grow with a slot's
 sequence, page pools that hold a slot's last ``window`` positions only,
 fixed-size arrays indexed by the slot itself, or any of them together.
 
-The model is chosen from its description (``builder_for``): a dict with
+The model is chosen from its description (``builder_for``, of
+``models/decoder_programs.py``, which builds every family's programs on
+one frame): a dict with
 ``mamba_d_state`` is the hybrid state-space decoder
 (``models/hybrid_ssm_decoder.py``: recurrent state and a convolution
 window a slot a state-space layer, K/V page pools for its few attention
@@ -114,6 +116,7 @@ import numpy as np
 
 from paddle_tpu.core.types import np_dtype
 from paddle_tpu.kernels.paged_attention import pages_for
+from paddle_tpu.models.decoder_programs import builder_for
 from paddle_tpu.observability import tracing as _tracing
 from paddle_tpu.serving.generation import (
     NoFreeSlotError,
@@ -126,48 +129,6 @@ from paddle_tpu.serving.kv_pool import NoFreePageError, PagePool
 from paddle_tpu.serving.server import ServingError
 
 __all__ = ["DecoderOnlySession", "builder_for"]
-
-
-def builder_for(desc):
-    """The function that builds ``desc``'s serving programs, chosen by the
-    description's own keys."""
-    if "mamba_d_state" in desc:
-        from paddle_tpu.models.hybrid_ssm_decoder import (
-            build_hybrid_ssm_decoder,
-        )
-
-        return build_hybrid_ssm_decoder
-    if "kv_lora_rank" in desc:
-        from paddle_tpu.models.latent_moe_decoder import (
-            build_latent_moe_decoder,
-            check_served,
-        )
-
-        # a description the builder does not serve (group-limited routing,
-        # an index_topk_pattern, indexer_types that start with `shared`)
-        # is refused here, by the key at fault, before anything is built
-        check_served(desc)
-        return build_latent_moe_decoder
-    if "layer_types" in desc and desc.get("sliding_window") is not None:
-        from paddle_tpu.models.windowed_moe_decoder import (
-            build_windowed_moe_decoder,
-        )
-
-        return build_windowed_moe_decoder
-    if "linear_attn_config" in desc:
-        from paddle_tpu.models import linear_attn_moe_decoder as lad
-
-        # refused by the key at fault before anything is built: leading
-        # dense layers, value heads of their own, full-rank gates, RoPE
-        lad.check_served(desc)
-        return lad.build_linear_attn_moe_decoder
-    raise ServingError(
-        "DecoderOnlySession knows no builder for this description (keys "
-        "%s): it serves a hybrid state-space decoder (mamba_d_state), a "
-        "latent-attention decoder (kv_lora_rank), a decoder of window "
-        "and full attention layers (layer_types with a sliding_window) or "
-        "a decoder of delta-rule linear-attention and grouped-query "
-        "attention layers (linear_attn_config)" % sorted(desc))
 
 
 class _PageKind(object):
@@ -273,8 +234,7 @@ class DecoderOnlySession(object):
         powers of two of the page size; 2048 tokens a dispatch).
     prefill_rungs : ask the builder for a prefill program a rung of prompt
         rows under a bucket's most (module docstring): more programs to
-        compile, for dispatches of few LONG prompts. A builder that has
-        no rungs refuses the keyword.
+        compile, for dispatches of few LONG prompts.
     num_pages : size of the pools that grow with the sequence, trash page
         included (default: full occupancy; a ring is always at full
         occupancy).
@@ -317,8 +277,7 @@ class DecoderOnlySession(object):
             num_pages=num_pages,
             prefill_token_budget=prefill_token_budget, sampler=sampler,
             dtype=dtype, probe_rows=probe_rows,
-            tokens_per_dispatch=self._K,
-            **({"prefill_rungs": True} if prefill_rungs else {}))
+            tokens_per_dispatch=self._K, prefill_rungs=prefill_rungs)
         self.geometry = geo = built["geometry"]
         self._buckets = geo["buckets"]
         if self._buckets[-1] < self._max_prompt:
@@ -345,11 +304,8 @@ class DecoderOnlySession(object):
             int(np.prod(a["shape"])) * np.dtype(np_dtype(a["dtype"])).itemsize
             for a in geo["state"]["slot_arrays"].values()) // self._S
         self._prefill_chunk = int(geo.get("prefill_chunk") or 0)
-        # {bucket: {prompt rows: program}}; one rung, the most a dispatch
-        # takes, where the builder gives no others
-        self._prefill_progs = built.get("prefill_rungs") or {
-            T: {self._per_dispatch[T]: prog}
-            for T, prog in built["prefill"].items()}
+        # {bucket: {prompt rows: program}}
+        self._prefill_progs = built["prefill_rungs"]
         self._step_prog = built["step"]
         self._fetch = built["fetches"]
         # what the frontend's worker reads of a session (its package-

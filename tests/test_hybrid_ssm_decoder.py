@@ -335,6 +335,44 @@ def test_a_slots_logits_do_not_depend_on_its_dispatch_mates():
     assert (alone.tokens_of(slot) == sess.tokens_of(mine)).all()
 
 
+def test_a_dispatch_runs_the_least_rung_of_rows_that_holds_its_prompts():
+    """With ``prefill_rungs`` a bucket has a program a power of two of
+    prompt rows under its most (budget 64: 8, 4 and 2 prompts of 8, 16 and
+    32): one prompt is walked as one row, three as four, and tokens, logits
+    and the state a prefill installs are those of the session that walks
+    the whole budget."""
+    def serve(**kw):
+        sess, _tree = make_session(tap=True, **kw)
+        for p in prompts_of([27, 5, 6, 7]):
+            sess.enqueue(p)
+        slot_of = {rid: slot for slot, rid in sess.admit_pending().items()}
+        state = {rid: slot_state(sess, slot) for rid, slot in slot_of.items()}
+        first = {int(slot): logits[row, 0]
+                 for feed, logits in sess._exe.prefill
+                 for row, slot in enumerate(feed["slot_idx"])}
+        for _ in range(3):
+            sess.step()
+        steps = np.concatenate(sess._exe.steps)[:, :, 0]      # [6, S, V]
+        return sess, {rid: (sess.tokens_of(slot), first[slot],
+                            steps[:, slot]) + state[rid]
+                      for rid, slot in slot_of.items()}
+
+    plain, want = serve()
+    sess, got = serve(prefill_rungs=True)
+    assert plain.geometry["prefill_rungs"] == {8: [8], 16: [4], 32: [2]}
+    assert sess.geometry["prefill_rungs"] == {
+        8: [1, 2, 4, 8], 16: [1, 2, 4], 32: [1, 2]}
+    assert sess.geometry["prompts_per_dispatch"] == {8: 8, 16: 4, 32: 2}
+    places = lambda s: sorted(len(feed["prompt_ids"])  # noqa: E731
+                              for feed, _first in s._exe.prefill)
+    assert places(plain) == [64, 64] and places(sess) == [32, 32]
+    assert sess.last_prefills == [(8, [5, 6, 7]), (32, [27])]
+    for rid in range(4):
+        assert (got[rid][0] == want[rid][0]).all()
+        for mine, theirs in zip(got[rid][1:], want[rid][1:]):
+            assert rel(mine, theirs) < 1e-5
+
+
 def test_cancel_and_reuse_a_slot_starts_from_its_own_prefill():
     sess, tree = make_session(num_slots=3, tap=True)
     for p in prompts_of([9, 30, 3]):

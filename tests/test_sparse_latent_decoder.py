@@ -293,8 +293,7 @@ def test_a_dispatch_runs_the_least_rung_of_rows_that_holds_its_prompts():
     """With ``prefill_rungs`` a bucket has a program a power of two of
     prompt rows under its most (budget 64: 8, 4 and 2 prompts of 8, 16 and
     32): one prompt is walked as one row, three as four, and the logits
-    are those of the session that walks the whole budget; a builder
-    without rungs refuses the keyword."""
+    are those of the session that walks the whole budget."""
     plain, tree = make_session()
     sess, _tree = make_session(prefill_rungs=True)
     assert plain.geometry["prefill_rungs"] == {8: [8], 16: [4], 32: [2]}
@@ -326,11 +325,6 @@ def test_a_dispatch_runs_the_least_rung_of_rows_that_holds_its_prompts():
         assert rnd.spans[0]["prefill_pad_tokens"] == 16 - 9
     finally:
         tracing.enable(False)
-    from paddle_tpu.models import windowed_moe_decoder  # noqa: F401
-
-    with pytest.raises(TypeError, match="prefill_rungs"):
-        builder_for({"layer_types": ["full_attention"], "sliding_window": 8})(
-            {}, 4, 48, 8, [8], prefill_rungs=True)
 
 
 def test_a_slot_crosses_index_topk_while_it_decodes():

@@ -333,6 +333,43 @@ def test_a_slots_logits_do_not_depend_on_its_dispatch_mates():
     assert rel(served_logits(crowd, slot), want) < 1e-5
 
 
+def test_a_dispatch_runs_the_least_rung_of_rows_that_holds_its_prompts():
+    """With ``prefill_rungs`` a bucket has a program a power of two of
+    prompt rows under its most (budget 64: 16, 8, 4 and 2 prompts of 4, 8,
+    16 and 32), each with the ring's ``window_rows`` of as many rows: one prompt
+    is walked as one row, three as four, and tokens and logits are those
+    of the session that walks the whole budget."""
+    def serve(**kw):
+        sess, _tree = make_session(**kw)
+        for p in prompts_of([27, 5, 6, 7]):
+            sess.enqueue(p)
+        slot_of = {rid: slot for slot, rid in sess.admit_pending().items()}
+        for _ in range(3):
+            sess.step()
+        # the bucket of 8 is dispatched first, then the bucket of 32
+        return sess, {rid: (sess.tokens_of(slot),
+                            served_logits(sess, slot, prefill_at=rid == 0))
+                      for rid, slot in slot_of.items()}
+
+    plain, want = serve()
+    sess, got = serve(prefill_rungs=True)
+    assert plain.geometry["prefill_rungs"] == {
+        4: [16], 8: [8], 16: [4], 32: [2]}
+    assert sess.geometry["prefill_rungs"] == {
+        4: [1, 2, 4, 8, 16], 8: [1, 2, 4, 8], 16: [1, 2, 4], 32: [1, 2]}
+    assert sess.geometry["prompts_per_dispatch"] == {
+        4: 16, 8: 8, 16: 4, 32: 2}
+    feeds = lambda s: sorted(  # noqa: E731
+        (len(feed["prompt_ids"]), feed["window_rows"].shape)
+        for feed, _first in s._exe.prefill)
+    assert feeds(plain) == [(64, (2, RING)), (64, (8, RING))]
+    assert feeds(sess) == [(32, (1, RING)), (32, (4, RING))]
+    assert sess.last_prefills == [(8, [5, 6, 7]), (32, [27])]
+    for rid in range(4):
+        assert (got[rid][0] == want[rid][0]).all()
+        assert rel(got[rid][1], want[rid][1]) < 1e-5
+
+
 # -- the accounting -----------------------------------------------------------
 
 def test_a_ring_never_holds_more_than_its_pages_and_both_kinds_conserve():
