@@ -57,8 +57,8 @@ _TAIL = 8     # rows of the chunk before that the convolution keeps
 
 
 def _tile(d, want):
-    """The channel tile: ``want`` lanes where it divides ``d``, else all."""
-    return want if d % want == 0 else d
+    """The channel tile: the most lanes up to ``want`` that divide ``d``."""
+    return next((t for t in range(want, 0, -128) if d % t == 0), d)
 
 
 def _silu(x):

@@ -65,11 +65,14 @@ def dense_projection(x, w, out_dtype="input", name=None):
 
 def dropless_moe_ffn(x, router_w, router_bias, expert_w_gate, expert_w_up,
                      expert_w_down, shared=None, valid=None, top_k=1,
-                     norm_topk=True, scale=1.0, held_first=None, name=None):
+                     norm_topk=True, scale=1.0, held_first=None, name=None,
+                     scoring=None):
     """Routed experts with no capacity and no dropped token: sigmoid
     scores plus a selection bias, the ``top_k`` largest chosen, the
     (token, expert) pairs sorted by expert and computed as grouped matrix
     products; ``shared`` = (gate, up, down) of a shared expert added once.
+    ``scoring="softmax_topk"`` is the second rule: the ``top_k`` largest
+    raw logits, a softmax over those alone, ``router_bias`` None.
     ``valid`` [N] marks the tokens that exist (others are neither computed
     nor counted). With ``held_first`` the expert weights are a shard of
     the router's experts that starts there: the pairs of experts held
@@ -81,9 +84,11 @@ def dropless_moe_ffn(x, router_w, router_bias, expert_w_gate, expert_w_up,
         "int32", stop_gradient=True)
     counts = helper.create_variable_for_type_inference(
         "int32", stop_gradient=True)
-    inputs = {"X": [x], "RouterW": [router_w], "RouterBias": [router_bias],
+    inputs = {"X": [x], "RouterW": [router_w],
               "ExpertWGate": [expert_w_gate], "ExpertWUp": [expert_w_up],
               "ExpertWDown": [expert_w_down]}
+    if router_bias is not None:
+        inputs["RouterBias"] = [router_bias]
     if shared is not None:
         inputs.update(SharedWGate=[shared[0]], SharedWUp=[shared[1]],
                       SharedWDown=[shared[2]])
@@ -93,9 +98,13 @@ def dropless_moe_ffn(x, router_w, router_bias, expert_w_gate, expert_w_up,
         type="dropless_moe_ffn", inputs=inputs,
         outputs={"Out": [out], "Chosen": [chosen],
                  "ExpertTokens": [counts]},
-        attrs={"top_k": int(top_k), "norm_topk": bool(norm_topk),
-               "scale": float(scale),
-               "held_first": -1 if held_first is None else int(held_first)})
+        # a rule is named only where it is asked for: the programs of the
+        # models that do not ask keep the attributes they had
+        attrs=dict({"top_k": int(top_k), "norm_topk": bool(norm_topk),
+                    "scale": float(scale),
+                    "held_first": -1 if held_first is None
+                    else int(held_first)},
+                   **({"scoring": scoring} if scoring else {})))
     return out, chosen, counts
 
 

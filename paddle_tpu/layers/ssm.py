@@ -1,9 +1,10 @@
 """Layers of a hybrid decoder-only block (``ops/ssm_ops.py``): the
 selective state-space mixer's pieces in their prefill and one-token forms,
-and grouped-query attention over paged K/V row pools. Like
-``layers/decoder.py`` they take flat token rows ``[N, ...]`` and the
-parameters as variables that the serving builder
-(``models/hybrid_ssm_decoder.py``) declares by name."""
+and grouped-query attention over paged K/V row pools; and a Mamba-2
+mixer's (``ops/ssd_ops.py``). Like ``layers/decoder.py`` they take flat
+token rows ``[N, ...]`` and the parameters as variables that the serving
+builder (``models/hybrid_ssm_decoder.py``, ``models/ssd_moe_decoder.py``)
+declares by name."""
 
 from paddle_tpu.layer_helper import LayerHelper
 
@@ -17,6 +18,10 @@ __all__ = [
     "gqa_prefill_attention",
     "gqa_paged_attention",
     "tied_vocab_projection",
+    "ssd_prefill",
+    "ssd_state_update",
+    "gated_row_norm",
+    "scaled_residual",
 ]
 
 
@@ -145,4 +150,67 @@ def tied_vocab_projection(x, table, name=None):
     helper.append_op(type="tied_vocab_projection",
                      inputs={"X": [x], "W": [table]},
                      outputs={"Out": [out]})
+    return out
+
+
+def _ssd_inputs(xbc, dt, dt_bias, a_log, d_skip):
+    return {"XBC": [xbc], "Dt": [dt], "DtBias": [dt_bias], "ALog": [a_log],
+            "DSkip": [d_skip]}
+
+
+def ssd_prefill(xbc, dt, dt_bias, a_log, d_skip, lens, d_state, name=None):
+    """The Mamba-2 recurrence over a prefill dispatch's prompts, in chunks
+    (``kernels/ssd.py``): ``xbc`` [N, heads * d_head + 2 d_state] the
+    convolved ``x | B | C`` row, ``dt`` [N, heads] the raw step. Returns (y
+    [N, heads * d_head] float32, state float32 after each prompt's last
+    real token, ``[prompts, lane groups, d_state, group lanes]``:
+    ``kernels/ssd.py`` has the layout)."""
+    helper = LayerHelper("ssd_prefill", name=name)
+    out, state = _new(helper, xbc, "float32"), _new(helper, xbc, "float32")
+    helper.append_op(
+        type="ssd_prefill",
+        inputs=dict(_ssd_inputs(xbc, dt, dt_bias, a_log, d_skip),
+                    Lens=[lens]),
+        outputs={"Out": [out], "State": [state]},
+        attrs={"d_state": int(d_state)})
+    return out, state
+
+
+def ssd_state_update(state, xbc, dt, dt_bias, a_log, d_skip, live, d_state,
+                     name=None):
+    """One token of the Mamba-2 recurrence for every slot: y [S, heads *
+    d_head] float32; ``state`` is updated in place (a slot that is not
+    live keeps its own and reads 0)."""
+    helper = LayerHelper("ssd_state_update", name=name)
+    out = _new(helper, xbc, "float32")
+    helper.append_op(
+        type="ssd_state_update",
+        inputs=dict(_ssd_inputs(xbc, dt, dt_bias, a_log, d_skip),
+                    State=[state], Live=[live]),
+        outputs={"Out": [out], "StateOut": [state]},
+        attrs={"d_state": int(d_state)})
+    return out
+
+
+def gated_row_norm(x, gate, scale, epsilon=1e-5, name=None):
+    """``RMSNorm(x * silu(gate)) * scale`` over the whole row, the gate
+    multiplied in BEFORE the statistics (Mamba-2's gated norm with one
+    group); in ``gate``'s dtype. ``layers.gated_head_norm`` is the norm
+    first, a head at a time, with a sigmoid gate."""
+    helper = LayerHelper("gated_row_norm", name=name)
+    out = _new(helper, gate)
+    helper.append_op(
+        type="gated_row_norm",
+        inputs={"X": [x], "Gate": [gate], "Scale": [scale]},
+        outputs={"Out": [out]}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def scaled_residual(x, y, scale, name=None):
+    """``x + scale * y`` computed in float32 and rounded once (a model
+    whose branches enter the residual stream through a multiplier)."""
+    helper = LayerHelper("scaled_residual", name=name)
+    out = _new(helper, x)
+    helper.append_op(type="scaled_residual", inputs={"X": [x], "Y": [y]},
+                     outputs={"Out": [out]}, attrs={"scale": float(scale)})
     return out

@@ -1,8 +1,9 @@
 """The serving programs of a decoder-only language model, written ONCE: the
 frame every family's builder fills (``models/latent_moe_decoder.py``,
 ``hybrid_ssm_decoder.py``, ``windowed_moe_decoder.py``,
-``linear_attn_moe_decoder.py``). A family file holds what its layers do
-and the state they keep (``DecoderFamily``); this module holds what a
+``linear_attn_moe_decoder.py``, ``ssd_moe_decoder.py``). A family file
+holds what its layers do and the state they keep (``DecoderFamily``); this
+module holds what a
 ``serving.decoder_session.DecoderOnlySession`` dispatches, whatever the
 model:
 
@@ -57,6 +58,9 @@ __all__ = ["DecoderFamily", "build_decoder_programs", "builder_for",
 # its builder there), asked in order. A module's ``check_served``, where it
 # has one, refuses by the key at fault before anything is built.
 _FAMILIES = (
+    # Mamba-2 has heads; a Mamba-1 description (the next row) has none
+    (lambda desc: "mamba_n_heads" in desc,
+     "ssd_moe_decoder", "build_ssd_moe_decoder"),
     (lambda desc: "mamba_d_state" in desc,
      "hybrid_ssm_decoder", "build_hybrid_ssm_decoder"),
     (lambda desc: "kv_lora_rank" in desc,
@@ -81,7 +85,8 @@ def builder_for(desc):
 
     raise ServingError(
         "DecoderOnlySession knows no builder for this description (keys "
-        "%s): it serves a hybrid state-space decoder (mamba_d_state), a "
+        "%s): it serves a hybrid Mamba-2 decoder with routed experts "
+        "(mamba_n_heads), a hybrid state-space decoder (mamba_d_state), a "
         "latent-attention decoder (kv_lora_rank), a decoder of window "
         "and full attention layers (layer_types with a sliding_window) or "
         "a decoder of delta-rule linear-attention and grouped-query "

@@ -36,3 +36,4 @@ from paddle_tpu.ops import sparse_attention_ops  # noqa: F401
 from paddle_tpu.ops import ssm_ops  # noqa: F401
 from paddle_tpu.ops import linear_attention_ops  # noqa: F401
 from paddle_tpu.ops import window_ops  # noqa: F401
+from paddle_tpu.ops import ssd_ops  # noqa: F401

@@ -243,10 +243,14 @@ def _lower_dropless_moe_ffn(ctx, ins, attrs):
     if valid is not None:
         valid = jnp.reshape(valid, (-1,)) > 0
     with jax.named_scope("dropless_route"):
-        chosen, weights = route_top_k(
-            x, ins["RouterW"][0], ins["RouterBias"][0],
-            int(attrs["top_k"]), bool(attrs.get("norm_topk", True)),
-            float(attrs.get("scale", 1.0)))
+        if attrs.get("scoring", "sigmoid") == "softmax_topk":
+            chosen, weights = route_softmax_top_k(
+                x, ins["RouterW"][0], int(attrs["top_k"]))
+        else:
+            chosen, weights = route_top_k(
+                x, ins["RouterW"][0], ins["RouterBias"][0],
+                int(attrs["top_k"]), bool(attrs.get("norm_topk", True)),
+                float(attrs.get("scale", 1.0)))
     first = int(attrs.get("held_first", -1))
     first = None if first < 0 else first
     n = x.shape[0] // _HELD_TOKEN_BLOCK
@@ -287,8 +291,21 @@ register_op(
             "Valid"],
     outputs=["Out", "Chosen", "ExpertTokens"],
     # held_first >= 0: the expert weights are the shard that starts there
-    # (the router keeps all its outputs)
-    attrs={"top_k": 1, "norm_topk": True, "scale": 1.0, "held_first": -1},
+    # (the router keeps all its outputs); scoring "softmax_topk" is the
+    # second rule (``route_softmax_top_k``: no bias is read)
+    attrs={"top_k": 1, "norm_topk": True, "scale": 1.0, "held_first": -1,
+           "scoring": "sigmoid"},
     lower=_lower_dropless_moe_ffn,
     grad=None,
 )
+
+
+def route_softmax_top_k(x, router_w, top_k):
+    """The ``top_k`` largest of the router's raw logits, a softmax over
+    those ``top_k`` logits alone (HF ``GraniteMoeTopKGating``): no bias, no
+    scale. Float32 at full precision, as ``route_top_k``. Returns (chosen
+    [N, k] int32, weights [N, k] float32)."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    top, chosen = jax.lax.top_k(logits, top_k)
+    return chosen.astype(jnp.int32), jax.nn.softmax(top, axis=-1)

@@ -1,14 +1,15 @@
 """The decoder-only serving programs, op for op and name for name.
 
-``models/decoder_programs.py`` writes the programs' frame once and the four
+``models/decoder_programs.py`` writes the programs' frame once and the five
 family files fill it; ``tests/golden/decoder_programs.json`` holds
-``core.fingerprint.program_fingerprint`` of every program of the five test
+``core.fingerprint.program_fingerprint`` of every program of the six test
 descriptions (``DESC`` of ``test_latent_moe_decoder``,
 ``test_sparse_latent_decoder``, ``test_hybrid_ssm_decoder``,
-``test_windowed_moe_decoder``, ``test_linear_attn_decoder``, at the
-geometry those files' sessions use): ``init``, ``step``, ``step`` with
+``test_windowed_moe_decoder``, ``test_linear_attn_decoder``,
+``test_ssd_moe_decoder``, at the geometry those files' sessions use): ``init``, ``step``, ``step`` with
 ``probe_rows=2`` and every rung of every bucket's prefill. They were
-recorded at the commit BEFORE the frame was written once (a rung of B rows
+recorded at the commit BEFORE the frame was written once (the sixth with
+the family that brought it; a rung of B rows
 that a builder of that commit did not take is the program it built for a
 budget of B rows), so a case fails at any commit that adds, drops or
 reorders an op, renames a variable or changes an attribute: the fingerprint
@@ -40,7 +41,7 @@ GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden",
 # the test file whose DESC it is -> its sessions' slots
 FAMILIES = {"test_latent_moe_decoder": 4, "test_sparse_latent_decoder": 4,
             "test_hybrid_ssm_decoder": 6, "test_windowed_moe_decoder": 4,
-            "test_linear_attn_decoder": 6}
+            "test_linear_attn_decoder": 6, "test_ssd_moe_decoder": 6}
 
 
 @functools.lru_cache(maxsize=None)
@@ -97,12 +98,26 @@ def test_an_unknown_description_is_refused_with_the_families_named():
         builder_for({"hidden_size": 64, "vocab_size": 512})
     assert str(err.value) == (
         "DecoderOnlySession knows no builder for this description (keys "
-        "['hidden_size', 'vocab_size']): it serves a hybrid state-space "
+        "['hidden_size', 'vocab_size']): it serves a hybrid Mamba-2 decoder "
+        "with routed experts (mamba_n_heads), a hybrid state-space "
         "decoder (mamba_d_state), a latent-attention decoder "
         "(kv_lora_rank), a decoder of window and full attention layers "
         "(layer_types with a sliding_window) or a decoder of delta-rule "
         "linear-attention and grouped-query attention layers "
         "(linear_attn_config)")
+
+
+def test_a_mamba2_description_and_a_mamba1_description_part_ways():
+    """Both have ``mamba_d_state``; the row that asks for heads stands
+    first and the Mamba-1 description still reaches its own builder."""
+    import test_hybrid_ssm_decoder as jamba
+    import test_ssd_moe_decoder as granite
+    from paddle_tpu.models import hybrid_ssm_decoder, ssd_moe_decoder
+
+    assert "mamba_d_state" in granite.DESC and "mamba_d_state" in jamba.DESC
+    assert builder_for(granite.DESC) is ssd_moe_decoder.build_ssd_moe_decoder
+    assert builder_for(jamba.DESC) \
+        is hybrid_ssm_decoder.build_hybrid_ssm_decoder
 
 
 if __name__ == "__main__":

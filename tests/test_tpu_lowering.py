@@ -796,3 +796,79 @@ def test_linear_decoder_step_copies_no_state_array_and_no_pool(v5e):
             sorted(set(moved_shapes))), line)]
     assert not moved, "the step moves a state array or a pool:\n" \
         + "\n".join(moved)
+
+
+# -- the Mamba-2 / expert decoder (kernels/ssd.py) ----------------------------
+
+@pytest.mark.parametrize("B,T", [(1, 4096), (16, 256)])
+def test_ssd_prefill_lowers_for_tpu_at_the_served_widths(v5e, B, T):
+    """The chunked Mamba-2 prefill of the 32B-A9B cell: 128 heads of 64 on
+    a state of 128, one prompt of 4096 tokens and a bucket row of 16 of
+    256."""
+    from paddle_tpu.kernels import ssd
+
+    H, P, N = 128, 64, 128
+    text = _compile_v5e(
+        v5e, lambda x, dt, a, b, c, d, lens: ssd.chunk_prefill(
+            x, dt, a, b, c, d, lens, force_pallas=True),
+        ((B, T, H * P), BF16), ((B, T, H), F32), ((H,), F32),
+        ((B, T, N), BF16), ((B, T, N), BF16), ((H,), F32),
+        ((B,), jnp.int32))
+    assert ssd.CHUNK_KERNEL_NAME in text
+
+
+def test_ssd_update_updates_the_state_in_place(v5e):
+    """The one-token update at the served sizes (64 slots, 128 heads, a
+    64 x 128 float32 state a head, two heads a lane group), the state
+    donated: Mosaic takes it, the compiled program holds no copy of the
+    state, keeps its one layout and its temporaries are a small fraction
+    of the 268 MB state of one layer."""
+    import re
+
+    from paddle_tpu.kernels import ssd
+
+    # 64 slots, 128 heads of 64 x 128: [slots, lane groups, d_state, lanes]
+    S, H, P, N = shape = ssd.state_shape(64, 128, 64, 128)
+    assert shape == (64, 64, 128, 128)
+
+    def step(state, x, dt, a, b, c, d, live):
+        with _tpu_target():
+            return ssd.state_update(state, x, dt, a, b, c, d, live)
+
+    sharding = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+            for shape, dt in (
+                ((S, H, P, N), F32), ((S, 8192), BF16), ((S, 128), F32),
+                ((128,), F32), ((S, 128), BF16), ((S, 128), BF16),
+                ((128,), F32), ((S,), jnp.int32))]
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert ssd.STATE_KERNEL_NAME in text
+    moved = [line.strip()[:160] for line in text.splitlines() if re.match(
+        r"\s*(?:ROOT )?%%\S+ = \w+\[%d,%d,%d,%d\]\S* "
+        r"(copy|transpose|gather|copy-start)\(" % (S, H, P, N), line)]
+    assert not moved, "the step moves a whole state array:\n" + "\n".join(moved)
+    assert compiled.memory_analysis().temp_size_in_bytes < S * H * P * N * 4 // 8
+    assert set(re.findall(r"\[%d,%d,%d,%d\]\{([\d,]+)" % (S, H, P, N), text)) \
+        == {"3,2,1,0"}
+
+
+def test_ssd_convolutions_lower_at_the_8448_wide_row(v5e):
+    """The ``x | B | C`` row is 8448 = 66 vectors wide, which 512 does not
+    divide: the convolutions take the most lanes under 512 that do (384),
+    not the whole row (33 MB of VMEM a block, which the compiler
+    refuses)."""
+    from paddle_tpu.kernels import selective_scan as ss
+
+    d, S = 8448, 64
+    assert ss._tile(d, 512) == 384
+    text = _compile_v5e(
+        v5e, lambda x, w, b: ss.causal_conv(x, w, b, force_pallas=True),
+        ((1, 4096, d), BF16), ((4, d), BF16), ((d,), BF16))
+    assert ss.CONV_KERNEL_NAME in text
+    text = _compile_v5e(
+        v5e, lambda win, x, w, b, live: ss.conv_step(
+            win, x, w, b, live, force_pallas=True),
+        ((3, S, d), BF16), ((S, d), BF16), ((4, d), BF16), ((d,), BF16),
+        ((S,), jnp.int32))
+    assert ss.CONV_STEP_KERNEL_NAME in text

@@ -204,14 +204,82 @@ def _bench_paged_decode(shapes, calls, steps, warmup):
     return rows
 
 
+def _bench_state_kernels(names, update_cases, prefill_cases, calls, steps,
+                         warmup):
+    """A per-slot-state family's two kernels alone against their composed
+    forms. ``update_cases``: (shape, state bytes moved a call, the zero
+    state's shape, ``update(state, impl) -> state``): ``calls`` dependent
+    calls inside one jit with the state donated (a decode dispatch makes
+    tokens x layers of them). ``prefill_cases``: (shape, chunk-heads
+    walked, operands, ``prefill(*operands) -> (out, state)``). Beside the
+    times, the
+    largest difference of the kernel's state and output from the composed
+    form's over the largest element."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    def worst(got, want):
+        return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+    def forced(impl):
+        return dict(force_reference=impl == "reference",
+                    force_pallas=impl == "pallas")
+
+    rows = []
+    for shape, moved, zero, update in update_cases:
+        times, last = {}, {}
+        for impl in ("reference", "pallas"):
+            def run(state, impl=impl):
+                return jax.lax.scan(
+                    lambda st, _: (update(st, **forced(impl)), None), state,
+                    None, length=calls)[0]
+
+            fn = jax.jit(run, donate_argnums=0)
+            box = [jnp.zeros(zero, jnp.float32)]
+
+            def once(fn=fn, box=box):
+                box[0] = fn(box[0])
+                return box[0][0, 0, 0]
+
+            times[impl] = _time_steps(once, steps, warmup) / calls
+            last[impl] = jax.jit(run)(jnp.zeros(zero, jnp.float32))
+        row = {"kernel": names[0], "shape": list(shape),
+               "xla_ms": round(times["reference"] * 1e3, 4),
+               "pallas_ms": round(times["pallas"] * 1e3, 4),
+               "speedup": round(times["reference"] / times["pallas"], 3),
+               "state_gbytes_per_s": round(moved / times["pallas"] / 1e9, 1),
+               "state_max_err": worst(last["pallas"], last["reference"])}
+        print(json.dumps(row), flush=True)
+        rows.append(row)
+    for shape, chunk_heads, operands, prefill in prefill_cases:
+        times, last = {}, {}
+        for impl in ("reference", "pallas"):
+            fn = jax.jit(functools.partial(prefill, **forced(impl)))
+            last[impl] = fn(*operands)
+            times[impl] = _time_steps(
+                lambda fn=fn: fn(*operands)[1][0, 0, 0],
+                max(steps // 4, 1), 1)
+        row = {"kernel": names[1], "shape": list(shape),
+               "xla_ms": round(times["reference"] * 1e3, 3),
+               "pallas_ms": round(times["pallas"] * 1e3, 3),
+               "speedup": round(times["reference"] / times["pallas"], 3),
+               "us_per_chunk_head": round(
+                   times["pallas"] * 1e6 / chunk_heads, 3),
+               "state_max_err": worst(last["pallas"][1],
+                                      last["reference"][1]),
+               "out_max_err": worst(last["pallas"][0],
+                                    last["reference"][0])}
+        print(json.dumps(row), flush=True)
+        rows.append(row)
+    return rows
+
+
 def _bench_delta_rule(update_shapes, prefill_shapes, calls, steps, warmup):
-    """The two delta-rule kernels alone against their composed references
-    (``kernels/delta_rule.py``): the one-token update of every slot's
-    matrix state, ``calls`` dependent calls inside one jit with the state
-    donated (a decode dispatch makes tokens x linear layers of them), and
-    the chunked prefill of one prompt against the plain loop over its
-    tokens. Beside the times, the largest difference of the kernel's state
-    from the reference's over the largest state element."""
+    """The two delta-rule kernels (``kernels/delta_rule.py``): the
+    one-token update of every slot's matrix state and the chunked prefill
+    of one prompt against the plain loop over its tokens."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -228,69 +296,61 @@ def _bench_delta_rule(update_shapes, prefill_shapes, calls, steps, warmup):
         beta = jax.random.uniform(ks[4], rows + (H,), jnp.float32, 0.0, 2.0)
         return q, k, v, g, beta
 
-    def worst(got, want):
-        return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
-
-    rows = []
+    updates, prefills = [], []
     for S, live, H, dk, dv in update_shapes:
-        q, k, v, g, beta = inputs(jax.random.PRNGKey(S), (S,), H, dk, dv)
-        mask = jnp.asarray(np.arange(S) < live, jnp.int32)
-        times, last = {}, {}
-        for impl in ("reference", "pallas"):
-            def run(state, impl=impl):
-                def body(state, _):
-                    _o, state = dr.state_update(
-                        state, q, k, v, g, beta, mask,
-                        force_reference=impl == "reference",
-                        force_pallas=impl == "pallas")
-                    return state, None
-
-                return jax.lax.scan(body, state, None, length=calls)[0]
-
-            fn = jax.jit(run, donate_argnums=0)
-            box = [jnp.zeros((S, H, dk, dv), jnp.float32)]
-
-            def once(fn=fn, box=box):
-                box[0] = fn(box[0])
-                return box[0][0, 0, 0]
-
-            times[impl] = _time_steps(once, steps, warmup) / calls
-            last[impl] = jax.jit(run)(jnp.zeros((S, H, dk, dv), jnp.float32))
-        moved = 2 * live * H * dk * dv * 4
-        row = {"kernel": dr.STATE_KERNEL_NAME, "shape": [S, live, H, dk, dv],
-               "xla_ms": round(times["reference"] * 1e3, 4),
-               "pallas_ms": round(times["pallas"] * 1e3, 4),
-               "speedup": round(times["reference"] / times["pallas"], 3),
-               "state_gbytes_per_s": round(moved / times["pallas"] / 1e9, 1),
-               "state_max_err": worst(last["pallas"], last["reference"])}
-        print(json.dumps(row))
-        rows.append(row)
+        args = inputs(jax.random.PRNGKey(S), (S,), H, dk, dv) + (
+            jnp.asarray(np.arange(S) < live, jnp.int32),)
+        updates.append((
+            (S, live, H, dk, dv), 2 * live * H * dk * dv * 4,
+            (S, H, dk, dv), lambda state, args=args, **kw: dr.state_update(
+                state, *args, **kw)[1]))
     for B, T, n, H, dk, dv in prefill_shapes:
-        q, k, v, g, beta = inputs(jax.random.PRNGKey(T), (B, T), H, dk, dv)
-        lens = jnp.full((B,), n, jnp.int32)
-        times, last = {}, {}
-        for impl in ("reference", "pallas"):
-            fn = jax.jit(lambda q, k, v, g, beta, impl=impl: dr.chunk_prefill(
-                q, k, v, g, beta, lens,
-                force_reference=impl == "reference",
-                force_pallas=impl == "pallas"))
-            last[impl] = fn(q, k, v, g, beta)
-            times[impl] = _time_steps(
-                lambda fn=fn: fn(q, k, v, g, beta)[1][0, 0, 0],
-                max(steps // 4, 1), 1)
-        row = {"kernel": dr.CHUNK_KERNEL_NAME, "shape": [B, T, n, H, dk, dv],
-               "xla_ms": round(times["reference"] * 1e3, 3),
-               "pallas_ms": round(times["pallas"] * 1e3, 3),
-               "speedup": round(times["reference"] / times["pallas"], 3),
-               "us_per_chunk_head": round(
-                   times["pallas"] * 1e6 / (B * H * -(-n // dr.CHUNK)), 3),
-               "state_max_err": worst(last["pallas"][1],
-                                      last["reference"][1]),
-               "out_max_err": worst(last["pallas"][0],
-                                    last["reference"][0])}
-        print(json.dumps(row))
-        rows.append(row)
-    return rows
+        args = inputs(jax.random.PRNGKey(T), (B, T), H, dk, dv) + (
+            jnp.full((B,), n, jnp.int32),)
+        prefills.append(((B, T, n, H, dk, dv), B * H * -(-n // dr.CHUNK),
+                         args, dr.chunk_prefill))
+    return _bench_state_kernels(
+        (dr.STATE_KERNEL_NAME, dr.CHUNK_KERNEL_NAME), updates, prefills,
+        calls, steps, warmup)
+
+
+def _bench_ssd(update_shapes, prefill_shapes, calls, steps, warmup):
+    """The two Mamba-2 kernels (``kernels/ssd.py``): the one-token update
+    of every slot's matrix state and the chunked prefill of the prompts of
+    one bucket against the composed chunked form."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.kernels import ssd
+
+    def inputs(key, rows, H, P, N):
+        ks = jax.random.split(key, 5)
+        x = jax.random.normal(ks[0], rows + (H * P,), jnp.bfloat16)
+        dt = jax.nn.softplus(
+            jax.random.normal(ks[1], rows + (H,), jnp.float32) - 2.0)
+        a = -jax.random.uniform(ks[2], (H,), jnp.float32, 1.0, 16.0)
+        b, c = (jax.random.normal(ks[i], rows + (N,), jnp.bfloat16)
+                for i in (3, 4))
+        return x, dt, a, b, c, jnp.ones((H,), jnp.float32)
+
+    updates, prefills = [], []
+    for S, live, H, P, N in update_shapes:
+        args = inputs(jax.random.PRNGKey(S), (S,), H, P, N) + (
+            jnp.asarray(np.arange(S) < live, jnp.int32),)
+        updates.append((
+            (S, live, H, P, N), 2 * live * H * P * N * 4,
+            ssd.state_shape(S, H, P, N),
+            lambda state, args=args, **kw: ssd.state_update(
+                state, *args, **kw)[1]))
+    for B, T, n, H, P, N in prefill_shapes:
+        args = inputs(jax.random.PRNGKey(T), (B, T), H, P, N) + (
+            jnp.full((B,), n, jnp.int32),)
+        prefills.append(((B, T, n, H, P, N), B * H * -(-n // ssd.CHUNK),
+                         args, ssd.chunk_prefill))
+    return _bench_state_kernels(
+        (ssd.STATE_KERNEL_NAME, ssd.CHUNK_KERNEL_NAME), updates, prefills,
+        calls, steps, warmup)
 
 
 def _flash_tile_cases(quick):
@@ -418,7 +478,7 @@ def _on_tpu():
 
 
 _FAMILIES = ("dynamic_lstm", "dynamic_gru", "flash_attention",
-             "flash_tiles", "paged_decode", "delta_rule")
+             "flash_tiles", "paged_decode", "delta_rule", "ssd")
 
 
 def _orchestrate(args):
@@ -498,6 +558,8 @@ def main():
         paged_shapes, paged_calls = [(8, 3, 2, 16, 8, 32)], 2
         update_shapes, prefill_shapes = [(4, 3, 2, 16, 16)], \
             [(1, 128, 100, 2, 16, 16)]
+        ssd_update, ssd_prefill = [(4, 3, 4, 8, 16)], \
+            [(2, 64, 40, 4, 8, 16)]
     else:
         steps, warmup = 20, 5
         rnn_shapes = [(32, 128, 256), (64, 256, 512), (16, 512, 1024)]
@@ -514,6 +576,15 @@ def main():
         # chunk a third of the way
         update_shapes = [(96, live, 64, 128, 128) for live in (96, 64)]
         prefill_shapes = [(1, 8192, n, 64, 128, 128) for n in (8192, 2700)]
+        # perfbench granite4_h_small_10l: 64 slots (all live, and three
+        # quarters) of 128 heads of 64 x 128, 36 calls a dispatch (4 tokens
+        # x 9 Mamba-2 layers); one 4096-token prompt, whole and ended a
+        # third of the way, and bucket rows of 4 prompts of 1024 and 16 of 256
+        ssd_update = [(64, live, 128, 64, 128) for live in (64, 48)]
+        ssd_prefill = [(1, 4096, 4096, 128, 64, 128),
+                       (1, 4096, 1300, 128, 64, 128),
+                       (4, 1024, 700, 128, 64, 128),
+                       (16, 256, 200, 128, 64, 128)]
 
     # child mode: exactly one family, crash loudly (the parent records
     # the traceback from stderr and keeps the other families)
@@ -528,6 +599,9 @@ def main():
     elif args.family == "delta_rule":
         _bench_delta_rule(update_shapes, prefill_shapes,
                           2 if args.quick else 12, steps, warmup)
+    elif args.family == "ssd":
+        _bench_ssd(ssd_update, ssd_prefill, 2 if args.quick else 36, steps,
+                   warmup)
     elif args.family == "flash_tiles":
         _bench_flash_tiles(_flash_tile_cases(args.quick),
                            2 if args.quick else 5)
