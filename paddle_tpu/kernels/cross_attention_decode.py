@@ -36,6 +36,12 @@ training flash kernel at ``block_q = 1``: a grid of ``S x H x T_src /
   issues no copy for them, and neither for a slot whose predecessor in
   the grid read the same block of the same group (N best-of-N slots in a
   row cost one group's row). A slot with no valid key returns exactly 0.
+* A DEAD slot (``live`` 0: its stream has ended, or it never held one)
+  has length 0 whatever its stale group says, and a slot of length 0
+  copies nothing either: ``steer_dead_slots`` rewrites its K/V index to
+  the block the last live slot before it ended on, so the pipeline sees
+  an unchanged index (4-8 of 256 slots hold a stream below the knee).
+  Its step does not touch the softmax state either: it stores zeros.
 * float32 rows and accumulators, the two products at the ambient matmul
   precision, as ``_flash_kernel`` has them.
 
@@ -104,37 +110,70 @@ def source_block(num_heads, src_length, head_dim, itemsize=4):
 
 
 def grouped_cross_attention_reference(q, k_pool, v_pool, group_of, mask,
-                                      sm_scale=None):
+                                      sm_scale=None, live=None):
     """Composed XLA path: gather each slot's group row and mask row,
     then the flash reference. A slot whose keys are all masked returns
     the uniform average here and 0 from the kernel (meaningless either
-    way, as ``flash_attention`` has it)."""
+    way, as ``flash_attention`` has it); a slot that ``live`` calls dead
+    returns 0 here too."""
     gof = group_of.astype(jnp.int32)
     m = mask[gof][:, None, None, :].astype(bool)  # [S, 1, 1, T_src]
-    return flash_attention_reference(
+    out = flash_attention_reference(
         q, k_pool[gof], v_pool[gof], sm_scale=sm_scale, mask=m)
+    if live is not None:
+        out = jnp.where(live[:, None, None, None] > 0, out, 0.0)
+    return out
 
 
-def _cross_decode_kernel(gof_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, block, n_blocks,
-                         sm_scale, source_minor):
+def steer_dead_slots(group_of, slot_len, block):
+    """The three vectors the K/V index maps read, all [S] int32:
+    ``(group, lo, hi)``, grid step ``(s, j)`` taking block
+    ``clip(j, lo[s], hi[s])`` of ``group[s]``'s row. A slot with keys
+    keeps its group and walks ``0 .. its last valid block`` (that block
+    again past it: no copy). A slot of length 0 takes the group of the
+    last slot before it that has keys and ``lo = hi =`` that slot's last
+    valid block, which is the index the grid step before it had, so no
+    copy is issued for it. Slots ahead of the first one with keys hold
+    its first block, the copy it needs anyway (the pipeline always
+    copies at its first step). Computed here, on whole vectors, so that
+    an index map is two loads, a max and a min."""
+    S = slot_len.shape[0]
+    has_keys = slot_len > 0
+    at = jnp.arange(S, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(has_keys, at, -1))
+    donor = jnp.where(before >= 0, before,
+                      jnp.argmax(has_keys).astype(jnp.int32))
+    last = jnp.maximum((slot_len + block - 1) // block - 1, 0)
+    hi = jnp.where(before >= 0, last[donor], 0)
+    return group_of[donor], jnp.where(has_keys, 0, hi), hi
+
+
+def kv_block_index(s, j, lo, hi):
+    """The source block grid step ``(s, j)`` takes (``steer_dead_slots``)."""
+    return jnp.minimum(jnp.maximum(j, lo[s]), hi[s])
+
+
+def _cross_decode_kernel(gof_ref, lo_ref, hi_ref, len_ref, q_ref, k_ref,
+                         v_ref, o_ref, acc_ref, m_ref, l_ref, *, block,
+                         n_blocks, sm_scale, source_minor):
     """One (slot, source block) grid step: absorb one block of the slot's
     group row into the online-softmax state of its N query rows, every
-    head at once. ``gof_ref`` already steered the K/V index maps; the
-    body needs the slot's length for the validity test and the skip."""
+    head at once. ``gof_ref``, ``lo_ref`` and ``hi_ref`` already steered
+    the K/V index maps; the body needs the slot's length for the
+    validity test and the skip."""
     from jax.experimental import pallas as pl
 
-    del gof_ref
+    del gof_ref, lo_ref, hi_ref
     s = pl.program_id(0)
     j = pl.program_id(1)
 
-    @pl.when(j == 0)
+    length = len_ref[s]
+
+    @pl.when(jnp.logical_and(j == 0, length > 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-
-    length = len_ref[s]
 
     def _compute():
         q = q_ref[0].astype(jnp.float32) * sm_scale      # [H, N, dh]
@@ -158,13 +197,17 @@ def _cross_decode_kernel(gof_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     pl.when(j * block < length)(_compute)
 
-    @pl.when(j == n_blocks - 1)
+    @pl.when(jnp.logical_and(j == n_blocks - 1, length > 0))
     def _finish():
         dead = m_ref[...] <= _MASKED_ROW_M
         o_ref[0] = jnp.where(
             dead, 0.0,
             acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         ).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_and(j == n_blocks - 1, length == 0))
+    def _no_keys():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
 
 
 def _cross_decode_pallas(q, k_pool, v_pool, group_of, slot_len, sm_scale,
@@ -183,18 +226,18 @@ def _cross_decode_pallas(q, k_pool, v_pool, group_of, slot_len, sm_scale,
         k_pool = jnp.swapaxes(k_pool, 2, 3)
         v_pool = jnp.swapaxes(v_pool, 2, 3)
 
-    def kv_map(s, j, gof, lens):
-        # past the slot's length: the last valid block again (no copy)
-        last = jnp.maximum((lens[s] + block - 1) // block - 1, 0)
-        jb = jnp.minimum(j, last)
+    group_of, lo, hi = steer_dead_slots(group_of, slot_len, block)
+
+    def kv_map(s, j, gof, lo, hi, lens):
+        jb = kv_block_index(s, j, lo, hi)
         return (gof[s], 0, 0, jb) if source_minor else (gof[s], 0, jb, 0)
 
     kv_spec = pl.BlockSpec(
         (1, H, dh, block) if source_minor else (1, H, block, dh), kv_map)
     q_spec = pl.BlockSpec((1, H, N, dh),
-                          lambda s, j, gof, lens: (s, 0, 0, 0))
+                          lambda s, j, gof, lo, hi, lens: (s, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(S, n_blocks),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
@@ -213,18 +256,21 @@ def _cross_decode_pallas(q, k_pool, v_pool, group_of, slot_len, sm_scale,
         interpret=interpret,
         name=CROSS_DECODE_KERNEL_NAME,
         **_mosaic_params(interpret, ("parallel", "arbitrary")),
-    )(group_of, slot_len, q, k_pool, v_pool)
+    )(group_of, lo, hi, slot_len, q, k_pool, v_pool)
 
 
 def grouped_cross_attention(q, k_pool, v_pool, group_of, mask,
                             sm_scale=None, force_reference=False,
-                            force_pallas=False):
+                            force_pallas=False, live=None):
     """Each slot's N query rows over its group's source row.
 
     q: [S, H, N, dh]; k_pool/v_pool: [G, H, T_src, dh]; group_of: [S]
     int group ids; mask: [G, T_src] validity rows, PREFIX-valid (the
-    kernel reads a row as its count of valid positions). Returns
-    [S, H, N, dh].
+    kernel reads a row as its count of valid positions); live: [S] (or
+    [S, 1]), nonzero where the slot holds a stream -- a dead slot
+    returns exactly 0 and costs neither a copy nor a product, whatever
+    group its stale ``group_of`` names. Without it every slot is live.
+    Returns [S, H, N, dh].
 
     Routing is ``flash_attention``'s: the Pallas kernel on TPU targets
     (interpreted when forced on the CPU), the composed reference
@@ -234,11 +280,15 @@ def grouped_cross_attention(q, k_pool, v_pool, group_of, mask,
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     gof = jnp.reshape(group_of, (-1,)).astype(jnp.int32)
+    if live is not None:
+        live = jnp.reshape(live, (-1,))
     use_pallas = force_pallas or (not force_reference and _is_tpu_target())
     if not use_pallas:
         return grouped_cross_attention_reference(
-            q, k_pool, v_pool, gof, mask, sm_scale=sm_scale)
+            q, k_pool, v_pool, gof, mask, sm_scale=sm_scale, live=live)
     slot_len = jnp.sum(mask > 0, axis=-1, dtype=jnp.int32)[gof]  # [S]
+    if live is not None:
+        slot_len = jnp.where(live > 0, slot_len, 0)
     try:
         return _cross_decode_pallas(q, k_pool, v_pool, gof, slot_len,
                                     sm_scale,
@@ -250,27 +300,36 @@ def grouped_cross_attention(q, k_pool, v_pool, group_of, mask,
 
 
 def grid_accounting(group_of, lengths, num_heads, src_length, head_dim,
-                    n_rows=1, itemsize=4):
-    """What one call's grid does, from ``group_of`` ([S] group a slot)
-    and ``lengths`` ([G] valid positions a group): the pipeline copies a
-    K and a V block at a grid step whose block index differs from the
-    step before it, and a step past its slot's length repeats the last
-    valid index. ``blocks_read`` counts those copies (a K and a V block
-    as one), ``blocks_skipped`` the grid steps whose compute did not
-    run, ``hbm_bytes`` the blocks read plus the query and output rows."""
+                    n_rows=1, itemsize=4, live=None):
+    """What one call's grid does, from ``group_of`` ([S] group a slot),
+    ``lengths`` ([G] valid positions a group) and ``live`` ([S], nonzero
+    where the slot holds a stream; all of them without it): the pipeline
+    copies a K and a V block at a grid step whose block index differs
+    from the step before it, a step past its slot's length repeats the
+    last valid index, and a slot with no key (dead, or of an empty
+    group) holds the index ``steer_dead_slots`` gives it. ``blocks_read``
+    counts those copies (a K and a V block as one; the first grid step
+    always copies), ``blocks_skipped`` the grid steps whose compute did
+    not run, ``hbm_bytes`` the blocks read plus the query and output
+    rows."""
     block = source_block(num_heads, src_length, head_dim, itemsize)
     n_blocks = int(src_length) // block
     block_bytes = 2 * int(num_heads) * block * int(head_dim) * int(itemsize)
-    read = skipped = 0
-    prev = None
-    for g in (int(x) for x in group_of):
-        valid = pages_for(lengths[g], block)
-        for j in range(n_blocks):
-            idx = (g, min(j, max(valid - 1, 0)))
-            read += idx != prev
-            skipped += j >= valid
-            prev = idx
     S = len(group_of)
+    valid = [pages_for(lengths[int(g)], block)
+             if live is None or live[s] else 0
+             for s, g in enumerate(group_of)]
+    first = next((s for s in range(S) if valid[s]), None)
+    read = skipped = 0
+    # the slots ahead of the first one with keys hold ITS first block
+    prev, held = None, (int(group_of[first or 0]), 0)
+    for s, g in enumerate(int(x) for x in group_of):
+        for j in range(n_blocks):
+            idx = (g, min(j, valid[s] - 1)) if valid[s] else held
+            read += idx != prev
+            skipped += j >= valid[s]
+            prev = idx
+        held = prev
     qo_bytes = 2 * S * int(num_heads) * int(n_rows) * int(head_dim) * int(
         itemsize)
     return {

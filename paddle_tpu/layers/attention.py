@@ -308,7 +308,8 @@ def paged_spec_kv_compact(k_pool, v_pool, page_table, pos, path,
 
 
 def grouped_cross_attention(query, k_pool, v_pool, group_of, mask,
-                            sm_scale=None, impl="auto", name=None):
+                            sm_scale=None, impl="auto", name=None,
+                            live=None):
     """Group-indexed cross attention for the paged decode step.
 
     ``query`` [S, H, N, dh] (N = 1 in the step program, the tree's
@@ -320,13 +321,18 @@ def grouped_cross_attention(query, k_pool, v_pool, group_of, mask,
     row; on a TPU the decode kernel reads that row in place by index
     (kernels/cross_attention_decode.py), so N slots decoding
     continuations of one source cost one group's HBM instead of N
-    dense rows. The reference path gathers the rows."""
+    dense rows. The reference path gathers the rows. ``live`` [S, 1]
+    (optional) is nonzero where the slot holds a stream: a dead slot
+    reads and multiplies nothing and its rows come back exactly 0."""
     helper = LayerHelper("grouped_cross_attention", name=name)
     out = helper.create_variable_for_type_inference(query.dtype)
+    inputs = {"Q": [query], "KPool": [k_pool], "VPool": [v_pool],
+              "GroupOf": [group_of], "Mask": [mask]}
+    if live is not None:
+        inputs["Live"] = [live]
     helper.append_op(
         type="grouped_cross_attention",
-        inputs={"Q": [query], "KPool": [k_pool], "VPool": [v_pool],
-                "GroupOf": [group_of], "Mask": [mask]},
+        inputs=inputs,
         outputs={"Out": [out]},
         attrs={"sm_scale": float(sm_scale or 0.0), "impl": impl},
     )

@@ -923,6 +923,20 @@ def _register_member(blk, S, npp, feeds, rows=1):
         srow("pgd_score", feeds[5], "float32")
 
 
+def _live_row(done, ptable, S):
+    """[S, 1] int64, 1 where a slot holds a stream: not ``done`` AND its
+    table row is not the trash row. A release (finish, cancel, rollback)
+    points the whole row at page 0 and leaves ``pgd_done`` as it was,
+    and a slot that holds a stream owns its first page from admission
+    on (page 0 never circulates), so the first entry tells the two
+    apart from state the step program already holds."""
+    nn = fluid.layers
+    one = nn.fill_constant([S, 1], "int64", 1)
+    holds_pages = nn.elementwise_min(
+        nn.slice(ptable, axes=[1], starts=[0], ends=[1]), one)
+    return nn.elementwise_mul(nn.elementwise_sub(one, done), holds_pages)
+
+
 def _build_admit_prog(rows, S, T, D, G, npp, n_layer, n_head, d_inner,
                       src_vocab_size, beam=False):
     """The paged decoder's admission program for ``rows`` sources a
@@ -1319,13 +1333,12 @@ def build_paged_slot_decoder(
             pe_table = pvar("pgd_pe_table", [T, D])
             src_mask = pvar("pgd_src_mask", [G, T])
             # resident tokens per slot AFTER this step's write: pos + 1
-            # for LIVE slots, 0 for done/unoccupied ones — a zero length
-            # makes the ragged kernel skip the slot outright (its logits
-            # are garbage either way: the sampler forces eos on done
-            # slots), so empty slots cost neither FLOPs nor page traffic
-            # and the grid accounting models exactly what the step runs
-            live_row = nn.elementwise_sub(
-                nn.fill_constant([S, 1], "int64", 1), done)
+            # for LIVE slots, 0 for dead ones — a zero length makes the
+            # ragged kernel skip the slot outright (its logits are
+            # garbage either way: nothing reads a dead slot's tokens),
+            # so empty slots cost neither FLOPs nor page traffic and the
+            # grid accounting models exactly what the step runs
+            live_row = _live_row(done, ptable, S)
             lengths = nn.elementwise_mul(
                 fluid.layers.increment(pos, value=1, in_place=False),
                 live_row)
@@ -1381,7 +1394,8 @@ def build_paged_slot_decoder(
                 ctx = fluid.layers.grouped_cross_attention(
                     q2, pvar("pgd_kcross_%d" % i, [G, n_head, T, dh]),
                     pvar("pgd_vcross_%d" % i, [G, n_head, T, dh]),
-                    group_of, src_mask, sm_scale=dh ** -0.5)
+                    group_of, src_mask, sm_scale=dh ** -0.5,
+                    live=live_row)
                 ctx = nn.reshape(nn.transpose(ctx, perm=[0, 2, 1, 3]),
                                  shape=[0, 0, n_head * dh])
                 h = nn.elementwise_add(h, nn.fc(
@@ -1456,8 +1470,7 @@ def build_paged_slot_decoder(
                 group_of = pvar("pgd_group_of", [S, 1], "int64")
                 pe_table = pvar("pgd_pe_table", [T, D])
                 src_mask = pvar("pgd_src_mask", [G, T])
-                live_row = nn.elementwise_sub(
-                    nn.fill_constant([S, 1], "int64", 1), done)
+                live_row = _live_row(done, ptable, S)
                 # the tree kernel's ragged bound: committed storage for
                 # a LIVE slot is [0, pos) and its tree occupies storage
                 # pos .. pos + N - 1; -1 marks a dead slot (zero output
@@ -1528,7 +1541,8 @@ def build_paged_slot_decoder(
                         q2,
                         pvar("pgd_kcross_%d" % i, [G, n_head, T, dh]),
                         pvar("pgd_vcross_%d" % i, [G, n_head, T, dh]),
-                        group_of, src_mask, sm_scale=dh ** -0.5)
+                        group_of, src_mask, sm_scale=dh ** -0.5,
+                        live=live_row)
                     ctx = nn.reshape(
                         nn.transpose(ctx, perm=[0, 2, 1, 3]),
                         shape=[0, 0, n_head * dh])

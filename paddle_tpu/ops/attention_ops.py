@@ -254,8 +254,11 @@ def _lower_grouped_cross_attention(ctx, ins, attrs):
     K/V index maps, so NO per-slot copy of a row exists and N best-of-N
     slots cost one group's HBM; ``Mask`` rows are prefix-valid
     (``sequence_mask``) and reach the kernel as a length a group. The
-    composed reference (``attention_impl`` = ``reference``, and every
-    non-TPU target) gathers the rows — a real copy there."""
+    optional ``Live`` ([S, 1], nonzero where the slot holds a stream)
+    makes a dead slot's length 0: no copy, no product, a result of
+    exactly 0. The composed reference (``attention_impl`` =
+    ``reference``, and every non-TPU target) gathers the rows — a real
+    copy there."""
     from paddle_tpu.kernels.cross_attention_decode import (
         grouped_cross_attention)
 
@@ -272,17 +275,18 @@ def _lower_grouped_cross_attention(ctx, ins, attrs):
         sm_scale=attrs.get("sm_scale", 0.0) or None,
         force_reference=(impl == "reference"),
         force_pallas=(impl == "pallas"),
+        live=ins.get("Live", [None])[0],  # [S, 1] or absent
     )
 
 
 register_op(
     "grouped_cross_attention",
-    inputs=["Q", "KPool", "VPool", "GroupOf", "Mask"],
+    inputs=["Q", "KPool", "VPool", "GroupOf", "Mask", "Live"],
     outputs=["Out"],
     attrs={"sm_scale": 0.0, "impl": "auto"},
     lower=_lower_grouped_cross_attention,
     grad=None,  # decode-only op: no training path attends grouped
-    no_grad_inputs=("GroupOf", "Mask"),
+    no_grad_inputs=("GroupOf", "Mask", "Live"),
     infer_shape=_paged_attention_infer_shape,
 )
 

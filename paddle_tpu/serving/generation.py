@@ -831,8 +831,11 @@ class SlotDecodeSession(object):
         copy, and the grid steps they run, by the kernel's own grid
         rules (kernels/cross_attention_decode.grid_accounting) over the
         device's ``group_of`` and source lengths as the host mirrors
-        them — a released slot keeps its group and is counted, as the
-        kernel reads it. Call under ``tracing.ENABLED``."""
+        them and the slots that hold a stream as the dispatch starts
+        (``_live``, a beam's done hypotheses left out) — a released slot
+        keeps its group and is dead to the kernel, whose step program
+        reads the same from ``pgd_done`` and the slot's table row. Call
+        under ``tracing.ENABLED``."""
         from paddle_tpu.kernels.cross_attention_decode import (
             grid_accounting)
 
@@ -848,8 +851,11 @@ class SlotDecodeSession(object):
                 (np.asarray(scope.get_value("pgd_src_mask")) > 0).sum(
                     axis=-1).astype("int64"))
         group_of, lengths = self._cross_view
+        live = np.zeros(self._S, bool)
+        live[[s for s, st in self._live.items()
+              if not st.get("done")]] = True
         acct = grid_accounting(group_of, lengths, self._n_head, self._T,
-                               self._D // self._n_head)
+                               self._D // self._n_head, live=live)
         _tracing.round_count("cross_blocks_read",
                              calls * acct["blocks_read"])
         _tracing.round_count("cross_blocks_grid",

@@ -344,3 +344,107 @@ def test_a_rolled_back_fork_group_is_repointed_by_one_dispatch(trained):
     assert books[:2] == fresh[:2] and books[3:] == fresh[3:]
     assert sorted(books[2]) == sorted(fresh[2])
     assert sess.pool_conserved and sess._leaked_pages == 0
+
+
+# -- a released slot is dead to the decode step --------------------------------
+
+class _LiveTap(object):
+    """Between a session and its executor: every decode dispatch also
+    fetches the liveness the step program hands its cross-attention
+    kernel (``[steps, S, 1]``, a row a token step)."""
+
+    def __init__(self, exe):
+        self._exe, self.live = exe, []
+
+    def __getattr__(self, name):
+        return getattr(self._exe, name)
+
+    def run_multi_step(self, program, steps, feed=None, fetch_list=None,
+                       scope=None, **kw):
+        names = {op.input("Live")[0] for op in program.global_block().ops
+                 if op.type == "grouped_cross_attention"}
+        (name,) = names   # every layer is handed the one vector
+        out = self._exe.run_multi_step(
+            program, steps, feed=feed, fetch_list=list(fetch_list) + [name],
+            scope=scope, **kw)
+        self.live.append(np.asarray(out[-1])[..., 0])
+        return out[:-1]
+
+
+def _traced_step(sess):
+    """One ``step()`` inside a round of its own: (what it returned, the
+    round's counts)."""
+    from paddle_tpu.observability import tracing
+
+    tracing.enable(True)
+    try:
+        rd = tracing.round_begin()
+        try:
+            done = sess.step()
+        finally:
+            tracing.round_end(rd, keep=False)
+    finally:
+        tracing.enable(False)
+    return done, rd.spans[0]
+
+
+def test_cancelled_slots_are_dead_in_the_next_step(trained):
+    """After ``cancel_many`` the step program reads the cancelled slots
+    as dead (their ``pgd_done`` is still 0: the table row says it), the
+    host's mirror counts the same, nobody else's tokens move, and a
+    re-admitted slot is live again at once."""
+    n, calls = 10, CFG["n_layer"] * 1
+    sess, twin = (_paged(trained, steps=1) for _ in range(2))
+    for s in (sess, twin):
+        assert _admit(s, trained, range(n)) == list(range(n))
+        assert s.step() == {}
+    sess._exe = tap = _LiveTap(sess._exe)
+    _done, root = _traced_step(sess)
+    # every admitted slot live, the never-occupied ones dead: a copy a
+    # live slot (a group each), a grid step a slot, layer by layer
+    np.testing.assert_array_equal(tap.live[-1][0], np.arange(S) < n)
+    assert (root["cross_blocks_read"], root["cross_blocks_grid"]) \
+        == (calls * n, calls * S)
+    twin.step()
+    gone = [2, 5, 7]
+    assert sess.cancel_many(gone) == gone
+    done_flags = np.asarray(sess._scope.get_value("pgd_done"))[:, 0]
+    assert not done_flags[gone].any()   # cancelled, not done: the row
+    assert not _table(sess)[gone].any()
+    got, root = _traced_step(sess)
+    want = twin.step()
+    keep = [s for s in range(n) if s not in gone]
+    np.testing.assert_array_equal(tap.live[-1][0],
+                                  np.isin(np.arange(S), keep))
+    assert (root["cross_blocks_read"], root["cross_blocks_grid"]) \
+        == (calls * len(keep), calls * S)
+    assert sorted(got) == sorted(s for s in want if s not in gone)
+    # a cancelled slot's next occupant is live in the very next step
+    back = _admit(sess, trained, range(n, n + 2))
+    assert back == gone[:2]
+    _traced_step(sess)
+    np.testing.assert_array_equal(
+        tap.live[-1][0], np.isin(np.arange(S), keep + back))
+    # the survivors' streams are those of the run nobody was cancelled in
+    got, want = _finish(sess), _finish(twin)
+    for slot in keep:
+        np.testing.assert_array_equal(got[slot], want[slot])
+    assert sess.pool_conserved and sess.pages_in_use == 0
+
+
+def test_a_finished_slot_is_dead_within_its_dispatch(trained):
+    """Two token steps a dispatch: a stream that ends at the first is
+    dead at the second (``pgd_done``), and after its release by the
+    table row too."""
+    sess = _paged(trained)
+    _admit(sess, trained, range(6))
+    sess._exe = tap = _LiveTap(sess._exe)
+    was_live = set(sess.active_slots)
+    while sess.active_slots:
+        sess.step()
+        first, second = tap.live[-1]
+        assert set(np.flatnonzero(first)) == was_live
+        assert set(np.flatnonzero(second)) <= was_live
+        was_live = set(sess.active_slots)
+        # whoever is still decoding was live at both steps
+        assert was_live <= set(np.flatnonzero(second))

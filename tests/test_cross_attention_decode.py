@@ -6,6 +6,8 @@ decoding the same requests with the kernel and with the reference.
 
 The compile for the chip itself is tests/test_tpu_lowering.py's."""
 
+import itertools
+
 import numpy as np
 import pytest
 
@@ -126,19 +128,119 @@ def test_grid_accounting_against_a_hand_count(monkeypatch):
     acct = cad.grid_accounting(group_of, lengths, H, T, dh)
     block_bytes = 2 * H * 128 * dh * 4
     # steps (group, block index): (0,0) (0,1) | (1,0) (1,0) | (1,0) (1,0)
-    # | (2,0) (2,0) | (3,0) (3,1) | (0,0) (0,1): a copy where it changes
+    # | held (1,0) (1,0) | (3,0) (3,1) | (0,0) (0,1): a copy where it
+    # changes, and the slot of the empty group 2 holds what slot 2 read
     assert acct["block"] == 128 and acct["grid_steps"] == 12
-    assert acct["blocks_read"] == 2 + 1 + 0 + 1 + 2 + 2
+    assert acct["blocks_read"] == 2 + 1 + 0 + 0 + 2 + 2
     # compute skipped: second block of groups 1, 1, and both of group 2
     assert acct["blocks_skipped"] == 1 + 1 + 2
     qo = 2 * 6 * H * dh * 4
-    assert acct["hbm_bytes"] == 8 * block_bytes + qo
+    assert acct["hbm_bytes"] == 7 * block_bytes + qo
     # whole rows: a step a slot, a copy a slot but where a group repeats
+    # and where a slot has no key
     monkeypatch.setattr(cad, "VMEM_BUDGET_BYTES", 4 << 20)
     whole = cad.grid_accounting(group_of, lengths, H, T, dh, n_rows=4)
     assert (whole["block"], whole["grid_steps"], whole["blocks_read"],
-            whole["blocks_skipped"]) == (256, 6, 5, 1)
-    assert whole["hbm_bytes"] == 5 * 2 * block_bytes + 4 * qo
+            whole["blocks_skipped"]) == (256, 6, 4, 1)
+    assert whole["hbm_bytes"] == 4 * 2 * block_bytes + 4 * qo
+
+
+def test_grid_accounting_with_liveness_against_a_hand_count(monkeypatch):
+    """Blocks read = the runs of distinct (group, block) the LIVE slots
+    walk: a dead slot, wherever it stands and whatever its stale group,
+    holds the index of the step before it."""
+    H, T, dh = 8, 256, 64
+    monkeypatch.setattr(cad, "VMEM_BUDGET_BYTES", 128 * 4 * H * 4 * dh)
+    lengths = [130, 5, 256, 200]           # blocks: 2, 1, 2, 2
+    group_of = [2, 0, 1, 1, 3, 1, 0, 2]
+    live = [0, 1, 1, 0, 0, 1, 0, 1]
+    acct = cad.grid_accounting(group_of, lengths, H, T, dh, live=live)
+    # slot 0 (dead, ahead of every live one) holds slot 1's first block
+    # (0,0), the copy slot 1 needs; then (0,0) (0,1) | (1,0) (1,0) |
+    # held | held | (1,0) (1,0): the member of group 1 behind two dead
+    # slots still shares slot 2's copy | held | (2,0) (2,1)
+    assert acct["grid_steps"] == 16
+    assert acct["blocks_read"] == 0 + 2 + 1 + 0 + 0 + 0 + 0 + 2
+    assert acct["blocks_skipped"] == 2 + 0 + 1 + 2 + 2 + 1 + 2 + 0
+    # nobody live: the pipeline's first step copies one block, no more
+    none = cad.grid_accounting(group_of, lengths, H, T, dh, live=[0] * 8)
+    assert (none["blocks_read"], none["blocks_skipped"]) == (1, 16)
+    # everybody live is the count without the input
+    assert cad.grid_accounting(
+        group_of, lengths, H, T, dh, live=[1] * 8) == cad.grid_accounting(
+        group_of, lengths, H, T, dh)
+
+
+def _grid_indices(gof, slot_len, block, n_blocks):
+    """The K/V block index of every grid step, in the grid's order, from
+    the functions the kernel's index map is made of."""
+    gof = jnp.asarray(gof, jnp.int32)
+    slot_len = jnp.asarray(slot_len, jnp.int32)
+    group, lo, hi = cad.steer_dead_slots(gof, slot_len, block)
+    return [(int(group[s]), int(cad.kv_block_index(s, j, lo, hi)))
+            for s in range(len(gof)) for j in range(n_blocks)]
+
+
+# 16 slots over 8 groups: members of one group stand side by side (2 and
+# 3, 9 to 11) and apart (group 6: slots 5 and 13)
+_GROUP_OF = [0, 1, 2, 2, 3, 6, 4, 5, 7, 1, 1, 1, 3, 6, 0, 5]
+_LIVE = {0: [], 1: [10], 8: [1, 2, 3, 6, 9, 10, 13, 15],
+         16: list(range(16))}
+
+
+@pytest.mark.parametrize("dh,T,block", _LAYOUTS,
+                         ids=["source_on_lanes", "row_major"])
+@pytest.mark.parametrize("N", [1, 5])
+@pytest.mark.parametrize("n_live", sorted(_LIVE))
+def test_dead_slots_read_nothing_and_live_slots_read_as_before(
+        monkeypatch, n_live, N, dh, T, block):
+    """With the step's liveness a live slot's rows are bit-equal to the
+    op without it, a dead slot's are exactly 0, every dead slot's K/V
+    index is the index of the grid step before it (no copy), side by
+    side members of a group still share one, and ``grid_accounting``
+    counts the copies the index map makes."""
+    H = 2
+    lane = cad.lanes_hold_source(dh)
+    monkeypatch.setattr(cad, "VMEM_BUDGET_BYTES",
+                        block * 4 * H * 4 * (dh if lane else 128))
+    lengths = [1, block, block + 1, T, 3, T - 1, 2 * block, 7]
+    S, n_blocks = len(_GROUP_OF), T // block
+    rng = np.random.RandomState(100 * n_live + N)
+    q, k, v, gof, mask = _case(rng, S, len(lengths), H, N, T, dh, lengths,
+                               _GROUP_OF)
+    live = np.zeros((S, 1), "int64")
+    live[_LIVE[n_live]] = 1
+    got = np.asarray(cad.grouped_cross_attention(
+        q, k, v, gof, mask, force_pallas=True, live=jnp.asarray(live)))
+    want = np.asarray(cad.grouped_cross_attention(
+        q, k, v, gof, mask, force_pallas=True))
+    alive = live[:, 0] > 0
+    np.testing.assert_array_equal(got[alive], want[alive])
+    assert np.all(got[~alive] == 0.0)
+    np.testing.assert_array_equal(
+        np.asarray(cad.grouped_cross_attention(
+            q, k, v, gof, mask, force_reference=True,
+            live=jnp.asarray(live)))[~alive], 0.0)
+    slot_len = np.where(alive, np.asarray(lengths)[_GROUP_OF], 0)
+    steps = _grid_indices(_GROUP_OF, slot_len, block, n_blocks)
+    for s in np.flatnonzero(~alive):
+        for j in range(n_blocks):
+            at = s * n_blocks + j
+            assert at == 0 or steps[at] == steps[at - 1], (s, j)
+    if n_live == 16:
+        # slots 2 and 3 (three blocks' worth of group 2) walk the same
+        # blocks; slots 9 to 11 (group 1, one block) make ONE copy
+        assert steps[2 * n_blocks:3 * n_blocks] \
+            == steps[3 * n_blocks:4 * n_blocks]
+        assert len(set(steps[9 * n_blocks:12 * n_blocks])) == 1
+    copies = 1 + sum(a != b for a, b in zip(steps, steps[1:]))
+    acct = cad.grid_accounting(_GROUP_OF, lengths, H, T, dh, n_rows=N,
+                               live=live[:, 0])
+    assert acct["block"] == block
+    assert acct["blocks_read"] == copies
+    runs = [x for x, _ in itertools.groupby(
+        steps[at] for at in range(len(steps)) if alive[at // n_blocks])]
+    assert copies == max(len(runs), 1)
 
 
 # -- the session --------------------------------------------------------------

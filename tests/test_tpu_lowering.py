@@ -534,19 +534,86 @@ def test_cross_decode_lowers_for_tpu_and_copies_no_pool(v5e, S, H, N, T, dh):
     args = [jax.ShapeDtypeStruct(shape, dtype,
                                  sharding=SingleDeviceSharding(v5e[0]))
             for shape, dtype in (((S, H, N, dh), F32), pool, pool,
-                                 ((S,), jnp.int32), ((S, T), F32))]
+                                 ((S,), jnp.int32), ((S, T), F32),
+                                 ((S, 1), jnp.int32))]
 
     def traced(*a):
         with _tpu_target():
-            return cad.grouped_cross_attention(*a)
+            return cad.grouped_cross_attention(*a[:5], live=a[5])
 
     compiled = jax.jit(traced).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "%" + cad.CROSS_DECODE_KERNEL_NAME + "." in text
     assert pa.PAGED_KERNEL_NAME not in text
+    _assert_moves_no_pool(text, S * H * T * dh)
     pool_bytes = S * H * T * dh * 4
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+def test_cross_decode_index_of_a_dead_slot_is_the_step_before_it():
+    """What saves a dead slot's copy, at the served grid (256 slots, one
+    block a slot) with 8 live: the pipeline copies a block only where the
+    K/V index differs from the grid step before, and the index the
+    kernel's map gives a dead slot, from the prefetch vectors the op
+    computes, never does."""
+    from paddle_tpu.kernels import cross_attention_decode as cad
+
+    S, H, T, dh = 256, 8, 256, 64
+    block = cad.source_block(H, T, dh)
+    rng = np.random.RandomState(47)
+    group_of = rng.permutation(S).astype("int32")   # stale groups too
+    live = np.zeros(S, bool)
+    live[rng.choice(S, 8, replace=False)] = True
+    slot_len = np.where(live, rng.randint(1, T + 1, S), 0).astype("int32")
+    group, lo, hi = cad.steer_dead_slots(
+        jnp.asarray(group_of), jnp.asarray(slot_len), block)
+    index = [(int(group[s]), int(cad.kv_block_index(s, j, lo, hi)))
+             for s in range(S) for j in range(T // block)]
+    first = int(np.flatnonzero(live)[0])
+    for s in range(S):
+        if live[s]:
+            assert index[s] == (group_of[s], 0)     # its own row
+        else:
+            assert index[s] == (index[s - 1] if s else
+                                (group_of[first], 0))
+    assert 1 + sum(a != b for a, b in zip(index, index[1:])) == 8
+
+
+def test_transformer_step_program_steers_once_and_copies_no_cross_pool(v5e):
+    """The paged session's step program as the executor builds it, at the
+    served widths (perfbench transformer_base; two layers of its six),
+    four token steps a dispatch: both kernels are in it, no cross (or
+    page) pool is copied, transposed or gathered, and every layer's
+    cross-attention call of a token step takes the SAME four prefetch
+    vectors (group, first and last block, length times liveness): the
+    steering is computed once a step, not once a layer."""
+    import re
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import _described_compile as dc
+    from paddle_tpu.kernels import cross_attention_decode as cad
+    from paddle_tpu.models import transformer
+
+    S, T, D, H, V, L = 256, 256, 512, 8, 32000, 2
+    step, fetch = transformer.build_paged_slot_decoder(
+        S, src_vocab_size=V, trg_vocab_size=V, max_length=T, n_layer=L,
+        n_head=H, d_model=D, d_inner=2048, page_size=16)[5:7]
+    state = {name: (var.shape, var.dtype)
+             for name, var in step.global_block().vars.items()
+             if var.persistable}
+    with _tpu_target():
+        text = dc.compile_program(step, v5e[0], state, {}, [fetch],
+                                  steps=4).as_text()
+    assert cad.CROSS_DECODE_KERNEL_NAME in text
+    assert pa.PAGED_KERNEL_NAME in text
+    _assert_moves_no_pool(text, S * H * T * (D // H))
+    calls = re.findall(
+        r"= f32\[%d,%d,1,%d\]\S* custom-call\(([^)]*)\), "
+        r"custom_call_target=\"tpu_custom_call\"" % (S, H, D // H), text)
+    assert len(calls) == L, len(calls)
+    assert len({tuple(c.split(", ")[:4]) for c in calls}) == 1, calls
 
 
 # the latent-attention decoder's serving cell (perfbench glm47_flash_6l):
