@@ -240,8 +240,8 @@ def _bench_transformer(fluid, on_tpu, use_amp):
     bs = int(os.environ.get("BENCH_BS", bs))  # batch-sweep override
     seq = int(os.environ.get("BENCH_SEQ", seq))
     # vocab override: lets the CPU proxy run the real 32k vocab head at
-    # small bs/seq, which is where the CE-head lever (FLAGS_fused_ce)
-    # lives — the default 500-vocab proxy is insensitive to it
+    # small bs/seq — the default 500-vocab proxy is insensitive to what
+    # the loss head costs
     vocab = int(os.environ.get("BENCH_VOCAB", vocab))
     # depth override: MFU stays a valid per-model measurement since the
     # FLOP accounting below scales with n_layer

@@ -25,8 +25,8 @@ import hashlib
 # Flags whose value changes what the block lowers TO (not just runtime
 # behavior); they join the executable cache key so toggling recompiles.
 # flash_backward is read inside the flash-attention custom_vjp at trace
-# time; build-time flags (fused_ce) already show up in the program
-# structure and need no entry here.
+# time. A choice made while a program is BUILT shows up in the program's
+# structure and needs no entry here.
 TRACE_FLAGS = ("use_pallas_lstm", "use_pallas_gru", "remat_gradients",
                "conv_nhwc", "attention_impl", "flash_backward",
                "paged_attention", "tree_attention")

@@ -169,13 +169,6 @@ _DEFS = {
     # reference on CPU), "pallas" (force the kernel — interpret mode on
     # CPU, the test path), "reference" (force the composed path)
     "tree_attention": ("auto", str),
-    # route the transformer's label-smoothed CE head through the fused
-    # single-pass op (ops/loss_ops.py fused_label_smooth_ce): bf16
-    # logits with f32-accumulated reductions, hand-written one-pass
-    # backward. MFU lever #1 (the composed head moves ~10 GB/step of
-    # f32 logits-shaped traffic at bench shapes, per the cost model);
-    # opt-in until the chip A/B (watcher leg transformer-ce-fused) lands
-    "fused_ce": (False, bool),
     # request-scoped distributed tracing across the serving plane
     # (observability/tracing.py): ServingClient mints a trace id that
     # rides the JSON-lines envelope; frontend + decode session record

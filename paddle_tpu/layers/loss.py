@@ -57,14 +57,14 @@ def softmax_with_cross_entropy(
 
 
 def fused_label_smooth_ce(logits, label, epsilon=0.0, name=None):
-    """Label-smoothed cross entropy in ONE fused pass over the vocab dim
+    """Label-smoothed cross entropy over the vocab dim
     (ops/loss_ops.py fused_label_smooth_ce): factored smoothing — no
     soft-label tensor, no second log-softmax pass — with the logits kept
-    in their network dtype (bf16 under AMP) and f32-accumulated
-    reductions. Returns f32 [N, 1] loss. The MFU lever-#1 form of the
-    composed softmax_with_cross_entropy + log_softmax head; enable in
-    the bundled transformer with
-    FLAGS_fused_ce=1."""
+    in their network dtype (bf16 under AMP) and f32 arithmetic inside
+    each pass. Returns f32 [N, 1] loss. The bundled transformer's head:
+    on a v5e its training step read 264.40 ms against 270.19 with the
+    head composed of softmax_with_cross_entropy + log_softmax (PERF.md
+    section 6, PR 48)."""
     helper = LayerHelper("fused_label_smooth_ce", name=name)
     loss = helper.create_variable_for_type_inference("float32")
     helper.append_op(

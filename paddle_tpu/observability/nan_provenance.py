@@ -53,6 +53,8 @@ _HINTS = {
     "softmax_with_cross_entropy": "extreme logits — clip logits, lower "
                                   "the learning rate, or enable loss "
                                   "scaling under AMP",
+    "fused_label_smooth_ce": "extreme logits — clip logits or lower the "
+                             "learning rate",
 }
 _DEFAULT_HINT = ("inspect this op's inputs at the reported step; common "
                  "fixes: gradient clipping, a lower learning rate, epsilon "

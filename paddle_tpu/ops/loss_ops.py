@@ -17,7 +17,25 @@ def _label_to_int(label):
     return label.astype(jnp.int32)
 
 
+def _pick_label(x, lbl):
+    """``x[..., lbl]`` as ``[..., 1]`` float32, by a masked sum over the
+    class axis and not a gather. XLA fuses no gather's operand: under
+    ``take_along_axis`` the whole ``[tokens, classes]`` operand is stored
+    first (in float32, behind an upcast), where the mask and the sum fuse
+    into whichever pass already reads ``x``. A sum of one value and zeros
+    is that value, and its gradient is the one-hot scatter the gather's
+    gradient is. A label outside ``[0, classes)`` matches no class: 0."""
+    classes = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    picked = jnp.where(classes == lbl[..., None], x.astype(jnp.float32), 0.0)
+    return jnp.sum(picked, axis=-1, keepdims=True)
+
+
 def _lower_softmax_xent(ctx, ins, attrs):
+    """Hard labels pick their log-probability with ``_pick_label``. A
+    label outside ``[0, classes)`` (the default ``ignore_index=-100`` fed
+    as a label) gives a loss and a gradient of zero, the reference's
+    meaning of an ignored label (a gather would wrap round to another
+    class); ``ignore_index >= 0`` names one class to ignore besides."""
     logits, label = ins["Logits"][0], ins["Label"][0]
     lse = jax.scipy.special.logsumexp(logits, axis=-1, keepdims=True)
     log_softmax = logits - lse
@@ -25,7 +43,7 @@ def _lower_softmax_xent(ctx, ins, attrs):
         loss = -jnp.sum(label * log_softmax, axis=-1, keepdims=True)
     else:
         lbl = _label_to_int(label)
-        nll = -jnp.take_along_axis(log_softmax, lbl[..., None], axis=-1)
+        nll = -_pick_label(log_softmax, lbl).astype(log_softmax.dtype)
         ignore = attrs.get("ignore_index", -100)
         if ignore >= 0:
             nll = jnp.where((lbl == ignore)[..., None], jnp.zeros_like(nll), nll)
@@ -45,27 +63,30 @@ register_op(
 
 
 def _lower_fused_label_smooth_ce(ctx, ins, attrs):
-    """Single-pass label-smoothed cross entropy over the vocab dim.
-
-    The composed head (softmax_with_cross_entropy + log_softmax +
-    scale/add, models/transformer.py) makes ~5 logits-shaped passes and
-    — because those ops are AMP-blacklisted — materializes them in f32:
-    ~10 GB/step of HBM traffic at bench shapes (predicted by
-    tools/hlo_cost_model.py; not measured on the chip). This op keeps the
-    logits in their network dtype (bf16 under AMP) and uses the
-    factored identity
+    """Label-smoothed cross entropy over the vocab dim, the Transformer's
+    loss head (models/transformer.py). The logits stay in their network
+    dtype (bf16 under AMP: the op is on neither AMP list) and the loss is
+    the factored identity
 
         L = lse - (1-eps) * x_y - (eps/V) * sum_i x_i
 
     so the smoothing term needs only sum(x) — no second log-softmax
-    pass — with every reduction f32-accumulated (fused into one pass by
-    XLA; no f32 logits-shaped tensor exists). The hand-written backward
-    is the single fused expression
+    pass — with every reduction in f32 and x_y a masked sum
+    (``_pick_label``). The hand-written backward is the single expression
 
         dL/dx_i = (softmax_i - eps/V - (1-eps) * 1[i=y]) * g
 
-    (exact: d lse = softmax, d x_y = onehot, d sum = 1). One bf16
-    [N, V] write instead of the composed head's f32 chain.
+    (exact: d lse = softmax, d x_y = onehot, d sum = 1), which XLA fuses
+    into the operand of the two products that consume it.
+
+    Measured on a v5e (PERF.md section 6, PR 48; `train_big_1chip`,
+    16384 tokens x 32000 classes a step, three pairs): against the head
+    composed of softmax_with_cross_entropy + log_softmax + scale/add, both
+    with the masked pick, the step is 264.40 ms against 270.19 (the
+    composed head's d(logits) costs the two products 3.4 ms more and its
+    row passes 2.4 ms); neither stores anything of the logits' size but
+    the bf16 logits. With the gather the composed head stored two f32
+    copies, 275.19 ms.
 
     Reference capability anchor: softmax_with_cross_entropy_op.cc +
     label_smooth_op.cc composed; the fusion itself is TPU-motivated.
@@ -76,23 +97,24 @@ def _lower_fused_label_smooth_ce(ctx, ins, attrs):
     lbl = _label_to_int(label)
 
     def fwd(x, l):
-        m = jnp.max(x, axis=-1, keepdims=True)
-        s = jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True,
-                    dtype=jnp.float32)
-        lse = m.astype(jnp.float32) + jnp.log(s)
-        xy = jnp.take_along_axis(x, l[..., None], axis=-1)
-        sumx = jnp.sum(x, axis=-1, keepdims=True, dtype=jnp.float32)
-        loss = (lse - (1.0 - eps) * xy.astype(jnp.float32)
-                - (eps / vocab) * sumx)
+        # float32 inside each pass whatever the logits' dtype (the cast
+        # fuses into the reader): bfloat16 logits lose nothing here, and
+        # dx is rounded once, at its store
+        xf = x.astype(jnp.float32)
+        m = jnp.max(xf, axis=-1, keepdims=True)
+        s = jnp.sum(jnp.exp(xf - m), axis=-1, keepdims=True)
+        lse = m + jnp.log(s)
+        sumx = jnp.sum(xf, axis=-1, keepdims=True)
+        loss = lse - (1.0 - eps) * _pick_label(x, l) - (eps / vocab) * sumx
         return loss, (x, l, m, s)
 
     def bwd(res, g):
         x, l, m, s = res
-        softmax = jnp.exp(x - m) / s.astype(x.dtype)
-        onehot = jax.nn.one_hot(l, vocab, dtype=x.dtype)
+        softmax = jnp.exp(x.astype(jnp.float32) - m) / s
+        onehot = jax.nn.one_hot(l, vocab, dtype=jnp.float32)
         dx = (softmax - eps / vocab - (1.0 - eps) * onehot) \
-            * g.astype(x.dtype)
-        return (dx, None)
+            * g.astype(jnp.float32)
+        return (dx.astype(x.dtype), None)
 
     f = jax.custom_vjp(lambda x, l: fwd(x, l)[0])
     f.defvjp(fwd, bwd)

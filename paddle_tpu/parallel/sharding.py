@@ -527,7 +527,8 @@ class _Deriver(object):
                 self._tag_out(op, batch=batch, tp=False)
             elif t in ("mean", "reduce_sum", "reduce_mean", "reduce_max",
                        "reduce_min", "cross_entropy",
-                       "softmax_with_cross_entropy", "accuracy",
+                       "softmax_with_cross_entropy",
+                       "fused_label_smooth_ce", "accuracy",
                        "square_error_cost", "sum", "top_k", "arg_max",
                        "fetch"):
                 self._maybe_reshard(op, op_idx)

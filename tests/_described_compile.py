@@ -1,8 +1,9 @@
 """Compile a serving builder's programs for a DESCRIBED v5e (no chip):
 what ``Executor`` would build for a program placed on that device
 (``CompiledProgram`` / ``MultiStepProgram``), lowered from shapes alone.
-Used by ``tests/test_sparse_latent_decoder.py`` at small sizes and by a
-builder's scratch script at the served ones."""
+Used by ``tests/test_sparse_latent_decoder.py`` at small sizes, by
+``tests/test_tpu_lowering.py`` (the trainer's loss head among them) and by
+a builder's scratch script at the served ones."""
 
 import jax
 import jax.numpy as jnp
@@ -13,9 +14,11 @@ from paddle_tpu.core import lowering
 from paddle_tpu.core.types import np_dtype
 
 
-def compile_program(program, device, state, feeds, fetches, steps=None):
+def compile_program(program, device, state, feeds, fetches, steps=None,
+                    is_test=True):
     """``state`` / ``feeds``: {name: (shape, dtype)}. Returns the compiled
-    executable (``as_text()``, ``memory_analysis()``)."""
+    executable (``as_text()``, ``memory_analysis()``). ``is_test=False``
+    for a training program (dropout draws, the optimizer's updates)."""
     sharding = SingleDeviceSharding(device)
 
     def spec(shape, dtype):
@@ -29,11 +32,11 @@ def compile_program(program, device, state, feeds, fetches, steps=None):
     feed_specs = {n: (tuple(s), narrow(d)) for n, (s, d) in feeds.items()}
     if steps:
         cp = lowering.MultiStepProgram(
-            program, steps, feed_specs, fetches, list(state), is_test=True,
-            device=device, stack_fetches=True)
+            program, steps, feed_specs, fetches, list(state),
+            is_test=is_test, device=device, stack_fetches=True)
     else:
         cp = lowering.CompiledProgram(
-            program, feed_specs, fetches, list(state), is_test=True,
+            program, feed_specs, fetches, list(state), is_test=is_test,
             device=device)
     mut = {n: spec(state[n][0], narrow(state[n][1]))
            for n in cp.mutable_state}

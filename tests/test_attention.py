@@ -410,7 +410,10 @@ def test_transformer_generates_after_training():
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup)
     rng = np.random.RandomState(7)
-    for _ in range(140):
+    # 300 steps: at 140 the loss is still ~0.4 and the accuracy below
+    # swings 0.79-0.96 with the seed and with the last float32 place of
+    # the loss head; from 300 it reads 0.96-1.0 over seeds 6-8
+    for _ in range(300):
         batch = _copy_task_batch(rng, 16, seq, vocab)
         exe.run(main, feed=batch, fetch_list=[loss])
 

@@ -1,13 +1,29 @@
-"""fused_label_smooth_ce: the MFU lever-#1 op must be
-algebraically identical to the composed head it replaces
-(softmax_with_cross_entropy + log_softmax smoothing term,
-models/transformer.py), in loss AND in gradients."""
+"""fused_label_smooth_ce, the Transformer's loss head, must be
+algebraically identical to the head composed from
+softmax_with_cross_entropy + a log_softmax smoothing term (what
+models/transformer.py built before the chip settled the two, PERF.md
+section 6, PR 48), in loss AND in gradients."""
 
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import flags
+
+
+def _composed_cost(logits, label, eps, v):
+    """The per-row loss ``transformer.build`` composed before PR 48, from
+    ``softmax_with_cross_entropy`` and a ``log_softmax`` smoothing term."""
+    cost = fluid.layers.softmax_with_cross_entropy(logits, label)
+    if eps:
+        neg_sum_logp = fluid.layers.scale(
+            fluid.layers.reduce_sum(
+                fluid.layers.log_softmax(logits), dim=-1, keep_dim=True),
+            scale=-1.0)
+        cost = fluid.layers.elementwise_add(
+            fluid.layers.scale(cost, scale=1.0 - eps),
+            fluid.layers.scale(neg_sum_logp, scale=eps / v))
+    return cost
 
 
 def _build_head(fused, eps, n, v, seed):
@@ -25,16 +41,7 @@ def _build_head(fused, eps, n, v, seed):
             cost = fluid.layers.fused_label_smooth_ce(
                 logits, label, epsilon=eps)
         else:
-            cost = fluid.layers.softmax_with_cross_entropy(logits, label)
-            if eps:
-                neg_sum_logp = fluid.layers.scale(
-                    fluid.layers.reduce_sum(
-                        fluid.layers.log_softmax(logits), dim=-1,
-                        keep_dim=True),
-                    scale=-1.0)
-                cost = fluid.layers.elementwise_add(
-                    fluid.layers.scale(cost, scale=1.0 - eps),
-                    fluid.layers.scale(neg_sum_logp, scale=eps / v))
+            cost = _composed_cost(logits, label, eps, v)
         loss = fluid.layers.mean(cost)
         fluid.optimizer.SGD(learning_rate=0.5).minimize(loss)
     return main, startup, loss
@@ -99,25 +106,22 @@ def test_fused_ce_grad_formula():
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
 
 
-def test_fused_ce_flag_switches_transformer_head():
+def test_transformer_head_is_the_fused_op():
+    """``transformer.build`` has one head (the chip settled it, PERF.md
+    section 6, PR 48): no flag, no keyword and no composed branch."""
     from paddle_tpu.models import transformer
 
-    def ops_of(prog):
-        return {op.type for op in prog.global_block().ops}
-
-    old = flags.get("fused_ce")
-    try:
-        flags.set_flag("fused_ce", True)
-        with fluid.scope_guard(fluid.executor.Scope()):
-            main, startup = fluid.Program(), fluid.Program()
-            with fluid.program_guard(main, startup):
-                transformer.build(src_vocab_size=40, trg_vocab_size=40,
-                                  max_length=8, n_layer=1, n_head=2,
-                                  d_model=16, d_inner=32, dropout=0.0)
-            assert "fused_label_smooth_ce" in ops_of(main)
-            assert "log_softmax" not in ops_of(main)
-    finally:
-        flags.set_flag("fused_ce", old)
+    with fluid.scope_guard(fluid.executor.Scope()):
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            transformer.build(src_vocab_size=40, trg_vocab_size=40,
+                              max_length=8, n_layer=1, n_head=2,
+                              d_model=16, d_inner=32, dropout=0.0)
+    ops = {op.type for op in main.global_block().ops}
+    assert "fused_label_smooth_ce" in ops
+    assert not ops & {"log_softmax", "softmax_with_cross_entropy"}
+    with pytest.raises(KeyError):
+        flags.get("fused_ce")
 
 
 def test_fused_ce_bf16_logits_stay_bf16():
@@ -142,55 +146,79 @@ def test_fused_ce_bf16_logits_stay_bf16():
     assert out["Loss"].dtype == jnp.float32
 
 
-def test_fused_ce_full_transformer_trajectory():
-    """End to end on the real model: transformer.build under
-    FLAGS_fused_ce must produce the same 3-step loss trajectory as the
-    composed head (same seeds, same feeds) — pins the model wiring, not
-    just the op."""
+def _composed_head(logits, label, trg_len, eps, vocab, max_length):
+    """``_composed_cost`` on the Transformer's logits, masked and averaged
+    over the real target positions as ``transformer.build`` does."""
+    cost = _composed_cost(
+        fluid.layers.reshape(logits, shape=[-1, vocab]),
+        fluid.layers.reshape(label, shape=[-1, 1]), eps, vocab)
+    mask = fluid.layers.sequence_mask(trg_len, maxlen=max_length,
+                                      dtype="float32")
+    cost = fluid.layers.reshape(cost, shape=[-1, max_length])
+    return fluid.layers.elementwise_div(
+        fluid.layers.reduce_sum(fluid.layers.elementwise_mul(cost, mask)),
+        fluid.layers.reduce_sum(mask))
+
+
+def _tiny_transformer_losses(composed, trg_len):
+    """Three SGD steps of the tiny Transformer on seeded feeds: the losses
+    of ``transformer.build``'s own head, or of ``_composed_head`` on its
+    logits."""
     from paddle_tpu.models import transformer
 
-    def run(fused):
-        old = flags.get("fused_ce")
-        flags.set_flag("fused_ce", fused)
-        try:
-            fluid.unique_name.switch()
-            with fluid.scope_guard(fluid.executor.Scope()):
-                main, startup = fluid.Program(), fluid.Program()
-                main.random_seed = startup.random_seed = 11
-                with fluid.program_guard(main, startup):
-                    loss, feeds, _ = transformer.build(
-                        src_vocab_size=60, trg_vocab_size=60,
-                        max_length=8, n_layer=1, n_head=2, d_model=16,
-                        d_inner=32, dropout=0.0)
-                    fluid.optimizer.SGD(learning_rate=0.5).minimize(loss)
-                # the flag must actually switch the head, or the A/B
-                # below compares the composed head against itself
-                has_fused = any(op.type == "fused_label_smooth_ce"
-                                for op in main.global_block().ops)
-                assert has_fused == fused, (
-                    "FLAGS_fused_ce plumbing broken: fused=%r but "
-                    "program has_fused=%r" % (fused, has_fused))
-                exe = fluid.Executor(fluid.CPUPlace())
-                exe.run(startup)
-                rng = np.random.RandomState(3)
-                losses = []
-                for _ in range(3):
-                    feed = {
-                        "src_word": rng.randint(1, 60, (2, 8)).astype("int64"),
-                        "src_len": np.full((2, 1), 8, "int64"),
-                        "trg_word": rng.randint(1, 60, (2, 8)).astype("int64"),
-                        "trg_len": np.full((2, 1), 8, "int64"),
-                        "label": rng.randint(1, 60, (2, 8)).astype("int64"),
-                    }
-                    (lv,) = exe.run(main, feed=feed, fetch_list=[loss])
-                    losses.append(float(np.ravel(lv)[0]))
-            return losses
-        finally:
-            flags.set_flag("fused_ce", old)
+    fluid.unique_name.switch()
+    with fluid.scope_guard(fluid.executor.Scope()):
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 11
+        with fluid.program_guard(main, startup):
+            loss, feeds, extras = transformer.build(
+                src_vocab_size=60, trg_vocab_size=60, max_length=8,
+                n_layer=1, n_head=2, d_model=16, d_inner=32, dropout=0.0)
+            if composed:
+                by_name = {v.name: v for v in feeds}
+                loss = _composed_head(
+                    extras["logits"], by_name["label"], by_name["trg_len"],
+                    0.1, 60, 8)
+            fluid.optimizer.SGD(learning_rate=0.5).minimize(loss)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        rng = np.random.RandomState(3)
+        losses = []
+        for _ in range(3):
+            feed = {
+                "src_word": rng.randint(1, 60, (2, 8)).astype("int64"),
+                "src_len": np.full((2, 1), 8, "int64"),
+                "trg_word": rng.randint(1, 60, (2, 8)).astype("int64"),
+                "trg_len": np.asarray(trg_len, "int64").reshape(2, 1),
+                "label": rng.randint(1, 60, (2, 8)).astype("int64"),
+            }
+            (lv,) = exe.run(main, feed=feed, fetch_list=[loss])
+            losses.append(float(np.ravel(lv)[0]))
+    return losses
 
-    ref = run(False)
-    fused = run(True)
-    np.testing.assert_allclose(fused, ref, rtol=1e-5, atol=1e-6,
-                               err_msg="full-model fused-CE trajectory "
-                                       "diverged from the composed head")
-    assert ref[-1] < ref[0], "training did not reduce the loss"
+
+def test_transformer_head_matches_a_composed_head_trajectory():
+    """End to end on the real model: ``transformer.build``'s loss gives
+    the same 3-step trajectory as a head composed here on its logits from
+    ``softmax_with_cross_entropy`` and ``log_softmax`` (same seeds, same
+    feeds; padded targets, so the mask is exercised) — pins the model
+    wiring, not just the op."""
+    ref = _tiny_transformer_losses(composed=True, trg_len=[8, 5])
+    built = _tiny_transformer_losses(composed=False, trg_len=[8, 5])
+    np.testing.assert_allclose(built, ref, rtol=1e-5, atol=1e-6,
+                               err_msg="transformer.build's head diverged "
+                                       "from the composed head")
+
+
+def test_tiny_transformer_trajectory_is_the_one_before_pr_48():
+    """Three SGD steps of the tiny model, full-length targets, give the
+    losses the tree before PR 48 gave with its composed head and its
+    gather (read there on this backend: 4.332022190093994,
+    4.37652063369751, 4.219750881195068; the fused head with the masked
+    sum reads them to the last float32 place: ...2667, ...1110, the third
+    equal)."""
+    losses = _tiny_transformer_losses(composed=False, trg_len=[8, 8])
+    np.testing.assert_allclose(
+        losses, [4.332022190093994, 4.37652063369751, 4.219750881195068],
+        rtol=1e-6)
+    assert losses[-1] < losses[0], "training did not reduce the loss"
