@@ -10,6 +10,7 @@ XLA's job; gradient ops re-trace forward rules under jax.vjp and XLA CSE
 dedups the recompute.
 """
 
+import contextlib
 import threading
 
 import numpy as np
@@ -138,7 +139,11 @@ class BlockLowerer(object):
             is_test=self.is_test or op.attrs.get("is_test", False),
             block_lowerer=self,
         )
-        outs = normalize_outputs(opdef, opdef.lower(ctx, ins, op.attrs))
+        # built under ``fluid.name_scope``: the instructions this op lowers
+        # to carry the scope in their ``op_name``
+        scope = op.attrs.get("op_namescope")
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            outs = normalize_outputs(opdef, opdef.lower(ctx, ins, op.attrs))
         for slot, arrs in outs.items():
             names = op.output(slot)
             for name, val in zip(names, arrs):

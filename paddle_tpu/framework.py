@@ -159,6 +159,13 @@ class Operator(object):
             self.attrs[OP_ROLE_VAR_ATTR_NAME] = list(prog._op_role_var)
         if "__rng_id__" not in self.attrs:
             self.attrs["__rng_id__"] = prog._next_rng_id()
+        if _name_scope_stack and OP_NAMESCOPE_ATTR_NAME not in self.attrs:
+            # an op built under ``name_scope`` says so (the reference's
+            # ``op_namescope``); its lowering runs under a
+            # ``jax.named_scope`` of that name (core/lowering.py), so a
+            # compiled program's instructions say which sub-block they are
+            self.attrs[OP_NAMESCOPE_ATTR_NAME] = "/".join(
+                s for s in _name_scope_stack if s)
 
     def input_arg_names(self):
         return [n for ns in self.inputs.values() for n in ns]
@@ -641,6 +648,7 @@ def program_guard(main_program, startup_program=None):
 
 
 _name_scope_stack = []
+OP_NAMESCOPE_ATTR_NAME = "op_namescope"
 
 
 @contextlib.contextmanager

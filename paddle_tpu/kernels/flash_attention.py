@@ -159,7 +159,8 @@ def _is_tpu_target():
 
 def flash_attention_reference(q, k, v, causal=False, sm_scale=None,
                               mask=None):
-    """XLA reference path. q:[B,H,T,d] k,v:[B,H,S,d]; mask:[B,1|H,T,S]."""
+    """XLA reference path. q:[B,H,T,d] k:[B,H,S,d] v:[B,H,S,dv];
+    mask:[B,1|H,T,S]."""
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum(
@@ -392,6 +393,11 @@ def _flash_forward(q, k, v, kv_mask, causal, sm_scale, block_q, block_k,
 
     B, H, T, d = q.shape
     S = k.shape[2]
+    # the value width is the values' own (latent attention: queries and
+    # keys of 192 beside values of 128); the second product, the
+    # accumulator and the output are that wide. The tiles are chosen by
+    # the query width, the larger of the two where they differ
+    dv = v.shape[-1]
     # grouped-query attention: K/V carry H // kv_group heads and each
     # serves kv_group query heads THROUGH THE INDEX MAP — the repeated
     # K/V never materializes (a custom call can't fold a broadcast
@@ -432,15 +438,18 @@ def _flash_forward(q, k, v, kv_mask, causal, sm_scale, block_q, block_k,
     q_spec = pl.BlockSpec((1, hb, block_q, d), lambda b, h, i, j: (b, h, i, 0))
     # the kv head (block) of query-head block h: itself, or the ONE head
     # its hb heads share
-    kv_spec = pl.BlockSpec(
-        (1, kvb, block_k, d),
-        (lambda b, h, i, j: (b, h, j, 0)) if g == 1
-        else (lambda b, h, i, j: (b, h * hb // g, j, 0)))
+    kv_map = ((lambda b, h, i, j: (b, h, j, 0)) if g == 1
+              else (lambda b, h, i, j: (b, h * hb // g, j, 0)))
+    kv_spec = pl.BlockSpec((1, kvb, block_k, d), kv_map)
+    v_spec = kv_spec if dv == d else pl.BlockSpec(
+        (1, kvb, block_k, dv), kv_map)
+    o_spec = q_spec if dv == d else pl.BlockSpec(
+        (1, hb, block_q, dv), lambda b, h, i, j: (b, h, i, 0))
     out = pl.pallas_call(
         kernel,
         grid=(B, H // hb, Tp // block_q, n_kv),
         in_specs=[
-            q_spec, kv_spec, kv_spec,
+            q_spec, kv_spec, v_spec,
             pl.BlockSpec(
                 (1, 1, block_k),
                 (lambda b, h, i, j: (b, 0, j)) if has_mask
@@ -448,17 +457,17 @@ def _flash_forward(q, k, v, kv_mask, causal, sm_scale, block_q, block_k,
             ),
         ],
         out_specs=[
-            q_spec,
+            o_spec,
             pl.BlockSpec(
                 (1, hb, 1, block_q), lambda b, h, i, j: (b, h, 0, i)
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Tp, d), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Tp, dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, 1, Tp), jnp.float32),
         ],
         scratch_shapes=[] if n_kv == 1 else [
-            pltpu.VMEM((hb, block_q, d), jnp.float32),
+            pltpu.VMEM((hb, block_q, dv), jnp.float32),
             pltpu.VMEM((hb, block_q, 1), jnp.float32),
             pltpu.VMEM((hb, block_q, 1), jnp.float32),
         ],
@@ -749,6 +758,11 @@ def _flash(q, k, v, kv_mask, has_mask, causal, sm_scale, block_q, block_k,
 
 def _flash_fwd(q, k, v, kv_mask, has_mask, causal, sm_scale, block_q,
                block_k, interpret, kv_group=1, window=0):
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            "flash_attention backward: the dK/dV and dQ kernels run at one "
+            "head width; values of %d beside queries of %d are served "
+            "forward only" % (v.shape[-1], q.shape[-1]))
     out, lse = _flash_forward(q, k, v, kv_mask if has_mask else None,
                               causal, sm_scale, block_q, block_k, interpret,
                               kv_group=kv_group, window=window)
@@ -829,7 +843,11 @@ def flash_attention(
     kv_group=1,
     window=0,
 ):
-    """Fused attention. q:[B,H,T,d], k,v:[B,H,S,d] -> [B,H,T,d].
+    """Fused attention. q:[B,H,T,d], k:[B,H,S,d], v:[B,H,S,dv] ->
+    [B,H,T,dv]. ``dv`` may differ from ``d`` in the forward (latent
+    attention's 192-wide queries and keys beside 128-wide values: the
+    second product and the output are ``dv`` wide, nothing is padded);
+    the backward kernels refuse that by name.
 
     ``kv_group`` > 1 is grouped-query attention: k/v carry H/kv_group
     heads, each serving kv_group query heads through the kernel's index

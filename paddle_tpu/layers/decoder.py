@@ -66,13 +66,23 @@ def dense_projection(x, w, out_dtype="input", name=None):
 def dropless_moe_ffn(x, router_w, router_bias, expert_w_gate, expert_w_up,
                      expert_w_down, shared=None, valid=None, top_k=1,
                      norm_topk=True, scale=1.0, held_first=None, name=None,
-                     scoring=None):
+                     scoring=None, zero_experts=0, token_block=None):
     """Routed experts with no capacity and no dropped token: sigmoid
     scores plus a selection bias, the ``top_k`` largest chosen, the
     (token, expert) pairs sorted by expert and computed as grouped matrix
     products; ``shared`` = (gate, up, down) of a shared expert added once.
     ``scoring="softmax_topk"`` is the second rule: the ``top_k`` largest
-    raw logits, a softmax over those alone, ``router_bias`` None.
+    raw logits, a softmax over those alone, ``router_bias`` None;
+    ``scoring="softmax"`` the third: a softmax over ALL the router's
+    outputs, the ``top_k`` largest of ``p + router_bias`` chosen, the
+    weights ``scale * p`` of the chosen, not renormalised. With
+    ``zero_experts`` = Z the router's last Z outputs are zero-compute
+    (identity) experts: a choice that falls on one adds ``weight * x``
+    and has no expert's products, held shard or not, and a fourth value
+    is returned, the valid tokens' choices that fell on one ``[1]``.
+    ``token_block``: the tokens a block in which a held shard's large
+    dispatch goes through the experts (the op's 2048 when None; the rows
+    sorted for a block are ``token_block * top_k``).
     ``valid`` [N] marks the tokens that exist (others are neither computed
     nor counted). With ``held_first`` the expert weights are a shard of
     the router's experts that starts there: the pairs of experts held
@@ -94,28 +104,41 @@ def dropless_moe_ffn(x, router_w, router_bias, expert_w_gate, expert_w_up,
                       SharedWDown=[shared[2]])
     if valid is not None:
         inputs["Valid"] = [valid]
+    outputs = {"Out": [out], "Chosen": [chosen], "ExpertTokens": [counts]}
+    # a rule, and the identities, are named only where they are asked for:
+    # the programs of the models that do not ask keep what they had
+    extra = {"scoring": scoring} if scoring else {}
+    if zero_experts:
+        zeros = helper.create_variable_for_type_inference(
+            "int32", stop_gradient=True)
+        outputs["ZeroTokens"] = [zeros]
+        extra["zero_experts"] = int(zero_experts)
+    if token_block:
+        extra["token_block"] = int(token_block)
     helper.append_op(
-        type="dropless_moe_ffn", inputs=inputs,
-        outputs={"Out": [out], "Chosen": [chosen],
-                 "ExpertTokens": [counts]},
-        # a rule is named only where it is asked for: the programs of the
-        # models that do not ask keep the attributes they had
+        type="dropless_moe_ffn", inputs=inputs, outputs=outputs,
         attrs=dict({"top_k": int(top_k), "norm_topk": bool(norm_topk),
                     "scale": float(scale),
                     "held_first": -1 if held_first is None
-                    else int(held_first)},
-                   **({"scoring": scoring} if scoring else {})))
+                    else int(held_first)}, **extra))
+    if zero_experts:
+        return out, chosen, counts, zeros
     return out, chosen, counts
 
 
 def latent_rope_rows(q, kva, kv_norm, heads, nope_dim, rope_dim, theta,
                      positions=None, period=0, epsilon=1e-5,
-                     interleave=False, name=None):
+                     interleave=False, name=None, q_scale=1.0,
+                     kv_scale=1.0):
     """The query ``[N, H, dn + dr]`` with RoPE on its rotary part, and the
     row to cache ``[N, C + dr]`` = ``[RMSNorm(ckv) | RoPE(k_rope)]``.
     ``positions`` [N] (decode), or none and ``period`` = the bucket length
     (prefill: token ``n`` stands at ``n % period``). ``interleave``:
-    RoPE over adjacent pairs, else over the two halves."""
+    RoPE over adjacent pairs, else over the two halves. ``q_scale``
+    multiplies the query (both parts) and ``kv_scale`` the normed
+    compressed part of the row (not its rotary key), in float32 before
+    they are rounded: the two constants of a model whose low-rank paths
+    are rescaled (``mla_scale_q_lora`` / ``mla_scale_kv_lora``)."""
     helper = LayerHelper("latent_rope_rows", name=name)
     q_out = helper.create_variable_for_type_inference(q.dtype)
     row = helper.create_variable_for_type_inference(q.dtype)
@@ -125,10 +148,15 @@ def latent_rope_rows(q, kva, kv_norm, heads, nope_dim, rope_dim, theta,
     helper.append_op(
         type="latent_rope_rows", inputs=inputs,
         outputs={"QOut": [q_out], "Row": [row]},
-        attrs={"heads": int(heads), "nope_dim": int(nope_dim),
-               "rope_dim": int(rope_dim), "theta": float(theta),
-               "period": int(period), "epsilon": float(epsilon),
-               "interleave": bool(interleave)})
+        attrs=dict(
+            {"heads": int(heads), "nope_dim": int(nope_dim),
+             "rope_dim": int(rope_dim), "theta": float(theta),
+             "period": int(period), "epsilon": float(epsilon),
+             "interleave": bool(interleave)},
+            # named only where a model has them: the others' programs
+            # keep the attributes they had
+            **({"q_scale": float(q_scale), "kv_scale": float(kv_scale)}
+               if (q_scale, kv_scale) != (1.0, 1.0) else {})))
     return q_out, row
 
 
@@ -167,7 +195,8 @@ def latent_paged_attention(q, kv_b, pool, page_table, lengths, nope_dim,
 
 def latent_prefill_attention(q, rows, kv_b, prompts, nope_dim, name=None):
     """Expanded-form causal attention of ``prompts`` prompts of equal
-    (bucket) length through the flash kernel: ``[N, H * v_dim]``."""
+    (bucket) length through the flash kernel, the values at their own
+    width: ``[N, H * v_dim]``."""
     return _one("latent_prefill_attention",
                 {"Q": [q], "Rows": [rows], "KVB": [kv_b]},
                 {"prompts": int(prompts), "nope_dim": int(nope_dim)},

@@ -1,7 +1,8 @@
 """The serving programs of a decoder-only language model, written ONCE: the
 frame every family's builder fills (``models/latent_moe_decoder.py``,
 ``hybrid_ssm_decoder.py``, ``windowed_moe_decoder.py``,
-``linear_attn_moe_decoder.py``, ``ssd_moe_decoder.py``). A family file
+``linear_attn_moe_decoder.py``, ``ssd_moe_decoder.py``,
+``shortcut_moe_decoder.py``). A family file
 holds what its layers do and the state they keep (``DecoderFamily``); this
 module holds what a
 ``serving.decoder_session.DecoderOnlySession`` dispatches, whatever the
@@ -63,6 +64,10 @@ _FAMILIES = (
      "ssd_moe_decoder", "build_ssd_moe_decoder"),
     (lambda desc: "mamba_d_state" in desc,
      "hybrid_ssm_decoder", "build_hybrid_ssm_decoder"),
+    # two latent blocks a layer and zero-compute experts; such a
+    # description has the next row's ``kv_lora_rank`` too
+    (lambda desc: "zero_expert_num" in desc,
+     "shortcut_moe_decoder", "build_shortcut_moe_decoder"),
     (lambda desc: "kv_lora_rank" in desc,
      "latent_moe_decoder", "build_latent_moe_decoder"),
     (lambda desc: "layer_types" in desc
@@ -87,6 +92,8 @@ def builder_for(desc):
         "DecoderOnlySession knows no builder for this description (keys "
         "%s): it serves a hybrid Mamba-2 decoder with routed experts "
         "(mamba_n_heads), a hybrid state-space decoder (mamba_d_state), a "
+        "decoder of two latent-attention blocks a layer with the expert "
+        "block on a shortcut and zero-compute experts (zero_expert_num), a "
         "latent-attention decoder (kv_lora_rank), a decoder of window "
         "and full attention layers (layer_types with a sliding_window) or "
         "a decoder of delta-rule linear-attention and grouped-query "

@@ -78,11 +78,6 @@ def decoder_dims(desc):
             "not lie among its `of`" % (shard, d["E"]))
     check_served(desc)
     _sparse_dims(desc, d)
-    if d["dn"] + d["dr"] != d["dv"]:
-        raise NotImplementedError(
-            "prefill runs the flash kernel at one head width: "
-            "qk_nope_head_dim + qk_rope_head_dim (%d) must equal "
-            "v_head_dim (%d)" % (d["dn"] + d["dr"], d["dv"]))
     d["W"] = d["C"] + d["dr"]          # the cached row
     d["Wp"] = pool_width(d["W"])       # as the pool holds it
     return d

@@ -110,7 +110,10 @@ register_op(
 def _lower_latent_rope_rows(ctx, ins, attrs):
     """The query after its up-projection and the compressed key/value
     row, made ready for the cache: per head ``[q_nope | RoPE(q_rope)]``,
-    and the cached row ``[RMSNorm(ckv) | RoPE(k_rope)]``."""
+    and the cached row ``[RMSNorm(ckv) | RoPE(k_rope)]``. ``q_scale``
+    multiplies the whole query and ``kv_scale`` the normed compressed
+    part (not the rotary key), both in float32 before the rows are
+    rounded; at 1.0 the op lowers to what it lowered to without them."""
     q, kva = ins["Q"][0], ins["KVA"][0]          # [N, H*(dn+dr)], [N, C+R]
     if ins.get("Positions"):
         pos = jnp.reshape(ins["Positions"][0], (-1,))
@@ -120,11 +123,23 @@ def _lower_latent_rope_rows(ctx, ins, attrs):
     dr, theta = int(attrs["rope_dim"]), float(attrs["theta"])
     C = kva.shape[-1] - dr
     rotate = rope(attrs.get("interleave", False))
+    q_scale = float(attrs.get("q_scale", 1.0))
+    kv_scale = float(attrs.get("kv_scale", 1.0))
     q = q.reshape(q.shape[0], H, dn + dr)
-    q = jnp.concatenate(
-        [q[..., :dn], rotate(q[..., dn:], pos, theta)], -1)
-    ckv = rms_norm(kva[:, :C], ins["KVNorm"][0],
-                   float(attrs.get("epsilon", 1e-5)))
+    if q_scale != 1.0:
+        q32 = q.astype(_F32) * q_scale
+        q = jnp.concatenate(
+            [q32[..., :dn], rotate(q32[..., dn:], pos, theta)],
+            -1).astype(q.dtype)
+    else:
+        q = jnp.concatenate(
+            [q[..., :dn], rotate(q[..., dn:], pos, theta)], -1)
+    eps = float(attrs.get("epsilon", 1e-5))
+    if kv_scale != 1.0:
+        ckv = (rms_norm(kva[:, :C].astype(_F32), ins["KVNorm"][0], eps)
+               * kv_scale).astype(kva.dtype)
+    else:
+        ckv = rms_norm(kva[:, :C], ins["KVNorm"][0], eps)
     row = jnp.concatenate(
         [ckv, rotate(kva[:, C:], pos, theta)], -1)
     return {"QOut": q, "Row": row}
@@ -134,7 +149,8 @@ register_op(
     "latent_rope_rows", inputs=["Q", "KVA", "KVNorm", "Positions"],
     outputs=["QOut", "Row"],
     attrs={"heads": 1, "nope_dim": 0, "rope_dim": 0, "theta": 10000.0,
-           "period": 0, "epsilon": 1e-5, "interleave": False},
+           "period": 0, "epsilon": 1e-5, "interleave": False,
+           "q_scale": 1.0, "kv_scale": 1.0},
     lower=_lower_latent_rope_rows, grad=None)
 
 
@@ -208,7 +224,9 @@ def _lower_latent_prefill_attention(ctx, ins, attrs):
     """Prefill attention in the expanded form: every row's per-head keys
     and values are made from its compressed part, the rotary key is
     shared by all heads, and causal attention runs through the flash
-    kernel at head width ``dn + dr`` (= the value width here)."""
+    kernel at query and key width ``dn + dr`` and the values' own width
+    (equal in ``glm4_moe_lite``, 192 beside 128 in the DeepSeek-V3
+    shape)."""
     from paddle_tpu.kernels.flash_attention import flash_attention
 
     q, rows, kv_b = ins["Q"][0], ins["Rows"][0], ins["KVB"][0]

@@ -242,10 +242,15 @@ def _lower_dropless_moe_ffn(ctx, ins, attrs):
     valid = ins.get("Valid", [None])[0]
     if valid is not None:
         valid = jnp.reshape(valid, (-1,)) > 0
+    scoring = attrs.get("scoring", "sigmoid")
     with jax.named_scope("dropless_route"):
-        if attrs.get("scoring", "sigmoid") == "softmax_topk":
+        if scoring == "softmax_topk":
             chosen, weights = route_softmax_top_k(
                 x, ins["RouterW"][0], int(attrs["top_k"]))
+        elif scoring == "softmax":
+            chosen, weights = route_softmax(
+                x, ins["RouterW"][0], ins["RouterBias"][0],
+                int(attrs["top_k"]), float(attrs.get("scale", 1.0)))
         else:
             chosen, weights = route_top_k(
                 x, ins["RouterW"][0], ins["RouterBias"][0],
@@ -253,11 +258,28 @@ def _lower_dropless_moe_ffn(ctx, ins, attrs):
                 float(attrs.get("scale", 1.0)))
     first = int(attrs.get("held_first", -1))
     first = None if first < 0 else first
-    n = x.shape[0] // _HELD_TOKEN_BLOCK
+    zero = int(attrs.get("zero_experts", 0))
+    if zero:
+        # the router's last ``zero`` outputs are identities: such a choice
+        # is the token itself times its weight, computed here for every
+        # token whoever holds the real experts. It has no group among the
+        # sorted pairs: its index lies past every held expert's, so with
+        # ``first`` (0 where all the real experts are held) it sorts
+        # behind the groups like a pair held elsewhere
+        real = ins["RouterW"][0].shape[-1] - zero
+        first = 0 if first is None else first
+        is_zero = chosen >= real
+        if valid is not None:
+            is_zero = is_zero & valid[:, None]
+        identity = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1)
+    # tokens a block: the op's own where a model with many choices a token
+    # asks for fewer (the sorted copies are ``block * top_k`` rows)
+    block = int(attrs.get("token_block", 0)) or _HELD_TOKEN_BLOCK
+    n = x.shape[0] // block
     with jax.named_scope("dropless_experts"):
         experts = (ins["ExpertWGate"][0], ins["ExpertWUp"][0],
                    ins["ExpertWDown"][0])
-        if first is None or n < 2 or x.shape[0] % _HELD_TOKEN_BLOCK:
+        if first is None or n < 2 or x.shape[0] % block:
             routed, counts = dropless_experts(x, chosen, weights, *experts,
                                               valid=valid, first=first)
         else:
@@ -271,17 +293,22 @@ def _lower_dropless_moe_ffn(ctx, ins, attrs):
             live = (jnp.ones((x.shape[0],), bool) if valid is None
                     else valid)
             routed, counts = jax.lax.map(one, tuple(
-                a.reshape((n, _HELD_TOKEN_BLOCK) + a.shape[1:])
+                a.reshape((n, block) + a.shape[1:])
                 for a in (x, chosen, weights, live)))
             routed = routed.reshape((x.shape[0],) + routed.shape[2:])
             counts = counts.sum(axis=0)
     out = routed
+    if zero:
+        out = out + identity[:, None] * x.astype(jnp.float32)
     if ins.get("SharedWGate"):
         # the shared expert sees every token once, outside the routing
         out = out + gated_ffn(x, ins["SharedWGate"][0], ins["SharedWUp"][0],
                               ins["SharedWDown"][0]).astype(jnp.float32)
-    return {"Out": out.astype(x.dtype), "Chosen": chosen,
+    outs = {"Out": out.astype(x.dtype), "Chosen": chosen,
             "ExpertTokens": counts}
+    if zero:
+        outs["ZeroTokens"] = jnp.sum(is_zero, dtype=jnp.int32).reshape(1)
+    return outs
 
 
 register_op(
@@ -289,12 +316,16 @@ register_op(
     inputs=["X", "RouterW", "RouterBias", "ExpertWGate", "ExpertWUp",
             "ExpertWDown", "SharedWGate", "SharedWUp", "SharedWDown",
             "Valid"],
-    outputs=["Out", "Chosen", "ExpertTokens"],
+    outputs=["Out", "Chosen", "ExpertTokens", "ZeroTokens"],
     # held_first >= 0: the expert weights are the shard that starts there
     # (the router keeps all its outputs); scoring "softmax_topk" is the
-    # second rule (``route_softmax_top_k``: no bias is read)
+    # second rule (``route_softmax_top_k``: no bias is read), "softmax"
+    # the third (``route_softmax``); zero_experts: the router's last
+    # outputs that are identities (``ZeroTokens``: the valid tokens'
+    # choices that fell on one); token_block: the tokens a block of a held
+    # shard's dispatch (0: ``_HELD_TOKEN_BLOCK``)
     attrs={"top_k": 1, "norm_topk": True, "scale": 1.0, "held_first": -1,
-           "scoring": "sigmoid"},
+           "scoring": "sigmoid", "zero_experts": 0, "token_block": 0},
     lower=_lower_dropless_moe_ffn,
     grad=None,
 )
@@ -309,3 +340,19 @@ def route_softmax_top_k(x, router_w, top_k):
                         precision=jax.lax.Precision.HIGHEST)
     top, chosen = jax.lax.top_k(logits, top_k)
     return chosen.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+def route_softmax(x, router_w, router_bias, top_k, scale):
+    """A softmax over ALL the router's outputs (HF
+    ``LongcatFlashTopkRouter``): ``p = softmax(float32(x) float32(Wr))``;
+    the ``top_k`` outputs with the largest ``p + b`` are chosen (the bias
+    moves the choice alone); the weights are ``scale * p`` of the chosen,
+    NOT renormalised over them. Float32 at full precision, as
+    ``route_top_k``. Returns (chosen [N, k] int32, weights [N, k]
+    float32)."""
+    p = jax.nn.softmax(jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    _, chosen = jax.lax.top_k(p + router_bias.astype(jnp.float32), top_k)
+    return (chosen.astype(jnp.int32),
+            jnp.take_along_axis(p, chosen, axis=-1) * scale)

@@ -106,7 +106,10 @@ their own) and, where the model holds a SHARD of its experts,
 ``experts_routed_tokens`` / ``experts_held_tokens`` (the (token, expert)
 choices of the dispatch's steps and those that fell on a held expert)
 and ``experts_held_hit`` (the held experts a step and layer that got a
-token), from the same counts.
+token), from the same counts; where the router has zero-compute
+(identity) experts among its outputs, ``experts_zero_tokens`` (the
+choices that fell on one: the expert op's ``ZeroTokens``, fetched with the
+same dispatch).
 """
 
 import collections
@@ -659,8 +662,11 @@ class DecoderOnlySession(object):
         self.last_window_rows = ring_rows
         self.last_selected_rows = chosen_rows
         fetch = [self._fetch["token"]]
-        if self._fetch.get("expert_tokens"):
-            fetch.append(self._fetch["expert_tokens"])
+        # the expert layers' counts come back with the tokens, in the
+        # dispatch's own fetch: no dispatch and no sync of their own
+        counted = [key for key in ("expert_tokens", "zero_tokens")
+                   if self._fetch.get(key)]
+        fetch += [self._fetch[key] for key in counted]
         feed = {"live": self._live_mask}
         for kind in self._kinds:
             feed[kind.table_feed] = kind.table
@@ -673,7 +679,7 @@ class DecoderOnlySession(object):
                 in_flight=self.in_flight)
         toks = np.asarray(out[0])                       # [K, S, 1]
         self.steps_done += 1
-        self._count(out[1] if len(out) > 1 else None)
+        self._count(**dict(zip(counted, out[1:])))
         finished = {}
         for slot in list(self._live):
             st = self._live[slot]
@@ -689,7 +695,7 @@ class DecoderOnlySession(object):
             self._trace_step(pre_pos, finished, t_step, time.time(), False)
         return finished
 
-    def _count(self, expert_tokens):
+    def _count(self, expert_tokens=None, zero_tokens=None):
         """The round's counters, from what came back with the tokens."""
         counters = {"pages_in_use": self.pages_in_use}
         if len(self._kinds) > 1:
@@ -721,7 +727,8 @@ class DecoderOnlySession(object):
             if ratio.size:
                 counters["expert_max_over_mean"] = float(ratio.mean())
             experts = self.geometry.get("experts")
-            if experts and experts["held"] < experts["of"]:
+            if experts and (experts["held"] < experts["of"]
+                            or zero_tokens is not None):
                 # a shard of the experts: the (token, expert) choices the
                 # dispatch's steps made, those that fell on a held expert,
                 # and the held experts a step and layer that got any
@@ -731,6 +738,11 @@ class DecoderOnlySession(object):
                         * experts["top_k"]),
                     experts_held_tokens=int(c.sum()),
                     experts_held_hit=float((c > 0).sum(axis=-1).mean()))
+            if zero_tokens is not None:
+                # of those choices, the ones that fell on a zero-compute
+                # (identity) expert: no expert's weights are read for them
+                counters["experts_zero_tokens"] = int(
+                    np.asarray(zero_tokens).sum())
         self.last_counters = counters
         if _tracing.ENABLED:
             for key, value in counters.items():

@@ -1,15 +1,16 @@
 """The decoder-only serving programs, op for op and name for name.
 
-``models/decoder_programs.py`` writes the programs' frame once and the five
+``models/decoder_programs.py`` writes the programs' frame once and the six
 family files fill it; ``tests/golden/decoder_programs.json`` holds
-``core.fingerprint.program_fingerprint`` of every program of the six test
+``core.fingerprint.program_fingerprint`` of every program of the seven test
 descriptions (``DESC`` of ``test_latent_moe_decoder``,
 ``test_sparse_latent_decoder``, ``test_hybrid_ssm_decoder``,
 ``test_windowed_moe_decoder``, ``test_linear_attn_decoder``,
-``test_ssd_moe_decoder``, at the geometry those files' sessions use): ``init``, ``step``, ``step`` with
+``test_ssd_moe_decoder``, ``test_shortcut_moe_decoder``, at the geometry
+those files' sessions use): ``init``, ``step``, ``step`` with
 ``probe_rows=2`` and every rung of every bucket's prefill. They were
-recorded at the commit BEFORE the frame was written once (the sixth with
-the family that brought it; a rung of B rows
+recorded at the commit BEFORE the frame was written once (the sixth and the
+seventh with the family that brought each; a rung of B rows
 that a builder of that commit did not take is the program it built for a
 budget of B rows), so a case fails at any commit that adds, drops or
 reorders an op, renames a variable or changes an attribute: the fingerprint
@@ -41,7 +42,8 @@ GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden",
 # the test file whose DESC it is -> its sessions' slots
 FAMILIES = {"test_latent_moe_decoder": 4, "test_sparse_latent_decoder": 4,
             "test_hybrid_ssm_decoder": 6, "test_windowed_moe_decoder": 4,
-            "test_linear_attn_decoder": 6, "test_ssd_moe_decoder": 6}
+            "test_linear_attn_decoder": 6, "test_ssd_moe_decoder": 6,
+            "test_shortcut_moe_decoder": 4}
 
 
 @functools.lru_cache(maxsize=None)
@@ -100,7 +102,9 @@ def test_an_unknown_description_is_refused_with_the_families_named():
         "DecoderOnlySession knows no builder for this description (keys "
         "['hidden_size', 'vocab_size']): it serves a hybrid Mamba-2 decoder "
         "with routed experts (mamba_n_heads), a hybrid state-space "
-        "decoder (mamba_d_state), a latent-attention decoder "
+        "decoder (mamba_d_state), a decoder of two latent-attention blocks "
+        "a layer with the expert block on a shortcut and zero-compute "
+        "experts (zero_expert_num), a latent-attention decoder "
         "(kv_lora_rank), a decoder of window and full attention layers "
         "(layer_types with a sliding_window) or a decoder of delta-rule "
         "linear-attention and grouped-query attention layers "

@@ -1067,3 +1067,96 @@ def test_softmax_cross_entropy_writes_no_float32_copy_of_the_logits(v5e, dtype):
     writes, reads, gathers = _logits_traffic(compiled.as_text(), N * V)
     assert not writes, "\n".join("%s[%s] %s" % w for w in writes)
     assert reads and not gathers, (reads, gathers)
+
+
+# -- the shortcut decoder (perfbench longcat_flash_omni_4l) --------------------
+
+@pytest.mark.parametrize("B,T", [(1, 4096), (8, 512)])
+def test_flash_forward_lowers_at_a_value_width_of_its_own(v5e, B, T):
+    """The cell's prefill attention: 64 heads, queries and keys of 192
+    beside values of 128 in bfloat16, one prompt of the longest bucket and
+    eight of the shortest. Mosaic takes the forward with its second
+    product, accumulator and output at the VALUES' width: no operand or
+    result of the call is 192 (or 256) wide where a value is."""
+    q = ((B, 64, T, 192), BF16)
+    v = ((B, 64, T, 128), BF16)
+    text = _compile_v5e(
+        v5e, lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+        q, q, v)
+    (call,) = [ln for ln in text.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in ln
+               and "flash_attention_fwd" in ln]
+    assert "bf16[%d,64,%d,128]" % (B, T) in call.split(" custom-call(")[0]
+    # two operands of 192 (q, k), one of 128 (v)
+    operands = call.split(" custom-call(")[1]
+    assert operands.count("bf16[%d,64,%d,192]" % (B, T)) == 2
+    assert operands.count("bf16[%d,64,%d,128]" % (B, T)) == 1
+
+
+def test_shortcut_decoder_programs_fit_the_chip_at_the_served_sizes(v5e):
+    """The cell's own configuration (``perfbench/configs/
+    longcat_flash_omni_4l.json``: 4 layers of two latent blocks, 16 of 512
+    experts beside 256 identities, 64 slots of 5120 positions, eight
+    pools) compiled for the described chip from shapes alone: the decode
+    dispatch and the fullest prefill (4096 token places). Mosaic takes the
+    kernels at these widths (64 heads over a 640-lane pool; 192 beside
+    128), the instructions say which sub-block they are, the pools are
+    updated in place, and arguments + temporaries stay under 15.0 GB of
+    the chip's 17.18 (PERF.md, PR 49: 13.70 + 0.21 and 13.70 + 1.05)."""
+    import json
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import _described_compile as dc
+    from paddle_tpu.models import shortcut_moe_decoder as scd
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "longcat_flash_omni_4l.json")) as f:
+        cfg = json.load(f)
+    pool = cfg["pool"]
+    S = pool["num_slots"]
+    built = scd.build_shortcut_moe_decoder(
+        cfg, S, pool["max_prompt"] + pool["max_new_tokens"],
+        pool["page_size"], pool["prefill_buckets"],
+        prefill_token_budget=pool["prefill_token_budget"],
+        tokens_per_dispatch=pool["tokens_per_dispatch"],
+        prefill_rungs=True, probe_rows=2)
+    geo, f = built["geometry"], built["fetches"]
+    npp = geo["pages_per_slot"]
+    state = dict(scd.parameter_shapes(cfg, "bfloat16"))
+    for name, a in geo["state"]["page_pools"].items():
+        state[name] = (a["shape"], a["dtype"])
+    assert len(geo["state"]["page_pools"]) == 2 * cfg["num_layers"]
+    state["scd_tok"] = state["scd_pos"] = ((S, 1), "int64")
+
+    def peak(compiled):
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.temp_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+    with _tpu_target():
+        step = dc.compile_program(
+            built["step"], v5e[0], state,
+            {"page_table": ((S, npp), "int64"), "live": ((S, 1), "int64"),
+             "probe_slots": ((2,), "int64")},
+            [f["token"], f["expert_tokens"], f["zero_tokens"],
+             f["probe_logits"], f["chosen"]], steps=4)
+        T = pool["prefill_buckets"][-1]
+        prefill = dc.compile_program(
+            built["prefill_rungs"][T][1], v5e[0], state,
+            {"prompt_ids": ((T,), "int64"), "prompt_len": ((1,), "int64"),
+             "slot_idx": ((1,), "int64"), "page_rows": ((1, npp), "int64"),
+             "last_idx": ((1,), "int64")},
+            [f["first_token"], f["first_logits"], f["first_chosen"]])
+    text = step.as_text()
+    for name in ("latent_paged_decode_attention", "gmm", "shortcut_moe",
+                 "dense_ffn_0", "dense_ffn_1"):
+        assert name in text, name
+    pool_elems = int(np.prod(geo["state"]["page_pools"]["scd_pool_0"][
+        "shape"]))
+    _assert_moves_no_pool(text, pool_elems)
+    assert "flash_attention_fwd" in prefill.as_text()
+    assert step.memory_analysis().alias_size_in_bytes >= 8 * pool_elems * 2
+    assert peak(step) < 14.2e9, peak(step)
+    assert peak(prefill) < 15.0e9, peak(prefill)
