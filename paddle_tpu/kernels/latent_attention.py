@@ -16,10 +16,21 @@ kernel never expands a cached row:
     scores[h, t] = (q_lat[h] . ckv[t] + q_rope[h] . k_rope[t]) * sm_scale
     o_lat[h]     = softmax(scores[h]) @ ckv
 
-* Grid ``(slot, page)``, the page table scalar-prefetched as in
-  ``paged_attention``: each step DMAs one resident page once and every
-  head uses it. Pages past a slot's length skip their compute and, with
-  the host's last-valid-page aliasing of the table's tail, their copy.
+* Grid ``(slot,)``, the page table and the lengths scalar-prefetched and
+  the pool left in HBM, as in ``paged_attention``: a grid step is a slot,
+  and its RESIDENT pages, ``ceil(length / page_size)`` of them, are walked
+  inside the body by ``paged_attention._walk_resident_pages``, several
+  pages a step of the walk (``_pages_per_step``): the step's pages are
+  copied together into one half of a VMEM buffer while the half before it
+  is absorbed, every head using the rows once, and the next slot's first
+  pages are started under this slot's last. A page past a slot's length
+  is never copied or computed on and its table entry never read (the
+  kernel does not need the host's last-valid-page aliasing of the table's
+  tail); a slot's last step may hold fewer resident pages than the
+  others, and the rows of the half that no copy filled are zeroed, so
+  their weights of exactly 0 meet no stale NaN. A (slot, page) grid paid
+  ~0.5 us a resident 164 KB page and ~0.1 us a page that held nothing
+  (PERF.md section 6, PR 50).
 * Rows and queries stay in the pool's dtype (bfloat16 when served); the
   two products accumulate in float32, the softmax runs in float32.
 * Heads are padded to the sublane tile for the MXU; slots of length 0
@@ -42,8 +53,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.kernels.flash_attention import _is_tpu_target
-from paddle_tpu.kernels.paged_attention import KernelCompileError
+from paddle_tpu.kernels.flash_attention import (
+    _VMEM_BUDGET, _is_tpu_target, _mosaic_params)
+from paddle_tpu.kernels.paged_attention import (
+    KernelCompileError, _start_slot, _walk_resident_pages, _walk_scratch)
 
 LATENT_KERNEL_NAME = "latent_paged_decode_attention"
 
@@ -53,6 +66,18 @@ _HEAD_TILE = 16  # bfloat16 sublane tile: heads are padded to it
 
 
 LANES = 128
+
+# Pages a step of the walk. One constant serves both served geometries
+# (256 slots x 32 padded heads x tables of 12 pages with ~5 resident, 64 x
+# 64 x 40 with ~16; 164 KB pages): the kernel alone read 1.21-1.23 / 0.90-
+# 0.95 ms a call on the (slot, page) grid and 0.97-1.00 / 0.76-0.81, 0.72-
+# 0.74 / 0.53-0.56, 0.60-0.62 / 0.41-0.43, 0.57-0.59 / 0.36-0.37 at 1, 2,
+# 4, 8 pages a step; 12 and 16 read 0.67 / 0.35 and 0.81 / 0.36 (a ragged
+# last step computes the whole step's rows). What a step buys is ROWS A
+# PRODUCT, not copies in flight: eight pages copied together and absorbed
+# a page at a time read what one page a step reads (0.998 / 0.753; my
+# chip runs, PR 50; PERF.md section 6).
+_PAGES_PER_STEP = 8
 
 
 def pool_width(row_width):
@@ -95,58 +120,90 @@ def latent_paged_attention_reference(q_lat, q_rope, pool, page_table,
     return jnp.where(dead, 0.0, out).astype(q_lat.dtype)
 
 
-def _latent_decode_kernel(table_ref, len_ref, q_ref, row_ref, o_ref,
-                          acc_ref, m_ref, l_ref, *, page_size, n_pages,
-                          kv_rank, sm_scale):
-    """One (slot, page) step: absorb one resident page of latent rows
-    into every head's online-softmax state."""
+def _absorb_rows(q, rows, first, length, acc_ref, m_ref, l_ref, *, kv_rank,
+                 sm_scale):
+    """Absorb ``rows`` [n, W], a slot's cached rows from position
+    ``first`` on, into every head's online-softmax state (``q`` [Hp, W];
+    the running max and sum ``m``, ``l`` [Hp, 1] and ``acc`` [Hp, C] in
+    float32 VMEM scratch). Rows at or past ``length`` are masked in the
+    scores; their weights are exactly 0, so they must be FINITE."""
+    sc = jax.lax.dot_general(
+        q, rows, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale       # [Hp, n]
+    pos = first + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    sc = jnp.where(pos < length, sc, _NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+    pexp = jnp.exp(sc - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        pexp.astype(rows.dtype), rows[:, :kv_rank],
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                  # [Hp, C]
+    m_ref[...] = m_new
+
+
+def _finish_heads(o_ref, acc_ref, m_ref, l_ref):
+    """The slot's output from its online-softmax state; exactly 0 where no
+    row was seen."""
+    dead = m_ref[...] <= _MASKED_ROW_M
+    o_ref[0] = jnp.where(
+        dead, 0.0,
+        acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _latent_decode_kernel(table_ref, len_ref, q_ref, pool_hbm, o_ref,
+                          row_buf, sem, ahead_ref, acc_ref, m_ref, l_ref,
+                          *, page_size, group, kv_rank, sm_scale):
+    """One grid step is one SLOT: every head's online-softmax state
+    absorbs the slot's resident pages, ``group`` of them a step of the
+    walk (``_walk_resident_pages``). ``table_ref`` and ``len_ref`` are
+    the scalar-prefetch operands; the pool stays in HBM."""
     from jax.experimental import pallas as pl
 
     s = pl.program_id(0)
-    p = pl.program_id(1)
+    # a step's rows past the table's last page are not the slot's,
+    # whatever length a caller hands in
+    length = jnp.minimum(len_ref[s], table_ref.shape[1] * page_size)
+    _start_slot(acc_ref, m_ref, l_ref)
+    q = q_ref[0]                                      # [Hp, W]
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def absorb(g, row_ref):
+        _absorb_rows(q, row_ref[0], g * group * page_size, length, acc_ref,
+                     m_ref, l_ref, kv_rank=kv_rank, sm_scale=sm_scale)
 
-    length = len_ref[s]
-
-    def _compute():
-        q = q_ref[0]                                  # [Hp, W]
-        rows = row_ref[0]                             # [ps, W]
-        sc = jax.lax.dot_general(
-            q, rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # [Hp, ps]
-        pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 1)
-        sc = jnp.where(pos < length, sc, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        pexp = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=-1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pexp.astype(rows.dtype), rows[:, :kv_rank],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [Hp, C]
-        m_ref[...] = m_new
-
-    pl.when(p * page_size < length)(_compute)
-
-    @pl.when(p == n_pages - 1)
-    def _finish():
-        dead = m_ref[...] <= _MASKED_ROW_M
-        o_ref[0] = jnp.where(
-            dead, 0.0,
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+    # the ragged bound: ceil(length / page_size) pages, 0 for an empty slot
+    _walk_resident_pages(
+        table_ref, s,
+        lambda slot: (len_ref[slot] + page_size - 1) // page_size,
+        (pool_hbm,), (row_buf,), sem, ahead_ref, absorb, group=group)
+    _finish_heads(o_ref, acc_ref, m_ref, l_ref)
 
 
+def _pages_per_step(page_size, width, itemsize, heads, pages_per_slot):
+    """How many pages a step of the walk copies and absorbs together:
+    ``_PAGES_PER_STEP``, fewer where the table is narrower or where both
+    halves of the walk's buffer with a step's float32 scores and weights
+    would not fit ``flash_attention._VMEM_BUDGET``."""
+    def held(pages):
+        rows = pages * page_size
+        return 2 * rows * width * itemsize + 2 * heads * rows * 4
+
+    pages = max(1, min(_PAGES_PER_STEP, pages_per_slot))
+    while pages > 1 and held(pages) > _VMEM_BUDGET:
+        pages //= 2
+    return pages
+
+
+# jitted so that a program's many calls (one a layer and token step: 24
+# and 32 in the served step programs) trace and lower the kernel ONCE:
+# untraced it was 7.6 and 11.2 s of set-up over the (slot, page) kernel's
+# (``trace_lower_s``, my chip runs, PR 50)
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "interpret", "group"))
 def _latent_pallas(q_lat, q_rope, pool, page_table, lengths, sm_scale,
-                   interpret):
+                   interpret, group=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -154,21 +211,22 @@ def _latent_pallas(q_lat, q_rope, pool, page_table, lengths, sm_scale,
     ps, W = pool.shape[1], pool.shape[2]
     npp = page_table.shape[1]
     Hp = -(-H // _HEAD_TILE) * _HEAD_TILE
+    if group is None:
+        group = _pages_per_step(ps, W, pool.dtype.itemsize, Hp, npp)
     q = _fit(jnp.concatenate([q_lat, q_rope], axis=-1), pool).astype(
         pool.dtype)
     if Hp != H:
         q = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, npp),
+        grid=(S,),
         in_specs=[
-            pl.BlockSpec((1, Hp, W), lambda s, p, table, lens: (s, 0, 0)),
-            pl.BlockSpec((1, ps, W),
-                         lambda s, p, table, lens: (table[s, p], 0, 0)),
+            pl.BlockSpec((1, Hp, W), lambda s, table, lens: (s, 0, 0)),
+            # the pool stays where it is: the body copies its pages
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (1, Hp, C), lambda s, p, table, lens: (s, 0, 0)),
-        scratch_shapes=[
+        out_specs=pl.BlockSpec((1, Hp, C), lambda s, table, lens: (s, 0, 0)),
+        scratch_shapes=_walk_scratch(pool, group=group) + [
             pltpu.VMEM((Hp, C), jnp.float32),
             pltpu.VMEM((Hp, 1), jnp.float32),
             pltpu.VMEM((Hp, 1), jnp.float32),
@@ -176,12 +234,13 @@ def _latent_pallas(q_lat, q_rope, pool, page_table, lengths, sm_scale,
     )
     out = pl.pallas_call(
         functools.partial(
-            _latent_decode_kernel, page_size=ps, n_pages=npp, kv_rank=C,
+            _latent_decode_kernel, page_size=ps, group=group, kv_rank=C,
             sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Hp, C), q_lat.dtype),
         interpret=interpret,
         name=LATENT_KERNEL_NAME,
+        **_mosaic_params(interpret, ("arbitrary",)),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pool)
     return out[:, :H]
 

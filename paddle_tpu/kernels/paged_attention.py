@@ -187,23 +187,30 @@ def _finish_slot(o_ref, acc_ref, l_ref):
 
 
 def _walk_resident_pages(table_ref, slot, pages_of, pools, bufs, sem,
-                         ahead_ref, absorb):
+                         ahead_ref, absorb, group=1):
     """Walk one slot's resident pages INSIDE a kernel body whose grid is
-    ``(slots,)``. ``pages_of(slot)`` is how many of the slot's table
-    entries are resident; page ``p`` of the slot is ``table_ref[slot,
-    p]`` of every pool in ``pools`` (refs left in HBM, ``[P, page_size,
-    width]``), fetched by the kernel's own copy into one half of the
-    matching ``bufs`` scratch (``[2, page_size, width]``) while the page
-    before it is absorbed from the other half; ``sem`` is a DMA
-    semaphore a pool and half (``[len(pools), 2]``).
-    ``absorb(p, *page_refs)`` gets the page's index in the slot and a
-    ``[1, page_size, width]`` ref a pool.
+    ``(slots,)``, ``group`` pages a step of the walk. ``pages_of(slot)``
+    is how many of the slot's table entries are resident; page ``p`` of
+    the slot is ``table_ref[slot, p]`` of every pool in ``pools`` (refs
+    left in HBM, ``[P, page_size, width]``), fetched by the kernel's own
+    copy into one half of the matching ``bufs`` scratch (``[2, group *
+    page_size, width]``) while the pages before it are absorbed from the
+    other half; ``sem`` is a DMA semaphore a pool and half
+    (``[len(pools), 2]``), which a half's ``group`` copies share.
+    ``absorb(g, *group_refs)`` gets the step's index in the slot (its
+    first page is ``g * group``) and a ``[1, group * page_size, width]``
+    ref a pool. The copies of a step are started together; a slot's last
+    step may hold fewer than ``group`` resident pages: the others are
+    not copied and their rows of the half are set to ZERO before
+    ``absorb`` sees them (what the half held there is whatever an
+    earlier step left, and ``0 x NaN`` is NaN in a product that masks
+    only its scores).
 
     The halves alternate over the whole CALL, not a slot: ``ahead_ref``
     (SMEM ``int32[2]``, kept from grid step to grid step, which
-    therefore run in order) holds the pages walked so far and whether
-    this slot's first page is in flight already, started by the slot
-    before it under its own last page. Above the knee every slot is live
+    therefore run in order) holds the steps walked so far and whether
+    this slot's first pages are in flight already, started by the slot
+    before it under its own last step. Above the knee every slot is live
     with a page or two, and a first page fetched with nothing to hide
     behind was a quarter of the call (0.316 -> 0.234 ms at 256 live, my
     chip run, PR 41).
@@ -214,6 +221,9 @@ def _walk_resident_pages(table_ref, slot, pages_of, pools, bufs, sem,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    G = int(group)
+    ps = bufs[0].shape[1] // G
+
     @pl.when(slot == 0)
     def _first_slot():
         ahead_ref[0] = 0
@@ -223,13 +233,33 @@ def _walk_resident_pages(table_ref, slot, pages_of, pools, bufs, sem,
         # never past the table's row, whatever length a caller hands in
         return jnp.minimum(pages_of(of_slot), table_ref.shape[1])
 
+    # at one page a step the walk traces to what it was before it took a
+    # group (PR 50): no arithmetic on the page's index, whole halves
     n = resident(slot)
+    steps = n if G == 1 else (n + G - 1) // G
 
-    def copies(of_slot, p, half):
-        page = table_ref[of_slot, p]
-        return [pltpu.make_async_copy(pool.at[page], buf.at[half],
-                                      sem.at[i, half])
-                for i, (pool, buf) in enumerate(zip(pools, bufs))]
+    def for_copies(of_slot, pages, g, half, act):
+        """``act`` on the copy of every resident page of step ``g`` of a
+        slot with ``pages`` resident: the step's first page is resident
+        or the step would not be walked."""
+        for j in range(G):
+            p = g if G == 1 else g * G + j
+
+            def one(p=p, j=j):
+                page = table_ref[of_slot, p]
+                for i, (pool, buf) in enumerate(zip(pools, bufs)):
+                    dst = (buf.at[half] if G == 1
+                           else buf.at[half, pl.ds(j * ps, ps)])
+                    act(pltpu.make_async_copy(pool.at[page], dst,
+                                              sem.at[i, half]))
+
+            if j == 0:
+                one()
+            else:
+                pl.when(p < pages)(one)
+
+    def start(of_slot, pages, g, half):
+        for_copies(of_slot, pages, g, half, lambda c: c.start())
 
     @pl.when(n > 0)
     def _walk():
@@ -239,42 +269,45 @@ def _walk_resident_pages(table_ref, slot, pages_of, pools, bufs, sem,
         n_next = jnp.where(slot + 1 < n_slots, resident(nxt), 0)
 
         @pl.when(ahead_ref[1] == 0)
-        def _first_page():
-            for c in copies(slot, 0, walked % 2):
-                c.start()
+        def _first_pages():
+            start(slot, n, 0, walked % 2)
 
-        def body(p, carry):
-            half = (walked + p) % 2
+        def body(g, carry):
+            half = (walked + g) % 2
 
-            @pl.when(p + 1 < n)
-            def _next_page():
-                for c in copies(slot, p + 1, 1 - half):
-                    c.start()
+            @pl.when(g + 1 < steps)
+            def _next_pages():
+                start(slot, n, g + 1, 1 - half)
 
-            @pl.when((p + 1 == n) & (n_next > 0))
+            @pl.when((g + 1 == steps) & (n_next > 0))
             def _next_slot():
-                for c in copies(nxt, 0, 1 - half):
-                    c.start()
+                start(nxt, n_next, 0, 1 - half)
 
-            for c in copies(slot, p, half):
-                c.wait()
-            absorb(p, *[buf.at[pl.ds(half, 1)] for buf in bufs])
+            for_copies(slot, n, g, half, lambda c: c.wait())
+            for j in range(1, G):
+                @pl.when(g * G + j >= n)
+                def _not_resident(j=j):
+                    for buf in bufs:
+                        buf[pl.ds(half, 1), pl.ds(j * ps, ps), :] = (
+                            jnp.zeros((1, ps, buf.shape[2]), buf.dtype))
+
+            absorb(g, *[buf.at[pl.ds(half, 1)] for buf in bufs])
             return carry
 
-        jax.lax.fori_loop(0, n, body, 0)
-        ahead_ref[0] = walked + n
+        jax.lax.fori_loop(0, steps, body, 0)
+        ahead_ref[0] = walked + steps
         ahead_ref[1] = (n_next > 0).astype(jnp.int32)
 
 
-def _walk_scratch(k_pool, v_pool):
-    """The scratch ``_walk_resident_pages`` needs for a K and a V pool:
-    two halves a pool, a DMA semaphore a pool and half, its state."""
+def _walk_scratch(*pools, group=1):
+    """The scratch ``_walk_resident_pages`` needs for ``pools`` (a K and
+    a V pool, or one pool of latent rows): two halves of ``group`` pages
+    a pool, a DMA semaphore a pool and half, its state."""
     from jax.experimental.pallas import tpu as pltpu
 
-    return [
-        pltpu.VMEM((2,) + tuple(k_pool.shape[1:]), k_pool.dtype),
-        pltpu.VMEM((2,) + tuple(v_pool.shape[1:]), v_pool.dtype),
-        pltpu.SemaphoreType.DMA((2, 2)),
+    return [pltpu.VMEM((2, group * pool.shape[1], pool.shape[2]),
+                       pool.dtype) for pool in pools] + [
+        pltpu.SemaphoreType.DMA((len(pools), 2)),
         pltpu.SMEM((2,), jnp.int32),
     ]
 

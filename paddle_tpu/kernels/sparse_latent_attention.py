@@ -53,11 +53,13 @@ from paddle_tpu.kernels.latent_attention import (
     _HEAD_TILE,
     _MASKED_ROW_M,
     _NEG_INF,
+    _absorb_rows,
+    _finish_heads,
     _fit,
-    _latent_decode_kernel,
     latent_paged_attention_reference,
 )
-from paddle_tpu.kernels.paged_attention import KernelCompileError
+from paddle_tpu.kernels.paged_attention import (
+    KernelCompileError, _start_slot)
 
 INDEX_SCORE_KERNEL_NAME = "index_score_decode"
 SPARSE_DECODE_KERNEL_NAME = "sparse_latent_decode_attention"
@@ -199,6 +201,28 @@ def gather_selected_rows(pool, page_table, selected):
     return pool.reshape(-1, pool.shape[-1])[flat]
 
 
+def _sparse_decode_kernel(table_ref, len_ref, q_ref, row_ref, o_ref,
+                          acc_ref, m_ref, l_ref, *, chunk, n_chunks,
+                          kv_rank, sm_scale):
+    """One (slot, chunk) grid step: absorb one chunk of the slot's
+    gathered rows into every head's online-softmax state (the absorbed
+    latent kernel's arithmetic, ``latent_attention._absorb_rows``)."""
+    from jax.experimental import pallas as pl
+
+    s = pl.program_id(0)
+    p = pl.program_id(1)
+    pl.when(p == 0)(lambda: _start_slot(acc_ref, m_ref, l_ref))
+    length = len_ref[s]
+
+    @pl.when(p * chunk < length)
+    def _compute():
+        _absorb_rows(q_ref[0], row_ref[0], p * chunk, length, acc_ref,
+                     m_ref, l_ref, kv_rank=kv_rank, sm_scale=sm_scale)
+
+    pl.when(p == n_chunks - 1)(
+        lambda: _finish_heads(o_ref, acc_ref, m_ref, l_ref))
+
+
 def _sparse_decode_pallas(q_lat, q_rope, rows, counts, sm_scale, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -214,8 +238,8 @@ def _sparse_decode_pallas(q_lat, q_rope, rows, counts, sm_scale, interpret):
         rows.dtype)
     if Hp != H:
         q = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
-    # the dense kernel's body over the gathered rows: slot s's chunks are
-    # "pages" s * n .. s * n + n - 1 of a pool of its own
+    # slot s's chunks are "pages" s * n .. s * n + n - 1 of a pool of
+    # their own
     table = jnp.arange(S * n, dtype=jnp.int32).reshape(S, n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -234,7 +258,7 @@ def _sparse_decode_pallas(q_lat, q_rope, rows, counts, sm_scale, interpret):
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_latent_decode_kernel, page_size=chunk, n_pages=n,
+        functools.partial(_sparse_decode_kernel, chunk=chunk, n_chunks=n,
                           kv_rank=C, sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Hp, C), q_lat.dtype),

@@ -241,6 +241,91 @@ def test_latent_kernel_matches_its_reference(dtype, tol):
     assert not np.asarray(got[0], "float32").any()       # length 0 -> 0
 
 
+def _poisoned_case(lengths, npp, dtype, ps=8, seed=11):
+    """Slots of ``lengths`` over a pool in which ONLY their resident pages
+    hold rows: every other page is NaN, and a table's entries past a
+    slot's resident pages name pages outside the pool. Returns the case
+    and its clean twin (the tail on the trash page, the other pages zero)
+    for the reference."""
+    S, H, C, R = len(lengths), 3, 16, 4
+    rng = np.random.RandomState(seed)
+    P = 2 + S * npp                 # page 0 and the last page never resident
+    ids = 1 + rng.permutation(P - 2)
+    table = np.full((S, npp), P + 7, "int64")
+    pool = np.full((P, ps, la.pool_width(C + R)), np.nan, "float32")
+    clean = np.zeros_like(pool)
+    at = 0
+    for slot, n in enumerate(lengths):
+        held = min(-(-n // ps), npp)
+        table[slot, :held] = ids[at:at + held]
+        at += held
+    resident = table[table < P]
+    clean[resident, :, :C + R] = rng.standard_normal(
+        (resident.size, ps, C + R))
+    pool[resident] = clean[resident]
+    q_lat = jnp.asarray(rng.standard_normal((S, H, C)), dtype)
+    q_rope = jnp.asarray(rng.standard_normal((S, H, R)), dtype)
+    lengths = jnp.asarray(lengths)
+    case = (q_lat, q_rope, jnp.asarray(pool, dtype), jnp.asarray(table),
+            lengths)
+    twin = (q_lat, q_rope, jnp.asarray(clean, dtype),
+            jnp.asarray(np.where(table < P, table, 0)), lengths)
+    return case, twin
+
+
+@pytest.mark.parametrize("group", [1, 2, 4])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_latent_kernel_reads_resident_pages_only(dtype, tol, group):
+    """One grid step a slot, ``group`` pages a step of the walk: a slot's
+    last step may be ragged (its rows past the last resident page are
+    whatever the buffer held), a slot may be empty between two live ones,
+    and nothing but resident pages and resident table entries may be
+    read: everything else is NaN or out of range."""
+    ps, npp = 8, 2 * group + 1
+    lengths = [0, 1, ps, ps + 1, 0, group * ps - 1, group * ps,
+               group * ps + 1, npp * ps, 0]
+    case, twin = _poisoned_case(lengths, npp, dtype, ps=ps)
+    want = la.latent_paged_attention_reference(*twin, sm_scale=0.25)
+    got = la._latent_pallas(*case, sm_scale=0.25, interpret=True,
+                            group=group)
+    assert got.shape == want.shape and got.dtype == dtype
+    got = np.asarray(got, "float32")
+    assert np.isfinite(got).all()
+    assert rel(got, want) < tol
+    for slot, n in enumerate(lengths):
+        if n:
+            assert rel(got[slot], want[slot]) < tol
+        else:
+            assert not got[slot].any()                   # length 0 -> 0
+
+
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_a_length_over_the_table_stays_inside_the_row(group):
+    """A length above ``pages_per_slot * page_size`` walks the slot's own
+    row of the table and no entry of the next slot's, which here names
+    pages outside the pool."""
+    ps, npp = 8, 3
+    lengths = [npp * ps + 5, 0, npp * ps + ps * group, 0]
+    case, twin = _poisoned_case(lengths, npp, jnp.float32, ps=ps)
+    want = la.latent_paged_attention_reference(*twin, sm_scale=0.25)
+    got = np.asarray(la._latent_pallas(*case, sm_scale=0.25,
+                                       interpret=True, group=group))
+    assert np.isfinite(got).all() and rel(got, want) < 1e-5
+    assert not got[1].any() and not got[3].any()
+
+
+def test_pages_a_step_follow_the_shapes():
+    """The wrapper's rule for the pages a step of the walk: one count at
+    both served geometries, never more than the table holds, fewer where
+    the walk's two halves would not fit the kernel's VMEM."""
+    served = la._pages_per_step(128, 640, 2, 32, 12)
+    assert served == la._pages_per_step(128, 640, 2, 64, 40) == 8
+    assert la._pages_per_step(8, 20, 4, 16, 3) == 3
+    assert la._pages_per_step(8, 20, 4, 16, 1) == 1
+    assert la._pages_per_step(512, 640, 4, 64, 40) == 4
+
+
 def test_latent_kernel_is_the_expanded_attention(monkeypatch):
     """Absorbed decode against the reference's EXPANDED attention: the
     last position of a sequence through ``attention`` equals the kernel's

@@ -616,20 +616,39 @@ def test_transformer_step_program_steers_once_and_copies_no_cross_pool(v5e):
     assert len({tuple(c.split(", ")[:4]) for c in calls}) == 1, calls
 
 
-# the latent-attention decoder's serving cell (perfbench glm47_flash_6l):
-# 256 slots, 20 heads, a 512 + 64 wide row pool in pages of 128
-def test_latent_decode_lowers_for_tpu_at_the_served_widths(v5e):
+# the two latent-attention serving cells (perfbench glm47_flash_6l: 256
+# slots, 20 heads, tables of 12 pages; longcat_flash_omni_4l: 64 slots, 64
+# heads, tables of 40): a 512 + 64 wide row in a 640-wide bfloat16 pool of
+# 128-row pages
+@pytest.mark.parametrize("S,H,npp", [(256, 20, 12), (64, 64, 40)],
+                         ids=["glm47_flash", "longcat_flash"])
+def test_latent_decode_lowers_for_tpu_at_the_served_widths(v5e, S, H, npp):
+    """One Mosaic call whose grid step is a SLOT (the resident pages are
+    walked inside the body, the pool left in HBM), and no copy of a
+    pool-sized array around it."""
     from paddle_tpu.kernels import latent_attention as la
 
-    S, H, C, R, ps, npp = 256, 20, 512, 64, 128, 12
-    text = _compile_v5e(
-        v5e,
-        lambda ql, qr, pool, t, n: la.latent_paged_attention(
-            ql, qr, pool, t, n, sm_scale=0.0625, force_pallas=True),
-        ((S, H, C), BF16), ((S, H, R), BF16),
-        ((1 + S * npp, ps, la.pool_width(C + R)), BF16), ((S, npp), jnp.int32),
-        ((S,), jnp.int32))
+    C, R, ps = 512, 64, 128
+    pool_shape = (1 + S * npp, ps, la.pool_width(C + R))
+    specs = (((S, H, C), BF16), ((S, H, R), BF16), (pool_shape, BF16),
+             ((S, npp), jnp.int32), ((S,), jnp.int32))
+
+    def fn(ql, qr, pool, t, n):
+        return la.latent_paged_attention(
+            ql, qr, pool, t, n, sm_scale=0.0625, force_pallas=True)
+
+    text = _compile_v5e(v5e, fn, *specs)
     assert la.LATENT_KERNEL_NAME in text
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+    def traced(*a):
+        with _tpu_target():
+            return fn(*a)
+
+    args = [jax.ShapeDtypeStruct(shape, dt) for shape, dt in specs]
+    assert _pallas_grids(jax.make_jaxpr(traced)(*args).jaxpr) == {
+        la.LATENT_KERNEL_NAME: (S,)}
+    _assert_moves_no_pool(text, pool_shape[0] * ps * pool_shape[2])
 
 
 def test_flash_prefill_width_lowers_for_tpu(v5e):

@@ -204,6 +204,113 @@ def _bench_paged_decode(shapes, calls, steps, warmup):
     return rows
 
 
+def _resident_lengths(traffic, slots, max_length, seed):
+    """What ``slots`` slots hold above the knee under a traffic file: a
+    slot is met inside a request for as long as its answer lasts, so the
+    (prompt, answer) pairs are drawn in proportion to the answer and the
+    slot holds the prompt and a uniform part of the answer."""
+    import numpy as np
+
+    from perfbench import loadgen
+
+    rng = np.random.RandomState(seed)
+    n = 4 * int(traffic["clients"])
+    src = loadgen.draw_lengths(traffic["src_len"], n)[rng.permutation(n)]
+    trg = loadgen.draw_lengths(traffic["trg_len"], n)
+    took = rng.choice(n, slots, p=trg / float(trg.sum()))
+    lengths = src[took] + np.floor(
+        rng.uniform(0.0, 1.0, slots) * trg[took]).astype(np.int64)
+    return np.minimum(lengths, max_length).astype("int32")
+
+
+def _bench_latent_decode(cases, walks, steps, warmup, seed=0):
+    """The absorbed latent decode kernel alone at a served cell's
+    geometry (``perfbench/configs/<config>.json``: slots, heads, table
+    width, the 640-wide bfloat16 pool of 128-row pages) with every slot
+    holding what the cell's traffic leaves in it: ``calls`` dependent
+    calls inside one jit (a decode dispatch makes tokens x pools of them).
+    A row a walk (``group``: pages a step of the walk; ``{}`` is the
+    wrapper's own rule): ms a call,
+    the resident pages over the table's, the share of the cost model's
+    least time that the call reaches, and one call's distance from
+    ``latent_paged_attention_reference``."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.kernels import latent_attention as la
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench")
+    with open(os.path.join(root, "peaks.json")) as f:
+        peaks = json.load(f)["chips"][0]
+    rows = []
+    for config, traffic, costs, calls, resize in cases:
+        with open(os.path.join(root, "configs", config + ".json")) as f:
+            cfg = json.load(f)
+        with open(os.path.join(root, "traffic", traffic + ".json")) as f:
+            mix = json.load(f)
+        costs = importlib.import_module("perfbench." + costs)
+        pool_cfg = dict(cfg["pool"], **resize)
+        S, ps = pool_cfg["num_slots"], pool_cfg["page_size"]
+        H, C = cfg["num_attention_heads"], cfg["kv_lora_rank"]
+        R = cfg["qk_rope_head_dim"]
+        T = pool_cfg["max_prompt"] + pool_cfg["max_new_tokens"]
+        npp = -(-T // ps)
+        lengths = _resident_lengths(mix, S, T, seed)
+        pages = -(-lengths // ps)
+        table = np.zeros((S, npp), "int32")
+        order = 1 + np.random.RandomState(seed).permutation(int(pages.sum()))
+        at = 0
+        for slot in range(S):
+            table[slot, :pages[slot]] = order[at:at + pages[slot]]
+            at += pages[slot]
+        dtype = jnp.dtype(cfg["dtype"])
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+        pool = jax.random.normal(
+            k1, (1 + S * npp, ps, la.pool_width(C + R)), dtype)
+        q_lat = jax.random.normal(k2, (S, H, C), dtype)
+        q_rope = jax.random.normal(k3, (S, H, R), dtype)
+        table, lens = jnp.asarray(table), jnp.asarray(lengths)
+        sm_scale = (cfg["qk_nope_head_dim"] + R) ** -0.5
+        least = costs.least_seconds(*costs.latent_decode_attention(
+            cfg, int(lengths.sum()), S), peaks)
+        want = np.asarray(la.latent_paged_attention_reference(
+            q_lat, q_rope, pool, table, lens, sm_scale), "float32")
+        for walk in walks:
+            got = np.asarray(la._latent_pallas(
+                q_lat, q_rope, pool, table, lens, sm_scale,
+                interpret=not _on_tpu(), **walk), "float32")
+
+            def run(q_lat, q_rope, pool, walk=walk):
+                def body(q, _):
+                    out = la._latent_pallas(
+                        q, q_rope, pool, table, lens, sm_scale,
+                        interpret=not _on_tpu(), **walk)
+                    return q + (1e-3 * out).astype(q.dtype), None
+
+                return jax.lax.scan(body, q_lat, None, length=calls)[0]
+
+            fn = jax.jit(run)
+            secs = _time_steps(lambda: fn(q_lat, q_rope, pool), steps,
+                               warmup) / calls
+            row = {"kernel": la.LATENT_KERNEL_NAME, "config": config,
+                   "traffic": traffic, "shape": [S, H, npp, ps],
+                   "walk": walk, "rows": int(lengths.sum()),
+                   "resident_pages": int(pages.sum()),
+                   "table_pages": S * npp,
+                   "pallas_ms": round(secs * 1e3, 4),
+                   "least_ms": round(least * 1e3, 4),
+                   "roofline_share": round(least / secs, 4),
+                   "rel_l2": float(np.linalg.norm(got - want)
+                                   / np.linalg.norm(want))}
+            print(json.dumps(row))
+            rows.append(row)
+    return rows
+
+
 def _bench_state_kernels(names, update_cases, prefill_cases, calls, steps,
                          warmup):
     """A per-slot-state family's two kernels alone against their composed
@@ -478,7 +585,8 @@ def _on_tpu():
 
 
 _FAMILIES = ("dynamic_lstm", "dynamic_gru", "flash_attention",
-             "flash_tiles", "paged_decode", "delta_rule", "ssd")
+             "flash_tiles", "paged_decode", "latent_decode", "delta_rule",
+             "ssd")
 
 
 def _orchestrate(args):
@@ -560,6 +668,11 @@ def main():
             [(1, 128, 100, 2, 16, 16)]
         ssd_update, ssd_prefill = [(4, 3, 4, 8, 16)], \
             [(2, 64, 40, 4, 8, 16)]
+        latent_cases = [("glm47_flash_6l", "closed_320_chat",
+                         "kernel_costs_glm", 2,
+                         dict(num_slots=4, max_prompt=256,
+                              max_new_tokens=128))]
+        latent_walks = ({}, dict(group=2))
     else:
         steps, warmup = 20, 5
         rnn_shapes = [(32, 128, 256), (64, 256, 512), (16, 512, 1024)]
@@ -570,6 +683,16 @@ def main():
         # 6 layers)
         paged_shapes = [(256, live, 8, 64, 16, 256) for live in (8, 32, 256)]
         paged_calls = 24
+        # perfbench glm47_flash_6l under closed_320_chat (256 slots x 20
+        # heads x 12 pages, 24 calls a dispatch: 4 tokens x 6 layers) and
+        # longcat_flash_omni_4l under closed_80_agentic (64 x 64 x 40, 32
+        # calls: 4 tokens x 8 pools); the wrapper's rule, then the sweep
+        latent_cases = [
+            ("glm47_flash_6l", "closed_320_chat", "kernel_costs_glm", 24,
+             {}),
+            ("longcat_flash_omni_4l", "closed_80_agentic",
+             "kernel_costs_longcat", 32, {})]
+        latent_walks = ({},) + tuple(dict(group=g) for g in (1, 2, 4, 8))
         # perfbench solar_open2_4l: 96 slots (all live, and two thirds)
         # of 64 heads of 128 x 128, 12 calls a dispatch (4 tokens x 3
         # linear layers); one 8192-token prompt, whole and ended inside a
@@ -596,6 +719,8 @@ def main():
                    steps, warmup)
     elif args.family == "paged_decode":
         _bench_paged_decode(paged_shapes, paged_calls, steps, warmup)
+    elif args.family == "latent_decode":
+        _bench_latent_decode(latent_cases, latent_walks, steps, warmup)
     elif args.family == "delta_rule":
         _bench_delta_rule(update_shapes, prefill_shapes,
                           2 if args.quick else 12, steps, warmup)
