@@ -41,7 +41,10 @@ compiles that happen outside this module (stray helper jits) are counted
 too — the numbers are the process's whole compile tax, not just the
 executor's share. ``trace_seconds`` / ``lower_seconds`` come from the same
 taps: the time JAX spent tracing Python to jaxprs and lowering jaxprs to
-MLIR modules, which no cache layer here saves.
+MLIR modules, which no cache layer here saves. ``by_function`` is the same
+three totals by the jitted function's name (docs/OBSERVABILITY.md, "The
+set-up ledger"): an executable's own step, the IR builder's shape
+inference, a kernel's body, a stray helper each have a row.
 """
 
 import hashlib
@@ -86,7 +89,17 @@ _stats.update(
     # jaxpr and lowering the jaxpr to an MLIR module
     trace_seconds=0.0,
     lower_seconds=0.0,
+    # the part of ``lower_seconds`` that ``trace_seconds`` counts too:
+    # tracing reports that fired inside a lowering (a lowering rule that
+    # traces; 0.05-0.17 s of a cell's set-up on the chip, PERF.md, PR 51)
+    trace_in_lower_seconds=0.0,
 )
+# {fun_name: [calls, trace_s, lower_s, backend_s]}: ``trace_seconds``,
+# ``lower_seconds`` and ``compile_seconds`` by jitted function; past the
+# cap, under "<other>"
+_by_function = {}
+_FUNCTIONS_CAP = 256
+_TRACE, _LOWER, _BACKEND = 1, 2, 3
 
 _configured = {"dir": None}
 
@@ -108,7 +121,23 @@ def _on_event(name, **kw):
         _tls.last = "miss"
 
 
-def _on_duration(name, secs, **kw):
+def _book(kind, fun_name, secs):
+    """``secs`` to ``fun_name``'s row of ``by_function``. Under _lock."""
+    # a module is named jit_<function>, an eager primitive's
+    # jit(<primitive>): one name for the three kinds
+    fun_name = str(fun_name or "?")
+    if fun_name.startswith("jit_"):
+        fun_name = fun_name[4:]
+    elif fun_name.startswith("jit(") and fun_name.endswith(")"):
+        fun_name = fun_name[4:-1]
+    if fun_name not in _by_function and len(_by_function) >= _FUNCTIONS_CAP:
+        fun_name = "<other>"
+    row = _by_function.setdefault(fun_name, [0, 0.0, 0.0, 0.0])
+    row[0] += kind == _TRACE
+    row[kind] += secs
+
+
+def _on_duration(name, secs, fun_name=None, **kw):
     if name == "/jax/core/compile/backend_compile_duration":
         # the hit/miss event for THIS compile fired earlier on this same
         # thread (jax records them synchronously inside the compile call),
@@ -122,6 +151,7 @@ def _on_duration(name, secs, **kw):
                 _stats["compile_seconds_warm"] += secs
             else:
                 _stats["compile_seconds_cold"] += secs
+            _book(_BACKEND, fun_name, secs)
         _record_compile_span("xla_backend_compile", secs,
                              "warm" if last == "hit" else "cold")
     elif name == "/jax/compilation_cache/cache_retrieval_time_sec":
@@ -145,9 +175,22 @@ def _on_duration(name, secs, **kw):
             del done[:512]
         with _lock:
             _stats["trace_seconds"] += net
+            _book(_TRACE, fun_name, net)
     elif name == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        # one module a top-level jit, so no lowering lies inside another
+        # and the sum needs no union; what does lie inside one is tracing,
+        # which ``trace_seconds`` has counted: those seconds are in both
+        # totals, and ``trace_in_lower_seconds`` says how many they are
+        began = time.perf_counter() - secs
+        inside = 0.0
+        for end, traced in reversed(getattr(_tls, "traces", ())):
+            if end <= began:
+                break
+            inside += traced
         with _lock:
             _stats["lower_seconds"] += secs
+            _stats["trace_in_lower_seconds"] += inside
+            _book(_LOWER, fun_name, secs)
 
 
 def _record_compile_span(name, secs, kind):
@@ -190,9 +233,15 @@ def stats():
     XLA compiles no cache layer could serve — the warm-start smoke stage
     asserts it is zero in a second process sharing the cache.
     ``enabled``/``cache_dir`` describe the AOT image layer (the flag);
-    ``xla_cache_dir`` is where JAX's persistent cache is live, or None."""
+    ``xla_cache_dir`` is where JAX's persistent cache is live, or None.
+    ``by_function`` is ``{fun_name: [calls, trace_s, lower_s, backend_s]}``:
+    the columns add up to ``trace_seconds``, ``lower_seconds`` and
+    ``compile_seconds``."""
     with _lock:
         snap = dict(_stats)
+        # fresh rows a call: a caller's shallow copy of the snapshot (the
+        # benchmark's, at the end of warm-up) keeps the table as it was
+        snap["by_function"] = {k: list(v) for k, v in _by_function.items()}
     snap["enabled"] = _configured["dir"] is not None
     snap["cache_dir"] = _configured["dir"]
     snap["xla_cache_dir"] = (
@@ -209,6 +258,7 @@ def reset_stats():
     with _lock:
         for k in _stats:
             _stats[k] = 0.0 if isinstance(_stats[k], float) else 0
+        _by_function.clear()
 
 
 # -- configuration -----------------------------------------------------------
@@ -397,6 +447,7 @@ def _load_aot(path):
         _stats["aot_hits"] += 1
         _stats["compile_seconds"] += dt
         _stats["compile_seconds_warm"] += dt
+        _book(_BACKEND, "aot_image_load", dt)
     _record_compile_span("aot_image_load", dt, "warm")
     try:
         # HBM ledger (observability/memory.py): a deserialized image's

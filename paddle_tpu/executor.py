@@ -190,7 +190,7 @@ def _shared_executable(key, build, program, feed_specs, fetch_names,
         why, program=key[0], fetch_names=tuple(fetch_names),
         feed_specs=tuple(sorted(
             (n, (s, d)) for n, (s, d) in feed_specs.items()))),
-        forced=refresh)
+        forced=refresh, program=program)
 
     def attempt():
         if _chaos.ENABLED:

@@ -561,7 +561,9 @@ def _infer_op_shapes(block, op):
         if arrs:
             ins_structs[slot] = arrs
 
-    def f(ins):
+    # named for the set-up ledger: one trace an appended op, this
+    # function's row of ``exec_cache.stats()["by_function"]``
+    def infer_op_shapes(ins):
         import jax.random as jrandom
 
         from paddle_tpu.core.lowering import BlockLowerer
@@ -576,7 +578,7 @@ def _infer_op_shapes(block, op):
         )
         return op_registry.normalize_outputs(opdef, opdef.lower(ctx, ins, op.attrs))
 
-    out = jax.eval_shape(f, ins_structs)
+    out = jax.eval_shape(infer_op_shapes, ins_structs)
     for slot, structs in out.items():
         names = op.output(slot)
         for name, s in zip(names, structs):

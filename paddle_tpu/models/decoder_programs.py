@@ -50,6 +50,7 @@ import paddle_tpu as fluid
 from paddle_tpu import unique_name
 from paddle_tpu.kernels.paged_attention import pages_for
 from paddle_tpu.models.transformer import _sampler_attrs
+from paddle_tpu.observability.explain import setup_span
 
 __all__ = ["DecoderFamily", "build_decoder_programs", "builder_for",
            "load_named", "load_parameters"]
@@ -228,10 +229,12 @@ def build_decoder_programs(family_of, desc, num_slots, max_positions,
                        append_batch_size=False)
 
     @contextlib.contextmanager
-    def program(**known):
-        """A new program under construction and its frame."""
+    def program(label, **known):
+        """A new program under construction and its frame, under the
+        set-up span ``label`` (what the program's executables are called
+        in the set-up ledger)."""
         prog = fluid.Program()
-        with unique_name.guard({}), \
+        with setup_span(label, prog), unique_name.guard({}), \
                 fluid.program_guard(prog, fluid.Program()):
             blk = prog.global_block()
             yield prog, types.SimpleNamespace(
@@ -262,7 +265,7 @@ def build_decoder_programs(family_of, desc, num_slots, max_positions,
                     [nn.reshape(c, shape=[1] + list(c.shape))
                      for c in parts], axis=0), parts[0].dtype)
 
-    with program() as (init, f):
+    with program("init") as (init, f):
         for n, var in f.state.items():
             nn.assign(nn.fill_constant(list(specs[n]["shape"]),
                                        specs[n]["dtype"], 0.0), output=var)
@@ -271,7 +274,10 @@ def build_decoder_programs(family_of, desc, num_slots, max_positions,
 
     by_rows = {T: {} for T in buckets}
     for T, B in [(T, B) for T in buckets for B in rungs[T]]:
-        with program(rows=B, bucket=T) as (by_rows[T][B], f):
+        # a rung under a bucket's most rows: prefill/<bucket>/<rows>
+        label = "prefill/%d" % T + ("" if B == per_dispatch[T]
+                                    else "/%d" % B)
+        with program(label, rows=B, bucket=T) as (by_rows[T][B], f):
             ids = feed("prompt_ids", [B * T])
             f.lens = feed("prompt_len", [B])
             f.slot_idx = feed("slot_idx", [B])
@@ -294,7 +300,7 @@ def build_decoder_programs(family_of, desc, num_slots, max_positions,
             nn.slot_rows_write(f.pos, f.slot_idx, lens2)
             stacked(stacks)
 
-    with program() as (step, f):
+    with program("step") as (step, f):
         f.table = feed("page_table", [S, npp])
         f.ring_tables = [feed(ring["table_feed"],
                               [S, ring["pages_per_slot"]])

@@ -9,6 +9,8 @@ and pre-norm residual blocks (better large-scale training stability).
 """
 
 import paddle_tpu as fluid
+from paddle_tpu.observability.explain import (
+    name_program as _name_program, setup_span as _setup_span)
 
 
 def _ffn(x, d_model, d_inner, name):
@@ -708,7 +710,8 @@ def build_slot_decoder(
     with unique_name.guard({}):
         init = fluid.Program()
         init_startup = fluid.Program()
-        with fluid.program_guard(init, init_startup):
+        with _setup_span("init", init), \
+                fluid.program_guard(init, init_startup):
             blk = init.global_block()
 
             def persist(name, value):
@@ -729,7 +732,8 @@ def build_slot_decoder(
 
         admit = fluid.Program()
         admit_startup = fluid.Program()
-        with fluid.program_guard(admit, admit_startup):
+        with _setup_span("admit/1", admit), \
+                fluid.program_guard(admit, admit_startup):
             blk = admit.global_block()
             src = nn.data("src_word", shape=[T], dtype="int64")
             src_len = nn.data("src_len", shape=[1], dtype="int64")
@@ -773,7 +777,8 @@ def build_slot_decoder(
 
         step = fluid.Program()
         step_startup = fluid.Program()
-        with fluid.program_guard(step, step_startup):
+        with _setup_span("step", step), \
+                fluid.program_guard(step, step_startup):
             blk = step.global_block()
             cur = nn.data("cur_tok", shape=[1], dtype="int64")
             pe_row = nn.data("pe_row", shape=[1, D], dtype="float32")
@@ -1160,7 +1165,8 @@ def build_paged_slot_decoder(
     with unique_name.guard({}):
         init = fluid.Program()
         init_startup = fluid.Program()
-        with fluid.program_guard(init, init_startup):
+        with _setup_span("init", init), \
+                fluid.program_guard(init, init_startup):
             blk = init.global_block()
 
             def persist(name, value, dtype="float32"):
@@ -1198,13 +1204,15 @@ def build_paged_slot_decoder(
                 persist("pgd_score",
                         nn.fill_constant([S, 1], "float32", 0.0))
 
-        admit = _build_admit_prog(
-            1, S, T, D, G, npp, n_layer, n_head, d_inner, src_vocab_size,
-            beam)
+        with _setup_span("admit/1"):
+            admit = _name_program(_build_admit_prog(
+                1, S, T, D, G, npp, n_layer, n_head, d_inner,
+                src_vocab_size, beam))
 
         join = fluid.Program()
         join_startup = fluid.Program()
-        with fluid.program_guard(join, join_startup):
+        with _setup_span("join", join), \
+                fluid.program_guard(join, join_startup):
             blk = join.global_block()
             _register_member(blk, S, npp, _slot_state_feeds(npp, beam))
 
@@ -1214,7 +1222,7 @@ def build_paged_slot_decoder(
         # layers (norms/fcs) exactly like the step program will; a
         # FRESH name scope gives both the training build's .w_0/.w_1
         # parameter suffixes instead of shifting each other's counters
-        with unique_name.guard({}), \
+        with _setup_span("prefill", prefill), unique_name.guard({}), \
                 fluid.program_guard(prefill, prefill_startup):
             blk = prefill.global_block()
             pword = nn.data("prefix_word", shape=[T], dtype="int64")
@@ -1286,7 +1294,8 @@ def build_paged_slot_decoder(
 
         table = fluid.Program()
         table_startup = fluid.Program()
-        with fluid.program_guard(table, table_startup):
+        with _setup_span("release/1", table), \
+                fluid.program_guard(table, table_startup):
             blk = table.global_block()
             slot = nn.data("slot_idx", shape=[1], dtype="int64",
                            append_batch_size=False)
@@ -1297,7 +1306,8 @@ def build_paged_slot_decoder(
 
         step = fluid.Program()
         step_startup = fluid.Program()
-        with fluid.program_guard(step, step_startup):
+        with _setup_span("step", step), \
+                fluid.program_guard(step, step_startup):
             blk = step.global_block()
 
             def pvar(name, shape, dtype="float32"):
@@ -1421,7 +1431,7 @@ def build_paged_slot_decoder(
             # param-owning layers, so a FRESH name scope keeps the
             # .w_0/.w_1 parameter suffixes aligned with the training
             # build instead of shifting the outer scope's counters
-            with unique_name.guard({}), \
+            with _setup_span("spec", spec), unique_name.guard({}), \
                     fluid.program_guard(spec, spec_startup):
                 blk = spec.global_block()
 

@@ -14,11 +14,24 @@ Events go to the ``paddle_tpu.observability.explain`` logger as JSON, to
 the metrics registry (``paddle_tpu_recompiles_total{changed=...}``), and
 to a bounded in-process list (:func:`events`) for tests and tooling.
 Always on: the cost is one dict diff per *compile*, never per step.
+
+The event also says WHO asked for the executable (docs/OBSERVABILITY.md,
+"The set-up ledger"): ``seq`` (its index over the ring's lifetime),
+``ops`` (the program's operator count) and ``label``/``span``: the path
+and index of a SET-UP SPAN. :func:`setup_span` is a named span around the
+code that BUILDS a program or makes its first run (the two serving
+sessions' constructors and builders); a program built under one is
+stamped with the span's path, so an executable first asked for later, by
+the warm-up with no span open, still carries the name. Stamps (``ts`` of
+a record, ``t0``/``t1`` of a span) are ``time.perf_counter()`` moved onto
+``time.time()``'s epoch by one offset taken at import, so they lie beside
+the dispatch records' and the round spans' stamps.
 """
 
 import collections
 import json
 import logging
+import functools
 import threading
 import time
 
@@ -26,7 +39,8 @@ from paddle_tpu.observability import lock_witness
 from paddle_tpu.observability.metrics_registry import REGISTRY
 
 __all__ = ["record_compile", "events", "reset", "COMPONENTS",
-           "COMPONENT_LINT_RULES"]
+           "COMPONENT_LINT_RULES", "setup_span", "setup_spans",
+           "name_program", "spanned", "stamp"]
 
 logger = logging.getLogger("paddle_tpu.observability.explain")
 
@@ -62,6 +76,18 @@ _lock = lock_witness.make_lock("observability.explain")
 _entries = collections.deque(maxlen=_MAX_ENTRIES)  # recent compile keys
 _events = []     # bounded structured event log
 _compile_count = [0]
+_MAX_SPANS = 1024
+_spans = []      # bounded set-up spans, beside the records
+_span_base = [0]  # spans dropped off the front: indices stay lifetime's
+_tls = threading.local()
+# time.perf_counter() + _EPOCH reads as time.time() did at import
+_EPOCH = time.time() - time.perf_counter()
+
+
+def stamp(perf=None):
+    """``time.perf_counter()`` (now, or ``perf``) on ``time.time()``'s
+    epoch: the clock of every ``t0``/``t1`` here."""
+    return (time.perf_counter() if perf is None else perf) + _EPOCH
 
 _recompiles = REGISTRY.counter(
     "paddle_tpu_recompiles_total",
@@ -112,12 +138,110 @@ def _describe_change(key, old, new):
     return "%r -> %r" % (old, new)
 
 
-def record_compile(components, forced=False):
+# -- set-up spans ------------------------------------------------------------
+
+class setup_span(object):
+    """``with setup_span("cow/4"):`` around code that builds a program or
+    makes its first run. Kept in a bounded list (:func:`setup_spans`) as
+    ``{"index", "name", "path", "t0", "t1", "parent"}``: ``index`` counts
+    spans over the list's lifetime and ``parent`` is the enclosing span's
+    (None at a root); ``path`` joins the names from the thread's
+    outermost open span down with ``/`` and is what a record asked for
+    under the span takes as its ``label``. ``program`` is stamped with
+    the path (``name_program``), so an executable that is first run
+    later, with no span open, still carries the name. Two clock reads;
+    always on, like the events: set-up only, never a step."""
+
+    __slots__ = ("name", "program", "_index")
+
+    def __init__(self, name, program=None):
+        self.name, self.program = name, program
+
+    def __enter__(self):
+        parent, above = _innermost()
+        path = self.name if above is None else above + "/" + self.name
+        span = {"name": self.name, "path": path, "t0": stamp(),
+                "t1": None, "parent": parent}
+        self._index = _keep_span(span)
+        _tls.stack.append((self._index, path))
+        if self.program is not None:
+            self.program._setup_label = path
+        return self
+
+    def __exit__(self, *exc):
+        _tls.stack.pop()
+        _close_span(self._index)
+        return False
+
+
+def _keep_span(span):
+    """Append; returns the span's ``index`` (the list drops its older
+    half when full, the count goes on)."""
+    with _lock:
+        span["index"] = _span_base[0] + len(_spans)
+        _spans.append(span)
+        if len(_spans) > _MAX_SPANS:
+            del _spans[:_MAX_SPANS // 2]
+            _span_base[0] += _MAX_SPANS // 2
+        return span["index"]
+
+
+def _close_span(index):
+    with _lock:
+        at = index - _span_base[0]
+        if at >= 0:
+            _spans[at]["t1"] = stamp()
+
+
+def _innermost():
+    """(index, path) of this thread's innermost open span, or (None,
+    None)."""
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    return stack[-1] if stack else (None, None)
+
+
+def name_program(program):
+    """Stamp ``program`` with the innermost open span's path, as
+    ``setup_span(name, program)`` does for a program it is given: the
+    ``label`` of the executables made from it, whenever they are first
+    asked for. Returns the program."""
+    program._setup_label = _innermost()[1]
+    return program
+
+
+def spanned(name):
+    """Decorator: every call runs under ``setup_span(name)`` (a
+    session's constructor under its root ``session.init``)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def under_span(*args, **kwargs):
+            with setup_span(name):
+                return fn(*args, **kwargs)
+        return under_span
+    return wrap
+
+
+def setup_spans():
+    """The set-up spans (oldest first, bounded), as copies."""
+    with _lock:
+        return [dict(sp) for sp in _spans]
+
+
+# -- the record --------------------------------------------------------------
+
+def record_compile(components, forced=False, program=None):
     """One fresh XLA trace. ``components`` maps COMPONENTS keys to the
     new cache-key pieces; ``forced`` marks use_program_cache=False
-    bypasses (nothing to blame — the caller asked). Returns the event."""
+    bypasses (nothing to blame — the caller asked). ``program`` gives
+    the event its ``ops`` and, where it was built under a set-up span,
+    its ``label``. Returns the event."""
     comp = _canon(components)
-    now = time.time()
+    now = stamp()
+    span, label = _innermost()
+    if program is not None:
+        label = getattr(program, "_setup_label", None) or label
     with _lock:
         nearest = None
         nearest_score = -1
@@ -161,6 +285,10 @@ def record_compile(components, forced=False):
         "device": comp.get("device"),
         "compiles_so_far": n_compiles,
     }
+    event.update(
+        seq=n_compiles - 1, label=label, span=span,
+        ops=(sum(len(b.ops) for b in program.blocks)
+             if program is not None else None))
     with _lock:
         _events.append(event)
         del _events[:-_MAX_EVENTS]
@@ -176,8 +304,10 @@ def events():
 
 
 def reset():
-    """Forget prior compiles and events (tests)."""
+    """Forget prior compiles, events and spans (tests)."""
     with _lock:
         _entries.clear()
         del _events[:]
         _compile_count[0] = 0
+        del _spans[:]
+        _span_base[0] = 0

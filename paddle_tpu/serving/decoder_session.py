@@ -120,6 +120,7 @@ import numpy as np
 from paddle_tpu.core.types import np_dtype
 from paddle_tpu.kernels.paged_attention import pages_for
 from paddle_tpu.models.decoder_programs import builder_for
+from paddle_tpu.observability import explain as _explain
 from paddle_tpu.observability import tracing as _tracing
 from paddle_tpu.serving.generation import (
     NoFreeSlotError,
@@ -348,7 +349,13 @@ class DecoderOnlySession(object):
         self._released = 0       # ring pages given back since _count()
         self.probe_slots = np.zeros((int(probe_rows),), "int64")
         self.admit_token_budget = None
-        self._exe.run(built["init"], scope=self._scope)
+        # the pools' and the per-slot arrays' device allocations. The
+        # builder's spans (``init``, ``prefill/<bucket>``, ``step``) are
+        # roots: a span or a decorator around this whole constructor cost
+        # the Jamba cell 4 s of its 21 s of IR building on the chip's host
+        # (PERF.md section 6, PR 51)
+        with _explain.setup_span("pools"):
+            self._exe.run(built["init"], scope=self._scope)
 
     # -- what this session does not do ---------------------------------------
     _paged = True

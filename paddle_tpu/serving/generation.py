@@ -73,6 +73,7 @@ from collections import deque
 
 import numpy as np
 
+from paddle_tpu.observability import explain as _explain
 from paddle_tpu.observability import tracing as _tracing
 from paddle_tpu.observability.metrics_registry import REGISTRY as _REGISTRY
 from paddle_tpu.resilience import chaos as _chaos
@@ -239,6 +240,10 @@ class SlotDecodeSession(object):
     docs/SERVING.md "Speculative decode".
     """
 
+    # the set-up ledger (docs/OBSERVABILITY.md): the root span; under it
+    # one span a program family and member, around its building and,
+    # where the constructor makes it, its first run
+    @_explain.spanned("session.init")
     def __init__(self, exe, num_slots, max_length=64, d_model=128,
                  bos_id=1, eos_id=2, scope=None, paged=False,
                  page_size=8, num_pages=None, num_groups=None, steps=1,
@@ -347,7 +352,9 @@ class SlotDecodeSession(object):
                 self._beam_fetches = dict(self._fetch_name)
                 self._fetch_name = self._beam_fetches["token"]
             pe = transformer.position_encoding_table(self._T, self._D)
-            self._run(self._init_prog, {"pe_table": pe}, [])
+            # the pools' device allocations: the init program fills them
+            with _explain.setup_span("pools"):
+                self._run(self._init_prog, {"pe_table": pe}, [])
             # page 0 is the trash page: never allocated, every
             # unoccupied slot's table row points at it. Pages carry
             # refcounts (kv_pool.PagePool): shared pages free only when
@@ -406,12 +413,13 @@ class SlotDecodeSession(object):
             # zero-recompile steady state must not depend on which
             # window sizes churn happens to produce first.
             for rung in self._cow_rungs:
-                self._run(self._cow_prog(rung), {
-                    "src_pages": np.zeros(rung, "int64"),
-                    "dst_pages": np.zeros(rung, "int64"),
-                    "slot_idxs": np.zeros(rung, "int64"),
-                    "page_rows": np.zeros((rung, self._npp), "int64"),
-                }, [])
+                with _explain.setup_span("cow/%d" % rung):
+                    self._run(self._cow_prog(rung), {
+                        "src_pages": np.zeros(rung, "int64"),
+                        "dst_pages": np.zeros(rung, "int64"),
+                        "slot_idxs": np.zeros(rung, "int64"),
+                        "page_rows": np.zeros((rung, self._npp), "int64"),
+                    }, [])
             # batched admission (admit_pending): one encoder dispatch for
             # the head run of the queue, its row count a rung of a short
             # ladder. Every rung is built and warmed HERE by an
@@ -437,22 +445,24 @@ class SlotDecodeSession(object):
             self.release_rows = 0        # slots they repointed
             self.release_pad_rows = 0    # rows of padding beside them
             for rung in self._rungs[1:]:
-                self._table_progs[rung] = \
-                    transformer.build_table_batch_prog(
-                        rung, self._S, max_length=self._T,
-                        page_size=self._ps)
-                self._run(self._table_progs[rung],
-                          self._trash_feed((), rung), [])
+                with _explain.setup_span("release/%d" % rung):
+                    self._table_progs[rung] = \
+                        transformer.build_table_batch_prog(
+                            rung, self._S, max_length=self._T,
+                            page_size=self._ps)
+                    self._run(self._table_progs[rung],
+                              self._trash_feed((), rung), [])
             for rung in self._admit_rungs[1:]:
-                self._admit_progs[rung] = \
-                    transformer.build_admit_batch_prog(
-                        rung, self._S, max_length=self._T,
-                        d_model=self._D, page_size=self._ps,
-                        num_groups=self._G,
-                        **{k: v for k, v in decoder_cfg.items()
-                           if k != "trg_vocab_size"})
-                self._run(self._admit_progs[rung],
-                          self._admit_feed((), rung), [])
+                with _explain.setup_span("admit/%d" % rung):
+                    self._admit_progs[rung] = \
+                        transformer.build_admit_batch_prog(
+                            rung, self._S, max_length=self._T,
+                            d_model=self._D, page_size=self._ps,
+                            num_groups=self._G,
+                            **{k: v for k, v in decoder_cfg.items()
+                               if k != "trg_vocab_size"})
+                    self._run(self._admit_progs[rung],
+                              self._admit_feed((), rung), [])
             # beam bookkeeping (beam_width > 1): lanes of K aligned
             # slots; per-step parent permutations mirrored here
             self._beam_live = {}      # lane -> {"slots": [...]}
@@ -515,7 +525,8 @@ class SlotDecodeSession(object):
              self._fetch_name) = transformer.build_slot_decoder(
                 num_slots, max_length=max_length, d_model=d_model,
                 eos_id=eos_id, sampler=sampler, **decoder_cfg)
-            self._run(self._init_prog, {}, [])
+            with _explain.setup_span("pools"):
+                self._run(self._init_prog, {}, [])
             self._admit_rungs = ()  # the dense layout admits one by one
         self._free = list(range(self._S - 1, -1, -1))
         self._live = {}  # slot -> {"trg": [T] int64, "pos": int}
