@@ -187,12 +187,15 @@ def _finish_slot(o_ref, acc_ref, l_ref):
 
 
 def _walk_resident_pages(table_ref, slot, pages_of, pools, bufs, sem,
-                         ahead_ref, absorb, group=1):
+                         ahead_ref, absorb, group=1, column_of=None):
     """Walk one slot's resident pages INSIDE a kernel body whose grid is
     ``(slots,)``, ``group`` pages a step of the walk. ``pages_of(slot)``
-    is how many of the slot's table entries are resident; page ``p`` of
-    the slot is ``table_ref[slot, p]`` of every pool in ``pools`` (refs
-    left in HBM, ``[P, page_size, width]``), fetched by the kernel's own
+    is how many of the slot's pages are resident; page ``p`` of the slot
+    is ``table_ref[slot, p]`` of every pool in ``pools`` (refs left in
+    HBM, ``[P, page_size, width]``), or ``table_ref[slot, column_of(slot,
+    p)]`` where the table is not in page order (the one hook: a RING's
+    ``p``-th resident page sits in column ``(first page + p) % R``),
+    fetched by the kernel's own
     copy into one half of the matching ``bufs`` scratch (``[2, group *
     page_size, width]``) while the pages before it are absorbed from the
     other half; ``sem`` is a DMA semaphore a pool and half
@@ -246,7 +249,8 @@ def _walk_resident_pages(table_ref, slot, pages_of, pools, bufs, sem,
             p = g if G == 1 else g * G + j
 
             def one(p=p, j=j):
-                page = table_ref[of_slot, p]
+                page = table_ref[
+                    of_slot, p if column_of is None else column_of(of_slot, p)]
                 for i, (pool, buf) in enumerate(zip(pools, bufs)):
                     dst = (buf.at[half] if G == 1
                            else buf.at[half, pl.ds(j * ps, ps)])

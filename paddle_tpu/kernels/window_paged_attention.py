@@ -1,27 +1,30 @@
 """Grouped-query paged decode attention over the last ``window`` positions
 of every slot, for a layer whose cache is a RING of pages.
 
-``gqa_paged_attention``'s grid ``(slot, page)``, online softmax, row
-layout ``[pages, page_size, Hkv * dh]`` and trash page, plus a first
-visible position a slot (``max(length - window, 0)``). What a window layer
-changes:
+``gqa_paged_attention``'s kernel (grid ``(slot,)``, the pools left in HBM,
+a slot's resident pages walked inside the body several a step, online
+softmax, row layout ``[pages, page_size, Hkv * dh]``, trash page) over a
+first visible position a slot (``max(length - window, 0)``). What a window
+layer changes:
 
 * **The table is a ring**, ``[S, R]``: logical page ``j`` of a slot (the
   rows of positions ``j * page_size`` and on) is held in column ``j % R``,
   so the entries are not in position order. The host gives a page back
   the moment its last row is behind every query the next dispatch can
   hold, and ``R`` pages a slot are enough whatever the sequence's length.
-* **Pages behind the window cost nothing**: the grid's step ``p`` holds
-  logical page ``first // page_size + p`` (position order, whatever the
-  column), steps past the slot's last page alias it (no copy, by the
-  pipeline's unchanged block index) and skip their compute, and the first
-  page's rows before ``first`` are masked.
+* **Pages behind the window cost nothing**: the walk's ``p``-th page of a
+  slot is logical page ``first // page_size + p`` (position order), found
+  through the walk's one hook, the COLUMN of a slot's ``p``-th page
+  (``(first // page_size + p) % R``); ``last - first + 1`` pages are
+  resident (none in an empty slot), no other column is read, and the
+  first page's rows before ``first`` are masked.
 * **A kernel name of its own** (``WINDOW_KERNEL_NAME``): a device trace
   tells a window layer's calls from a full layer's.
 
-The per-page update is ``gqa_paged_attention._gqa_decode_kernel``'s,
-written out again: that file's kernel is traced into two served models'
-programs, and Mosaic's serialised body changes with any edit there.
+The body of a grid step and the ``pallas_call`` are
+``gqa_paged_attention._attend_slot`` / ``_slot_walk_call``, written once
+for both files: the full-table kernel is this one with ``first`` 0 and the
+identity column.
 
 ``window_paged_attention_reference`` is the composed path beside it (the
 explicit oracle, and the default off the TPU).
@@ -34,9 +37,9 @@ import jax.numpy as jnp
 
 from paddle_tpu.kernels.flash_attention import _is_tpu_target
 from paddle_tpu.kernels.gqa_paged_attention import (
-    _GROUP_TILE,
-    _MASKED_ROW_M,
     _NEG_INF,
+    _attend_slot,
+    _slot_walk_call,
 )
 from paddle_tpu.kernels.paged_attention import KernelCompileError
 
@@ -83,98 +86,36 @@ def window_paged_attention_reference(q, k_pool, v_pool, ring_table, lengths,
     return jnp.where(dead, 0.0, out).astype(q.dtype)
 
 
-def _window_decode_kernel(table_ref, first_ref, len_ref, q_ref, k_ref, v_ref,
-                          o_ref, acc_ref, m_ref, l_ref, *, page_size, n_cols,
-                          kv_heads, group, head_dim, sm_scale):
-    """One (slot, page) step: every query group absorbs its head's part
-    of one resident page of the window into its online-softmax state."""
+def _window_decode_kernel(table_ref, first_ref, len_ref, q_ref, *refs,
+                          page_size, **dims):
+    """One grid step is one SLOT over a ring: its ``p``-th resident page
+    is logical page ``first // page_size + p``, in the ring's column of
+    that page modulo ``R``, and the rows before ``first`` are masked."""
     from jax.experimental import pallas as pl
 
     s = pl.program_id(0)
-    p = pl.program_id(1)
+    R = table_ref.shape[1]
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def pages_of(slot):
+        lo, hi = ring_pages(first_ref[slot], len_ref[slot], page_size)
+        return jnp.where(len_ref[slot] > 0, hi - lo + 1, 0)
 
-    first, length = first_ref[s], len_ref[s]
-    base = (first // page_size + p) * page_size
+    def column_of(slot, p):
+        return (first_ref[slot] // page_size + p) % R
 
-    def _compute():
-        for h in range(kv_heads):
-            rows = slice(h * group, (h + 1) * group)
-            lanes = slice(h * head_dim, (h + 1) * head_dim)
-            q = q_ref[0, rows, :]                         # [group, dh]
-            k = k_ref[0, :, lanes]                        # [ps, dh]
-            v = v_ref[0, :, lanes]
-            sc = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            pos = base + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-            sc = jnp.where((pos >= first) & (pos < length), sc, _NEG_INF)
-            m_prev = m_ref[rows, :]
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-            pexp = jnp.exp(sc - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
-                pexp, axis=-1, keepdims=True)
-            acc_ref[rows, :] = acc_ref[rows, :] * alpha + jax.lax.dot_general(
-                pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)       # [group, dh]
-            m_ref[rows, :] = m_new
-
-    pl.when(base < length)(_compute)
-
-    @pl.when(p == n_cols - 1)
-    def _finish():
-        dead = m_ref[...] <= _MASKED_ROW_M
-        o_ref[0] = jnp.where(
-            dead, 0.0,
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+    _attend_slot(table_ref, s, first_ref[s], len_ref[s], pages_of, column_of,
+                 q_ref, *refs, page_size=page_size, **dims)
 
 
+# jitted for the reason ``gqa_paged_attention._gqa_pallas`` is
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "interpret", "group"))
 def _window_pallas(q, k_pool, v_pool, ring_table, first, lengths, sm_scale,
-                   interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    S, H, dh = q.shape
-    ps, width = k_pool.shape[1], k_pool.shape[2]
-    Hkv = width // dh
-    g = H // Hkv
-    gp = -(-g // _GROUP_TILE) * _GROUP_TILE
-    R = ring_table.shape[1]
-    qg = q.reshape(S, Hkv, g, dh).astype(k_pool.dtype)
-    if gp != g:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    qg = qg.reshape(S, Hkv * gp, dh)
-
-    def page_of(s, p, table, first, lens):
-        lo, hi = ring_pages(first[s], lens[s], ps)
-        return (table[s, jnp.minimum(lo + p, hi) % R], 0, 0)
-
-    q_spec = pl.BlockSpec((1, Hkv * gp, dh),
-                          lambda s, p, table, first, lens: (s, 0, 0))
-    kv_spec = pl.BlockSpec((1, ps, width), page_of)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(S, R),
-        in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((Hkv * gp, dh), jnp.float32),
-                        pltpu.VMEM((Hkv * gp, 1), jnp.float32),
-                        pltpu.VMEM((Hkv * gp, 1), jnp.float32)])
-    out = pl.pallas_call(
-        functools.partial(
-            _window_decode_kernel, page_size=ps, n_cols=R, kv_heads=Hkv,
-            group=gp, head_dim=dh, sm_scale=sm_scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Hkv * gp, dh), q.dtype),
-        interpret=interpret, name=WINDOW_KERNEL_NAME,
-    )(ring_table.astype(jnp.int32), first.astype(jnp.int32),
-      lengths.astype(jnp.int32), qg, k_pool, v_pool)
-    return out.reshape(S, Hkv, gp, dh)[:, :, :g].reshape(S, H, dh)
+                   interpret, group=None):
+    return _slot_walk_call(
+        _window_decode_kernel, WINDOW_KERNEL_NAME,
+        (ring_table, first, lengths), q, k_pool, v_pool, sm_scale, interpret,
+        group)
 
 
 def window_paged_attention(q, k_pool, v_pool, ring_table, lengths, window,

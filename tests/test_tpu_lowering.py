@@ -616,6 +616,27 @@ def test_transformer_step_program_steers_once_and_copies_no_cross_pool(v5e):
     assert len({tuple(c.split(", ")[:4]) for c in calls}) == 1, calls
 
 
+def _assert_one_slot_walk(v5e, fn, kernel, specs, pool_elems):
+    """``fn`` compiles for the described chip to ONE Mosaic call named
+    ``kernel`` whose grid step is a SLOT (the resident pages are walked
+    inside the body, the pools left in HBM: D16's guard), with no copy of
+    a pool-sized array around it. Returns the compiled text."""
+    text = _compile_v5e(v5e, fn, *specs)
+    assert kernel in text
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+    def traced(*a):
+        with _tpu_target():
+            return fn(*a)
+
+    args = [jax.ShapeDtypeStruct(shape, dt) for shape, dt in specs]
+    S = specs[0][0][0]
+    assert _pallas_grids(jax.make_jaxpr(traced)(*args).jaxpr) == {
+        kernel: (S,)}
+    _assert_moves_no_pool(text, pool_elems)
+    return text
+
+
 # the two latent-attention serving cells (perfbench glm47_flash_6l: 256
 # slots, 20 heads, tables of 12 pages; longcat_flash_omni_4l: 64 slots, 64
 # heads, tables of 40): a 512 + 64 wide row in a 640-wide bfloat16 pool of
@@ -632,23 +653,10 @@ def test_latent_decode_lowers_for_tpu_at_the_served_widths(v5e, S, H, npp):
     pool_shape = (1 + S * npp, ps, la.pool_width(C + R))
     specs = (((S, H, C), BF16), ((S, H, R), BF16), (pool_shape, BF16),
              ((S, npp), jnp.int32), ((S,), jnp.int32))
-
-    def fn(ql, qr, pool, t, n):
-        return la.latent_paged_attention(
-            ql, qr, pool, t, n, sm_scale=0.0625, force_pallas=True)
-
-    text = _compile_v5e(v5e, fn, *specs)
-    assert la.LATENT_KERNEL_NAME in text
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
-
-    def traced(*a):
-        with _tpu_target():
-            return fn(*a)
-
-    args = [jax.ShapeDtypeStruct(shape, dt) for shape, dt in specs]
-    assert _pallas_grids(jax.make_jaxpr(traced)(*args).jaxpr) == {
-        la.LATENT_KERNEL_NAME: (S,)}
-    _assert_moves_no_pool(text, pool_shape[0] * ps * pool_shape[2])
+    _assert_one_slot_walk(
+        v5e, lambda ql, qr, pool, t, n: la.latent_paged_attention(
+            ql, qr, pool, t, n, sm_scale=0.0625, force_pallas=True),
+        la.LATENT_KERNEL_NAME, specs, pool_shape[0] * ps * pool_shape[2])
 
 
 def test_flash_prefill_width_lowers_for_tpu(v5e):
@@ -733,36 +741,50 @@ def test_ssm_one_token_kernels_update_the_state_in_place(v5e):
         == {"2,1,0"}
 
 
-def test_gqa_decode_lowers_for_tpu_at_the_served_widths(v5e):
+# the four served geometries of the grouped-query decode kernels
+# (perfbench solar_open2_4l, trinity_mini_5l, granite4_h_small_10l,
+# jamba2_3b): slots, query heads on key/value heads of 128, table pages
+# of 128 rows, bfloat16
+_GQA_SERVED = {
+    "solar": (96, 64, 8, 80), "trinity": (96, 32, 4, 68),
+    "granite": (64, 32, 8, 40), "jamba": (256, 20, 1, 12),
+}
+
+
+@pytest.mark.parametrize("cell", list(_GQA_SERVED))
+def test_gqa_decode_lowers_for_tpu_at_the_served_widths(v5e, cell):
     from paddle_tpu.kernels import gqa_paged_attention as gq
 
-    S, H, dh, ps, npp = 256, 20, 128, 128, 12
-    pool = ((1 + S * npp, ps, dh), BF16)
-    text = _compile_v5e(
+    S, H, Hkv, npp = _GQA_SERVED[cell]
+    dh = ps = 128
+    pool = ((1 + S * npp, ps, Hkv * dh), BF16)
+    _assert_one_slot_walk(
         v5e, lambda q, k, v, t, n: gq.gqa_paged_attention(
             q, k, v, t, n, force_pallas=True),
-        ((S, H, dh), BF16), pool, pool, ((S, npp), jnp.int32),
-        ((S,), jnp.int32))
-    assert gq.GQA_KERNEL_NAME in text
+        gq.GQA_KERNEL_NAME,
+        (((S, H, dh), BF16), pool, pool, ((S, npp), jnp.int32),
+         ((S,), jnp.int32)), (1 + S * npp) * ps * Hkv * dh)
 
 
-def test_window_decode_lowers_for_tpu_at_the_served_widths(v5e):
-    """The window layers' decode of the 26B-A3B cell: 32 query heads on 4
-    key/value heads of 128, a ring of 18 pages of 128 rows a slot, 96
-    slots; its name holds no other kernel's."""
+@pytest.mark.parametrize("cell", list(_GQA_SERVED))
+def test_window_decode_lowers_for_tpu_at_the_served_widths(v5e, cell):
+    """The window layers' decode at the served geometries' heads and
+    slots (served: the 26B-A3B cell's, 32 query heads on 4 key/value heads
+    of 128, 96 slots), a ring of 18 pages of 128 rows a slot under a
+    window of 2048; its name holds no other kernel's."""
     from paddle_tpu.kernels import gqa_paged_attention as gq
     from paddle_tpu.kernels import window_paged_attention as wp
 
-    S, H, Hkv, dh, ps, R = 96, 32, 4, 128, 128, 18
+    S, H, Hkv, _ = _GQA_SERVED[cell]
+    dh, ps, R = 128, 128, 18
     pool = ((1 + S * R, ps, Hkv * dh), BF16)
-    text = _compile_v5e(
+    text = _assert_one_slot_walk(
         v5e, lambda q, k, v, t, n: wp.window_paged_attention(
             q, k, v, t, n, 2048, force_pallas=True),
-        ((S, H, dh), BF16), pool, pool, ((S, R), jnp.int32),
-        ((S,), jnp.int32))
-    assert wp.WINDOW_KERNEL_NAME in text
+        wp.WINDOW_KERNEL_NAME,
+        (((S, H, dh), BF16), pool, pool, ((S, R), jnp.int32),
+         ((S,), jnp.int32)), (1 + S * R) * ps * Hkv * dh)
     assert gq.GQA_KERNEL_NAME not in text
-    _assert_moves_no_pool(text, (1 + S * R) * ps * Hkv * dh)
 
 
 def test_flash_window_prefill_width_lowers_for_tpu(v5e):

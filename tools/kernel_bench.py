@@ -311,6 +311,157 @@ def _bench_latent_decode(cases, walks, steps, warmup, seed=0):
     return rows
 
 
+def _bench_gqa_decode(cases, walks, calls, steps, warmup, seed=0):
+    """The grouped-query decode kernels alone at a served cell's geometry
+    (``perfbench/configs/<config>.json``: slots, query heads on key/value
+    heads, the table's width, bfloat16 pools of 128-row pages) with every
+    slot holding what the cell's traffic leaves in it: ``calls`` dependent
+    calls inside one jit. A configuration with a ``sliding_window`` also
+    runs the ring kernel over the same slots. A row a
+    kernel and walk (``group``: pages a step of the walk; ``{}`` is the
+    wrapper's own rule): ms a call, the grid's steps, the page walks and
+    what a (slot, page) grid would step through, the share of the cost
+    model's least time that the call reaches, and one call's distance
+    from the kernel's reference."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.kernels import gqa_paged_attention as gq
+    from paddle_tpu.kernels import window_paged_attention as wp
+    from paddle_tpu.models import windowed_moe_decoder as wmd
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench")
+    with open(os.path.join(root, "peaks.json")) as f:
+        peaks = json.load(f)["chips"][0]
+    rows = []
+    for config, traffic, costs, resize in cases:
+        with open(os.path.join(root, "configs", config + ".json")) as f:
+            cfg = json.load(f)
+        with open(os.path.join(root, "traffic", traffic + ".json")) as f:
+            mix = json.load(f)
+        pool_cfg = dict(cfg["pool"], **resize)
+        S, ps = pool_cfg["num_slots"], pool_cfg["page_size"]
+        H, Hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+        dh = cfg.get("head_dim") or cfg["hidden_size"] // H
+        T = pool_cfg["max_prompt"] + pool_cfg["max_new_tokens"]
+        lengths = _resident_lengths(mix, S, T, seed)
+        dtype = jnp.dtype(cfg["dtype"])
+        sm_scale = dh ** -0.5
+        rng = np.random.RandomState(seed)
+        k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+        q = jax.random.normal(k3[2], (S, H, dh), dtype)
+        lens = jnp.asarray(lengths)
+
+        def least(visible):
+            # kernel_costs_*.gqa_decode_attention's arithmetic (the
+            # Mamba-2 cell's module has none): each visible K and V row
+            # read once a key/value head, queries read, outputs written
+            if costs:
+                module, fn = costs
+                module = importlib.import_module("perfbench." + module)
+                return module.least_seconds(
+                    *getattr(module, fn)(cfg, visible, S), peaks)
+            return max(4.0 * H * dh * visible / peaks["bf16_flops_per_s"],
+                       (2 * visible * Hkv + 2 * S * H) * dh * 2
+                       / peaks["hbm_bytes_per_s"])
+
+        def pools(width_pages):
+            shape = (1 + S * width_pages, ps, Hkv * dh)
+            return (jax.random.normal(k3[0], shape, dtype),
+                    jax.random.normal(k3[1], shape, dtype))
+
+        def table_of(first_page, last_page, width, ring):
+            """A slot's resident logical pages ``first_page .. last_page``
+            over distinct shuffled page ids, in column ``page % width``; a
+            full table's tail holds the slot's last valid id (the host's
+            fill), a ring's other columns the trash page."""
+            table = np.zeros((S, width), "int32")
+            held = np.where(lengths > 0, last_page - first_page + 1, 0)
+            ids, at = 1 + rng.permutation(int(held.sum())), 0
+            for slot in range(S):
+                n = int(held[slot])
+                cols = (int(first_page[slot]) + np.arange(n)) % width
+                table[slot, cols] = ids[at:at + n]
+                if n and not ring:
+                    table[slot, n:] = table[slot, n - 1]
+                at += n
+            return table, held
+
+        last = np.maximum(lengths - 1, 0) // ps
+        kernels = [("full", -(-T // ps), np.zeros(S, "int64"), lengths)]
+        window = pool_cfg.get("window", cfg.get("sliding_window"))
+        if window:
+            first = np.maximum(lengths - window, 0)
+            kernels.append(("ring", wmd.ring_pages_per_slot(
+                window, pool_cfg["tokens_per_dispatch"], ps), first // ps,
+                lengths - first))
+        for kind, width, first_page, visible in kernels:
+            table, held = table_of(first_page, last, width, kind == "ring")
+            table = jnp.asarray(table)
+            kp, vp = pools(width)
+            if kind == "ring":
+                first = jnp.maximum(lens - window, 0)
+                name = wp.WINDOW_KERNEL_NAME
+
+                def kernel(q, kp, vp, **walk):
+                    return wp._window_pallas(
+                        q, kp, vp, table, first, lens, sm_scale,
+                        interpret=not _on_tpu(), **walk)
+
+                def reference(rows):
+                    return wp.window_paged_attention_reference(
+                        q[rows], kp, vp, table[rows], lens[rows], window,
+                        sm_scale)
+            else:
+                name = gq.GQA_KERNEL_NAME
+
+                def kernel(q, kp, vp, **walk):
+                    return gq._gqa_pallas(
+                        q, kp, vp, table, lens, sm_scale,
+                        interpret=not _on_tpu(), **walk)
+
+                def reference(rows):
+                    return gq.gqa_paged_attention_reference(
+                        q[rows], kp, vp, table[rows], lens[rows], sm_scale)
+
+            # the reference gathers a slot's whole table in float32: eight
+            # slots at a time
+            want = np.concatenate([
+                np.asarray(reference(slice(at, at + 8)), "float32")
+                for at in range(0, S, 8)])
+            floor = least(int(visible.sum()))
+            for walk in walks:
+                got = np.asarray(kernel(q, kp, vp, **walk), "float32")
+
+                def run(q, kp, vp, walk=walk):
+                    def body(q, _):
+                        out = kernel(q, kp, vp, **walk)
+                        return q + (1e-3 * out).astype(q.dtype), None
+
+                    return jax.lax.scan(body, q, None, length=calls)[0]
+
+                fn = jax.jit(run)
+                secs = _time_steps(lambda: fn(q, kp, vp), steps,
+                                   warmup) / calls
+                row = {"kernel": name, "config": config, "traffic": traffic,
+                       "shape": [S, H, Hkv, dh, width, ps], "walk": walk,
+                       "rows": int(visible.sum()), "grid_steps": S,
+                       "page_walks": int(held.sum()),
+                       "total_page_slots": S * width,
+                       "pallas_ms": round(secs * 1e3, 4),
+                       "least_ms": round(floor * 1e3, 4),
+                       "roofline_share": round(floor / secs, 4),
+                       "rel_l2": float(np.linalg.norm(got - want)
+                                       / np.linalg.norm(want))}
+                print(json.dumps(row))
+                rows.append(row)
+    return rows
+
+
 def _bench_state_kernels(names, update_cases, prefill_cases, calls, steps,
                          warmup):
     """A per-slot-state family's two kernels alone against their composed
@@ -585,8 +736,8 @@ def _on_tpu():
 
 
 _FAMILIES = ("dynamic_lstm", "dynamic_gru", "flash_attention",
-             "flash_tiles", "paged_decode", "latent_decode", "delta_rule",
-             "ssd")
+             "flash_tiles", "paged_decode", "latent_decode", "gqa_decode",
+             "delta_rule", "ssd")
 
 
 def _orchestrate(args):
@@ -641,6 +792,9 @@ def main():
                     help="tiny shapes + few steps (CPU smoke)")
     ap.add_argument("--family", choices=_FAMILIES,
                     help="run ONE family, in this process")
+    ap.add_argument("--pages", default="1,2,4,8",
+                    help="gqa_decode: the pages a step of the walk to "
+                         "sweep beside the kernels' own rule")
     args = ap.parse_args()
 
     if args.family is None:
@@ -673,6 +827,10 @@ def main():
                          dict(num_slots=4, max_prompt=256,
                               max_new_tokens=128))]
         latent_walks = ({}, dict(group=2))
+        gqa_cases = [("trinity_mini_5l", "closed_120_longctx",
+                      ("kernel_costs_trinity", "decode_attention"),
+                      dict(num_slots=4, max_prompt=768, max_new_tokens=256,
+                           window=256))]
     else:
         steps, warmup = 20, 5
         rnn_shapes = [(32, 128, 256), (64, 256, 512), (16, 512, 1024)]
@@ -693,6 +851,20 @@ def main():
             ("longcat_flash_omni_4l", "closed_80_agentic",
              "kernel_costs_longcat", 32, {})]
         latent_walks = ({},) + tuple(dict(group=g) for g in (1, 2, 4, 8))
+        # the four cells that serve the grouped-query kernel, each under
+        # its own traffic: solar_open2_4l (96 slots, 64 heads on 8, tables
+        # of 80 pages), trinity_mini_5l (96, 32 on 4, 68; its four window
+        # layers a ring of 18 under a window of 2048),
+        # granite4_h_small_10l (64, 32 on 8, 40), jamba2_3b (256, 20 on 1,
+        # 12)
+        gqa_cases = [
+            ("solar_open2_4l", "closed_120_docreason",
+             ("kernel_costs_solar", "gqa_decode_attention"), {}),
+            ("trinity_mini_5l", "closed_120_longctx",
+             ("kernel_costs_trinity", "decode_attention"), {}),
+            ("granite4_h_small_10l", "closed_80_sessions", None, {}),
+            ("jamba2_3b", "closed_320_chat",
+             ("kernel_costs_jamba", "gqa_decode_attention"), {})]
         # perfbench solar_open2_4l: 96 slots (all live, and two thirds)
         # of 64 heads of 128 x 128, 12 calls a dispatch (4 tokens x 3
         # linear layers); one 8192-token prompt, whole and ended inside a
@@ -721,6 +893,11 @@ def main():
         _bench_paged_decode(paged_shapes, paged_calls, steps, warmup)
     elif args.family == "latent_decode":
         _bench_latent_decode(latent_cases, latent_walks, steps, warmup)
+    elif args.family == "gqa_decode":
+        _bench_gqa_decode(
+            gqa_cases, ({},) + tuple(
+                dict(group=int(g)) for g in args.pages.split(",") if g),
+            2 if args.quick else 24, steps, warmup)
     elif args.family == "delta_rule":
         _bench_delta_rule(update_shapes, prefill_shapes,
                           2 if args.quick else 12, steps, warmup)
