@@ -129,7 +129,7 @@ def dropless_moe_ffn(x, router_w, router_bias, expert_w_gate, expert_w_up,
 def latent_rope_rows(q, kva, kv_norm, heads, nope_dim, rope_dim, theta,
                      positions=None, period=0, epsilon=1e-5,
                      interleave=False, name=None, q_scale=1.0,
-                     kv_scale=1.0):
+                     kv_scale=1.0, rotate=True):
     """The query ``[N, H, dn + dr]`` with RoPE on its rotary part, and the
     row to cache ``[N, C + dr]`` = ``[RMSNorm(ckv) | RoPE(k_rope)]``.
     ``positions`` [N] (decode), or none and ``period`` = the bucket length
@@ -138,25 +138,33 @@ def latent_rope_rows(q, kva, kv_norm, heads, nope_dim, rope_dim, theta,
     multiplies the query (both parts) and ``kv_scale`` the normed
     compressed part of the row (not its rotary key), in float32 before
     they are rounded: the two constants of a model whose low-rank paths
-    are rescaled (``mla_scale_q_lora`` / ``mla_scale_kv_lora``)."""
+    are rescaled (``mla_scale_q_lora`` / ``mla_scale_kv_lora``).
+    ``rotate=False`` is a model with NO positional encoding
+    (``mla_use_nope``): neither rotary part is rotated, ``positions`` and
+    ``period`` are not read and the scales must be 1."""
+    if not rotate and ((q_scale, kv_scale) != (1.0, 1.0)
+                       or positions is not None):
+        raise ValueError("latent_rope_rows(rotate=False) takes no "
+                         "positions and no q_scale / kv_scale")
     helper = LayerHelper("latent_rope_rows", name=name)
     q_out = helper.create_variable_for_type_inference(q.dtype)
     row = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": [q], "KVA": [kva], "KVNorm": [kv_norm]}
     if positions is not None:
         inputs["Positions"] = [positions]
-    helper.append_op(
-        type="latent_rope_rows", inputs=inputs,
-        outputs={"QOut": [q_out], "Row": [row]},
-        attrs=dict(
-            {"heads": int(heads), "nope_dim": int(nope_dim),
+    attrs = {"heads": int(heads), "nope_dim": int(nope_dim),
              "rope_dim": int(rope_dim), "theta": float(theta),
              "period": int(period), "epsilon": float(epsilon),
-             "interleave": bool(interleave)},
-            # named only where a model has them: the others' programs
-            # keep the attributes they had
-            **({"q_scale": float(q_scale), "kv_scale": float(kv_scale)}
-               if (q_scale, kv_scale) != (1.0, 1.0) else {})))
+             "interleave": bool(interleave)}
+    # named only where a model has them: the others' programs keep the
+    # attributes they had
+    if (q_scale, kv_scale) != (1.0, 1.0):
+        attrs.update(q_scale=float(q_scale), kv_scale=float(kv_scale))
+    if not rotate:
+        attrs["rotate"] = False
+    helper.append_op(
+        type="latent_rope_rows", inputs=inputs,
+        outputs={"QOut": [q_out], "Row": [row]}, attrs=attrs)
     return q_out, row
 
 

@@ -1,8 +1,8 @@
 """The serving programs of a decoder-only language model, written ONCE: the
 frame every family's builder fills (``models/latent_moe_decoder.py``,
 ``hybrid_ssm_decoder.py``, ``windowed_moe_decoder.py``,
-``linear_attn_moe_decoder.py``, ``ssd_moe_decoder.py``,
-``shortcut_moe_decoder.py``). A family file
+``linear_attn_moe_decoder.py`` (two namings, one file),
+``ssd_moe_decoder.py``, ``shortcut_moe_decoder.py``). A family file
 holds what its layers do and the state they keep (``DecoderFamily``); this
 module holds what a
 ``serving.decoder_session.DecoderOnlySession`` dispatches, whatever the
@@ -69,6 +69,10 @@ _FAMILIES = (
     # description has the next row's ``kv_lora_rank`` too
     (lambda desc: "zero_expert_num" in desc,
      "shortcut_moe_decoder", "build_shortcut_moe_decoder"),
+    # delta-rule linear layers whose full-attention sibling is LATENT:
+    # such a description has the next row's ``kv_lora_rank`` too
+    (lambda desc: "linear_attn_config" in desc and "kv_lora_rank" in desc,
+     "linear_attn_moe_decoder", "build_linear_attn_moe_decoder"),
     (lambda desc: "kv_lora_rank" in desc,
      "latent_moe_decoder", "build_latent_moe_decoder"),
     (lambda desc: "layer_types" in desc
@@ -95,6 +99,8 @@ def builder_for(desc):
         "(mamba_n_heads), a hybrid state-space decoder (mamba_d_state), a "
         "decoder of two latent-attention blocks a layer with the expert "
         "block on a shortcut and zero-compute experts (zero_expert_num), a "
+        "decoder of delta-rule linear-attention layers beside latent-"
+        "attention layers (linear_attn_config with kv_lora_rank), a "
         "latent-attention decoder (kv_lora_rank), a decoder of window "
         "and full attention layers (layer_types with a sliding_window) or "
         "a decoder of delta-rule linear-attention and grouped-query "

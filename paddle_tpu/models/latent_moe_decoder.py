@@ -52,6 +52,7 @@ __all__ = ["decoder_dims", "parameter_shapes", "random_parameters",
 
 def decoder_dims(desc):
     """The sizes the programs are built from, by the config's keys."""
+    check_served(desc)
     d = dict(
         D=int(desc["hidden_size"]), H=int(desc["num_attention_heads"]),
         dn=int(desc["qk_nope_head_dim"]), dr=int(desc["qk_rope_head_dim"]),
@@ -76,7 +77,6 @@ def decoder_dims(desc):
         raise ValueError(
             "expert_shard %r: n_routed_experts=%d experts from `first` do "
             "not lie among its `of`" % (shard, d["E"]))
-    check_served(desc)
     _sparse_dims(desc, d)
     d["W"] = d["C"] + d["dr"]          # the cached row
     d["Wp"] = pool_width(d["W"])       # as the pool holds it
@@ -87,6 +87,12 @@ def check_served(desc):
     """Refuse, by the key at fault, a description whose keys ask for what
     this builder does not serve (``decoder_dims`` and the session's
     ``builder_for`` both ask; only the keys that are there are read)."""
+    if "q_lora_rank" in desc and desc["q_lora_rank"] is None:
+        raise NotImplementedError(
+            "q_lora_rank=None: only a compressed query (q_a, its norm, "
+            "q_b) is built here; a latent layer whose query comes straight "
+            "from the normed input is `linear_attn_moe_decoder`'s, beside "
+            "`linear_attn_config`")
     if desc.get("n_group", 1) != 1 or desc.get("topk_group", 1) != 1:
         raise NotImplementedError(
             "group-limited routing (n_group=%r, topk_group=%r): only one "

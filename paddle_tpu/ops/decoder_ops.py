@@ -113,15 +113,26 @@ def _lower_latent_rope_rows(ctx, ins, attrs):
     and the cached row ``[RMSNorm(ckv) | RoPE(k_rope)]``. ``q_scale``
     multiplies the whole query and ``kv_scale`` the normed compressed
     part (not the rotary key), both in float32 before the rows are
-    rounded; at 1.0 the op lowers to what it lowered to without them."""
+    rounded; at 1.0 the op lowers to what it lowered to without them.
+    ``rotate`` false: no rotation of either rotary part, and no position
+    is read."""
     q, kva = ins["Q"][0], ins["KVA"][0]          # [N, H*(dn+dr)], [N, C+R]
+    H, dn = int(attrs["heads"]), int(attrs["nope_dim"])
+    dr, theta = int(attrs["rope_dim"]), float(attrs["theta"])
+    C = kva.shape[-1] - dr
+    if not attrs.get("rotate", True):
+        # no positional encoding (``mla_use_nope``): the query as it was
+        # projected, the row ``[RMSNorm(ckv) | k_pe]``; the rotary lanes
+        # stay in the score, unrotated
+        return {"QOut": q.reshape(q.shape[0], H, dn + dr),
+                "Row": jnp.concatenate(
+                    [rms_norm(kva[:, :C], ins["KVNorm"][0],
+                              float(attrs.get("epsilon", 1e-5))),
+                     kva[:, C:]], -1)}
     if ins.get("Positions"):
         pos = jnp.reshape(ins["Positions"][0], (-1,))
     else:   # prefill: token n of the flat batch stands at n % bucket
         pos = jnp.arange(q.shape[0]) % int(attrs["period"])
-    H, dn = int(attrs["heads"]), int(attrs["nope_dim"])
-    dr, theta = int(attrs["rope_dim"]), float(attrs["theta"])
-    C = kva.shape[-1] - dr
     rotate = rope(attrs.get("interleave", False))
     q_scale = float(attrs.get("q_scale", 1.0))
     kv_scale = float(attrs.get("kv_scale", 1.0))
@@ -150,7 +161,7 @@ register_op(
     outputs=["QOut", "Row"],
     attrs={"heads": 1, "nope_dim": 0, "rope_dim": 0, "theta": 10000.0,
            "period": 0, "epsilon": 1e-5, "interleave": False,
-           "q_scale": 1.0, "kv_scale": 1.0},
+           "q_scale": 1.0, "kv_scale": 1.0, "rotate": True},
     lower=_lower_latent_rope_rows, grad=None)
 
 

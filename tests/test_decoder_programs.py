@@ -2,15 +2,16 @@
 
 ``models/decoder_programs.py`` writes the programs' frame once and the six
 family files fill it; ``tests/golden/decoder_programs.json`` holds
-``core.fingerprint.program_fingerprint`` of every program of the seven test
+``core.fingerprint.program_fingerprint`` of every program of the eight test
 descriptions (``DESC`` of ``test_latent_moe_decoder``,
 ``test_sparse_latent_decoder``, ``test_hybrid_ssm_decoder``,
 ``test_windowed_moe_decoder``, ``test_linear_attn_decoder``,
-``test_ssd_moe_decoder``, ``test_shortcut_moe_decoder``, at the geometry
-those files' sessions use): ``init``, ``step``, ``step`` with
+``test_ssd_moe_decoder``, ``test_shortcut_moe_decoder``,
+``test_linear_latent_decoder``, at the geometry those files' sessions
+use): ``init``, ``step``, ``step`` with
 ``probe_rows=2`` and every rung of every bucket's prefill. They were
-recorded at the commit BEFORE the frame was written once (the sixth and the
-seventh with the family that brought each; a rung of B rows
+recorded at the commit BEFORE the frame was written once (the sixth, the
+seventh and the eighth with the PR that brought each; a rung of B rows
 that a builder of that commit did not take is the program it built for a
 budget of B rows), so a case fails at any commit that adds, drops or
 reorders an op, renames a variable or changes an attribute: the fingerprint
@@ -43,7 +44,7 @@ GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden",
 FAMILIES = {"test_latent_moe_decoder": 4, "test_sparse_latent_decoder": 4,
             "test_hybrid_ssm_decoder": 6, "test_windowed_moe_decoder": 4,
             "test_linear_attn_decoder": 6, "test_ssd_moe_decoder": 6,
-            "test_shortcut_moe_decoder": 4}
+            "test_shortcut_moe_decoder": 4, "test_linear_latent_decoder": 6}
 
 
 @functools.lru_cache(maxsize=None)
@@ -104,11 +105,26 @@ def test_an_unknown_description_is_refused_with_the_families_named():
         "with routed experts (mamba_n_heads), a hybrid state-space "
         "decoder (mamba_d_state), a decoder of two latent-attention blocks "
         "a layer with the expert block on a shortcut and zero-compute "
-        "experts (zero_expert_num), a latent-attention decoder "
+        "experts (zero_expert_num), a decoder of delta-rule "
+        "linear-attention layers beside latent-attention layers "
+        "(linear_attn_config with kv_lora_rank), a latent-attention decoder "
         "(kv_lora_rank), a decoder of window and full attention layers "
         "(layer_types with a sliding_window) or a decoder of delta-rule "
         "linear-attention and grouped-query attention layers "
         "(linear_attn_config)")
+
+
+def test_the_two_namings_of_the_linear_family_reach_one_builder():
+    """A ``kimi_linear`` description has ``kv_lora_rank`` beside its
+    ``linear_attn_config``: the row that asks for both stands before the
+    latent family's, and ``solar_open2``'s still reaches the same file."""
+    import test_linear_attn_decoder as solar
+    import test_linear_latent_decoder as kimi
+    from paddle_tpu.models import linear_attn_moe_decoder as lad
+
+    assert "kv_lora_rank" in kimi.DESC and "kv_lora_rank" not in solar.DESC
+    assert builder_for(kimi.DESC) is builder_for(solar.DESC) \
+        is lad.build_linear_attn_moe_decoder
 
 
 def test_a_mamba2_description_and_a_mamba1_description_part_ways():

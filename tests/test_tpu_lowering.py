@@ -121,6 +121,13 @@ def _assert_moves_no_pool(text, pool_elems):
     assert not moved, "the program moves a whole pool:\n" + "\n".join(moved)
 
 
+def _peak_bytes(compiled):
+    """Arguments + temporaries + outputs that alias no argument."""
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
 def _pallas_grids(jaxpr):
     """``{kernel name: grid}`` of every ``pallas_call`` in ``jaxpr`` and
     the jaxprs nested in it (a scan's body, a cond's branches)."""
@@ -637,12 +644,13 @@ def _assert_one_slot_walk(v5e, fn, kernel, specs, pool_elems):
     return text
 
 
-# the two latent-attention serving cells (perfbench glm47_flash_6l: 256
+# the three latent-attention serving cells (perfbench glm47_flash_6l: 256
 # slots, 20 heads, tables of 12 pages; longcat_flash_omni_4l: 64 slots, 64
-# heads, tables of 40): a 512 + 64 wide row in a 640-wide bfloat16 pool of
-# 128-row pages
-@pytest.mark.parametrize("S,H,npp", [(256, 20, 12), (64, 64, 40)],
-                         ids=["glm47_flash", "longcat_flash"])
+# heads, tables of 40; kimi_linear_5l: 384 slots, 32 heads, tables of 64):
+# a 512 + 64 wide row in a 640-wide bfloat16 pool of 128-row pages
+@pytest.mark.parametrize("S,H,npp", [(256, 20, 12), (64, 64, 40),
+                                     (384, 32, 64)],
+                         ids=["glm47_flash", "longcat_flash", "kimi_linear"])
 def test_latent_decode_lowers_for_tpu_at_the_served_widths(v5e, S, H, npp):
     """One Mosaic call whose grid step is a SLOT (the resident pages are
     walked inside the body, the pool left in HBM), and no copy of a
@@ -801,13 +809,16 @@ def test_flash_window_prefill_width_lowers_for_tpu(v5e):
 
 # -- the delta-rule linear-attention decoder (kernels/delta_rule.py) ----------
 
-@pytest.mark.parametrize("B,T", [(1, 8192), (16, 512)])
-def test_delta_rule_prefill_lowers_for_tpu_at_the_served_widths(v5e, B, T):
+@pytest.mark.parametrize("B,T,H", [(1, 8192, 64), (16, 512, 64),
+                                   (2, 4096, 32), (16, 512, 32)])
+def test_delta_rule_prefill_lowers_for_tpu_at_the_served_widths(v5e, B, T,
+                                                                H):
     """The chunked delta rule of the 250B-A15B cell: 64 heads of 128 x
-    128, one prompt of 8192 tokens and a bucket row of 16 of 512."""
+    128, one prompt of 8192 tokens and a bucket row of 16 of 512; and of
+    the 48B-A3B cell: 32 heads, two prompts of 4096 and 16 of 512."""
     from paddle_tpu.kernels import delta_rule as dr
 
-    H, d = 64, 128
+    d = 128
     text = _compile_v5e(
         v5e, lambda q, k, v, g, beta, lens: dr.chunk_prefill(
             q, k, v, g, beta, lens, force_pallas=True),
@@ -816,17 +827,19 @@ def test_delta_rule_prefill_lowers_for_tpu_at_the_served_widths(v5e, B, T):
     assert dr.CHUNK_KERNEL_NAME in text
 
 
-def test_delta_rule_update_updates_the_state_in_place(v5e):
-    """The one-token update at the served sizes (96 slots, 64 heads, a
-    128 x 128 float32 state a head), the state donated: Mosaic takes it,
-    the compiled program holds no copy of the state, keeps its one layout
-    (``dv`` on the lanes) and its temporaries are a small fraction of the
-    403 MB state of one layer."""
+@pytest.mark.parametrize("S,H", [(96, 64), (384, 32)],
+                         ids=["solar_open2", "kimi_linear"])
+def test_delta_rule_update_updates_the_state_in_place(v5e, S, H):
+    """The one-token update at the served sizes (96 slots of 64 heads, and
+    384 slots of 32: a 128 x 128 float32 state a head), the state donated:
+    Mosaic takes it, the compiled program holds no copy of the state,
+    keeps its one layout (``dv`` on the lanes) and its temporaries are a
+    small fraction of the 403 / 805 MB state of one layer."""
     import re
 
     from paddle_tpu.kernels import delta_rule as dr
 
-    S, H, d = 96, 64, 128
+    d = 128
 
     def step(state, q, k, v, g, beta, live):
         with _tpu_target():
@@ -1171,11 +1184,6 @@ def test_shortcut_decoder_programs_fit_the_chip_at_the_served_sizes(v5e):
     assert len(geo["state"]["page_pools"]) == 2 * cfg["num_layers"]
     state["scd_tok"] = state["scd_pos"] = ((S, 1), "int64")
 
-    def peak(compiled):
-        m = compiled.memory_analysis()
-        return (m.argument_size_in_bytes + m.temp_size_in_bytes
-                + m.output_size_in_bytes - m.alias_size_in_bytes)
-
     with _tpu_target():
         step = dc.compile_program(
             built["step"], v5e[0], state,
@@ -1199,5 +1207,90 @@ def test_shortcut_decoder_programs_fit_the_chip_at_the_served_sizes(v5e):
     _assert_moves_no_pool(text, pool_elems)
     assert "flash_attention_fwd" in prefill.as_text()
     assert step.memory_analysis().alias_size_in_bytes >= 8 * pool_elems * 2
-    assert peak(step) < 14.2e9, peak(step)
-    assert peak(prefill) < 15.0e9, peak(prefill)
+    assert _peak_bytes(step) < 14.2e9, _peak_bytes(step)
+    assert _peak_bytes(prefill) < 15.0e9, _peak_bytes(prefill)
+
+
+# -- the linear / latent decoder (perfbench kimi_linear_5l) --------------------
+
+def test_linear_latent_decoder_programs_fit_the_chip_at_the_served_sizes(v5e):
+    """The cell's own configuration (``perfbench/configs/
+    kimi_linear_5l.json``: four delta-rule layers and one NoPE latent
+    layer, a leading dense layer, 64 of 256 experts, 384 slots of 8192
+    positions) compiled for the described chip from shapes alone: the
+    decode dispatch and the fullest prefill of the longest bucket. Mosaic
+    takes the kernels at these shapes (the delta rule at ``[384, 32, 128,
+    128]``, the absorbed decode at 32 heads over a 640-lane pool, the
+    flash forward at 192 beside 128), the instructions say which sub-block
+    they are, the matrix states and the latent pool are updated in place,
+    and arguments + temporaries stay under 15.0 GB of the chip's 17.18
+    (PERF.md, PR 53: 11.93 + 0.06 and 11.93 + 0.73)."""
+    import json
+    import re
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import _described_compile as dc
+    from paddle_tpu.kernels import delta_rule as dr
+    from paddle_tpu.models import linear_attn_moe_decoder as lad
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "kimi_linear_5l.json")) as f:
+        cfg = json.load(f)
+    pool = cfg["pool"]
+    S = pool["num_slots"]
+    built = lad.build_linear_attn_moe_decoder(
+        cfg, S, pool["max_prompt"] + pool["max_new_tokens"],
+        pool["page_size"], pool["prefill_buckets"],
+        prefill_token_budget=pool["prefill_token_budget"],
+        tokens_per_dispatch=pool["tokens_per_dispatch"],
+        prefill_rungs=True, probe_rows=2)
+    geo, f = built["geometry"], built["fetches"]
+    npp = geo["pages_per_slot"]
+    state = dict(lad.parameter_shapes(cfg, "bfloat16"))
+    shapes = []
+    for kind in ("page_pools", "slot_arrays"):
+        for name, a in geo["state"][kind].items():
+            state[name] = (a["shape"], a["dtype"])
+            shapes.append(",".join(str(n) for n in a["shape"]))
+    assert list(geo["state"]["page_pools"]) == ["lad_pool_3"]
+    assert geo["state"]["slot_arrays"]["lad_s_4"]["shape"] \
+        == (384, 32, 128, 128)
+    state["lad_tok"] = state["lad_pos"] = ((S, 1), "int64")
+
+    with _tpu_target():
+        step = dc.compile_program(
+            built["step"], v5e[0], state,
+            {"page_table": ((S, npp), "int64"), "live": ((S, 1), "int64"),
+             "probe_slots": ((2,), "int64")},
+            [f["token"], f["expert_tokens"], f["probe_logits"],
+             f["chosen"]], steps=4)
+        T = pool["prefill_buckets"][-1]
+        B = pool["prefill_token_budget"] // T
+        prefill = dc.compile_program(
+            built["prefill_rungs"][T][B], v5e[0], state,
+            {"prompt_ids": ((B * T,), "int64"),
+             "prompt_len": ((B,), "int64"), "slot_idx": ((B,), "int64"),
+             "page_rows": ((B, npp), "int64"), "last_idx": ((B,), "int64")},
+            [f["first_token"], f["first_logits"], f["first_chosen"]])
+    text = step.as_text()
+    for name in ("kda_mixer/" + dr.STATE_KERNEL_NAME,
+                 "latent_attention/jit(_latent_pallas)", "gmm",
+                 "latent_paged_decode_attention", "dense_ffn/", "moe/"):
+        assert name in text, name
+    moved = [line.strip()[:160] for line in text.splitlines() if re.match(
+        r"\s*(?:ROOT )?%%\S+ = \w+\[(%s)\]\S* "
+        r"(copy|transpose|gather|copy-start)\(" % "|".join(
+            sorted(set(shapes))), line)]
+    assert not moved, "the step moves a state array or the pool:\n" \
+        + "\n".join(moved)
+    pre = prefill.as_text()
+    assert "flash_attention_fwd" in pre and dr.CHUNK_KERNEL_NAME in pre
+    resident = sum(int(np.prod(a["shape"])) * (4 if a["dtype"] == "float32"
+                                               else 2)
+                   for kind in ("page_pools", "slot_arrays")
+                   for a in geo["state"][kind].values())
+    assert step.memory_analysis().alias_size_in_bytes >= resident
+    assert _peak_bytes(step) < 12.5e9, _peak_bytes(step)
+    assert _peak_bytes(prefill) < 15.0e9, _peak_bytes(prefill)

@@ -277,8 +277,14 @@ def _bench_latent_decode(cases, walks, steps, warmup, seed=0):
         sm_scale = (cfg["qk_nope_head_dim"] + R) ** -0.5
         least = costs.least_seconds(*costs.latent_decode_attention(
             cfg, int(lengths.sum()), S), peaks)
-        want = np.asarray(la.latent_paged_attention_reference(
-            q_lat, q_rope, pool, table, lens, sm_scale), "float32")
+        # the reference gathers a slot's whole table in float32: a few
+        # slots at a time, so that 384 slots of 64 pages fit beside the pool
+        part = max(1, (1 << 28) // (npp * ps * C))
+        want = np.concatenate([np.asarray(
+            la.latent_paged_attention_reference(
+                q_lat[at:at + part], q_rope[at:at + part], pool,
+                table[at:at + part], lens[at:at + part], sm_scale),
+            "float32") for at in range(0, S, part)])
         for walk in walks:
             got = np.asarray(la._latent_pallas(
                 q_lat, q_rope, pool, table, lens, sm_scale,
@@ -849,7 +855,11 @@ def main():
             ("glm47_flash_6l", "closed_320_chat", "kernel_costs_glm", 24,
              {}),
             ("longcat_flash_omni_4l", "closed_80_agentic",
-             "kernel_costs_longcat", 32, {})]
+             "kernel_costs_longcat", 32, {}),
+            # kimi_linear_5l under closed_480_reasoning (384 slots x 32
+            # heads x 64 pages, 4 calls a dispatch: 4 tokens x ONE pool)
+            ("kimi_linear_5l", "closed_480_reasoning",
+             "kernel_costs_kimi", 4, {})]
         latent_walks = ({},) + tuple(dict(group=g) for g in (1, 2, 4, 8))
         # the four cells that serve the grouped-query kernel, each under
         # its own traffic: solar_open2_4l (96 slots, 64 heads on 8, tables
@@ -871,6 +881,13 @@ def main():
         # chunk a third of the way
         update_shapes = [(96, live, 64, 128, 128) for live in (96, 64)]
         prefill_shapes = [(1, 8192, n, 64, 128, 128) for n in (8192, 2700)]
+        # perfbench kimi_linear_5l: 384 slots (all live, and two thirds)
+        # of 32 heads of 128 x 128, 16 calls a dispatch (4 tokens x 4
+        # linear layers); two 4096-token prompts and a bucket row of 8 of
+        # 1024 that end inside a chunk
+        update_shapes += [(384, live, 32, 128, 128) for live in (384, 256)]
+        prefill_shapes += [(2, 4096, 4096, 32, 128, 128),
+                           (8, 1024, 700, 32, 128, 128)]
         # perfbench granite4_h_small_10l: 64 slots (all live, and three
         # quarters) of 128 heads of 64 x 128, 36 calls a dispatch (4 tokens
         # x 9 Mamba-2 layers); one 4096-token prompt, whole and ended a
