@@ -57,6 +57,7 @@ class OpDef(object):
         "no_grad_inputs",
         "intermediate_outputs",
         "infer_shape",
+        "lower_grad",
     )
 
     def __init__(
@@ -70,6 +71,7 @@ class OpDef(object):
         no_grad_inputs,
         intermediate_outputs,
         infer_shape,
+        lower_grad=None,
     ):
         self.type = type
         self.inputs = inputs  # list of slot names; "*X" marks duplicable
@@ -80,6 +82,9 @@ class OpDef(object):
         self.no_grad_inputs = no_grad_inputs
         self.intermediate_outputs = intermediate_outputs
         self.infer_shape = infer_shape  # optional override
+        # optional rule of the synthesized <type>_grad op, in place of the
+        # vjp of ``lower`` (lower_grad_via_vjp's signature)
+        self.lower_grad = lower_grad
 
     def input_slots(self):
         return [s.lstrip("*") for s in self.inputs]
@@ -107,6 +112,7 @@ def register_op(
     no_grad_inputs=(),
     intermediate_outputs=(),
     infer_shape=None,
+    lower_grad=None,
 ):
     """Register an operator definition (REGISTER_OPERATOR analog).
 
@@ -117,6 +123,9 @@ def register_op(
       - callable(op, out_grads, in_grads_wanted) -> list of op spec dicts:
         custom grad-desc maker (for ops composed of other ops);
       - None: op has no gradient (EmptyGradOpMaker).
+    ``lower_grad`` (with ``grad="auto"``): the synthesized op's lowering
+    calls it in place of ``lower_grad_via_vjp``, with the same arguments,
+    where an op forms a gradient another way than ``jax.vjp`` would.
     """
     if type in _REGISTRY:
         raise ValueError("op %r already registered" % type)
@@ -132,6 +141,7 @@ def register_op(
         no_grad_inputs=frozenset(no_grad_inputs),
         intermediate_outputs=frozenset(intermediate_outputs),
         infer_shape=infer_shape,
+        lower_grad=lower_grad,
     )
     _REGISTRY[type] = opdef
     return opdef
@@ -314,7 +324,8 @@ def ensure_auto_grad_op(fwd_type):
             names = op.output(s + "@GRAD")
             if any(names):
                 wanted[s] = [bool(n) for n in names]
-        gres = lower_grad_via_vjp(fwd, ctx, fwd_ins, attrs, out_grads, wanted)
+        rule = fwd.lower_grad or lower_grad_via_vjp
+        gres = rule(fwd, ctx, fwd_ins, attrs, out_grads, wanted)
         return {s + "@GRAD": gs for s, gs in gres.items()}
 
     return register_op(

@@ -9,7 +9,7 @@ the MXU with float32 accumulation via XLA's default precision).
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.core.op_registry import register_op
+from paddle_tpu.core.op_registry import lower_grad_via_vjp, register_op
 from paddle_tpu.ops.common import broadcast_y, flatten_to_2d, reduce_axes, to_dtype
 
 
@@ -24,12 +24,70 @@ def _lower_mul(ctx, ins, attrs):
     return jnp.reshape(out, out_shape)
 
 
+def _lone_weight_grad(x2, dout2):
+    """``x2^T dout2`` (``[K, N]``) for an ``x2`` WIDER than ``dout2``, as a
+    product of its own on a STORED ``dout2``; the optimizer's update then
+    runs alone behind it.
+
+    Fused with its Adam update and with whatever makes its operands, this
+    gradient was the slowest product of the trainer's step on the v5e: 2.35
+    ms where the same product alone on plain operands takes 0.79, because
+    its operand fusion rebuilt ``dout2`` (a dropout's regenerated bits and a
+    select over the float32 residual gradient) for every window of the
+    ``[K, N]`` result. Two barriers hold the form; both were read in the
+    step compiled for a described v5e and timed on the chip (PERF.md section
+    6, PR 54):
+
+    - on ``dout2``: the narrow operand is stored once (``rows x N``, the
+      smaller of the two) and the product reads it plain: 0.73 ms a product
+      against 1.49 with the rebuild in its operand fusion;
+    - on the product: it keeps the update out of the product's fusion, so
+      the window is chosen for the product; with the update fused behind
+      it eight of twelve read 1.07 ms, and the step 4.4 ms more.
+
+    The operands keep the order ``jax.vjp`` gives them. The other order
+    (``dout2^T x2``, turned afterwards) is the same product at the same
+    rate and needs a relayout and a third barrier to keep the update in the
+    parameter's layout: 5.7 ms a step slower in all."""
+    dout2 = jax.lax.optimization_barrier(dout2)
+    return jax.lax.optimization_barrier(
+        jax.lax.dot_general(x2, dout2, (((0,), (0,)), ((), ()))))
+
+
+def _lower_mul_grad(fwd_def, ctx, ins, attrs, out_grads, wanted):
+    """``mul_grad``: what ``jax.vjp`` of ``_lower_mul`` gives, except
+    ``Y@GRAD`` where the flattened ``X`` has more columns than ``Out`` (a
+    feed-forward's second product): that one is ``_lone_weight_grad``. The
+    rule sees the two column counts and nothing else; equal widths and a
+    narrower ``X`` keep the vjp's form, and so does ``X@GRAD`` always."""
+    x, y = ins["X"][0], ins["Y"][0]
+    dout = (out_grads.get("Out") or [None])[0]
+    x2 = flatten_to_2d(x, attrs.get("x_num_col_dims", 1))
+    y2 = flatten_to_2d(y, attrs.get("y_num_col_dims", 1))
+    alone = (
+        dout is not None
+        and (wanted.get("Y") or [False])[0]
+        and jnp.issubdtype(jnp.result_type(y), jnp.inexact)
+        and x2.shape[1] > y2.shape[1]
+    )
+    if not alone:
+        return lower_grad_via_vjp(fwd_def, ctx, ins, attrs, out_grads, wanted)
+    rest = {slot: w for slot, w in wanted.items() if slot != "Y"}
+    grads = lower_grad_via_vjp(fwd_def, ctx, ins, attrs, out_grads, rest)
+    dout2 = jnp.reshape(jnp.asarray(dout, jnp.result_type(x, y)),
+                        (x2.shape[0], y2.shape[1]))
+    dy = _lone_weight_grad(x2, dout2).astype(jnp.result_type(y))
+    grads["Y"] = [jnp.reshape(dy, jnp.shape(y))]
+    return grads
+
+
 register_op(
     "mul",
     inputs=["X", "Y"],
     outputs=["Out"],
     attrs={"x_num_col_dims": 1, "y_num_col_dims": 1},
     lower=_lower_mul,
+    lower_grad=_lower_mul_grad,
 )
 
 

@@ -1048,17 +1048,18 @@ def _logits_traffic(text, elems):
     return writes, reads, gathers
 
 
-def test_training_head_stores_only_bfloat16_logits(v5e):
+_TRAINER_STEP = {}
+
+
+def _trainer_step(v5e):
     """The trainer's step (``perfbench/train_common.build_program``: the
     model at ``transformer_big``'s published widths, label smoothing 0.1,
     Adam, the bf16 AMP rewrite; ONE layer, batch 8) compiled for the
-    described chip: of the ``[tokens, 32000]`` head only the bfloat16
-    logits the projection writes are stored. The label's log-probability
-    is a masked sum that fuses into a pass that reads them; a gather (as
-    ``take_along_axis`` was) makes XLA store ``logits - lse`` whole in
-    float32 first, twice at 2.1 GB a step in the benchmark's cell."""
+    described chip, once a process: ``(compiled, B, T, V)``."""
     import sys
 
+    if _TRAINER_STEP:
+        return _TRAINER_STEP["step"]
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     import _described_compile as dc
     import paddle_tpu as fluid
@@ -1082,6 +1083,17 @@ def test_training_head_stores_only_bfloat16_logits(v5e):
     with _tpu_target():
         compiled = dc.compile_program(main, v5e[0], state, feed, [loss.name],
                                       is_test=False)
+    _TRAINER_STEP["step"] = (compiled, B, T, V)
+    return _TRAINER_STEP["step"]
+
+
+def test_training_head_stores_only_bfloat16_logits(v5e):
+    """Of the trainer's step's ``[tokens, 32000]`` head only the bfloat16
+    logits the projection writes are stored. The label's log-probability
+    is a masked sum that fuses into a pass that reads them; a gather (as
+    ``take_along_axis`` was) makes XLA store ``logits - lse`` whole in
+    float32 first, twice at 2.1 GB a step in the benchmark's cell."""
+    compiled, B, T, V = _trainer_step(v5e)
     writes, reads, gathers = _logits_traffic(compiled.as_text(), B * T * V)
     assert [(d, dims) for d, dims, _ in writes] in (
         [("bf16", "%d,%d,%d" % (B, T, V))], [("bf16", "%d,%d" % (B * T, V))]
@@ -1092,6 +1104,60 @@ def test_training_head_stores_only_bfloat16_logits(v5e):
     assert not gathers, "\n".join(gathers)
     # 431 949 312 bytes as repaired (the parent: 566 277 632), + 10%
     assert compiled.memory_analysis().temp_size_in_bytes < 476e6
+
+
+def _fused_bodies(text):
+    """{computation name: its lines} of the compiled text."""
+    import re
+
+    bodies, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"%?([\w.-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = bodies.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return bodies
+
+
+def test_ffn_second_product_weight_gradient_stands_alone(v5e):
+    """In the trainer's step the gradient of ``*_ffn_fc2.w_0`` (``[4096,
+    1024]``: the input is WIDER than the output) is a product of its own
+    (``ops/math_ops.py``: ``_lone_weight_grad``; its result the bfloat16
+    gradient) and the Adam update runs without a product inside and in the
+    parameter's own layout: no ``copy`` of a float32 ``[4096, 1024]`` array
+    in the ENTRY computation. The first product's ``[1024, 4096]`` update
+    keeps its product, as ``jax.vjp`` forms it. Fused with its update and
+    with the fusion that rebuilds ``dOut``, eleven of the cell's twelve
+    such products ran at ~29% of the matrix unit's peak on the chip; alone
+    and on a stored ``dOut`` they run at ~95% (PERF.md section 6, PR 54)."""
+    import re
+
+    text = _trainer_step(v5e)[0].as_text()
+    bodies = _fused_bodies(text)
+
+    def has_product(line):
+        called = re.search(r"calls=%([\w.-]+)", line)
+        return any(" convolution(" in b for b in bodies[called.group(1)])
+
+    updates = {"4096,1024": [], "1024,4096": []}
+    alone, copies = [], []
+    for res, opc, _operands, line in _entry_ops(text):
+        if opc == "fusion":
+            for dims in updates:
+                if "f32[%s]" % dims in res:
+                    updates[dims].append(has_product(line))
+            if re.match(r"bf16\[4096,1024\]", res) and has_product(line):
+                alone.append(line[:160])
+        if opc == "copy" and "f32[4096,1024]" in res:
+            copies.append(line[:160])
+    # an encoder and a decoder layer: two weights of each shape
+    assert updates["4096,1024"] == [False, False], updates
+    assert len(alone) == 2, alone
+    assert updates["1024,4096"] == [True, True], updates
+    assert not copies, "\n".join(copies)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

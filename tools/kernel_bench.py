@@ -652,6 +652,31 @@ def _flash_tile_cases(quick):
     return cases
 
 
+def _traced_device_ops(call, steps):
+    """``[name, start_ns, duration_ns]`` of every device op of ``steps``
+    runs of ``call()`` (which blocks until its result is ready), from a
+    profiler trace; ``[]`` where the trace holds no device plane (off the
+    chip)."""
+    import shutil
+    import tempfile
+
+    import jax
+
+    from perfbench import trace_reduce
+
+    trace_dir = tempfile.mkdtemp()
+    try:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(steps):
+                call()
+        path = [os.path.join(r, f) for r, _d, fs in os.walk(trace_dir)
+                for f in fs if f.endswith(".xplane.pb")][0]
+        devices = trace_reduce.flatten(path)["devices"]
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    return next(iter(devices.values()))["ops"] if devices else []
+
+
 def _bench_flash_tiles(cases, steps, fa=None):
     """The three flash kernels alone, by their device time in a profiler
     trace (``perfbench/trace_reduce.py``; found by their pallas_call
@@ -659,8 +684,6 @@ def _bench_flash_tiles(cases, steps, fa=None):
     tiles and heads a grid step it is swept over. ``_choose_tiles`` is
     replaced for a swept row and nothing else of the kernel is."""
     import importlib
-    import shutil
-    import tempfile
 
     import jax
     import jax.numpy as jnp
@@ -699,16 +722,10 @@ def _bench_flash_tiles(cases, steps, fa=None):
                          if backward else attend)
             try:
                 jax.block_until_ready(fn(q, k, v))
-                trace_dir = tempfile.mkdtemp()
-                with jax.profiler.trace(trace_dir):
-                    for _ in range(steps):
-                        jax.block_until_ready(fn(q, k, v))
-                path = [os.path.join(r, f) for r, _d, fs in
-                        os.walk(trace_dir) for f in fs
-                        if f.endswith(".xplane.pb")][0]
-                ops = next(iter(trace_reduce.flatten(
-                    path)["devices"].values()))["ops"]
-                shutil.rmtree(trace_dir, ignore_errors=True)
+                ops = _traced_device_ops(
+                    lambda: jax.block_until_ready(fn(q, k, v)), steps)
+                if not ops:
+                    raise RuntimeError("no device plane in the trace")
                 ms = {n: 0.0 for n in names}
                 for name, _t0, dur in ops:
                     # under jax.grad the calls are jvp_<name>_ and
@@ -737,13 +754,102 @@ def _bench_flash_tiles(cases, steps, fa=None):
     return rows
 
 
+def _bench_weight_grad(shapes, steps):
+    """A dense layer's weight gradient ``x^T dOut`` with an Adam-like update
+    behind it, alone and on PLAIN operands, in three forms: ``vjp`` as
+    ``jax.vjp`` of ``x @ w`` forms it (the update fused behind the product),
+    ``alone`` as ``mul_grad`` forms it for an ``x`` wider than ``dOut``
+    (``ops/math_ops.py``: ``_lone_weight_grad``: the same operand order, the
+    product and the update each alone) and ``turned`` (``dOut^T x`` alone,
+    one relayout of the gradient, the update alone in the parameter's
+    layout: the other operand order, which PR 54 tried first). bfloat16
+    operands and gradient, float32 parameter and moments, all donated;
+    device time of a call from a profiler trace, the share of the chip's
+    bfloat16 peak that the product's ``2 M K N`` operations reach over the
+    WHOLE call, and the call's largest ops. These rows are the floor a
+    step's program can reach (PR 54: ~88% of peak fused and ~74% turned at
+    every shape, whichever operand is the wide one: the orientation costs
+    nothing); inside a step the operands come from fusions that may rebuild
+    them a window, which only the cell's trace shows."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import math_ops
+    from perfbench import trace_reduce
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench")
+    with open(os.path.join(root, "peaks.json")) as f:
+        peak = json.load(f)["chips"][0]["bf16_flops_per_s"]
+    rows_of = (((0,), (0,)), ((), ()))
+
+    def turned(dout, x):
+        barrier = jax.lax.optimization_barrier
+        dyt = barrier(jax.lax.dot_general(dout, x, rows_of))
+        # flat: one dimension has one layout, so the update keeps its own
+        return jnp.reshape(barrier(jnp.reshape(dyt.T, (-1,))),
+                           dyt.shape[::-1])
+
+    forms = {
+        "vjp": lambda dout, x: jax.lax.dot_general(x, dout, rows_of),
+        "alone": lambda dout, x: math_ops._lone_weight_grad(x, dout),
+        "turned": turned,
+    }
+    rows = []
+    for M, K, N in shapes:
+        kx, kd = jax.random.split(jax.random.PRNGKey(0))
+        x = jax.random.normal(kx, (M, K), jnp.float32).astype(jnp.bfloat16)
+        dout = jax.random.normal(kd, (M, N), jnp.float32).astype(
+            jnp.bfloat16)
+        for form, product in forms.items():
+
+            def step(x_, dout_, p, m1, m2, product=product):
+                g = product(dout_, x_).astype(jnp.float32)
+                m1 = 0.9 * m1 + 0.1 * g
+                m2 = 0.999 * m2 + 0.001 * g * g
+                return p - 2e-4 * m1 / (jnp.sqrt(m2) + 1e-8), m1, m2
+
+            fn = jax.jit(step, donate_argnums=(2, 3, 4))
+            state = tuple(jnp.zeros((K, N), jnp.float32) for _ in range(3))
+            state = [jax.block_until_ready(fn(x, dout, *state))]
+
+            def call():
+                state[0] = jax.block_until_ready(fn(x, dout, *state[0]))
+
+            ops = _traced_device_ops(call, steps)
+            if not ops:   # the CPU smoke: the forms ran, nothing timed
+                row = {"kernel": "weight_grad", "x": [M, K], "dout": [M, N],
+                       "form": form, "error": "no device plane in the trace"}
+                print(json.dumps(row), flush=True)
+                rows.append(row)
+                continue
+            by_op = {}
+            for name, _t0, dur in ops:
+                shape = trace_reduce.first_shape(name)
+                label = "%s %s%s" % (
+                    trace_reduce.op_name(name),
+                    shape[0] if shape else "", list(shape[1]) if shape
+                    else "")
+                by_op[label] = by_op.get(label, 0.0) + dur / 1e6 / steps
+            ms = sum(by_op.values())
+            row = {"kernel": "weight_grad", "x": [M, K], "dout": [M, N],
+                   "form": form, "ms": round(ms, 4),
+                   "pct_of_peak": round(
+                       100 * 2.0 * M * K * N / (ms * 1e-3) / peak, 1),
+                   "ops_ms": {k: round(v, 4) for k, v in sorted(
+                       by_op.items(), key=lambda kv: -kv[1])[:4]}}
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+    return rows
+
+
 def _on_tpu():
     return os.environ.get("BENCH_PLATFORM") != "cpu"
 
 
 _FAMILIES = ("dynamic_lstm", "dynamic_gru", "flash_attention",
              "flash_tiles", "paged_decode", "latent_decode", "gqa_decode",
-             "delta_rule", "ssd")
+             "delta_rule", "ssd", "weight_grad")
 
 
 def _orchestrate(args):
@@ -921,6 +1027,13 @@ def main():
     elif args.family == "ssd":
         _bench_ssd(ssd_update, ssd_prefill, 2 if args.quick else 36, steps,
                    warmup)
+    elif args.family == "weight_grad":
+        # transformer_big's step (64 x 256 tokens): the FFN's second and
+        # first product, an attention projection, the 32000-wide head
+        _bench_weight_grad(
+            [(64, 48, 16), (64, 16, 48)] if args.quick else
+            [(16384, 4096, 1024), (16384, 1024, 4096), (16384, 1024, 1024),
+             (16384, 1024, 32000)], 2 if args.quick else 10)
     elif args.family == "flash_tiles":
         _bench_flash_tiles(_flash_tile_cases(args.quick),
                            2 if args.quick else 5)
