@@ -307,9 +307,9 @@ def embedding_rows(table, ids, name=None):
 
 def delta_rule_gates(f, dt_bias, a_log, b, heads, beta_scale=1.0, name=None):
     """A delta-rule mixer's gates (``ops/linear_attention_ops.py``): the
-    log decay a key channel ``-exp(a_log) * softplus(f + dt_bias)`` [N,
-    heads * dk] and the step ``beta_scale * sigmoid(b)`` [N, heads], both
-    float32."""
+    log decay ``-exp(a_log) * softplus(f + dt_bias)``, a key channel [N,
+    heads * dk] or a head [N, heads] as ``f`` is, and the step
+    ``beta_scale * sigmoid(b)`` [N, heads], both float32."""
     helper = LayerHelper("delta_rule_gates", name=name)
     g, beta = [helper.create_variable_for_type_inference("float32")
                for _ in range(2)]
@@ -321,11 +321,13 @@ def delta_rule_gates(f, dt_bias, a_log, b, heads, beta_scale=1.0, name=None):
     return g, beta
 
 
-def delta_rule_prefill(q, k, v, g, beta, lens, name=None):
+def delta_rule_prefill(q, k, v, g, beta, lens, state_pack=1, name=None):
     """The gated delta rule over a prefill dispatch's prompts (one a
-    bucket row), in chunks. Returns (out [N, heads * dv] float32, state
-    [prompts, heads, dk, dv] float32 after each prompt's last real
-    token)."""
+    bucket row), in chunks; ``g`` a key channel [N, heads * dk] or a head
+    [N, heads]. Returns (out [N, heads * dv] float32, state [prompts,
+    heads / state_pack, dk, state_pack * dv] float32 after each prompt's
+    last real token: ``state_pack`` heads' value lanes side by side, as
+    the served array holds them)."""
     helper = LayerHelper("delta_rule_prefill", name=name)
     out, state = [helper.create_variable_for_type_inference("float32")
                   for _ in range(2)]
@@ -333,14 +335,16 @@ def delta_rule_prefill(q, k, v, g, beta, lens, name=None):
         type="delta_rule_prefill",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
                 "Lens": [lens]},
-        outputs={"Out": [out], "State": [state]})
+        outputs={"Out": [out], "State": [state]},
+        # named only where asked: the other models' programs keep theirs
+        attrs={"state_pack": int(state_pack)} if state_pack != 1 else {})
     return out, state
 
 
 def delta_rule_state_update(state, q, k, v, g, beta, live, name=None):
     """One token of the gated delta rule for every slot: out [S, heads *
-    dv] float32; ``state`` [S, heads, dk, dv] is updated in place (a slot
-    that is not live keeps its own and reads 0)."""
+    dv] float32; ``state`` [S, heads / pack, dk, pack * dv] is updated in
+    place (a slot that is not live keeps its own and reads 0)."""
     helper = LayerHelper("delta_rule_state_update", name=name)
     out = helper.create_variable_for_type_inference("float32")
     helper.append_op(
@@ -351,10 +355,14 @@ def delta_rule_state_update(state, q, k, v, g, beta, live, name=None):
     return out
 
 
-def gated_head_norm(x, scale, gate, heads, epsilon=1e-5, name=None):
+def gated_head_norm(x, scale, gate, heads, epsilon=1e-5, gate_act=None,
+                    name=None):
     """``RMSNorm`` over each of ``heads`` heads of ``x`` (one ``scale``
-    vector) times ``sigmoid(gate)``, in ``gate``'s dtype."""
+    vector) times ``sigmoid(gate)``, or ``silu(gate)`` under ``gate_act=
+    "silu"``, in ``gate``'s dtype."""
+    attrs = {"heads": int(heads), "epsilon": float(epsilon)}
+    if gate_act:        # named only where asked
+        attrs["gate_act"] = str(gate_act)
     return _one("gated_head_norm",
-                {"X": [x], "Scale": [scale], "Gate": [gate]},
-                {"heads": int(heads), "epsilon": float(epsilon)},
+                {"X": [x], "Scale": [scale], "Gate": [gate]}, attrs,
                 dtype=gate.dtype, name=name)

@@ -2,7 +2,8 @@
 frame every family's builder fills (``models/latent_moe_decoder.py``,
 ``hybrid_ssm_decoder.py``, ``windowed_moe_decoder.py``,
 ``linear_attn_moe_decoder.py`` (two namings, one file),
-``ssd_moe_decoder.py``, ``shortcut_moe_decoder.py``). A family file
+``ssd_moe_decoder.py``, ``shortcut_moe_decoder.py``,
+``gated_delta_decoder.py``). A family file
 holds what its layers do and the state they keep (``DecoderFamily``); this
 module holds what a
 ``serving.decoder_session.DecoderOnlySession`` dispatches, whatever the
@@ -80,6 +81,10 @@ _FAMILIES = (
      "windowed_moe_decoder", "build_windowed_moe_decoder"),
     (lambda desc: "linear_attn_config" in desc,
      "linear_attn_moe_decoder", "build_linear_attn_moe_decoder"),
+    # Gated DeltaNet layers named by the ``linear_*`` keys: dense, its
+    # ``layer_types`` has no ``sliding_window`` beside it
+    (lambda desc: "linear_key_head_dim" in desc,
+     "gated_delta_decoder", "build_gated_delta_decoder"),
 )
 
 
@@ -102,9 +107,11 @@ def builder_for(desc):
         "decoder of delta-rule linear-attention layers beside latent-"
         "attention layers (linear_attn_config with kv_lora_rank), a "
         "latent-attention decoder (kv_lora_rank), a decoder of window "
-        "and full attention layers (layer_types with a sliding_window) or "
+        "and full attention layers (layer_types with a sliding_window), "
         "a decoder of delta-rule linear-attention and grouped-query "
-        "attention layers (linear_attn_config)" % sorted(desc))
+        "attention layers (linear_attn_config) or a dense decoder of "
+        "Gated DeltaNet and multi-head attention layers "
+        "(linear_key_head_dim)" % sorted(desc))
 
 
 def load_named(scope, named, shapes=None):

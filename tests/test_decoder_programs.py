@@ -1,17 +1,17 @@
 """The decoder-only serving programs, op for op and name for name.
 
-``models/decoder_programs.py`` writes the programs' frame once and the six
+``models/decoder_programs.py`` writes the programs' frame once and the seven
 family files fill it; ``tests/golden/decoder_programs.json`` holds
-``core.fingerprint.program_fingerprint`` of every program of the eight test
+``core.fingerprint.program_fingerprint`` of every program of the nine test
 descriptions (``DESC`` of ``test_latent_moe_decoder``,
 ``test_sparse_latent_decoder``, ``test_hybrid_ssm_decoder``,
 ``test_windowed_moe_decoder``, ``test_linear_attn_decoder``,
 ``test_ssd_moe_decoder``, ``test_shortcut_moe_decoder``,
-``test_linear_latent_decoder``, at the geometry those files' sessions
-use): ``init``, ``step``, ``step`` with
+``test_linear_latent_decoder``, ``test_gated_delta_decoder``, at the
+geometry those files' sessions use): ``init``, ``step``, ``step`` with
 ``probe_rows=2`` and every rung of every bucket's prefill. They were
-recorded at the commit BEFORE the frame was written once (the sixth, the
-seventh and the eighth with the PR that brought each; a rung of B rows
+recorded at the commit BEFORE the frame was written once (the sixth to
+the ninth with the PR that brought each; a rung of B rows
 that a builder of that commit did not take is the program it built for a
 budget of B rows), so a case fails at any commit that adds, drops or
 reorders an op, renames a variable or changes an attribute: the fingerprint
@@ -44,7 +44,8 @@ GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden",
 FAMILIES = {"test_latent_moe_decoder": 4, "test_sparse_latent_decoder": 4,
             "test_hybrid_ssm_decoder": 6, "test_windowed_moe_decoder": 4,
             "test_linear_attn_decoder": 6, "test_ssd_moe_decoder": 6,
-            "test_shortcut_moe_decoder": 4, "test_linear_latent_decoder": 6}
+            "test_shortcut_moe_decoder": 4, "test_linear_latent_decoder": 6,
+            "test_gated_delta_decoder": 6}
 
 
 @functools.lru_cache(maxsize=None)
@@ -109,9 +110,10 @@ def test_an_unknown_description_is_refused_with_the_families_named():
         "linear-attention layers beside latent-attention layers "
         "(linear_attn_config with kv_lora_rank), a latent-attention decoder "
         "(kv_lora_rank), a decoder of window and full attention layers "
-        "(layer_types with a sliding_window) or a decoder of delta-rule "
+        "(layer_types with a sliding_window), a decoder of delta-rule "
         "linear-attention and grouped-query attention layers "
-        "(linear_attn_config)")
+        "(linear_attn_config) or a dense decoder of Gated DeltaNet and "
+        "multi-head attention layers (linear_key_head_dim)")
 
 
 def test_the_two_namings_of_the_linear_family_reach_one_builder():

@@ -863,6 +863,65 @@ def test_delta_rule_update_updates_the_state_in_place(v5e, S, H):
         == {"3,2,1,0"}
 
 
+@pytest.mark.parametrize("B,T", [(8, 1024), (32, 256)])
+def test_delta_rule_prefill_lowers_at_a_decay_a_head_and_odd_widths(v5e, B,
+                                                                    T):
+    """The chunked delta rule of the 7B hybrid's cell: 30 heads of 96 keys
+    beside 192 values under ONE log decay a head, bucket rows of 8 prompts
+    of 1024 and 32 of 256. Neither width is a lane multiple: the rows go
+    in head-major."""
+    from paddle_tpu.kernels import delta_rule as dr
+
+    H, dk, dv = 30, 96, 192
+    text = _compile_v5e(
+        v5e, lambda q, k, v, g, beta, lens: dr.chunk_prefill(
+            q, k, v, g, beta, lens, force_pallas=True),
+        ((B, T, H * dk), BF16), ((B, T, H * dk), BF16),
+        ((B, T, H * dv), BF16), ((B, T, H), F32), ((B, T, H), F32),
+        ((B,), jnp.int32))
+    assert dr.CHUNK_KERNEL_NAME in text
+
+
+@pytest.mark.parametrize("pack", [2, 1])
+def test_delta_rule_update_holds_the_state_at_its_published_size(v5e, pack):
+    """The one-token update at 96 slots of 30 heads of 96 x 192 under a
+    decay a head, the state donated. Two heads a tile (384 lanes: three
+    whole tiles) the state's array is its elements, 212 MB, and the
+    arguments are within 5% of that; a head a tile the 192 lanes are laid
+    out as 256 and the array is a third larger. Mosaic takes both; neither
+    program copies the state."""
+    import re
+
+    from paddle_tpu.kernels import delta_rule as dr
+
+    S, H, dk, dv = 96, 30, 96, 192
+    shape = (S, H // pack, dk, pack * dv)
+
+    def step(state, q, k, v, g, beta, live):
+        with _tpu_target():
+            return dr.state_update(state, q, k, v, g, beta, live)
+
+    sharding = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in (
+                (shape, F32), ((S, H * dk), BF16), ((S, H * dk), BF16),
+                ((S, H * dv), BF16), ((S, H), F32), ((S, H), F32),
+                ((S,), jnp.int32))]
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert dr.STATE_KERNEL_NAME in text
+    moved = [line.strip()[:160] for line in text.splitlines() if re.match(
+        r"\s*(?:ROOT )?%%\S+ = \w+\[%d,%d,%d,%d\]\S* "
+        r"(copy|transpose|gather|copy-start)\(" % shape, line)]
+    assert not moved, "the step moves a whole state array:\n" + "\n".join(moved)
+    published = S * H * dk * dv * 4
+    held = compiled.memory_analysis().argument_size_in_bytes
+    if pack == 2:
+        assert published < held < 1.05 * published, held
+    else:
+        assert held > 1.3 * published, held
+
+
 def test_linear_decoder_step_copies_no_state_array_and_no_pool(v5e):
     """The whole decode program of the delta-rule decoder (an attention
     layer then three linear layers at the served head widths, 8 slots, 4
@@ -1360,3 +1419,101 @@ def test_linear_latent_decoder_programs_fit_the_chip_at_the_served_sizes(v5e):
     assert step.memory_analysis().alias_size_in_bytes >= resident
     assert _peak_bytes(step) < 12.5e9, _peak_bytes(step)
     assert _peak_bytes(prefill) < 15.0e9, _peak_bytes(prefill)
+
+
+def test_gated_delta_decoder_programs_fit_the_chip_at_the_served_sizes(v5e):
+    """The cell's own configuration (``perfbench/configs/
+    olmo_hybrid_8l.json``: six Gated DeltaNet layers and two multi-head
+    attention layers, every FFN dense, the whole vocabulary, 96 slots of
+    2048 positions) compiled for the described chip from shapes alone: the
+    decode dispatch and the fullest prefill of every bucket. Mosaic takes
+    the kernels at these shapes (the delta rule at 30 heads of 96 x 192
+    under a decay a head, two heads a tile of the state; the grouped-query
+    decode at a group of ONE over 3840-wide rows; the flash forward at 30
+    heads of 128), the instructions say which sub-block they are, the
+    matrix states and the K/V pools are updated in place, a linear layer's
+    state is held at its published 2.21 MB a slot, and arguments +
+    temporaries stay under 15.0 GB of the chip's 17.18 (PERF.md, PR 55:
+    12.23 + 0.01 and 12.23 + 0.92)."""
+    import json
+    import re
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import _described_compile as dc
+    from paddle_tpu.kernels import delta_rule as dr
+    from paddle_tpu.models import gated_delta_decoder as gdd
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "olmo_hybrid_8l.json")) as f:
+        cfg = json.load(f)
+    pool = cfg["pool"]
+    S = pool["num_slots"]
+    built = gdd.build_gated_delta_decoder(
+        cfg, S, pool["max_prompt"] + pool["max_new_tokens"],
+        pool["page_size"], pool["prefill_buckets"],
+        prefill_token_budget=pool["prefill_token_budget"],
+        tokens_per_dispatch=pool["tokens_per_dispatch"],
+        prefill_rungs=True, probe_rows=2)
+    geo, f = built["geometry"], built["fetches"]
+    npp = geo["pages_per_slot"]
+    state = dict(gdd.parameter_shapes(cfg, "bfloat16"))
+    shapes = []
+    for kind in ("page_pools", "slot_arrays"):
+        for name, a in geo["state"][kind].items():
+            state[name] = (a["shape"], a["dtype"])
+            shapes.append(",".join(str(n) for n in a["shape"]))
+    assert list(geo["state"]["page_pools"]) == [
+        "gdd_k_3", "gdd_v_3", "gdd_k_7", "gdd_v_7"]
+    assert geo["state"]["page_pools"]["gdd_k_3"]["shape"] \
+        == (1537, 128, 3840)
+    assert geo["state"]["slot_arrays"]["gdd_s_6"]["shape"] \
+        == (96, 15, 96, 384)
+    assert geo["state"]["slot_arrays"]["gdd_win_6"]["shape"] \
+        == (3, 96, 11520)
+    assert geo["state_bytes_slot_layer"] == 30 * 96 * 192 * 4 == 2211840
+    assert geo["kv_row_bytes"] == 15360
+    state["gdd_tok"] = state["gdd_pos"] = ((S, 1), "int64")
+
+    with _tpu_target():
+        step = dc.compile_program(
+            built["step"], v5e[0], state,
+            {"page_table": ((S, npp), "int64"), "live": ((S, 1), "int64"),
+             "probe_slots": ((2,), "int64")},
+            [f["token"], f["probe_logits"]], steps=4)
+        prefills = []
+        for T in pool["prefill_buckets"]:
+            B = pool["prefill_token_budget"] // T
+            prefills.append(dc.compile_program(
+                built["prefill_rungs"][T][B], v5e[0], state,
+                {"prompt_ids": ((B * T,), "int64"),
+                 "prompt_len": ((B,), "int64"),
+                 "slot_idx": ((B,), "int64"),
+                 "page_rows": ((B, npp), "int64"),
+                 "last_idx": ((B,), "int64")},
+                [f["first_token"], f["first_logits"]]))
+    text = step.as_text()
+    for name in ("gdn_mixer/" + dr.STATE_KERNEL_NAME,
+                 "gdn_mixer/ssm_conv_step", "mha_attention/",
+                 "gqa_paged_decode_attention", "dense_ffn/"):
+        assert name in text, name
+    moved = [line.strip()[:160] for line in text.splitlines() if re.match(
+        r"\s*(?:ROOT )?%%\S+ = \w+\[(%s)\]\S* "
+        r"(copy|transpose|gather|copy-start)\(" % "|".join(
+            sorted(set(shapes))), line)]
+    assert not moved, "the step moves a state array or a pool:\n" \
+        + "\n".join(moved)
+    resident = sum(int(np.prod(a["shape"])) * (4 if a["dtype"] == "float32"
+                                               else 2)
+                   for kind in ("page_pools", "slot_arrays")
+                   for a in geo["state"][kind].values())
+    # 6.04 GB of rows, 1.27 GB of state, 0.04 GB of windows
+    assert round(resident / 1e9, 2) == 7.36
+    assert step.memory_analysis().alias_size_in_bytes >= resident
+    assert _peak_bytes(step) < 12.5e9, _peak_bytes(step)
+    for pre in prefills:
+        text = pre.as_text()
+        assert "flash_attention_fwd" in text
+        assert dr.CHUNK_KERNEL_NAME in text
+        assert _peak_bytes(pre) < 15.0e9, _peak_bytes(pre)

@@ -336,6 +336,7 @@ def _bench_gqa_decode(cases, walks, calls, steps, warmup, seed=0):
     import numpy as np
 
     from paddle_tpu.kernels import gqa_paged_attention as gq
+    from paddle_tpu.kernels import paged_attention as pa
     from paddle_tpu.kernels import window_paged_attention as wp
     from paddle_tpu.models import windowed_moe_decoder as wmd
 
@@ -399,6 +400,11 @@ def _bench_gqa_decode(cases, walks, calls, steps, warmup, seed=0):
 
         last = np.maximum(lengths - 1, 0) // ps
         kernels = [("full", -(-T // ps), np.zeros(S, "int64"), lengths)]
+        if H == Hkv:
+            # multi-head: the Transformer's kernel (every head on the
+            # lanes of one row) takes these pools too; one row, its rule
+            kernels.append(("paged", -(-T // ps), np.zeros(S, "int64"),
+                            lengths))
         window = pool_cfg.get("window", cfg.get("sliding_window"))
         if window:
             first = np.maximum(lengths - window, 0)
@@ -430,6 +436,14 @@ def _bench_gqa_decode(cases, walks, calls, steps, warmup, seed=0):
                         q, kp, vp, table, lens, sm_scale,
                         interpret=not _on_tpu(), **walk)
 
+                if kind == "paged":
+                    name = pa.PAGED_KERNEL_NAME
+
+                    def kernel(q, kp, vp):
+                        return pa._paged_pallas(
+                            q, kp, vp, table, lens, sm_scale,
+                            interpret=not _on_tpu())
+
                 def reference(rows):
                     return gq.gqa_paged_attention_reference(
                         q[rows], kp, vp, table[rows], lens[rows], sm_scale)
@@ -440,8 +454,15 @@ def _bench_gqa_decode(cases, walks, calls, steps, warmup, seed=0):
                 np.asarray(reference(slice(at, at + 8)), "float32")
                 for at in range(0, S, 8)])
             floor = least(int(visible.sum()))
-            for walk in walks:
-                got = np.asarray(kernel(q, kp, vp, **walk), "float32")
+            for walk in (walks if kind != "paged" else ({},)):
+                try:
+                    got = np.asarray(kernel(q, kp, vp, **walk), "float32")
+                except Exception as exc:    # a walk Mosaic refuses: a row
+                    row = {"kernel": name, "config": config, "walk": walk,
+                           "refused": str(exc).splitlines()[0][:240]}
+                    print(json.dumps(row))
+                    rows.append(row)
+                    continue
 
                 def run(q, kp, vp, walk=walk):
                     def body(q, _):
@@ -550,29 +571,38 @@ def _bench_delta_rule(update_shapes, prefill_shapes, calls, steps, warmup):
 
     from paddle_tpu.kernels import delta_rule as dr
 
-    def inputs(key, rows, H, dk, dv):
+    def inputs(key, rows, H, dk, dv, pack=0):
+        """``pack`` > 0: the log decay a HEAD (Gated DeltaNet), else a key
+        channel."""
         ks = jax.random.split(key, 5)
         q, k = (jax.random.normal(ks[i], rows + (H * dk,), jnp.bfloat16)
                 for i in range(2))
         v = jax.random.normal(ks[2], rows + (H * dv,), jnp.bfloat16)
         g = -jnp.exp(jax.random.uniform(
-            ks[3], rows + (H * dk,), jnp.float32, np.log(1e-3), np.log(4.0)))
+            ks[3], rows + (H if pack else H * dk,), jnp.float32,
+            np.log(1e-3), np.log(4.0)))
         beta = jax.random.uniform(ks[4], rows + (H,), jnp.float32, 0.0, 2.0)
         return q, k, v, g, beta
 
+    # a shape's trailing ``pack`` (heads a tile of the state, 1 or 2) marks
+    # a decay a head; the prefill's is only that mark
     updates, prefills = [], []
-    for S, live, H, dk, dv in update_shapes:
-        args = inputs(jax.random.PRNGKey(S), (S,), H, dk, dv) + (
+    for S, live, H, dk, dv, *pack in update_shapes:
+        pack = pack[0] if pack else 0
+        args = inputs(jax.random.PRNGKey(S), (S,), H, dk, dv, pack) + (
             jnp.asarray(np.arange(S) < live, jnp.int32),)
+        tiles = max(pack, 1)
         updates.append((
-            (S, live, H, dk, dv), 2 * live * H * dk * dv * 4,
-            (S, H, dk, dv), lambda state, args=args, **kw: dr.state_update(
+            (S, live, H, dk, dv) + ((pack,) if pack else ()),
+            2 * live * H * dk * dv * 4, (S, H // tiles, dk, tiles * dv),
+            lambda state, args=args, **kw: dr.state_update(
                 state, *args, **kw)[1]))
-    for B, T, n, H, dk, dv in prefill_shapes:
-        args = inputs(jax.random.PRNGKey(T), (B, T), H, dk, dv) + (
+    for B, T, n, H, dk, dv, *pack in prefill_shapes:
+        args = inputs(jax.random.PRNGKey(T), (B, T), H, dk, dv,
+                      pack[0] if pack else 0) + (
             jnp.full((B,), n, jnp.int32),)
-        prefills.append(((B, T, n, H, dk, dv), B * H * -(-n // dr.CHUNK),
-                         args, dr.chunk_prefill))
+        prefills.append(((B, T, n, H, dk, dv) + tuple(pack),
+                         B * H * -(-n // dr.CHUNK), args, dr.chunk_prefill))
     return _bench_state_kernels(
         (dr.STATE_KERNEL_NAME, dr.CHUNK_KERNEL_NAME), updates, prefills,
         calls, steps, warmup)
@@ -904,6 +934,9 @@ def main():
                     help="tiny shapes + few steps (CPU smoke)")
     ap.add_argument("--family", choices=_FAMILIES,
                     help="run ONE family, in this process")
+    ap.add_argument("--config", default="",
+                    help="families delta_rule and gqa_decode: only the "
+                         "rows of this perfbench configuration")
     ap.add_argument("--pages", default="1,2,4,8",
                     help="gqa_decode: the pages a step of the walk to "
                          "sweep beside the kernels' own rule")
@@ -930,8 +963,10 @@ def main():
         rnn_shapes = [(4, 16, 32)]
         fa_shapes = [(1, 2, 128, 32)]
         paged_shapes, paged_calls = [(8, 3, 2, 16, 8, 32)], 2
-        update_shapes, prefill_shapes = [(4, 3, 2, 16, 16)], \
-            [(1, 128, 100, 2, 16, 16)]
+        # the second of each: a decay a head, two heads a tile
+        update_shapes, prefill_shapes = \
+            [(4, 3, 2, 16, 16), (4, 3, 4, 24, 64, 2)], \
+            [(1, 128, 100, 2, 16, 16), (1, 128, 100, 3, 24, 40, 1)]
         ssd_update, ssd_prefill = [(4, 3, 4, 8, 16)], \
             [(2, 64, 40, 4, 8, 16)]
         latent_cases = [("glm47_flash_6l", "closed_320_chat",
@@ -994,6 +1029,28 @@ def main():
         update_shapes += [(384, live, 32, 128, 128) for live in (384, 256)]
         prefill_shapes += [(2, 4096, 4096, 32, 128, 128),
                            (8, 1024, 700, 32, 128, 128)]
+        # perfbench olmo_hybrid_8l: 96 slots (all live, and two thirds) of
+        # 30 heads of 96 x 192 under a decay a HEAD, two heads a tile of
+        # the state (and one, the layout that pads 192 lanes to 256), 24
+        # calls a dispatch (4 tokens x 6 linear layers); bucket rows of 8
+        # prompts of 1024 and 32 of 256 that end inside a chunk
+        olmo_update = [(96, live, 30, 96, 192, pack)
+                       for pack in (2, 1) for live in (96, 64)]
+        olmo_prefill = [(8, 1024, 700, 30, 96, 192, 2),
+                        (32, 256, 200, 30, 96, 192, 2)]
+        if args.config == "olmo_hybrid_8l":
+            update_shapes, prefill_shapes = olmo_update, olmo_prefill
+        elif not args.config:
+            update_shapes += olmo_update
+            prefill_shapes += olmo_prefill
+        # olmo_hybrid_8l's full layers: 96 slots, 30 heads on 30 (a group
+        # of ONE), tables of 16 pages of 3840-wide rows; the Transformer's
+        # kernel at 30 x 128 beside the grouped-query one
+        gqa_cases.append(("olmo_hybrid_8l", "closed_120_evalgen",
+                          ("kernel_costs_olmo", "mha_decode_attention"),
+                          {}))
+        if args.config:
+            gqa_cases = [c for c in gqa_cases if c[0] == args.config]
         # perfbench granite4_h_small_10l: 64 slots (all live, and three
         # quarters) of 128 heads of 64 x 128, 36 calls a dispatch (4 tokens
         # x 9 Mamba-2 layers); one 4096-token prompt, whole and ended a
