@@ -231,10 +231,12 @@ def paged_kv_prefill(k_pool, v_pool, k_new, v_new, page_row, write_from,
 
 def paged_copy_page(k_pool, v_pool, src_page, dst_page, name=None):
     """On-device page copy (the COW primitive): ``pool[dst] =
-    pool[src]`` for both the K and V pool in one op. The serving
-    session dispatches this before repointing a forked slot's table
-    row at the private copy. In-place state convention on the pool
-    vars."""
+    pool[src]`` for both the K and V pool in one op. ``src_page`` /
+    ``dst_page`` are ``[n]`` int tensors, one pair or a whole window's,
+    every source read before any destination is written (the op's
+    lowering says which windows that allows). The serving session
+    dispatches this before repointing a forked slot's table row at the
+    private copy. In-place state convention on the pool vars."""
     helper = LayerHelper("paged_copy_page", name=name)
     helper.append_op(
         type="paged_copy_page",

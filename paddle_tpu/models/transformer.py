@@ -1715,13 +1715,22 @@ def build_cow_batch_prog(num_slots, max_length, n_layer, n_head,
     multiply COW pairs per step, so the dispatch count is the hot-path
     number; tests pin it).
 
+    The program takes its window WHOLE, it does not unroll it: one
+    ``paged_copy_page`` a layer takes the ``src_pages`` / ``dst_pages``
+    vectors (a gather of every source page, then a scatter onto the
+    destinations) and one row scatter installs the rows, so a program
+    holds ``n_layer + 1`` operators at every rung and building it costs
+    the same at 512 pairs as at 1. Every source is read before any
+    destination is written; ``serving.generation._check_cow_window``
+    holds a window to what makes that equal to copying in order.
+
     Feeds: ``src_pages``/``dst_pages``/``slot_idxs`` ``[pairs]`` int64
     and ``page_rows [pairs, npp]`` — each pair's slot with that slot's
     FINAL row (a slot with several pairs in one window repeats its
-    final row; the repeated scatter is idempotent). Pad short windows
-    with ``(src=0, dst=0)`` trash-page self-copies bound to a live
-    slot's unchanged row — bit-neutral by construction. Copies all run
-    before any repoint (the copy-before-repoint COW discipline, batch
+    final row; the repeated scatter writes equal values). Pad short
+    windows with ``(src=0, dst=0)`` trash-page self-copies bound to a
+    live slot's unchanged row — bit-neutral by construction. Copies all
+    run before any repoint (the copy-before-repoint COW discipline, batch
     edition). ``pairs`` is a bucket-ladder rung
     (``analysis.lint.suggest_buckets`` discipline): the session builds
     one program per rung and pads up, so the executable set stays
@@ -1755,7 +1764,6 @@ def build_cow_batch_prog(num_slots, max_length, n_layer, n_head,
                                 append_batch_size=False)
             page_rows = nn.data("page_rows", shape=[n, npp],
                                 dtype="int64", append_batch_size=False)
-            idxs = [nn.fill_constant([1], "int64", i) for i in range(n)]
             for i in range(n_layer):
                 kpool = blk.create_var(name="pgd_kpool_%d" % i,
                                        shape=pool_shape,
@@ -1763,17 +1771,10 @@ def build_cow_batch_prog(num_slots, max_length, n_layer, n_head,
                 vpool = blk.create_var(name="pgd_vpool_%d" % i,
                                        shape=pool_shape,
                                        dtype="float32", persistable=True)
-                for j in range(n):
-                    fluid.layers.paged_copy_page(
-                        kpool, vpool,
-                        nn.gather(src_pages, idxs[j]),
-                        nn.gather(dst_pages, idxs[j]))
-            t = blk.create_var(name="pgd_table", shape=[S, npp],
-                               dtype="int64", persistable=True)
-            for j in range(n):
-                nn.dynamic_update_slice(
-                    t, nn.gather(page_rows, idxs[j]),
-                    nn.gather(slot_idxs, idxs[j]), axis=0, out=t)
+                fluid.layers.paged_copy_page(
+                    kpool, vpool, src_pages, dst_pages)
+            _write_rows(blk, "pgd_table", [S, npp], page_rows, slot_idxs,
+                        n, "int64")
     return prog
 
 

@@ -293,21 +293,32 @@ register_op(
 
 def _lower_paged_copy_page(ctx, ins, attrs):
     """On-device page copy — the copy half of copy-on-write: duplicate
-    one K and one V page (``pool[dst] = pool[src]``) so a forked slot
-    whose write position enters a SHARED page (refcount > 1) gets a
-    private bit-identical copy before its table row repoints. Both
-    pools move in one op so a COW is one fused dispatch per layer, not
-    two."""
-    k_pool = ins["KPool"][0]  # [P, page_size, H * dh]
-    v_pool = ins["VPool"][0]
-    src = jnp.reshape(ins["Src"][0], ()).astype(jnp.int32)
-    dst = jnp.reshape(ins["Dst"][0], ()).astype(jnp.int32)
+    K and V pages (``pool[dst] = pool[src]``) so a forked slot whose
+    write position enters a SHARED page (refcount > 1) gets a private
+    bit-identical copy before its table row repoints. ``Src`` / ``Dst``
+    hold one pair or a whole window's ``[n]``: every source page is READ
+    (one gather) before any destination is written (one scatter), which
+    equals copying the pairs in order whenever no destination is another
+    pair's source or destination and a repeated pair copies a page onto
+    itself — what ``SlotDecodeSession._dispatch_cow`` holds its windows
+    to. Both pools move in one op so a COW is one fused dispatch per
+    layer, not two."""
+    src = jnp.reshape(ins["Src"][0], (-1,)).astype(jnp.int32)  # [n]
+    dst = jnp.reshape(ins["Dst"][0], (-1,)).astype(jnp.int32)
 
-    def copy(pool):
-        row = jax.lax.dynamic_slice_in_dim(pool, src, 1, axis=0)
-        return jax.lax.dynamic_update_slice_in_dim(pool, row, dst, axis=0)
+    def copy(pool):  # [P, page_size, H * dh]
+        return pool.at[dst].set(pool[src])
 
-    return {"KOut": copy(k_pool), "VOut": copy(v_pool)}
+    return {"KOut": copy(ins["KPool"][0]), "VOut": copy(ins["VPool"][0])}
+
+
+def _paged_copy_page_infer_shape(block, op):
+    # KOut / VOut are their pools' shapes: declared, so that appending the
+    # op traces nothing to learn them
+    for pool_slot, out_slot in (("KPool", "KOut"), ("VPool", "VOut")):
+        pool = block._find_var_recursive(op.input(pool_slot)[0])
+        out = block._find_var_recursive(op.output(out_slot)[0])
+        out.shape, out.dtype = pool.shape, pool.dtype
 
 
 register_op(
@@ -317,6 +328,7 @@ register_op(
     lower=_lower_paged_copy_page,
     grad=None,
     no_grad_inputs=("Src", "Dst"),
+    infer_shape=_paged_copy_page_infer_shape,
 )
 
 

@@ -202,6 +202,24 @@ _spec_accept_rate = _REGISTRY.gauge(
     "is 1 + rate * K")
 
 
+def _check_cow_window(copies):
+    """The copy program (``build_cow_batch_prog``) gathers every source
+    page of a window and then scatters them onto the destinations, which
+    leaves what copying the pairs IN ORDER would only if no destination
+    is another pair's source or destination. A destination is a page the
+    window itself acquired (``_cow_copies``, the copy-reorder oracle), so
+    it is nobody's source and nobody else's destination by construction;
+    this holds every window to that before it is dispatched. ``copies``:
+    the window's real ``(slot, src, dst)`` pairs (the ``(0, 0)`` trash
+    self-copies that pad a rung write a page onto itself)."""
+    dsts = {dst for _slot, _src, dst in copies}
+    if (len(dsts) != len(copies) or 0 in dsts
+            or not dsts.isdisjoint(src for _slot, src, _dst in copies)):
+        raise RuntimeError(
+            "copy-on-write window %r: a destination page is the trash "
+            "page, repeated, or another pair's source" % (copies,))
+
+
 class SlotDecodeSession(object):
     """Continuous-batching decode over a slot-paged cache pool.
 
@@ -720,7 +738,10 @@ class SlotDecodeSession(object):
         with is bit-neutral). The window pads up the rung ladder, every
         copy lands before any repoint, and each slot's FINAL row rides
         the same executable — the per-pair copy_prog's atomicity,
-        without its per-pair dispatch tax.
+        without its per-pair dispatch tax. The program reads every
+        source page before it writes any destination
+        (``_check_cow_window`` holds the window to what makes that equal
+        to copying in order; a window it refuses is a failed dispatch).
 
         A FAILED dispatch may or may not have committed device-side, so
         the host restores every shared source in its slot's row
@@ -754,6 +775,7 @@ class SlotDecodeSession(object):
         copies = [(s, src, dst) for s, src, dst in window
                   if not (src == 0 and dst == 0)]
         try:
+            _check_cow_window(copies)
             self._run(self._cow_prog(rung), feed, [])
         except BaseException:
             for slot, src_pg, dst_pg in copies:

@@ -37,6 +37,23 @@ def _fresh_programs():
     yield
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _fresh_executables():
+    """A test module starts with none of another module's executables: the
+    process-wide registry is content-addressed, so a program that an
+    earlier file of this worker compiled would otherwise be served from
+    it, traced by nobody, and a module that counts what ITS run traced
+    (the set-up ledger's rehearsal) would pass or fail by the order the
+    files came in."""
+    import paddle_tpu.executor as executor_mod
+    from paddle_tpu.core import exec_cache
+    from paddle_tpu.observability import explain
+
+    executor_mod._shared_executables.clear()
+    exec_cache.reset_stats()
+    explain.reset()
+
+
 _NATIVE_BUILD_RESULT = {}
 
 

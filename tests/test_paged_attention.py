@@ -403,6 +403,171 @@ def test_prefill_and_copy_page_ops_write_whole_token_rows():
     np.testing.assert_array_equal(np.asarray(out["VOut"])[1], v2[2])
 
 
+# copy-on-write windows ``[(slot, src, dst)]`` over 4 slots of 2 pages (9
+# pages: page 0 the trash page); ``(slot, 0, 0)`` is a rebind or padding
+_COW_WINDOWS = {
+    "one_pair": [(1, 3, 7)],
+    "several_real_pairs": [(0, 1, 7), (2, 5, 8), (3, 6, 2), (1, 4, 3)],
+    "padded_with_trash_self_copies":
+        [(2, 5, 8), (0, 1, 7)] + [(2, 0, 0)] * 6,
+    "a_slot_repeated_with_its_final_row":
+        [(1, 3, 7), (1, 4, 8), (3, 0, 0), (1, 0, 0)],
+    # a source shared by three pairs (N sharers, N - 1 copies) and a
+    # source that is the trash page's neighbour in the feed's padding
+    "a_source_copied_three_times":
+        [(0, 5, 2), (1, 5, 7), (3, 5, 8), (2, 0, 0)],
+}
+
+
+def _cow_window_case(case):
+    """Pools and a table of 4 slots, the window's feeds, and what a plain
+    loop over its pairs leaves: every copy, then every slot's final row."""
+    H, dh, ps, npp, S = 2, 4, 4, 2, 4
+    rng = np.random.RandomState(11)
+    kp, vp, table = _pools(rng, S, H, dh, ps, npp, [8] * S)
+    window = _COW_WINDOWS[case]
+    final_row = {slot: rng.randint(1, kp.shape[0], npp)
+                 for slot, _src, _dst in window}
+    feed = {
+        "src_pages": np.asarray([w[1] for w in window], "int64"),
+        "dst_pages": np.asarray([w[2] for w in window], "int64"),
+        "slot_idxs": np.asarray([w[0] for w in window], "int64"),
+        "page_rows": np.stack([final_row[w[0]] for w in window]
+                              ).astype("int64"),
+    }
+    want_k, want_v, want_table = kp.copy(), vp.copy(), table.copy()
+    for slot, src, dst in window:
+        want_k[dst] = want_k[src]
+        want_v[dst] = want_v[src]
+    for slot, row in final_row.items():
+        want_table[slot] = row
+    return (H, dh, ps, npp, S), (kp, vp, table), feed, \
+        (want_k, want_v, want_table)
+
+
+@pytest.mark.parametrize("case", sorted(_COW_WINDOWS))
+def test_copy_page_op_copies_a_window_in_order(case):
+    """``paged_copy_page`` with ``Src`` / ``Dst`` of a whole window (no
+    destination another pair's source or destination) equals a plain
+    NumPy loop over the pairs, bit for bit; one pair is the case
+    ``n = 1``."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops
+
+    _geom, (kp, vp, _table), feed, (want_k, want_v, _t) = \
+        _cow_window_case(case)
+    out = attention_ops._lower_paged_copy_page(None, {
+        "KPool": [jnp.asarray(kp)], "VPool": [jnp.asarray(vp)],
+        "Src": [jnp.asarray(feed["src_pages"])],
+        "Dst": [jnp.asarray(feed["dst_pages"])]}, {})
+    np.testing.assert_array_equal(np.asarray(out["KOut"]), want_k)
+    np.testing.assert_array_equal(np.asarray(out["VOut"]), want_v)
+
+
+@pytest.mark.parametrize("case", sorted(_COW_WINDOWS))
+def test_cow_batch_program_equals_a_loop_over_its_pairs(case):
+    """``build_cow_batch_prog``'s program, run through the ``Executor`` on
+    a scope that holds the pools and the table: every layer's pools and
+    the table equal the plain loop's, bit for bit (all copies, then each
+    slot's final row, a repeated slot's written more than once)."""
+    import jax.numpy as jnp
+    from paddle_tpu.models import transformer
+
+    (H, dh, ps, npp, S), (kp, vp, table), feed, want = \
+        _cow_window_case(case)
+    L = 2
+    prog = transformer.build_cow_batch_prog(
+        S, npp * ps, L, H, H * dh, ps, kp.shape[0],
+        len(feed["src_pages"]))
+    scope = fluid.Scope()
+    for i in range(L):
+        scope.var("pgd_kpool_%d" % i).set(jnp.asarray(kp + i))
+        scope.var("pgd_vpool_%d" % i).set(jnp.asarray(vp - i))
+    scope.var("pgd_table").set(jnp.asarray(table))
+    fluid.Executor(fluid.CPUPlace()).run(prog, feed=feed, fetch_list=[],
+                                         scope=scope)
+    want_k, want_v, want_table = want
+    for i in range(L):
+        # adding a constant commutes with copying whole pages
+        np.testing.assert_array_equal(
+            np.asarray(scope.find_var("pgd_kpool_%d" % i).get_tensor()),
+            want_k + i)
+        np.testing.assert_array_equal(
+            np.asarray(scope.find_var("pgd_vpool_%d" % i).get_tensor()),
+            want_v - i)
+    np.testing.assert_array_equal(
+        np.asarray(scope.find_var("pgd_table").get_tensor()), want_table)
+
+
+def test_cow_batch_program_holds_the_same_operators_at_every_rung():
+    """The program takes its window whole: a copy a layer and the row
+    write, whatever the rung (it held 22 operators a pair unrolled)."""
+    from paddle_tpu.models import transformer
+
+    def ops(pairs):
+        prog = transformer.build_cow_batch_prog(
+            256, 256, 6, 8, 512, 16, 4097, pairs)
+        return [op.type for op in prog.global_block().ops]
+
+    assert ops(64) == ops(512) == ["paged_copy_page"] * 6 + ["scatter"]
+    assert len(ops(64)) < 32
+    assert ops(1) == ["paged_copy_page"] * 6 + ["dynamic_update_slice"]
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_copy_page_op_equals_the_loop_on_any_window_a_session_plans(seed):
+    """The op gathers every source and then scatters: drawn windows that
+    keep the session's rule (destinations fresh pages: distinct, none a
+    source, none the trash page; sources shared freely; any number of
+    trash self-copies anywhere) equal the loop over their pairs."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops
+    from paddle_tpu.serving.generation import _check_cow_window
+
+    rng = np.random.RandomState(100 + seed)
+    P, ps, width = 40, 4, 8
+    kp = rng.randn(P, ps, width).astype("float32")
+    vp = rng.randn(P, ps, width).astype("float32")
+    for _ in range(8):
+        pages = rng.permutation(np.arange(1, P))
+        n_real = rng.randint(1, 12)
+        dsts = pages[:n_real]
+        srcs = rng.choice(pages[n_real:], n_real)   # repeats allowed
+        pairs = list(zip(srcs, dsts)) + [(0, 0)] * rng.randint(0, 6)
+        pairs = [pairs[i] for i in rng.permutation(len(pairs))]
+        _check_cow_window([(0, s, d) for s, d in pairs if (s, d) != (0, 0)])
+        want_k, want_v = kp.copy(), vp.copy()
+        for s, d in pairs:
+            want_k[d] = want_k[s]
+            want_v[d] = want_v[s]
+        out = attention_ops._lower_paged_copy_page(None, {
+            "KPool": [jnp.asarray(kp)], "VPool": [jnp.asarray(vp)],
+            "Src": [jnp.asarray([s for s, _d in pairs])],
+            "Dst": [jnp.asarray([d for _s, d in pairs])]}, {})
+        np.testing.assert_array_equal(np.asarray(out["KOut"]), want_k)
+        np.testing.assert_array_equal(np.asarray(out["VOut"]), want_v)
+        kp, vp = want_k, want_v
+
+
+@pytest.mark.parametrize("copies", [
+    [(0, 1, 7), (2, 7, 8)],      # a chain: a destination is a later source
+    [(0, 7, 3), (2, 1, 7)],      # ... or an earlier one
+    [(0, 1, 7), (2, 3, 7)],      # a destination written twice
+    [(0, 1, 0)],                 # the trash page as a real destination
+], ids=["chain", "source_overwritten", "repeated_destination",
+        "trash_destination"])
+def test_a_window_the_copy_program_cannot_take_in_one_pass_is_refused(
+        copies):
+    """What makes gather-then-scatter equal to copying in order is held
+    where windows are dispatched: a window that breaks it raises before
+    the program runs (the session treats that as a failed dispatch)."""
+    from paddle_tpu.serving.generation import _check_cow_window
+
+    with pytest.raises(RuntimeError, match="copy-on-write window"):
+        _check_cow_window(copies)
+    _check_cow_window([(0, 1, 7), (2, 1, 8), (3, 4, 5)])
+
+
 def test_grid_accounting_scales_with_resident_pages():
     """The kernel's modeled HBM traffic follows pages actually
     RESIDENT, not S x max_length: half the resident tokens ~ half the

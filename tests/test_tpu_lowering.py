@@ -523,6 +523,56 @@ def test_batched_table_repoint_writes_the_table_in_place(v5e):
         memory.temp_size_in_bytes
 
 
+def test_copy_on_write_window_copies_no_pool(v5e):
+    """The lowest rung of ``SlotDecodeSession``'s copy-on-write ladder at
+    the served shapes (perfbench transformer_base: a window of 64 pairs
+    over 256 slots' tables of 16 pages, 12 pools ``f32[4097,16,512]`` of
+    134 MB): the compiled program is a gather of the window's 64 source
+    pages and a scatter onto its destinations a pool, and one row scatter
+    into the table, all in place. It holds no ``copy``, ``transpose`` or
+    gather of a pool's size, aliases all twelve pools and the table onto
+    their inputs, and its temporaries (the window's pages, 2 MB a pool)
+    stay under one pool. The sibling of
+    ``test_paged_step_copies_no_pool`` and of
+    ``test_batched_admission_copies_no_cross_pool``."""
+    import re
+
+    import _described_compile as dc
+    from paddle_tpu.models import transformer
+
+    pairs, S, T, H, dh, L, ps = 64, 256, 256, 8, 64, 6, 16
+    npp = pa.pages_for(T, ps)
+    P = 1 + S * npp
+    prog = transformer.build_cow_batch_prog(S, T, L, H, H * dh, ps, P, pairs)
+    state = {name: (var.shape, var.dtype)
+             for name, var in prog.global_block().vars.items()
+             if var.persistable}
+    pools = ["pgd_%spool_%d" % (kv, i) for kv in "kv" for i in range(L)]
+    assert set(state) == set(pools + ["pgd_table"])
+    assert all(tuple(state[n][0]) == (P, ps, H * dh) for n in pools)
+    compiled = dc.compile_program(prog, v5e[0], state, {
+        "src_pages": ((pairs,), "int64"), "dst_pages": ((pairs,), "int64"),
+        "slot_idxs": ((pairs,), "int64"),
+        "page_rows": ((pairs, npp), "int64")}, [])
+    text = compiled.as_text()
+    # the window whole: no loop over its pairs and no op a pair
+    assert "while(" not in text and "tpu_custom_call" not in text
+    assert len(re.findall(r"\[%d,%d,%d\]\S* gather\(" % (pairs, ps, H * dh),
+                          text)) == 2 * L
+    pool_elems = P * ps * H * dh
+    _assert_moves_no_pool(text, pool_elems)
+    memory = compiled.memory_analysis()
+    mutable_bytes = 4 * (2 * L * pool_elems + S * npp)
+    assert memory.alias_size_in_bytes == mutable_bytes, (
+        memory.alias_size_in_bytes, mutable_bytes)
+    head = text.split("entry_computation_layout", 1)[0]
+    assert head.count("may-alias") + head.count("must-alias") == len(state)
+    print("copy-on-write window, rung %d: temp_size_in_bytes %d"
+          % (pairs, memory.temp_size_in_bytes))
+    assert memory.temp_size_in_bytes < 4 * pool_elems, \
+        memory.temp_size_in_bytes
+
+
 # the decode step's cross attention at the served shapes (perfbench
 # transformer_base: 256 slots and groups, 8 heads of 64, 256 source
 # positions), the tree-verify program's N nodes, transformer_big's 16
