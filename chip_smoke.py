@@ -45,8 +45,8 @@ import time
 
 import numpy as np
 
-# Transformer base (Vaswani et al. 2017, table 3 "base"); the widths
-# bench.py names for the chip. Nothing is cut for the one-chip run.
+# Transformer base (Vaswani et al. 2017, table 3 "base"), at its
+# published widths. Nothing is cut for the one-chip run.
 FULL = dict(n_layer=6, n_head=8, d_model=512, d_inner=2048, vocab=32000,
             seq=256, batch=64, train_steps=8,
             # serving pool geometry: page size 16 (one bf16 sublane

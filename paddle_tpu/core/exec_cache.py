@@ -14,7 +14,7 @@ full XLA compile from scratch. This module kills that tax in two layers:
    cache key, so it never derives from a temp name, a pid or a time —
    a path that moves never hits. Nothing here ever switches it off.
    ``enable_xla_cache()`` turns it on (``chip_smoke.py`` and
-   ``bench.py`` do at start; ``FLAGS_exec_cache_dir`` implies it).
+   ``perfbench/`` do at start; ``FLAGS_exec_cache_dir`` implies it).
 2. **AOT executable images** (``<FLAGS_exec_cache_dir>/aot``; empty flag
    = layer off, zero overhead): serialized ``lower()``/``compile()``
    output of the whole step function, keyed by
@@ -35,8 +35,8 @@ process (dirs are created 0o700; never point the flag at a
 world-writable path).
 
 Stats: counters below are exported through ``profiler.exec_cache_stats()``
-and feed ``bench.py``'s ``compile_seconds_cold``/``compile_seconds_warm``
-fields. Backend compile time is observed via ``jax.monitoring`` events, so
+(``compile_seconds_cold``/``compile_seconds_warm`` among them) and read by
+``perfbench/``. Backend compile time is observed via ``jax.monitoring`` events, so
 compiles that happen outside this module (stray helper jits) are counted
 too — the numbers are the process's whole compile tax, not just the
 executor's share. ``trace_seconds`` / ``lower_seconds`` come from the same
@@ -230,7 +230,7 @@ def record_gather_plan(rebuilt):
 
 def stats():
     """Snapshot of the cache counters. ``fresh_compiles`` is the number of
-    XLA compiles no cache layer could serve — the warm-start smoke stage
+    XLA compiles no cache layer could serve — tests/test_exec_cache.py
     asserts it is zero in a second process sharing the cache.
     ``enabled``/``cache_dir`` describe the AOT image layer (the flag);
     ``xla_cache_dir`` is where JAX's persistent cache is live, or None.

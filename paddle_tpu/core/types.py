@@ -18,8 +18,8 @@ class NoAcceleratorError(RuntimeError):
 def require_accelerator(count=1):
     """The accelerator or fail: the first ``count`` non-CPU devices this
     process owns, as JAX reports them. The entry points that MEASURE
-    (``chip_smoke.py``, ``bench.py``'s chip worker, ``fluid_benchmark.py
-    --device TPU``, ``tools/kernel_bench.py``) call this first, so a
+    (``chip_smoke.py``, ``perfbench/run.py`` on a chip,
+    ``tools/kernel_bench.py``) call this first, so a
     host with no chip is an error that names the missing device — never
     a CPU number under a device metric's name."""
     import jax

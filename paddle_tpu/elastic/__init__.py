@@ -22,8 +22,8 @@ plans) were missing a host for:
   to a fresh restore at that world size.
 
 ``docs/RESILIENCE.md`` ("Elastic fleet") has the generation protocol,
-the reshard rules table and the failure matrix; ``tools/run_ci.sh
-elastic`` proves the whole loop under real SIGKILL churn.
+the reshard rules table and the failure matrix; ``tests/test_elastic.py``
+holds the whole loop through a join and an eviction.
 """
 
 from paddle_tpu.elastic import coordinator  # noqa: F401

@@ -26,8 +26,8 @@ membership-generation change as a first-class training event. Every
 Because restore re-seats both state and the RNG stream, the loss
 trajectory after a reshape is *bit-identical* to a fresh process
 restored from the same checkpoint at that world size — the contract
-``tools/elastic_smoke.py`` (CI ``elastic`` stage) asserts under real
-SIGKILL churn.
+``tests/test_elastic.py`` asserts through a join and an eviction
+(``test_elastic_session_reshapes_with_bit_identical_trajectory``).
 
 A worker that was evicted (it stalled past its lease; heartbeats answer
 ``unknown_worker``) re-registers as a *new* member and rejoins at the

@@ -18,20 +18,6 @@ _DEFS = {
     "check_nan_inf": (False, bool),
     # per-op sync + memory print (executor.cc FLAGS_benchmark)
     "benchmark": (False, bool),
-    # eager GC threshold, GB (executor.cc FLAGS_eager_delete_tensor_gb);
-    # device memory is XLA's on TPU — kept for config-surface parity.
-    "eager_delete_tensor_gb": (-1.0, float),
-    # deterministic reductions (build_strategy.h FLAGS_cpu_deterministic)
-    "cpu_deterministic": (False, bool),
-    # poison freshly allocated host buffers (malloc.cc FLAGS_init_allocated_mem)
-    "init_allocated_mem": (False, bool),
-    # fraction of device memory to use (gpu_info.cc:22) — advisory on TPU
-    # (maps to XLA_PYTHON_CLIENT_MEM_FRACTION at process start).
-    "fraction_of_gpu_memory_to_use": (0.92, float),
-    # reader queue soak-test mode (FLAGS_reader_queue_speed_test_mode)
-    "reader_queue_speed_test_mode": (False, bool),
-    # rpc knobs kept for config parity (rpc_deadline etc.)
-    "rpc_deadline": (180000, int),
     # forced rematerialization for all grad ops (memory_optimize's lever)
     "remat_gradients": (False, bool),
     # route dynamic_lstm through the fused Pallas recurrence kernel
@@ -61,8 +47,7 @@ _DEFS = {
     # copies ZERO KV bytes; "reference" is the in-tree copy-reorder
     # oracle (every surviving hypothesis physically copies its parent's
     # resident pages, the pre-paged-attention baseline) — bit-identical
-    # tokens, O(T) bytes per reorder, the A/B bench.py's beam_speedup
-    # gates. The oracle needs ~beam_width * pages_per_slot free-page
+    # tokens, O(T) bytes per reorder. The oracle needs ~beam_width * pages_per_slot free-page
     # headroom for its transient copies; size num_pages accordingly.
     "beam_reorder": ("rebind", str),
     # backward pass of the flash kernel: "pallas" (FlashAttention-2-style

@@ -1,6 +1,6 @@
 """Ragged paged-attention decode: block-paged KV pool + Pallas kernel.
 
-The serving decode residual (ROADMAP item 3, PAPERS.md "Ragged Paged
+The serving decode step's attention (PAPERS.md "Ragged Paged
 Attention", arxiv 2604.15464): ``SlotDecodeSession``'s dense slot pool
 attends over all ``max_length`` positions for every slot regardless of
 how many tokens a slot actually holds, so decode FLOPs/HBM traffic

@@ -60,8 +60,7 @@ The training plane adds phase-level attribution:
   an input/host/compute/bandwidth-bound verdict per step — plus an
   online median+MAD regression detector that names the guilty phase.
   Ring + ``<metrics_path>.stepprof.jsonl``; ``tools/step_breakdown.py
-  --steps`` is the offline view, ``tools/perf_ledger.py`` the
-  append-only trajectory.
+  --steps`` is the offline view.
 
 The serving plane adds request-scoped attribution:
 
@@ -94,8 +93,8 @@ from paddle_tpu.observability.metrics_registry import REGISTRY  # noqa: F401
 
 def _exec_cache_collector():
     """Scrape-time view of the executable-cache counters: the single
-    source of truth stays core/exec_cache.py (bench.py and the warm-start
-    smoke read it directly); the registry mirrors it so one Prometheus
+    source of truth stays core/exec_cache.py (perfbench/ and the tests
+    read it directly); the registry mirrors it so one Prometheus
     scrape carries compile-tax data without double bookkeeping."""
     from paddle_tpu.core import exec_cache
 

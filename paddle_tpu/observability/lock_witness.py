@@ -368,7 +368,7 @@ def held_by_thread():
 
 
 def registered_locks():
-    """name -> construction count (the lock census a smoke can assert
+    """name -> construction count (the lock census a test can assert
     coverage against)."""
     with _wlock:
         return dict(_registered)
@@ -376,8 +376,8 @@ def registered_locks():
 
 def report():
     """The witness verdict: edges, cycles, dispatch holds. What the
-    witness-armed frontend smoke asserts on (zero cycles, zero long
-    holds)."""
+    witness-armed case of tests/test_frontend.py asserts on (zero
+    cycles, zero long holds)."""
     if not _wlock.acquire(timeout=_WLOCK_TIMEOUT):
         return {"edges": {}, "cycles": [], "long_holds": [],
                 "registered": {}, "degraded": True}

@@ -23,7 +23,7 @@ This module does the same for *memory* — the resource every roadmap item
   it occurs, and the top-K live tensors there. Executors register the
   plan per compiled executable, so predicted-vs-measured peak is a
   first-class report (``profiler.memory_stats()``,
-  ``tools/step_breakdown.py --memory``, bench.py artifacts).
+  ``tools/step_breakdown.py --memory``).
 
 * **OOM forensics** — :func:`enrich_and_raise` upgrades a
   ``RESOURCE_EXHAUSTED``-style failure into diagnostic rule **M001**

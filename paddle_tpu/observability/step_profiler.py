@@ -78,7 +78,7 @@ RING_CAP = 2048
 DISPATCH_RING_CAP = 16384  # a benchmark run makes ~10 000 dispatches
 
 # phase vocabulary — the record's "phases" dict only carries nonzero
-# entries, but consumers (step_breakdown, perf_ledger) treat this tuple
+# entries, but the consumer (tools/step_breakdown.py) treats this tuple
 # as the full axis
 PHASES = ("input_wait", "feed", "compile", "dispatch", "device", "fetch",
           "host")
@@ -626,7 +626,7 @@ def inflight():
 
 def write_stepprof_jsonl(path, mode="w"):
     """One JSON line per profiled step — the file
-    tools/step_breakdown.py --steps and tools/perf_ledger.py consume.
+    tools/step_breakdown.py --steps consumes; returns the count written.
     telemetry.flush() writes it as ``<metrics_path>.stepprof.jsonl``."""
     import json
 

@@ -51,8 +51,8 @@ _flops = {}              # fingerprint -> flops per step
 _callbacks = []
 
 # THE peak table — the only one: published per-chip peaks keyed by the
-# ``device_kind`` JAX reports (lowercased). bench.py, peak_flops() below,
-# the step profiler's roofline join and tools/hlo_cost_model.py all read
+# ``device_kind`` JAX reports (lowercased). peak_flops() below, the
+# step profiler's roofline join and tools/hlo_cost_model.py all read
 # it. Source: Google Cloud TPU documentation, system-architecture pages
 # ("TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM; "TPU v4": 275 TFLOP/s bf16;
 # "TPU v6e": 918 TFLOP/s bf16). A device that is not here HAS NO PEAK:

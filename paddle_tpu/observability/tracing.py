@@ -446,7 +446,7 @@ def write_traces_jsonl(path):
 def perfetto_events(rec, row=0, pid=1):
     """One completed record -> Chrome/Perfetto ``traceEvents`` (complete
     'X' events, microsecond timestamps; ``row`` is the track the
-    request renders on). Shared by tools/trace_view.py and the smoke's
+    request renders on). Shared by tools/trace_view.py and the tests'
     validity check."""
     events = [{
         "name": "trace %s" % rec["trace_id"], "ph": "M",

@@ -223,8 +223,8 @@ class ParallelExecutor(object):
             if fsdp is not None or tp is not None:
                 raise NotImplementedError(
                     "pipeline_stages does not yet compose with a "
-                    "fsdp/tp planning mesh (pipe-axis composition is an "
-                    "open ROADMAP item); drop fsdp=/tp= or the pipeline")
+                    "fsdp/tp planning mesh (pipe-axis composition is "
+                    "ROADMAP R5); drop fsdp=/tp= or the pipeline")
             self.mesh = build_mesh(
                 num_devices=n, data=n // pipeline_stages,
                 pipe=pipeline_stages, devices=pool)

@@ -44,7 +44,7 @@ the eviction/rejoin path the elastic runtime recovers through) — and
 the serving sites: ``serve.dispatch`` (the BatchingServer batch
 dispatch AND the decode session's step dispatch, which passes
 ``step=steps_done`` so ``kill@site=serve.dispatch,step=N`` SIGKILLs a
-decoding process deterministically — the servechaos CI leg),
+decoding process deterministically — the tests' SIGKILL leg),
 ``serve.admit`` (inside a slot admission, after slots/pages are claimed
 and before the dispatch — a fault here must roll the whole group back
 and, under retry, re-admit bit-identically), ``pool.acquire`` (the KV

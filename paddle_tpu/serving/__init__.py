@@ -24,10 +24,10 @@ bounded set of warm executables. This package is that layer:
   encoder state into a free slot mid-flight, and ONE fixed-shape step
   executable advances every active sequence per token — the
   ragged-paged-attention serving shape, sized to this repo.
-* ``loadgen`` — the deterministic load generator behind
-  ``tools/serve_smoke.py`` (CI ``serve`` stage) and bench.py's serving
-  leg, so the gated numbers and the smoke-tested behavior come from
-  one code path.
+* ``loadgen`` — the deterministic demo model and request stream the
+  serving tests share (``tests/test_serving.py`` and three more);
+  timings come from ``perfbench/``, which has a load generator of its
+  own.
 * ``snapshot.DecodeSnapshotManager`` — preemption-safe decode:
   atomic, digest-verified snapshot/restore of a live
   ``SlotDecodeSession`` (live KV pages gathered through the page

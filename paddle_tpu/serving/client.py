@@ -539,7 +539,7 @@ class ServingClient(JsonLineClient):
         (reassembled from the incremental chunks, so the streaming
         framing itself is covered by every parity assertion).
         ``on_event`` (optional) sees every raw stream event before it
-        is folded in — the hook the smoke/bench use to time the first
+        is folded in — the hook a caller uses to time the first
         token without re-implementing the reassembly."""
         rows = fill = None
         for ev in self.generate(src, src_len=src_len, n=n,

@@ -27,8 +27,8 @@ reject as retriable by TYPE — no message sniffing — and backs off by
 ``retry_after_s``.
 
 ``docs/RESILIENCE.md`` "Serving resilience" documents the full
-failure matrix; ``tools/serve_chaos_smoke.py`` (CI ``servechaos``
-stage) proves the brownout -> healthy round trip under a real flood.
+failure matrix; ``tests/test_serving_resilience.py`` holds the
+brownout -> healthy round trip under a flood of requests.
 """
 
 from paddle_tpu.observability.metrics_registry import REGISTRY as _REGISTRY

@@ -40,7 +40,7 @@ stream generator's ``GeneratorExit`` (a failed socket write) — each
 routing to ``SlotDecodeSession.cancel`` / ``drop_pending`` on the decode
 worker thread, which returns the slot and drops the page references;
 ``pool_conserved`` (free + unique-allocated == P - 1) holds afterwards,
-asserted by the tests and the CI ``net`` stage's kill-mid-stream leg.
+asserted by the tests' kill-mid-stream legs (``tests/test_frontend.py``).
 
 Who reads a cancel: ONE watcher thread (``_ConnWatcher``) holds a
 selector over the sockets of the connections that have a stream in

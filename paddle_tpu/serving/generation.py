@@ -1738,8 +1738,8 @@ class SlotDecodeSession(object):
         try:
             if _chaos.ENABLED:
                 # the decode-side serving dispatch site: kill@step=N
-                # SIGKILLs entering the Nth step dispatch (the
-                # servechaos CI leg), io/compile faults exercise the
+                # SIGKILLs entering the Nth step dispatch (the SIGKILL
+                # leg of the tests), io/compile faults exercise the
                 # classified-retry shell the executor dispatch wears
                 _chaos.fault("serve.dispatch", step=self.steps_done)
             if self._beam_width > 1:

@@ -1,11 +1,9 @@
-"""Deterministic serving load generator.
-
-One code path produces both the CI smoke's assertions and the bench
-capture's numbers (``tools/serve_smoke.py`` — the ``serve`` stage — and
-bench.py's serving leg), so the budgets in ``benchmark/budgets.json``
-gate exactly the behavior the smoke proves: a warm process replaying a
-MIXED-shape request stream with zero fresh compiles and a p99 inside
-budget.
+"""The deterministic demo model and request stream the serving tests
+share (``tests/test_serving.py``, ``test_frontend.py``, ``test_router.py``,
+``test_serving_resilience.py``): a tiny softmax MLP trained and saved per
+seed, a MIXED batch-size request list, and a closed-loop replay of it
+against a ``BatchingServer``. Timings come from ``perfbench/`` on the
+chip, which has a load generator of its own.
 """
 
 import threading
@@ -14,7 +12,6 @@ import time
 import numpy as np
 
 __all__ = ["build_demo_model", "demo_requests", "replay",
-           "serving_capture", "wire_capture",
            "DEMO_FEATURES", "DEMO_CLASSES"]
 
 DEMO_FEATURES = 12
@@ -25,10 +22,9 @@ DEMO_BATCH_MIX = (1, 2, 3, 5, 7, 8, 4, 6)
 
 
 def build_demo_model(dirname, seed=3, train_steps=30):
-    """Train + save the tiny softmax MLP the serving smoke/bench serve.
+    """Train + save the tiny softmax MLP the serving tests serve.
     Deterministic per seed (fixed program seeds, fresh name counters, a
-    seeded data stream), so the cold and warm smoke processes agree on
-    every cache key."""
+    seeded data stream), so two builds agree on every cache key."""
     import paddle_tpu as fluid
     from paddle_tpu import unique_name
     from paddle_tpu.core.scope import Scope
@@ -76,50 +72,22 @@ def demo_requests(n, seed=17):
     return out
 
 
-def replay(server, requests, concurrency=4, deadline_s=None,
-           latencies=None):
+def replay(server, requests, concurrency=4):
     """Closed-loop replay: ``concurrency`` client threads round-robin
     the request list, each running its request synchronously (what a
     fleet of synchronous callers looks like, and what makes the
     dispatcher's coalescing window matter). Returns
-    ``(wall_seconds, ok_count, error_list)``.
-
-    SOCKET mode — the one deterministic wire load generator CI smoke
-    and bench share: pass a zero-arg CALLABLE for ``server`` and each
-    client thread builds (and closes) its OWN target from it, e.g.
-    ``lambda: ServingClient(frontend.address)`` — one connection per
-    synchronous caller, the closed-loop shape a real fleet presents.
-    Both ``BatchingServer`` and ``ServingClient`` expose the shared
-    ``run(inputs, deadline_s=...)`` entry this drives, so the same
-    replay exercises the in-process server or the wire.
-
-    ``latencies``: optional list; per-request wall seconds (successful
-    requests only) are appended — client-side numbers for the wire SLO
-    gates (``latency_ms_p99`` over real sockets)."""
+    ``(wall_seconds, ok_count, error_list)``."""
     errors = []
     ok = [0] * concurrency
-    per_req = [[] for _ in range(concurrency)]
 
     def client(cid):
-        try:
-            # factory failures (refused connection, restarted frontend)
-            # must land in the error list, not die with the thread
-            target = server() if callable(server) else server
-        except Exception as exc:  # noqa: BLE001 - collected
-            errors.append(exc)
-            return
-        try:
-            for req in requests[cid::concurrency]:
-                try:
-                    t0 = time.perf_counter()
-                    target.run(req, deadline_s=deadline_s)
-                    per_req[cid].append(time.perf_counter() - t0)
-                    ok[cid] += 1
-                except Exception as exc:  # noqa: BLE001 - collected
-                    errors.append(exc)
-        finally:
-            if callable(server):
-                target.close()
+        for req in requests[cid::concurrency]:
+            try:
+                server.run(req)
+                ok[cid] += 1
+            except Exception as exc:  # noqa: BLE001 - collected
+                errors.append(exc)
 
     threads = [threading.Thread(target=client, args=(i,),
                                 name="paddle-tpu-loadgen-%d" % i)
@@ -129,99 +97,4 @@ def replay(server, requests, concurrency=4, deadline_s=None,
         t.start()
     for t in threads:
         t.join()
-    wall = time.perf_counter() - t0
-    if latencies is not None:
-        for chunk in per_req:
-            latencies.extend(chunk)
-    return wall, sum(ok), errors
-
-
-def serving_capture(server, n_ok, wall_s):
-    """The bench/smoke record for the serving leg: requests/sec plus the
-    SLO numbers ``tools/perf_diff.py`` gates (latency_ms_p50/p99,
-    batch_occupancy)."""
-    st = server.stats()
-    lat = st["latency_ms"]
-
-    def r(v, nd=3):
-        return round(v, nd) if v is not None else None
-
-    return {
-        "metric": "serving_throughput",
-        "value": round(n_ok / wall_s, 2) if wall_s else None,
-        "unit": "requests/sec",
-        "vs_baseline": None,
-        "latency_ms_p50": r(lat["p50_ms"]),
-        "latency_ms_p99": r(lat["p99_ms"]),
-        "batch_occupancy": r(st["mean_occupancy"], 4),
-        "batches": st["batches"],
-        "batch_buckets": st["batch_buckets"],
-        "requests_ok": n_ok,
-        "requests_rejected": st["queue_full"] + st["deadline"],
-    }
-
-
-def wire_capture(n_ok, wall_s, latencies, ttft_s=None, traces=None):
-    """The bench/smoke record for the NETWORK front-end leg:
-    wire-level requests/sec plus CLIENT-side latency percentiles (the
-    replay's ``latencies`` out-param — what the user actually waited,
-    socket included) and the stream time-to-first-token
-    (``ttft_s``: one measurement or a list; the median lands as
-    ``ttft_ms``). ``tools/perf_diff.py`` gates all three against the
-    ``frontend`` budgets.
-
-    ``traces`` (optional): completed trace records
-    (``observability.tracing`` ring entries, one per streamed request)
-    — their derived stats land as ``ttft_breakdown``: the median split
-    of time-to-first-token into queue wait, prefill and the first
-    decode dispatch, the attribution a bare ttft_ms can't give."""
-    window = sorted(latencies or ())
-
-    def pct(p):
-        if not window:
-            return None
-        idx = min(len(window) - 1, int(round(p * (len(window) - 1))))
-        return round(window[idx] * 1000.0, 3)
-
-    if ttft_s is not None and not np.isscalar(ttft_s):
-        seq = sorted(float(t) for t in ttft_s)
-        ttft_s = seq[len(seq) // 2] if seq else None
-    rec = {
-        "metric": "frontend_throughput",
-        "value": round(n_ok / wall_s, 2) if wall_s else None,
-        "unit": "requests/sec",
-        "vs_baseline": None,
-        "latency_ms_p50": pct(0.50),
-        "latency_ms_p99": pct(0.99),
-        "ttft_ms": (round(float(ttft_s) * 1000.0, 3)
-                    if ttft_s is not None else None),
-        "requests_ok": n_ok,
-    }
-    traces = [t for t in (traces or ()) if t]
-    if traces:
-        def med(vals):
-            seq = sorted(v for v in vals if v is not None)
-            return seq[len(seq) // 2] if seq else 0.0
-
-        def first_dispatch_s(rec_t):
-            steps = [s for s in rec_t.get("spans", ())
-                     if s["name"] == "decode.step"
-                     and s["t1"] is not None]
-            if not steps:
-                return None
-            first = min(steps, key=lambda s: s["t0"])
-            return first["t1"] - first["t0"]
-
-        stats = [t.get("stats", {}) for t in traces]
-        rec["ttft_breakdown"] = {
-            "queue_ms": round(med([s.get("queue_s") for s in stats])
-                              * 1000.0, 3),
-            "prefill_ms": round(med([s.get("prefill_s")
-                                     for s in stats]) * 1000.0, 3),
-            "first_dispatch_ms": round(
-                med([first_dispatch_s(t) for t in traces])
-                * 1000.0, 3),
-        }
-        rec["span_coverage"] = round(
-            med([s.get("span_coverage") for s in stats]), 4)
-    return rec
+    return time.perf_counter() - t0, sum(ok), errors

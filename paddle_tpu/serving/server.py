@@ -28,8 +28,8 @@ Contract points:
 * **Observability.** Per-request latency (by outcome), queue depth,
   batch occupancy and reject counters land in
   ``observability.REGISTRY`` (docs/OBSERVABILITY.md has the rows), and
-  ``latency_percentiles()`` gives exact p50/p99 over a recent window —
-  what ``tools/serve_smoke.py`` and the perf gate consume.
+  ``latency_percentiles()`` gives exact p50/p99 over a recent window,
+  which ``stats()`` reports as ``latency_ms``.
 """
 
 import threading
@@ -750,8 +750,8 @@ class BatchingServer(object):
         return list(self._ladder)
 
     def latency_percentiles(self):
-        """Exact p50/p99 (ms) over the recent completed-request window —
-        the numbers tools/serve_smoke.py exports and perf_diff gates."""
+        """Exact p50/p99 (ms) over the recent completed-request window:
+        ``{"p50_ms", "p99_ms", "n"}``, both ``None`` while it is empty."""
         with self._stats_lock:
             window = list(self._latencies)
         if not window:

@@ -32,9 +32,9 @@ quarantine) with a decode-specific dialect:
 * **Bit-exact resumption.** Sampling PRNG keys are
   ``(seed, slot, position)`` — never a host counter — so a restored
   session's subsequent tokens are bit-identical to the uninterrupted
-  run's; ``tools/run_ci.sh servechaos`` SIGKILLs a decoding child and
-  proves the restored process's remaining token streams byte-for-byte,
-  with 0 fresh compiles (the warm exec cache serves every executable).
+  run's; ``tests/test_serving_resilience.py`` SIGKILLs a decoding child
+  and holds another process's restored token streams byte-for-byte, and
+  a restore inside one process at 0 fresh compiles.
 * **Graceful preemption.** ``install_signal_handlers`` wires SIGTERM/
   SIGINT exactly like ``TrainSession``: a signal landing mid-dispatch
   defers to the session's quiesce point (the in-flight dispatch
@@ -107,7 +107,7 @@ def _unaliased_host_copy(arr):
     ``jax.device_put`` ZERO-COPIES a 64-byte-aligned numpy buffer on
     CPU — and the decode dispatch DONATES its state inputs, so an
     aliased buffer would have XLA freeing memory numpy still owns
-    (heap corruption, found the hard way under the servechaos smoke).
+    (heap corruption, found the hard way under a SIGKILL drill).
     Staging in a misaligned buffer forces device_put to copy into
     XLA-owned memory on every dispatch. (The obvious alternative,
     jnp.array, traces one tiny convert computation per shape/dtype —

@@ -29,19 +29,19 @@ def _simple_program(lr=0.05, seed=3):
 
 def test_flags_env_parsing(monkeypatch):
     monkeypatch.setenv("FLAGS_check_nan_inf", "true")
-    monkeypatch.setenv("FLAGS_eager_delete_tensor_gb", "2.5")
-    monkeypatch.setenv("FLAGS_rpc_deadline", "1234")
+    monkeypatch.setenv("FLAGS_checkpoint_interval_secs", "2.5")
+    monkeypatch.setenv("FLAGS_checkpoint_max_to_keep", "1234")
     flags.refresh_from_env()
     try:
         assert flags.get("check_nan_inf") is True
-        assert flags.get("eager_delete_tensor_gb") == 2.5
-        assert flags.get("rpc_deadline") == 1234
+        assert flags.get("checkpoint_interval_secs") == 2.5
+        assert flags.get("checkpoint_max_to_keep") == 1234
         with pytest.raises(KeyError):
             flags.get("no_such_flag")
     finally:
         monkeypatch.delenv("FLAGS_check_nan_inf")
-        monkeypatch.delenv("FLAGS_eager_delete_tensor_gb")
-        monkeypatch.delenv("FLAGS_rpc_deadline")
+        monkeypatch.delenv("FLAGS_checkpoint_interval_secs")
+        monkeypatch.delenv("FLAGS_checkpoint_max_to_keep")
         flags.refresh_from_env()
     assert flags.get("check_nan_inf") is False
 

@@ -196,6 +196,12 @@ def test_rebind_matches_copy_oracle_and_moves_zero_pages(trained):
         np.testing.assert_array_equal(g["tokens"], r["tokens"])
         np.testing.assert_array_equal(g["scores"], r["scores"])
     assert copy_sess.pool_conserved and copy_sess.pages_in_use == 0
+    from paddle_tpu.observability import REGISTRY
+
+    text = REGISTRY.to_prometheus()
+    assert "paddle_tpu_serving_active_beams 0" in text
+    assert "paddle_tpu_serving_beam_reorder_bytes_total" in text
+    assert "paddle_tpu_serving_beam_cow_copies_total" in text
 
 
 def test_warm_beam_rerun_adds_zero_fresh_compiles(trained):

@@ -5,10 +5,10 @@ Satellite coverage from the compile-tax PR: every trace flag toggle
 recompiles, program mutation recompiles, structurally identical programs
 share one executable, a corrupted on-disk entry degrades to a fresh
 compile (asserted through the exec_cache stats counters), and
-run_async(...).result() matches run(...) bit-for-bit. The cross-PROCESS
-warm start is proven by tools/run_ci.sh `warm` (tools/warm_start_smoke.py);
-here the same disk layers are exercised in-process by purging the
-in-memory registries between runs.
+run_async(...).result() matches run(...) bit-for-bit. The disk layers are
+exercised in-process by purging the in-memory registries between runs,
+and across two PROCESSES by one case that runs a small child twice
+against one cache directory (a plain and a sharded executable).
 """
 
 import json
@@ -414,7 +414,7 @@ from paddle_tpu.core import exec_cache
 
 exec_cache.configure(sys.argv[1] or None)
 if not sys.argv[1]:
-    exec_cache.enable_xla_cache()  # what chip_smoke.py and bench.py do
+    exec_cache.enable_xla_cache()  # what chip_smoke.py and perfbench do
 jax.jit(lambda x: x * 2 + 1)(jnp.arange(8.0)).block_until_ready()
 print(json.dumps({
     "dir": jax.config.jax_compilation_cache_dir,
@@ -424,13 +424,17 @@ print(json.dumps({
 """
 
 
-def _placement_probe(tmp_path, aot_dir="", **env_overrides):
+def _placement_probe(tmp_path, aot_dir="", script=_PLACEMENT_PROBE,
+                     **env_overrides):
+    """Run ``script`` with one argument in a child started from an
+    unrelated cwd, the two cache variables as ``env_overrides`` say and
+    not as this process has them; its last line of output, parsed."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {k: v for k, v in os.environ.items()
            if k not in ("JAX_COMPILATION_CACHE_DIR", "FLAGS_exec_cache_dir")}
     env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo, **env_overrides)
     out = subprocess.run(
-        [sys.executable, "-c", _PLACEMENT_PROBE, aot_dir], env=env,
+        [sys.executable, "-c", script, aot_dir], env=env,
         cwd=str(tmp_path), capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
@@ -461,3 +465,59 @@ def test_xla_cache_defaults_to_one_fixed_path_in_the_checkout(tmp_path):
     assert first["enabled"] and second["enabled"]
     assert second["stats"]["persistent_hits"] >= 1
     assert second["stats"]["fresh_compiles"] == 0
+
+
+# -- two processes, one cache directory ---------------------------------------
+
+_WARM_START_CHILD = """
+import json, sys
+import numpy as np
+import paddle_tpu as fluid
+from paddle_tpu.core import exec_cache
+from paddle_tpu.observability import REGISTRY
+
+main, startup = fluid.Program(), fluid.Program()
+main.random_seed = startup.random_seed = 3
+with fluid.program_guard(main, startup):
+    x = fluid.layers.data("x", [8])
+    y = fluid.layers.data("y", [1])
+    hid = fluid.layers.fc(x, size=16, act="relu")
+    loss = fluid.layers.mean(
+        fluid.layers.square_error_cost(fluid.layers.fc(hid, size=1), y))
+    fluid.optimizer.SGD(0.1).minimize(loss)
+exe = fluid.Executor(fluid.CPUPlace())
+exe.run(startup)
+feed = {"x": np.arange(64, dtype="float32").reshape(8, 8) / 64.0,
+        "y": np.ones((8, 1), "float32")}
+if sys.argv[1] == "sharded":
+    pe = fluid.ParallelExecutor(loss_name=loss.name, main_program=main,
+                                use_tpu=False, fsdp=2, tp=2)
+    out = pe.run(fetch_list=[loss], feed=feed)
+else:
+    out = exe.run(main, feed=feed, fetch_list=[loss])
+scrape = [l for l in REGISTRY.to_prometheus().splitlines()
+          if l.startswith("paddle_tpu_fresh_compiles_total ")]
+print(json.dumps({"loss": float(np.ravel(np.asarray(out[0]))[0]),
+                  "scraped": float(scrape[0].split()[-1]),
+                  "stats": exec_cache.stats()}))
+"""
+
+
+@pytest.mark.parametrize("kind", ["plain", "sharded"])
+def test_a_second_process_runs_the_program_with_no_fresh_compile(
+        tmp_path, kind):
+    """Only the structural fingerprint connects the second process to the
+    first one's executables: it must load them (AOT images) and compile
+    nothing, by the cache's own count and by the metrics scrape, and
+    compute the same loss."""
+    cold, warm = [
+        _placement_probe(
+            tmp_path, kind, script=_WARM_START_CHILD,
+            XLA_FLAGS="--xla_force_host_platform_device_count=4",
+            FLAGS_exec_cache_dir=str(tmp_path / "cache"),
+            JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"))
+        for _ in range(2)]
+    assert cold["stats"]["enabled"] and cold["stats"]["fresh_compiles"] > 0
+    assert warm["stats"]["fresh_compiles"] == 0 == warm["scraped"], warm
+    assert warm["stats"]["aot_hits"] >= 1, warm["stats"]
+    assert warm["loss"] == cold["loss"]

@@ -38,6 +38,7 @@ from paddle_tpu.distributed.master import (
     serve_json_lines,
 )
 from paddle_tpu.executor import global_scope
+from paddle_tpu.observability import lock_witness
 from paddle_tpu.resilience import chaos
 from paddle_tpu.serving.client import (
     ServingClient,
@@ -397,7 +398,29 @@ def test_generate_streams_incrementally_and_matches_oracle(trained):
     assert np.array_equal(wire[0], want1[0])
 
 
-def test_generate_best_of_and_prefix_reuse_over_the_wire(trained):
+@pytest.fixture(params=[False, True], ids=["plain", "lock_witness"])
+def witness(request):
+    """Armed BEFORE the session and the frontend are built (the witness
+    wraps a framework lock at its construction), the lock witness must
+    see a real frontend serve its streams with no lock-order cycle and
+    no lock held across a device dispatch."""
+    if not request.param:
+        yield
+        return
+    lock_witness.enable()
+    lock_witness.reset()
+    try:
+        yield
+        report = lock_witness.report()
+    finally:
+        lock_witness.disable()
+        lock_witness.reset()
+    assert report["registered"] and not report["degraded"], report
+    assert not report["cycles"], report["cycles"]
+    assert not report["long_holds"], report["long_holds"]
+
+
+def test_generate_best_of_and_prefix_reuse_over_the_wire(trained, witness):
     src = trained["src"]
     pfx = [int(t) for t in src[0][:5]]
     sess, oracle = _paged(trained), _paged(trained)
@@ -1716,6 +1739,8 @@ def test_metrics_health_stats_endpoints(demo_predictor, trained):
         assert "paddle_tpu_frontend_active_connections" in text
         assert "paddle_tpu_frontend_bytes_sent_total" in text
         assert "paddle_tpu_frontend_ttft_seconds" in text
+        # the scrape over the wire carries the compile counter too
+        assert "paddle_tpu_fresh_compiles_total" in text
         health = cl.health()
         assert health == {"server": "healthy", "decode": "healthy"}
         stats = cl.stats()

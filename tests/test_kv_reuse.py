@@ -209,6 +209,9 @@ def test_prefix_fork_shares_pages_until_cow_and_conserves(trained):
         "warm fork/prefix wave paid fresh compiles"
     sess.clear_prefix_cache()
     assert sess.pages_in_use == 0 and sess.free_pages == sess._P - 1
+    from paddle_tpu.observability import REGISTRY
+
+    assert "paddle_tpu_serving_kv_pages_shared 0" in REGISTRY.to_prometheus()
 
 
 def test_admit_failure_rollback_repoints_before_freeing(trained):

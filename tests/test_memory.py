@@ -1,7 +1,7 @@
 """Memory-observability coverage: the live-buffer ledger balances, the
 memory plan brackets the measured watermark, M001 OOM forensics name the
-top holders in the black box, and the perf/memory regression sentry
-(tools/perf_diff.py) gates on injected regressions."""
+top holders in the black box. That every golden model reports both peaks
+is a case a model beside its regression run (tests/test_golden_cpp.py)."""
 
 import json
 import os
@@ -201,31 +201,6 @@ def test_memory_plan_within_2x_of_measured():
     assert ms["top_holders"]
 
 
-def test_every_golden_model_reports_memory():
-    """Acceptance: every golden model reports BOTH predicted and
-    measured peak HBM through profiler.memory_stats() on the CPU
-    backend, and the plan's curve is well-formed."""
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from golden_models import GOLDEN_MODELS, build_golden
-    from paddle_tpu.core.scope import Scope
-
-    for name in sorted(GOLDEN_MODELS):
-        telemetry.reset(flops=True)
-        memory.reset()
-        with fluid.scope_guard(Scope()):
-            program, _feed_names, fetch, feed, exe = build_golden(name)
-            telemetry.enable(True)
-            exe.run(program, feed=feed, fetch_list=[fetch.name])
-            ms = profiler.memory_stats()
-            telemetry.enable(False)
-        assert ms["measured_peak_bytes"], "%s: no measured peak" % name
-        assert ms["predicted_peak_bytes"], "%s: no predicted peak" % name
-        assert np.isfinite(ms["predicted_peak_bytes"]), name
-        plan = ms["predicted_plan"]
-        assert plan["peak_bytes"] == ms["predicted_peak_bytes"], name
-        assert plan["top_live"], "%s: plan names no live tensors" % name
-
-
 # ---------------------------------------------------------------------------
 # M001 OOM forensics
 # ---------------------------------------------------------------------------
@@ -312,103 +287,6 @@ def test_oom_not_enriched_when_not_oom():
         exe.run(main, feed=_feed(), fetch_list=[loss])
 
 
-# ---------------------------------------------------------------------------
-# perf/memory regression sentry
-# ---------------------------------------------------------------------------
-
-
-def _bench_artifact(path, fresh_compiles=4, p50=50.0, peak=1000000,
-                    predicted=2000000, value=10.0):
-    rec = {"models": {"resnet50": {
-        "value": value, "unit": "images/sec",
-        "step_ms": {"p50": p50, "p95": p50 * 4},
-        "compile_seconds_cold": 10.0,
-        "exec_cache": {"fresh_compiles": fresh_compiles},
-        "peak_hbm_bytes": peak, "predicted_peak_bytes": predicted,
-    }}}
-    with open(path, "w") as f:
-        f.write(json.dumps(rec) + "\n")
-    return str(path)
-
-
-def test_perf_diff_clean_and_fresh_compile_regression(tmp_path):
-    import perf_diff
-
-    base = _bench_artifact(tmp_path / "base.json")
-    same = _bench_artifact(tmp_path / "same.json")
-    # identical artifacts: clean (returns, no SystemExit)
-    perf_diff.main([same, "--baseline", base])
-    # +30% fresh compiles: deterministic counter, must gate HARD even
-    # though it sits inside any noise band
-    worse = _bench_artifact(tmp_path / "worse.json",
-                            fresh_compiles=int(4 * 1.3) + 1)
-    with pytest.raises(SystemExit) as ei:
-        perf_diff.main([worse, "--baseline", base])
-    assert ei.value.code == 1
-
-
-def test_perf_diff_timing_noise_band(tmp_path):
-    import perf_diff
-
-    base = _bench_artifact(tmp_path / "base.json")
-    # +20% p50 sits inside the default 25% band: noise, not regression
-    noisy = _bench_artifact(tmp_path / "noisy.json", p50=60.0)
-    perf_diff.main([noisy, "--baseline", base])
-    # +60% p50 is a regression
-    slow = _bench_artifact(tmp_path / "slow.json", p50=80.0)
-    with pytest.raises(SystemExit) as ei:
-        perf_diff.main([slow, "--baseline", base])
-    assert ei.value.code == 1
-    # a higher predicted peak is deterministic: gates hard at any size
-    fat = _bench_artifact(tmp_path / "fat.json", predicted=2000001)
-    with pytest.raises(SystemExit) as ei:
-        perf_diff.main([fat, "--baseline", base])
-    assert ei.value.code == 1
-
-
-def test_perf_diff_budget_mode(tmp_path):
-    import perf_diff
-
-    cand = _bench_artifact(tmp_path / "cand.json")
-    budgets = tmp_path / "budgets.json"
-    budgets.write_text(json.dumps({
-        "band": 0.5,
-        "models": {"resnet50": {
-            "fresh_compiles": {"max": 4, "why": "seed"},
-            "predicted_peak_bytes": {"max": 2000000, "why": "seed"},
-            "step_ms_p50": {"max": 50.0, "why": "seed"},
-            "throughput": {"min": 10.0, "why": "seed"},
-        }}}))
-    perf_diff.main([cand, "--budgets", str(budgets)])
-    over = _bench_artifact(tmp_path / "over.json", fresh_compiles=5)
-    with pytest.raises(SystemExit) as ei:
-        perf_diff.main([over, "--budgets", str(budgets)])
-    assert ei.value.code == 1
-
-
-def test_perf_diff_budget_mode_fails_on_missing_metric(tmp_path):
-    """A budgeted metric absent from the candidate is a FAILURE, not a
-    silent skip — a PR that breaks the telemetry capture must not turn
-    the gate green by shrinking what it checks."""
-    import perf_diff
-
-    budgets = tmp_path / "budgets.json"
-    budgets.write_text(json.dumps({
-        "band": 0.5,
-        "models": {"resnet50": {
-            "fresh_compiles": {"max": 4, "why": "seed"},
-            "throughput": {"min": 10.0, "why": "seed"},
-        }}}))
-    # a capture that lost its exec-cache counters: throughput survives,
-    # fresh_compiles is gone
-    bare = tmp_path / "bare.json"
-    bare.write_text(json.dumps(
-        {"models": {"resnet50": {"value": 10.0}}}) + "\n")
-    with pytest.raises(SystemExit) as ei:
-        perf_diff.main([str(bare), "--budgets", str(budgets)])
-    assert ei.value.code == 1
-
-
 def test_predicted_peak_no_cross_executable_fallback():
     """An explicit fingerprint with no registered plan must report None,
     not another executable's prediction."""
@@ -418,38 +296,6 @@ def test_predicted_peak_no_cross_executable_fallback():
     assert memory.predicted_peak("fp_a") == 123
     assert memory.predicted_peak("fp_unplanned") is None
     assert memory.predicted_peak() == 123  # no fingerprint: last plan
-
-
-def test_perf_diff_unreadable_exits_2(tmp_path):
-    import perf_diff
-
-    bad = tmp_path / "bad.json"
-    bad.write_text("not json at all {{{")
-    with pytest.raises(SystemExit) as ei:
-        perf_diff.main([str(bad), "--baseline", str(bad)])
-    assert ei.value.code == 2
-
-
-def test_committed_budgets_parse_and_cover_the_gate():
-    """The checked-in budgets file must parse, carry lineage for every
-    number, and budget the deterministic counters the gate exists for."""
-    with open(os.path.join(REPO, "benchmark", "budgets.json")) as f:
-        budgets = json.load(f)
-    assert budgets["models"], "budgets must cover at least one model"
-    for model, entries in budgets["models"].items():
-        assert "fresh_compiles" in entries, model
-        if model not in ("servechaos", "router", "trace", "stepprof"):
-            # every bench-leg model budgets its memory plan; the
-            # servechaos/router/trace/stepprof smoke captures have no
-            # memory_plan surface — their deterministic gate is
-            # fresh_compiles == 0 (in the RESTORED process / on the
-            # failover survivor / across the tracing-ON wire leg /
-            # across the profiled replay)
-            assert "predicted_peak_bytes" in entries, model
-        for metric, spec in entries.items():
-            assert spec.get("why"), (
-                "budget %s/%s needs a lineage 'why'" % (model, metric))
-            assert "max" in spec or "min" in spec, (model, metric)
 
 
 # ---------------------------------------------------------------------------

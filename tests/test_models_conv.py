@@ -72,8 +72,8 @@ def test_vgg_builds_and_steps():
 
 
 def test_resnet50_imagenet_builds():
-    """ResNet-50 graph builds and infers shapes (train step exercised in
-    bench.py on real hardware; too heavy for unit CI)."""
+    """ResNet-50 graph builds and infers shapes (its train step is too
+    heavy for unit CI)."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
         loss, feeds, extras = resnet.build(

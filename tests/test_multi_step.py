@@ -76,8 +76,7 @@ def test_multi_step_stacked_fetches_trajectory():
 
 
 def test_multi_step_with_in_graph_reader():
-    """No feeds at all: input comes from the in-graph random reader, the
-    bench.py configuration."""
+    """No feeds at all: input comes from the in-graph random reader."""
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = 11
     startup.random_seed = 11

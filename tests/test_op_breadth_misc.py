@@ -559,7 +559,7 @@ def test_attention_lstm_outputs_and_grad():
         ["X", "CellW", "StateProjW"], "Hidden", max_relative_error=2e-2)
 
 
-def test_attention_lstm_beam_decode_smoke():
+def test_attention_lstm_beam_decode_runs():
     rng = _RNG(75)
     B, S, D, C, V, M, K, T = 2, 5, 3, 5, 11, 4, 3, 6
     ins = {

@@ -669,6 +669,12 @@ def test_staggered_admissions_bit_identical_to_dense_decoder(trained):
         assert steps < 100
     np.testing.assert_array_equal(got, want)
     assert sess.pages_in_use == 0  # everything recycled
+    # and the scrape says so: the pool's gauge back at 0, a decode rate
+    from paddle_tpu.observability import REGISTRY
+
+    text = REGISTRY.to_prometheus()
+    assert "paddle_tpu_serving_kv_pages_in_use 0" in text
+    assert "paddle_tpu_serving_decode_tokens_per_sec" in text
 
 
 def test_multi_token_dispatch_matches_and_reruns_warm(trained):

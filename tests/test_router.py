@@ -31,6 +31,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import flags
+from paddle_tpu.core import exec_cache
 from paddle_tpu.distributed.master import (
     AuthError,
     JsonLineClient,
@@ -414,6 +415,7 @@ def test_failover_restores_banked_snapshot_bit_exact(
     src = trained["src"]
     s1, s2, oracle = _paged(trained), _paged(trained), _paged(trained)
     exp = _expected_tokens(oracle, src[LONG_SRC])
+    compiled = exec_cache.stats()["fresh_compiles"]
     fe1 = ServingFrontend(
         session=s1, snapshot_manager=DecodeSnapshotManager(
             s1, str(tmp_path / "snapA"), interval_steps=1))
@@ -459,6 +461,8 @@ def test_failover_restores_banked_snapshot_bit_exact(
                     s2.pool_conserved and not s2.active_slots):
                 time.sleep(0.02)
             assert s2.pool_conserved
+            # the survivor served the restore from executables it had
+            assert exec_cache.stats()["fresh_compiles"] == compiled
             m2.close()
         finally:
             chaos.disable()

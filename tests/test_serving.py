@@ -123,7 +123,7 @@ def test_mixed_sizes_resolve_to_ladder_and_stop_compiling(
         demo_predictor):
     """After warmup over the bucket ladder, a mixed-batch-size load adds
     ZERO fresh compiles — the L001 mitigation, measured at the
-    exec-cache counters the CI smoke scrapes."""
+    exec-cache counters."""
     server = BatchingServer(demo_predictor, max_batch=8, workers=1)
     try:
         assert server.warmup() == [2, 4, 8]

@@ -20,7 +20,10 @@ graceful degradation, chaos-hardened serving dispatch.
 * a Pallas ``paged_attention`` failure trips the once-per-process
   reference fallback (counter + flag) instead of killing the request;
 * SIGTERM mid-decode finishes the in-flight dispatch, banks a final
-  snapshot and dies BY the signal (subprocess leg).
+  snapshot and dies BY the signal (subprocess leg);
+* SIGKILL entering a seeded step dispatch (``kill@site=serve.dispatch``):
+  another process restores the newest verified periodic snapshot and
+  re-emits the whole backlog bit-identical (subprocess leg).
 """
 
 import json
@@ -34,6 +37,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import flags
+from paddle_tpu.core import exec_cache
 from paddle_tpu.executor import global_scope
 from paddle_tpu.observability import REGISTRY
 from paddle_tpu.resilience import chaos
@@ -136,7 +140,9 @@ def test_snapshot_restore_is_bit_exact_mid_flight(trained, tmp_path):
     mgr.save()
     mgr.close(save=False)
 
-    # restored: a FRESH session + restore, then the same continuation
+    # restored: a FRESH session + restore, then the same continuation,
+    # every executable of it one the oracle's run already compiled
+    compiled = exec_cache.stats()["fresh_compiles"]
     restored = _paged(trained)
     mgr2 = DecodeSnapshotManager(restored, str(tmp_path / "snap"))
     manifest = mgr2.restore()
@@ -158,6 +164,8 @@ def test_snapshot_restore_is_bit_exact_mid_flight(trained, tmp_path):
         np.testing.assert_array_equal(rdone[rid], vdone2[rid])
     for o_rid, rid in zip(orids, vrids):
         np.testing.assert_array_equal(odone[o_rid], rdone[rid])
+    assert exec_cache.stats()["fresh_compiles"] == compiled, (
+        "the restore and its continuation paid fresh compiles")
     mgr2.close(save=False)
 
 
@@ -678,43 +686,104 @@ def test_server_dispatch_arms_watchdog(trained, tmp_path, monkeypatch):
             == len([e for e in events if e[0] == "disarm"]))
 
 
-# -- SIGTERM mid-decode (subprocess) -----------------------------------------
+# -- a signal mid-decode (subprocess) ----------------------------------------
 
+def _drill(n_requests):
+    """A seeded 1-layer model, a factory of paged sessions over it and
+    request rows, built the same in ANY process (fresh name counters, both
+    programs seeded): the subprocess legs' victim, and the oracle and the
+    restored session of the test that outlives it."""
+    from paddle_tpu import unique_name
+    from paddle_tpu.core.scope import Scope
+    from paddle_tpu.models import transformer
+
+    cfg = dict(CFG, n_layer=1)
+    with unique_name.guard({}):
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 41
+        with fluid.program_guard(main, startup):
+            transformer.build(dropout=0.0, label_smooth_eps=0.0,
+                              max_length=SEQ, d_model=D, **cfg)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+
+    def session():
+        return SlotDecodeSession(
+            exe, num_slots=S, max_length=SEQ, d_model=D, paged=True,
+            page_size=4, steps=2, sampler=Sampler(seed=3),
+            scope=scope.new_scope(), **cfg)
+
+    src = np.random.RandomState(7).randint(
+        3, VOCAB, (n_requests, SEQ)).astype("int64")
+    return session, src
+
+
+# argv: snapshot dir, snapshot interval in steps, number of requests
 _CHILD = r"""
 import os, sys, time
-import numpy as np
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import paddle_tpu as fluid
-from paddle_tpu.models import transformer
-from paddle_tpu.serving.generation import Sampler, SlotDecodeSession
-from paddle_tpu.serving.snapshot import DecodeSnapshotManager
+sys.path.insert(0, os.path.join(os.getcwd(), "tests"))
+import test_serving_resilience as t
 
-snap_dir = sys.argv[1]
-VOCAB, SEQ, D, S = 24, 8, 32, 4
-CFG = dict(src_vocab_size=VOCAB, trg_vocab_size=VOCAB, n_layer=1,
-           n_head=2, d_inner=64)
-main, startup = fluid.Program(), fluid.Program()
-main.random_seed = 41; startup.random_seed = 41
-with fluid.program_guard(main, startup):
-    transformer.build(dropout=0.0, label_smooth_eps=0.0,
-                      max_length=SEQ, d_model=D, **CFG)
-exe = fluid.Executor(fluid.CPUPlace())
-exe.run(startup)
-sess = SlotDecodeSession(exe, num_slots=S, max_length=SEQ, d_model=D,
-                         paged=True, page_size=4, steps=2,
-                         sampler=Sampler(seed=3), **CFG)
-mgr = DecodeSnapshotManager(sess, snap_dir,
-                            install_signal_handlers=True)
-rng = np.random.RandomState(7)
-src = rng.randint(3, VOCAB, (64, SEQ)).astype("int64")
-for i in range(64):
-    sess.enqueue(src[i])
+session, src = t._drill(int(sys.argv[3]))
+sess = session()
+mgr = t.DecodeSnapshotManager(sess, sys.argv[1],
+                              interval_steps=int(sys.argv[2]),
+                              install_signal_handlers=True)
+for row in src:
+    sess.enqueue(row)
 print("READY", flush=True)
 while sess._pending or sess._live:
     sess.pump()
     time.sleep(0.01)
-print("DRAINED", flush=True)  # only reached if SIGTERM never lands
+print("DRAINED", flush=True)  # only reached if no signal ever lands
 """
+
+_REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
+
+
+def test_sigkill_mid_decode_is_resumed_bit_exact_by_another_process(
+        tmp_path):
+    """The real preemption: a decoding process with periodic snapshots is
+    SIGKILLed entering a seeded step dispatch (no handler, no cleanup);
+    THIS process restores the newest verified snapshot into a fresh
+    session and re-emits every stream of the backlog, those finished
+    before the snapshot out of its result bank, bit-identical to an
+    uninterrupted run. Ten full-length requests over four slots finish in
+    dispatches 4, 8 and 12; the snapshots fall after dispatches 3 and 6
+    and the kill on entering the 8th, so whichever of the two was written
+    out in time is restored. (Not after dispatch 4 or 8: a snapshot taken
+    at the quiesce point of the dispatch that FINISHES a request holds
+    it neither live nor banked, ``pump`` banks after ``step`` returns:
+    ROADMAP D23.)"""
+    snap_dir = str(tmp_path / "snap")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               FLAGS_chaos_spec="seed=5;kill@site=serve.dispatch,step=7")
+    victim = subprocess.run(
+        [sys.executable, "-c", _CHILD, snap_dir, "3", "10"], env=env,
+        cwd=_REPO, capture_output=True, text=True, timeout=180)
+    assert victim.returncode == -signal.SIGKILL, (
+        victim.returncode, victim.stderr[-2000:])
+    assert "READY" in victim.stdout and "DRAINED" not in victim.stdout
+
+    session, src = _drill(10)
+    oracle = session()
+    rids = [oracle.enqueue(row) for row in src]
+    want = {}
+    while len(want) < len(rids):
+        want.update(oracle.pump())
+    restored = session()
+    mgr = DecodeSnapshotManager(restored, snap_dir)
+    assert mgr.restore() is not None, "no restorable snapshot after SIGKILL"
+    assert restored._pending or restored._live, "nothing was cut off"
+    got = {}
+    while restored._pending or restored._live:
+        got.update(restored.pump())
+    for rid in rids:
+        tokens = got[rid] if rid in got else restored.take_result(rid)
+        np.testing.assert_array_equal(tokens, want[rid])
+    mgr.close(save=False)
 
 
 @pytest.mark.slow
@@ -723,10 +792,9 @@ def test_sigterm_banks_final_snapshot_and_dies_by_signal(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("FLAGS_chaos_spec", None)
     proc = subprocess.Popen(
-        [sys.executable, "-c", _CHILD, snap_dir],
+        [sys.executable, "-c", _CHILD, snap_dir, "0", "64"],
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-        env=env,
-        cwd=os.path.join(os.path.dirname(__file__), os.pardir))
+        env=env, cwd=_REPO)
     try:
         line = proc.stdout.readline().strip()
         assert line == "READY", (line, proc.stderr.read())

@@ -17,6 +17,8 @@ verify kernel, the drafters, and the end-to-end session.
   kernel contract requires (and reject malformed trees loudly).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -384,6 +386,13 @@ def test_greedy_stream_is_bit_identical_to_off_oracle(trained):
     assert sess.spec_accepted > 0, \
         "drafter never landed a token on a trained copy task"
     assert sess.pages_in_use == 0  # spec churn recycled everything
+    # the acceptance telemetry is published, and counts what was proposed
+    text = REGISTRY.to_prometheus()
+    m = re.search(r"^paddle_tpu_serving_speculative_proposed_tokens_total "
+                  r"(\d+)", text, re.MULTILINE)
+    assert m and int(m.group(1)) >= sess.spec_proposed
+    assert "paddle_tpu_serving_speculative_accepted_tokens_total" in text
+    assert "paddle_tpu_serving_speculative_acceptance_rate" in text
     flags.set_flag("speculative", "off")
     off = sess.generate(trained["src"], trained["src_len"])
     np.testing.assert_array_equal(on, off)

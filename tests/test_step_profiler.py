@@ -1,13 +1,11 @@
 """Training-step observatory: the observe-don't-perturb contract
 (OFF = silent, ON = bit-identical + zero fresh compiles), phase
 coverage, the roofline/MFU join, starvation banking, the regression
-detector naming the guilty phase, the bounded ring, and the
-perf-ledger round trip."""
+detector naming the guilty phase, the bounded ring, and the JSONL
+flush."""
 
 import json
 import math
-import os
-import sys
 import time
 
 import numpy as np
@@ -59,7 +57,7 @@ def _leg(exe, main, startup, loss, singles=2, multi=8):
     """One schedule on a SHARED Executor with the run counter rewound:
     the step PRNG key folds the counter in, so identical counters replay
     identical init and step keys — legs compare executable for
-    executable (the stepprof_smoke.py discipline, sized for pytest)."""
+    executable."""
     exe._run_counter = 0
     exe.run(startup)
     out = []
@@ -298,7 +296,8 @@ class _SteppedClock(object):
         return getattr(time, name)
 
 
-def test_jsonl_flush_and_perf_ledger_round_trip(tmp_path, monkeypatch):
+def test_jsonl_flush_writes_every_record_with_its_brackets(
+        tmp_path, monkeypatch):
     """The brackets' arithmetic on a clock the test controls (a loaded
     host once read ``phase_coverage`` 0.6553 here, ROADMAP S6(d)): every
     dispatch takes 10 ms inside its ``dispatch`` bracket and a microsecond
@@ -329,37 +328,13 @@ def test_jsonl_flush_and_perf_ledger_round_trip(tmp_path, monkeypatch):
     n = step_profiler.write_stepprof_jsonl(str(jsonl))
     assert n == len(step_profiler.records())
     lines = [json.loads(l) for l in jsonl.read_text().splitlines()]
-    assert len(lines) == n
-
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "tools"))
-    import perf_ledger
-
-    entry = perf_ledger.summarize_stepprof(lines)
-    assert entry["records"] == 4  # startup + 3 train steps
-    # 10 ms in a bracket, a few readings of 1 us outside every bracket
-    assert 0.999 <= entry["phase_coverage"] <= 1.0
-    assert entry["step_ms"]["p50"] == pytest.approx(10.0, abs=0.05)
-    assert entry["regressions"] == 0
-    assert "achieved_mfu" not in entry  # cpu: not measured
-
-    ledger = tmp_path / "ledger.jsonl"
-    for label in ("a", "b"):
-        perf_ledger.append_entry(str(ledger), {"stepprof": entry},
-                                 label=label)
-    assert len(perf_ledger.read_ledger(str(ledger))) == 2
-    # identical trajectory points must gate clean (cmd_diff raises
-    # SystemExit(1) on regression, returns on clean)
-    perf_ledger.main(["diff", "--ledger", str(ledger)])
-
-    # a slowed newest entry must FAIL the relative gate
-    worse = dict(entry, step_ms={"p50": entry["step_ms"]["p50"] * 10,
-                                 "p95": entry["step_ms"]["p95"] * 10})
-    perf_ledger.append_entry(str(ledger), {"stepprof": worse}, label="c")
-    with pytest.raises(SystemExit) as ex:
-        perf_ledger.main(["diff", "--ledger", str(ledger)])
-    assert ex.value.code == 1
+    assert len(lines) == n == 4  # startup + 3 train steps
+    for rec in lines:
+        # 10 ms in a bracket, a few readings of 1 us outside every bracket
+        assert 0.999 <= rec["coverage"] <= 1.0
+        assert rec["step_s"] == pytest.approx(0.010, abs=5e-5)
+        assert not rec.get("regression")
+        assert rec.get("achieved_mfu") is None  # cpu: not measured
 
 
 # -- the always-on dispatch record -------------------------------------------

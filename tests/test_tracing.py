@@ -16,9 +16,9 @@ hooks):
   or force-closed spans and the in-flight table drains to empty;
 * blackbox snapshots list in-flight trace ids.
 
-Tracing must also be FREE when off — that half (byte-identical wire
-streams, zero fresh compiles, no minted context) is proved over real
-sockets by tools/trace_smoke.py (CI ``trace`` stage).
+Tracing must also be FREE when off: the session allocates nothing, and
+over a real socket the same streams come back with no trace field in any
+envelope and nothing in the rings.
 """
 
 import threading
@@ -332,7 +332,7 @@ def _count_readings(monkeypatch):
 def test_tracing_off_session_allocates_nothing(trained, monkeypatch):
     """With tracing off, the session's per-request maps stay empty —
     the zero-allocation half of the overhead contract at the session
-    layer (the wire half is tools/trace_smoke.py's control leg)."""
+    layer (the wire half is the next test's control leg)."""
     from paddle_tpu.observability import step_profiler
 
     tracing.enable(False)
@@ -352,6 +352,45 @@ def test_tracing_off_session_allocates_nothing(trained, monkeypatch):
     recs = step_profiler.dispatch_records()
     assert recs and readings == []
     assert all(r["cpu"] is None for r in recs)
+
+
+def test_the_wire_carries_a_trace_only_with_tracing_on(trained):
+    """Over a real socket. Off (the control): no trace id is minted and
+    the rings stay empty. On: the SAME tokens, no fresh compile, and one
+    trace a request that resolves over the wire, whose spans cover the
+    wall the client saw (both read 0.97-0.98 on an idle host; the floor
+    is 0.8 because the suite's workers share the cores and eight tokens
+    are ~100 ms), and that the TTFT histogram's exemplar names."""
+    from paddle_tpu.core import exec_cache
+    from paddle_tpu.serving.client import ServingClient
+    from paddle_tpu.serving.frontend import ServingFrontend, _fe_ttft
+
+    src = trained["src"]
+    rows = {}
+    for on in (False, True):
+        tracing.reset()
+        tracing.enable(on)
+        with ServingFrontend(session=_paged(trained)) as fe:
+            cl = ServingClient(fe.address)
+            compiled = exec_cache.stats()["fresh_compiles"]
+            t0 = time.time()
+            rows[on] = cl.generate_full(src[0], src_len=SEQ).tolist()
+            wall = time.time() - t0
+            if not on:
+                assert cl.last_trace_id is None
+                assert not tracing.completed() and not tracing.inflight_ids()
+            else:
+                assert exec_cache.stats()["fresh_compiles"] == compiled
+                rec = cl.trace(cl.last_trace_id)
+                assert rec and rec["trace_id"] == cl.last_trace_id
+                union = tracing._union_seconds(rec["spans"], rec["t1"])
+                assert union / wall >= 0.8, (union, wall, rec["stats"])
+                assert rec["stats"]["span_coverage"] >= 0.8, rec["stats"]
+                exemplar = next(iter(_fe_ttft.exemplars().values()))["id"]
+                assert cl.trace(exemplar)["trace_id"] == exemplar
+                assert not tracing.inflight_ids()
+            cl.close()
+    assert rows[True] == rows[False]
 
 
 # -- the decode worker's rounds ----------------------------------------------

@@ -18,7 +18,7 @@ accounting against the slot page lists + prefix trie).
 
 Exit codes:  0 ok · 1 usage/unreadable · 2 verification failed (digest
 mismatch / missing file / no complete checkpoint) — the code the chaos
-CI stage and restore-time tooling gate on.
+smoke and restore-time tooling gate on.
 """
 
 import argparse

@@ -1,8 +1,9 @@
 """Offline MFU cost model: per-fused-op flops + HBM bytes from the
 TPU-target lowering, no chip required.
 
-Perf planning that does not spend chip time: this tool traces the EXACT
-train step bench.py times — same program builders, same shapes, same
+Perf planning that does not spend chip time: this tool traces a whole
+train step (ResNet-50 at batch 128, Transformer-base at 64 x 256) — the
+models' own program builders, the
 bf16 AMP rewrite, and the TPU kernel selection (ambient platform "tpu"
 picks the Pallas flash-attention path, not the CPU reference path) —
 then walks the jaxpr with an XLA-style fusion-group model:
@@ -34,7 +35,7 @@ decisions, not a readback of them; pallas_call HBM bytes are an upper
 bound (grid steps whose index map revisits a block may be served from
 VMEM); while_loop trip counts are unknown statically (reported with
 multiplier 1). Totals are cross-checked against the analytic FLOP
-accounting bench.py uses for MFU.
+accounting (2 FLOPs per MAC, the convention of the chips' peaks).
 """
 
 import argparse
@@ -566,7 +567,7 @@ def summarize(groups, model_flops, label):
 # ---------------------------------------------------------------- models
 
 def build_resnet(fluid, bs, img):
-    """Same program bench.py times (bench.py:_bench_resnet, graph data)."""
+    """ResNet-50's train step on in-graph random data."""
     from paddle_tpu.models import resnet
     from paddle_tpu.transpiler import rewrite_program_amp
     main_prog, startup = fluid.Program(), fluid.Program()
@@ -582,8 +583,8 @@ def build_resnet(fluid, bs, img):
         fluid.optimizer.Momentum(
             learning_rate=0.1, momentum=0.9).minimize(loss)
     rewrite_program_amp(main_prog, "bfloat16")
-    # bench.py TRAIN_GFLOP_PER_IMG (2-FLOPs-per-MAC hardware convention);
-    # conv flops scale ~(img/224)^2
+    # 3 x 7.76 GFLOP an image at 224 (forward + backward, 2 FLOPs per
+    # MAC, the hardware convention); conv flops scale ~(img/224)^2
     model_flops = bs * 3 * 7.76e9 * (img / 224.0) ** 2
     return main_prog, startup, {}, model_flops
 
@@ -613,7 +614,7 @@ def build_transformer(fluid, bs, seq):
     }
     feed = {k: v for k, v in feed.items()
             if any(f.name == k for f in feeds)}
-    # bench.py's exact 6N accounting (enc + dec incl. cross-attention)
+    # the exact 6N accounting (enc + dec incl. cross-attention)
     n_params = (
         n_layer * (4 * d_model * d_model + 2 * d_model * d_inner)
         + n_layer * (8 * d_model * d_model + 2 * d_model * d_inner))

@@ -11,6 +11,12 @@ Usage (TPU host):   python tools/kernel_bench.py
 CPU smoke:          BENCH_PLATFORM=cpu python tools/kernel_bench.py --quick
 (on CPU the Pallas paths run in interpreter mode and are expected to lose
 badly; only the TPU numbers decide flag defaults.)
+
+A kernel timed alone says what the kernel costs, not what the cell pays:
+a share of a cell's step printed here is taken on the HOST's clock around
+back-to-back calls, so it is a LOWER bound on the kernel's share in the
+cell, where the kernel also waits on its neighbours and on the host. The
+cell's own share comes from its traced run (``perfbench/run.py --trace 1``).
 """
 
 import argparse
@@ -89,7 +95,7 @@ def _bench_rnn(fluid, op_name, flag, shapes, steps, warmup):
 def _bench_flash(fluid, shapes, steps, warmup, window=0):
     """window > 0 also times the sliding-window pruned kernel vs the
     windowed reference at the same shape — the O(window) wall-time
-    proof interpret mode cannot provide (tools/longctx_bench.py)."""
+    proof interpret mode cannot provide."""
     import numpy as np
 
     rows = []
@@ -1097,7 +1103,7 @@ def main():
     else:
         _bench_flash(fluid, fa_shapes, steps, warmup)
         # sliding-window leg: same longest shape, window = seq/8 — the
-        # pruned-kernel wall-time proof (longctx_bench.py tile counts
+        # pruned-kernel wall-time proof (the tiles the window keeps
         # predict ~seq/(2*window)x on the flash side). Scaled with the
         # shape so the --quick smoke (seq 128) still exercises a window
         # that actually prunes.

@@ -99,7 +99,7 @@ MODULES = [
     "paddle_tpu.parallel.sharding",
     "paddle_tpu.analysis.shard_check",
     # PR 8: the serving surface (continuous batching server + the
-    # slot-paged decode session + the load generator CI/bench share)
+    # slot-paged decode session + the serving tests' demo model and load)
     "paddle_tpu.serving.server",
     "paddle_tpu.serving.generation",
     "paddle_tpu.serving.loadgen",
